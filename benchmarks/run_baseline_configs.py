@@ -40,9 +40,6 @@ def _force_cpu_mesh(n: int):
         os.environ.get("XLA_FLAGS", "") + f" --xla_force_host_platform_device_count={n}"
     )
     os.environ["JAX_PLATFORMS"] = "cpu"
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
 
 # (desc, model_tiny, model_full, mesh kwargs, microbatches, batch, greedy)

@@ -33,6 +33,7 @@ from ..models import api as M
 from ..utils import faults
 from ..utils.logging import get_logger, request_id_context
 from ..utils.metrics import DEFAULT_SIZE_BUCKETS, MetricsRegistry
+from ..utils.probe import device_summary
 from ..utils.tokenizer import load_tokenizer
 from ..utils.tracing import FlightRecorder, Trace
 from ..serving.trace_store import TraceStore
@@ -327,10 +328,13 @@ class SingleDeviceBackend:
         """Per-device health: a timed device probe, the in-process analogue
         of the reference's 5s-timeout /workers sweep
         (orchestration.py:306-329)."""
-        from ..utils.probe import probe_device
+        from ..utils.probe import device_memory, probe_device
 
         dev = jax.devices()[0]
-        return [{"stage": 0, "devices": [str(dev)], **probe_device(dev)}]
+        return [{
+            "stage": 0, "devices": [str(dev)],
+            "memory": [device_memory(dev)], **probe_device(dev),
+        }]
 
 
 class InferenceEngine:
@@ -799,8 +803,8 @@ class InferenceEngine:
         self._constraint_lock = threading.Lock()
         # Abandoned (deadline-overrun) device calls still running on their
         # daemon threads: token -> {"what", "since"}. /health flips to
-        # "degraded" while any exists (round-2 review weak #5 — on a flaky
-        # tunnel this is THE failure mode), and the server's optional
+        # "degraded" while any exists (a hung device call is the failure
+        # mode this names), and the server's optional
         # --die-on-wedge reaper exits the process off max_wedged_age().
         self._wedged: dict = {}  # guarded-by: _wedged_lock
         self._wedged_lock = threading.Lock()
@@ -2660,6 +2664,7 @@ class InferenceEngine:
             "model": self.cfg.name,
             "backend": self.backend.name,
             "n_stages": getattr(self.backend, "n_stages", 1),
+            "device": device_summary(),
             "requests_served": self.request_count,
             "stats": self.stats(),
         }

@@ -936,6 +936,7 @@ class ShadowStore:
             self._m_tier_bytes["host"].set(self._host_bytes)
             self._m_tier_bytes["disk"].set(self._disk_bytes)
 
+    # jaxlint: decode-unreachable -- host-side tier spill over numpy entries under the store lock (drain-time entry point for embedders and bench.py's kv_tiers leg; no package caller)
     def demote_host_tier(self) -> int:
         """Spill every host-tier entry to the disk tier (parents-first —
         the eviction cascade's natural order) and drop it from tier 1:

@@ -63,6 +63,23 @@ def resolve_interpret(interpret: bool | None) -> bool:
     return jax.default_backend() != "tpu"
 
 
+def scale_column(s_ref, kv, n: int):
+    """This kv head's dequant scales as an [n, 1] column, from a scale
+    block [1, KV, n] that spans every kv head (int8 KV, ops/kv_quant).
+
+    The TPU lowering takes a block whose last two dims are the array's own
+    (KV) or 8/128-aligned, so the scales ride blocked whole over KV with
+    the tokens on lanes, and the kernel picks its head's row. The row
+    becomes a column exactly — the diagonal of its sublane broadcast,
+    summed over lanes, adds only zeros — so `tile * column` is
+    bit-identical to `tile * scales[:, None]`, with no copy or padded
+    relayout of the scale array in HBM."""
+    row = s_ref[0, pl.ds(kv, 1), :]  # [1, n]
+    r = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    return jnp.sum(jnp.where(r == c, row, 0.0), axis=1, keepdims=True)
+
+
 def _needed_tiles(pos, qi, *, T: int, block_t: int, block_k: int):
     """KV tiles live for query tile qi: keys up to its last valid query
     position pos + min((qi+1)*block_t, T) - 1."""
@@ -100,9 +117,10 @@ def _flash_kernel(
 ):
     if quant:
         # int8 cache (ops/kv_quant): per-(token, head) fp32 scales ride
-        # as two extra [1, 1, block_k] operands; dequant happens in the
-        # tile prologue below — the kernel streams HALF the cache bytes
-        # from HBM and the MXU still sees fp32 tiles.
+        # as two extra [1, KV, block_k] operands (scale_column picks this
+        # head's); dequant happens in the tile prologue below — the kernel
+        # streams HALF the cache bytes from HBM and the MXU still sees
+        # fp32 tiles.
         ks_ref, vscale_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         ks_ref = vscale_ref = None
@@ -110,6 +128,7 @@ def _flash_kernel(
     pos = pos_ref[0]
     valid_from = vs_ref[pl.program_id(0)]
     win = win_ref[0]
+    kv = pl.program_id(1)
     qi = pl.program_id(2)
     j = pl.program_id(3)
     n_j = pl.num_programs(3)
@@ -137,7 +156,7 @@ def _flash_kernel(
 
         ks = k_ref[0, 0].astype(jnp.float32)  # [block_k, Dh]
         if quant:
-            ks = ks * ks_ref[0, 0][:, None]  # dequant prologue
+            ks = ks * scale_column(ks_ref, kv, block_k)  # dequant prologue
         s = jax.lax.dot_general(
             q, ks, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [rows, block_k]
@@ -159,7 +178,7 @@ def _flash_kernel(
         l_ref[:] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
         vs = v_ref[0, 0].astype(jnp.float32)
         if quant:
-            vs = vs * vscale_ref[0, 0][:, None]
+            vs = vs * scale_column(vscale_ref, kv, block_k)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
             p, vs, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -252,10 +271,11 @@ def flash_attend(
         )
         return (b, kv, jnp.clip(j, first, needed - 1), 0)
 
-    def kv_index_3(b, kv, qi, j, pos_ref, vs_ref, win_ref):
+    def scale_index(b, kv, qi, j, pos_ref, vs_ref, win_ref):
         # the quant-scale operands [B, KV, S]: same clamped tile walk,
-        # one rank down
-        return kv_index(b, kv, qi, j, pos_ref, vs_ref, win_ref)[:3]
+        # every kv head in the block (see scale_column)
+        b, _, tile, _ = kv_index(b, kv, qi, j, pos_ref, vs_ref, win_ref)
+        return (b, 0, tile)
 
     kernel = functools.partial(
         _flash_kernel,
@@ -280,10 +300,10 @@ def flash_attend(
     operands = [q5, cache_k, cache_v]
     if quant:
         # scale rows [B, KV, S] tile with the SAME clamped kv index map,
-        # one [block_k] strip per tile
+        # one [KV, block_k] strip per tile
         in_specs += [
-            pl.BlockSpec((1, 1, block_k), kv_index_3),
-            pl.BlockSpec((1, 1, block_k), kv_index_3),
+            pl.BlockSpec((1, KV, block_k), scale_index),
+            pl.BlockSpec((1, KV, block_k), scale_index),
         ]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -46,6 +46,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .flash_attention import resolve_interpret, scale_column
+
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)  # mask fill; avoids inf-inf NaNs
 
 
@@ -86,14 +88,15 @@ def _paged_kernel(
     del table_ref  # physical placement is the index maps' concern
     if quant:
         # int8 pool (ops/kv_quant): per-(token, head) fp32 scales ride as
-        # two extra [1, 1, bs] operands walking the same table; dequant in
-        # the block prologue — the table walk streams the int8 bytes, the
-        # MXU sees fp32
+        # two extra [1, KV, bs] operands walking the same table
+        # (scale_column picks this head's); dequant in the block prologue —
+        # the table walk streams the int8 bytes, the MXU sees fp32
         ks_ref, vscale_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         ks_ref = vscale_ref = None
         o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
+    kv = pl.program_id(1)
     j = pl.program_id(2)
     n_j = pl.num_programs(2)
     pos_b = pos_ref[b]
@@ -113,8 +116,8 @@ def _paged_kernel(
         ks = k_ref[0, 0].astype(jnp.float32)  # [bs, Dh]
         vs = v_ref[0, 0].astype(jnp.float32)
         if quant:
-            ks = ks * ks_ref[0, 0][:, None]
-            vs = vs * vscale_ref[0, 0][:, None]
+            ks = ks * scale_column(ks_ref, kv, bs)
+            vs = vs * scale_column(vscale_ref, kv, bs)
         s = jax.lax.dot_general(
             q, ks, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [group, bs]
@@ -173,7 +176,6 @@ def paged_flash_attend(
     Returns [B,1,H,Dh] in q.dtype — same contract as the gather path in
     engine/paged.make_paged_hook with the mask derived from pos/window.
     """
-    from .flash_attention import resolve_interpret
     from .kv_quant import KVQuant
 
     quant = isinstance(pool_k, KVQuant)
@@ -206,10 +208,10 @@ def paged_flash_attend(
         )
         return (table_ref[b, jnp.clip(j, first, needed - 1)], kv, 0, 0)
 
-    def kv_index_3(b, kv, j, table_ref, pos_ref, win_ref):
-        # the quant-scale operands [N, KV, bs]: same table walk, one rank
-        # down
-        return kv_index(b, kv, j, table_ref, pos_ref, win_ref)[:3]
+    def scale_index(b, kv, j, table_ref, pos_ref, win_ref):
+        # the quant-scale operands [N, KV, bs]: same table walk, every kv
+        # head in the block (ops/flash_attention.scale_column)
+        return (kv_index(b, kv, j, table_ref, pos_ref, win_ref)[0], 0, 0)
 
     kernel = functools.partial(
         _paged_kernel,
@@ -231,8 +233,8 @@ def paged_flash_attend(
     operands = [q5, pool_k, pool_v]
     if quant:
         in_specs += [
-            pl.BlockSpec((1, 1, bs), kv_index_3),
-            pl.BlockSpec((1, 1, bs), kv_index_3),
+            pl.BlockSpec((1, KV, bs), scale_index),
+            pl.BlockSpec((1, KV, bs), scale_index),
         ]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -386,7 +388,6 @@ def flash_attend_slots(
     q [B,1,H,Dh] (decode, T=1); cache_k/v [B,KV,S,Dh]; pos [B] int32.
     Returns [B,1,H,Dh] in q.dtype.
     """
-    from .flash_attention import resolve_interpret
 
     B, T, H, Dh = q.shape
     assert T == 1, "slots kernel serves decode steps (T=1) only"
@@ -502,6 +503,7 @@ def _ragged_kernel(
         ks_ref = vscale_ref = None
         o_ref, m_ref, l_ref, acc_ref = rest
     g = pl.program_id(0)
+    kv = pl.program_id(1)
     j = pl.program_id(2)
     n_j = pl.num_programs(2)
     q_start = meta_ref[g, 1]
@@ -527,8 +529,8 @@ def _ragged_kernel(
         ks = k_ref[0, 0].astype(jnp.float32)  # [bs, Dh]
         vs = v_ref[0, 0].astype(jnp.float32)
         if quant:
-            ks = ks * ks_ref[0, 0][:, None]
-            vs = vs * vscale_ref[0, 0][:, None]
+            ks = ks * scale_column(ks_ref, kv, bs)
+            vs = vs * scale_column(vscale_ref, kv, bs)
         s = jax.lax.dot_general(
             q, ks, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [rows, bs]
@@ -599,7 +601,6 @@ def ragged_paged_attend(
     table), which is exactly the bucketed scratch prefill's per-token
     contract — so one compiled program replaces the whole bucket ladder.
     """
-    from .flash_attention import resolve_interpret
     from .kv_quant import KVQuant
 
     quant = isinstance(pool_k, KVQuant)
@@ -636,10 +637,10 @@ def ragged_paged_attend(
         row = jnp.maximum(meta_ref[g, 0], 0)
         return (table_ref[row, jnp.clip(j, first, needed - 1)], kv, 0, 0)
 
-    def kv_index_3(g, kv, j, meta_ref, table_ref, win_ref):
-        # the quant-scale operands [N, KV, bs]: same table walk, one rank
-        # down
-        return kv_index(g, kv, j, meta_ref, table_ref, win_ref)[:3]
+    def scale_index(g, kv, j, meta_ref, table_ref, win_ref):
+        # the quant-scale operands [N, KV, bs]: same table walk, every kv
+        # head in the block (ops/flash_attention.scale_column)
+        return (kv_index(g, kv, j, meta_ref, table_ref, win_ref)[0], 0, 0)
 
     kernel = functools.partial(
         _ragged_kernel,
@@ -663,8 +664,8 @@ def ragged_paged_attend(
     operands = [q5, pool_k, pool_v]
     if quant:
         in_specs += [
-            pl.BlockSpec((1, 1, bs), kv_index_3),
-            pl.BlockSpec((1, 1, bs), kv_index_3),
+            pl.BlockSpec((1, KV, bs), scale_index),
+            pl.BlockSpec((1, KV, bs), scale_index),
         ]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(

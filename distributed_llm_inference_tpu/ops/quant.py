@@ -234,8 +234,9 @@ def q4_matmul_rows(x2d: jnp.ndarray, w: Q4Tensor, interpret: bool = None):
     guarantees the tiling gates."""
     from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    from .flash_attention import resolve_interpret
+
+    interpret = resolve_interpret(interpret)
     R, d_in = x2d.shape
     G, half, d_out = w.q.shape
     g = 2 * half

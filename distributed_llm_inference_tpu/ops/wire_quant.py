@@ -152,8 +152,8 @@ def proxy_stage_generate(cfg, params, prompt_ids, max_new: int,
     exactly the wire quantization.
 
     Used by the `bench.py wire_quant` leg and the greedy
-    token-match-rate gates; environments without jax.shard_map (the CPU
-    CI) calibrate the mesh tests' tolerance against this.
+    token-match-rate gates: the mesh tests' tolerance is calibrated
+    against this.
     """
     ranges, fwd = _proxy_fwd(cfg, n_stages, quant)
 

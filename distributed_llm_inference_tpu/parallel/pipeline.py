@@ -223,7 +223,7 @@ class SPMDBackendBase:
         from concurrent.futures import ThreadPoolExecutor
 
         from ..config import stage_layer_range
-        from ..utils.probe import probe_device
+        from ..utils.probe import device_memory, probe_device
 
         devs = self.mesh.devices  # [dp, pp, sp, tp]
         stage_devs = [devs[:, s].reshape(-1) for s in range(self.pp)]
@@ -250,6 +250,10 @@ class SPMDBackendBase:
             stage_line = {
                 "stage": s,
                 "devices": [str(d) for d in stage_devs[s]],
+                "memory": [
+                    device_memory(d) if d.process_index == me else {}
+                    for d in stage_devs[s]
+                ],
                 "layers": list(
                     range(*stage_layer_range(self.cfg.n_layers, self.pp, s))
                 ),

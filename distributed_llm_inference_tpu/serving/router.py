@@ -144,14 +144,17 @@ class Replica:
     """One upstream engine server, plus the router's view of its health."""
 
     def __init__(self, rid: str, url: str, proc=None, spawn_argv=None,
-                 spawn_env=None, replica_class: str = "mixed"):
+                 spawn_env=None, replica_class: str = "mixed",
+                 spawn_log=None):
         self.rid = rid
         self.url = url.rstrip("/")
         # router-spawned replicas carry their subprocess + respawn recipe
-        # (rolling restarts need both); URL-joined replicas have neither
+        # (rolling restarts need both) and the file their output goes to
+        # (utils/chips.child_log); URL-joined replicas have none of them
         self.proc = proc
         self.spawn_argv = spawn_argv
         self.spawn_env = spawn_env
+        self.spawn_log = spawn_log
         # disaggregation class ("prefill" | "decode" | "mixed"): set at
         # spawn (--spawn-prefill/--spawn-decode) or learned from the
         # replica's /health — fresh long-prompt work goes to prefill-
@@ -1160,7 +1163,7 @@ class Router:
             rep.proc.wait(timeout=10)
         rep.proc = subprocess.Popen(
             rep.spawn_argv, env=rep.spawn_env,
-            stdout=subprocess.DEVNULL, stderr=subprocess.STDOUT,
+            stdout=rep.spawn_log, stderr=subprocess.STDOUT,
         )
         self._wait_replica_ready(rep)
         # warm-handoff check: a replica started with --restore-dir
@@ -1198,9 +1201,11 @@ class Router:
         t0 = time.time()
         while time.time() - t0 < deadline_s:
             if rep.proc.poll() is not None:
+                from ..utils import chips
+
                 raise RuntimeError(
                     f"{rep.rid} exited rc={rep.proc.returncode} during "
-                    "rolling restart"
+                    f"rolling restart; {chips.log_tail(rep.spawn_log)}"
                 )
             try:
                 with urllib.request.urlopen(
@@ -1714,6 +1719,8 @@ class RouterServer:
         for rep in self.router.replicas:
             if rep.proc is not None and rep.proc.poll() is None:
                 rep.proc.send_signal(signal.SIGTERM)
+            if rep.spawn_log is not None:
+                rep.spawn_log.close()
 
 
 def _free_port(host: str = "127.0.0.1") -> int:
@@ -1727,15 +1734,22 @@ def _free_port(host: str = "127.0.0.1") -> int:
 def spawn_replicas(n: int, spawn_args, host: str = "127.0.0.1",
                    ready_deadline_s: float = 300.0, env=None,
                    replica_class: str = "mixed",
-                   name_prefix: str = "r") -> list:
+                   name_prefix: str = "r", first_chip: int = 0) -> list:
     """Spawn N engine servers as subprocesses on free ports and wait for
     every /ready. Each replica remembers its argv/env so rolling restarts
     can respawn it identically. replica_class != "mixed" appends
     --replica-class to every spawn (and tags the router-side Replica), so
     --spawn-prefill/--spawn-decode build a disaggregated fleet from one
-    argument string."""
-    import os
+    argument string.
 
+    One process for each chip (utils/chips.py): on a TPU host replica i
+    is given chip first_chip + i and nothing else, and a fleet larger
+    than the host's chip count is refused before anything starts. Each
+    replica's output goes to a log file of its own; a start-up failure
+    quotes its end."""
+    from ..utils import chips
+
+    chips.check_chip_budget(first_chip + n, env)
     replicas = []
     for i in range(n):
         port = _free_port(host)
@@ -1746,15 +1760,15 @@ def spawn_replicas(n: int, spawn_args, host: str = "127.0.0.1",
         ]
         if replica_class != "mixed":
             argv += ["--replica-class", replica_class]
-        spawn_env = dict(os.environ if env is None else env)
+        spawn_env = chips.child_env(env, first_chip + i)
+        spawn_log = chips.child_log(f"{name_prefix}{i}")
         proc = subprocess.Popen(
-            argv, env=spawn_env,
-            stdout=subprocess.DEVNULL, stderr=subprocess.STDOUT,
+            argv, env=spawn_env, stdout=spawn_log, stderr=subprocess.STDOUT,
         )
         replicas.append(Replica(
             f"{name_prefix}{i}", f"http://{host}:{port}", proc=proc,
             spawn_argv=argv, spawn_env=spawn_env,
-            replica_class=replica_class,
+            replica_class=replica_class, spawn_log=spawn_log,
         ))
     deadline = time.time() + ready_deadline_s
     for rep in replicas:
@@ -1762,7 +1776,7 @@ def spawn_replicas(n: int, spawn_args, host: str = "127.0.0.1",
             if rep.proc.poll() is not None:
                 raise SystemExit(
                     f"replica {rep.rid} exited rc={rep.proc.returncode} "
-                    "during startup"
+                    f"during startup; {chips.log_tail(rep.spawn_log)}"
                 )
             try:
                 with urllib.request.urlopen(
@@ -1773,9 +1787,15 @@ def spawn_replicas(n: int, spawn_args, host: str = "127.0.0.1",
             except (urllib.error.URLError, OSError):
                 pass
             if time.time() > deadline:
-                raise SystemExit(f"replica {rep.rid} never became ready")
+                raise SystemExit(
+                    f"replica {rep.rid} never became ready; "
+                    f"{chips.log_tail(rep.spawn_log)}"
+                )
             time.sleep(0.2)
-        print(f"✅ replica {rep.rid} ready at {rep.url}")
+        print(
+            f"✅ replica {rep.rid} ready at {rep.url} "
+            f"(log: {rep.spawn_log.name})"
+        )
     return replicas
 
 
@@ -1869,6 +1889,12 @@ def main(argv: Optional[list] = None):
     )
     args = ap.parse_args(argv)
 
+    from ..utils import chips
+
+    # the whole fleet against the host's chips, before the first spawn
+    chips.check_chip_budget(
+        args.spawn + args.spawn_prefill + args.spawn_decode
+    )
     replicas = []
     if args.spawn > 0:
         replicas.extend(
@@ -1878,11 +1904,13 @@ def main(argv: Optional[list] = None):
         replicas.extend(spawn_replicas(
             args.spawn_prefill, shlex.split(args.spawn_args),
             replica_class="prefill", name_prefix="p",
+            first_chip=len(replicas),
         ))
     if args.spawn_decode > 0:
         replicas.extend(spawn_replicas(
             args.spawn_decode, shlex.split(args.spawn_args),
             replica_class="decode", name_prefix="d",
+            first_chip=len(replicas),
         ))
     if args.replicas:
         for i, url in enumerate(u for u in args.replicas.split(",") if u):

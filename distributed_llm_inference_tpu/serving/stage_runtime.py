@@ -80,7 +80,7 @@ import numpy as np
 from ..config import ModelConfig
 from ..models import api as M
 from ..models.registry import get_model_config
-from ..utils import faults
+from ..utils import chips, faults
 from ..utils.logging import get_logger
 from ..utils.metrics import MetricsRegistry
 from ..utils.retry import RETRY_STATUSES, retry_delay
@@ -533,7 +533,11 @@ def _watch_parent(srv: ThreadingHTTPServer, ppid: int):
 
 def stage_main(args) -> int:
     """CLI entry for one stage process (see main() for the flags)."""
+    from ..utils import compile_cache
+
     faults.arm_from_env()
+    # respawned stages reuse their predecessor's compiled programs
+    compile_cache.enable()
     cfg = get_model_config(args.model)
     worker = StageWorker(
         cfg, args.stage, args.stages, seed=args.seed,
@@ -742,6 +746,13 @@ class StageSupervisor:
             raise ValueError("need one port per stage")
         self.restart_budget = int(restart_budget)
         self.env = dict(env) if env else None
+        # one process for each chip (utils/chips.py): refuse a fleet the
+        # host's chips cannot hold before any stage starts; each stage's
+        # output goes to a log file of its own
+        chips.check_chip_budget(self.n_stages, self.env)
+        self._logs = [
+            chips.child_log(f"stage{s}") for s in range(self.n_stages)
+        ]
         self._argv_extra = []
         if max_seq:
             self._argv_extra += ["--max-seq", str(max_seq)]
@@ -769,9 +780,11 @@ class StageSupervisor:
         ] + self._argv_extra
 
     def spawn(self, stage: int) -> subprocess.Popen:
+        """Start stage `stage` on a chip of its own (on a TPU host it sees
+        chip `stage` and nothing else)."""
         proc = subprocess.Popen(
-            self.spawn_argv(stage), env=self.env,
-            stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
+            self.spawn_argv(stage), env=chips.child_env(self.env, stage),
+            stdout=self._logs[stage], stderr=subprocess.STDOUT,
         )
         with self._lock:
             self._procs[stage] = proc
@@ -780,6 +793,10 @@ class StageSupervisor:
     def spawn_all(self):
         for s in range(self.n_stages):
             self.spawn(s)
+
+    def log_tail(self, stage: int) -> str:
+        """Where stage `stage`'s output went, and how it ends."""
+        return chips.log_tail(self._logs[stage])
 
     def proc(self, stage: int) -> Optional[subprocess.Popen]:
         with self._lock:
@@ -824,6 +841,8 @@ class StageSupervisor:
     def shutdown(self):
         for s in range(self.n_stages):
             self.stop(s, kill=True, timeout_s=5.0)
+        for log_file in self._logs:
+            log_file.close()
 
 
 # -- controller ---------------------------------------------------------------
@@ -923,7 +942,8 @@ class MPMDPipeline:
                 pass
             if not self.sup.proc_alive(stage):
                 raise RuntimeError(
-                    f"stage {stage} exited before becoming ready"
+                    f"stage {stage} exited before becoming ready; "
+                    f"{self.sup.log_tail(stage)}"
                 )
             time.sleep(0.1)
         raise TimeoutError(f"stage {stage} not ready in {timeout_s}s")

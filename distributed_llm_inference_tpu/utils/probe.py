@@ -5,7 +5,7 @@ with a 5 s timeout and reports online / offline / error
 (/root/reference/orchestration.py:306-329). A mesh stage is an in-process
 device slice, so the equivalent probe is a tiny timed device op: round-trip
 one scalar through the device and report how long it took. A wedged device
-(hung transfer queue, dead tunnel) is reported "offline" after the timeout
+(hung transfer queue, lost device) is reported "offline" after the timeout
 instead of hanging the health endpoint.
 """
 
@@ -52,3 +52,26 @@ def probe_device(dev, timeout_s: float = 5.0, _op=None) -> dict:
             "error": f"device probe timed out after {timeout_s:.1f}s",
         }
     return result
+
+
+def device_summary() -> dict:
+    """What this process runs on, as JAX reports it: the /health `device`
+    field (and chip_smoke.py's last line)."""
+    devs = jax.devices()
+    return {
+        "platform": devs[0].platform,
+        "kind": devs[0].device_kind,
+        "count": len(devs),
+    }
+
+
+def device_memory(dev) -> dict:
+    """One device's allocator counters for the /workers detail —
+    `bytes_in_use` per device shows whether a sharded model really spread
+    over the mesh. Empty where the backend reports none (CPU)."""
+    stats = dev.memory_stats() or {}
+    return {
+        k: stats[k]
+        for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+        if k in stats
+    }

@@ -37,11 +37,6 @@ from distributed_llm_inference_tpu.engine.engine import InferenceEngine
 from distributed_llm_inference_tpu.models import api as M
 from distributed_llm_inference_tpu.utils import faults
 
-needs_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="this jax build has no jax.shard_map (pp backends unavailable)",
-)
-
 SERVE_CFG = dict(dtype="float32", eos_token_id=-1, max_seq_len=512)
 RANK = 4
 KW = dict(max_tokens=8, greedy=True, chat=False)
@@ -725,7 +720,6 @@ def test_crash_with_adapters_resident_recovers_bit_identical(setup):
 
 # -- pp twin ------------------------------------------------------------------
 
-@needs_shard_map
 def test_pp_fleet_serves_adapters_identically(setup):
     """The pipeline backend's shard_map twin: the same adapter request on
     a pp=2 mesh emits the single-device fleet's exact greedy stream (the

@@ -30,12 +30,6 @@ PKG_ROOT = os.path.join(
     "distributed_llm_inference_tpu",
 )
 
-needs_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="this jax build has no jax.shard_map (pp backends unavailable)",
-)
-
-
 def make_pkg(tmp_path, files: dict) -> str:
     """Write a throwaway package tree and return its root."""
     root = tmp_path / "fixture_pkg"
@@ -1335,7 +1329,6 @@ def test_run_hlo_checks_all_green():
     assert not bad, bad
 
 
-@needs_shard_map
 def test_pp_decode_artifact(eight_devices):
     if not hlo.pp_available():
         pytest.skip("pp HLO check needs >= 2 devices")

@@ -4,6 +4,7 @@ import json
 import os
 import io
 import contextlib
+import subprocess
 import sys
 
 import pytest
@@ -27,41 +28,33 @@ def test_harness_runs_each_config_shape(capsys):
         assert rec["platform"] == "cpu"
 
 
-def test_bench_sidecar_roundtrip(tmp_path, monkeypatch):
-    """bench.py's sidecar is the crash-recovery channel: a result written
-    after each completed leg must read back exactly, atomically replacing
-    the previous state, and a missing/corrupt file must read as None."""
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    import bench
-
-    side = tmp_path / "side.json"
-    monkeypatch.setenv("_BENCH_SIDECAR", str(side))
-    r1 = {"metric": "m", "value": 1.0}
-    bench._write_sidecar(r1)
-    assert bench._read_sidecar(str(side)) == r1
-    r2 = dict(r1, value=2.0, extra_leg=3)
-    bench._write_sidecar(r2)
-    assert bench._read_sidecar(str(side)) == r2
-    assert bench._read_sidecar(str(tmp_path / "absent.json")) is None
-    side.write_text("{corrupt")
-    assert bench._read_sidecar(str(side)) is None
-    # unset env: write is a silent no-op (never fatal mid-bench)
-    monkeypatch.delenv("_BENCH_SIDECAR")
-    bench._write_sidecar(r2)
-
-
-def test_bench_child_json_takes_last_line():
-    """The consumer contract: the LAST parseable JSON line wins, so the
-    early solo-greedy emit is superseded by the enriched final line when
-    the child survives, and stands when it does not."""
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    import bench
-
-    out = (
-        'WARNING: noise\n'
-        '{"metric": "m", "value": 1.0}\n'
-        'more noise {not json}\n'
-        '{"metric": "m", "value": 2.0, "int8_tokens_per_sec": 5}\n'
+def _run_off_chip(script, *args):
+    """Run a root-level script with JAX held to the CPU, as this sandbox
+    and CI hold it; returns (returncode, stdout lines)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, script), *args],
+        cwd=root, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=300,
     )
-    assert bench._parse_child_json(out)["value"] == 2.0
-    assert bench._parse_child_json("no json at all") is None
+    return proc.returncode, [l for l in proc.stdout.splitlines() if l.strip()]
+
+
+def test_chip_smoke_cannot_pass_off_the_chip():
+    """The guard that the smoke is a CHIP check: with no TPU its server
+    child (started with JAX_PLATFORMS=tpu whatever the parent's setting)
+    fails to start, the script exits non-zero, and no `ok: true` line is
+    printed."""
+    rc, lines = _run_off_chip("chip_smoke.py", "--model", "test-llama-tiny")
+    assert rc != 0
+    assert lines, "the smoke prints what it is about to start"
+    assert '"ok": true' not in lines[-1]
+    assert not any(l.lstrip().startswith('{"ok"') for l in lines)
+
+
+def test_bench_needs_a_tpu():
+    """bench.py is one process that measures a TPU: off the chip it exits
+    non-zero before building a model, and emits no result line."""
+    rc, lines = _run_off_chip("bench.py")
+    assert rc != 0
+    assert not any(l.lstrip().startswith("{") for l in lines)

@@ -492,13 +492,6 @@ def test_hit_depth_degrades_to_fit_buckets(base_engine):
     assert st["prefix_cache"]["dedup_saved_tokens"] == 4 * BS
 
 
-needs_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="this jax build has no jax.shard_map (pp backends unavailable)",
-)
-
-
-@needs_shard_map
 @pytest.mark.slow
 def test_pp_block_sharing_matches_dense(eight_devices):
     """Block sharing on the pp=2 mesh: the layer-local fill gather + the

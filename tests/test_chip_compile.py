@@ -1,0 +1,226 @@
+"""Compile the main path's kernels and whole step programs for a TPU v5e
+that is DESCRIBED, not attached (on-chip-measurement guide, section 2).
+
+Interpret mode hides what the chip's compiler refuses — every int8-KV kernel
+variant passed the interpret-mode suite and was refused by the TPU lowering
+(block-shape tiling of the scale operands) until PR 21. These compiles cost
+no chip time and run in the test's own process; nothing executes, so they say
+nothing about results or speed.
+
+Rules this file keeps (the driver runs the suite under several xdist workers,
+and only one process at a time may load the TPU library): the topology is
+described inside a module-scoped, non-autouse fixture that skips when it
+cannot be described — never at import, never in a skipif or parametrize
+argument, never in conftest.py — and ALL such tests live in this one file.
+Kernels take interpret=False explicitly; the whole-model steps steer
+`resolve_interpret` (which would see the CPU backend and lower the
+interpreter) with DLI_PALLAS_INTERPRET=0 in the test, not through a new
+option of the program.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distributed_llm_inference_tpu.config import resolve_attn_impl
+from distributed_llm_inference_tpu.engine import generate as G
+from distributed_llm_inference_tpu.engine import paged as EP
+from distributed_llm_inference_tpu.models import api as M
+from distributed_llm_inference_tpu.models.registry import get_model_config
+from distributed_llm_inference_tpu.ops import quant as Q
+from distributed_llm_inference_tpu.ops.flash_attention import flash_attend
+from distributed_llm_inference_tpu.ops.kv_quant import KVQuant
+from distributed_llm_inference_tpu.ops.paged_attention import (
+    flash_attend_slots,
+    paged_flash_attend,
+    ragged_paged_attend,
+)
+
+# TinyLlama-1.1B widths: 32 query heads over 4 kv heads, head_dim 64
+H, KV, DH = 32, 4, 64
+POOL_BLOCKS = 3072  # chip_smoke.py's pool: >= 1 GiB of bf16 KV at bs 16
+SLOTS = 8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A described-chip executable is written to the persistent cache but
+    cannot be read back without a chip: keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _spec(sharding):
+    def make(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return make
+
+
+def _placed(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled.as_text()
+
+
+def _kv(S, shape, quant):
+    """A cache/pool operand [..., tokens, DH]: bf16, or the int8 KVQuant
+    leaf pair (int8 data + fp32 per-(token, head) scales)."""
+    if quant:
+        return KVQuant(S(shape, jnp.int8), S(shape[:-1], jnp.float32))
+    return S(shape, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("T,seq", [(128, 512), (512, 2048)])
+def test_flash_attend_compiles(one_chip, no_persistent_cache, T, seq, quant):
+    S = _spec(one_chip)
+    kv = _kv(S, (1, KV, seq, DH), quant)
+    text = _compile(
+        functools.partial(flash_attend, interpret=False),
+        S((1, T, H, DH), jnp.bfloat16), kv, kv, S((), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("bs", [16, 32, 128])
+def test_paged_flash_attend_compiles(one_chip, no_persistent_cache, bs, quant):
+    S = _spec(one_chip)
+    pool = _kv(S, (POOL_BLOCKS, KV, bs, DH), quant)
+    text = _compile(
+        functools.partial(paged_flash_attend, interpret=False),
+        S((SLOTS, 1, H, DH), jnp.bfloat16), pool, pool,
+        S((SLOTS, 2048 // bs), jnp.int32), S((SLOTS,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("bs,tq", [(16, 8), (16, 16), (32, 32), (128, 128)])
+def test_ragged_paged_attend_compiles(one_chip, no_persistent_cache, bs, tq,
+                                      quant):
+    """(16, 8) is the serving shape (continuous.py's 8-row query tile over
+    chip_smoke.py's 16-token blocks); the rest are bs == tq."""
+    S = _spec(one_chip)
+    pool = _kv(S, (POOL_BLOCKS, KV, bs, DH), quant)
+    tiles = 16
+    text = _compile(
+        functools.partial(ragged_paged_attend, interpret=False),
+        S((tiles * tq, H, DH), jnp.bfloat16), pool, pool,
+        S((SLOTS, 2048 // bs), jnp.int32), S((tiles, 4), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_flash_attend_slots_compiles(one_chip, no_persistent_cache):
+    S = _spec(one_chip)
+    cache = S((SLOTS, KV, 2048, DH), jnp.bfloat16)
+    text = _compile(
+        functools.partial(flash_attend_slots, interpret=False),
+        S((SLOTS, 1, H, DH), jnp.bfloat16), cache, cache,
+        S((SLOTS,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_q4_matmul_rows_compiles(one_chip, no_persistent_cache):
+    """The int4 weight kernel at TinyLlama's gate/up projection shape."""
+    w = _placed(
+        jax.eval_shape(
+            lambda: Q.quantize_tensor4(jnp.zeros((2048, 5632), jnp.float32))
+        ),
+        one_chip,
+    )
+    text = _compile(
+        functools.partial(Q.q4_matmul_rows, interpret=False),
+        _spec(one_chip)((SLOTS, 2048), jnp.float32), w,
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def tinyllama(one_chip):
+    """(cfg, params) of the whole published tinyllama-1.1b in bf16 with the
+    Pallas attention path selected, as shapes placed on the described chip."""
+    cfg = resolve_attn_impl(
+        get_model_config("tinyllama-1.1b").replace(dtype="bfloat16"), "pallas"
+    )
+    params = jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
+    return cfg, _placed(params, one_chip)
+
+
+def test_tinyllama_prefill_step_compiles_with_kernel(
+    one_chip, no_persistent_cache, tinyllama, monkeypatch
+):
+    """One whole prefill (T 128) of the engine's own program: without the
+    env steer the compile contains no kernel at all — resolve_interpret
+    sees the CPU backend and lowers the interpreter."""
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    cfg, params = tinyllama
+    S = _spec(one_chip)
+    place = functools.partial(_placed, sharding=one_chip)
+    cache = place(jax.eval_shape(
+        lambda: M.init_kv_cache(cfg, 1, max_seq=cfg.max_seq_len)
+    ))
+    i32 = S((), jnp.int32)
+    compiled = G.prefill.lower(
+        cfg, params, S((1, 128), jnp.int32), i32, cache,
+        place(jax.eval_shape(lambda: jax.random.PRNGKey(0))),
+        place(jax.eval_shape(lambda: G.default_sampling(greedy=True))),
+        None, i32, None, None,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_tinyllama_paged_decode_step_compiles_with_kernel(
+    one_chip, no_persistent_cache, tinyllama, monkeypatch
+):
+    """One whole decode step (T 1 per slot) over the block pool — the fleet's
+    decode program, whose attention is the paged kernel walking the table."""
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    cfg, params = tinyllama
+    S = _spec(one_chip)
+    place = functools.partial(_placed, sharding=one_chip)
+    state, sparams = place(
+        jax.eval_shape(lambda: G.init_slots(SLOTS, cfg.vocab_size))
+    )
+    pool = place(jax.eval_shape(lambda: EP.init_pool(cfg, POOL_BLOCKS, 16)))
+    compiled = EP.decode_slots_paged.lower(
+        cfg, params, state, pool, S((SLOTS, 2048 // 16), jnp.int32),
+        place(jax.eval_shape(lambda: jax.random.PRNGKey(0))), sparams,
+        num_steps=1,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()

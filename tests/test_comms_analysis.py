@@ -28,12 +28,6 @@ PKG_ROOT = os.path.join(
     "distributed_llm_inference_tpu",
 )
 
-needs_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="this jax build has no jax.shard_map (pp backends unavailable)",
-)
-
-
 def make_pkg(tmp_path, files: dict) -> str:
     root = tmp_path / "fixture_pkg"
     for rel, body in files.items():
@@ -458,7 +452,6 @@ def test_repo_lint_clean_all_comms_rules():
 
 # -- derived bytes vs measured counters on a real pp mesh --------------------
 
-@needs_shard_map
 @pytest.mark.parametrize("wq", [None, "int8"])
 def test_derived_bytes_match_measured_counters(wq):
     import jax.numpy as jnp
@@ -538,7 +531,6 @@ def test_collective_operand_parser():
     assert hlo._collective_operands(attr_only, "tensor") == []
 
 
-@needs_shard_map
 def test_hlo_comms_graph_round_trip():
     if len(jax.devices()) < 2:
         pytest.skip("needs >= 2 devices for a pp mesh")
@@ -550,7 +542,6 @@ def test_hlo_comms_graph_round_trip():
     assert hlo.check_gather_dtype(wired) == []
 
 
-@needs_shard_map
 def test_hlo_sp_attend_round_trip():
     if len(jax.devices()) < 2:
         pytest.skip("needs >= 2 devices for an sp mesh")

@@ -25,11 +25,6 @@ from distributed_llm_inference_tpu.models import api as M
 
 pytestmark = pytest.mark.slow
 
-needs_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="this jax build has no jax.shard_map (pp backends unavailable)",
-)
-
 SCHEMA = {
     "type": "object",
     "properties": {"name": {"type": "string"}, "age": {"type": "integer"}},
@@ -48,7 +43,6 @@ def pair():
     return sd, pp
 
 
-@needs_shard_map
 def test_pp_greedy_bit_exact(pair):
     """Acceptance: bit-exact greedy equivalence single-device vs the pp
     ring on the 8-virtual-device CPU mesh, for every constraint kind."""
@@ -66,7 +60,6 @@ def test_pp_greedy_bit_exact(pair):
         assert a["response"] == b["response"], spec
 
 
-@needs_shard_map
 def test_pp_sampled_satisfies_constraint(pair):
     _, pp = pair
     pat = r"[0-9]{2,4}"
@@ -77,7 +70,6 @@ def test_pp_sampled_satisfies_constraint(pair):
         assert re.fullmatch(pat, r["response"]), r["response"]
 
 
-@needs_shard_map
 def test_pp_schema_parses(pair):
     _, pp = pair
     r = pp.generate("json:", max_tokens=120, greedy=True, chat=False,
@@ -86,7 +78,6 @@ def test_pp_schema_parses(pair):
     assert isinstance(obj["name"], str) and isinstance(obj["age"], int)
 
 
-@needs_shard_map
 def test_1f1b_routes_constraint_to_plain_ring(pair):
     sd, _ = pair
     cfg = get_model_config("test-llama-tiny")

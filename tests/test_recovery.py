@@ -505,13 +505,6 @@ def test_wedge_unready_zero_disables():
 
 # -- pp warm-recovery seam (the shard_map shadow twins) -----------------------
 
-needs_shard_map = pytest.mark.skipif(
-    not hasattr(__import__("jax"), "shard_map"),
-    reason="this jax build has no jax.shard_map (pp backends unavailable)",
-)
-
-
-@needs_shard_map
 @pytest.mark.slow
 def test_pp_shadow_gather_restore_roundtrip(eight_devices):
     """The pipeline backend's layer-local shadow twins: restoring known
@@ -549,7 +542,6 @@ def test_pp_shadow_gather_restore_roundtrip(eight_devices):
         )
 
 
-@needs_shard_map
 @pytest.mark.slow
 def test_pp_fleet_recovers_warm(eight_devices):
     """End to end on the pp=2 mesh: the continuous fleet's shadow is

@@ -875,9 +875,6 @@ def test_draft_model_fleet_accepts_everything_with_identical_draft(setup):
 
 # -- pp shard_map twin --------------------------------------------------------
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"), reason="jax.shard_map unavailable"
-)
 def test_pp_spec_mixed_step_token_identical(setup, eight_devices):
     """The pipeline's spec-mixed program produces the identical packed
     fetch / slot state as the single-device program on the same

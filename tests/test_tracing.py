@@ -459,9 +459,10 @@ def _post(base, payload, headers=None, timeout=180):
 @pytest.mark.chaos
 def test_fleet_round_trip_single_tree(fleet):
     """THE acceptance leg: one client-rooted request through router ->
-    prefill handoff -> fabric pull -> decode yields ONE assembled trace
-    tree covering every hop, span total ≈ end-to-end wall, and both
-    export formats agree."""
+    prefill handoff -> fabric PUSH (the prefill replica posts the finished
+    chain to the decode peer, which promotes it — no pull round trip since
+    PR 19) -> decode yields ONE assembled trace tree covering every hop,
+    span total ≈ end-to-end wall, and both export formats agree."""
     router, _, base, pre, dec = fleet
     ctx = SpanContext.new_root()
     code, body, hdrs = _post(
@@ -473,7 +474,10 @@ def test_fleet_round_trip_single_tree(fleet):
     assert code == 200 and body["status"] == "success", body
     assert hdrs.get("X-Trace-Id") == ctx.trace_id
     assert body["replica"] == "d0"          # token loop on the decode tier
-    assert body.get("kv_fabric_blocks", 0) > 0
+    # the pushed chain landed before the decode admission: its blocks were
+    # promoted from the shadow tier and the prompt served as a prefix hit
+    assert body.get("kv_promoted_blocks", 0) > 0
+    assert body.get("prefix_cached_tokens", 0) > 0
 
     code, tr, _ = _get(base, f"/debug/traces/{ctx.trace_id}")
     assert code == 200
@@ -483,9 +487,8 @@ def test_fleet_round_trip_single_tree(fleet):
     assert ("router", "router.dispatch") in names
     assert ("router", "router.handoff_prefill") in names
     assert ("replica-prefill", "replica.request") in names
-    assert ("replica-prefill", "kv.serve") in names
+    assert ("replica-prefill", "fabric.push") in names
     assert ("replica-decode", "replica.request") in names
-    assert ("replica-decode", "fabric.pull") in names
     assert any(s == "replica-decode" and n.startswith("launch.")
                for s, n in names)
     assert any(n.startswith("stage.") for _, n in names)
@@ -540,7 +543,9 @@ def test_fleet_flight_and_kv_headers(fleet):
     assert code == 200
     assert set(fl["replicas"]) == {"p0", "d0"}
     kinds = [e["kind"] for e in fl["replicas"]["d0"].get("events", [])]
-    assert "admit" in kinds and "fabric_fetch" in kinds
+    # the hand-off PUSHES (PR 19): the decode ring records the inbound
+    # chain, not a fetch
+    assert "admit" in kinds and "fabric_push_in" in kinds
     # fabric response header echo (miss path: echo must not depend on a hit)
     req = urllib.request.Request(
         pre.url + "/kv/" + "ab" * 8,

@@ -34,11 +34,6 @@ from distributed_llm_inference_tpu.models import api as M
 from distributed_llm_inference_tpu.ops import kv_quant as KQ
 from distributed_llm_inference_tpu.ops import wire_quant as WQ
 
-needs_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="this jax build has no jax.shard_map (pp backends unavailable)",
-)
-
 # Greedy token-match-rate gate for the int8 wire on the tiny proxy
 # config (4 layers, dim 64, RANDOM weights — near-flat logits, far
 # harsher than any real checkpoint): teacher-forced per-decision
@@ -288,7 +283,6 @@ def _greedy_seq(backend, prompt, n):
     return [int(first[0])] + [int(t) for t in np.asarray(out[0])[: n - 1]]
 
 
-@needs_shard_map
 def test_pp_wire_off_bit_identical(tiny, eight_devices):
     """pp_wire_quant=None is bit-identical to today's outputs (and both
     are bit-identical to the single device — the pre-existing pp
@@ -304,7 +298,6 @@ def test_pp_wire_off_bit_identical(tiny, eight_devices):
     assert base == solo
 
 
-@needs_shard_map
 def test_pp_wire_on_matches_proxy_numerics(tiny, eight_devices):
     """The numerics-twin contract: the pp=2 mesh with the int8 wire on
     emits EXACTLY the proxy's quantized sequence — every hand-off is one
@@ -320,7 +313,6 @@ def test_pp_wire_on_matches_proxy_numerics(tiny, eight_devices):
         assert mesh_seq == proxy_seq, (seed, mesh_seq, proxy_seq)
 
 
-@needs_shard_map
 def test_pp_wire_on_match_rate_gate(tiny, eight_devices):
     """Per-decision gate on the real mesh: the FIRST sampled token of
     each prefill is one independent decision (no cascade) — agreement
@@ -348,7 +340,6 @@ def test_pp_wire_on_match_rate_gate(tiny, eight_devices):
     assert hits / total >= WIRE_MATCH_MIN, (hits, total)
 
 
-@needs_shard_map
 @pytest.mark.slow
 def test_1f1b_wire_off_and_on(tiny, eight_devices):
     """1F1B fleet decode: wire off is bit-identical to the default
@@ -395,7 +386,6 @@ def test_1f1b_wire_off_and_on(tiny, eight_devices):
         assert on[r] == proxy_seq, (r, on[r], proxy_seq)
 
 
-@needs_shard_map
 def test_sp_wire_off_bit_identical_and_on_equals_kv_quant_prefill(
     tiny, eight_devices
 ):
@@ -450,7 +440,6 @@ def test_sp_wire_off_bit_identical_and_on_equals_kv_quant_prefill(
     assert float(np.max(np.abs(logits_full - logits_kvq))) < 0.5
 
 
-@needs_shard_map
 @pytest.mark.slow
 def test_sp_pp_composition_wire(tiny, eight_devices):
     """sp x pp: off is bit-identical to the default composed backend;
@@ -486,7 +475,6 @@ def test_sp_pp_composition_wire(tiny, eight_devices):
     assert all(0 <= t < cfg.vocab_size for t in on)
 
 
-@needs_shard_map
 @pytest.mark.slow
 def test_pp_wire_chaos_crash_recovers_within_envelope(tiny, eight_devices):
     """The chaos leg: a mid-decode crash on a pp=2 paged fleet WITH the
@@ -529,7 +517,6 @@ def test_pp_wire_chaos_crash_recovers_within_envelope(tiny, eight_devices):
         cont.close()
 
 
-@needs_shard_map
 def test_pp_wire_bytes_counter_accounts(tiny, eight_devices):
     """dli_pp_wire_bytes_total: attached through the engine seam, the
     backend counts static per-launch bytes on the microstep +
