@@ -115,9 +115,11 @@ import numpy as np
 
 from ..utils import faults
 from ..utils.logging import get_logger
-from ..utils.metrics import DEFAULT_SIZE_BUCKETS
+from ..utils.metrics import (
+    ADMISSION_WAIT_HELP, DEFAULT_SIZE_BUCKETS, STEPS_AHEAD_BUCKETS,
+)
 from ..utils.retry import overload_retry_after
-from ..utils.tracing import Trace, sample_decision
+from ..utils.tracing import PhaseClock, Trace, sample_decision
 from . import generate as G
 from .block_prefix import chunk_digests
 
@@ -141,6 +143,7 @@ class _Request:
         "promoted_blocks",
         "spec_want", "spec_drafted", "spec_accepted", "spec_launches",
         "adapter", "tenant", "adapter_page", "trace_ctx", "profiled",
+        "queue_wait_s",
     )
 
     def __init__(self, prompt: str, kwargs: dict, stream_q=None,
@@ -171,6 +174,10 @@ class _Request:
         # gets launch-level attribution spans.
         self.trace_ctx = trace_ctx
         self.profiled = False
+        # `queue_wait` stage spans since the last grant, summed over the
+        # re-waits of a blocked head; observed into
+        # dli_queue_wait_seconds at admission and reset there
+        self.queue_wait_s = 0.0
         self.done = threading.Event()
         self.result: Optional[dict] = None
         self.enqueued = time.time()
@@ -473,6 +480,11 @@ class ContinuousEngine:
         self._jobs: list = []
         self._prefilling: dict = {}
         self._host_pos = np.zeros((self.n_slots,), np.int64)
+        # the position at which each slot's budget runs out (armed
+        # position + req.budget): decode rows the host still launches
+        # past it, until the fetch tells it the row has ended, are dead
+        # on the device and read no KV (the launch record's kv_tokens)
+        self._host_end = np.zeros((self.n_slots,), np.int64)
         self._idle_arm = None
         if self._chunked:
             from . import paged as _P_arm
@@ -737,6 +749,22 @@ class ContinuousEngine:
         self._trace_rate = float(engine.engine_cfg.trace_sample_rate)
         self._prof_active = 0  # profiled requests seen in the last launch
         self._launch_log: collections.deque = collections.deque()
+        # The launch record (ISSUE 24): ONE dict per launch, built at the
+        # dispatch seam from what the loop already holds (_launch_record)
+        # and closed at the matching fetch. It feeds the counters, the
+        # `launch.<phase>` profiler annotation, the flight `plan` event
+        # and the sampled per-tenant span above — always on, no device
+        # read. _launch_seq numbers launches; _steps_inflight is the
+        # scheduler steps dispatched and not yet fetched.
+        self._launch_seq = 0
+        self._steps_inflight = 0
+        # uniform sliding window only: a per-layer pattern has no one
+        # width, and is counted as full attention (overstates there)
+        self._kv_window = (
+            self.cfg.attn_window
+            if self.cfg.attn_window_pattern == "all"
+            and self.cfg.attn_window_layer_types is None else None
+        )
         # observability
         self.admitted = 0  # guarded-by: _cv
         self.completed = 0  # guarded-by: _cv
@@ -758,9 +786,26 @@ class ContinuousEngine:
             "dli_queue_depth", "requests waiting for dispatch", ("queue",)
         ).labels(queue="continuous")
         self._m_admission_wait = m.histogram(
-            "dli_admission_wait_seconds",
-            "enqueue-to-admission wait", ("queue",),
+            "dli_admission_wait_seconds", ADMISSION_WAIT_HELP, ("queue",),
         ).labels(queue="continuous")
+        # its two halves (ISSUE 24), observed together with it, once per
+        # admission: the request's accumulated `queue_wait` stage spans
+        # and its `admission` span (utils/tracing.Trace.checkpoint)
+        self._m_queue_wait = m.histogram(
+            "dli_queue_wait_seconds",
+            "enqueue until a slot and pool blocks were granted (re-waits "
+            "of a blocked head included)", ("queue",),
+        ).labels(queue="continuous")
+        self._m_prefill = m.histogram(
+            "dli_prefill_seconds",
+            "grant until the request's first token was fetched (chunked "
+            "prefill shares its steps with the decode rows)", ("queue",),
+        ).labels(queue="continuous")
+        self._m_blocked = m.counter(
+            "dli_admission_blocked_total",
+            "scheduler iterations that left the head of the queue "
+            "waiting, by what it waited for", ("reason",),
+        )
         self._m_step = m.histogram(
             "dli_decode_step_seconds",
             "per-token decode step time, chunk launch-to-fetch / "
@@ -845,7 +890,8 @@ class ContinuousEngine:
         )
         self._m_ragged_launches = m.counter(
             "dli_ragged_launches_total",
-            "ragged ingest launches", ("phase",),
+            "launches by program: ragged ingest (extend / prefill) and "
+            "scheduler steps (mixed / chunk)", ("phase",),
         )
         self._m_ragged_exact = m.counter(
             "dli_ragged_exact_prefix_hits_total",
@@ -871,8 +917,31 @@ class ContinuousEngine:
         ).labels()
         self._m_sched_rows = m.counter(
             "dli_sched_decode_rows_total",
-            "decode rows carried by mixed scheduler launches",
+            "decode rows carried by scheduler launches (a pure-decode "
+            "chunk counts its row-steps)",
         ).labels()
+        # launch-record families (ISSUE 24, pre-registered in
+        # engine/engine.py): KV positions attention had to read against
+        # those the kernels' grids walked, how much work was dispatched
+        # ahead of each launch, and where the worker thread's time went
+        self._m_kv_tokens = m.counter(
+            "dli_attn_kv_tokens_total",
+            "KV positions per layer and KV head: attended = the fewest "
+            "the launch's rows need (host position model, window-"
+            "clipped), walked = what the kernel's grid covers",
+            ("phase", "state"),
+        )
+        self._m_steps_ahead = m.histogram(
+            "dli_launch_steps_ahead",
+            "scheduler steps dispatched and unfetched when a launch was "
+            "dispatched", ("phase",), buckets=STEPS_AHEAD_BUCKETS,
+        )
+        self._clock = PhaseClock(m.counter(
+            "dli_worker_phase_seconds_total",
+            "wall time of the scheduler's worker thread by phase "
+            "(contiguous: the phases sum to the thread's life)",
+            ("phase",),
+        ))
         # fleet speculative-decoding families (pre-registered in
         # engine/engine.py): draft/accept/reject token flow, verify-row
         # launches by draft source, tokens-per-launch distribution
@@ -1578,6 +1647,7 @@ class ContinuousEngine:
         self._jobs = []
         self._prefilling = {}
         self._host_pos[:] = 0
+        self._host_end[:] = 0
         # speculation bookkeeping dies with the fleet too: unfetched
         # verify rows are unfetched launches (their emissions drop, the
         # salvage record holds fetched tokens only — same contract);
@@ -2631,17 +2701,63 @@ class ContinuousEngine:
         finally:
             self._restarting = False
 
+    # -- the launch record (ISSUE 24) -----------------------------------------
+    def _launch_record(self, phase: str, steps: int, kv_tokens: int,
+                       grid_rows: int, row_steps: int, **fields) -> dict:
+        """The ONE record of a launch, built at the dispatch seam before
+        the jitted call: host integers the loop already holds, no device
+        read. `kv_tokens` is the fewest KV positions (per layer and KV
+        head) attention must read for the launch's rows, from the host
+        position model; `kv_grid_tokens` what the kernel's grid covers
+        (`grid_rows` tiles or slots, each the whole block table wide,
+        every step); `steps_ahead` the scheduler steps dispatched and
+        not yet fetched; `row_steps` the decode row-steps it carries (a
+        chunk's rows run up to `steps` each). Counted here; the caller
+        hands it to the `launch.<phase>` annotation, the flight `plan`
+        event and the sampled per-tenant span, and the fetch closes it
+        by `seq`."""
+        self._launch_seq += 1
+        rec = {
+            "phase": phase, "seq": self._launch_seq, "steps": steps,
+            "decode_rows": 0, "prefill_chunks": 0, "prefill_tokens": 0,
+            "spec_drafted": 0, "steps_ahead": self._steps_inflight,
+            "kv_tokens": int(kv_tokens),
+            "kv_grid_tokens": grid_rows * self._scratch_seq * steps,
+        }
+        rec.update(fields)
+        self._steps_inflight += steps
+        self._m_ragged_launches.labels(phase=phase).inc()
+        self._m_sched_rows.inc(row_steps)
+        self._m_sched_tokens.labels(kind="decode").inc(row_steps)
+        self._m_kv_tokens.labels(phase=phase, state="attended").inc(
+            rec["kv_tokens"]
+        )
+        self._m_kv_tokens.labels(phase=phase, state="walked").inc(
+            rec["kv_grid_tokens"]
+        )
+        self._m_steps_ahead.labels(phase=phase).observe(rec["steps_ahead"])
+        return rec
+
+    def _kv_span(self, start, length=1):
+        """KV positions a row must read whose last query sits at
+        `start + length - 1`: everything up to and including it, clipped
+        to a uniform sliding window (numpy-broadcasting)."""
+        n = start + length
+        return n if self._kv_window is None else np.minimum(
+            n, self._kv_window
+        )
+
     # -- launch-level device-time attribution (ISSUE 17) ---------------------
-    def _prof_note_launch(self, kind: str, t_launch: float, snapshot,
-                          **attrs):
-        """Open one launch-attribution record (worker thread, called at
-        the dispatch boundary ONLY behind the `self._trace_rate > 0`
-        guard — at the default rate 0 this method is unreachable from
-        the hot path and nothing here ever allocates). The record closes
-        at the matching packed fetch (_prof_close_launch), keyed by the
-        launch's own perf_counter timestamp: fetches drain the inflight
-        deque FIFO in launch order, so lag-pipelined launches attribute
-        correctly without any extra device sync."""
+    def _prof_note_launch(self, t_launch: float, snapshot, rec: dict):
+        """Open one per-tenant attribution record for a launch (worker
+        thread, called at the dispatch boundary ONLY behind the
+        `self._trace_rate > 0` guard — at the default rate 0 this method
+        is unreachable from the hot path). Its attrs are the launch
+        record's. It closes at the matching packed fetch
+        (_prof_close_launch), keyed by the launch's own perf_counter
+        timestamp: fetches drain the inflight deque FIFO in launch
+        order, so lag-pipelined launches attribute correctly without
+        any extra device sync."""
         targets = [
             (r.trace_ctx.trace_id, r.trace_ctx.span_id)
             for r in snapshot
@@ -2653,9 +2769,9 @@ class ContinuousEngine:
         self._launch_log.append({
             "t_launch": t_launch,
             "wall": time.time(),
-            "kind": kind,
+            "kind": rec["phase"],
             "targets": targets,
-            "attrs": attrs,
+            "attrs": rec,
         })
 
     def _prof_close_launch(self, t_launch: float, **attrs):
@@ -2686,13 +2802,14 @@ class ContinuousEngine:
         constrained / plain slot program — state, cache, and fsm chain
         device-side between launches, so no fetch is needed to launch the
         next chunk). Returns the inflight tuple (packed results dev
-        array, assignment snapshot, launch time, mutation seq) or None
-        when no slot is active."""
+        array, assignment snapshot, launch time, mutation seq, launch
+        record) or None when no slot is active."""
         if not any(r is not None for r in self._assignment):
             return None
         faults.check("decode_launch", tag=",".join(
             r.prompt for r in self._assignment if r is not None
         ))
+        pages = None
         if self.paged:
             if self._table_dev is None:
                 self._table_dev = self._snapshot(self._table)
@@ -2700,10 +2817,30 @@ class ContinuousEngine:
             # launch (pages=None when no pool is attached — a DISTINCT
             # compiled program that lowers byte-identically to the
             # pre-adapter build)
-            pages = (
-                self._snapshot(self._slot_pages)
-                if self._adapters is not None else None
-            )
+            if self._adapters is not None:
+                pages = self._snapshot(self._slot_pages)
+        snapshot = list(self._assignment)
+        K = self.chunk_steps
+        # host position model: row b runs min(K, steps left of its
+        # budget) steps from _host_pos[b], each reading its clipped
+        # length so far
+        rows = np.array([r is not None for r in snapshot])
+        live = np.clip(self._host_end - self._host_pos, 0, K) * rows
+        step = np.arange(K)
+        rec = self._launch_record(
+            "chunk", K,
+            kv_tokens=np.sum(
+                self._kv_span(self._host_pos[:, None] + step)
+                * (step < live[:, None])
+            ),
+            grid_rows=self.n_slots, row_steps=int(live.sum()),
+            decode_rows=int(np.count_nonzero(live)),
+        )
+        # every believed-active slot advances K (over-advance on rows
+        # that die mid-chunk is masked garbage, the frozen-row rule)
+        self._host_pos[rows] += K
+        self._clock.mark("dispatch", "launch.chunk", **rec)
+        if self.paged:
             emitted, mask, self.state, self.cache = (
                 self.backend.decode_slots_paged(
                     self.state, self.cache, self._table_dev,
@@ -2732,14 +2869,10 @@ class ContinuousEngine:
                 )
             )
         packed = G.pack_chunk(emitted, mask, self.state.active)
-        snapshot = list(self._assignment)
-        t_launch = time.perf_counter()
+        t_launch = self._clock.mark("plan")
         if self._trace_rate > 0.0:
-            self._prof_note_launch(
-                "chunk", t_launch, snapshot, steps=self.chunk_steps,
-                rows=sum(1 for r in snapshot if r is not None),
-            )
-        return (packed, snapshot, t_launch, self._mutation_seq)
+            self._prof_note_launch(t_launch, snapshot, rec)
+        return (packed, snapshot, t_launch, self._mutation_seq, rec)
 
     def _loop_inner(self):
         # In-flight decode chunks, oldest first. Launch up to chunk_lag
@@ -2751,6 +2884,8 @@ class ContinuousEngine:
         # a restart abandoned any in-flight launches — their attribution
         # records can never be closed (the fetches died with the crash)
         self._launch_log.clear()
+        self._steps_inflight = 0
+        self._clock.mark("admit")  # restore + recovery are re-admission
         # warm restore FIRST (supervisor restart or --restore-dir start):
         # the rebuilt pool takes the shadowed blocks back in one scatter
         # and the block-prefix index re-learns the chains, so the
@@ -2771,6 +2906,7 @@ class ContinuousEngine:
             self._sched_loop(inflight)
             return
         while True:
+            self._clock.mark("wait_work")
             with self._cv:
                 while (
                     not self._queue
@@ -2781,10 +2917,13 @@ class ContinuousEngine:
                 ):
                     self._cv.wait()
                 if self._closed:
+                    self._clock.mark(None)
                     return
                 queue_head = bool(self._queue or self._resume)
+            self._clock.mark("admit")
             if queue_head:
                 self._admit()
+            self._clock.mark("plan")
             chunk = self._launch_chunk()
             launched = chunk is not None
             if launched:
@@ -2814,6 +2953,7 @@ class ContinuousEngine:
         model and gather decode tokens from slot state ON DEVICE, so no
         fetch is ever needed to launch the next step."""
         while True:
+            self._clock.mark("wait_work")
             with self._cv:
                 while (
                     not self._queue
@@ -2824,9 +2964,13 @@ class ContinuousEngine:
                 ):
                     self._cv.wait()
                 if self._closed:
+                    self._clock.mark(None)
                     return
+            self._clock.mark("reap")
             self._reap_jobs()
+            self._clock.mark("admit")
             self._start_jobs()
+            self._clock.mark("plan")
             spec_rows = self._plan_spec()
             if (
                 self._jobs or spec_rows or self._spec_inflight
@@ -2844,15 +2988,10 @@ class ContinuousEngine:
             else:
                 step = self._launch_chunk()
                 if step is not None:
-                    # host position model: every believed-active slot
-                    # advanced chunk_steps (over-advance on rows that die
-                    # mid-chunk is masked garbage, the frozen-row rule).
-                    # Drafting pauses until this launch's many-token
-                    # emissions are fetched (_chunk_unfetched).
+                    # drafting pauses until this launch's many-token
+                    # emissions are fetched (_chunk_unfetched); the host
+                    # position model advanced inside _launch_chunk
                     self._chunk_unfetched += 1
-                    for b, r in enumerate(self._assignment):
-                        if r is not None:
-                            self._host_pos[b] += self.chunk_steps
             launched = step is not None
             if launched:
                 inflight.append(step)
@@ -2935,6 +3074,7 @@ class ContinuousEngine:
                     b for b, r in enumerate(self._assignment) if r is None
                 ]
                 if not free:
+                    self._m_blocked.labels(reason="slot").inc()
                     return
                 if not from_resume:
                     head = self._queue[0]
@@ -2953,6 +3093,7 @@ class ContinuousEngine:
                         # attempt (the pressure ladder), so a head whose
                         # shortfall a victim could cover is sized with
                         # need=None on its first attempt and reaches it.
+                        self._m_blocked.labels(reason="blocks").inc()
                         return
                     req = self._queue.pop(0)
                     self._note_queue_locked()
@@ -2983,12 +3124,7 @@ class ContinuousEngine:
                     first_dev = self._admit_one(req, free[0])
                     self._admitting = None
                     if first_dev is _BLOCKED:
-                        with self._cv:
-                            if from_resume:
-                                self._resume.insert(0, req)
-                            else:
-                                self._queue.insert(0, req)
-                                self._note_queue_locked()
+                        self._requeue_blocked(req, from_resume)
                         return
                     if first_dev is not None:
                         req.first_id = int(np.asarray(first_dev)[0])
@@ -3003,12 +3139,7 @@ class ContinuousEngine:
                 started = self._start_job(req, free[0])
                 self._admitting = None
                 if started is _BLOCKED:
-                    with self._cv:
-                        if from_resume:
-                            self._resume.insert(0, req)
-                        else:
-                            self._queue.insert(0, req)
-                            self._note_queue_locked()
+                    self._requeue_blocked(req, from_resume)
                     return
                 if (
                     started is not None and from_resume
@@ -3032,6 +3163,18 @@ class ContinuousEngine:
             # any other exception escapes to the supervisor (crash
             # containment + suspect implication), exactly like _admit
 
+    def _requeue_blocked(self, req: _Request, from_resume: bool):
+        """An admission attempt came back _BLOCKED (the attempt counted
+        why): back to the FRONT of where it came from (FIFO fairness);
+        the fleet keeps decoding until a release frees what it waits
+        for."""
+        with self._cv:
+            if from_resume:
+                self._resume.insert(0, req)
+            else:
+                self._queue.insert(0, req)
+                self._note_queue_locked()
+
     def _start_job(self, req: _Request, slot: int):
         """Plan one chunked admission: tokenize, prefix-reuse lookup at
         EXACT chunk depth, clamp the budget, allocate + map pool blocks,
@@ -3040,7 +3183,7 @@ class ContinuousEngine:
         failed fast (result already set), or the job."""
         eng, cfg = self.engine, self.cfg
         faults.check("admission", tag=req.prompt)
-        req.trace.checkpoint("queue_wait")
+        req.queue_wait_s += req.trace.checkpoint("queue_wait")
         if req.cancelled:
             req.result = self._cancel_env(req)
             self._push_final(req)
@@ -3065,6 +3208,7 @@ class ContinuousEngine:
             # every adapter page is referenced by other in-flight
             # requests: backpressure exactly like pool-block exhaustion
             # (the caller requeues at the front; a release frees a page)
+            self._m_blocked.labels(reason="adapter").inc()
             return _BLOCKED
         k = req.kwargs
         text = (
@@ -3129,6 +3273,7 @@ class ContinuousEngine:
                 self._alloc.decref(shared)
             req.block_ids = None
             self._release_adapter(req)
+            self._m_blocked.labels(reason="blocks").inc()
             return _BLOCKED
         req.block_ids = shared + blk_ids
         table_row = np.zeros((self._max_blocks,), np.int32)
@@ -3335,7 +3480,7 @@ class ContinuousEngine:
         is exact even while earlier verify rows are unfetched. Returns
         the inflight tuple ("mixed", packed dev, decode snapshot,
         {slot: req} completions, launch time, mutation seq, spec
-        bookkeeping) or None when the fleet is empty."""
+        bookkeeping, launch record) or None when the fleet is empty."""
         P = self._P
         spec_rows = spec_rows or {}
         assigned = [
@@ -3520,6 +3665,26 @@ class ContinuousEngine:
             self._snapshot(self._slot_pages)
             if self._adapters is not None else None
         )
+        # the launch record: a decode / verify row whose budget ran out
+        # before this step (its fetch is still on the way) is dead on
+        # the device and reads nothing; a prefill chunk reads its prefix
+        # and itself once (a lower bound: the kernel reads per query
+        # tile)
+        live_tiles = stats["tiles"] - stats["pad_tiles"]
+        rec = self._launch_record(
+            "mixed", 1,
+            kv_tokens=sum(
+                int(self._kv_span(start, n))
+                for b, start, n, _ in entries[:n_dec]
+                if start < self._host_end[b]
+            ) + sum(int(self._kv_span(st, n)) for _, n, st in chunk_list),
+            grid_rows=stats["tiles"], row_steps=n_dec,
+            decode_rows=n_dec, prefill_chunks=len(chunk_list),
+            prefill_tokens=sum(n for _, n, _ in chunk_list),
+            spec_drafted=sum(nd for nd, _, _ in spec_rows.values()),
+            tiles=stats["tiles"], tiles_live=live_tiles,
+        )
+        self._clock.mark("dispatch", "launch.mixed", **rec)
         packed, self.state, self.sparams, self.cache = (
             self.backend.mixed_step_ragged(
                 jnp.asarray(toks), jnp.asarray(tok_row),
@@ -3531,6 +3696,7 @@ class ContinuousEngine:
                 dev=dev_dev, pages=pages_dev,
             )
         )
+        t_launch = self._clock.mark("plan")
         # host position model + completion bookkeeping AFTER the launch
         # is enqueued (the arming rode the program itself). Verify rows
         # do NOT advance here: their advance is data-dependent (the
@@ -3556,7 +3722,7 @@ class ContinuousEngine:
                 self._host_pos[b] += 1
         if spec_rows:
             mode = "draft_model" if self._draft_mode else "ngram"
-            drafted = sum(nd for nd, _, _ in spec_rows.values())
+            drafted = rec["spec_drafted"]
             self._m_spec_launches.labels(mode=mode).inc(len(spec_rows))
             self._m_spec_drafted.inc(drafted)
             self.spec_launches += len(spec_rows)
@@ -3571,6 +3737,7 @@ class ContinuousEngine:
             job = self._prefilling.pop(slot)
             self._jobs.remove(job)
             self._host_pos[slot] = job.prompt_len
+            self._host_end[slot] = job.prompt_len + req.budget
             if self._bpx is not None:
                 # full prompt blocks are complete + immutable once this
                 # launch lands; later gathers serialize behind it on
@@ -3588,22 +3755,19 @@ class ContinuousEngine:
             # launch above, so it reads their final content
             for job, _, _ in chunk_list:
                 self._shadow_capture(job.req, written=job.p0 + job.done)
-        # launch-composition observability
-        n_pf_tokens = sum(n for _, n, _ in chunk_list)
-        # flight recorder: the scheduler plan with its budget split —
-        # only steps that actually interleaved prefill work are recorded
-        # (pure-decode steps would flood the ring with no forensic value)
+        # flight recorder: the launch record with the scheduler's budget
+        # split — only steps that actually interleaved prefill work are
+        # recorded (pure-decode steps would flood the ring with no
+        # forensic value)
         if chunk_list or spec_rows:
             self.engine.flight.record(
-                "plan", seq=self._mutation_seq, decode_rows=n_dec,
-                prefill_chunks=len(chunk_list),
-                prefill_tokens=n_pf_tokens, spec_rows=len(spec_rows),
+                "plan", **rec, spec_rows=len(spec_rows),
                 budget=self._sched.last_plan,
             )
-        self._m_sched_rows.inc(n_dec)
-        self._m_sched_chunks.inc(len(chunk_list))
-        self._m_sched_tokens.labels(kind="decode").inc(n_dec)
-        self._m_sched_tokens.labels(kind="prefill").inc(n_pf_tokens)
+        self._m_sched_chunks.inc(rec["prefill_chunks"])
+        self._m_sched_tokens.labels(kind="prefill").inc(
+            rec["prefill_tokens"]
+        )
         if stats["prefill_rows"]:
             self._m_ragged_rows.labels(kind="prefill").inc(
                 stats["prefill_rows"]
@@ -3613,10 +3777,7 @@ class ContinuousEngine:
                 stats["decode_rows"]
             )
         self._m_ragged_tiles.labels(state="pad").inc(stats["pad_tiles"])
-        self._m_ragged_tiles.labels(state="live").inc(
-            stats["tiles"] - stats["pad_tiles"]
-        )
-        self._m_ragged_launches.labels(phase="mixed").inc()
+        self._m_ragged_tiles.labels(state="live").inc(live_tiles)
         # decode snapshot: only rows DECODING at launch (mid-prefill rows
         # emit nothing; the completing slot's first decode token arrives
         # with the NEXT launch; legacy-mode slots frozen behind an
@@ -3625,18 +3786,12 @@ class ContinuousEngine:
         snapshot = [
             self._assignment[b] if b in active else None for b in range(B)
         ]
-        t_launch = time.perf_counter()
         if self._trace_rate > 0.0:
-            self._prof_note_launch(
-                "mixed", t_launch, snapshot, seq=self._mutation_seq,
-                decode_rows=n_dec, prefill_chunks=len(chunk_list),
-                prefill_tokens=n_pf_tokens,
-                spec_drafted=sum(nd for nd, _, _ in spec_rows.values()),
-            )
+            self._prof_note_launch(t_launch, snapshot, rec)
         return (
             "mixed", packed, snapshot, completions, t_launch,
             self._mutation_seq,
-            spec_meta if spec_plan_dev is not None else None,
+            spec_meta if spec_plan_dev is not None else None, rec,
         )
 
     def _fresh_arm(self):
@@ -3662,14 +3817,14 @@ class ContinuousEngine:
         verify-row resync/accounting (position advance, accept counts),
         then the shared decode distribution (stop/cancel/deadline/
         finalize) over the combined emission matrix."""
-        _, packed_dev, snapshot, completions, t_launch, seq, spec_meta = step
+        (_, packed_dev, snapshot, completions, t_launch, seq, spec_meta,
+         rec) = step
         faults.check("fetch", tag=",".join(
             r.prompt for r in snapshot if r is not None
         ))
         # [5, B] plain / [5 + 2*(K+1) + 1, B] with a SpecPlan — still the
         # ONE fetch per step
-        packed = np.asarray(packed_dev)
-        self._m_step.observe(max(0.0, time.perf_counter() - t_launch))
+        packed = self._fetch(packed_dev, t_launch, rec)
         emitted, mask, active, firsts, armed = packed[:5]
         sp_emit = sp_mask = sp_adv = None
         if spec_meta is not None:
@@ -3687,7 +3842,7 @@ class ContinuousEngine:
             req.first_id = int(firsts[slot])
             if not req.ttft:
                 req.ttft = now - req.t_start
-            req.trace.checkpoint("admission")  # chunked prefill span
+            prefill_s = req.trace.checkpoint("admission")  # chunked prefill
             with self._cv:
                 self.admitted += 1
                 if req.record:
@@ -3695,8 +3850,7 @@ class ContinuousEngine:
                 occ = sum(r is not None for r in self._assignment)
                 self.peak_occupancy = max(self.peak_occupancy, occ)
             self._m_occupied.set(occ)
-            if req.record:
-                self._m_admission_wait.observe(now - req.enqueued)
+            self._observe_admission(req, now - req.enqueued, prefill_s)
             log.info(
                 "admitted", slot=slot, prompt_len=req.prompt_tokens,
                 budget=req.budget, occupancy=occ, chunked=True,
@@ -3781,6 +3935,7 @@ class ContinuousEngine:
                     break
                 free = [b for b, r in enumerate(self._assignment) if r is None]
                 if not free:
+                    self._m_blocked.labels(reason="slot").inc()
                     break
                 if (
                     not from_resume
@@ -3796,6 +3951,7 @@ class ContinuousEngine:
                     # pool still can't take it even by evicting every
                     # unreferenced cached chain — don't re-tokenize/replan
                     # on every chunk iteration; wait for a release
+                    self._m_blocked.labels(reason="blocks").inc()
                     break
                 if from_resume:
                     req = self._resume.pop(0)
@@ -3832,12 +3988,7 @@ class ContinuousEngine:
                     # paged pool exhausted: requeue at the FRONT (FIFO
                     # fairness) and stop admitting until a release frees
                     # blocks — the fleet keeps decoding meanwhile
-                    with self._cv:
-                        if from_resume:
-                            self._resume.insert(0, req)
-                        else:
-                            self._queue.insert(0, req)
-                            self._note_queue_locked()
+                    self._requeue_blocked(req, from_resume)
                     break
                 if first_dev is not None:  # None: failed fast (e.g. queued
                     if from_resume and req.preempted_at:
@@ -3908,7 +4059,7 @@ class ContinuousEngine:
         faults.check("admission", tag=req.prompt)
         # everything before this point (bounded queue + worker pickup) is
         # queueing delay; a _BLOCKED retry folds its re-wait in here too
-        req.trace.checkpoint("queue_wait")
+        req.queue_wait_s += req.trace.checkpoint("queue_wait")
         if req.cancelled:
             # a _BLOCKED requeue can carry a request whose client already
             # went away (stream teardown races the pop) — drop it here
@@ -3938,6 +4089,7 @@ class ContinuousEngine:
             # requests: backpressure, caller requeues at the front.
             # Acquired BEFORE any block incref so the unwind paths below
             # only release what they took on top of it.
+            self._m_blocked.labels(reason="adapter").inc()
             return _BLOCKED
         k = req.kwargs
         text = (
@@ -4027,6 +4179,7 @@ class ContinuousEngine:
                     self._alloc.decref(shared)
                 req.block_ids = None
                 self._release_adapter(req)
+                self._m_blocked.labels(reason="blocks").inc()
                 return _BLOCKED  # pool exhausted; caller requeues at front
             req.block_ids = shared + blk_ids
             table_row = np.zeros((self._max_blocks,), np.int32)
@@ -4055,6 +4208,7 @@ class ContinuousEngine:
                     self._alloc.decref(req.block_ids)
                     req.block_ids = None
                 self._release_adapter(req)
+                self._m_blocked.labels(reason="constraint").inc()
                 return _BLOCKED  # retry after a release frees rows
             req.cart = (cart, off)
         sampling = G.default_sampling(
@@ -4156,7 +4310,6 @@ class ContinuousEngine:
                 # chunked mode reaches here through RECOVERY's serialized
                 # whole-prefill re-admissions: seed the host position
                 # model so subsequent mixed launches plan this row exactly
-                self._host_pos[slot] = prompt_len
             elif self.paged:
                 self.cache, self.state, self.sparams = (
                     self.backend.insert_slot_paged(
@@ -4173,6 +4326,12 @@ class ContinuousEngine:
                 )
             if not use_ragged:
                 self._scratch = scratch
+            # seed the host position model (every fleet mode: the launch
+            # record reads it; chunked mode reaches here through
+            # RECOVERY's serialized whole-prefill re-admissions, and
+            # subsequent mixed launches plan this row from it)
+            self._host_pos[slot] = prompt_len
+            self._host_end[slot] = prompt_len + req.budget
         except BaseException:
             if req.block_ids is not None:
                 # admission died after the block grant (failed prefill,
@@ -4213,7 +4372,7 @@ class ContinuousEngine:
             # behind the prefill, the copy lands on the shadow thread
             self._shadow_capture(req, written=prompt_len)
         req.slot = slot
-        req.trace.checkpoint("admission")  # prefill + splice into the slot
+        prefill_s = req.trace.checkpoint("admission")  # prefill + splice
         with self._cv:
             self._assignment[slot] = req
             self.admitted += 1
@@ -4222,8 +4381,7 @@ class ContinuousEngine:
             occ = sum(r is not None for r in self._assignment)
             self.peak_occupancy = max(self.peak_occupancy, occ)
         self._m_occupied.set(occ)
-        if req.record:
-            self._m_admission_wait.observe(time.time() - req.enqueued)
+        self._observe_admission(req, time.time() - req.enqueued, prefill_s)
         log.info(
             "admitted", slot=slot, prompt_len=prompt_len,
             budget=req.budget, occupancy=occ,
@@ -4324,19 +4482,41 @@ class ContinuousEngine:
             self._m_ragged_programs.set(be.ragged_program_count())
         return first
 
+    def _fetch(self, packed_dev, t_launch: float, rec: dict):
+        """The ONE blocking fetch that closes a launch record: the wait
+        is the `fetch.<phase>` interval of the worker's clock, everything
+        after it `distribute`. dli_decode_step_seconds is launch-to-fetch
+        over the launch's steps: under lag-N pipelining this includes
+        queue wait behind earlier launches, so it is the EFFECTIVE
+        per-token step time the fleet delivers, not raw compute."""
+        self._clock.mark(
+            "fetch_wait", f"fetch.{rec['phase']}", seq=rec["seq"]
+        )
+        packed = np.asarray(packed_dev)
+        now = self._clock.mark("distribute")
+        self._steps_inflight -= rec["steps"]
+        self._m_step.observe(max(0.0, now - t_launch) / rec["steps"])
+        return packed
+
+    def _observe_admission(self, req: _Request, wait_s: float,
+                           prefill_s: float):
+        """A request's first token is on the server: the whole wait
+        since enqueue, and its two halves — the `queue_wait` stage spans
+        since the last grant and the `admission` span just closed."""
+        queue_s, req.queue_wait_s = req.queue_wait_s, 0.0
+        if req.record:
+            self._m_admission_wait.observe(wait_s)
+            self._m_queue_wait.observe(queue_s)
+            self._m_prefill.observe(prefill_s)
+
     def _process(self, chunk):
         """Fetch one decode chunk's packed results and distribute/finalize."""
-        packed_dev, snapshot, t_launch, seq = chunk
+        packed_dev, snapshot, t_launch, seq, rec = chunk
         faults.check("fetch", tag=",".join(
             r.prompt for r in snapshot if r is not None
         ))
-        packed = np.asarray(packed_dev)  # [2K+1, B] — the ONE fetch per chunk
-        # launch-to-fetch over the chunk's steps: under lag-N pipelining
-        # this includes queue wait behind earlier chunks, so it is the
-        # EFFECTIVE per-token step time the fleet delivers, not raw compute
-        self._m_step.observe(
-            max(0.0, time.perf_counter() - t_launch) / self.chunk_steps
-        )
+        # [2K+1, B] — the ONE fetch per chunk
+        packed = self._fetch(packed_dev, t_launch, rec)
         K = self.chunk_steps
         emitted = packed[:K]
         mask = packed[K : 2 * K].astype(bool)

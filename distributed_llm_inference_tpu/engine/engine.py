@@ -32,7 +32,10 @@ from ..config import EngineConfig, ModelConfig
 from ..models import api as M
 from ..utils import faults
 from ..utils.logging import get_logger, request_id_context
-from ..utils.metrics import DEFAULT_SIZE_BUCKETS, MetricsRegistry
+from ..utils.metrics import (
+    ADMISSION_WAIT_HELP, DEFAULT_SIZE_BUCKETS, STEPS_AHEAD_BUCKETS,
+    MetricsRegistry,
+)
 from ..utils.probe import device_summary
 from ..utils.tokenizer import load_tokenizer
 from ..utils.tracing import FlightRecorder, Trace
@@ -416,8 +419,23 @@ class InferenceEngine:
             "dli_queue_shed_total", "requests shed with 429", ("queue",)
         )
         self.metrics.histogram(
-            "dli_admission_wait_seconds", "enqueue-to-dispatch wait",
-            ("queue",),
+            "dli_admission_wait_seconds", ADMISSION_WAIT_HELP, ("queue",),
+        )
+        # its two halves on the continuous engine, and why a head waited
+        self.metrics.histogram(
+            "dli_queue_wait_seconds",
+            "enqueue until a slot and pool blocks were granted (re-waits "
+            "of a blocked head included)", ("queue",),
+        )
+        self.metrics.histogram(
+            "dli_prefill_seconds",
+            "grant until the request's first token was fetched (chunked "
+            "prefill shares its steps with the decode rows)", ("queue",),
+        )
+        self.metrics.counter(
+            "dli_admission_blocked_total",
+            "scheduler iterations that left the head of the queue "
+            "waiting, by what it waited for", ("reason",),
         )
         self.metrics.gauge("dli_slots_total", "continuous-fleet decode slots")
         self.metrics.gauge(
@@ -596,7 +614,8 @@ class InferenceEngine:
         )
         self.metrics.counter(
             "dli_ragged_launches_total",
-            "ragged ingest launches", ("phase",),
+            "launches by program: ragged ingest (extend / prefill) and "
+            "scheduler steps (mixed / chunk)", ("phase",),
         )
         self.metrics.counter(
             "dli_ragged_exact_prefix_hits_total",
@@ -623,7 +642,30 @@ class InferenceEngine:
         )
         self.metrics.counter(
             "dli_sched_decode_rows_total",
-            "decode rows carried by mixed scheduler launches",
+            "decode rows carried by scheduler launches (a pure-decode "
+            "chunk counts its row-steps)",
+        )
+        # launch-record families (engine/continuous.py counts them at
+        # every dispatch, mixed step or pure-decode chunk): KV positions
+        # attended against walked, work dispatched ahead of a launch,
+        # and the worker thread's wall time by phase
+        self.metrics.counter(
+            "dli_attn_kv_tokens_total",
+            "KV positions per layer and KV head: attended = the fewest "
+            "the launch's rows need (host position model, window-"
+            "clipped), walked = what the kernel's grid covers",
+            ("phase", "state"),
+        )
+        self.metrics.histogram(
+            "dli_launch_steps_ahead",
+            "scheduler steps dispatched and unfetched when a launch was "
+            "dispatched", ("phase",), buckets=STEPS_AHEAD_BUCKETS,
+        )
+        self.metrics.counter(
+            "dli_worker_phase_seconds_total",
+            "wall time of the scheduler's worker thread by phase "
+            "(contiguous: the phases sum to the thread's life)",
+            ("phase",),
         )
         # fleet speculative-decoding families (engine/continuous.py
         # labels them when the mixed fleet speculates — ISSUE 13):

@@ -34,7 +34,7 @@ import time
 from typing import Any, Optional
 
 from ..utils.logging import get_logger
-from ..utils.metrics import DEFAULT_SIZE_BUCKETS
+from ..utils.metrics import ADMISSION_WAIT_HELP, DEFAULT_SIZE_BUCKETS
 from ..utils.retry import overload_retry_after
 from ..utils.tracing import Trace
 
@@ -158,8 +158,7 @@ class BatchingQueue:
             "dli_queue_shed_total", "requests shed with 429", ("queue",)
         ).labels(queue="batching")
         self._m_wait = m.histogram(
-            "dli_admission_wait_seconds", "enqueue-to-dispatch wait",
-            ("queue",),
+            "dli_admission_wait_seconds", ADMISSION_WAIT_HELP, ("queue",),
         ).labels(queue="batching")
         self._m_coalesced = m.counter(
             "dli_coalesced_fleets_total",

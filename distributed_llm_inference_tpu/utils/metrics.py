@@ -46,6 +46,17 @@ DEFAULT_TIME_BUCKETS = (
 )
 # Small-integer-count buckets (batch sizes, fleet occupancy).
 DEFAULT_SIZE_BUCKETS = (1, 2, 4, 8, 16, 32, 64)
+# dli_launch_steps_ahead: scheduler steps, linear (0 for an idle chip,
+# 16 a decode chunk; the latency buckets would put them all in one)
+STEPS_AHEAD_BUCKETS = tuple(range(0, 65, 4))
+# dli_admission_wait_seconds is registered by three components; on the
+# continuous engine it has always been the whole wait for a first token
+# on the server, not a queue wait
+ADMISSION_WAIT_HELP = (
+    "enqueue until dispatch (queue=\"batching\"); on the continuous "
+    "engine enqueue until the request's first token was fetched, prefill "
+    "included: dli_queue_wait_seconds + dli_prefill_seconds"
+)
 
 MAX_SERIES = 64  # label-set cap per family
 WINDOW = 256  # raw-observation window per histogram child (matches

@@ -37,6 +37,12 @@ Everything here stays strictly host-side: nothing crosses into traced
 XLA code, and the launch-level attribution the continuous engine records
 under `engine_cfg.trace_sample_rate` is host timestamps keyed by launch
 seq — never an extra device sync.
+
+`PhaseClock` (ISSUE 24) is the same contiguous model one level down: the
+continuous engine's worker thread marks the phase it enters, the time up
+to the next mark goes to `dli_worker_phase_seconds_total{phase}`, and
+each interval is one `jax.profiler.TraceAnnotation`, so a `/profiler`
+trace shows what the host did beside the device's own events.
 """
 
 from __future__ import annotations
@@ -215,6 +221,65 @@ class Trace:
             out = {f"{k}_s": round(v, 6) for k, v in self._spans.items()}
             out["total_s"] = round(now - self._t0, 6)
         return out
+
+
+WORKER_PHASES = (
+    "wait_work", "reap", "admit", "plan", "dispatch", "fetch_wait",
+    "distribute",
+)
+# the phases in which the thread blocks: one may outlast a profiler
+# session, which keeps no interval still open at its end
+WAIT_PHASES = ("wait_work", "fetch_wait")
+
+
+class PhaseClock:
+    """Contiguous phase timer of ONE thread (the continuous engine's
+    worker), in the model of `Trace.checkpoint`: `mark(phase)` says which
+    phase the thread enters now; the time until the next mark is added to
+    that phase's child of `family` (a counter labeled `phase`), so the
+    phases sum to the thread's wall time by construction. Each interval
+    is also one `jax.profiler.TraceAnnotation` on the profiler's clock,
+    named `phase.<phase>` or, where the caller gives one, `span` with
+    `attrs` as the event's stats (the launch record rides the dispatch
+    interval this way). The profiler keeps only intervals that began AND
+    ended inside a session, so every interval carries `prev` (the phase
+    it followed: the one open when the session began is put back from
+    the first recorded) and a wait is preceded by an instant
+    `begin.<name>` marker (the one open when the session ended runs from
+    its marker to the end). Outside a profiler session an annotation is
+    a constructor call and two no-op methods."""
+
+    __slots__ = ("_children", "_phase", "_last", "_open", "_annotation")
+
+    def __init__(self, family, phases=WORKER_PHASES):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self._children = {p: family.labels(phase=p) for p in phases}
+        self._phase: Optional[str] = None
+        self._last = time.perf_counter()
+        self._open = None
+
+    def mark(self, phase: Optional[str], span: Optional[str] = None, /,
+             **attrs) -> float:
+        """Close the open interval, open `phase` (None: stop the clock).
+        Returns the perf_counter reading that ends the one and begins the
+        other."""
+        now = time.perf_counter()
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+        if self._phase is not None:
+            self._children[self._phase].inc(now - self._last)
+        prev, self._phase, self._last = self._phase, phase, now
+        if phase is not None:
+            name = span or f"phase.{phase}"
+            if phase in WAIT_PHASES:
+                with self._annotation(f"begin.{name}", **attrs):
+                    pass
+            self._open = self._annotation(name, prev=prev or "", **attrs)
+            self._open.__enter__()
+        return now
 
 
 class FlightRecorder:

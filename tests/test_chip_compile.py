@@ -224,3 +224,84 @@ def test_tinyllama_paged_decode_step_compiles_with_kernel(
         num_steps=1,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# What a device trace calls the fleet's two step programs and their two
+# attention kernels: the benchmark's configurations name these strings under
+# `serving.trace` (cellbench/configs/*.json), five per-layer metrics find
+# their events by them, and a rename must fail here, not null them.
+STEP_MODULES = {"mixed_step_ragged", "decode_slots_paged"}
+ATTENTION_KERNELS = {"ragged_paged_attend", "paged_flash_attend"}
+
+
+def _module_name(hlo_text):
+    return hlo_text.split("HloModule ", 1)[1].split(",", 1)[0].split()[0]
+
+
+def _custom_call_names(hlo_text):
+    """Instruction names of the compiled module's custom calls, as a device
+    trace's `XLA Ops` line shows them (`%paged_flash_attend.3`)."""
+    import re
+
+    return set(re.findall(r"%([\w.\-]+) = [^\n]*custom-call\(", hlo_text))
+
+
+def test_step_programs_and_kernels_carry_the_names_a_trace_is_read_by(
+    one_chip, no_persistent_cache, tinyllama, monkeypatch
+):
+    import glob
+    import json
+    import os
+
+    import numpy as np
+
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    cfg, params = tinyllama
+    S = _spec(one_chip)
+    place = functools.partial(_placed, sharding=one_chip)
+    state, sparams = place(
+        jax.eval_shape(lambda: G.init_slots(SLOTS, cfg.vocab_size))
+    )
+    pool = place(jax.eval_shape(lambda: EP.init_pool(cfg, POOL_BLOCKS, 16)))
+    table = S((SLOTS, 2048 // 16), jnp.int32)
+    key = place(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    chunk = EP.decode_slots_paged.lower(
+        cfg, params, state, pool, table, key, sparams, num_steps=2,
+    ).compile().as_text()
+    # the mixed step as the scheduler launches it: a flat axis of
+    # (slots + 1) tiles, decode positions derived on the device
+    tile, width = 8, (SLOTS + 1) * 8
+    entries = [(b, 0, 1, EP.RAGGED_DECODE) for b in range(SLOTS)]
+    meta, tok_row, tok_pos, offsets, _ = EP.build_ragged_meta(
+        entries, width=width, tile=tile
+    )
+    dev = EP.DeviceMeta(*(
+        S(a.shape, a.dtype) for a in EP.build_device_meta(
+            entries, offsets, SLOTS, width=width, tile=tile)
+    ))
+    arm = place(jax.eval_shape(
+        lambda: EP.idle_mixed_arm(SLOTS, cfg.vocab_size)
+    ))
+    flat = lambda a: S(np.shape(a), np.asarray(a).dtype)  # noqa: E731
+    mixed = EP.mixed_step_ragged.lower(
+        cfg, params, S((width,), jnp.int32), flat(tok_row), flat(tok_pos),
+        S((width,), jnp.bool_), flat(meta), pool, table, state, sparams, key,
+        S((SLOTS,), jnp.int32), arm, dev=dev,
+    ).compile().as_text()
+    names = {
+        "decode_slots_paged": (chunk, "paged_flash_attend"),
+        "mixed_step_ragged": (mixed, "ragged_paged_attend"),
+    }
+    for module, (text, kernel) in names.items():
+        assert module in _module_name(text), _module_name(text)
+        calls = _custom_call_names(text)
+        assert any(kernel in c for c in calls), (module, sorted(calls))
+    # and these are the strings the benchmark's configurations name
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    files = glob.glob(os.path.join(root, "cellbench", "configs", "*.json"))
+    assert files
+    for path in files:
+        with open(path) as f:
+            trace = json.load(f)["serving"]["trace"]
+        assert set(trace["step_modules"]) == STEP_MODULES, path
+        assert set(trace["attention_kernels"]) == ATTENTION_KERNELS, path

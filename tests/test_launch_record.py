@@ -1,0 +1,356 @@
+"""The launch record and the worker's phase clock (ISSUE 24;
+engine/continuous.py `_launch_record` / `_fetch`, utils/tracing.PhaseClock).
+
+One record per launch, mixed step or pure-decode chunk, feeds the
+counters, the profiler annotations, the flight `plan` event and the sampled
+per-tenant span. These tests hold it to numbers a hand can compute: a fixed
+request list is served one request at a time through a tiny chunked paged
+fleet with a sliding window, so every prompt lands in chunks of the step
+width and every answer runs to its budget (no stop token), and the sums
+follow from prompt lengths, answer lengths and the window alone.
+"""
+
+import math
+import os
+import time
+
+import jax
+import pytest
+
+from distributed_llm_inference_tpu import EngineConfig, get_model_config
+from distributed_llm_inference_tpu.engine.continuous import ContinuousEngine
+from distributed_llm_inference_tpu.engine.engine import InferenceEngine
+from distributed_llm_inference_tpu.models import api as M
+from distributed_llm_inference_tpu.utils.metrics import MetricsRegistry
+from distributed_llm_inference_tpu.utils.tracing import (
+    WORKER_PHASES, PhaseClock,
+)
+
+WINDOW = 48
+SLOTS, CHUNK_STEPS, LAG, BLOCK, MAX_SEQ = 3, 4, 2, 16, 256
+# (prompt, max_tokens): one-chunk prompts, a three-chunk prompt that
+# crosses the window inside its prefill, answers that end inside a chunk,
+# on a chunk's edge and after one token. Distinct from their first byte
+# on, so the block-prefix index finds nothing.
+REQUESTS = [
+    ("alpha beta gamma", 9),
+    ("Z" + " lorem ipsum dolor sit amet" * 6, 13),
+    ("q", 1),
+    ("mid-sized prompt, forty-one tokens long!!", 30),
+    ("7 seven", 5),
+]
+
+
+@pytest.fixture(scope="module")
+def setup():
+    cfg = get_model_config(
+        "test-llama-tiny", dtype="float32", eos_token_id=-1, max_seq_len=512,
+        attn_window=WINDOW,
+    )
+    return cfg, M.init_params(cfg, jax.random.PRNGKey(0))
+
+
+def _cont(cfg, params, **engine_cfg):
+    ecfg = dict(prefix_cache_entries=0, chunked_prefill=True,
+                step_token_budget=64, prefill_buckets=(64, 128, 256))
+    ecfg.update(engine_cfg)
+    eng = InferenceEngine(cfg, params=params, engine_cfg=EngineConfig(**ecfg))
+    return ContinuousEngine(
+        eng, n_slots=SLOTS, chunk_steps=CHUNK_STEPS, chunk_lag=LAG,
+        slot_max_seq=MAX_SEQ, kv_pool_blocks=64, kv_block_size=BLOCK,
+        restart_backoff_s=0.01,
+    )
+
+
+def _series(snap, name):
+    return {
+        tuple(sorted(s["labels"].items())): s
+        for s in snap.get(name, {}).get("series", [])
+    }
+
+
+def _value(snap, name, **labels):
+    s = _series(snap, name).get(tuple(sorted(labels.items())))
+    return 0 if s is None else s["value"]
+
+
+def _hist(snap, name, **labels):
+    s = _series(snap, name)[tuple(sorted(labels.items()))]
+    return s["sum"], s["count"]
+
+
+def _serve(cfg, params, **engine_cfg):
+    """The request list, one at a time, through a fresh fleet."""
+    cont = _cont(cfg, params, **engine_cfg)
+    t0 = time.perf_counter()
+    try:
+        results = [
+            cont.submit(p, max_tokens=n, greedy=True, chat=False)
+            for p, n in REQUESTS
+        ]
+    finally:
+        cont.close()
+        cont._thread.join(timeout=30)
+    wall = time.perf_counter() - t0
+    assert not cont._thread.is_alive()
+    assert all(r["status"] == "success" for r in results), results
+    return {
+        "snap": cont.engine.metrics.snapshot(), "results": results,
+        "wall_s": wall, "width": cont._sched_width, "cont": cont,
+        "flight": cont.engine.flight.events(),
+    }
+
+
+@pytest.fixture(scope="module")
+def runs(setup):
+    return [_serve(*setup) for _ in range(2)]
+
+
+def _clip(n):
+    return min(n, WINDOW)
+
+
+def _expected(run):
+    """By hand: a prompt of P tokens lands in chunks of the step width W,
+    each reading min(tokens so far, window) positions once; its first
+    token comes out of the last chunk, and each of its A - 1 further
+    tokens takes one decode step at positions P .. P + A - 2, a step at
+    position n reading min(n + 1, window)."""
+    W = run["width"]
+    out = {"mixed": 0, "prefill_tokens": 0, "attended_mixed": 0,
+           "attended_chunk": 0, "row_steps": 0}
+    for (_, answer), r in zip(REQUESTS, run["results"]):
+        P = r["prompt_tokens"]
+        assert r["tokens_generated"] == answer, r  # ran to its budget
+        chunks = math.ceil(P / W)
+        out["mixed"] += chunks
+        out["prefill_tokens"] += P
+        out["attended_mixed"] += sum(
+            _clip(min(P, (c + 1) * W)) for c in range(chunks)
+        )
+        out["attended_chunk"] += sum(
+            _clip(n + 1) for n in range(P, P + answer - 1)
+        )
+        out["row_steps"] += answer - 1
+    return out
+
+
+# -- (a) attended KV positions, exactly, on two runs ---------------------------
+
+@pytest.mark.parametrize("i", [0, 1])
+def test_attended_kv_tokens_equal_the_hand_sum(runs, i):
+    run = runs[i]
+    want = _expected(run)
+    snap = run["snap"]
+    kv = "dli_attn_kv_tokens_total"
+    assert _value(snap, kv, phase="mixed", state="attended") == want["attended_mixed"]
+    assert _value(snap, kv, phase="chunk", state="attended") == want["attended_chunk"]
+    # the window bit: the long prompt and the long answer both crossed it
+    assert want["attended_mixed"] < sum(r["prompt_tokens"] for r in run["results"]) * 2
+    assert any(r["prompt_tokens"] + n > WINDOW for r, (_, n) in zip(run["results"], REQUESTS))
+
+
+def test_walked_kv_tokens_are_the_grid(runs):
+    """tiles (mixed) or slots x steps (chunk), each the block table wide."""
+    run = runs[0]
+    snap = run["snap"]
+    row = -(-MAX_SEQ // BLOCK) * BLOCK
+    mixed = _value(snap, "dli_ragged_launches_total", phase="mixed")
+    chunk = _value(snap, "dli_ragged_launches_total", phase="chunk")
+    kv = "dli_attn_kv_tokens_total"
+    assert _value(snap, kv, phase="mixed", state="walked") == mixed * (run["width"] // 8) * row
+    assert _value(snap, kv, phase="chunk", state="walked") == chunk * SLOTS * CHUNK_STEPS * row
+    for phase in ("mixed", "chunk"):
+        assert 0 < _value(snap, kv, phase=phase, state="attended") < _value(
+            snap, kv, phase=phase, state="walked")
+
+
+# -- (b) both kinds of launch are counted; the old series keep their values ----
+
+def test_chunk_launches_and_row_steps_are_counted(runs):
+    run = runs[0]
+    want, snap = _expected(run), run["snap"]
+    chunk = _value(snap, "dli_ragged_launches_total", phase="chunk")
+    # every decode step ran in some chunk; the lag launches a few more
+    # chunks than the answers need (their rows are dead: no row-steps)
+    assert chunk * CHUNK_STEPS >= want["row_steps"] and chunk >= 5
+    assert _value(snap, "dli_sched_decode_rows_total") == want["row_steps"]
+    assert _value(snap, "dli_sched_step_tokens_total", kind="decode") == want["row_steps"]
+    # one fetch per launch of either kind, as ever (close() leaves the
+    # chunks the lag had dispatched ahead unfetched)
+    _, fetches = _hist(snap, "dli_decode_step_seconds", engine="continuous")
+    unfetched = run["cont"]._steps_inflight // CHUNK_STEPS
+    assert 0 <= unfetched <= LAG
+    assert fetches == chunk + want["mixed"] - unfetched
+
+
+def test_old_series_read_what_the_parent_counted(runs):
+    """The series the benchmark's readers name: the numbers the parent
+    commit (a38196f) gave for this list on both of two runs, read there by
+    hand (PR 24: 7 mixed launches, 233 prefill tokens, 7 chunks, 30
+    fetches, 5 admissions), and the rule behind them."""
+    for run in runs:
+        want, snap = _expected(run), run["snap"]
+        assert want["mixed"] == 7 and want["prefill_tokens"] == 233
+        assert _value(snap, "dli_ragged_launches_total", phase="mixed") == 7
+        assert _value(snap, "dli_sched_step_tokens_total", kind="prefill") == 233
+        assert _value(snap, "dli_sched_prefill_chunks_total") == 7
+        assert _hist(snap, "dli_decode_step_seconds", engine="continuous")[1] == 30
+        assert _hist(snap, "dli_admission_wait_seconds", queue="continuous")[1] == 5
+
+
+# -- (c) the worker's phases sum to its wall time -------------------------------
+
+def test_worker_phases_sum_to_wall_time(runs):
+    run = runs[1]
+    phases = {
+        dict(k)["phase"]: s["value"]
+        for k, s in _series(run["snap"], "dli_worker_phase_seconds_total").items()
+    }
+    assert set(phases) == set(WORKER_PHASES)
+    # the thread started inside the engine's constructor, before t0, and
+    # ended inside close(): the clock covers at least the served part
+    assert sum(phases.values()) >= 0.99 * run["wall_s"] - 0.05
+    clock = run["cont"]._clock
+    assert clock._phase is None and clock._open is None  # stopped at close
+    assert phases["fetch_wait"] > 0 and phases["dispatch"] > 0 and phases["plan"] > 0
+
+
+def test_phase_clock_is_contiguous():
+    fam = MetricsRegistry().counter("dli_worker_phase_seconds_total", "", ("phase",))
+    clock = PhaseClock(fam)
+    t0 = clock.mark("plan")
+    time.sleep(0.01)
+    clock.mark("dispatch", "launch.mixed", seq=1, kv_tokens=5)
+    time.sleep(0.01)
+    clock.mark("plan")
+    t1 = clock.mark(None)
+    total = sum(s["value"] for s in fam.snapshot()["series"])
+    assert total == pytest.approx(t1 - t0, rel=0.01)
+    assert clock.mark(None) >= t1  # stopped: nothing more is added
+    assert sum(s["value"] for s in fam.snapshot()["series"]) == total
+
+
+# -- (d) the two halves of a first token's wait ---------------------------------
+
+def test_queue_wait_plus_prefill_is_the_admission_wait(runs):
+    for run in runs:
+        snap = run["snap"]
+        whole, n = _hist(snap, "dli_admission_wait_seconds", queue="continuous")
+        queue, nq = _hist(snap, "dli_queue_wait_seconds", queue="continuous")
+        prefill, npf = _hist(snap, "dli_prefill_seconds", queue="continuous")
+        assert n == nq == npf == len(REQUESTS)
+        assert queue + prefill == pytest.approx(whole, abs=1e-3 * n)
+        assert prefill > queue  # one request at a time: nothing waits for a slot
+
+
+def test_a_waiting_head_is_counted_by_reason(setup):
+    """Four requests at once for three slots: while all slots are taken the
+    head waits for a slot, once per scheduler iteration."""
+    import threading
+
+    cont = _cont(*setup)
+    try:
+        ts = [
+            threading.Thread(target=cont.submit, args=(f"{i} waits",),
+                             kwargs=dict(max_tokens=24, greedy=True, chat=False))
+            for i in range(SLOTS + 1)
+        ]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join()
+    finally:
+        cont.close()
+    snap = cont.engine.metrics.snapshot()
+    assert _value(snap, "dli_admission_blocked_total", reason="slot") >= 1
+    assert _value(snap, "dli_admission_blocked_total", reason="blocks") == 0
+    queue, n = _hist(snap, "dli_queue_wait_seconds", queue="continuous")
+    assert n == SLOTS + 1 and queue > 0
+
+
+# -- (f) work dispatched ahead of a launch --------------------------------------
+
+def test_steps_ahead_is_zero_first_and_bounded_by_the_lag(runs):
+    run = runs[0]
+    plans = [e for e in run["flight"] if e["kind"] == "plan"]
+    assert plans and plans[0]["steps_ahead"] == 0 and plans[0]["seq"] == 1
+    bound = (LAG + 1) * CHUNK_STEPS
+    for phase in ("mixed", "chunk"):
+        s = _series(run["snap"], "dli_launch_steps_ahead")[(("phase", phase),)]
+        assert s["count"] == _value(run["snap"], "dli_ragged_launches_total", phase=phase)
+        assert s["sum"] <= bound * s["count"]
+        assert s["p99"] <= bound
+
+
+def test_flight_plan_event_is_the_launch_record(runs):
+    ev = [e for e in runs[0]["flight"] if e["kind"] == "plan"]
+    assert len(ev) == 7  # one per mixed step that carried a prefill chunk
+    for e in ev:
+        assert e["phase"] == "mixed" and e["steps"] == 1 and e["prefill_chunks"] == 1
+        assert 0 < e["kv_tokens"] <= e["kv_grid_tokens"]
+        assert e["tiles_live"] <= e["tiles"] and "budget" in e
+    assert sum(e["prefill_tokens"] for e in ev) == 233
+    assert [e["seq"] for e in ev] == sorted(e["seq"] for e in ev)
+
+
+# -- (e) the annotations, on the profiler's clock -------------------------------
+
+def test_profiler_trace_holds_launch_fetch_and_phase_events(setup, tmp_path):
+    from jax.profiler import ProfileData
+
+    cont = _cont(*setup)
+    try:
+        cont.submit("warm the programs", max_tokens=6, greedy=True, chat=False)
+        before = cont.engine.metrics.snapshot()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for p, n in REQUESTS[:2]:
+                cont.submit(p, max_tokens=n, greedy=True, chat=False)
+            time.sleep(0.05)  # the lagged fetches drain
+        finally:
+            jax.profiler.stop_trace()
+        after = cont.engine.metrics.snapshot()
+    finally:
+        cont.close()
+    path = next(
+        os.path.join(base, f) for base, _, fs in os.walk(tmp_path)
+        for f in fs if f.endswith(".xplane.pb")
+    )
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.split(".")[0] in ("launch", "fetch", "phase", "begin"):
+                    events.append((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                                   dict(ev.stats)))
+    # a wait is preceded by an instant marker of its own name and stats
+    # (the profiler keeps no interval still open when it stops)
+    begun = [(n[len("begin."):], s, st) for n, s, _, st in events if n.startswith("begin.")]
+    events = [e for e in events if not e[0].startswith("begin.")]
+    waits = {(n, st.get("seq")) for n, _, _, st in events
+             if n.startswith("fetch.") or n == "phase.wait_work"}
+    assert begun and {(n, st.get("seq")) for n, _, st in begun} >= waits
+    names = {n for n, *_ in events}
+    assert {"launch.mixed", "launch.chunk", "fetch.mixed", "fetch.chunk"} <= names
+    assert {"phase.plan", "phase.distribute", "phase.wait_work", "phase.admit",
+            "phase.reap"} <= names
+    launches = {st["seq"]: (n, s, e, st) for n, s, e, st in events if n.startswith("launch.")}
+    fetches = {st["seq"]: (n, s, e) for n, s, e, st in events if n.startswith("fetch.")}
+    kv = "dli_attn_kv_tokens_total"
+    for phase in ("mixed", "chunk"):
+        delta = (_value(after, kv, phase=phase, state="attended")
+                 - _value(before, kv, phase=phase, state="attended"))
+        got = sum(int(st["kv_tokens"]) for n, _, _, st in launches.values()
+                  if n == f"launch.{phase}")
+        assert got == delta > 0
+    assert fetches
+    for seq, (name, start, _) in fetches.items():
+        if seq in launches:  # a launch from before the trace has none
+            ln, _, l_end, st = launches[seq]
+            assert name == "fetch." + ln.split(".", 1)[1] and start >= l_end
+            assert int(st["steps"]) in (1, CHUNK_STEPS)
+    # the worker's intervals are contiguous: each begins where one ended
+    worker = sorted((s, e) for n, s, e, _ in events)
+    gaps = [b[0] - a[1] for a, b in zip(worker, worker[1:])]
+    assert max(gaps) < 2e6 and min(gaps) > -2e3  # ns: no hole, no overlap

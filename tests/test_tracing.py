@@ -292,9 +292,12 @@ def test_trace_sample_rate_validated():
 
 @pytest.mark.chaos
 def test_zero_overhead_at_rate_zero(engine, monkeypatch):
-    """The sampling contract: at the default rate 0 the hot path must
-    not allocate a single span — enforced by making span creation blow
-    up for the duration, then serving a full request."""
+    """The sampling contract of the PER-TENANT export: at the default
+    rate 0 the hot path must not store a single span or note a launch
+    for a tenant — enforced by making span creation blow up for the
+    duration, then serving a full request. (The launch record itself,
+    its counters and profiler annotations are always on:
+    tests/test_launch_record.py.)"""
     import distributed_llm_inference_tpu.engine.continuous as C
 
     def _bomb(*a, **k):
@@ -319,8 +322,9 @@ def test_zero_overhead_at_rate_zero(engine, monkeypatch):
 @pytest.mark.chaos
 def test_launch_attribution_and_exemplar_link_at_rate_one(engine):
     """rate 1.0: every launch a profiled request rode emits one
-    launch.<kind> span parented under the request's inbound span, and
-    the latency histograms' exemplars link to the SAME stored trace."""
+    launch.<phase> span parented under the request's inbound span,
+    whose attrs are the launch record, and the latency histograms'
+    exemplars link to the SAME stored trace."""
     import dataclasses
 
     old = engine.engine_cfg
@@ -342,6 +346,8 @@ def test_launch_attribution_and_exemplar_link_at_rate_one(engine):
             assert sp["parent_id"] == ctx.span_id  # nests under inbound
             assert sp["t1"] >= sp["t0"]
             assert sp["attrs"].get("launch_to_fetch_s") is not None
+            assert sp["name"] == "launch." + sp["attrs"]["phase"]
+            assert sp["attrs"]["kv_tokens"] <= sp["attrs"]["kv_grid_tokens"]
         # exemplar -> this exact trace, which IS inspectable in the store
         ex = engine._m_duration.labels(engine="continuous").exemplars()
         assert any(e["trace_id"] == ctx.trace_id for e in ex.values())
