@@ -765,6 +765,9 @@ class ContinuousEngine:
             if self.cfg.attn_window_pattern == "all"
             and self.cfg.attn_window_layer_types is None else None
         )
+        # whether attention reads the pool through the paged kernels'
+        # block walk (_kv_walk) or gathers whole tables
+        self._kv_walks = self.paged and self.cfg.attn_impl == "pallas"
         # observability
         self.admitted = 0  # guarded-by: _cv
         self.completed = 0  # guarded-by: _cv
@@ -885,8 +888,8 @@ class ContinuousEngine:
         )
         self._m_ragged_tiles = m.counter(
             "dli_ragged_tiles_total",
-            "ragged-launch query tiles by liveness (live / pad — pad "
-            "tiles cost no DMA, only grid steps)", ("state",),
+            "ragged-launch query tiles by liveness (live / pad — a pad "
+            "tile is one program that walks no KV block)", ("state",),
         )
         self._m_ragged_launches = m.counter(
             "dli_ragged_launches_total",
@@ -922,13 +925,13 @@ class ContinuousEngine:
         ).labels()
         # launch-record families (ISSUE 24, pre-registered in
         # engine/engine.py): KV positions attention had to read against
-        # those the kernels' grids walked, how much work was dispatched
+        # those the kernels' block loops walked, how much work was dispatched
         # ahead of each launch, and where the worker thread's time went
         self._m_kv_tokens = m.counter(
             "dli_attn_kv_tokens_total",
             "KV positions per layer and KV head: attended = the fewest "
             "the launch's rows need (host position model, window-"
-            "clipped), walked = what the kernel's grid covers",
+            "clipped), walked = what the kernels' block loops cover",
             ("phase", "state"),
         )
         self._m_steps_ahead = m.histogram(
@@ -2703,26 +2706,28 @@ class ContinuousEngine:
 
     # -- the launch record (ISSUE 24) -----------------------------------------
     def _launch_record(self, phase: str, steps: int, kv_tokens: int,
-                       grid_rows: int, row_steps: int, **fields) -> dict:
+                       kv_grid_tokens: int, row_steps: int,
+                       **fields) -> dict:
         """The ONE record of a launch, built at the dispatch seam before
         the jitted call: host integers the loop already holds, no device
         read. `kv_tokens` is the fewest KV positions (per layer and KV
         head) attention must read for the launch's rows, from the host
-        position model; `kv_grid_tokens` what the kernel's grid covers
-        (`grid_rows` tiles or slots, each the whole block table wide,
-        every step); `steps_ahead` the scheduler steps dispatched and
-        not yet fetched; `row_steps` the decode row-steps it carries (a
-        chunk's rows run up to `steps` each). Counted here; the caller
-        hands it to the `launch.<phase>` annotation, the flight `plan`
-        event and the sampled per-tenant span, and the fetch closes it
-        by `seq`."""
+        position model; `kv_grid_tokens` the positions attention covers
+        to read them (`_kv_walk`: whole blocks of each live row's live
+        range under the paged kernels, every row's whole table under
+        the gather path); `steps_ahead` the scheduler steps dispatched
+        and not yet fetched; `row_steps` the decode row-steps it carries
+        (a chunk's rows run up to `steps` each). Counted here; the
+        caller hands it to the `launch.<phase>` annotation, the flight
+        `plan` event and the sampled per-tenant span, and the fetch
+        closes it by `seq`."""
         self._launch_seq += 1
         rec = {
             "phase": phase, "seq": self._launch_seq, "steps": steps,
             "decode_rows": 0, "prefill_chunks": 0, "prefill_tokens": 0,
             "spec_drafted": 0, "steps_ahead": self._steps_inflight,
             "kv_tokens": int(kv_tokens),
-            "kv_grid_tokens": grid_rows * self._scratch_seq * steps,
+            "kv_grid_tokens": int(kv_grid_tokens),
         }
         rec.update(fields)
         self._steps_inflight += steps
@@ -2746,6 +2751,25 @@ class ContinuousEngine:
         return n if self._kv_window is None else np.minimum(
             n, self._kv_window
         )
+
+    def _kv_walk(self, start, length=1):
+        """KV positions the paged kernels' block loop covers for a query
+        tile of `length` queries from position `start`: (needed - first)
+        x block size, ops/paged_attention._ragged_live_range's arithmetic
+        in numpy, and nothing for a tile that holds no query
+        (numpy-broadcasting; tests/test_launch_record.py holds the two
+        together). Under the gather path (attn_impl "xla", and the dense
+        fleet) every row reads its whole table, live or not."""
+        if not self._kv_walks:
+            return np.full(np.broadcast(start, length).shape,
+                           self._scratch_seq)
+        bs = self.kv_block_size
+        last = start + np.maximum(length, 1) - 1
+        needed = np.clip(-(-(last + 1) // bs), 1, self._max_blocks)
+        first = 0 if self._kv_window is None else np.minimum(
+            np.maximum(start - self._kv_window + 1, 0) // bs, needed - 1
+        )
+        return np.where(length > 0, (needed - first) * bs, 0)
 
     # -- launch-level device-time attribution (ISSUE 17) ---------------------
     def _prof_note_launch(self, t_launch: float, snapshot, rec: dict):
@@ -2827,13 +2851,13 @@ class ContinuousEngine:
         rows = np.array([r is not None for r in snapshot])
         live = np.clip(self._host_end - self._host_pos, 0, K) * rows
         step = np.arange(K)
+        at = self._host_pos[:, None] + step
+        alive = step < live[:, None]  # the device holds the row active
         rec = self._launch_record(
             "chunk", K,
-            kv_tokens=np.sum(
-                self._kv_span(self._host_pos[:, None] + step)
-                * (step < live[:, None])
-            ),
-            grid_rows=self.n_slots, row_steps=int(live.sum()),
+            kv_tokens=np.sum(self._kv_span(at) * alive),
+            kv_grid_tokens=np.sum(self._kv_walk(at, alive)),
+            row_steps=int(live.sum()),
             decode_rows=int(np.count_nonzero(live)),
         )
         # every believed-active slot advances K (over-advance on rows
@@ -3678,7 +3702,8 @@ class ContinuousEngine:
                 for b, start, n, _ in entries[:n_dec]
                 if start < self._host_end[b]
             ) + sum(int(self._kv_span(st, n)) for _, n, st in chunk_list),
-            grid_rows=stats["tiles"], row_steps=n_dec,
+            kv_grid_tokens=np.sum(self._kv_walk(meta[:, 1], meta[:, 2])),
+            row_steps=n_dec,
             decode_rows=n_dec, prefill_chunks=len(chunk_list),
             prefill_tokens=sum(n for _, n, _ in chunk_list),
             spec_drafted=sum(nd for nd, _, _ in spec_rows.values()),

@@ -609,8 +609,8 @@ class InferenceEngine:
         )
         self.metrics.counter(
             "dli_ragged_tiles_total",
-            "ragged-launch query tiles by liveness (live / pad — pad "
-            "tiles cost no DMA, only grid steps)", ("state",),
+            "ragged-launch query tiles by liveness (live / pad — a pad "
+            "tile is one program that walks no KV block)", ("state",),
         )
         self.metrics.counter(
             "dli_ragged_launches_total",
@@ -653,7 +653,7 @@ class InferenceEngine:
             "dli_attn_kv_tokens_total",
             "KV positions per layer and KV head: attended = the fewest "
             "the launch's rows need (host position model, window-"
-            "clipped), walked = what the kernel's grid covers",
+            "clipped), walked = what the kernels' block loops cover",
             ("phase", "state"),
         )
         self.metrics.histogram(

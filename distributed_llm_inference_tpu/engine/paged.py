@@ -36,10 +36,10 @@ paged attention):
     run the stock masked attention — the gather reads the same bytes a
     dense cache read would, plus one materialization (~+2 x
     cache-bytes/step of HBM traffic vs dense while weight streaming
-    still dominates at small batch). attn_impl="pallas": the fused
-    paged-attention kernel (ops/paged_attention.py) walks the block
-    table directly with an online softmax — one DMA per LIVE block, no
-    materialized view, dead blocks never leave HBM.
+    still dominates at small batch). attn_impl="pallas": the paged
+    kernels (ops/paged_attention.py) walk each live row's live blocks
+    of the table with an online softmax — one DMA per block for all KV
+    heads, no materialized view; a freed slot walks nothing.
   * Writes are scatters: token K/V lands at
     pool[table[b, pos_b // bs], :, pos_b % bs] per slot row b. Distinct
     live slots never share a block, so scatter indices never collide
@@ -254,7 +254,7 @@ def blocks_needed(prompt_len: int, max_tokens: int, block_size: int) -> int:
     return -(-(prompt_len + max_tokens) // block_size)
 
 
-def make_paged_hook(table: jnp.ndarray):
+def make_paged_hook(table: jnp.ndarray, active=None):
     """attn_hook for models/llama.decoder_layer over a paged pool.
 
     table: [B, max_blocks] int32 physical block ids. The hook sees this
@@ -262,6 +262,11 @@ def make_paged_hook(table: jnp.ndarray):
     by forward_layers' scan) and per-row positions pos [B]; the chunk is
     always T=1 (decode — prefill runs on a contiguous scratch cache and is
     spliced in by insert_slot_paged).
+    active: [B] bool slot liveness (SlotState.active), or None for every
+    row live. The fused kernel walks no KV block for a row whose flag is
+    false — a freed slot's position stays frozen at its last request's
+    length — and returns zeros there; the row's logits are discarded by
+    slot_step either way. The gather path ignores it.
     """
 
     def hook(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
@@ -310,21 +315,20 @@ def make_paged_hook(table: jnp.ndarray):
             new_v = cache_v.at[blk, :, off, :].set(v[:, 0])
         if cfg.attn_impl == "pallas":
             # Fused Pallas paged attention (ops/paged_attention.py) for
-            # BOTH leaf types: walks the table block by block with an
-            # online softmax — no contiguous-view materialization, dead
-            # blocks never leave HBM; int8 pools dequantize in the block
-            # prologue (half the bytes per live block). The full variant
-            # surface runs fused since round 5: softcap and scale
-            # overrides are static kernel params, and mixed per-layer
-            # window patterns feed this layer's width through the
-            # window_dyn scalar-prefetch operand (window_flag only
-            # exists for mixed configs — models/llama.make_window_flags).
+            # BOTH leaf types: each live row walks its own live blocks
+            # of the table with an online softmax — no contiguous-view
+            # materialization; int8 pools dequantize in the block
+            # prologue. Softcap and scale overrides are static kernel
+            # params, and mixed per-layer window patterns feed this
+            # layer's width through the window_dyn scalar-prefetch
+            # operand (window_flag only exists for mixed configs —
+            # models/llama.make_window_flags).
             from ..models.llama import kernel_window
             from ..ops.paged_attention import paged_flash_attend
 
             w, wd = kernel_window(cfg, window_flag)
             attn = paged_flash_attend(
-                q, new_k, new_v, table, pos, wd, window=w,
+                q, new_k, new_v, table, pos, wd, active, window=w,
                 scale=cfg.query_scale, softcap=cfg.attn_softcap,
             )
             return attn, new_k, new_v
@@ -476,11 +480,13 @@ def restore_shadow_blocks(pool, blocks, block_ids):
     return _restore_shadow(pool, blocks, block_ids)
 
 
-def _forward_step_paged(cfg, params, tokens, pool, table, pos, pages=None):
+def _forward_step_paged(cfg, params, tokens, pool, table, pos, pages=None,
+                        active=None):
     """One decode step through the stack over the paged pool (family-
     dispatched: gpt2 rides the same hook seam). pages: optional [B] i32
     adapter-pool page ids (0 = base) — traced, so adapter mixes never
-    recompile."""
+    recompile. active: optional [B] bool — rows whose output nothing
+    reads (make_paged_hook)."""
     from ..models import api as M
 
     bs = pool["k"].shape[3]
@@ -488,7 +494,7 @@ def _forward_step_paged(cfg, params, tokens, pool, table, pos, pages=None):
     x = M.embed(cfg, params, tokens, pos)
     x, pool = M.forward_layers(
         cfg, params["layers"], x, pool, pos,
-        attn_hook=make_paged_hook(table), attn_seq_len=MB * bs,
+        attn_hook=make_paged_hook(table, active), attn_seq_len=MB * bs,
         lora_pages=pages,
     )
     logits = M.unembed(cfg, params, x[:, -1:, :])
@@ -521,7 +527,7 @@ def decode_slots_paged(
         state, pool = carry
         logits, pool = _forward_step_paged(
             cfg, params, state.token[:, None], pool, table, state.pos,
-            pages=pages,
+            pages=pages, active=state.active,
         )
         new, emit, can_emit = G.slot_step(cfg, state, sparams, logits, sub)
         return (new, pool), (emit, can_emit)
@@ -595,7 +601,7 @@ def insert_slot_paged(
 # (ops/paged_attention.ragged_paged_attend) — or its XLA gather twin on
 # CPU — reading the mapped shared head IN PLACE. One compiled program per
 # launch width covers ANY tail length (the last launch pads with dead
-# tiles whose DMA Pallas skips), so the block-prefix planner reuses at
+# tiles, which the kernel does not walk), so the block-prefix planner reuses at
 # exact chunk depth instead of degrading to a bucket boundary.
 
 RAGGED_PREFILL = 0  # launch-entry kind: a prompt chunk (length >= 1)
@@ -620,9 +626,9 @@ def build_ragged_meta(entries, *, width: int, tile: int):
     index (-1 = launch padding, scattered to the trash block) and
     absolute position; offsets[i] is entry i's flat token offset; stats
     counts tiles/pad_tiles/rows-by-kind for the dli_ragged_* metrics.
-    Dead tiles copy their predecessor's (row, q_start) with q_len 0, so
-    their clamped KV walk repeats the predecessor's physical indices and
-    Pallas skips the DMA (see ops/paged_attention._ragged_live_range).
+    Dead tiles copy their predecessor's (row, q_start) with q_len 0:
+    the kernel walks nothing for them (ops/paged_attention._walk_kernel)
+    and the row index stays a valid one.
 
     The plan's POSITIONAL half is only authoritative where the host
     position model is exact. For decode/verify rows in the mixed
@@ -663,9 +669,8 @@ def build_ragged_meta(entries, *, width: int, tile: int):
             tok_row[w : w + q_len] = row
             tok_pos[w : w + q_len] = q_start + np.arange(q_len)
             g += 1
-    # launch padding: dead tiles inherit the predecessor's placement so
-    # the kernel's clamped index repeats (DMA skipped), q_len 0 gates the
-    # compute off
+    # launch padding: dead tiles inherit the predecessor's placement (a
+    # valid row); q_len 0 gates their walk off
     stats["pad_tiles"] = G - g
     while g < G:
         if g > 0:
@@ -998,8 +1003,8 @@ def build_device_meta(entries, offsets, n_dev: int, *, width: int,
     the walk here only recomputes each tile's offset within its entry.
     Launch-padding tiles inherit their predecessor's flags exactly like
     build_ragged_meta copies its (row, q_start): a pad tile behind a
-    derived tile must derive the SAME value so its clamped KV walk keeps
-    repeating physical indices and Pallas keeps skipping the DMA.
+    derived tile derives the SAME value (the kernel walks nothing for
+    it either way: q_len 0).
 
     Returns numpy (tile_on [G] bool, tile_off [G] i32, tok_on [W] bool,
     tok_off [W] i32) — wrap in a DeviceMeta for the launch."""

@@ -1,40 +1,46 @@
-"""Pallas TPU paged-attention decode kernel over the block pool.
+"""Pallas TPU paged attention over the block pool: the decode kernel
+(`paged_flash_attend`), the mixed prefill + decode kernel
+(`ragged_paged_attend`) and the dense slot-fleet kernel
+(`flash_attend_slots`).
 
-Fused replacement for the gather-then-attend path in `engine/paged.py`:
-the XLA path materializes each slot's block table into a contiguous
-[B, KV, MB*bs, Dh] view (one extra HBM write + read of the whole logical
-window per layer per step) and then runs the masked einsum attention over
-it. Here the kernel walks the block table directly — each grid step DMAs
-ONE physical pool block [bs, Dh] into VMEM and folds it into an
-online-softmax (flash) accumulator, so
+The two paged kernels are ONE kernel body under two wrappers. A program
+of the grid is one row's work: a decode slot's single query, or one query
+tile of the mixed launch's flat token axis (times a number of KV-head
+groups where the working set of all heads would not fit the VMEM
+budget). The layer's pool slices stay in HBM in the layout the scatter
+writes ([N, KV, bs, Dh]); the program walks its row's block table itself:
 
-  * HBM traffic is one read of the slot's LIVE blocks (dead tail blocks
-    and — with a sliding window — dead head blocks repeat their
-    neighbour's index, so Pallas skips the DMA entirely), with no
-    contiguous-view materialization at all;
-  * the pool is never reshaped/transposed: the kernel reads the same
-    [N, KV, bs, Dh] layout the scatter writes.
+  * `first, needed` bound the row's LIVE logical blocks: the causal
+    frontier above, the sliding window below (static, or a traced
+    per-layer width riding as a scalar-prefetch operand — Gemma-2/3);
+  * `lax.fori_loop(first, needed, ...)` copies block `table[row, j]` —
+    the slab of all the group's KV heads, contiguous in that layout —
+    into one of two VMEM buffers, and starts block j + 1's copy before it
+    waits for block j's; an int8 pool's scale slabs (ops/kv_quant) walk
+    the same loop;
+  * the slab's heads fold into their online-softmax accumulators in one
+    batched matmul pair: K and V raised to float32, float32 scores,
+    softcap before the mask, float32 accumulators, output in q.dtype;
+  * a row that holds nothing (a launch-padding tile, a decode slot whose
+    `active` flag is false) copies nothing and loops zero times; its
+    output is zeros, which the caller discards.
 
-Contract (matches `engine/paged.make_paged_hook`'s gather path):
-  * decode only — T=1 queries at per-row positions `pos` [B];
-  * mask is derived IN-KERNEL from `pos` and the window — static, or a
-    TRACED per-layer width via the `window_dyn` scalar-prefetch operand
-    (Gemma-2/3 alternating patterns): row b attends logical positions
-    max(0, pos_b-win+1) .. pos_b inclusive. Score-scale overrides and
-    Gemma-2 softcapping are static kernel params, so the full attention
-    variant surface runs fused (round 5 — the kernel previously fell
-    back to the gather path for these).
-  * GQA is folded into the query-row dimension exactly like
-    ops/flash_attention.py: the score matmul is [group, Dh] x [Dh, bs].
+So the device's work follows the rows' live blocks, not slots x KV heads
+x table width. What the walk covers is counted on the host by
+engine/continuous._kv_walk (the launch record's `kv_grid_tokens`), which
+repeats `_ragged_live_range`'s arithmetic in numpy;
+tests/test_launch_record.py holds the two together. Times on the chip are
+in PERF.md (section 6, PR 25) and the ledger, not here.
 
-The reference has no analogue at any level — it has no KV cache at all
-(/root/reference/Worker1.py:132-134); block-paged KV + this kernel are
-north-star serving scope (vLLM-class HBM discipline, re-designed for
-XLA's static shapes: the table is a plain traced input, admission never
-recompiles).
+Contract (matches `engine/paged.make_paged_hook`'s gather path): the mask
+is derived IN-KERNEL from the positions and the window: a query at
+position p attends logical positions max(0, p - win + 1) .. p. Score
+scale and Gemma-2 softcapping are static kernel parameters. GQA is folded
+into the query-row dimension exactly like ops/flash_attention.py: the
+score matmul of one KV head is [queries x group, Dh] x [Dh, bs].
 
-On non-TPU backends the kernel runs in interpret mode (CPU test suite);
-numerics match the gather path to fp32 tolerance.
+On non-TPU backends the kernels run in interpret mode (the CPU test
+suite); numerics match the gather path to fp32 tolerance.
 """
 
 from __future__ import annotations
@@ -46,7 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import resolve_interpret, scale_column
+from .flash_attention import resolve_interpret
 
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)  # mask fill; avoids inf-inf NaNs
 
@@ -70,79 +76,264 @@ def _live_range(pos_b, *, bs: int, MB: int, win):
     return first, needed
 
 
-def _paged_kernel(
-    table_ref,  # scalar-prefetch [B, MB] int32
-    pos_ref,  # scalar-prefetch [B] int32
+# -- the paged walk: decode rows and mixed query tiles, one kernel body -------
+#
+# The flat query axis of a mixed launch holds every row's tokens back to
+# back at query-tile granularity (a prefill row contributes its chunk, a
+# decode row one token); a per-tile metadata array carries (row, start,
+# length, kind). A decode launch is the same thing with one query per
+# tile. The TPU "Ragged Paged Attention" kernel (PAPERS.md) is the design
+# source; the flash accumulation discipline is shared with
+# ops/flash_attention.py.
+
+RAGGED_PREFILL = 0  # metadata `kind`: a prompt-chunk row (length >= 1)
+RAGGED_DECODE = 1  # metadata `kind`: a single-token decode row
+
+# VMEM one program's working set may take (_heads_per_slab counts it and
+# sizes the KV-head group by it): inside the default scoped limit, 16 MiB
+# on v5e, with room for the query and output blocks' second buffers.
+_WALK_VMEM_BYTES = 12 * 2**20
+
+
+def _ragged_live_range(q_start, q_len, *, bs: int, MB: int, win):
+    """(first, needed) logical-block bounds for a query tile starting at
+    absolute position q_start with q_len valid queries: blocks
+    [first, needed) hold at least one position some query of the tile
+    attends. A tile that holds nothing (q_len == 0) evaluates with an
+    effective length of 1 to keep the clips sane; the kernel walks
+    nothing for it. `win` is a TRACED scalar (<= 0 = full causal)."""
+    last = q_start + jnp.maximum(q_len, 1) - 1
+    needed = jnp.clip(pl.cdiv(last + 1, bs), 1, MB)
+    first = jnp.where(
+        win > 0,
+        jnp.minimum(jnp.maximum(q_start - win + 1, 0) // bs, needed - 1),
+        0,
+    )
+    return first, needed
+
+
+def _heads_per_slab(KV: int, bs: int, Dh: int, itemsize: int, quant: bool,
+                    rows: int) -> int:
+    """KV heads one program folds at a time: the largest divisor of KV
+    whose working set stays inside _WALK_VMEM_BYTES as VMEM tiles it
+    (sublanes 32 / itemsize, 128 lanes). Per head: the K and V slabs, each
+    held twice in the pool's dtype and once in float32, and per query row
+    the float32 query, accumulator, running max and sum, and a block's
+    scores and probabilities."""
+
+    def up(n, m):
+        return -(-n // m) * m
+
+    lanes, toks = up(Dh, 128), up(bs, 128)
+    head = 4 * up(bs, 32 // itemsize) * lanes * itemsize
+    head += 2 * up(bs, 8) * lanes * 4
+    head += up(rows, 8) * 4 * (2 * lanes + 2 * 128 + 3 * toks)
+    if quant:  # a [heads, bs] float32 scale slab beside each int8 slab
+        head += 4 * toks * 4
+    fit = max(1, _WALK_VMEM_BYTES // head)
+    # a [heads, bs] scale slab is cut from [KV, bs] along float32 sublanes
+    step = 8 if quant else 1
+    return max(
+        (d for d in range(1, min(KV, fit) + 1)
+         if KV % d == 0 and (d % step == 0 or d == KV)),
+        default=KV,
+    )
+
+
+def _walk_kernel(
+    meta_ref,  # scalar-prefetch [G, 4] int32: (row, q_start, q_len, kind)
+    table_ref,  # scalar-prefetch [R, MB] int32
     win_ref,  # scalar-prefetch [1] int32: sliding window (<= 0 = full)
-    q_ref,  # [1, 1, 1, group, Dh] VMEM
-    k_ref,  # [1, 1, bs, Dh] VMEM (one physical pool block)
-    v_ref,  # [1, 1, bs, Dh] VMEM
-    *rest,  # quant: (ks_ref, vscale_ref, o_ref, scratch...) else (o_ref, ...)
+    q_ref,  # [1, tq, KVg, group, Dh] VMEM: one query tile, one head group
+    k_hbm,  # [N, KV, bs, Dh] HBM: the layer's pool slice, as scattered
+    v_hbm,
+    *rest,  # quant: (ks_hbm, vs_hbm [N, KV, bs], o_ref, scratch...)
     bs: int,
     MB: int,
+    tq: int,
+    KVg: int,
     group: int,
     scale: float,
     softcap: float | None,
-    quant: bool = False,
+    quant: bool,
 ):
-    del table_ref  # physical placement is the index maps' concern
+    """One program: query tile g (tq queries of one row; a decode slot is
+    a tile of one) against head group hg's KVg KV heads. The walk over
+    the row's live blocks is the fori_loop below; every head of a slab
+    folds in one batched matmul pair. Row r of a head's score tile is
+    (local query t = r // group, query head r % group of the KV head),
+    its absolute position q_start + t."""
     if quant:
-        # int8 pool (ops/kv_quant): per-(token, head) fp32 scales ride as
-        # two extra [1, KV, bs] operands walking the same table
-        # (scale_column picks this head's); dequant in the block prologue —
-        # the table walk streams the int8 bytes, the MXU sees fp32
-        ks_ref, vscale_ref, o_ref, m_ref, l_ref, acc_ref = rest
+        # int8 pool (ops/kv_quant): per-(token, head) fp32 scales walk the
+        # same loop as two more slabs, tokens on lanes
+        (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, m_ref, l_ref, acc_ref, sem,
+         ksbuf, vsbuf) = rest
+        srcs, bufs = (k_hbm, v_hbm, ks_hbm, vs_hbm), (kbuf, vbuf, ksbuf, vsbuf)
     else:
-        ks_ref = vscale_ref = None
-        o_ref, m_ref, l_ref, acc_ref = rest
-    b = pl.program_id(0)
-    kv = pl.program_id(1)
-    j = pl.program_id(2)
-    n_j = pl.num_programs(2)
-    pos_b = pos_ref[b]
+        o_ref, kbuf, vbuf, m_ref, l_ref, acc_ref, sem = rest
+        srcs, bufs = (k_hbm, v_hbm), (kbuf, vbuf)
+    g = pl.program_id(0)
+    hg = pl.program_id(1)
+    row = jnp.maximum(meta_ref[g, 0], 0)
+    q_start = meta_ref[g, 1]
+    q_len = meta_ref[g, 2]  # 0 = the row holds nothing: walk nothing
     win = win_ref[0]
+    rows = tq * group
     Dh = q_ref.shape[-1]
-    first, needed = _live_range(pos_b, bs=bs, MB=MB, win=win)
+    first, needed = _ragged_live_range(q_start, q_len, bs=bs, MB=MB, win=win)
+    needed = jnp.where(q_len > 0, needed, first)
 
-    @pl.when(j == 0)
-    def _():
-        m_ref[:] = jnp.full((group, 1), _NEG, jnp.float32)
-        l_ref[:] = jnp.zeros((group, 1), jnp.float32)
-        acc_ref[:] = jnp.zeros((group, Dh), jnp.float32)
+    m_ref[:] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+    l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    @pl.when((j >= first) & (j < needed))
+    def copies(j, slot):
+        # logical block j of the row -> buffer `slot`: the head group's
+        # slab of the physical block, one contiguous run of HBM per pool
+        blk = table_ref[row, j]
+        return [
+            pltpu.make_async_copy(
+                src.at[blk, pl.ds(hg * KVg, KVg)], buf.at[slot],
+                sem.at[i, slot],
+            )
+            for i, (src, buf) in enumerate(zip(srcs, bufs))
+        ]
+
+    @pl.when(first < needed)
     def _():
-        q = q_ref[0, 0, 0].astype(jnp.float32) * scale  # [group, Dh]
-        ks = k_ref[0, 0].astype(jnp.float32)  # [bs, Dh]
-        vs = v_ref[0, 0].astype(jnp.float32)
-        if quant:
-            ks = ks * scale_column(ks_ref, kv, bs)
-            vs = vs * scale_column(vscale_ref, kv, bs)
+        for c in copies(first, first % 2):
+            c.start()
+
+    # the tile's queries, KV heads first: [KVg, rows, Dh]
+    q = q_ref[0]
+    q = q[0] if tq == 1 else jnp.swapaxes(q, 0, 1)
+    q = q.reshape(KVg, rows, Dh).astype(jnp.float32) * scale
+    t_local = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0) // group
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
+    q_pos = q_start + t_local
+    heads = ((0,), (0,))  # dot_general batch dims: the slab's KV heads
+
+    def fold_block(j, carry):
+        slot = j % 2
+
+        @pl.when(j + 1 < needed)
+        def _():
+            for c in copies(j + 1, 1 - slot):
+                c.start()
+
+        for c in copies(j, slot):
+            c.wait()
+        kv_pos = j * bs + col
+        mask = (t_local < q_len) & (kv_pos <= q_pos)
+        mask &= (win <= 0) | (kv_pos > q_pos - win)
+        ks = kbuf[slot].astype(jnp.float32)  # [KVg, bs, Dh]
+        vs = vbuf[slot].astype(jnp.float32)
         s = jax.lax.dot_general(
-            q, ks, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [group, bs]
+            q, ks, (((2,), (2,)), heads), preferred_element_type=jnp.float32
+        )  # [KVg, rows, bs]
+        if quant:
+            # a token's scale is common to its Dh products, so it scales
+            # the score (and below the probability) with the tokens on
+            # lanes as the slab holds them: q . (k * s) == (q . k) * s
+            s = s * ksbuf[slot, :, pl.ds(0, bs)][:, None, :]
         if softcap is not None:  # Gemma-2 logit capping, pre-mask (HF order)
             s = softcap * jnp.tanh(s / softcap)
-        kv_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (group, bs), 1)
-        mask = kv_pos <= pos_b
-        mask &= (win <= 0) | (kv_pos > pos_b - win)
         s = jnp.where(mask, s, _NEG)
         m_prev, l_prev = m_ref[:], l_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)  # first block: exp(_NEG - _NEG) == 1
         alpha = jnp.exp(m_prev - m_new)
         m_ref[:] = m_new
-        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
+        if quant:
+            p = p * vsbuf[slot, :, pl.ds(0, bs)][:, None, :]
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, vs, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p, vs, (((2,), (1,)), heads), preferred_element_type=jnp.float32
         )
+        return carry
 
-    @pl.when(j == n_j - 1)
-    def _():
-        l = l_ref[:]
-        l = jnp.where(l == 0.0, 1.0, l)  # fully-masked row (never in serving)
-        o_ref[0, 0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
+    jax.lax.fori_loop(first, needed, fold_block, 0)
+
+    l = l_ref[:]
+    l = jnp.where(l == 0.0, 1.0, l)  # padding queries, rows not walked
+    o = (acc_ref[:] / l).astype(o_ref.dtype).reshape(KVg, tq, group, Dh)
+    o_ref[0] = o.reshape(1, KVg, group, Dh) if tq == 1 else o.swapaxes(0, 1)
+
+
+def _lanes(a):
+    """`a` with its minor dimension zero-padded to whole 128-lane tiles.
+    A manual DMA cannot slice an HBM operand whose minor dimension is not
+    (Mosaic: "Slice shape ... must be aligned to tiling (128)"), so a head
+    dim under 128 costs a padded copy of the layer's pool slice per call,
+    and 16- or 32-token blocks one of an int8 pool's scales. The head
+    dims and block size the benchmark's cells run (128, 128) pad nothing.
+    Zero lanes add nothing to a score and their output lanes are cut."""
+    pad = -a.shape[-1] % 128
+    return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)]) if pad else a
+
+
+def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
+                scale, softcap, interpret):
+    """The pallas_call both wrappers share. q [G, tq, H, Dh]: G query
+    tiles of tq queries; meta [G, 4]; returns q's shape and dtype."""
+    from .kv_quant import KVQuant
+
+    quant = isinstance(pool_k, KVQuant)
+    scales = []
+    if quant:
+        scales = [_lanes(pool_k.s), _lanes(pool_v.s)]
+        pool_k, pool_v = pool_k.q, pool_v.q
+    G, tq, H, Dh = q.shape
+    KV, bs = pool_k.shape[1], pool_k.shape[2]
+    group = H // KV
+    MB = table.shape[1]
+    q5 = _lanes(q.reshape(G, tq, KV, group, Dh))
+    pool_k, pool_v = _lanes(pool_k), _lanes(pool_v)
+    Dp = q5.shape[-1]
+    rows = tq * group
+    KVg = _heads_per_slab(KV, bs, Dp, pool_k.dtype.itemsize, quant, rows)
+    if window_dyn is None:
+        win_arr = jnp.full((1,), -1 if window is None else window, jnp.int32)
+    else:
+        win_arr = jnp.reshape(window_dyn.astype(jnp.int32), (1,))
+
+    kernel = functools.partial(
+        _walk_kernel, bs=bs, MB=MB, tq=tq, KVg=KVg, group=group,
+        scale=scale if scale is not None else Dh**-0.5, softcap=softcap,
+        quant=quant,
+    )
+    tile = pl.BlockSpec(
+        (1, tq, KVg, group, Dp),
+        lambda g, hg, meta_ref, table_ref, win_ref: (g, 0, hg, 0, 0),
+    )
+    scratch = [
+        pltpu.VMEM((2, KVg, bs, Dp), pool_k.dtype),
+        pltpu.VMEM((2, KVg, bs, Dp), pool_v.dtype),
+        pltpu.VMEM((KVg, rows, 1), jnp.float32),
+        pltpu.VMEM((KVg, rows, 1), jnp.float32),
+        pltpu.VMEM((KVg, rows, Dp), jnp.float32),
+        pltpu.SemaphoreType.DMA((4 if quant else 2, 2)),
+    ]
+    if quant:
+        scale_slab = pltpu.VMEM((2, KVg) + scales[0].shape[2:], jnp.float32)
+        scratch += [scale_slab, scale_slab]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(G, KV // KVg),
+        in_specs=[tile]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * (2 + len(scales)),
+        out_specs=tile,
+        scratch_shapes=scratch,
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q5.shape, q5.dtype),
+        interpret=interpret,
+    )(meta.astype(jnp.int32), table.astype(jnp.int32), win_arr,
+      q5, pool_k, pool_v, *scales)
+    return out[..., :Dh].reshape(q.shape)
 
 
 @functools.partial(
@@ -155,6 +346,7 @@ def paged_flash_attend(
     table: jnp.ndarray,
     pos: jnp.ndarray,
     window_dyn: jnp.ndarray | None = None,
+    active: jnp.ndarray | None = None,
     *,
     window: int | None = None,
     scale: float | None = None,
@@ -165,9 +357,12 @@ def paged_flash_attend(
 
     q [B,1,H,Dh]; pool_k/v [N,KV,bs,Dh] (one layer's pool slice) — or
     ops/kv_quant.KVQuant leaves (int8 blocks + per-(token, head) fp32
-    scales [N,KV,bs]), dequantized in the block prologue so the table
-    walk streams HALF the bytes per live block; table [B,MB] int32
-    physical block ids; pos [B] int32 per-row positions.
+    scales [N,KV,bs]), dequantized in the block prologue; table [B,MB]
+    int32 physical block ids; pos [B] int32 per-row positions.
+    active: [B] bool, or None for every row live — a row whose flag is
+    false is not walked (no DMA, no loop) and its output is zeros: a freed
+    slot's position stays frozen at its last request's length, and only
+    the caller knows nothing reads its row.
     window: static sliding-window width (None = full causal);
     window_dyn: TRACED scalar override (<= 0 = full) riding as a
     scalar-prefetch operand — per-layer patterns (Gemma-2/3) feed each
@@ -176,88 +371,73 @@ def paged_flash_attend(
     Returns [B,1,H,Dh] in q.dtype — same contract as the gather path in
     engine/paged.make_paged_hook with the mask derived from pos/window.
     """
-    from .kv_quant import KVQuant
-
-    quant = isinstance(pool_k, KVQuant)
-    if quant:
-        pool_k, k_scale = pool_k.q, pool_k.s
-        pool_v, v_scale = pool_v.q, pool_v.s
     B, T, H, Dh = q.shape
     assert T == 1, "paged kernel serves decode steps (T=1) only"
-    KV, bs = pool_k.shape[1], pool_k.shape[2]
-    MB = table.shape[1]
-    group = H // KV
-
-    interpret = resolve_interpret(interpret)
-
-    q5 = q.reshape(B, 1, KV, group, Dh)
-    table = table.astype(jnp.int32)
-    pos = pos.astype(jnp.int32)
-    if window_dyn is None:
-        win_arr = jnp.full((1,), window if window is not None else -1, jnp.int32)
-    else:
-        win_arr = jnp.reshape(window_dyn.astype(jnp.int32), (1,))
-
-    def kv_index(b, kv, j, table_ref, pos_ref, win_ref):
-        # Clamp dead logical blocks (past the causal frontier, or before
-        # a sliding window) to the nearest live one: the PHYSICAL index
-        # then repeats across consecutive dead steps, Pallas skips the
-        # DMA, and the kernel's pl.when gate skips their compute.
-        first, needed = _live_range(
-            pos_ref[b], bs=bs, MB=MB, win=win_ref[0]
-        )
-        return (table_ref[b, jnp.clip(j, first, needed - 1)], kv, 0, 0)
-
-    def scale_index(b, kv, j, table_ref, pos_ref, win_ref):
-        # the quant-scale operands [N, KV, bs]: same table walk, every kv
-        # head in the block (ops/flash_attention.scale_column)
-        return (kv_index(b, kv, j, table_ref, pos_ref, win_ref)[0], 0, 0)
-
-    kernel = functools.partial(
-        _paged_kernel,
-        bs=bs,
-        MB=MB,
-        group=group,
-        scale=scale if scale is not None else Dh**-0.5,
-        softcap=softcap,
-        quant=quant,
+    live = jnp.ones((B,), jnp.int32) if active is None else active
+    meta = jnp.stack(
+        [jnp.arange(B, dtype=jnp.int32), pos.astype(jnp.int32),
+         live.astype(jnp.int32), jnp.full((B,), RAGGED_DECODE, jnp.int32)],
+        axis=1,
     )
-    in_specs = [
-        pl.BlockSpec(
-            (1, 1, 1, group, Dh),
-            lambda b, kv, j, table_ref, pos_ref, win_ref: (b, 0, kv, 0, 0),
-        ),
-        pl.BlockSpec((1, 1, bs, Dh), kv_index),
-        pl.BlockSpec((1, 1, bs, Dh), kv_index),
-    ]
-    operands = [q5, pool_k, pool_v]
-    if quant:
-        in_specs += [
-            pl.BlockSpec((1, KV, bs), scale_index),
-            pl.BlockSpec((1, KV, bs), scale_index),
-        ]
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, KV, MB),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, 1, group, Dh),
-            lambda b, kv, j, table_ref, pos_ref, win_ref: (b, 0, kv, 0, 0),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, Dh), jnp.float32),
-        ],
+    return _paged_walk(
+        q, pool_k, pool_v, table, meta, window, window_dyn, scale=scale,
+        softcap=softcap, interpret=resolve_interpret(interpret),
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, 1, KV, group, Dh), q.dtype),
-        interpret=interpret,
-    )(table, pos, win_arr, *operands)
-    return out.reshape(B, 1, H, Dh)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("interpret", "window", "scale", "softcap")
+)
+def ragged_paged_attend(
+    q: jnp.ndarray,
+    pool_k,
+    pool_v,
+    table: jnp.ndarray,
+    meta: jnp.ndarray,
+    window_dyn: jnp.ndarray | None = None,
+    *,
+    window: int | None = None,
+    scale: float | None = None,
+    softcap: float | None = None,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """Mixed prefill + decode GQA attention over the (already updated)
+    block pool — one launch for rows of ARBITRARY per-row length.
+
+    q [W, H, Dh]: the flat query-token axis — every row's tokens laid out
+    back to back at query-tile granularity (tq = W // meta.shape[0]); a
+    prefill row contributes its chunk, a decode row one token.
+    pool_k/v [N, KV, bs, Dh] (one layer's pool slice) — or
+    ops/kv_quant.KVQuant leaves (int8 blocks + per-(token, head) fp32
+    scales), dequantized in the block prologue.
+    table [R, MB] int32 physical block ids, one row per fleet row.
+    meta [G, 4] int32 per-tile metadata (row, q_start, q_len, kind), the
+    host-built launch plan (engine/paged.build_ragged_meta): q_start is
+    the tile's first ABSOLUTE position, q_len its valid queries (0 =
+    launch-padding tile: not walked, output zeros), kind is
+    RAGGED_PREFILL / RAGGED_DECODE (launch accounting; the math is
+    uniform — a decode row is simply q_len == 1 at its own position).
+    window / window_dyn / scale / softcap: as `paged_flash_attend`.
+    Returns [W, H, Dh] in q.dtype: each query token's attention output
+    over its row's KV prefix (positions 0..q_pos through the block
+    table), which is exactly the bucketed scratch prefill's per-token
+    contract — so one compiled program replaces the whole bucket ladder.
+    A prefill chunk's tiles each walk the row's prefix: the tile size is
+    the scheduler's.
+    """
+    W, H, Dh = q.shape
+    G = meta.shape[0]
+    tq = W // G
+    assert tq * G == W, "flat query axis must be a whole number of tiles"
+    out = _paged_walk(
+        q.reshape(G, tq, H, Dh), pool_k, pool_v, table, meta, window,
+        window_dyn, scale=scale, softcap=softcap,
+        interpret=resolve_interpret(interpret),
+    )
+    return out.reshape(W, H, Dh)
+
+
+# -- the dense slot-fleet cache -------------------------------------------------
 
 
 def _slots_kernel(
@@ -279,22 +459,11 @@ def _slots_kernel(
 ):
     """One (batch row, seq tile) step: ALL kv heads in one MXU matmul.
 
-    The per-(b, kv) variant (`_paged_kernel`) issues KV x S/bk programs of
-    [group, bk] work each; this tile folds every kv head — scores are one
-    [H, KV*bk] matmul (rows = all query heads, columns = every kv head's
-    tile) and a block-diagonal mask kills the cross-head terms: 4x the
-    multiplies on paper, but they ride an MXU that was idling, and the
-    program count drops by KV x.
-
-    Measured on v5e (TinyLlama, 8 x 8192 fleet cache at pos 1024):
-    ~1.08 ms/call vs the XLA einsum's ~1.00 ms at the attention level
-    (bench.py's fleet leg re-measures both every round), and 382 vs 395
-    tok/s inside the full end-to-end fleet decode step — the live-prefix
-    DMA savings do not yet overcome Mosaic pipelining overhead against
-    XLA's fused masked einsum. That is why the serving hook never
-    selects this kernel: decode stays on the XLA path regardless of
-    attn_impl, and this kernel is the baseline future work (splash-style
-    multi-tile pipelining) has to beat.
+    Scores are one [H, KV*bk] matmul (rows = all query heads, columns =
+    every kv head's tile) and a block-diagonal mask kills the cross-head
+    terms. The serving hook does not select this kernel: dense-fleet
+    decode stays on the XLA path whatever attn_impl says; bench.py's
+    fleet leg is its only caller.
     """
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -381,9 +550,7 @@ def flash_attend_slots(
     the shared-scalar-position counterpart (its grid offsets assume one
     frontier for the whole batch; this kernel's are per-row).
 
-    Not reachable from the serving hook: see `_slots_kernel` — on v5e
-    at serving sizes the XLA einsum still edges it out end to end;
-    bench.py's fleet leg tracks the attention-level gap each round.
+    Not reachable from the serving hook: see `_slots_kernel`.
 
     q [B,1,H,Dh] (decode, T=1); cache_k/v [B,KV,S,Dh]; pos [B] int32.
     Returns [B,1,H,Dh] in q.dtype.
@@ -441,251 +608,3 @@ def flash_attend_slots(
         interpret=interpret,
     )(pos, q5, cache_k, cache_v)
     return out.reshape(B, 1, H, Dh)
-
-
-# -- ragged paged attention: mixed prefill + decode rows, one launch ----------
-#
-# The decode kernel above serves exactly one query per row; prefill still
-# climbs a bucket ladder of chunked fills over a contiguous scratch cache
-# that is then scattered into the pool. This kernel collapses both phases
-# into ONE grid: the flat query axis holds every row's tokens back to back
-# (a prefill row contributes its chunk, a decode row contributes one
-# token), a per-tile metadata array carries (row, start, length, kind),
-# and the KV walk reads each tile's placement straight from the block
-# table. Dead tiles (launch padding, or KV blocks past a tile's causal
-# frontier) repeat their neighbour's physical index, so Pallas skips the
-# DMA — padding costs control flow, not HBM bandwidth. The TPU "Ragged
-# Paged Attention" kernel (PAPERS.md) is the design source; the flash
-# accumulation discipline is shared with ops/flash_attention.py.
-
-RAGGED_PREFILL = 0  # metadata `kind`: a prompt-chunk row (length >= 1)
-RAGGED_DECODE = 1  # metadata `kind`: a single-token decode row
-
-
-def _ragged_live_range(q_start, q_len, *, bs: int, MB: int, win):
-    """(first, needed) logical-block bounds for a query tile starting at
-    absolute position q_start with q_len valid queries. Dead tiles
-    (q_len == 0 launch padding) evaluate with an effective length of 1 so
-    their range — and therefore their clamped physical index — equals
-    their predecessor's, which is what lets Pallas skip the DMA
-    entirely (the builder copies the predecessor's row/start into pad
-    tiles). `win` is a TRACED scalar (<= 0 = full causal)."""
-    last = q_start + jnp.maximum(q_len, 1) - 1
-    needed = jnp.clip(pl.cdiv(last + 1, bs), 1, MB)
-    first = jnp.where(
-        win > 0,
-        jnp.minimum(jnp.maximum(q_start - win + 1, 0) // bs, needed - 1),
-        0,
-    )
-    return first, needed
-
-
-def _ragged_kernel(
-    meta_ref,  # scalar-prefetch [G, 4] int32: (row, q_start, q_len, kind)
-    table_ref,  # scalar-prefetch [R, MB] int32
-    win_ref,  # scalar-prefetch [1] int32: sliding window (<= 0 = full)
-    q_ref,  # [1, tq, 1, group, Dh] VMEM (one query tile, one kv head)
-    k_ref,  # [1, 1, bs, Dh] VMEM (one physical pool block)
-    v_ref,  # [1, 1, bs, Dh] VMEM
-    *rest,  # quant: (ks_ref, vscale_ref, o_ref, scratch...) else (o_ref, ...)
-    bs: int,
-    MB: int,
-    tq: int,
-    group: int,
-    scale: float,
-    softcap: float | None,
-    quant: bool = False,
-):
-    del table_ref  # physical placement is the index maps' concern
-    if quant:
-        ks_ref, vscale_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        ks_ref = vscale_ref = None
-        o_ref, m_ref, l_ref, acc_ref = rest
-    g = pl.program_id(0)
-    kv = pl.program_id(1)
-    j = pl.program_id(2)
-    n_j = pl.num_programs(2)
-    q_start = meta_ref[g, 1]
-    q_len = meta_ref[g, 2]  # 0 = dead (launch-padding) tile
-    win = win_ref[0]
-    rows = tq * group
-    Dh = q_ref.shape[-1]
-    first, needed = _ragged_live_range(q_start, q_len, bs=bs, MB=MB, win=win)
-
-    @pl.when(j == 0)
-    def _():
-        m_ref[:] = jnp.full((rows, 1), _NEG, jnp.float32)
-        l_ref[:] = jnp.zeros((rows, 1), jnp.float32)
-        acc_ref[:] = jnp.zeros((rows, Dh), jnp.float32)
-
-    @pl.when((q_len > 0) & (j >= first) & (j < needed))
-    def _():
-        # Row r of the tile is (local query t = r // group, head g = r %
-        # group); its absolute position is q_start + t — the SAME GQA
-        # row-folding as the decode kernel, with tq queries per tile
-        # instead of one.
-        q = q_ref[0].reshape(rows, Dh).astype(jnp.float32) * scale
-        ks = k_ref[0, 0].astype(jnp.float32)  # [bs, Dh]
-        vs = v_ref[0, 0].astype(jnp.float32)
-        if quant:
-            ks = ks * scale_column(ks_ref, kv, bs)
-            vs = vs * scale_column(vscale_ref, kv, bs)
-        s = jax.lax.dot_general(
-            q, ks, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [rows, bs]
-        if softcap is not None:  # Gemma-2 logit capping, pre-mask (HF order)
-            s = softcap * jnp.tanh(s / softcap)
-        t_local = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0) // group
-        q_pos = q_start + t_local
-        kv_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
-        mask = (t_local < q_len) & (kv_pos <= q_pos)
-        mask &= (win <= 0) | (kv_pos > q_pos - win)
-        s = jnp.where(mask, s, _NEG)
-        m_prev, l_prev = m_ref[:], l_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)  # first block: exp(_NEG - _NEG) == 1
-        alpha = jnp.exp(m_prev - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, vs, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    @pl.when(j == n_j - 1)
-    def _():
-        l = l_ref[:]
-        l = jnp.where(l == 0.0, 1.0, l)  # padding rows are fully masked
-        o_ref[0] = (
-            (acc_ref[:] / l).reshape(tq, 1, group, Dh).astype(o_ref.dtype)
-        )
-
-
-@functools.partial(
-    jax.jit, static_argnames=("interpret", "window", "scale", "softcap")
-)
-def ragged_paged_attend(
-    q: jnp.ndarray,
-    pool_k,
-    pool_v,
-    table: jnp.ndarray,
-    meta: jnp.ndarray,
-    window_dyn: jnp.ndarray | None = None,
-    *,
-    window: int | None = None,
-    scale: float | None = None,
-    softcap: float | None = None,
-    interpret: bool | None = None,
-) -> jnp.ndarray:
-    """Mixed prefill + decode GQA attention over the (already updated)
-    block pool — one launch for rows of ARBITRARY per-row length.
-
-    q [W, H, Dh]: the flat query-token axis — every row's tokens laid out
-    back to back at query-tile granularity (tq = W // meta.shape[0]); a
-    prefill row contributes its chunk, a decode row one token.
-    pool_k/v [N, KV, bs, Dh] (one layer's pool slice) — or
-    ops/kv_quant.KVQuant leaves (int8 blocks + per-(token, head) fp32
-    scales), dequantized in the block prologue.
-    table [R, MB] int32 physical block ids, one row per fleet row.
-    meta [G, 4] int32 per-tile metadata (row, q_start, q_len, kind), the
-    host-built launch plan (engine/paged.build_ragged_meta): q_start is
-    the tile's first ABSOLUTE position, q_len its valid queries (0 =
-    launch-padding tile — its row/q_start repeat the predecessor's so the
-    clamped KV index repeats and Pallas skips the DMA), kind is
-    RAGGED_PREFILL / RAGGED_DECODE (launch accounting; the math is
-    uniform — a decode row is simply q_len == 1 at its own position).
-    window / window_dyn / scale / softcap: as `paged_flash_attend`.
-    Returns [W, H, Dh] in q.dtype: each query token's attention output
-    over its row's KV prefix (positions 0..q_pos through the block
-    table), which is exactly the bucketed scratch prefill's per-token
-    contract — so one compiled program replaces the whole bucket ladder.
-    """
-    from .kv_quant import KVQuant
-
-    quant = isinstance(pool_k, KVQuant)
-    if quant:
-        pool_k, k_scale = pool_k.q, pool_k.s
-        pool_v, v_scale = pool_v.q, pool_v.s
-    W, H, Dh = q.shape
-    G = meta.shape[0]
-    tq = W // G
-    assert tq * G == W, "flat query axis must be a whole number of tiles"
-    KV, bs = pool_k.shape[1], pool_k.shape[2]
-    MB = table.shape[1]
-    group = H // KV
-
-    interpret = resolve_interpret(interpret)
-
-    q5 = q.reshape(G, tq, KV, group, Dh)
-    table = table.astype(jnp.int32)
-    meta = meta.astype(jnp.int32)
-    if window_dyn is None:
-        win_arr = jnp.full((1,), window if window is not None else -1, jnp.int32)
-    else:
-        win_arr = jnp.reshape(window_dyn.astype(jnp.int32), (1,))
-
-    def kv_index(g, kv, j, meta_ref, table_ref, win_ref):
-        # Clamp dead logical blocks to the tile's live range; pad tiles
-        # (q_len == 0) share their predecessor's (row, q_start), so their
-        # whole walk repeats the previous tile's physical indices and
-        # Pallas skips every DMA. The kernel's pl.when gate skips the
-        # compute either way.
-        first, needed = _ragged_live_range(
-            meta_ref[g, 1], meta_ref[g, 2], bs=bs, MB=MB, win=win_ref[0]
-        )
-        row = jnp.maximum(meta_ref[g, 0], 0)
-        return (table_ref[row, jnp.clip(j, first, needed - 1)], kv, 0, 0)
-
-    def scale_index(g, kv, j, meta_ref, table_ref, win_ref):
-        # the quant-scale operands [N, KV, bs]: same table walk, every kv
-        # head in the block (ops/flash_attention.scale_column)
-        return (kv_index(g, kv, j, meta_ref, table_ref, win_ref)[0], 0, 0)
-
-    kernel = functools.partial(
-        _ragged_kernel,
-        bs=bs,
-        MB=MB,
-        tq=tq,
-        group=group,
-        scale=scale if scale is not None else Dh**-0.5,
-        softcap=softcap,
-        quant=quant,
-    )
-    rows = tq * group
-    in_specs = [
-        pl.BlockSpec(
-            (1, tq, 1, group, Dh),
-            lambda g, kv, j, meta_ref, table_ref, win_ref: (g, 0, kv, 0, 0),
-        ),
-        pl.BlockSpec((1, 1, bs, Dh), kv_index),
-        pl.BlockSpec((1, 1, bs, Dh), kv_index),
-    ]
-    operands = [q5, pool_k, pool_v]
-    if quant:
-        in_specs += [
-            pl.BlockSpec((1, KV, bs), scale_index),
-            pl.BlockSpec((1, KV, bs), scale_index),
-        ]
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(G, KV, MB),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, tq, 1, group, Dh),
-            lambda g, kv, j, meta_ref, table_ref, win_ref: (g, 0, kv, 0, 0),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, Dh), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((G, tq, KV, group, Dh), q.dtype),
-        interpret=interpret,
-    )(meta, table, win_arr, *operands)
-    return out.reshape(W, H, Dh)

@@ -1211,13 +1211,13 @@ class PipelineBackend(SPMDBackendBase):
 
         def body(shared, layers, state, pool, table, key, sparams, *extra):
             pages = extra[0] if with_pages else None
-            hook = EP.make_paged_hook(table)
             bs = pool["k"].shape[3]
             MB = table.shape[1]
             s = jax.lax.axis_index(AXIS_PP)
 
             def step(carry, sub):
                 state, pool = carry
+                hook = EP.make_paged_hook(table, state.active)
                 x = embed_sharded(
                     cfg, shared, state.token[:, None], state.pos, S
                 )

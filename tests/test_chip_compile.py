@@ -145,6 +145,53 @@ def test_ragged_paged_attend_compiles(one_chip, no_persistent_cache, bs, tq,
     assert "tpu_custom_call" in text
 
 
+# The benchmark's two configurations as their cells serve them
+# (cellbench/configs/*.json): slots, query heads, KV heads, head dim, block
+# size, table width, pool blocks, window.
+CELL_SHAPES = {
+    "olmo2-7b-16l": (12, 32, 32, 128, 128, 16, 61, None),
+    "mistral-7b-16l": (16, 32, 8, 128, 128, 50, 271, 4096),
+}
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_paged_flash_attend_compiles_at_cell_shapes(
+    one_chip, no_persistent_cache, cell, quant
+):
+    """The pure-decode chunk's kernel, with the slots' active mask."""
+    slots, h, kv, dh, bs, mb, blocks, window = CELL_SHAPES[cell]
+    S = _spec(one_chip)
+    pool = _kv(S, (blocks, kv, bs, dh), quant)
+    text = _compile(
+        functools.partial(paged_flash_attend, interpret=False, window=window),
+        S((slots, 1, h, dh), jnp.bfloat16), pool, pool,
+        S((slots, mb), jnp.int32), S((slots,), jnp.int32), None,
+        S((slots,), jnp.bool_),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_ragged_paged_attend_compiles_at_cell_shapes(
+    one_chip, no_persistent_cache, cell, quant
+):
+    """The mixed step's kernel: max(128, (slots + 1) x 8) flat tokens in
+    query tiles of 8 (16 tiles for olmo2's 12 slots, 17 for mistral's 16)."""
+    slots, h, kv, dh, bs, mb, blocks, window = CELL_SHAPES[cell]
+    S = _spec(one_chip)
+    pool = _kv(S, (blocks, kv, bs, dh), quant)
+    tq = 8
+    tiles = max(128, (slots + 1) * tq) // tq
+    text = _compile(
+        functools.partial(ragged_paged_attend, interpret=False, window=window),
+        S((tiles * tq, h, dh), jnp.bfloat16), pool, pool,
+        S((slots, mb), jnp.int32), S((tiles, 4), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
 def test_flash_attend_slots_compiles(one_chip, no_persistent_cache):
     S = _spec(one_chip)
     cache = S((SLOTS, KV, 2048, DH), jnp.bfloat16)

@@ -165,6 +165,109 @@ def test_walked_kv_tokens_are_the_grid(runs):
             snap, kv, phase=phase, state="walked")
 
 
+# -- (a2) under the paged kernels the walk is counted, not the grid -------------
+
+@pytest.fixture(scope="module")
+def walk_run(setup):
+    """The same list through the Pallas kernels (interpreted here): the
+    block walk decides `kv_grid_tokens`, and inactive slots walk nothing."""
+    cfg, params = setup
+    return _serve(cfg.replace(attn_impl="pallas"), params)
+
+
+def _walk(start, n=1):
+    """By hand: the whole blocks between the first position the tile's
+    first query attends and the last position its last query attends."""
+    needed = min(-(-(start + n) // BLOCK), -(-MAX_SEQ // BLOCK))
+    first = min(max(start - WINDOW + 1, 0) // BLOCK, needed - 1)
+    return (needed - first) * BLOCK
+
+
+def test_walked_kv_tokens_are_the_live_blocks(walk_run):
+    """A prompt's chunk is walked once per 8-token query tile, a decode
+    step once; a slot that holds no row, and a row whose budget ran out
+    inside a chunk, are not walked at all."""
+    run = walk_run
+    W, snap = run["width"], run["snap"]
+    mixed = chunk = 0
+    for (_, answer), r in zip(REQUESTS, run["results"]):
+        P = r["prompt_tokens"]
+        assert r["tokens_generated"] == answer, r
+        for c in range(math.ceil(P / W)):
+            end = min(P, (c + 1) * W)
+            mixed += sum(_walk(t, min(8, end - t)) for t in range(c * W, end, 8))
+        chunk += sum(_walk(n) for n in range(P, P + answer - 1))
+    kv = "dli_attn_kv_tokens_total"
+    assert _value(snap, kv, phase="mixed", state="walked") == mixed
+    assert _value(snap, kv, phase="chunk", state="walked") == chunk
+    # the attended counts do not depend on which path reads the pool
+    want = _expected(run)
+    assert _value(snap, kv, phase="mixed", state="attended") == want["attended_mixed"]
+    assert _value(snap, kv, phase="chunk", state="attended") == want["attended_chunk"]
+    # a decode step walks at most the window rounded out to whole blocks
+    assert want["attended_chunk"] <= chunk <= (
+        want["attended_chunk"] + 2 * BLOCK * want["row_steps"])
+    row = -(-MAX_SEQ // BLOCK) * BLOCK
+    chunks = _value(snap, "dli_ragged_launches_total", phase="chunk")
+    assert chunk < chunks * SLOTS * CHUNK_STEPS * row / 4  # the old grid
+
+
+def test_launch_records_walk_no_less_than_they_attend(walk_run):
+    """Per record: kv_tokens <= kv_grid_tokens, and no more than each live
+    tile's window rounded out to whole blocks."""
+    ev = [e for e in walk_run["flight"] if e["kind"] == "plan"]
+    assert len(ev) == 7
+    for e in ev:
+        assert 0 < e["kv_tokens"] <= e["kv_grid_tokens"]
+        assert e["kv_grid_tokens"] <= e["tiles_live"] * (WINDOW + 2 * BLOCK)
+        assert e["kv_grid_tokens"] < e["tiles"] * MAX_SEQ
+
+
+@pytest.mark.parametrize("window", [None, 1, 5, 48, 100, 4096])
+@pytest.mark.parametrize("bs,mb", [(8, 4), (16, 16), (32, 3), (128, 50)])
+def test_host_walk_is_the_kernels_live_range(bs, mb, window):
+    """`_kv_walk` (numpy, the launch record) against `_live_range` and
+    `_ragged_live_range` (the kernels' loop bounds) on a sweep of
+    positions and tile lengths, past the table's end included."""
+    import types
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_llm_inference_tpu.ops.paged_attention import (
+        _live_range, _ragged_live_range,
+    )
+
+    host = types.SimpleNamespace(
+        _kv_walks=True, kv_block_size=bs, _max_blocks=mb, _kv_window=window,
+        _scratch_seq=bs * mb,
+    )
+    pos = np.arange(0, bs * mb + bs + 3)
+    win = jnp.int32(window if window is not None else -1)
+    first, needed = _live_range(jnp.asarray(pos), bs=bs, MB=mb, win=win)
+    np.testing.assert_array_equal(
+        ContinuousEngine._kv_walk(host, pos),
+        (np.asarray(needed) - np.asarray(first)) * bs,
+    )
+    for n in (1, 3, 8):
+        first, needed = _ragged_live_range(
+            jnp.asarray(pos), jnp.int32(n), bs=bs, MB=mb, win=win
+        )
+        walked = ContinuousEngine._kv_walk(host, pos, n)
+        np.testing.assert_array_equal(
+            walked, (np.asarray(needed) - np.asarray(first)) * bs
+        )
+        span = pos + n if window is None else np.minimum(pos + n, window)
+        inside = pos + n <= bs * mb  # the engine never launches past it
+        assert np.all(walked[inside] >= span[inside])
+        assert np.all(walked <= bs * mb)
+    # a tile that holds no query (launch padding, a freed slot) walks
+    # nothing; the gather path reads every row's whole table, live or not
+    assert np.all(ContinuousEngine._kv_walk(host, pos, 0) == 0)
+    host._kv_walks = False
+    assert np.all(ContinuousEngine._kv_walk(host, pos, 0) == bs * mb)
+
+
 # -- (b) both kinds of launch are counted; the old series keep their values ----
 
 def test_chunk_launches_and_row_steps_are_counted(runs):

@@ -244,6 +244,106 @@ def _gather_attend(q, pool_k, pool_v, table, pos, window=None):
     return attend(q, gk, gv, mask)
 
 
+def _walk_case(seed=5, quant=False):
+    """A scattered pool, three slots' tables with trash-block tails, and
+    one query per slot (GQA 8 / 2)."""
+    from distributed_llm_inference_tpu.ops.kv_quant import (
+        KVQuant, quantize_chunk,
+    )
+
+    B, H, KV, Dh, bs, MB, N = 4, 8, 2, 16, 8, 4, 16
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (B, 1, H, Dh), jnp.float32)
+    pool_k = jax.random.normal(ks[1], (N, KV, bs, Dh), jnp.float32)
+    pool_v = jax.random.normal(ks[2], (N, KV, bs, Dh), jnp.float32)
+    if quant:
+        qk, sk = quantize_chunk(pool_k.transpose(0, 2, 1, 3))
+        qv, sv = quantize_chunk(pool_v.transpose(0, 2, 1, 3))
+        pool_k = KVQuant(qk.transpose(0, 2, 1, 3), sk.transpose(0, 2, 1))
+        pool_v = KVQuant(qv.transpose(0, 2, 1, 3), sv.transpose(0, 2, 1))
+    table = jnp.asarray(
+        [[5, 2, 7, 12], [1, 9, 0, 0], [11, 4, 6, 3], [0, 0, 0, 0]], jnp.int32
+    )
+    return q, pool_k, pool_v, table, bs, MB
+
+
+# (positions, static window, window_dyn, softcap, int8 pool, active mask):
+# every row of `table` but the last is a live slot; the last is a freed
+# slot whose row is all trash blocks
+_BS, _MB = 8, 4
+WALK_CASES = {
+    "pos-0": ([0, 0, 0, 0], None, None, None, False, None),
+    "pos-block-minus-1": ([_BS - 1] * 4, None, None, None, False, None),
+    "pos-block": ([_BS] * 4, None, None, None, False, None),
+    "pos-table-end": ([_MB * _BS - 1, 2 * _BS - 1, _MB * _BS - 1, 0],
+                      None, None, None, False, None),
+    "pos-mixed": ([11, 7, 30, 3], None, None, None, False, None),
+    "window-first-block-dead": ([29, 15, 31, 3], 9, None, None, False, None),
+    "window-one-block": ([29, 15, 31, 3], 3, None, None, False, None),
+    "window-dyn": ([29, 15, 31, 3], None, 9, None, False, None),
+    "window-dyn-full": ([29, 15, 31, 3], None, -1, None, False, None),
+    "softcap": ([11, 7, 30, 3], None, None, 5.0, False, None),
+    "softcap-window": ([29, 15, 31, 3], 13, None, 9.0, False, None),
+    "int8": ([11, 7, 30, 3], None, None, None, True, None),
+    "int8-window": ([29, 15, 31, 3], 9, None, None, True, None),
+    "inactive-slot": ([11, 7, 30, 3], None, None, None, False,
+                      [True, False, True, True]),
+    "freed-slot-trash-row": ([11, 7, 30, 31], None, None, None, False,
+                             [True, True, True, False]),
+    "freed-slot-trash-row-int8-window": ([11, 7, 30, 31], 9, None, None, True,
+                                         [True, True, True, False]),
+    "all-inactive": ([11, 7, 30, 3], None, None, None, False, [False] * 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_paged_kernel_walk_matches_gather(case):
+    """Kernel-level: the block walk == gather + attend on every live row;
+    a row whose active flag is false is not walked (its output, which the
+    caller discards, is zeros) and leaves the live rows' outputs as they
+    are without the mask."""
+    from distributed_llm_inference_tpu.ops.attention import (
+        attend, slot_causal_mask,
+    )
+    from distributed_llm_inference_tpu.ops.kv_quant import KVQuant, dequantize
+    from distributed_llm_inference_tpu.ops.paged_attention import (
+        paged_flash_attend,
+    )
+
+    pos, window, dyn, softcap, quant, active = WALK_CASES[case]
+    q, pool_k, pool_v, table, bs, MB = _walk_case(quant=quant)
+    assert (bs, MB) == (_BS, _MB)
+    pos = jnp.asarray(pos, jnp.int32)
+    got = np.asarray(paged_flash_attend(
+        q, pool_k, pool_v, table, pos,
+        None if dyn is None else jnp.int32(dyn),
+        None if active is None else jnp.asarray(active),
+        window=window, softcap=softcap, interpret=True,
+    ))
+
+    def view(leaf):
+        if isinstance(leaf, KVQuant):
+            leaf = dequantize(leaf)
+        B, KV, Dh = table.shape[0], leaf.shape[1], leaf.shape[-1]
+        return leaf[table].transpose(0, 2, 1, 3, 4).reshape(B, KV, MB * bs, Dh)
+
+    w = window if dyn is None else (dyn if dyn > 0 else None)
+    want = np.asarray(attend(
+        q, view(pool_k), view(pool_v), slot_causal_mask(pos, 1, MB * bs, w),
+        softcap=softcap,
+    ))
+    live = np.ones(len(pos), bool) if active is None else np.asarray(active)
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+    assert np.all(got[~live] == 0.0)
+    if active is not None:
+        unmasked = np.asarray(paged_flash_attend(
+            q, pool_k, pool_v, table, pos,
+            None if dyn is None else jnp.int32(dyn),
+            window=window, softcap=softcap, interpret=True,
+        ))
+        np.testing.assert_array_equal(got[live], unmasked[live])
+
+
 @pytest.mark.parametrize("window", [None, 21])
 @pytest.mark.slow
 def test_paged_kernel_matches_gather(window):

@@ -112,6 +112,84 @@ def test_ragged_kernel_matches_dense_reference(quant):
         )
 
 
+# Hand-built tile plans over _mixed_case's pool (bs 8, MB 4, tile 4):
+# (row, q_start, q_len) per tile, q_len 0 = a tile that holds nothing.
+# Pad tiles sit between and after live ones, with their predecessor's
+# placement (as build_ragged_meta writes them) or with a row of their own.
+RAGGED_WALK_CASES = {
+    "pads-between-and-after": (
+        [(0, 5, 4), (0, 9, 0), (1, 20, 1), (1, 20, 0), (3, 9, 1), (3, 9, 0),
+         (3, 9, 0), (2, 0, 4)], None, None, False),
+    "pads-first": (
+        [(0, 0, 0), (0, 0, 0), (2, 0, 4), (2, 4, 2), (1, 31, 1), (3, 7, 1),
+         (3, 8, 1), (0, 0, 1)], None, None, False),
+    "only-pads": ([(0, 5, 0)] * 8, None, None, False),
+    "block-edges": (
+        [(0, 0, 1), (1, 7, 1), (2, 8, 1), (3, 31, 1), (0, 4, 4), (1, 28, 4),
+         (2, 6, 4), (3, 0, 0)], None, None, False),
+    "window-first-block-dead": (
+        [(0, 14, 4), (1, 20, 1), (1, 20, 0), (3, 31, 1), (2, 28, 4),
+         (2, 28, 0), (0, 9, 1), (0, 9, 0)], 7, None, False),
+    "window-dyn": (
+        [(0, 14, 4), (1, 20, 1), (1, 20, 0), (3, 31, 1), (2, 28, 4),
+         (2, 28, 0), (0, 9, 1), (0, 9, 0)], None, 7, False),
+    "softcap": (
+        [(0, 5, 4), (0, 9, 0), (1, 20, 1), (1, 20, 0), (3, 9, 1), (3, 9, 0),
+         (3, 9, 0), (2, 0, 4)], None, None, False, 5.0),
+    "int8-pads-between": (
+        [(0, 5, 4), (0, 9, 0), (1, 20, 1), (1, 20, 0), (3, 9, 1), (3, 9, 0),
+         (3, 9, 0), (2, 0, 4)], None, None, True),
+    "int8-window": (
+        [(0, 14, 4), (1, 20, 1), (1, 20, 0), (3, 31, 1), (2, 28, 4),
+         (2, 28, 0), (0, 9, 1), (0, 9, 0)], 7, None, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RAGGED_WALK_CASES))
+def test_ragged_kernel_walk_matches_dense_reference(case):
+    """Every live query == the dense reference at its position; a tile
+    that holds nothing is not walked and writes zeros; queries past a
+    tile's q_len are padding (fully masked: zeros)."""
+    tiles, window, dyn, quant, *rest = RAGGED_WALK_CASES[case]
+    softcap = rest[0] if rest else None
+    (pool_k, pool_v, table, _, _, _, _, _, _, q, bs, MB, KV, Dh) = \
+        _mixed_case(seed=3, quant=quant)
+    tile = 4
+    assert q.shape[0] == len(tiles) * tile
+    meta = np.asarray(
+        [(r, s, n, RAGGED_DECODE if n == 1 else RAGGED_PREFILL)
+         for r, s, n in tiles], np.int32,
+    )
+    out = np.asarray(ragged_paged_attend(
+        q, pool_k, pool_v, table, jnp.asarray(meta),
+        None if dyn is None else jnp.int32(dyn),
+        window=window, softcap=softcap, interpret=True,
+    ))
+    w = window if dyn is None else dyn
+
+    def view(leaf, row):
+        g = dequantize(KVQuant(leaf.q[table[row]], leaf.s[table[row]])) \
+            if isinstance(leaf, KVQuant) else leaf[table[row]]
+        return g.transpose(1, 0, 2, 3).reshape(1, KV, MB * bs, Dh)
+
+    for g, (row, start, n) in enumerate(tiles):
+        got = out[g * tile : (g + 1) * tile]
+        assert np.all(got[n:] == 0.0), (case, g)
+        if n == 0:
+            continue
+        positions = np.arange(start, start + n)
+        kv_pos = np.arange(MB * bs)
+        mask = kv_pos[None, :] <= positions[:, None]
+        if w is not None:
+            mask &= kv_pos[None, :] > positions[:, None] - w
+        ref = attend(
+            q[g * tile : g * tile + n][None], view(pool_k, row),
+            view(pool_v, row), jnp.asarray(mask)[None], softcap=softcap,
+        )[0]
+        np.testing.assert_allclose(got[:n], np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5, err_msg=f"{case} {g}")
+
+
 def test_ragged_kernel_sliding_window():
     (pool_k, pool_v, table, entries, meta, tok_row, tok_pos, offs, stats,
      q, bs, MB, KV, Dh) = _mixed_case()
