@@ -25,7 +25,7 @@ class ModelConfig:
     """
 
     name: str = "tinyllama-1.1b"
-    arch: str = "llama"  # "llama" | "gpt2"
+    arch: str = "llama"  # "llama" | "gpt2" | "mla_moe"
     vocab_size: int = 32000
     dim: int = 2048
     n_layers: int = 22
@@ -109,6 +109,26 @@ class ModelConfig:
     n_experts: int = 0
     n_experts_per_tok: int = 2
     tie_embeddings: bool = False
+    # Latent attention + routed experts (arch "mla_moe", models/mla_moe.py:
+    # the DeepSeek-V3 block as kanana-2-30b-a3b publishes it). The cache
+    # holds one row [c | k_r] of kv_lora_rank + qk_rope_head_dim numbers
+    # a token and layer; attention reads it in absorbed form. head_dim is
+    # the published one (the rope part); n_kv_heads the published count,
+    # which the latent cache does not use. ffn_dim is the width of the
+    # first_k_dense leading dense layers.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # n_experts routed experts of width moe_ffn_dim, n_experts_per_tok a
+    # token, chosen by sigmoid(score) + selection bias and weighed by the
+    # scores alone (renormalized under moe_renormalize, times
+    # routed_scaling); n_shared_experts fused into one SwiGLU of width
+    # n_shared_experts * moe_ffn_dim that every token takes.
+    moe_ffn_dim: int = 0
+    n_shared_experts: int = 0
+    first_k_dense: int = 0
+    routed_scaling: float = 1.0
     # GPT-2 only: learned absolute position embeddings.
     use_learned_pos: bool = False
     dtype: str = "float32"  # parameter / activation dtype: "float32" | "bfloat16"
@@ -233,8 +253,21 @@ class ModelConfig:
                 f"n_heads ({self.n_heads}) must be divisible by n_kv_heads "
                 f"({self.n_kv_heads})"
             )
+        if self.arch == "mla_moe":
+            if min(self.kv_lora_rank, self.qk_nope_head_dim,
+                   self.qk_rope_head_dim, self.v_head_dim) < 1:
+                raise ValueError(
+                    "arch 'mla_moe' needs kv_lora_rank, qk_nope_head_dim, "
+                    "qk_rope_head_dim and v_head_dim"
+                )
+            if not (self.n_experts and self.moe_ffn_dim
+                    and 0 <= self.first_k_dense < self.n_layers):
+                raise ValueError(
+                    "arch 'mla_moe' needs n_experts, moe_ffn_dim and "
+                    "first_k_dense < n_layers (an expert stack)"
+                )
         if self.n_experts:
-            if self.arch != "llama":
+            if self.arch not in ("llama", "mla_moe"):
                 raise ValueError("MoE (n_experts > 0) is llama-family only")
             if not 1 <= self.n_experts_per_tok <= self.n_experts:
                 raise ValueError(
@@ -245,6 +278,18 @@ class ModelConfig:
     @property
     def head_dim(self) -> int:
         return self.head_dim_override or self.dim // self.n_heads
+
+    @property
+    def latent_dim(self) -> int:
+        """Numbers of one latent cache row [c | k_r] (0: a per-head K/V
+        cache)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_row(self) -> int:
+        """The row as the pool stores it: whole 128-lane tiles, zero pad
+        (a manual DMA cannot cut a tile, ops/paged_attention._lanes)."""
+        return -(-self.latent_dim // 128) * 128
 
     @property
     def all_stop_ids(self) -> tuple:
@@ -258,7 +303,10 @@ class ModelConfig:
         direct multiplier)."""
         if self.attn_scale_override is not None:
             return float(self.attn_scale_override)
-        base = self.query_scale_override or self.head_dim
+        base = self.query_scale_override or (
+            self.qk_nope_head_dim + self.qk_rope_head_dim
+            if self.arch == "mla_moe" else self.head_dim
+        )
         return float(base) ** -0.5
 
     @property

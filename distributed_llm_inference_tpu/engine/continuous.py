@@ -295,10 +295,15 @@ class ContinuousEngine:
         restore_dir: Optional[str] = None,
     ):
         cfg = engine.cfg
-        if cfg.arch not in ("llama", "gpt2"):
+        if cfg.arch not in ("llama", "gpt2", "mla_moe"):
             raise ValueError(
-                f"continuous batching supports the llama and gpt2 families; "
-                f"model arch is {cfg.arch!r}"
+                f"continuous batching supports the llama, gpt2 and mla_moe "
+                f"families; model arch is {cfg.arch!r}"
+            )
+        if cfg.latent_dim and kv_pool_blocks is None:
+            raise ValueError(
+                f"{cfg.name}: a latent-attention model's fleet is paged "
+                f"(pass kv_pool_blocks): there is no dense latent fleet"
             )
         if not getattr(engine.backend, "supports_slots", False):
             raise ValueError(
@@ -631,6 +636,12 @@ class ContinuousEngine:
         use_shadow = (
             engine.engine_cfg.kv_shadow if kv_shadow is None else kv_shadow
         )
+        if self.paged:
+            # a latent pool (models/mla_moe.py) carries neither of these
+            self._P.refuse_unsupported_latent(
+                cfg, kv_shadow=use_shadow and self._bpx is not None,
+                bucketed=not self._chunked,
+            )
         if (
             self.paged and use_shadow and self._bpx is not None
             and hasattr(self.backend, "gather_shadow_blocks")
@@ -933,6 +944,25 @@ class ContinuousEngine:
             "the launch's rows need (host position model, window-"
             "clipped), walked = what the kernels' block loops cover",
             ("phase", "state"),
+        )
+        # routed experts (a latent pool's "routed" leaf, models/mla_moe.py):
+        # its shape [2, expert layers, experts], or None for a model
+        # without them. The counts ride each launch's packed fetch.
+        routed = self.cache.get("routed") if self.paged else None
+        self._routed_shape = None if routed is None else tuple(routed.shape)
+        self._m_moe_tokens = m.counter(
+            "dli_moe_expert_tokens_total",
+            "token-expert pairs the routed experts computed", ("phase",),
+        )
+        self._m_moe_touched = m.counter(
+            "dli_moe_experts_touched_total",
+            "experts that got at least one token, summed over expert "
+            "layers and scheduler steps", ("phase",),
+        )
+        self._m_moe_slots = m.counter(
+            "dli_moe_expert_slots_total",
+            "expert layers x experts x scheduler steps launched: what "
+            "dli_moe_experts_touched_total is a share of", ("phase",),
         )
         self._m_steps_ahead = m.histogram(
             "dli_launch_steps_ahead",
@@ -2893,6 +2923,8 @@ class ContinuousEngine:
                 )
             )
         packed = G.pack_chunk(emitted, mask, self.state.active)
+        if self._routed_shape is not None:
+            packed = self._P.pack_routed(packed, self.cache["routed"])
         t_launch = self._clock.mark("plan")
         if self._trace_rate > 0.0:
             self._prof_note_launch(t_launch, snapshot, rec)
@@ -4518,7 +4550,27 @@ class ContinuousEngine:
             "fetch_wait", f"fetch.{rec['phase']}", seq=rec["seq"]
         )
         packed = np.asarray(packed_dev)
-        now = self._clock.mark("distribute")
+        routed = {}
+        if self._routed_shape is not None:
+            # what the launch's expert layers routed came in the same
+            # array: it closes the record and rides the span that follows
+            # the fetch, with the launch's seq
+            packed, counts = self._P.unpack_routed(packed, self._routed_shape)
+            phase = rec["phase"]
+            rec["moe_pairs"] = int(counts[0].sum())
+            rec["moe_experts_touched"] = int(counts[1].sum())
+            slots = counts[1].size * rec["steps"]
+            self._m_moe_tokens.labels(phase=phase).inc(rec["moe_pairs"])
+            self._m_moe_touched.labels(phase=phase).inc(
+                rec["moe_experts_touched"]
+            )
+            self._m_moe_slots.labels(phase=phase).inc(slots)
+            routed = {
+                "seq": rec["seq"], "moe_pairs": rec["moe_pairs"],
+                "moe_experts_touched": rec["moe_experts_touched"],
+                "moe_expert_slots": slots,
+            }
+        now = self._clock.mark("distribute", **routed)
         self._steps_inflight -= rec["steps"]
         self._m_step.observe(max(0.0, now - t_launch) / rec["steps"])
         return packed

@@ -180,7 +180,7 @@ class SingleDeviceBackend:
     # routes through llama.default_attn_hook since round 5).
     @property
     def supports_paged(self):
-        return self.cfg.arch in ("llama", "gpt2")
+        return self.cfg.arch in ("llama", "gpt2", "mla_moe")
 
     def init_paged_pool(self, n_blocks, block_size):
         from . import paged as P

@@ -86,7 +86,27 @@ def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
     plus per-(token, head) scales [L, N, KV, bs] — so BOTH HBM levers
     compose: the pool tracks in-flight tokens AND each token costs half
     the bytes. n_layers overrides the layer count (the pp mesh pads the
-    layer axis to ceil(L/pp)*pp, matching the padded stacked layers)."""
+    layer axis to ceil(L/pp)*pp, matching the padded stacked layers).
+
+    A latent-attention model (cfg.latent_dim > 0, models/mla_moe.py) has
+    no K/V pair: its pool holds ONE row [c | k_r | zero pad] of
+    cfg.latent_row numbers a token and layer, in the same block layout
+    with one shared "head", one leaf a layer stack:
+        "dense", "moe"  [L_stack, n_blocks, 1, block_size, latent_row]
+    Allocator, block tables and prefix digests do not see the difference.
+    The "routed" leaf [2, L_moe, E] int32 is not cache: the step programs
+    zero it and the expert layers add what they routed, so the counts
+    leave the device in the launch's one packed fetch (pack_routed)."""
+    if cfg.latent_dim:
+        from ..models.mla_moe import stack_depths
+
+        Ld, Lm = stack_depths(cfg)
+        row = (n_blocks, 1, block_size, cfg.latent_row)
+        return {
+            "dense": jnp.zeros((Ld,) + row, cfg.jnp_dtype),
+            "moe": jnp.zeros((Lm,) + row, cfg.jnp_dtype),
+            "routed": jnp.zeros((2, Lm, cfg.n_experts), jnp.int32),
+        }
     shape = (
         n_layers or cfg.n_layers, n_blocks, cfg.n_kv_heads, block_size,
         cfg.head_dim,
@@ -254,6 +274,78 @@ def blocks_needed(prompt_len: int, max_tokens: int, block_size: int) -> int:
     return -(-(prompt_len + max_tokens) // block_size)
 
 
+def pool_block_size(pool) -> int:
+    """Tokens a block of the pool holds (either layout)."""
+    return (pool["k"] if "k" in pool else pool["moe"]).shape[3]
+
+
+def refuse_unsupported_latent(cfg: ModelConfig, **asked):
+    """The ONE start-up check of what a latent pool does not carry. Each
+    caller passes what it knows (runtime.create_backend: quant, kv_quant,
+    mesh, lora, adapter_slots; the continuous engine: kv_shadow,
+    ragged); a per-head K/V model passes through."""
+    if not cfg.latent_dim:
+        return
+    why = {
+        "quant": "weight quantization: ops/quant knows no expert-bank or "
+                 "latent-projection leaf",
+        "kv_quant": "the int8 pool: a latent row has no per-head scale",
+        "mesh": "pp / tp / ep / sp / dp meshes: two layer stacks and a "
+                "latent pool are not partitioned (parallel/partition.py)",
+        "lora": "LoRA merge: models/lora.py knows the llama leaves only",
+        "adapter_slots": "runtime adapters: llama family only",
+        "kv_shadow": "the host shadow store (and swap preemption, "
+                     "/kv export): it copies K/V block pairs; pass "
+                     "--no-kv-shadow",
+        "bucketed": "the bucketed scratch prefill: a latent model is "
+                    "served by ragged chunked prefill only",
+    }
+    bad = [why[name] for name, value in asked.items() if value]
+    if bad:
+        raise ValueError(
+            f"{cfg.name}: a latent-attention model is served on one device "
+            f"from a latent pool, which does not carry: " + "; ".join(bad)
+        )
+
+
+def _routed_reset(pool):
+    """A step program's first act on a latent pool: zero what the last
+    launch routed. A per-head pool passes through untouched."""
+    if "routed" not in pool:
+        return pool
+    return {**pool, "routed": jnp.zeros_like(pool["routed"])}
+
+
+def _pack_rows(packed, routed):
+    pad = -routed.size % packed.shape[1]
+    flat = jnp.pad(routed.reshape(-1), (0, pad))
+    return jnp.concatenate([packed, flat.reshape(-1, packed.shape[1])], axis=0)
+
+
+@jax.jit
+def pack_routed(packed, routed):
+    """The launch's packed int32 fetch [rows, B] with the routed counts
+    [2, L_moe, E] appended as further rows (zero-padded to whole rows):
+    they reach the host in the ONE blocking fetch, never a second."""
+    return _pack_rows(packed, routed)
+
+
+def unpack_routed(packed, shape):
+    """Host side (numpy): (the launch's own rows, routed [2, L_moe, E])."""
+    B = packed.shape[1]
+    size = shape[0] * shape[1] * shape[2]
+    rows = -(-size // B)
+    return (packed[:-rows],
+            packed[-rows:].reshape(-1)[:size].reshape(shape))
+
+
+def _latent_rows(pool_c, table):
+    """A table's blocks of one layer's latent pool slice [N, 1, bs, R] as
+    contiguous rows [..., MB * bs, R] (the gather path)."""
+    g = pool_c[table][..., 0, :, :]  # [..., MB, bs, R]
+    return g.reshape(g.shape[:-3] + (-1, g.shape[-1]))
+
+
 def make_paged_hook(table: jnp.ndarray, active=None):
     """attn_hook for models/llama.decoder_layer over a paged pool.
 
@@ -297,6 +389,23 @@ def make_paged_hook(table: jnp.ndarray, active=None):
             # discard as the dense pipeline's gated cache writes.
             blk = jnp.where(update_gate, blk, TRASH_BLOCK)
         off = pos % bs
+        if v is None:
+            # latent pool (models/mla_moe.py): k is the token's one row,
+            # q the absorbed queries; same scatter, the walk in latent
+            # form or the gathered rows the slow way
+            from ..models.mla_moe import latent_attend
+
+            new_c = cache_k.at[blk, :, off, :].set(k[:, 0])
+            if cfg.attn_impl == "pallas":
+                from ..ops.paged_attention import paged_flash_attend
+
+                attn = paged_flash_attend(
+                    q, new_c, None, table, pos, None, active,
+                    scale=cfg.query_scale, value_dim=cfg.kv_lora_rank,
+                )
+            else:
+                attn = latent_attend(cfg, q, _latent_rows(new_c, table), mask)
+            return attn, new_c, None
         if isinstance(cache_k, KVQuant):
             # int8 pool: quantize the token's K/V, scatter data + scale
             # into the slot's block
@@ -355,6 +464,7 @@ def make_paged_hook(table: jnp.ndarray, active=None):
         )
         return attn, new_k, new_v
 
+    hook.live = active  # rows routed experts compute for (models/mla_moe)
     return hook
 
 
@@ -489,7 +599,7 @@ def _forward_step_paged(cfg, params, tokens, pool, table, pos, pages=None,
     reads (make_paged_hook)."""
     from ..models import api as M
 
-    bs = pool["k"].shape[3]
+    bs = pool_block_size(pool)
     MB = table.shape[1]
     x = M.embed(cfg, params, tokens, pos)
     x, pool = M.forward_layers(
@@ -522,6 +632,8 @@ def decode_slots_paged(
     is structural. The table is a plain (traced) input: admission changes
     it without recompiling. pages: optional [B] i32 per-slot adapter
     pages (0 = base), traced like the table."""
+
+    pool = _routed_reset(pool)
 
     def body(carry, sub):
         state, pool = carry
@@ -745,6 +857,23 @@ def _ragged_attend_xla(cfg, q, cache_k, cache_v, table, tok_row, tok_pos,
     )
 
 
+def _ragged_latent_xla(cfg, q, pool_c, table, tok_row, tok_pos):
+    """XLA twin of the ragged kernel's latent form: each flat token's
+    absorbed queries q [W, 1, H, R] against its row's gathered latent rows
+    under the causal mask of its own position; launch padding attends
+    nothing. One fleet row gathers once, as _ragged_attend_xla does."""
+    from ..models.mla_moe import latent_attend
+
+    S = table.shape[1] * pool_c.shape[2]
+    kv_pos = jnp.arange(S, dtype=jnp.int32)
+    mask = (kv_pos[None, :] <= tok_pos[:, None]) & (tok_row >= 0)[:, None]
+    if table.shape[0] == 1:
+        rows = _latent_rows(pool_c, table)  # [1, S, R]
+        return latent_attend(cfg, q[:, 0][None], rows, mask[None])[0][:, None]
+    rows = _latent_rows(pool_c, table[jnp.maximum(tok_row, 0)])  # [W, S, R]
+    return latent_attend(cfg, q, rows, mask[:, None, :])
+
+
 def make_ragged_fill_hook(table, meta, tok_row):
     """attn_hook for the ragged ingest programs: flat-token layout
     ([W, 1] chunks — each token is a batch row at its own position, the
@@ -778,6 +907,18 @@ def make_ragged_fill_hook(table, meta, tok_row):
             live = live & update_gate
         blk = jnp.where(live, blk, TRASH_BLOCK)
         off = pos % bs
+        if v is None:  # latent pool: as in make_paged_hook
+            new_c = cache_k.at[blk, :, off, :].set(k[:, 0])
+            if cfg.attn_impl == "pallas":
+                from ..ops.paged_attention import ragged_paged_attend
+
+                attn = ragged_paged_attend(
+                    q[:, 0], new_c, None, table, meta,
+                    scale=cfg.query_scale, value_dim=cfg.kv_lora_rank,
+                )[:, None]
+            else:
+                attn = _ragged_latent_xla(cfg, q, new_c, table, tok_row, pos)
+            return attn, new_c, None
         if isinstance(cache_k, KVQuant):
             qk, sk = quantize_chunk(k)
             qv, sv = quantize_chunk(v)
@@ -807,6 +948,7 @@ def make_ragged_fill_hook(table, meta, tok_row):
             )
         return attn, new_k, new_v
 
+    hook.live = tok_row >= 0  # launch padding reaches no routed expert
     return hook
 
 
@@ -1179,6 +1321,7 @@ def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
     pool)."""
     from ..models import api as M
 
+    pool = _routed_reset(pool)
     if dev is not None:
         meta, tok_pos = apply_device_meta(meta, tok_row, tok_pos, dev,
                                           state.pos)
@@ -1221,6 +1364,8 @@ def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
         cfg, state, sparams, logits, pf_logits, key, arm,
         spec=spec, sp_logits=sp_logits, sp_draft=sp_draft,
     )
+    if "routed" in pool:  # a latent pool's experts: same fetch, more rows
+        packed = _pack_rows(packed, pool["routed"])
     return packed, state, sparams, pool
 
 

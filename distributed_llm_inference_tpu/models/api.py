@@ -1,17 +1,19 @@
 """Arch dispatch: one functional interface over the model families.
 
 The engine and pipeline runtime call these; cfg.arch picks the family
-(llama: RMSNorm/RoPE/GQA/SwiGLU — gpt2: LayerNorm/learned-pos/MHA/gelu).
-Both families share the stacked-layer pytree + KV-cache layout, so the
-pipeline partitioner and cache plumbing are family-agnostic.
+(llama: RMSNorm/RoPE/GQA/SwiGLU — gpt2: LayerNorm/learned-pos/MHA/gelu —
+mla_moe: latent attention, routed experts, a leading dense stack).
+llama and gpt2 share the stacked-layer pytree + KV-cache layout, so the
+pipeline partitioner and cache plumbing are agnostic between them;
+mla_moe has two stacks and a latent cache and serves one device only.
 """
 
 from __future__ import annotations
 
 from ..config import ModelConfig
-from . import gpt2, llama
+from . import gpt2, llama, mla_moe
 
-_FAMILIES = {"llama": llama, "gpt2": gpt2}
+_FAMILIES = {"llama": llama, "gpt2": gpt2, "mla_moe": mla_moe}
 
 
 def family(cfg: ModelConfig):

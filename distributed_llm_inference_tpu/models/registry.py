@@ -103,6 +103,22 @@ register(ModelConfig(
     eos_token_id=2, bos_token_id=1,
 ))
 
+# --- Kanana-2 (arch "mla_moe": the DeepSeek-V3 block — latent attention
+# without a low-rank query, sigmoid router with a selection bias, shared
+# experts, one leading dense layer; kakaocorp/kanana-2-30b-a3b-instruct-2601
+# config.json). head_dim is the published one (= qk_rope_head_dim). The
+# published config names no special tokens: Llama-3's ids are assumed.
+register(ModelConfig(
+    name="kanana-2-30b-a3b", arch="mla_moe", vocab_size=128256, dim=2048,
+    n_layers=48, n_heads=32, n_kv_heads=32, ffn_dim=6144, max_seq_len=32768,
+    norm_eps=1e-6, rope_theta=1000000.0, head_dim_override=64,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, n_experts=128, n_experts_per_tok=6, moe_ffn_dim=768,
+    n_shared_experts=2, first_k_dense=1, routed_scaling=2.448,
+    moe_renormalize=True,
+    eos_token_id=128001, bos_token_id=128000, pad_token_id=128001,
+))
+
 # --- Qwen2 family (llama arch + q/k/v projection biases) ------------------
 _qwen2_7b = register(ModelConfig(
     name="qwen2-7b", arch="llama", vocab_size=152064, dim=3584,
@@ -273,6 +289,15 @@ register(ModelConfig(
     name="test-moe-tiny", arch="llama", vocab_size=256, dim=64,
     n_layers=4, n_heads=4, n_kv_heads=2, ffn_dim=96, max_seq_len=128,
     n_experts=4, n_experts_per_tok=2,
+    eos_token_id=2, bos_token_id=1,
+))
+register(ModelConfig(
+    name="test-mla-moe-tiny", arch="mla_moe", vocab_size=256, dim=64,
+    n_layers=3, n_heads=4, n_kv_heads=4, ffn_dim=96, max_seq_len=128,
+    norm_eps=1e-6, rope_theta=1000000.0, head_dim_override=8,
+    kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    n_experts=16, n_experts_per_tok=3, moe_ffn_dim=32, n_shared_experts=2,
+    first_k_dense=1, routed_scaling=2.448,
     eos_token_id=2, bos_token_id=1,
 ))
 register(ModelConfig(

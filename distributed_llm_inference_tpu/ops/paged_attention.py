@@ -146,8 +146,7 @@ def _walk_kernel(
     win_ref,  # scalar-prefetch [1] int32: sliding window (<= 0 = full)
     q_ref,  # [1, tq, KVg, group, Dh] VMEM: one query tile, one head group
     k_hbm,  # [N, KV, bs, Dh] HBM: the layer's pool slice, as scattered
-    v_hbm,
-    *rest,  # quant: (ks_hbm, vs_hbm [N, KV, bs], o_ref, scratch...)
+    *rest,  # (v_hbm, [ks_hbm, vs_hbm [N, KV, bs],] o_ref, scratch...)
     bs: int,
     MB: int,
     tq: int,
@@ -156,21 +155,30 @@ def _walk_kernel(
     scale: float,
     softcap: float | None,
     quant: bool,
+    latent: int = 0,
 ):
     """One program: query tile g (tq queries of one row; a decode slot is
     a tile of one) against head group hg's KVg KV heads. The walk over
     the row's live blocks is the fori_loop below; every head of a slab
     folds in one batched matmul pair. Row r of a head's score tile is
     (local query t = r // group, query head r % group of the KV head),
-    its absolute position q_start + t."""
-    if quant:
+    its absolute position q_start + t.
+
+    latent > 0 is the latent (MLA, absorbed) form: the pool holds one row
+    [c | k_r | pad] a token and there is no V pool; scores run over the
+    whole row, values are its first `latent` numbers, so one DMA serves
+    both, and the output is `latent` wide."""
+    if latent:
+        o_ref, kbuf, m_ref, l_ref, acc_ref, sem = rest
+        srcs, bufs = (k_hbm,), (kbuf,)
+    elif quant:
         # int8 pool (ops/kv_quant): per-(token, head) fp32 scales walk the
         # same loop as two more slabs, tokens on lanes
-        (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, m_ref, l_ref, acc_ref, sem,
-         ksbuf, vsbuf) = rest
+        (v_hbm, ks_hbm, vs_hbm, o_ref, kbuf, vbuf, m_ref, l_ref, acc_ref,
+         sem, ksbuf, vsbuf) = rest
         srcs, bufs = (k_hbm, v_hbm, ks_hbm, vs_hbm), (kbuf, vbuf, ksbuf, vsbuf)
     else:
-        o_ref, kbuf, vbuf, m_ref, l_ref, acc_ref, sem = rest
+        v_hbm, o_ref, kbuf, vbuf, m_ref, l_ref, acc_ref, sem = rest
         srcs, bufs = (k_hbm, v_hbm), (kbuf, vbuf)
     g = pl.program_id(0)
     hg = pl.program_id(1)
@@ -227,7 +235,7 @@ def _walk_kernel(
         mask = (t_local < q_len) & (kv_pos <= q_pos)
         mask &= (win <= 0) | (kv_pos > q_pos - win)
         ks = kbuf[slot].astype(jnp.float32)  # [KVg, bs, Dh]
-        vs = vbuf[slot].astype(jnp.float32)
+        vs = ks[:, :, :latent] if latent else vbuf[slot].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, ks, (((2,), (2,)), heads), preferred_element_type=jnp.float32
         )  # [KVg, rows, bs]
@@ -257,8 +265,9 @@ def _walk_kernel(
 
     l = l_ref[:]
     l = jnp.where(l == 0.0, 1.0, l)  # padding queries, rows not walked
-    o = (acc_ref[:] / l).astype(o_ref.dtype).reshape(KVg, tq, group, Dh)
-    o_ref[0] = o.reshape(1, KVg, group, Dh) if tq == 1 else o.swapaxes(0, 1)
+    Dv = latent or Dh
+    o = (acc_ref[:] / l).astype(o_ref.dtype).reshape(KVg, tq, group, Dv)
+    o_ref[0] = o.reshape(1, KVg, group, Dv) if tq == 1 else o.swapaxes(0, 1)
 
 
 def _lanes(a):
@@ -274,11 +283,15 @@ def _lanes(a):
 
 
 def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
-                scale, softcap, interpret):
+                scale, softcap, interpret, value_dim=None):
     """The pallas_call both wrappers share. q [G, tq, H, Dh]: G query
-    tiles of tq queries; meta [G, 4]; returns q's shape and dtype."""
+    tiles of tq queries; meta [G, 4]; returns q's shape and dtype.
+    pool_v None is the latent form (`_latent_walk`)."""
     from .kv_quant import KVQuant
 
+    if pool_v is None:
+        return _latent_walk(q, pool_k, table, meta, value_dim, scale=scale,
+                            interpret=interpret)
     quant = isinstance(pool_k, KVQuant)
     scales = []
     if quant:
@@ -336,8 +349,53 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
     return out[..., :Dh].reshape(q.shape)
 
 
+def _latent_walk(q, pool, table, meta, value_dim, *, scale, interpret):
+    """The walk over a latent pool [N, 1, bs, R] (R whole 128-lane tiles:
+    [c | k_r | zero pad]): q [G, tq, H, R] holds each head's absorbed
+    query [q~ | q_r | 0] against the one row all heads share. Returns
+    [G, tq, H, value_dim]: sum p c per head, still in latent space."""
+    G, tq, H, R = q.shape
+    bs, MB = pool.shape[2], table.shape[1]
+    assert pool.shape[1] == 1 and pool.shape[3] == R and R % 128 == 0
+    assert 0 < value_dim <= R
+    rows = tq * H
+    kernel = functools.partial(
+        _walk_kernel, bs=bs, MB=MB, tq=tq, KVg=1, group=H, scale=scale,
+        softcap=None, quant=False, latent=value_dim,
+    )
+
+    def tile(width):
+        return pl.BlockSpec(
+            (1, tq, 1, H, width),
+            lambda g, hg, meta_ref, table_ref, win_ref: (g, 0, hg, 0, 0),
+        )
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(G, 1),
+        in_specs=[tile(R), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=tile(value_dim),
+        scratch_shapes=[
+            pltpu.VMEM((2, 1, bs, R), pool.dtype),
+            pltpu.VMEM((1, rows, 1), jnp.float32),
+            pltpu.VMEM((1, rows, 1), jnp.float32),
+            pltpu.VMEM((1, rows, value_dim), jnp.float32),
+            pltpu.SemaphoreType.DMA((1, 2)),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((G, tq, 1, H, value_dim), q.dtype),
+        interpret=interpret,
+    )(meta.astype(jnp.int32), table.astype(jnp.int32),
+      jnp.full((1,), -1, jnp.int32), q.reshape(G, tq, 1, H, R), pool)
+    return out.reshape(G, tq, H, value_dim)
+
+
 @functools.partial(
-    jax.jit, static_argnames=("interpret", "window", "scale", "softcap")
+    jax.jit,
+    static_argnames=("interpret", "window", "scale", "softcap", "value_dim"),
 )
 def paged_flash_attend(
     q: jnp.ndarray,
@@ -352,6 +410,7 @@ def paged_flash_attend(
     scale: float | None = None,
     softcap: float | None = None,
     interpret: bool | None = None,
+    value_dim: int | None = None,
 ) -> jnp.ndarray:
     """Paged GQA decode attention over the (already updated) block pool.
 
@@ -370,6 +429,8 @@ def paged_flash_attend(
     override (None = head_dim**-0.5); softcap: Gemma-2 logit capping.
     Returns [B,1,H,Dh] in q.dtype — same contract as the gather path in
     engine/paged.make_paged_hook with the mask derived from pos/window.
+    pool_v None is the latent form: pool_k [N,1,bs,R] rows [c | k_r | 0],
+    q [B,1,H,R] absorbed queries, the output [B,1,H,value_dim].
     """
     B, T, H, Dh = q.shape
     assert T == 1, "paged kernel serves decode steps (T=1) only"
@@ -382,11 +443,13 @@ def paged_flash_attend(
     return _paged_walk(
         q, pool_k, pool_v, table, meta, window, window_dyn, scale=scale,
         softcap=softcap, interpret=resolve_interpret(interpret),
+        value_dim=value_dim,
     )
 
 
 @functools.partial(
-    jax.jit, static_argnames=("interpret", "window", "scale", "softcap")
+    jax.jit,
+    static_argnames=("interpret", "window", "scale", "softcap", "value_dim"),
 )
 def ragged_paged_attend(
     q: jnp.ndarray,
@@ -400,6 +463,7 @@ def ragged_paged_attend(
     scale: float | None = None,
     softcap: float | None = None,
     interpret: bool | None = None,
+    value_dim: int | None = None,
 ) -> jnp.ndarray:
     """Mixed prefill + decode GQA attention over the (already updated)
     block pool — one launch for rows of ARBITRARY per-row length.
@@ -423,7 +487,8 @@ def ragged_paged_attend(
     table), which is exactly the bucketed scratch prefill's per-token
     contract — so one compiled program replaces the whole bucket ladder.
     A prefill chunk's tiles each walk the row's prefix: the tile size is
-    the scheduler's.
+    the scheduler's. pool_v None is the latent form, as in
+    `paged_flash_attend`: the output is [W, H, value_dim].
     """
     W, H, Dh = q.shape
     G = meta.shape[0]
@@ -432,9 +497,9 @@ def ragged_paged_attend(
     out = _paged_walk(
         q.reshape(G, tq, H, Dh), pool_k, pool_v, table, meta, window,
         window_dyn, scale=scale, softcap=softcap,
-        interpret=resolve_interpret(interpret),
+        interpret=resolve_interpret(interpret), value_dim=value_dim,
     )
-    return out.reshape(W, H, Dh)
+    return out.reshape(W, H, value_dim if pool_v is None else Dh)
 
 
 # -- the dense slot-fleet cache -------------------------------------------------

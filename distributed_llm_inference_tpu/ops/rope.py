@@ -103,3 +103,19 @@ def apply_rope(
     q_out = qf * cos_b + _rotate_half(qf) * sin_b
     k_out = kf * cos_b + _rotate_half(kf) * sin_b
     return q_out.astype(orig), k_out.astype(orig)
+
+
+def rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
+                     theta: float) -> jnp.ndarray:
+    """Rotary embedding over INTERLEAVED pairs (DeepSeek-V3 / Kanana-2
+    `rope_interleave`): numbers (2i, 2i+1) of the last axis turn together
+    by positions * theta**(-2i/d). x [..., d]; positions broadcast against
+    x's leading axes. Float32 inside, x's dtype out."""
+    d = x.shape[-1]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)

@@ -65,6 +65,15 @@ def create_backend(
         from .config import resolve_attn_impl
 
         cfg = resolve_attn_impl(cfg, attn_impl)
+    # a latent-attention model (models/mla_moe.py) is served on one device
+    # from a latent pool: refused here, before the weights are made
+    from .engine.paged import refuse_unsupported_latent
+
+    refuse_unsupported_latent(
+        cfg, quant=cfg.quant, kv_quant=cfg.kv_quant,
+        mesh=not mesh_cfg.is_trivial or microbatches > 1, lora=lora,
+        adapter_slots=adapter_slots,
+    )
     if sp_strategy != "ring" and mesh_cfg.sp <= 1:
         # fail loudly BEFORE any backend branch (including microbatches):
         # --sp-strategy ulysses without --sp > 1 would otherwise silently

@@ -1,0 +1,240 @@
+"""The dense (per-head K/V) serving path of one checkout, as numbers to hold
+against another checkout's bit for bit (ISSUE 28: a PR that touches the
+shared scheduler, pool or kernel code shows that the dense models' programs
+did not change). For three tiny dense presets under both attention paths:
+the greedy tokens, the logits and the K and V pool contents of a chunked
+prefill (three ragged launches), twelve paged decode steps, and a mixed
+launch in which a second row hits the first row's four full prefix blocks.
+No recorded numbers: run it in both checkouts on the CPU and compare.
+
+    python tests/dense_equal.py <checkout root> <out.npz>      # once a side
+    python tests/dense_equal.py --compare <a.npz> <b.npz>      # exit 1 if unequal
+
+tests/test_mla_moe.py runs `dump` twice on this tree and `unequal` on the
+two, so that a difference between two checkouts is the programs' and not
+the script's.
+
+And the device programs themselves, with no chip attached: `programs`
+compiles a dense model's two step programs (cut to 2 layers) for a
+described v5e at a cell's sizes, and `canon` takes the source positions out
+of the optimized HLO (the tables of files and stack frames, each
+instruction's metadata, the debug locations inside each Mosaic kernel's
+serialized module), so that two checkouts whose texts are then the same
+run the same bits on the chip, and a token that differs between two runs
+there is the scheduler's timing (which program served it), not arithmetic.
+
+    python tests/dense_equal.py --programs <checkout root> <model> <slots> <blocks> <context> <out dir>
+    python tests/dense_equal.py --compare-programs <dir a> <dir b>   # exit 1 if unequal
+"""
+import base64
+import json
+import os
+import re
+import sys
+
+import numpy as np
+
+PRESETS = {"test-llama-tiny": ("test-llama-tiny", {}),
+           "test-olmo2-tiny": ("test-olmo2-tiny", {}),
+           "mistral-shaped": ("test-llama-tiny", {"attn_window": 40})}  # GQA + a window
+IMPLS = ("pallas", "xla")
+BS, TILE = 16, 8
+
+
+def dump(names=tuple(PRESETS), impls=IMPLS) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_llm_inference_tpu import get_model_config
+    from distributed_llm_inference_tpu.config import resolve_attn_impl
+    from distributed_llm_inference_tpu.engine import paged as P
+    from distributed_llm_inference_tpu.models import api as M
+
+    out = {}
+    for name in names:
+        base, kw = PRESETS[name]
+        for impl in impls:
+            cfg = resolve_attn_impl(
+                get_model_config(base, dtype="float32", eos_token_id=-1, **kw), impl)
+            params = M.init_params(cfg, jax.random.PRNGKey(3))
+            rng = np.random.default_rng(1)
+            ids = rng.integers(3, 250, 70).astype(np.int32)
+            tail = rng.integers(3, 250, 9).astype(np.int32)
+            pool = P.init_pool(cfg, 24, BS)
+            table = np.zeros((2, 8), np.int32)
+            table[0, :6] = [3, 7, 2, 9, 5, 13]
+
+            def launch(pool, entries, toks, width=32):
+                meta, tok_row, tok_pos, offs, _ = P.build_ragged_meta(
+                    entries, width=width, tile=TILE)
+                flat = np.zeros((width,), np.int32)
+                for (_, _, n, _), off, t in zip(entries, offs, toks):
+                    flat[off:off + n] = t
+                x = M.embed(cfg, params, jnp.asarray(flat)[:, None], jnp.asarray(tok_pos))
+                hook = P.make_ragged_fill_hook(
+                    jnp.array(table), jnp.asarray(meta), jnp.asarray(tok_row))
+                x, pool = M.forward_layers(cfg, params["layers"], x, pool,
+                                           jnp.asarray(tok_pos), attn_hook=hook,
+                                           attn_seq_len=1)
+                return np.asarray(M.unembed(cfg, params, x)[:, 0]), pool, offs
+
+            logits = []
+            for start, n in ((0, 24), (24, 24), (48, 22)):
+                lg, pool, offs = launch(pool, [(0, start, n, P.RAGGED_PREFILL)],
+                                        [ids[start:start + n]])
+                logits.append(lg[offs[0]:offs[0] + n])
+            tok, toks = int(logits[-1][-1].argmax()), []
+            for p in range(70, 82):
+                lg, pool = P._forward_step_paged(
+                    cfg, params, jnp.asarray([[tok]]), pool, jnp.array(table[:1]),
+                    jnp.asarray([p], jnp.int32))
+                logits.append(np.asarray(lg))
+                tok = int(np.asarray(lg)[0].argmax())
+                toks.append(tok)
+            table[1, :6] = [3, 7, 2, 9, 11, 12]  # shares four full blocks (64 tokens)
+            lg, pool, offs = launch(
+                pool, [(0, 82, 1, P.RAGGED_DECODE), (1, 64, 9, P.RAGGED_PREFILL)],
+                [[tok], tail])
+            logits.append(lg)
+            key = f"{name}.{impl}"
+            out[key + ".tokens"] = np.asarray(toks, np.int32)
+            out[key + ".logits"] = np.concatenate(logits)
+            out[key + ".pool_k"] = np.asarray(pool["k"])
+            out[key + ".pool_v"] = np.asarray(pool["v"])
+    return out
+
+
+def unequal(a, b) -> list:
+    """The arrays that are not the same bits on both sides (or on one only)."""
+    return sorted(k for k in set(a) | set(b)
+                  if k not in a or k not in b or a[k].dtype != b[k].dtype
+                  or a[k].tobytes() != b[k].tobytes())
+
+
+def programs(model, slots, blocks, context, block_size=128, tile=8) -> dict:
+    """{program name: optimized HLO text} of `model`'s decode chunk and
+    mixed step, compiled for one chip of a described v5e:2x2."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from distributed_llm_inference_tpu.config import resolve_attn_impl
+    from distributed_llm_inference_tpu.engine import generate as G
+    from distributed_llm_inference_tpu.engine import paged as P
+    from distributed_llm_inference_tpu.models import api as M
+    from distributed_llm_inference_tpu.models.registry import get_model_config
+
+    chip = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def place(make):
+        return jax.tree.map(lambda a: S(a.shape, a.dtype), jax.eval_shape(make))
+
+    cfg = resolve_attn_impl(
+        get_model_config(model).replace(n_layers=2, dtype="bfloat16"), "pallas")
+    params = place(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
+    state, sparams = place(lambda: G.init_slots(slots, cfg.vocab_size))
+    pool = place(lambda: P.init_pool(cfg, blocks, block_size))
+    table = S((slots, context // block_size), jnp.int32)
+    key = place(lambda: jax.random.PRNGKey(0))
+    chunk = P.decode_slots_paged.lower(
+        cfg, params, state, pool, table, key, sparams, num_steps=16)
+    width = max(128, (slots + 1) * tile)
+    entries = [(b, 0, 1, P.RAGGED_DECODE) for b in range(slots)]
+    meta, tok_row, tok_pos, offsets, _ = P.build_ragged_meta(
+        entries, width=width, tile=tile)
+    dev = P.DeviceMeta(*(S(a.shape, a.dtype) for a in P.build_device_meta(
+        entries, offsets, slots, width=width, tile=tile)))
+
+    def flat(a):
+        return S(np.shape(a), np.asarray(a).dtype)
+
+    mixed = P.mixed_step_ragged.lower(
+        cfg, params, S((width,), jnp.int32), flat(tok_row), flat(tok_pos),
+        S((width,), jnp.bool_), flat(meta), pool, table, state, sparams, key,
+        S((slots,), jnp.int32),
+        place(lambda: P.idle_mixed_arm(slots, cfg.vocab_size)), dev=dev)
+    return {"decode_slots_paged": chunk.compile().as_text(),
+            "mixed_step_ragged": mixed.compile().as_text()}
+
+
+def canon(text: str) -> tuple:
+    """(the instructions without source positions, [each Mosaic kernel's
+    module printed without debug locations])."""
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    ctx = jax_mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    head = text.split("\n", 1)[0]
+    body = text[text.index("\n\n", text.index("StackFrames")):]
+    body = re.sub(r", metadata=\{[^}]*\}", "", body)
+    kernels = []
+
+    def kernel(m):
+        config = json.loads(m.group(1))
+        raw = base64.b64decode(config["custom_call_config"].pop("body"))
+        with ctx:
+            kernels.append(ir.Module.parse(raw).operation.get_asm(enable_debug_info=False))
+        return f"backend_config={json.dumps(config, sort_keys=True)} <kernel {len(kernels)}>"
+
+    body = re.sub(r'backend_config=(\{.*"custom_call_config".*\})', kernel, body)
+    return head + body, kernels
+
+
+def _under(root: str) -> None:
+    """Import the package of the checkout at `root`, on the CPU."""
+    sys.path.insert(0, root)
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import distributed_llm_inference_tpu as package
+
+    assert os.path.abspath(package.__file__).startswith(root), package.__file__
+
+
+def main() -> None:
+    mode = sys.argv[1]
+    if mode == "--compare":
+        a, b = (dict(np.load(p)) for p in sys.argv[2:4])
+        bad = unequal(a, b)
+        print(f"{len(a)} arrays compared, {sum(v.size for v in a.values())} numbers, "
+              f"unequal: {bad}")
+        sys.exit(1 if bad else 0)
+    if mode == "--compare-programs":
+        a, b = sys.argv[2:4]
+        bad = []
+        for name in sorted(set(os.listdir(a)) | set(os.listdir(b))):
+            with open(os.path.join(a, name)) as fa, open(os.path.join(b, name)) as fb:
+                (ta, ka), (tb, kb) = canon(fa.read()), canon(fb.read())
+            print(f"{name}: {len(ta.splitlines())} lines of instructions "
+                  f"{'identical' if ta == tb else 'DIFFER'}; {len(ka)} Mosaic kernel(s), "
+                  f"{sum(map(len, ka))} characters, {'identical' if ka == kb else 'DIFFER'}")
+            bad += [name] * (ta != tb or ka != kb)
+        sys.exit(1 if bad else 0)
+    if mode == "--programs":
+        _under(os.path.abspath(sys.argv[2]))
+        os.environ["DLI_PALLAS_INTERPRET"] = "0"
+        import jax
+
+        jax.config.update("jax_enable_compilation_cache", False)  # unreadable without a chip
+        model, out = sys.argv[3], sys.argv[7]
+        os.makedirs(out, exist_ok=True)
+        for name, text in programs(model, *map(int, sys.argv[4:7])).items():
+            with open(os.path.join(out, f"{model}.{name}.hlo.txt"), "w") as f:
+                f.write(text)
+            print(model, name, len(text), "bytes of HLO text")
+        return
+    _under(os.path.abspath(mode))
+    os.environ["DLI_PALLAS_INTERPRET"] = "1"
+    out = dump()
+    np.savez(sys.argv[2], **out)
+    print("wrote", sys.argv[2], len(out), "arrays")
+
+
+if __name__ == "__main__":
+    main()

@@ -352,3 +352,140 @@ def test_step_programs_and_kernels_carry_the_names_a_trace_is_read_by(
             trace = json.load(f)["serving"]["trace"]
         assert set(trace["step_modules"]) == STEP_MODULES, path
         assert set(trace["attention_kernels"]) == ATTENTION_KERNELS, path
+
+
+# -- the latent-attention, routed-expert family (ISSUE 28) ---------------------
+#
+# kanana-2-30b-a3b at its published widths: the paged kernels' latent form
+# (one 640-number row a token, 32 query heads on it), the routed experts'
+# grouped matrix product over the STACKED bank, and the two step programs
+# (cut to the dense layer and one expert layer: the names do not depend on
+# depth) with the names `cellbench/configs/kanana-2-30b-a3b-7l.json` gives.
+#
+# `routed_expert_matmul` is JAX's megablox `gmm` called through its private
+# `__wrapped__` (the undecorated function under `gmm`'s own jit), only so that
+# the custom call carries this program's name: a JAX release that drops the
+# attribute fails the two tests below first, not the benchmark's readers.
+EXPERT_KERNELS = {"routed_expert_matmul"}
+STEP_SCOPES = {"moe_route", "moe_dispatch", "moe_experts", "moe_combine",
+               "moe_shared", "mla_absorb"}
+LATENT_SLOTS, LATENT_BLOCKS, LATENT_CONTEXT = 8, 1750, 32768
+
+
+@pytest.mark.parametrize("tq", [1, 8])
+def test_latent_walk_compiles_at_cell_shapes(one_chip, no_persistent_cache, tq):
+    S = _spec(one_chip)
+    rows, width, r = 640, 128, 512
+    pool = S((LATENT_BLOCKS, 1, 128, rows), jnp.bfloat16)
+    table = S((LATENT_SLOTS, LATENT_CONTEXT // 128), jnp.int32)
+    if tq == 1:
+        text = _compile(
+            functools.partial(paged_flash_attend, interpret=False,
+                              scale=192 ** -0.5, value_dim=r),
+            S((LATENT_SLOTS, 1, 32, rows), jnp.bfloat16), pool, None, table,
+            S((LATENT_SLOTS,), jnp.int32))
+    else:
+        text = _compile(
+            functools.partial(ragged_paged_attend, interpret=False,
+                              scale=192 ** -0.5, value_dim=r),
+            S((width, 32, rows), jnp.bfloat16), pool, None, table,
+            S((width // tq, 4), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("pairs", [48, 768])
+def test_routed_expert_matmul_compiles_at_cell_shapes(
+    one_chip, no_persistent_cache, monkeypatch, pairs
+):
+    """A decode chunk's 8 rows x 6 and a mixed step's 128 tokens x 6, over
+    the six expert layers' stacked bank (never a per-layer copy of it)."""
+    from distributed_llm_inference_tpu.models import mla_moe as MM
+
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    S = _spec(one_chip)
+    for k, n in ((2048, 768), (768, 2048)):
+        text = _compile(
+            MM.grouped_matmul, S((pairs, k), jnp.bfloat16),
+            S((6, 128, k, n), jnp.bfloat16), S((128,), jnp.int32),
+            S((), jnp.int32))
+        assert any("routed_expert_matmul" in c for c in _custom_call_names(text))
+
+
+def test_latent_step_programs_carry_the_names_a_trace_is_read_by(
+    one_chip, no_persistent_cache, monkeypatch
+):
+    import json
+    import os
+    import re
+
+    import numpy as np
+
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    cfg = resolve_attn_impl(
+        get_model_config("kanana-2-30b-a3b").replace(n_layers=2, dtype="bfloat16"),
+        "pallas",
+    )
+    S = _spec(one_chip)
+    place = functools.partial(_placed, sharding=one_chip)
+    params = place(jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0))))
+    slots = LATENT_SLOTS
+    state, sparams = place(jax.eval_shape(lambda: G.init_slots(slots, cfg.vocab_size)))
+    pool = place(jax.eval_shape(lambda: EP.init_pool(cfg, LATENT_BLOCKS, 128)))
+    assert pool["moe"].shape == (1, LATENT_BLOCKS, 1, 128, 640)
+    table = S((slots, LATENT_CONTEXT // 128), jnp.int32)
+    key = place(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    chunk = EP.decode_slots_paged.lower(
+        cfg, params, state, pool, table, key, sparams, num_steps=2,
+    ).compile().as_text()
+    tile, width = 8, 128
+    entries = [(b, 0, 1, EP.RAGGED_DECODE) for b in range(slots)]
+    meta, tok_row, tok_pos, offsets, _ = EP.build_ragged_meta(
+        entries, width=width, tile=tile)
+    dev = EP.DeviceMeta(*(
+        S(a.shape, a.dtype) for a in EP.build_device_meta(
+            entries, offsets, slots, width=width, tile=tile)))
+    arm = place(jax.eval_shape(lambda: EP.idle_mixed_arm(slots, cfg.vocab_size)))
+    flat = lambda a: S(np.shape(a), np.asarray(a).dtype)  # noqa: E731
+    mixed = EP.mixed_step_ragged.lower(
+        cfg, params, S((width,), jnp.int32), flat(tok_row), flat(tok_pos),
+        S((width,), jnp.bool_), flat(meta), pool, table, state, sparams, key,
+        S((slots,), jnp.int32), arm, dev=dev,
+    ).compile().as_text()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "cellbench", "configs",
+                           "kanana-2-30b-a3b-7l.json")) as f:
+        trace = json.load(f)["serving"]["trace"]
+    assert set(trace["expert_kernels"]) == EXPERT_KERNELS
+    for module, text, kernel in (("decode_slots_paged", chunk, "paged_flash_attend"),
+                                 ("mixed_step_ragged", mixed, "ragged_paged_attend")):
+        assert module in _module_name(text)
+        calls = _custom_call_names(text)
+        for name in (kernel, *trace["expert_kernels"]):
+            assert any(name in c for c in calls), (module, name, sorted(calls))
+        stacks = " ".join(set(re.findall(r'op_name="([^"]*)"', text)))
+        for scope in STEP_SCOPES:
+            assert scope in stacks, (module, scope)
+
+
+def test_two_compiles_compare_equal_once_source_positions_are_out(
+    one_chip, no_persistent_cache, monkeypatch
+):
+    """tests/dense_equal.py holds one checkout's compiled dense step programs
+    against another's (ISSUE 28): `canon` leaves the instructions and the
+    Mosaic kernels and takes out what only says where a line of source
+    stands, so a moved line is no difference and a changed instruction is."""
+    import re
+
+    import dense_equal
+
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    texts = dense_equal.programs("test-llama-tiny", 4, 16, 128, block_size=16)
+    assert set(texts) == {"decode_slots_paged", "mixed_step_ragged"}
+    for name, text in texts.items():
+        body, kernels = dense_equal.canon(text)
+        assert name in body.split("\n", 1)[0] and len(kernels) == 1
+        assert "op_name=" not in body and "paged.py" not in body and "loc(" not in kernels[0]
+        moved = re.sub(r"line=(\d+)", lambda m: f"line={int(m.group(1)) + 7}", text)
+        assert moved != text and dense_equal.canon(moved) == (body, kernels)
+        changed = text.replace(" multiply(", " add(", 1)
+        assert changed != text and dense_equal.canon(changed)[0] != body

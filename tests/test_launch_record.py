@@ -298,7 +298,13 @@ def test_old_series_read_what_the_parent_counted(runs):
         assert _value(snap, "dli_ragged_launches_total", phase="mixed") == 7
         assert _value(snap, "dli_sched_step_tokens_total", kind="prefill") == 233
         assert _value(snap, "dli_sched_prefill_chunks_total") == 7
-        assert _hist(snap, "dli_decode_step_seconds", engine="continuous")[1] == 30
+        # 24 chunk + 7 mixed launches, each fetched once, less those close()
+        # found dispatched ahead: a race with the worker's last fetch, one
+        # on a quiet machine (the parent's 30), two under load
+        unfetched = run["cont"]._steps_inflight // CHUNK_STEPS
+        assert _value(snap, "dli_ragged_launches_total", phase="chunk") == 24
+        assert 1 <= unfetched <= LAG
+        assert _hist(snap, "dli_decode_step_seconds", engine="continuous")[1] == 31 - unfetched
         assert _hist(snap, "dli_admission_wait_seconds", queue="continuous")[1] == 5
 
 
