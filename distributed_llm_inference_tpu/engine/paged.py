@@ -40,12 +40,18 @@ paged attention):
     kernels (ops/paged_attention.py) walk each live row's live blocks
     of the table with an online softmax — one DMA per block for all KV
     heads, no materialized view; a freed slot walks nothing.
-  * Writes are scatters: token K/V lands at
-    pool[table[b, pos_b // bs], :, pos_b % bs] per slot row b. Distinct
-    live slots never share a block, so scatter indices never collide
-    (the shared trash block only ever receives writes from slots whose
-    position has run past their budget — masked garbage, never attended;
-    the same stale-region argument as the dense fleet's).
+  * Writes are per token: K/V lands at
+    pool[layer, table[b, pos_b // bs], :, pos_b % bs] per slot row b, by
+    the paged kernel itself where it can and by an XLA scatter elsewhere
+    (_kernel_step). Distinct live slots never share a block, so the
+    writes never collide (the shared trash block only ever receives
+    writes from slots whose position has run past their budget — masked
+    garbage, never attended; the same stale-region argument as the dense
+    fleet's).
+  * The step programs carry the pool through their loops (the layer
+    scan, the decode chunk's step scan) as ONE donated buffer: a hook
+    gets the stacked leaves and the layer's index (make_paged_hook),
+    never a slice the scan cut out.
 
 Paged mode serves BOTH families: the hook seam is shared
 (models/llama.default_attn_hook; gpt2's block routes through it since
@@ -339,21 +345,96 @@ def unpack_routed(packed, shape):
             packed[-rows:].reshape(-1)[:size].reshape(shape))
 
 
-def _latent_rows(pool_c, table):
-    """A table's blocks of one layer's latent pool slice [N, 1, bs, R] as
-    contiguous rows [..., MB * bs, R] (the gather path)."""
-    g = pool_c[table][..., 0, :, :]  # [..., MB, bs, R]
+def _latent_rows(pool_c, layer, table):
+    """A table's blocks of one layer of the stacked latent pool
+    [L, N, 1, bs, R] as contiguous rows [..., MB * bs, R] (the gather
+    path: a gather of the table's blocks, never a slice of the layer)."""
+    g = pool_c[layer, table][..., 0, :, :]  # [..., MB, bs, R]
     return g.reshape(g.shape[:-3] + (-1, g.shape[-1]))
 
 
-def make_paged_hook(table: jnp.ndarray, active=None):
-    """attn_hook for models/llama.decoder_layer over a paged pool.
+def _write_tokens(cache_k, cache_v, k, v, layer, blk, off):
+    """XLA's form of both paged hooks' write: one token a row, k / v
+    [B, 1, KV, Dh], scattered into the stacked pool leaves at
+    (layer, blk[b], :, off[b], :). An int8 pool quantizes the token and
+    scatters data and scale; a latent pool (v None) has the one leaf.
+    Returns (cache_k, cache_v)."""
 
-    table: [B, max_blocks] int32 physical block ids. The hook sees this
-    layer's pool slice (cache_k/v [N, KV, bs, Dh], the layer axis unstacked
-    by forward_layers' scan) and per-row positions pos [B]; the chunk is
-    always T=1 (decode — prefill runs on a contiguous scratch cache and is
-    spliced in by insert_slot_paged).
+    def put(leaf, new):  # new [B, 1, KV(, Dh)]
+        return leaf.at[layer, blk, :, off].set(new[:, 0])
+
+    if v is None:
+        return put(cache_k, k), None
+    if isinstance(cache_k, KVQuant):
+        (qk, sk), (qv, sv) = quantize_chunk(k), quantize_chunk(v)
+        return (KVQuant(put(cache_k.q, qk), put(cache_k.s, sk)),
+                KVQuant(put(cache_v.q, qv), put(cache_v.s, sv)))
+    return put(cache_k, k), put(cache_v, v)
+
+
+def _kernel_step(kernel, cache_k, cache_v, k, v, layer, blk, off,
+                 update_gate):
+    """The Pallas path of both paged hooks: write the step's tokens k / v
+    [B, 1, KV, Dh] into layer `layer` of the stacked pool and attend.
+    `kernel(pool_k, pool_v, write)` is the hook's call of its paged
+    kernel. Returns (attn, cache_k, cache_v).
+
+    Where the kernel can (ops/paged_attention.writes_in_place: every
+    benchmark cell) it gets the pool whole and writes the tokens itself
+    through the pool's aliased output, so the step holds no operation of
+    the pool's or a layer's size. An XLA scatter in front of the kernel
+    cannot do that: it wants the pool in another tiled layout than the
+    kernel's operand, and the compiler then copies the pool between the
+    two at every layer (PERF.md, PR 29). Elsewhere (a head dim that is not
+    whole 128-lane tiles, an int8 pool, and the pp ring's gated writes,
+    which land in the trash block) the layer's slice is cut out, XLA
+    scatters into it, the kernel reads it, and it goes back: a layer's
+    bytes a call, as before the pool was a carry."""
+    from ..ops.paged_attention import writes_in_place
+
+    if update_gate is None and writes_in_place(cache_k):
+        return kernel(cache_k, cache_v, (layer, k, v))
+
+    # (tree.map: an int8 leaf is two arrays, a latent pool's V is None)
+    cut = functools.partial(
+        jax.tree.map, lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0)
+    )
+    new_k, new_v = _write_tokens(cut(cache_k), cut(cache_v), k, v, 0, blk,
+                                 off)
+    first = functools.partial(jax.tree.map, lambda a: a[0])
+    attn = kernel(first(new_k), first(new_v), None)
+    back = functools.partial(
+        jax.tree.map,
+        lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, layer, 0),
+    )
+    return attn, back(cache_k, new_k), back(cache_v, new_v)
+
+
+def _gather_blocks_of(leaf, layer, ids):
+    """The blocks `ids` [...] of one layer of a stacked pool leaf, in
+    float32 if the leaf is int8: [..., KV, bs, Dh] (the gather path)."""
+    if isinstance(leaf, KVQuant):
+        return kv_dequantize(KVQuant(leaf.q[layer, ids], leaf.s[layer, ids]))
+    return leaf[layer, ids]
+
+
+def make_paged_hook(table: jnp.ndarray, active=None):
+    """attn_hook for a decode step over a paged pool (llama family, gpt2,
+    mla_moe).
+
+    The paged contract (`hook.paged`): forward_layers does NOT unstack the
+    pool. It carries the stacked leaves through its layer scan beside x and
+    hands the hook the whole leaf, cache_k/v [L, N, KV, bs, Dh] (latent:
+    [L_stack, N, 1, bs, R]; int8: KVQuant pairs), and `layer`, the traced
+    index of the layer being run. The step's tokens go into that layer of
+    the leaf in place (the kernel's own write, or XLA's scatter:
+    _kernel_step, _write_tokens) and attention reads the layer's blocks out
+    of the same buffer, so no operation of a step is as large as the pool;
+    a scan that took the pool as xs and returned it as ys sliced every
+    layer out and stacked a second pool (PERF.md, PR 29).
+
+    table: [B, max_blocks] int32 physical block ids; per-row positions pos
+    [B]; the chunk is always T=1 (decode).
     active: [B] bool slot liveness (SlotState.active), or None for every
     row live. The fused kernel walks no KV block for a row whose flag is
     false — a freed slot's position stays frozen at its last request's
@@ -362,16 +443,16 @@ def make_paged_hook(table: jnp.ndarray, active=None):
     """
 
     def hook(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
-             valid_start, window_flag=None):
+             valid_start, window_flag=None, layer=None):
         del valid_start  # slots never left-pad
         # window_flag (mixed per-layer patterns): the XLA gather path
         # ignores it — decoder_layer resolved `mask` per layer already —
         # but the fused kernel derives its traced width from it below
         B, T, H, Dh = q.shape
         assert T == 1, "paged hook serves decode steps (T=1) only"
-        bs = cache_k.shape[2]
+        bs = cache_k.shape[3]
         MB = table.shape[1]
-        # Write: token K/V -> pool[table[b, pos_b//bs], :, pos_b%bs].
+        # Write: token K/V -> pool[layer, table[b, pos_b//bs], :, pos_b%bs].
         # The lblk clamp is the overrun guard: an inactive slot's frozen
         # row keeps forwarding its pad token and its pos can sit one past
         # the budget — the clamped write lands garbage in the slot's OWN
@@ -389,58 +470,38 @@ def make_paged_hook(table: jnp.ndarray, active=None):
             # discard as the dense pipeline's gated cache writes.
             blk = jnp.where(update_gate, blk, TRASH_BLOCK)
         off = pos % bs
-        if v is None:
-            # latent pool (models/mla_moe.py): k is the token's one row,
-            # q the absorbed queries; same scatter, the walk in latent
-            # form or the gathered rows the slow way
-            from ..models.mla_moe import latent_attend
-
-            new_c = cache_k.at[blk, :, off, :].set(k[:, 0])
-            if cfg.attn_impl == "pallas":
-                from ..ops.paged_attention import paged_flash_attend
-
-                attn = paged_flash_attend(
-                    q, new_c, None, table, pos, None, active,
-                    scale=cfg.query_scale, value_dim=cfg.kv_lora_rank,
-                )
-            else:
-                attn = latent_attend(cfg, q, _latent_rows(new_c, table), mask)
-            return attn, new_c, None
-        if isinstance(cache_k, KVQuant):
-            # int8 pool: quantize the token's K/V, scatter data + scale
-            # into the slot's block
-            qk, sk = quantize_chunk(k)
-            qv, sv = quantize_chunk(v)
-            new_k = KVQuant(
-                cache_k.q.at[blk, :, off, :].set(qk[:, 0]),
-                cache_k.s.at[blk, :, off].set(sk[:, 0]),
-            )
-            new_v = KVQuant(
-                cache_v.q.at[blk, :, off, :].set(qv[:, 0]),
-                cache_v.s.at[blk, :, off].set(sv[:, 0]),
-            )
-        else:
-            new_k = cache_k.at[blk, :, off, :].set(k[:, 0])
-            new_v = cache_v.at[blk, :, off, :].set(v[:, 0])
         if cfg.attn_impl == "pallas":
-            # Fused Pallas paged attention (ops/paged_attention.py) for
-            # BOTH leaf types: each live row walks its own live blocks
-            # of the table with an online softmax — no contiguous-view
-            # materialization; int8 pools dequantize in the block
-            # prologue. Softcap and scale overrides are static kernel
-            # params, and mixed per-layer window patterns feed this
-            # layer's width through the window_dyn scalar-prefetch
-            # operand (window_flag only exists for mixed configs —
-            # models/llama.make_window_flags).
+            # Fused Pallas paged attention (ops/paged_attention.py): each
+            # live row walks its own live blocks of the table with an
+            # online softmax — no contiguous-view materialization; int8
+            # pools dequantize in the block prologue. Softcap and scale
+            # overrides are static kernel params, and mixed per-layer
+            # window patterns feed this layer's width through the
+            # window_dyn scalar-prefetch operand (window_flag only exists
+            # for mixed configs — models/llama.make_window_flags). A
+            # latent pool (models/mla_moe.py; v None): k is the token's
+            # one row, q the absorbed queries, the walk in latent form.
             from ..models.llama import kernel_window
             from ..ops.paged_attention import paged_flash_attend
 
-            w, wd = kernel_window(cfg, window_flag)
-            attn = paged_flash_attend(
-                q, new_k, new_v, table, pos, wd, active, window=w,
-                scale=cfg.query_scale, softcap=cfg.attn_softcap,
+            w, wd = (None, None) if v is None else kernel_window(
+                cfg, window_flag)
+            return _kernel_step(
+                lambda pool_k, pool_v, write: paged_flash_attend(
+                    q, pool_k, pool_v, table, pos, wd, active, write,
+                    window=w, scale=cfg.query_scale,
+                    softcap=None if v is None else cfg.attn_softcap,
+                    value_dim=cfg.kv_lora_rank if v is None else None,
+                ),
+                cache_k, cache_v, k, v, layer, blk, off, update_gate,
             )
-            return attn, new_k, new_v
+        new_k, new_v = _write_tokens(cache_k, cache_v, k, v, layer, blk, off)
+        if v is None:  # the gathered latent rows, the slow way
+            from ..models.mla_moe import latent_attend
+
+            attn = latent_attend(cfg, q, _latent_rows(new_k, layer, table),
+                                 mask)
+            return attn, new_k, None
 
         # Gather the whole table -> ONE contiguous per-slot view recipe
         # for both leaf types (int8 slabs dequantize through the dense
@@ -449,13 +510,10 @@ def make_paged_hook(table: jnp.ndarray, active=None):
         # content at logical positions > pos[b] (trash block included) is
         # masked by the slot causal mask, which forward_layers built to
         # the LOGICAL length MB*bs via attn_seq_len.
-        KV_ = cache_k.shape[1]
+        KV_ = cache_k.shape[2]
 
         def gathered(leaf):
-            g = (
-                kv_dequantize(KVQuant(leaf.q[table], leaf.s[table]))
-                if isinstance(leaf, KVQuant) else leaf[table]
-            )  # [B, MB, KV, bs, Dh]
+            g = _gather_blocks_of(leaf, layer, table)  # [B, MB, KV, bs, Dh]
             return g.transpose(0, 2, 1, 3, 4).reshape(B, KV_, MB * bs, Dh)
 
         attn = attend(
@@ -464,6 +522,7 @@ def make_paged_hook(table: jnp.ndarray, active=None):
         )
         return attn, new_k, new_v
 
+    hook.paged = True  # forward_layers carries the stacked pool (above)
     hook.live = active  # rows routed experts compute for (models/mla_moe)
     return hook
 
@@ -792,17 +851,18 @@ def build_ragged_meta(entries, *, width: int, tile: int):
     return meta, tok_row, tok_pos, offsets, stats
 
 
-def _ragged_attend_xla(cfg, q, cache_k, cache_v, table, tok_row, tok_pos,
-                       window_flag):
+def _ragged_attend_xla(cfg, q, cache_k, cache_v, layer, table, tok_row,
+                       tok_pos, window_flag):
     """XLA twin of the ragged kernel: per-token gather of the owning
-    row's blocks into a contiguous logical view, then the stock masked
-    attention. This is the CPU / debug reference (the kernel's interpret
-    mode is the bit-exactness oracle); on TPU the kernel path avoids
-    materializing the W x MB*bs view entirely. q [W, 1, H, Dh]."""
+    row's blocks of `layer` out of the stacked pool into a contiguous
+    logical view, then the stock masked attention. This is the CPU /
+    debug reference (the kernel's interpret mode is the bit-exactness
+    oracle); on TPU the kernel path avoids materializing the W x MB*bs
+    view entirely. q [W, 1, H, Dh]."""
     from ..models.llama import kernel_window
 
     W = q.shape[0]
-    KV, bs = cache_k.shape[1], cache_k.shape[2]
+    KV, bs = cache_k.shape[2], cache_k.shape[3]
     MB = table.shape[1]
     Dh = cache_k.shape[-1]
     S = MB * bs
@@ -821,10 +881,7 @@ def _ragged_attend_xla(cfg, q, cache_k, cache_v, table, tok_row, tok_pos,
         # [1, W, S] batch — the same attention shape the bucketed scratch
         # prefill runs, with none of its gather/scatter bookends.
         def gathered1(leaf):
-            g = (
-                kv_dequantize(KVQuant(leaf.q[table[0]], leaf.s[table[0]]))
-                if isinstance(leaf, KVQuant) else leaf[table[0]]
-            )  # [MB, KV, bs, Dh]
+            g = _gather_blocks_of(leaf, layer, table[0])  # [MB, KV, bs, Dh]
             return g.transpose(1, 0, 2, 3).reshape(1, KV, S, Dh)
 
         kv_pos = jnp.arange(S, dtype=jnp.int32)[None, :]
@@ -841,10 +898,7 @@ def _ragged_attend_xla(cfg, q, cache_k, cache_v, table, tok_row, tok_pos,
     row_table = table[rows]  # [W, MB]
 
     def gathered(leaf):
-        g = (
-            kv_dequantize(KVQuant(leaf.q[row_table], leaf.s[row_table]))
-            if isinstance(leaf, KVQuant) else leaf[row_table]
-        )  # [W, MB, KV, bs, Dh]
+        g = _gather_blocks_of(leaf, layer, row_table)  # [W, MB, KV, bs, Dh]
         return g.transpose(0, 2, 1, 3, 4).reshape(W, KV, S, Dh)
 
     kv_pos = jnp.arange(S, dtype=jnp.int32)[None, None, :]
@@ -857,20 +911,22 @@ def _ragged_attend_xla(cfg, q, cache_k, cache_v, table, tok_row, tok_pos,
     )
 
 
-def _ragged_latent_xla(cfg, q, pool_c, table, tok_row, tok_pos):
+def _ragged_latent_xla(cfg, q, pool_c, layer, table, tok_row, tok_pos):
     """XLA twin of the ragged kernel's latent form: each flat token's
     absorbed queries q [W, 1, H, R] against its row's gathered latent rows
     under the causal mask of its own position; launch padding attends
     nothing. One fleet row gathers once, as _ragged_attend_xla does."""
     from ..models.mla_moe import latent_attend
 
-    S = table.shape[1] * pool_c.shape[2]
+    S = table.shape[1] * pool_c.shape[3]
     kv_pos = jnp.arange(S, dtype=jnp.int32)
     mask = (kv_pos[None, :] <= tok_pos[:, None]) & (tok_row >= 0)[:, None]
     if table.shape[0] == 1:
-        rows = _latent_rows(pool_c, table)  # [1, S, R]
+        rows = _latent_rows(pool_c, layer, table)  # [1, S, R]
         return latent_attend(cfg, q[:, 0][None], rows, mask[None])[0][:, None]
-    rows = _latent_rows(pool_c, table[jnp.maximum(tok_row, 0)])  # [W, S, R]
+    rows = _latent_rows(
+        pool_c, layer, table[jnp.maximum(tok_row, 0)]
+    )  # [W, S, R]
     return latent_attend(cfg, q, rows, mask[:, None, :])
 
 
@@ -879,7 +935,8 @@ def make_ragged_fill_hook(table, meta, tok_row):
     ([W, 1] chunks — each token is a batch row at its own position, the
     slots-mode contract), per-token K/V scatter into the owning row's
     pool block, attention over the pool via the ragged kernel
-    (attn_impl="pallas") or its XLA gather twin.
+    (attn_impl="pallas") or its XLA gather twin. The paged contract of
+    make_paged_hook: the stacked pool leaf and the layer's index.
 
     table [R, MB]: the launch's fleet rows' block tables; meta [G, 4]:
     the per-tile launch plan (build_ragged_meta); tok_row [W]: per-token
@@ -888,14 +945,14 @@ def make_ragged_fill_hook(table, meta, tok_row):
     """
 
     def hook(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
-             valid_start, window_flag=None):
+             valid_start, window_flag=None, layer=None):
         del mask, valid_start  # mask derived from pos/tok_row in-kernel
         W, T = q.shape[0], q.shape[1]
         assert T == 1, "ragged fill runs the flat token layout (T=1 rows)"
-        bs = cache_k.shape[2]
+        bs = cache_k.shape[3]
         MB = table.shape[1]
-        # Write: token w's K/V -> pool[table[row_w, pos_w // bs], :,
-        # pos_w % bs]. Launch padding (row -1) — and, on the pp ring,
+        # Write: token w's K/V -> pool[layer, table[row_w, pos_w // bs],
+        # :, pos_w % bs]. Launch padding (row -1) — and, on the pp ring,
         # microsteps whose stage doesn't own the buffer (update_gate) —
         # redirect to the trash block: colliding trash writes are
         # write-only garbage at positions nothing ever attends.
@@ -907,47 +964,38 @@ def make_ragged_fill_hook(table, meta, tok_row):
             live = live & update_gate
         blk = jnp.where(live, blk, TRASH_BLOCK)
         off = pos % bs
-        if v is None:  # latent pool: as in make_paged_hook
-            new_c = cache_k.at[blk, :, off, :].set(k[:, 0])
-            if cfg.attn_impl == "pallas":
-                from ..ops.paged_attention import ragged_paged_attend
-
-                attn = ragged_paged_attend(
-                    q[:, 0], new_c, None, table, meta,
-                    scale=cfg.query_scale, value_dim=cfg.kv_lora_rank,
-                )[:, None]
-            else:
-                attn = _ragged_latent_xla(cfg, q, new_c, table, tok_row, pos)
-            return attn, new_c, None
-        if isinstance(cache_k, KVQuant):
-            qk, sk = quantize_chunk(k)
-            qv, sv = quantize_chunk(v)
-            new_k = KVQuant(
-                cache_k.q.at[blk, :, off, :].set(qk[:, 0]),
-                cache_k.s.at[blk, :, off].set(sk[:, 0]),
-            )
-            new_v = KVQuant(
-                cache_v.q.at[blk, :, off, :].set(qv[:, 0]),
-                cache_v.s.at[blk, :, off].set(sv[:, 0]),
-            )
-        else:
-            new_k = cache_k.at[blk, :, off, :].set(k[:, 0])
-            new_v = cache_v.at[blk, :, off, :].set(v[:, 0])
-        if cfg.attn_impl == "pallas":
+        if cfg.attn_impl == "pallas":  # as in make_paged_hook
             from ..models.llama import kernel_window
             from ..ops.paged_attention import ragged_paged_attend
 
-            w, wd = kernel_window(cfg, window_flag)
-            attn = ragged_paged_attend(
-                q[:, 0], new_k, new_v, table, meta, wd, window=w,
-                scale=cfg.query_scale, softcap=cfg.attn_softcap,
-            )[:, None]
+            w, wd = (None, None) if v is None else kernel_window(
+                cfg, window_flag)
+
+            def kernel(pool_k, pool_v, write):
+                out = ragged_paged_attend(
+                    q[:, 0], pool_k, pool_v, table, meta, wd, write,
+                    window=w, scale=cfg.query_scale,
+                    softcap=None if v is None else cfg.attn_softcap,
+                    value_dim=cfg.kv_lora_rank if v is None else None,
+                )
+                if write is None:
+                    return out[:, None]
+                attn, *pool = out
+                return attn[:, None], *pool
+
+            return _kernel_step(kernel, cache_k, cache_v, k, v, layer, blk,
+                                off, update_gate)
+        new_k, new_v = _write_tokens(cache_k, cache_v, k, v, layer, blk, off)
+        if v is None:
+            attn = _ragged_latent_xla(cfg, q, new_k, layer, table, tok_row,
+                                      pos)
         else:
             attn = _ragged_attend_xla(
-                cfg, q, new_k, new_v, table, tok_row, pos, window_flag
+                cfg, q, new_k, new_v, layer, table, tok_row, pos, window_flag
             )
         return attn, new_k, new_v
 
+    hook.paged = True  # forward_layers carries the stacked pool
     hook.live = tok_row >= 0  # launch padding reaches no routed expert
     return hook
 

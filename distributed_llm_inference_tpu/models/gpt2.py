@@ -84,7 +84,7 @@ def init_kv_cache(
 
 
 def decoder_layer(cfg, lp, x, cache_k, cache_v, pos, mask, update_gate=None,
-                  tp_axis=None, attn_hook=None):
+                  tp_axis=None, attn_hook=None, layer=None):
     """One GPT-2 block on chunk x [B,T,D] at offset pos.
 
     Cache write + attention go through the SHARED hook seam
@@ -113,8 +113,11 @@ def decoder_layer(cfg, lp, x, cache_k, cache_v, pos, mask, update_gate=None,
     v = (mm(h, lp["wv"]) + lp["bv"]).reshape(B, T, H, Dh)
 
     hook = attn_hook or default_attn_hook
+    # layer: under a paged hook cache_k/v are the STACKED pool leaves and
+    # this is the layer's index in them (llama.forward_layers)
     attn, new_k, new_v = hook(
-        cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate, None, None
+        cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate, None, None,
+        *(() if layer is None else (layer,)),
     )
     attn_out = mm(attn.reshape(B, T, H * Dh), lp["wo"])
     if tp_axis is not None:
@@ -155,14 +158,20 @@ def forward_layers(cfg, layers, x, cache, pos, update_gate=None, tp_axis=None,
     else:
         mask = causal_mask(pos, T, S)
 
-    def body(carry, xs):
-        xc = carry
-        lp, ck, cv = xs
-        xc, ck, cv = decoder_layer(cfg, lp, xc, ck, cv, pos, mask, update_gate,
-                                   tp_axis, attn_hook)
-        return xc, (ck, cv)
+    from .llama import scan_layers
 
-    x, (new_k, new_v) = jax.lax.scan(body, x, (layers, cache["k"], cache["v"]))
+    # a paged hook's pool rides the scan as a carry (llama.forward_layers)
+    paged = getattr(attn_hook, "paged", False)
+
+    def layer_step(xc, lp, kv, layer):
+        xc, ck, cv = decoder_layer(cfg, lp, xc, *kv, pos, mask, update_gate,
+                                   tp_axis, attn_hook,
+                                   layer if paged else None)
+        return xc, (ck, cv), None
+
+    x, (new_k, new_v), _ = scan_layers(
+        layer_step, x, layers, (cache["k"], cache["v"]), paged=paged
+    )
     return x, {"k": new_k, "v": new_v}
 
 

@@ -320,10 +320,14 @@ def decoder_layer(
     valid_start: Optional[jnp.ndarray] = None,
     ep_axis: Optional[str] = None,
     lora_pages: Optional[jnp.ndarray] = None,
+    layer: Optional[jnp.ndarray] = None,
 ):
     """One pre-norm decoder block on a chunk x [B,T,D] at offset `pos`.
 
     lp: this layer's params (no leading L axis). Returns (x, cache_k, cache_v).
+    layer: None, and cache_k/v are this layer's slices of the cache; or,
+    under a paged hook (forward_layers), the traced index of this layer in
+    the STACKED pool leaves that cache_k/v then are, handed on to the hook.
     update_gate: optional traced bool — when False the cache write is
     discarded (needed by the pipeline runtime, where a stage executes
     speculatively on microsteps when it holds no valid microbatch).
@@ -406,7 +410,7 @@ def decoder_layer(
     hook = attn_hook or default_attn_hook
     attn, new_k, new_v = hook(
         cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate, valid_start,
-        lp.get("window_flag"),
+        lp.get("window_flag"), *(() if layer is None else (layer,)),
     )
     attn_out = lmm(attn.reshape(B, T, H * Dh), "wo")
     if tp_axis is not None:
@@ -467,6 +471,16 @@ def forward_layers(
     (engine/paged.py) attends a GATHERED [B, KV, n_blocks*bs, Dh] view
     whose logical length is not the cache leaf's seq axis (that axis is
     the block size there), so masks must be built to the logical length.
+
+    A paged hook (`attn_hook.paged`, engine/paged.py) changes what the
+    scan does with the cache: the pool [L_slice, n_blocks, KV, bs, Dh] is
+    not scanned over but CARRIED, stacked, beside x, and each layer's hook
+    gets the whole leaves and the layer's index. As xs / ys the scan cut
+    every layer's slice out of the pool and stacked the slices it got back
+    into a second pool, moving the pool several times a step to write a
+    few tokens; as a carry the hook's scatter updates it in place. The
+    dense cache keeps the xs / ys form: its hook rewrites the layer's
+    whole slice anyway.
     """
     T = x.shape[1]
     S = attn_seq_len if attn_seq_len is not None else cache["k"].shape[3]
@@ -510,17 +524,47 @@ def forward_layers(
     else:
         mask = make_mask(cfg.attn_window)
 
-    def body(carry, xs):
-        xc = carry
-        lp, ck, cv = xs
-        xc, ck, cv = decoder_layer(
-            cfg, lp, xc, ck, cv, pos, cos, sin, mask, update_gate, tp_axis,
-            attn_hook, valid_start, ep_axis, lora_pages,
-        )
-        return xc, (ck, cv)
+    paged = getattr(attn_hook, "paged", False)
 
-    x, (new_k, new_v) = jax.lax.scan(body, x, (layers, cache["k"], cache["v"]))
+    def layer_step(xc, lp, kv, layer):
+        xc, ck, cv = decoder_layer(
+            cfg, lp, xc, *kv, pos, cos, sin, mask, update_gate, tp_axis,
+            attn_hook, valid_start, ep_axis, lora_pages,
+            layer if paged else None,
+        )
+        return xc, (ck, cv), None
+
+    x, (new_k, new_v), _ = scan_layers(
+        layer_step, x, layers, (cache["k"], cache["v"]), paged=paged
+    )
     return x, {"k": new_k, "v": new_v}
+
+
+def scan_layers(layer_step, x, layers, cache, *, paged: bool):
+    """The layer scan of every family (llama here, gpt2, mla_moe's stacks):
+    `layer_step(x, lp, cache, layer) -> (x, cache, ys)` over the stacked
+    parameters `layers`, `cache` any pytree of leaves stacked [L, ...].
+    Dense cache: `layer_step` gets the layer's slices, which are scanned
+    over and stacked again. paged: the stacked leaves ride the carry whole
+    and `layer` says which layer to touch (forward_layers' docstring).
+    Returns (x, cache, the stacked ys)."""
+    index = jnp.arange(jax.tree.leaves(cache)[0].shape[0], dtype=jnp.int32)
+    if paged:
+        def body(carry, xs):
+            (xc, cache), (lp, layer) = carry, xs
+            xc, cache, ys = layer_step(xc, lp, cache, layer)
+            return (xc, cache), ys
+
+        (x, cache), ys = jax.lax.scan(body, (x, cache), (layers, index))
+        return x, cache, ys
+
+    def body(xc, xs):
+        lp, cache, layer = xs
+        xc, cache, ys = layer_step(xc, lp, cache, layer)
+        return xc, (cache, ys)
+
+    x, (cache, ys) = jax.lax.scan(body, x, (layers, cache, index))
+    return x, cache, ys
 
 
 def embed(cfg: ModelConfig, params: Params, tokens: jnp.ndarray, pos=0) -> jnp.ndarray:

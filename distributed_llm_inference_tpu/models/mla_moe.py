@@ -230,9 +230,11 @@ def latent_attn_hook(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
 
 
 def attention(cfg: ModelConfig, lp: Params, x, cache, pos, positions, mask,
-              update_gate, hook):
+              update_gate, hook, layer=None):
     """The attention sublayer on the float32 residual x [B, T, D]; returns
-    (its float32 output [B, T, D], the layer's new cache slice)."""
+    (its float32 output [B, T, D], the new cache). cache: the layer's slice
+    of the dense cache, or under a paged hook the stack's whole pool leaf
+    with `layer`, the layer's index in it."""
     B, T, _ = x.shape
     dt = cfg.jnp_dtype
     H, r = cfg.n_heads, cfg.kv_lora_rank
@@ -254,7 +256,8 @@ def attention(cfg: ModelConfig, lp: Params, x, cache, pos, positions, mask,
             [q_lat, q_r, jnp.zeros((B, T, H, pad), dt)], axis=-1
         )
     o_lat, new_cache, _ = hook(
-        cfg, q_abs, row, None, cache, None, pos, mask, update_gate, None, None
+        cfg, q_abs, row, None, cache, None, pos, mask, update_gate, None, None,
+        *(() if layer is None else (layer,)),
     )
     with jax.named_scope("mla_absorb"):
         o = jnp.einsum("bthr,rhv->bthv", o_lat, w_kvb[..., dn:])
@@ -397,7 +400,9 @@ def forward_layers(cfg: ModelConfig, layers: Params, x, cache, pos,
     with a "routed" leaf [2, Lm, E] int32 the expert layers add to it what
     they routed: [0] tokens an expert got, [1] steps in which it got any.
     pos: a scalar, or one position a row (the flat token layout).
-    Returns (x, new cache)."""
+    Under a paged hook (`attn_hook.paged`, engine/paged.py) each stack's
+    pool leaf rides its scan as a carry, whole, and the hook gets the
+    layer's index: see models/llama.forward_layers. Returns (x, new cache)."""
     if tp_axis is not None or ep_axis is not None:
         raise ValueError("the mla_moe family is not sharded over tp or ep")
     T = x.shape[1]
@@ -420,35 +425,36 @@ def forward_layers(cfg: ModelConfig, layers: Params, x, cache, pos,
         live = jnp.repeat(live, T) if T > 1 else live
     dt = cfg.jnp_dtype
 
-    def attn_part(xc, lp, ck):
+    from .llama import scan_layers  # one scan for the dense cache and the pool
+
+    paged = getattr(attn_hook, "paged", False)
+
+    def attn_part(xc, lp, ck, layer):
         out, ck = attention(cfg, lp, xc, ck, pos, positions, mask,
-                            update_gate, hook)
+                            update_gate, hook, layer if paged else None)
         xc = xc + out
         return xc, rms_norm(xc, lp["mlp_norm"], cfg.norm_eps).astype(dt), ck
 
-    def dense_body(xc, xs):
-        lp, ck = xs
-        xc, h, ck = attn_part(xc, lp, ck)
-        return xc + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"]), ck
+    def dense_layer(xc, lp, ck, layer):
+        xc, h, ck = attn_part(xc, lp, ck, layer)
+        return xc + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"]), ck, None
 
     moe = layers["moe"]
     banks = {name: moe[name] for name in BANKS}  # closed over, never sliced
 
-    def moe_body(xc, xs):
-        lp, ck, layer = xs
-        xc, h, ck = attn_part(xc, lp, ck)
+    def moe_layer(xc, lp, ck, layer):
+        xc, h, ck = attn_part(xc, lp, ck, layer)
         out, sizes = moe_ffn(cfg, lp, banks, layer, h, live)
-        return xc + out, (ck, sizes)
+        return xc + out, ck, sizes
 
     new = dict(cache)
     if stack_depths(cfg)[0]:
-        x, new["dense"] = jax.lax.scan(
-            dense_body, x, (layers["dense"], cache["dense"])
+        x, new["dense"], _ = scan_layers(
+            dense_layer, x, layers["dense"], cache["dense"], paged=paged
         )
-    Lm = stack_depths(cfg)[1]
     small = {name: leaf for name, leaf in moe.items() if name not in BANKS}
-    x, (new["moe"], sizes) = jax.lax.scan(
-        moe_body, x, (small, cache["moe"], jnp.arange(Lm, dtype=jnp.int32))
+    x, new["moe"], sizes = scan_layers(
+        moe_layer, x, small, cache["moe"], paged=paged
     )
     if "routed" in cache:
         new["routed"] = cache["routed"] + jnp.stack(

@@ -7,8 +7,8 @@ The two paged kernels are ONE kernel body under two wrappers. A program
 of the grid is one row's work: a decode slot's single query, or one query
 tile of the mixed launch's flat token axis (times a number of KV-head
 groups where the working set of all heads would not fit the VMEM
-budget). The layer's pool slices stay in HBM in the layout the scatter
-writes ([N, KV, bs, Dh]); the program walks its row's block table itself:
+budget). The pool stays in HBM ([N, KV, bs, Dh] a layer); the program
+walks its row's block table itself:
 
   * `first, needed` bound the row's LIVE logical blocks: the causal
     frontier above, the sliding window below (static, or a traced
@@ -24,6 +24,18 @@ writes ([N, KV, bs, Dh]); the program walks its row's block table itself:
   * a row that holds nothing (a launch-padding tile, a decode slot whose
     `active` flag is false) copies nothing and loops zero times; its
     output is zeros, which the caller discards.
+
+The step programs hand the kernels the pool WHOLE, stacked over its layers
+([L, N, KV, bs, Dh], a donated loop carry) with the layer to read and the
+launch's new K/V rows, and the kernel writes those rows itself: the block
+a new token falls in is in VMEM for the walk anyway, so the program
+patches the rows into its copy and sends the touched sublane tiles back
+through the pool's aliased output (`input_output_aliases`). XLA then sees
+one buffer that only this custom call touches: no scatter whose preferred
+layout differs from the kernel's operand layout, hence no copy of the pool
+or of a layer's slice anywhere in a step (PERF.md, PR 29). Shapes that are
+not whole tiles keep the older form: XLA scatters into one layer's slice
+and the kernel reads that slice (`writes_in_place`).
 
 So the device's work follows the rows' live blocks, not slots x KV heads
 x table width. What the walk covers is counted on the host by
@@ -143,10 +155,11 @@ def _heads_per_slab(KV: int, bs: int, Dh: int, itemsize: int, quant: bool,
 def _walk_kernel(
     meta_ref,  # scalar-prefetch [G, 4] int32: (row, q_start, q_len, kind)
     table_ref,  # scalar-prefetch [R, MB] int32
-    win_ref,  # scalar-prefetch [1] int32: sliding window (<= 0 = full)
+    win_ref,  # scalar-prefetch [1] int32: sliding window (<= 0 = full);
+    # [2] with `write`: and the layer of the stacked pool
     q_ref,  # [1, tq, KVg, group, Dh] VMEM: one query tile, one head group
-    k_hbm,  # [N, KV, bs, Dh] HBM: the layer's pool slice, as scattered
-    *rest,  # (v_hbm, [ks_hbm, vs_hbm [N, KV, bs],] o_ref, scratch...)
+    *rest,  # ([new K, V rows,] the pool leaves in HBM, o_ref, [the pool's
+    # aliased outputs,] scratch...)
     bs: int,
     MB: int,
     tq: int,
@@ -156,6 +169,7 @@ def _walk_kernel(
     softcap: float | None,
     quant: bool,
     latent: int = 0,
+    write: bool = False,
 ):
     """One program: query tile g (tq queries of one row; a decode slot is
     a tile of one) against head group hg's KVg KV heads. The walk over
@@ -164,28 +178,42 @@ def _walk_kernel(
     (local query t = r // group, query head r % group of the KV head),
     its absolute position q_start + t.
 
+    The pool leaves are one layer's slices, k_hbm / v_hbm [N, KV, bs, Dh]
+    (an int8 pool: and ks_hbm / vs_hbm [N, KV, bs]), already holding the
+    launch's tokens; or, with `write`, the STACKED pool [L, N, KV, bs, Dh]
+    that does not hold them yet: the tile's q_len tokens, positions
+    q_start .. q_start + q_len - 1 of the row, arrive as new_refs
+    [1, tq, KVg, 1, Dh] and this program puts them where the table says.
+    A block they fall in is patched in VMEM once its copy has landed, and
+    its touched sublane tiles go back to HBM while the block folds. The
+    grid runs in order on the one core, so a later tile of the row reads
+    what an earlier one wrote; the pool is read and written through its
+    aliased OUTPUT refs (in interpret mode the inputs are copies).
+
     latent > 0 is the latent (MLA, absorbed) form: the pool holds one row
     [c | k_r | pad] a token and there is no V pool; scores run over the
     whole row, values are its first `latent` numbers, so one DMA serves
     both, and the output is `latent` wide."""
-    if latent:
-        o_ref, kbuf, m_ref, l_ref, acc_ref, sem = rest
-        srcs, bufs = (k_hbm,), (kbuf,)
-    elif quant:
+    n = 1 if latent else (4 if quant else 2)  # pool leaves
+    new_refs, rest = (rest[:n], rest[n:]) if write else ((), rest)
+    srcs, o_ref, rest = rest[:n], rest[n], rest[n + 1:]
+    if write:
+        srcs, rest = rest[:n], rest[n:]
+    m_ref, l_ref, acc_ref, sem, *bufs = rest
+    wsem = bufs.pop() if write else None
+    kbuf = bufs[0]
+    vbuf = None if latent else bufs[1]
+    if quant:
         # int8 pool (ops/kv_quant): per-(token, head) fp32 scales walk the
         # same loop as two more slabs, tokens on lanes
-        (v_hbm, ks_hbm, vs_hbm, o_ref, kbuf, vbuf, m_ref, l_ref, acc_ref,
-         sem, ksbuf, vsbuf) = rest
-        srcs, bufs = (k_hbm, v_hbm, ks_hbm, vs_hbm), (kbuf, vbuf, ksbuf, vsbuf)
-    else:
-        v_hbm, o_ref, kbuf, vbuf, m_ref, l_ref, acc_ref, sem = rest
-        srcs, bufs = (k_hbm, v_hbm), (kbuf, vbuf)
+        ksbuf, vsbuf = bufs[2:]
     g = pl.program_id(0)
     hg = pl.program_id(1)
     row = jnp.maximum(meta_ref[g, 0], 0)
     q_start = meta_ref[g, 1]
     q_len = meta_ref[g, 2]  # 0 = the row holds nothing: walk nothing
     win = win_ref[0]
+    layer = (win_ref[1],) if write else ()
     rows = tq * group
     Dh = q_ref.shape[-1]
     first, needed = _ragged_live_range(q_start, q_len, bs=bs, MB=MB, win=win)
@@ -201,11 +229,50 @@ def _walk_kernel(
         blk = table_ref[row, j]
         return [
             pltpu.make_async_copy(
-                src.at[blk, pl.ds(hg * KVg, KVg)], buf.at[slot],
+                src.at[(*layer, blk, pl.ds(hg * KVg, KVg))], buf.at[slot],
                 sem.at[i, slot],
             )
             for i, (src, buf) in enumerate(zip(srcs, bufs))
         ]
+
+    # `write`: the sublane tiles of a block that go back to HBM. The new
+    # rows of a block are at most tq consecutive ones, so a static span of
+    # whole tiles holds them wherever they start.
+    sub = 32 // kbuf.dtype.itemsize  # rows of one (sublane, 128-lane) tile
+    span = min(bs, sub * (pl.cdiv(tq, sub) + 1))
+
+    def put_back(j, slot, start):
+        blk = table_ref[row, j]
+        return [
+            pltpu.make_async_copy(
+                buf.at[slot, :, pl.ds(start, span)],
+                dst.at[(*layer, blk, pl.ds(hg * KVg, KVg),
+                        pl.ds(start, span))],
+                wsem.at[i],
+            )
+            for i, (dst, buf) in enumerate(zip(srcs, bufs))
+        ]
+
+    def patch(j, slot):
+        """The tile's new rows that fall in block j, into the block's VMEM
+        copy: only the span that goes back is touched. Returns the span's
+        first row."""
+        r0 = q_start - j * bs  # block row of the tile's first token
+        start = 0
+        if span < bs:
+            start = pl.multiple_of(
+                jnp.clip(r0, 0, bs - span) // sub * sub, sub
+            )
+        at = jax.lax.broadcasted_iota(
+            jnp.int32, (span, kbuf.shape[3]), 0) + (start - r0)
+        for new_ref, buf in zip(new_refs, bufs):
+            rows_ = (slot, slice(None), pl.ds(start, span))
+            cur = buf[rows_].astype(jnp.float32)  # [KVg, span, Dh]
+            for t in range(tq):
+                new = new_ref[0, t].astype(jnp.float32)  # [KVg, 1, Dh]
+                cur = jnp.where(((at == t) & (t < q_len))[None], new, cur)
+            buf[rows_] = cur.astype(buf.dtype)
+        return start
 
     @pl.when(first < needed)
     def _():
@@ -231,6 +298,14 @@ def _walk_kernel(
 
         for c in copies(j, slot):
             c.wait()
+        if write:
+            has_new = (j * bs < q_start + q_len) & ((j + 1) * bs > q_start)
+
+            @pl.when(has_new)
+            def _():
+                for c in put_back(j, slot, patch(j, slot)):
+                    c.start()
+
         kv_pos = j * bs + col
         mask = (t_local < q_len) & (kv_pos <= q_pos)
         mask &= (win <= 0) | (kv_pos > q_pos - win)
@@ -259,6 +334,14 @@ def _walk_kernel(
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
             p, vs, (((2,), (1,)), heads), preferred_element_type=jnp.float32
         )
+        if write:
+            # before the slot is filled again, and before a later program
+            # of the row reads the block
+            @pl.when(has_new)
+            def _():
+                for c in put_back(j, slot, 0):
+                    c.wait()
+
         return carry
 
     jax.lax.fori_loop(first, needed, fold_block, 0)
@@ -282,115 +365,119 @@ def _lanes(a):
     return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)]) if pad else a
 
 
-def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
-                scale, softcap, interpret, value_dim=None):
-    """The pallas_call both wrappers share. q [G, tq, H, Dh]: G query
-    tiles of tq queries; meta [G, 4]; returns q's shape and dtype.
-    pool_v None is the latent form (`_latent_walk`)."""
+def writes_in_place(leaf) -> bool:
+    """Whether the kernels can take this pool leaf ([..., KV, bs, Dh], of
+    a layer or stacked) whole and write a launch's tokens into it
+    themselves: a raw-dtype leaf (not ops/kv_quant's int8 pair) whose head
+    dim is whole 128-lane tiles and whose blocks are whole sublane tiles,
+    so a block's touched tiles go back to HBM by aligned DMAs. Every
+    benchmark cell is (head dim 128 or a 640-number latent row, 128-token
+    blocks). Otherwise (chip_smoke.py's TinyLlama, head dim 64; an int8
+    pool) the caller scatters into one layer's slice and passes that: the
+    padded copy `_lanes` makes is then one layer's, never the pool's."""
     from .kv_quant import KVQuant
 
-    if pool_v is None:
-        return _latent_walk(q, pool_k, table, meta, value_dim, scale=scale,
-                            interpret=interpret)
+    return (not isinstance(leaf, KVQuant) and leaf.shape[-1] % 128 == 0
+            and leaf.shape[-2] % (32 // leaf.dtype.itemsize) == 0)
+
+
+def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
+                scale, softcap, interpret, value_dim=None, write=None):
+    """The pallas_call both wrappers share. q [G, tq, H, Dh]: G query
+    tiles of tq queries; meta [G, 4]. pool_v None is the latent form.
+    write None: the pool leaves are one layer's slices and hold the
+    launch's tokens; returns the output, q's shape and dtype. write
+    (layer, new_k, new_v): the leaves are the stacked pool, new_k / new_v
+    [G, tq, KV, Dh] the launch's rows, which the kernel writes (see
+    `_walk_kernel`); returns (output, pool_k, pool_v), the pool updated in
+    place (donate it)."""
+    from .kv_quant import KVQuant
+
+    latent = pool_v is None
     quant = isinstance(pool_k, KVQuant)
-    scales = []
+    leaves = [pool_k] if latent else [pool_k, pool_v]
     if quant:
-        scales = [_lanes(pool_k.s), _lanes(pool_v.s)]
-        pool_k, pool_v = pool_k.q, pool_v.q
+        leaves = [pool_k.q, pool_v.q, _lanes(pool_k.s), _lanes(pool_v.s)]
+    n = len(leaves)
     G, tq, H, Dh = q.shape
-    KV, bs = pool_k.shape[1], pool_k.shape[2]
+    KV, bs = leaves[0].shape[-3:-1]
     group = H // KV
     MB = table.shape[1]
-    q5 = _lanes(q.reshape(G, tq, KV, group, Dh))
-    pool_k, pool_v = _lanes(pool_k), _lanes(pool_v)
-    Dp = q5.shape[-1]
-    rows = tq * group
-    KVg = _heads_per_slab(KV, bs, Dp, pool_k.dtype.itemsize, quant, rows)
     if window_dyn is None:
-        win_arr = jnp.full((1,), -1 if window is None else window, jnp.int32)
+        scalars = jnp.full((1,), -1 if window is None else window, jnp.int32)
     else:
-        win_arr = jnp.reshape(window_dyn.astype(jnp.int32), (1,))
+        scalars = jnp.reshape(window_dyn.astype(jnp.int32), (1,))
+    news = []
+    if write is not None:
+        assert all(writes_in_place(a) for a in leaves), leaves
+        layer, *news = write
+        scalars = jnp.concatenate(
+            [scalars, jnp.reshape(layer, (1,)).astype(jnp.int32)]
+        )
+        news = [a.reshape(G, tq, KV, 1, Dh) for a in news[:n]]
+    else:
+        leaves[:2] = [_lanes(a) for a in leaves[:2]]
+    q5 = _lanes(q.reshape(G, tq, KV, group, Dh))
+    Dp = q5.shape[-1]
+    Dv = value_dim if latent else Dp
+    rows = tq * group
+    KVg = _heads_per_slab(KV, bs, Dp, leaves[0].dtype.itemsize, quant, rows)
 
     kernel = functools.partial(
         _walk_kernel, bs=bs, MB=MB, tq=tq, KVg=KVg, group=group,
         scale=scale if scale is not None else Dh**-0.5, softcap=softcap,
-        quant=quant,
+        quant=quant, latent=value_dim if latent else 0,
+        write=write is not None,
     )
-    tile = pl.BlockSpec(
-        (1, tq, KVg, group, Dp),
-        lambda g, hg, meta_ref, table_ref, win_ref: (g, 0, hg, 0, 0),
-    )
+
+    def tile(per_head, width):
+        return pl.BlockSpec(
+            (1, tq, KVg, per_head, width),
+            lambda g, hg, meta_ref, table_ref, win_ref: (g, 0, hg, 0, 0),
+        )
+
     scratch = [
-        pltpu.VMEM((2, KVg, bs, Dp), pool_k.dtype),
-        pltpu.VMEM((2, KVg, bs, Dp), pool_v.dtype),
         pltpu.VMEM((KVg, rows, 1), jnp.float32),
         pltpu.VMEM((KVg, rows, 1), jnp.float32),
-        pltpu.VMEM((KVg, rows, Dp), jnp.float32),
-        pltpu.SemaphoreType.DMA((4 if quant else 2, 2)),
+        pltpu.VMEM((KVg, rows, Dv), jnp.float32),
+        pltpu.SemaphoreType.DMA((n, 2)),
     ]
-    if quant:
-        scale_slab = pltpu.VMEM((2, KVg) + scales[0].shape[2:], jnp.float32)
-        scratch += [scale_slab, scale_slab]
+    scratch += [pltpu.VMEM((2, KVg, bs, Dp), a.dtype) for a in leaves[:2]]
+    scratch += [
+        pltpu.VMEM((2, KVg) + a.shape[2:], jnp.float32) for a in leaves[2:]
+    ]
+    in_hbm = [pl.BlockSpec(memory_space=pl.ANY)] * n
+    out_specs = tile(group, Dv)
+    out_shape = jax.ShapeDtypeStruct((G, tq, KV, group, Dv), q.dtype)
+    aliases = {}
+    if write is not None:
+        scratch.append(pltpu.SemaphoreType.DMA((n,)))
+        out_specs = [out_specs] + in_hbm
+        out_shape = [out_shape] + [
+            jax.ShapeDtypeStruct(a.shape, a.dtype) for a in leaves
+        ]
+        # operands: 3 prefetched scalars, q, the new rows, the pool leaves
+        aliases = {4 + n + i: 1 + i for i in range(n)}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(G, KV // KVg),
-        in_specs=[tile]
-        + [pl.BlockSpec(memory_space=pl.ANY)] * (2 + len(scales)),
-        out_specs=tile,
+        in_specs=[tile(group, Dp)] + [tile(1, Dp)] * len(news) + in_hbm,
+        out_specs=out_specs,
         scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q5.shape, q5.dtype),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         interpret=interpret,
-    )(meta.astype(jnp.int32), table.astype(jnp.int32), win_arr,
-      q5, pool_k, pool_v, *scales)
-    return out[..., :Dh].reshape(q.shape)
-
-
-def _latent_walk(q, pool, table, meta, value_dim, *, scale, interpret):
-    """The walk over a latent pool [N, 1, bs, R] (R whole 128-lane tiles:
-    [c | k_r | zero pad]): q [G, tq, H, R] holds each head's absorbed
-    query [q~ | q_r | 0] against the one row all heads share. Returns
-    [G, tq, H, value_dim]: sum p c per head, still in latent space."""
-    G, tq, H, R = q.shape
-    bs, MB = pool.shape[2], table.shape[1]
-    assert pool.shape[1] == 1 and pool.shape[3] == R and R % 128 == 0
-    assert 0 < value_dim <= R
-    rows = tq * H
-    kernel = functools.partial(
-        _walk_kernel, bs=bs, MB=MB, tq=tq, KVg=1, group=H, scale=scale,
-        softcap=None, quant=False, latent=value_dim,
-    )
-
-    def tile(width):
-        return pl.BlockSpec(
-            (1, tq, 1, H, width),
-            lambda g, hg, meta_ref, table_ref, win_ref: (g, 0, hg, 0, 0),
-        )
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(G, 1),
-        in_specs=[tile(R), pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=tile(value_dim),
-        scratch_shapes=[
-            pltpu.VMEM((2, 1, bs, R), pool.dtype),
-            pltpu.VMEM((1, rows, 1), jnp.float32),
-            pltpu.VMEM((1, rows, 1), jnp.float32),
-            pltpu.VMEM((1, rows, value_dim), jnp.float32),
-            pltpu.SemaphoreType.DMA((1, 2)),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((G, tq, 1, H, value_dim), q.dtype),
-        interpret=interpret,
-    )(meta.astype(jnp.int32), table.astype(jnp.int32),
-      jnp.full((1,), -1, jnp.int32), q.reshape(G, tq, 1, H, R), pool)
-    return out.reshape(G, tq, H, value_dim)
+    )(meta.astype(jnp.int32), table.astype(jnp.int32), scalars, q5, *news,
+      *leaves)
+    width = Dv if latent else Dh
+    if write is None:
+        return out[..., :width].reshape(G, tq, H, width)
+    out, *pool = out
+    return out.reshape(G, tq, H, width), *pool, *[None] * (2 - n)
 
 
 @functools.partial(
@@ -405,6 +492,7 @@ def paged_flash_attend(
     pos: jnp.ndarray,
     window_dyn: jnp.ndarray | None = None,
     active: jnp.ndarray | None = None,
+    write: tuple | None = None,
     *,
     window: int | None = None,
     scale: float | None = None,
@@ -412,12 +500,18 @@ def paged_flash_attend(
     interpret: bool | None = None,
     value_dim: int | None = None,
 ) -> jnp.ndarray:
-    """Paged GQA decode attention over the (already updated) block pool.
+    """Paged GQA decode attention over the block pool.
 
-    q [B,1,H,Dh]; pool_k/v [N,KV,bs,Dh] (one layer's pool slice) — or
-    ops/kv_quant.KVQuant leaves (int8 blocks + per-(token, head) fp32
-    scales [N,KV,bs]), dequantized in the block prologue; table [B,MB]
-    int32 physical block ids; pos [B] int32 per-row positions.
+    q [B,1,H,Dh]; pool_k/v [N,KV,bs,Dh] (one layer's pool slice, already
+    updated) — or ops/kv_quant.KVQuant leaves (int8 blocks + per-(token,
+    head) fp32 scales [N,KV,bs]), dequantized in the block prologue; table
+    [B,MB] int32 physical block ids; pos [B] int32 per-row positions.
+    write (layer, new_k, new_v): pool_k/v are the STACKED pool
+    [L,N,KV,bs,Dh] (`writes_in_place`), not yet updated; the kernel puts
+    each live row's new token new_k/v [B,1,KV,Dh] at position pos[b] of
+    its table in layer `layer` (a traced scalar) and attends it with the
+    rest, and the call returns (output, pool_k, pool_v) with the pool
+    updated in place. A row that is not live writes nothing.
     active: [B] bool, or None for every row live — a row whose flag is
     false is not walked (no DMA, no loop) and its output is zeros: a freed
     slot's position stays frozen at its last request's length, and only
@@ -430,7 +524,8 @@ def paged_flash_attend(
     Returns [B,1,H,Dh] in q.dtype — same contract as the gather path in
     engine/paged.make_paged_hook with the mask derived from pos/window.
     pool_v None is the latent form: pool_k [N,1,bs,R] rows [c | k_r | 0],
-    q [B,1,H,R] absorbed queries, the output [B,1,H,value_dim].
+    q [B,1,H,R] absorbed queries, the output [B,1,H,value_dim]; new_v and
+    the returned pool_v are None.
     """
     B, T, H, Dh = q.shape
     assert T == 1, "paged kernel serves decode steps (T=1) only"
@@ -443,7 +538,7 @@ def paged_flash_attend(
     return _paged_walk(
         q, pool_k, pool_v, table, meta, window, window_dyn, scale=scale,
         softcap=softcap, interpret=resolve_interpret(interpret),
-        value_dim=value_dim,
+        value_dim=value_dim, write=write,
     )
 
 
@@ -458,6 +553,7 @@ def ragged_paged_attend(
     table: jnp.ndarray,
     meta: jnp.ndarray,
     window_dyn: jnp.ndarray | None = None,
+    write: tuple | None = None,
     *,
     window: int | None = None,
     scale: float | None = None,
@@ -465,15 +561,19 @@ def ragged_paged_attend(
     interpret: bool | None = None,
     value_dim: int | None = None,
 ) -> jnp.ndarray:
-    """Mixed prefill + decode GQA attention over the (already updated)
-    block pool — one launch for rows of ARBITRARY per-row length.
+    """Mixed prefill + decode GQA attention over the block pool — one
+    launch for rows of ARBITRARY per-row length.
 
     q [W, H, Dh]: the flat query-token axis — every row's tokens laid out
     back to back at query-tile granularity (tq = W // meta.shape[0]); a
     prefill row contributes its chunk, a decode row one token.
-    pool_k/v [N, KV, bs, Dh] (one layer's pool slice) — or
-    ops/kv_quant.KVQuant leaves (int8 blocks + per-(token, head) fp32
-    scales), dequantized in the block prologue.
+    pool_k/v [N, KV, bs, Dh] (one layer's pool slice, already updated) —
+    or ops/kv_quant.KVQuant leaves (int8 blocks + per-(token, head) fp32
+    scales), dequantized in the block prologue. write (layer, new_k,
+    new_v), as `paged_flash_attend`: the stacked pool, and the kernel
+    writes each tile's q_len tokens new_k/v [W, KV, Dh] at positions
+    q_start .. q_start + q_len - 1 of its row (launch padding, q_len 0,
+    writes nothing); returns (output, pool_k, pool_v).
     table [R, MB] int32 physical block ids, one row per fleet row.
     meta [G, 4] int32 per-tile metadata (row, q_start, q_len, kind), the
     host-built launch plan (engine/paged.build_ragged_meta): q_start is
@@ -498,8 +598,11 @@ def ragged_paged_attend(
         q.reshape(G, tq, H, Dh), pool_k, pool_v, table, meta, window,
         window_dyn, scale=scale, softcap=softcap,
         interpret=resolve_interpret(interpret), value_dim=value_dim,
+        write=write,
     )
-    return out.reshape(W, H, value_dim if pool_v is None else Dh)
+    if write is None:
+        return out.reshape(W, H, -1)
+    return out[0].reshape(W, H, -1), *out[1:]
 
 
 # -- the dense slot-fleet cache -------------------------------------------------

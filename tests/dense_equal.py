@@ -1,11 +1,26 @@
-"""The dense (per-head K/V) serving path of one checkout, as numbers to hold
-against another checkout's bit for bit (ISSUE 28: a PR that touches the
-shared scheduler, pool or kernel code shows that the dense models' programs
-did not change). For three tiny dense presets under both attention paths:
-the greedy tokens, the logits and the K and V pool contents of a chunked
-prefill (three ragged launches), twelve paged decode steps, and a mixed
-launch in which a second row hits the first row's four full prefix blocks.
-No recorded numbers: run it in both checkouts on the CPU and compare.
+"""The paged serving path of one checkout, as numbers to hold against
+another checkout's bit for bit (ISSUE 28: a PR that touches the shared
+scheduler, pool or kernel code shows that the dense models' programs did
+not change; ISSUE 29: that a pool carried through the layer scan and
+written in place holds what the sliced and re-stacked one held). For three
+tiny dense presets, the tiny latent-attention model (both its stacks) and a
+dense one with a 128-wide head (the shape at which the Pallas kernels write
+the pool themselves, ops/paged_attention.writes_in_place), under both
+attention paths: the greedy tokens, the logits and every pool leaf of a
+chunked prefill (three ragged launches), twelve paged decode steps, and a
+mixed launch in which a second row hits the first row's four full prefix
+blocks. No recorded numbers: run it in both checkouts on the CPU and
+compare.
+
+Two things are left out of "bit for bit", each for a reason. The pool's
+block 0 is not dumped: it is the trash block, write-only by contract
+(launch padding lands there under XLA's scatter and nowhere under the
+kernels' own write). And the two command-line dumps run with the CPU
+backend's fusion pass off (`_under`): fused, that backend contracts a
+multiply and an add into one rounding where they land in one loop, which
+follows the shape of the surrounding graph, not the program's arithmetic
+(RoPE's `k * cos + rot(k) * sin` beside a scatter into [N, ...] or into
+[L, N, ...] differs in the last bit of K; ISSUE 29).
 
     python tests/dense_equal.py <checkout root> <out.npz>      # once a side
     python tests/dense_equal.py --compare <a.npz> <b.npz>      # exit 1 if unequal
@@ -36,12 +51,18 @@ import numpy as np
 
 PRESETS = {"test-llama-tiny": ("test-llama-tiny", {}),
            "test-olmo2-tiny": ("test-olmo2-tiny", {}),
-           "mistral-shaped": ("test-llama-tiny", {"attn_window": 40})}  # GQA + a window
+           "mistral-shaped": ("test-llama-tiny", {"attn_window": 40}),  # GQA + a window
+           "test-mla-moe-tiny": ("test-mla-moe-tiny", {}),  # a latent pool, two stacks
+           "wide-head": ("test-llama-tiny", {"head_dim_override": 128})}
 IMPLS = ("pallas", "xla")
 BS, TILE = 16, 8
 
 
-def dump(names=tuple(PRESETS), impls=IMPLS) -> dict:
+def dump(names=tuple(PRESETS), impls=IMPLS, wrap=lambda hook: hook) -> dict:
+    """{"<preset>.<impl>.<tokens | logits | pool_<leaf>>": array}. wrap: what
+    the ragged and the decode hook pass through before forward_layers gets
+    them (tests/test_paged.py holds the carried pool against a hook that
+    cuts each layer's slice out and puts it back)."""
     import jax
     import jax.numpy as jnp
 
@@ -71,8 +92,8 @@ def dump(names=tuple(PRESETS), impls=IMPLS) -> dict:
                 for (_, _, n, _), off, t in zip(entries, offs, toks):
                     flat[off:off + n] = t
                 x = M.embed(cfg, params, jnp.asarray(flat)[:, None], jnp.asarray(tok_pos))
-                hook = P.make_ragged_fill_hook(
-                    jnp.array(table), jnp.asarray(meta), jnp.asarray(tok_row))
+                hook = wrap(P.make_ragged_fill_hook(
+                    jnp.array(table), jnp.asarray(meta), jnp.asarray(tok_row)))
                 x, pool = M.forward_layers(cfg, params["layers"], x, pool,
                                            jnp.asarray(tok_pos), attn_hook=hook,
                                            attn_seq_len=1)
@@ -85,9 +106,13 @@ def dump(names=tuple(PRESETS), impls=IMPLS) -> dict:
                 logits.append(lg[offs[0]:offs[0] + n])
             tok, toks = int(logits[-1][-1].argmax()), []
             for p in range(70, 82):
-                lg, pool = P._forward_step_paged(
-                    cfg, params, jnp.asarray([[tok]]), pool, jnp.array(table[:1]),
-                    jnp.asarray([p], jnp.int32))
+                pos = jnp.asarray([p], jnp.int32)
+                x = M.embed(cfg, params, jnp.asarray([[tok]]), pos)
+                x, pool = M.forward_layers(
+                    cfg, params["layers"], x, pool, pos,
+                    attn_hook=wrap(P.make_paged_hook(jnp.array(table[:1]))),
+                    attn_seq_len=table.shape[1] * BS)
+                lg = M.unembed(cfg, params, x[:, -1:, :])[:, 0, :]
                 logits.append(np.asarray(lg))
                 tok = int(np.asarray(lg)[0].argmax())
                 toks.append(tok)
@@ -99,8 +124,9 @@ def dump(names=tuple(PRESETS), impls=IMPLS) -> dict:
             key = f"{name}.{impl}"
             out[key + ".tokens"] = np.asarray(toks, np.int32)
             out[key + ".logits"] = np.concatenate(logits)
-            out[key + ".pool_k"] = np.asarray(pool["k"])
-            out[key + ".pool_v"] = np.asarray(pool["v"])
+            for leaf in sorted(set(pool) - {"routed"}):  # without the trash block
+                out[f"{key}.pool_{leaf}"] = np.asarray(pool[leaf])[:, 1:]
+            jax.clear_caches()  # unfused, a run's loops outnumber a process's mappings
     return out
 
 
@@ -189,9 +215,12 @@ def canon(text: str) -> tuple:
 
 
 def _under(root: str) -> None:
-    """Import the package of the checkout at `root`, on the CPU."""
+    """Import the package of the checkout at `root`, on the CPU, its fusion
+    pass off (the module's docstring says why)."""
     sys.path.insert(0, root)
     os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_disable_hlo_passes=fusion")
     import distributed_llm_inference_tpu as package
 
     assert os.path.abspath(package.__file__).startswith(root), package.__file__

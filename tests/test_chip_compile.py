@@ -271,6 +271,14 @@ def test_tinyllama_paged_decode_step_compiles_with_kernel(
         num_steps=1,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # Head dim 64 is not whole 128-lane tiles, so the kernel reads a padded
+    # copy of ONE layer's slice (ops/paged_attention.writes_in_place), as it
+    # did before the pool became a carry (temporaries 1.41 GB then, with the
+    # scan's second pool; 0.10 GB now): never a padded copy of the stacked
+    # pool, which would be 2.2 GB (22 layers x 2 leaves x 50 MB).
+    padded_slice = POOL_BLOCKS * KV * 16 * 128 * 2
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps < 2 * padded_slice + 2**28, temps
 
 
 # What a device trace calls the fleet's two step programs and their two
@@ -352,6 +360,90 @@ def test_step_programs_and_kernels_carry_the_names_a_trace_is_read_by(
             trace = json.load(f)["serving"]["trace"]
         assert set(trace["step_modules"]) == STEP_MODULES, path
         assert set(trace["attention_kernels"]) == ATTENTION_KERNELS, path
+
+
+# -- the pool is a loop carry that the kernels write in place (ISSUE 29) --------
+#
+# The three configurations at their cells' sizes (cellbench/configs/*.json:
+# model, layers, slots, pool blocks, context; 128-token blocks, query tiles
+# of 8). Before ISSUE 29 each step program held a second pool as a temporary
+# (2.56 / 2.97 GB for olmo2's 2.05 GB pool, 2.70 / 3.51 for mistral's 2.27,
+# 2.549 / 2.302 for kanana's 2.007) and moved the pool about five times a
+# step; what is left is weights relaid out once a launch.
+CELL_PROGRAMS = {
+    "olmo2-7b-16l": ("olmo2-7b", 16, 12, 61, 2048),
+    "mistral-7b-16l": ("mistral-7b", 16, 16, 271, 6400),
+    "kanana-2-30b-a3b-7l": ("kanana-2-30b-a3b", 7, 8, 1750, 32768),
+}
+# instructions that make no buffer of their own, or are the kernels
+_NO_BUFFER = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+              "custom-call"}
+
+
+def _pool_sized_instructions(hlo_text, pool):
+    """Instructions of a compiled module whose result has the shape of a
+    pool leaf or of one layer's slice of it (`copy`, `dynamic-slice`,
+    `dynamic-update-slice`, `scatter`, bare or as a fusion's root)."""
+    import re
+
+    shapes = set()
+    for leaf in jax.tree.leaves(pool):
+        if leaf.ndim == 5:
+            dims = [str(d) for d in leaf.shape]
+            shapes |= {",".join(dims), ",".join(dims[1:]),
+                       ",".join(["1"] + dims[1:])}
+    found = re.findall(
+        r"%([\w.\-]+) = \w+\[([\d,]+)\]\{[^}]*\} ([\w\-]+)\(", hlo_text)
+    return sorted(f"{op} {name} [{shape}]" for name, shape, op in found
+                  if shape in shapes and op not in _NO_BUFFER)
+
+
+@pytest.mark.parametrize("config", sorted(CELL_PROGRAMS))
+def test_step_programs_hold_no_copy_of_the_pool_at_cell_sizes(
+    one_chip, no_persistent_cache, monkeypatch, config
+):
+    import numpy as np
+
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    model, layers, slots, blocks, context = CELL_PROGRAMS[config]
+    cfg = resolve_attn_impl(
+        get_model_config(model).replace(n_layers=layers, dtype="bfloat16"),
+        "pallas",
+    )
+    S = _spec(one_chip)
+    place = functools.partial(_placed, sharding=one_chip)
+    params = place(jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0))))
+    state, sparams = place(jax.eval_shape(lambda: G.init_slots(slots, cfg.vocab_size)))
+    pool = place(jax.eval_shape(lambda: EP.init_pool(cfg, blocks, 128)))
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
+    assert pool_bytes > 2e9
+    table = S((slots, context // 128), jnp.int32)
+    key = place(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    chunk = EP.decode_slots_paged.lower(
+        cfg, params, state, pool, table, key, sparams, num_steps=16,
+    ).compile()
+    tile, width = 8, max(128, (slots + 1) * 8)
+    entries = [(b, 0, 1, EP.RAGGED_DECODE) for b in range(slots)]
+    meta, tok_row, tok_pos, offsets, _ = EP.build_ragged_meta(
+        entries, width=width, tile=tile)
+    dev = EP.DeviceMeta(*(
+        S(a.shape, a.dtype) for a in EP.build_device_meta(
+            entries, offsets, slots, width=width, tile=tile)))
+    arm = place(jax.eval_shape(lambda: EP.idle_mixed_arm(slots, cfg.vocab_size)))
+    flat = lambda a: S(np.shape(a), np.asarray(a).dtype)  # noqa: E731
+    mixed = EP.mixed_step_ragged.lower(
+        cfg, params, S((width,), jnp.int32), flat(tok_row), flat(tok_pos),
+        S((width,), jnp.bool_), flat(meta), pool, table, state, sparams, key,
+        S((slots,), jnp.int32), arm, dev=dev,
+    ).compile()
+    for name, compiled in (("decode_slots_paged", chunk),
+                           ("mixed_step_ragged", mixed)):
+        memory = compiled.memory_analysis()
+        # the pool goes in and comes out as one buffer ...
+        assert memory.alias_size_in_bytes >= pool_bytes - 2**20, (name, memory)
+        # ... and the temporaries hold nothing of its size
+        assert memory.temp_size_in_bytes < 0.45 * pool_bytes, (name, memory)
+        assert _pool_sized_instructions(compiled.as_text(), pool) == [], name
 
 
 # -- the latent-attention, routed-expert family (ISSUE 28) ---------------------

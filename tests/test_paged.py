@@ -757,3 +757,232 @@ def test_paged_pallas_gemma2_fleet_parity():
     for w, g in zip(want, got):
         assert w["status"] == g["status"] == "success", (w, g)
         assert g["response"] == w["response"]
+
+
+# ---------------------------------------------------------------------------
+# The paged hooks' contract (ISSUE 29): the STACKED pool and a layer index.
+# forward_layers carries the pool through its layer scan and hands each
+# layer's hook the whole leaves; the hook (or, where it can, the kernel
+# itself) writes the step's tokens into that layer and no other.
+
+_HOOK_KINDS = {  # leaf kind -> (model preset, overrides)
+    "raw": ("test-llama-tiny", {}),  # head dim 16: XLA scatters into a slice
+    "wide": ("test-llama-tiny", {"head_dim_override": 128}),  # the kernel writes
+    "int8": ("test-llama-tiny", {"kv_quant": "int8"}),
+    "latent": ("test-mla-moe-tiny", {}),
+}
+_HOOK_L, _HOOK_N, _HOOK_BS, _HOOK_MB = 3, 10, 16, 3
+
+
+def _hook_case(kind, impl):
+    """(cfg, a random stacked pool of _HOOK_L layers as numpy leaves, the
+    pool as the hooks take it)."""
+    from distributed_llm_inference_tpu.config import resolve_attn_impl
+    from distributed_llm_inference_tpu.ops.kv_quant import KVQuant
+
+    preset, kw = _HOOK_KINDS[kind]
+    cfg = resolve_attn_impl(
+        get_model_config(preset, dtype="float32", **kw), impl)
+    pool = P.init_pool(cfg, _HOOK_N, _HOOK_BS, n_layers=None if kind == "latent" else _HOOK_L)
+    pool.pop("routed", None)
+    rng = np.random.default_rng(7)
+
+    def fill(a):
+        if a.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, a.shape), jnp.int8)
+        return jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+
+    pool = jax.tree.map(fill, pool)
+    return cfg, pool
+
+
+def _hook_operands(cfg, kind, rows, rng):
+    """(q, k, v) of `rows` single-token batch rows in the hook's shapes."""
+    f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa: E731
+    if kind == "latent":
+        R = cfg.latent_row
+        return f32(rows, 1, cfg.n_heads, R), f32(rows, 1, 1, R), None
+    Dh = cfg.head_dim
+    return (f32(rows, 1, cfg.n_heads, Dh), f32(rows, 1, cfg.n_kv_heads, Dh),
+            f32(rows, 1, cfg.n_kv_heads, Dh))
+
+
+def _leaf_arrays(leaf):
+    """A pool leaf's arrays as numpy (an int8 leaf is data and scales)."""
+    return [np.asarray(a) for a in jax.tree.leaves(leaf)]
+
+
+def _expected_token(kind, x):
+    """What one token x [rows, 1, KV, Dh] leaves in each array of a leaf."""
+    from distributed_llm_inference_tpu.ops.kv_quant import quantize_chunk
+
+    if kind == "int8":
+        return [np.asarray(a)[:, 0] for a in quantize_chunk(x)]
+    return [np.asarray(x)[:, 0]]
+
+
+def _check_hook_writes(kind, before, after, layer, k, v, blk, off, live):
+    """The layer holds the live rows' tokens at (blk, :, off); every other
+    layer is untouched bit for bit, and so is everything else in the layer
+    outside the trash block and the rows' own (block, offset) cells."""
+    for name, x in (("k", k), ("v", v)):
+        if x is None:
+            continue
+        key = name if name in before else None
+        leaves = ([(s, before[s], after[s]) for s in before] if key is None
+                  else [(key, before[key], after[key])])
+        for stack, b_leaf, a_leaf in leaves:
+            for b, a, want in zip(_leaf_arrays(b_leaf), _leaf_arrays(a_leaf),
+                                  _expected_token(kind, x)):
+                others = [i for i in range(b.shape[0]) if i != layer]
+                if key is None and stack != "moe":  # the other latent stack
+                    np.testing.assert_array_equal(a, b)
+                    continue
+                np.testing.assert_array_equal(a[others], b[others])
+                allowed = np.zeros(b.shape[1:], bool)
+                allowed[P.TRASH_BLOCK] = True
+                for r in range(len(blk)):
+                    allowed[blk[r], :, off[r]] = True
+                    if live[r]:
+                        np.testing.assert_array_equal(
+                            a[layer, blk[r], :, off[r]], want[r])
+                np.testing.assert_array_equal(
+                    np.where(allowed, 0, a[layer]), np.where(allowed, 0, b[layer]))
+
+
+def _call_hook(hook, cfg, kind, q, k, v, pool, pos, mask, gate, layer):
+    """The hook as forward_layers' scan calls it; the latent family hands
+    it the "moe" stack's leaf. Returns (attn, the pool after)."""
+    if kind == "latent":
+        attn, new, _ = hook(cfg, q, k, None, pool["moe"], None, pos, mask,
+                            gate, None, None, jnp.int32(layer))
+        return attn, {**pool, "moe": new}
+    attn, nk, nv = hook(cfg, q, k, v, pool["k"], pool["v"], pos, mask, gate,
+                        None, None, jnp.int32(layer))
+    return attn, {"k": nk, "v": nv}
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("kind", sorted(_HOOK_KINDS))
+def test_paged_hook_writes_its_layer_of_the_stacked_pool(kind, impl):
+    """Decode rows at a block's last position, a block's first and the
+    middle of one, and a freed slot; the first layer and the last; and a
+    gated-off write (the pp ring's), which may touch the trash block only."""
+    from distributed_llm_inference_tpu.ops.attention import slot_causal_mask
+
+    cfg, pool = _hook_case(kind, impl)
+    bs, MB = _HOOK_BS, _HOOK_MB
+    table = jnp.asarray([[3, 7, 2], [5, 1, 0], [4, 6, 8], [9, 0, 0]], jnp.int32)
+    pos = jnp.asarray([bs - 1, bs, 2 * bs + 5, 3], jnp.int32)
+    active = jnp.asarray([True, True, True, False])
+    q, k, v = _hook_operands(cfg, kind, 4, np.random.default_rng(1))
+    mask = slot_causal_mask(pos, 1, MB * bs)
+    blk = np.asarray(table)[np.arange(4), np.asarray(pos) // bs]
+    off = np.asarray(pos) % bs
+    hook = P.make_paged_hook(table, active)
+    assert hook.paged
+    L = pool["moe" if kind == "latent" else "k"].shape[0]
+    attn = {}
+    for layer in (0, L - 1):
+        attn[layer], after = _call_hook(hook, cfg, kind, q, k, v, pool, pos,
+                                        mask, None, layer)
+        _check_hook_writes(kind, pool, after, layer, k, v, blk, off,
+                           np.asarray(active))
+    if kind != "latent":  # "a latent cache has no gated write (no pp)"
+        _, after = _call_hook(hook, cfg, kind, q, k, v, pool, pos, mask,
+                              jnp.asarray(False), 0)
+        _check_hook_writes(kind, pool, after, 0, k, v,
+                           np.zeros(4, int), off, np.zeros(4, bool))
+    if impl == "pallas":  # the two attention paths agree on the live rows
+        other, _ = _hook_case(kind, "xla")
+        want, _ = _call_hook(P.make_paged_hook(table, active), other, kind, q,
+                             k, v, pool, pos, mask, None, L - 1)
+        np.testing.assert_allclose(np.asarray(attn[L - 1])[:3],
+                                   np.asarray(want)[:3], atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("kind", sorted(_HOOK_KINDS))
+def test_ragged_fill_hook_writes_its_layer_of_the_stacked_pool(kind, impl):
+    """A prefill chunk that straddles a block edge, a decode row at a
+    block's first position and launch padding, on the flat token axis."""
+    cfg, pool = _hook_case(kind, impl)
+    bs = _HOOK_BS
+    table = jnp.asarray([[3, 7, 2], [5, 1, 0]], jnp.int32)
+    entries = [(0, bs - 5, 13, P.RAGGED_PREFILL), (1, bs, 1, P.RAGGED_DECODE)]
+    meta, tok_row, tok_pos, _, _ = P.build_ragged_meta(entries, width=32, tile=8)
+    q, k, v = _hook_operands(cfg, kind, 32, np.random.default_rng(2))
+    live = tok_row >= 0
+    blk = np.where(live, np.asarray(table)[np.maximum(tok_row, 0), tok_pos // bs],
+                   P.TRASH_BLOCK)
+    off = tok_pos % bs
+    hook = P.make_ragged_fill_hook(table, jnp.asarray(meta), jnp.asarray(tok_row))
+    assert hook.paged
+    pos = jnp.asarray(tok_pos)
+    L = pool["moe" if kind == "latent" else "k"].shape[0]
+    attn = {}
+    for layer in (0, L - 1):
+        attn[layer], after = _call_hook(hook, cfg, kind, q, k, v, pool, pos,
+                                        None, None, layer)
+        _check_hook_writes(kind, pool, after, layer, k, v, blk, off, live)
+    if kind != "latent":
+        _, after = _call_hook(hook, cfg, kind, q, k, v, pool, pos, None,
+                              jnp.asarray(False), 0)
+        _check_hook_writes(kind, pool, after, 0, k, v, np.zeros(32, int),
+                           off, np.zeros(32, bool))
+    if impl == "pallas":
+        other, _ = _hook_case(kind, "xla")
+        want, _ = _call_hook(hook, other, kind, q, k, v, pool, pos, None, None,
+                             L - 1)
+        np.testing.assert_allclose(np.asarray(attn[L - 1])[live],
+                                   np.asarray(want)[live], atol=1e-4, rtol=1e-4)
+
+
+def _slice_and_restack(hook):
+    """The contract before ISSUE 29, through today's hook: cut the layer's
+    slice out of the pool, run the hook on that one-layer pool, put the
+    slice back. What the layer scan did with the pool as its xs and ys."""
+
+    def cut(leaf, layer):
+        return None if leaf is None else jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0), leaf)
+
+    def back(leaf, new, layer):
+        return None if leaf is None else jax.tree.map(
+            lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n[0], layer, 0),
+            leaf, new)
+
+    def sliced(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
+               valid_start, window_flag, layer):
+        attn, nk, nv = hook(cfg, q, k, v, cut(cache_k, layer),
+                            cut(cache_v, layer), pos, mask, update_gate,
+                            valid_start, window_flag, jnp.int32(0))
+        return attn, back(cache_k, nk, layer), back(cache_v, nv, layer)
+
+    sliced.paged, sliced.live = True, hook.live
+    return sliced
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize(
+    "preset", ["test-llama-tiny", "test-olmo2-tiny", "mistral-shaped",
+               "test-mla-moe-tiny", "wide-head"])
+def test_carried_pool_equals_slice_and_restack(preset, impl):
+    """tests/dense_equal.py's chunked prefill + decode + prefix-hit repeat:
+    the pool carried through the layer scan and indexed by the layer holds,
+    and yields, what a pool cut into layer slices and stacked again does.
+    The same greedy tokens; logits and pool leaves to the last bits (the
+    CPU backend fuses the two graphs differently, dense_equal.py's
+    docstring: run from the command line, against a checkout of the parent
+    and unfused, the two dumps are the same bits)."""
+    import dense_equal
+
+    carried = dense_equal.dump((preset,), (impl,))
+    sliced = dense_equal.dump((preset,), (impl,), wrap=_slice_and_restack)
+    assert sorted(carried) == sorted(sliced) and len(carried) >= 4
+    for key, got in carried.items():
+        if key.endswith(".tokens"):
+            np.testing.assert_array_equal(got, sliced[key])
+        else:
+            assert got.any()
+            np.testing.assert_allclose(got, sliced[key], rtol=0, atol=1e-5)
