@@ -9,8 +9,8 @@ way callgraph.py did traced reachability:
   * `WIRE_LINKS` — the ONE symbolic bytes-per-launch model of every
     accounted wire link. The backends route `dli_pp_wire_bytes_total`
     accounting through `link_bytes` (parallel/pipeline.py
-    `_account_link`), so the counters, the bench `comms_report` leg,
-    and the `--comms` CLI report all derive from the same table; a
+    `_account_link`), so the counters and the `--comms` CLI report
+    derive from the same table; a
     hand-maintained per-call-seam copy cannot drift because it no
     longer exists.
   * `wire_link_bytes` — the canonical per-hop formula

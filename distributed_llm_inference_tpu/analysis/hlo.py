@@ -354,17 +354,16 @@ def check_mixed_no_recompile(engine=None) -> list:
 
 
 def _spec_mixed_args(engine, n_spec: int, n_draft: int, chunk: int,
-                     width: int = 32, k_max: int = 4,
-                     device_meta: bool = False):
-    """Operand tuple for the SPECULATIVE mixed scheduler step: the
-    _mixed_args fleet plus `n_spec` verify rows of `n_draft` drafts each
-    (n-gram mode — the drafts ride the host token plan). The accept
-    pattern is pure DATA (token contents vs the model's argmax), so
-    every composition must share one compiled program. With
-    device_meta=True the decode/verify rows' positions are marked for
-    on-device substitution (engine/paged.DeviceMeta) — the derivation
-    pattern and the adaptive per-slot K are plan data too, so every
-    (accept pattern, K) pair must share the one device-meta program."""
+                     width: int = 32, k_max: int = 4):
+    """Operand tuple for the SPECULATIVE mixed scheduler step, as the
+    engine dispatches it: the _mixed_args fleet plus `n_spec` verify
+    rows of `n_draft` drafts each (n-gram mode — the drafts ride the
+    host token plan), the decode/verify rows' positions marked for
+    on-device substitution (engine/paged.DeviceMeta). The accept
+    pattern is pure DATA (token contents vs the model's argmax), and
+    the derivation pattern and the adaptive per-slot K are plan data
+    too, so every (accept pattern, K) pair must share one compiled
+    program."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -419,14 +418,6 @@ def _spec_mixed_args(engine, n_spec: int, n_draft: int, chunk: int,
         jnp.asarray(dec_on), jnp.asarray(sp_on), jnp.asarray(sp_idx),
         jnp.asarray(sp_nd),
     )
-    base = (
-        cfg, engine.backend.params, jnp.asarray(toks), jnp.asarray(tok_row),
-        jnp.asarray(tok_pos), jnp.asarray(dec_flag), jnp.asarray(meta),
-        pool, table, state, sparams, jax.random.PRNGKey(0),
-        jnp.asarray(dec_idx), arm, spec,
-    )
-    if not device_meta:
-        return base
     t_on, t_off, k_on, k_off = EP.build_device_meta(
         entries, offsets, B, width=width, tile=8,
     )
@@ -434,14 +425,20 @@ def _spec_mixed_args(engine, n_spec: int, n_draft: int, chunk: int,
         jnp.asarray(t_on), jnp.asarray(t_off),
         jnp.asarray(k_on), jnp.asarray(k_off),
     )
-    return base + (None, dev)  # spec_toks=None, dev
+    return (
+        cfg, engine.backend.params, jnp.asarray(toks), jnp.asarray(tok_row),
+        jnp.asarray(tok_pos), jnp.asarray(dec_flag), jnp.asarray(meta),
+        pool, table, state, sparams, jax.random.PRNGKey(0),
+        jnp.asarray(dec_idx), arm, spec, None, dev,  # spec_toks=None
+    )
 
 
 def lower_spec_mixed_step(engine=None, n_spec: int = 1, n_draft: int = 3,
                           chunk: int = 9) -> str:
     """StableHLO of the REAL speculative mixed launch (verify rows +
-    decode rows + prefill chunks in one program) — declared pool
-    donation intact, traced accept/reject inside."""
+    decode rows + prefill chunks in one program, decode/verify positions
+    substituted on device from slot state) — declared pool donation
+    intact, traced accept/reject inside."""
     from ..engine import paged as EP
 
     engine = engine or tiny_engine()
@@ -452,26 +449,28 @@ def lower_spec_mixed_step(engine=None, n_spec: int = 1, n_draft: int = 3,
 
 def check_spec_mixed_shape_stability(engine=None) -> list:
     """Two DIFFERENT speculative compositions (verify-row count, draft
-    length, chunk length) must lower to the IDENTICAL program: accept
-    patterns and per-slot draft lengths are plan DATA — any
-    composition-dependent shape would recompile per accept pattern."""
+    length — the adaptive-K throttle's output — and chunk length) must
+    lower to the IDENTICAL program: accept patterns, derivation masks
+    and per-slot draft lengths are plan DATA — any composition-dependent
+    shape would recompile per accept pattern or per adaptive-K change."""
     engine = engine or tiny_engine()
     a = lower_spec_mixed_step(engine, n_spec=1, n_draft=3, chunk=9)
     b = lower_spec_mixed_step(engine, n_spec=2, n_draft=2, chunk=14)
     if a != b:
         return [
             "speculative mixed step lowered DIFFERENT programs for two "
-            "verify-row compositions — some per-step spec plan value "
-            "became shape-specializing (compile-per-accept-pattern in "
-            "production)"
+            "verify-row compositions — some per-step spec plan, "
+            "derivation or adaptive-K value became shape-specializing "
+            "(compile-per-accept-pattern / compile-per-K in production)"
         ]
     return []
 
 
 def check_spec_mixed_no_recompile(engine=None) -> list:
     """Execute the speculative mixed step with two different verify
-    compositions; the jit cache must not grow (one compiled program for
-    every accept pattern — the machine check ISSUE 13 names)."""
+    compositions AND adaptive-K values; the jit cache must not grow
+    (one compiled program for every accept pattern and K — the machine
+    check ISSUES 13 and 15 name)."""
     import jax
 
     from ..engine import paged as EP
@@ -487,70 +486,8 @@ def check_spec_mixed_no_recompile(engine=None) -> list:
         return [
             f"speculative mixed step recompiled across verify "
             f"compositions (jit cache grew {size_after_first} -> "
-            f"{size_after_second}) — accept patterns must stay traced "
-            f"data"
-        ]
-    return []
-
-
-def lower_spec_devmeta_step(engine=None, n_spec: int = 1, n_draft: int = 3,
-                            chunk: int = 9) -> str:
-    """StableHLO of the DEVICE-META speculative mixed launch (ISSUE 15:
-    decode/verify positions substituted on device from slot state, the
-    program the unfrozen back-to-back serving path dispatches)."""
-    from ..engine import paged as EP
-
-    engine = engine or tiny_engine()
-    return EP.mixed_step_ragged.lower(
-        *_spec_mixed_args(engine, n_spec, n_draft, chunk, device_meta=True)
-    ).as_text()
-
-
-def check_spec_devmeta_shape_stability(engine=None) -> list:
-    """Two DIFFERENT device-meta compositions — verify-row count AND
-    draft length (the adaptive-K throttle's output) — must lower to the
-    IDENTICAL program: derivation masks and per-slot K are plan data,
-    so a composition-dependent shape would recompile per accept pattern
-    or per adaptive-K change."""
-    engine = engine or tiny_engine()
-    a = lower_spec_devmeta_step(engine, n_spec=1, n_draft=3, chunk=9)
-    b = lower_spec_devmeta_step(engine, n_spec=2, n_draft=2, chunk=14)
-    if a != b:
-        return [
-            "device-meta speculative step lowered DIFFERENT programs for "
-            "two verify/K compositions — some derivation or adaptive-K "
-            "value became shape-specializing (compile-per-accept-pattern "
-            "/ compile-per-K in production)"
-        ]
-    return []
-
-
-def check_spec_devmeta_no_recompile(engine=None) -> list:
-    """Execute the device-meta speculative step with two different
-    verify compositions AND adaptive-K values; the jit cache must not
-    grow — one compiled program across accept patterns and K values,
-    the ISSUE 15 machine check."""
-    import jax
-
-    from ..engine import paged as EP
-
-    engine = engine or tiny_engine()
-    out = EP.mixed_step_ragged(
-        *_spec_mixed_args(engine, 1, 3, 9, device_meta=True)
-    )
-    jax.block_until_ready(out[0])
-    size_after_first = EP.mixed_step_ragged._cache_size()
-    out = EP.mixed_step_ragged(
-        *_spec_mixed_args(engine, 2, 2, 14, device_meta=True)
-    )
-    jax.block_until_ready(out[0])
-    size_after_second = EP.mixed_step_ragged._cache_size()
-    if size_after_second > size_after_first:
-        return [
-            f"device-meta speculative step recompiled across verify/K "
-            f"compositions (jit cache grew {size_after_first} -> "
-            f"{size_after_second}) — derivation masks and draft lengths "
-            f"must stay traced data"
+            f"{size_after_second}) — accept patterns, derivation masks "
+            f"and draft lengths must stay traced data"
         ]
     return []
 
@@ -944,9 +881,11 @@ def run_hlo_checks() -> dict:
     results["sched-mixed-recompile-guard"] = check_mixed_no_recompile(engine)
 
     # speculative mixed step (ISSUE 13: draft-then-verify inside the
-    # mixed launch): the verify rows' accept/reject must stay fully
-    # traced — zero host callbacks, pool donation intact, and ONE
-    # compiled program across every accept pattern / verify composition
+    # mixed launch; ISSUE 15: decode/verify q_start and positions
+    # derived on device from slot state): the verify rows' accept/reject
+    # must stay fully traced — zero host callbacks, pool donation
+    # intact, and ONE compiled program across every accept pattern /
+    # verify composition / adaptive-K value
     spec_mixed = lower_spec_mixed_step(engine)
     results["spec-mixed-callbacks"] = check_no_host_callbacks(spec_mixed)
     results["spec-mixed-donation"] = check_donation(spec_mixed, min_aliased=2)
@@ -954,20 +893,6 @@ def run_hlo_checks() -> dict:
         engine
     )
     results["spec-mixed-recompile-guard"] = check_spec_mixed_no_recompile(
-        engine
-    )
-
-    # device-meta speculative step (ISSUE 15: decode/verify q_start and
-    # positions derived on device from slot state — the unfrozen
-    # back-to-back launch path): zero host callbacks, pool donation, and
-    # ONE compiled program across accept patterns AND adaptive-K values
-    spec_dev = lower_spec_devmeta_step(engine)
-    results["spec-devmeta-callbacks"] = check_no_host_callbacks(spec_dev)
-    results["spec-devmeta-donation"] = check_donation(spec_dev, min_aliased=2)
-    results["spec-devmeta-shape-stability"] = (
-        check_spec_devmeta_shape_stability(engine)
-    )
-    results["spec-devmeta-recompile-guard"] = check_spec_devmeta_no_recompile(
         engine
     )
 

@@ -139,9 +139,9 @@ class ModelConfig:
     #   kv_quant      — KV-cache HBM bytes (the context/slot-count bound)
     #   pp_wire_quant — inter-stage ICI bytes (the deep-pipeline bound)
     # Weight-only quantization of the matmul weights (ops/quant.py):
-    # None | "int8" | "int4". int8 halves decode's HBM bytes/token
-    # (~1.6x measured on v5e); int4 halves them again (packed nibbles,
-    # group-wise scales). Both families; works on the single device AND
+    # None | "int8" | "int4". int8 halves decode's HBM bytes/token;
+    # int4 halves them again (packed nibbles, group-wise scales).
+    # Neither is measured on the serving path. Both families; works on the single device AND
     # the SPMD mesh backends (quantized leaves shard like their weights).
     quant: Optional[str] = None
     # KV-CACHE quantization (ops/kv_quant.py): "int8" stores K/V as int8
@@ -514,8 +514,14 @@ class EngineConfig:
     # Accept/reject is fully traced (match-prefix + correction token on
     # device, packed into the existing fetch — zero host syncs, one
     # compiled program for every accept pattern). Greedy acceptance is
-    # bit-identical to plain decode. spec_draft_len = drafted tokens per
-    # verify row (0 disables the machinery entirely).
+    # bit-identical to plain decode. Decode/verify rows read their
+    # q_start / per-token positions from the device-resident slot state
+    # (engine/paged.DeviceMeta + apply_device_meta), so a slot with an
+    # unfetched verify row is never frozen: verify rows launch back to
+    # back under lag pipelining, and the scheduler sizes each slot's
+    # next draft from its acceptance-rate EWMA
+    # (TokenBudgetScheduler.spec_slot_k). spec_draft_len = drafted
+    # tokens per verify row (0 disables the machinery entirely).
     spec_draft_len: int = 4
     # Fleet-wide self-speculation: True speculates for EVERY eligible
     # greedy slot; False speculates only for requests that ask
@@ -532,19 +538,6 @@ class EngineConfig:
     # n-gram lookup. A draft already attached via engine.set_draft()
     # takes precedence over loading this name. None = n-gram drafts.
     spec_draft_model: Optional[str] = None
-    # Device-derived launch metadata for the speculative mixed launch
-    # (engine/paged.DeviceMeta + apply_device_meta): decode/verify rows
-    # read their q_start / per-token positions from the device-resident
-    # slot state instead of the host position model, so a slot with an
-    # unfetched verify row is never frozen — every eligible slot submits
-    # a verify row EVERY scheduler step, back to back under lag
-    # pipelining, and the packed fetch only confirms emissions. On top,
-    # the scheduler sizes each slot's next draft adaptively from its
-    # acceptance-rate EWMA (TokenBudgetScheduler.spec_slot_k). False
-    # pins the PR-13 skip-until-fetched behavior (host-planned q_start,
-    # one verify row per fetch round trip) — kept as the bench.py
-    # `spec_lag` baseline.
-    spec_device_meta: bool = True
     # SLO-aware KV preemption (engine/continuous.py _preempt_for): when a
     # paged admission still cannot get blocks after the evict-
     # unreferenced-chains retry, the scheduler preempts the lowest-SLO-

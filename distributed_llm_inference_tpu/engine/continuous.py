@@ -48,29 +48,25 @@ fully traced (engine/paged.spec_verify — match-prefix + correction token
 on device, packed into the existing fetch), the slot's position simply
 advances by the accepted count (rejected draft K/V beyond the new
 frontier is overwritten before it can be attended or shadow-captured),
-and the host position model resyncs from the fetched advance. With
-device-derived launch metadata (ISSUE 15, engine_cfg.spec_device_meta,
-default ON) the kernel reads each decode/verify row's q_start and
-per-token positions from the device-resident slot state
-(engine/paged.DeviceMeta + apply_device_meta), so an unfetched verify
-row never freezes its slot: every eligible slot submits a verify row
-EVERY scheduler step, back to back under lag pipelining, the host
-drafts from an OPTIMISTIC history (fetched tokens + its own pending
-predicted windows — a misprediction only lowers acceptance, never
-correctness: the verify accepts only the model's own argmax), and the
-packed fetch confirms emissions after the fact. Per-slot adaptive K
-(TokenBudgetScheduler.spec_slot_k): an acceptance-rate EWMA fed from
-the same fetch sizes each slot's next draft between 0 and
-spec_draft_len. spec_device_meta=False pins the PR-13 behavior — a
-slot with an unfetched verify row is skipped (frozen on device via
-SpecPlan.dec_on) so the host-planned q_start stays exact — kept as the
-bench.py spec_lag baseline. Speculated tokens debit step_token_budget
-(TokenBudgetScheduler.spec_draft_len), so the SLO layer throttles K to 0
-under decode TPOT pressure — speculation accelerates idle fleets and
-self-disables under load. Greedy output is bit-identical to
-non-speculative decode (spec_verify replicates slot_step token for
-token), crash/preemption salvage included (unfetched verify emissions
-drop exactly like unfetched chunks).
+and the host position model resyncs from the fetched advance. The
+launch metadata is device-derived (ISSUE 15): the kernel reads each
+decode/verify row's q_start and per-token positions from the
+device-resident slot state (engine/paged.DeviceMeta +
+apply_device_meta), so an unfetched verify row never freezes its slot:
+every eligible slot submits a verify row EVERY scheduler step, back to
+back under lag pipelining, the host drafts from an OPTIMISTIC history
+(fetched tokens + its own pending predicted windows — a misprediction
+only lowers acceptance, never correctness: the verify accepts only the
+model's own argmax), and the packed fetch confirms emissions after the
+fact. Per-slot adaptive K (TokenBudgetScheduler.spec_slot_k): an
+acceptance-rate EWMA fed from the same fetch sizes each slot's next
+draft between 0 and spec_draft_len. Speculated tokens debit
+step_token_budget (TokenBudgetScheduler.spec_draft_len), so the SLO
+layer throttles K to 0 under decode TPOT pressure — speculation
+accelerates idle fleets and self-disables under load. Greedy output is
+bit-identical to non-speculative decode (spec_verify replicates
+slot_step token for token), crash/preemption salvage included
+(unfetched verify emissions drop exactly like unfetched chunks).
 
 Failure containment (ARCHITECTURE.md "Failure containment"): the worker
 loop runs under a SUPERVISOR (_loop/_supervise). A crash anywhere in the
@@ -473,15 +469,14 @@ class ContinuousEngine:
         )
         # chunked-mode host state: pending PrefillJobs (arrival order),
         # slot -> job for slots whose prompt is still landing, and the
-        # host's position model per slot. With device-derived launch
-        # metadata (spec_device_meta) the kernel reads decode/verify
-        # positions from slot state and this model is a LAGGED
-        # accounting view (launch entries carry it only as a
-        # placeholder; verify fetches catch it up by the accepted
-        # advance); without it, it must be exact for live rows — it IS
-        # the decode tiles' kernel metadata there (over-advance on rows
-        # that went inactive since the last fetch is masked garbage,
-        # the frozen-row argument)
+        # host's position model per slot. On a fleet that can speculate
+        # (_spec_capable) the kernel reads decode/verify positions from
+        # slot state and this model is a LAGGED accounting view (launch
+        # entries carry it only as a placeholder; verify fetches catch
+        # it up by the accepted advance); with spec_draft_len 0 it must
+        # be exact for live rows — it IS the decode tiles' kernel
+        # metadata there (over-advance on rows that went inactive since
+        # the last fetch is masked garbage, the frozen-row argument)
         self._jobs: list = []
         self._prefilling: dict = {}
         self._host_pos = np.zeros((self.n_slots,), np.int64)
@@ -500,31 +495,20 @@ class ContinuousEngine:
             )
         # Speculative decoding on the mixed fleet (ISSUE 13 + 15):
         # eligible greedy decode slots submit [current + K-draft] verify
-        # rows inside the mixed launch. Two position disciplines:
-        #   * spec_device_meta (default): q_start / per-token positions
-        #     derive ON DEVICE from slot state (engine/paged.DeviceMeta)
-        #     — verify rows launch EVERY step, back to back; the host
-        #     keeps a FIFO of pending (unfetched) verify launches per
-        #     slot (_spec_pending) carrying each launch's predicted
-        #     window so n-gram drafting continues from the optimistic
-        #     frontier, plus the advance upper bound for the block-
-        #     capacity clamp.
-        #   * legacy (spec_device_meta=False, the bench baseline): a
-        #     slot with an unfetched verify row is skipped from planning
-        #     (_spec_inflight) until the packed fetch resyncs its
-        #     position — the PR-13 alternation.
+        # rows inside the mixed launch. q_start / per-token positions
+        # derive ON DEVICE from slot state (engine/paged.DeviceMeta), so
+        # verify rows launch EVERY step, back to back; the host keeps a
+        # FIFO of pending (unfetched) verify launches per slot
+        # (_spec_pending) carrying each launch's predicted window so
+        # n-gram drafting continues from the optimistic frontier, plus
+        # the advance upper bound for the block-capacity clamp.
         ecfg = engine.engine_cfg
         self._spec_k_max = max(0, int(getattr(ecfg, "spec_draft_len", 0)))
         self._spec_auto = bool(getattr(ecfg, "spec_decode", False))
         self._spec_capable = bool(self._chunked and self._spec_k_max > 0)
-        self._spec_devmeta = bool(
-            self._spec_capable
-            and getattr(ecfg, "spec_device_meta", True)
-        )
-        self._spec_inflight: dict = {}  # legacy: slot -> (req, n_draft)
-        # device-meta mode: slot -> FIFO of dicts per unfetched verify
-        # launch ({req, nd, pred (drafts + predicted correction, n-gram
-        # mode), adv (position-advance upper bound nd + 1)})
+        # slot -> FIFO of dicts per unfetched verify launch ({req, nd,
+        # pred (drafts + predicted correction, n-gram mode), adv
+        # (position-advance upper bound nd + 1)})
         self._spec_pending: dict = {}
         # amortized decode-chunk launches not yet fetched: their
         # emissions are unpredictable many-token advances, so drafting
@@ -536,8 +520,7 @@ class ContinuousEngine:
         self.spec_drafted = 0
         self.spec_accepted = 0
         # verify rows launched while an earlier one was still unfetched
-        # — the back-to-back counter the lag-pipelining tests pin (zero
-        # by construction in the legacy mode)
+        # — the back-to-back counter the lag-pipelining tests pin
         self.spec_pipelined = 0
         # cfg-gated draft model (the decode_draft_speculative flavor):
         # a small same-tokenizer model proposes drafts device-side,
@@ -1618,11 +1601,10 @@ class ContinuousEngine:
                 "mode": "draft_model" if self._draft_mode else "ngram",
                 "draft_len": self._spec_k_max,
                 "fleet_wide": self._spec_auto,
-                "device_meta": self._spec_devmeta,
                 "launches": self.spec_launches,
                 "drafted_tokens": self.spec_drafted,
                 "accepted_tokens": self.spec_accepted,
-                "inflight_rows": len(self._spec_inflight) + sum(
+                "inflight_rows": sum(
                     len(v) for v in self._spec_pending.values()
                 ),
                 # verify rows launched while an earlier one was still
@@ -1684,9 +1666,7 @@ class ContinuousEngine:
         # speculation bookkeeping dies with the fleet too: unfetched
         # verify rows are unfetched launches (their emissions drop, the
         # salvage record holds fetched tokens only — same contract);
-        # pending device-meta windows and the chunk-fetch gate reset
-        # with them
-        self._spec_inflight.clear()
+        # pending windows and the chunk-fetch gate reset with them
         self._spec_pending.clear()
         self._chunk_unfetched = 0
         self._row_inflight[:] = 0
@@ -3028,18 +3008,14 @@ class ContinuousEngine:
             self._start_jobs()
             self._clock.mark("plan")
             spec_rows = self._plan_spec()
-            if (
-                self._jobs or spec_rows or self._spec_inflight
-                or self._spec_pending
-            ):
+            if self._jobs or spec_rows or self._spec_pending:
                 # mixed step: prefill chunks and/or verify rows ride the
                 # flat token axis with the decode rows. A slot whose
                 # verify row is still unfetched keeps the fleet on the
-                # mixed program too (legacy mode: it must stay frozen
-                # via dec_on until its position resyncs; device-meta
-                # mode: its next row's positions derive from slot state,
-                # and staying mixed keeps the per-launch emission
-                # bookkeeping uniform while verify fetches are pending)
+                # mixed program too (its next row's positions derive
+                # from slot state, and staying mixed keeps the
+                # per-launch emission bookkeeping uniform while verify
+                # fetches are pending)
                 step = self._launch_mixed(spec_rows)
             else:
                 step = self._launch_chunk()
@@ -3404,29 +3380,26 @@ class ContinuousEngine:
         the optimistic window — drafts + predicted correction — pending
         fetches extend the drafting history with).
 
-        Device-meta mode (the default): an unfetched verify row never
-        disqualifies its slot — positions derive on device, so the only
-        gates are DRAFT QUALITY ones: no amortized decode chunk may be
-        unfetched (many-token unpredictable advances), every pending
-        launch carrying the slot must be a verify launch of THIS tenant
-        with a predicted window (a pending plain row adds one token the
-        host cannot predict), and — n-gram mode — the optimistic
-        history must offer at least a 2-token window (draft + predicted
-        correction) so back-to-back drafts stay frontier-aligned under
-        full accept. Legacy mode (spec_device_meta=False) keeps the
-        PR-13 gates: previous verify row fetched, history fully fetched.
+        An unfetched verify row never disqualifies its slot — positions
+        derive on device, so the only gates are DRAFT QUALITY ones: no
+        amortized decode chunk may be unfetched (many-token
+        unpredictable advances), every pending launch carrying the slot
+        must be a verify launch of THIS tenant with a predicted window
+        (a pending plain row adds one token the host cannot predict),
+        and — n-gram mode — the optimistic history must offer at least
+        a 2-token window (draft + predicted correction) so back-to-back
+        drafts stay frontier-aligned under full accept.
 
         The scheduler picks the global K (0 under decode TPOT pressure
         — speculation self-disables under load), each slot's K is then
         sized by its acceptance EWMA (spec_slot_k — adaptive drafting),
         and clamped to its allocated blocks so a verify write can never
-        run the lblk clamp into a live block; in device-meta mode the
-        clamp uses the PESSIMISTIC frontier (host position + every
-        pending launch's maximum advance), since the device may already
-        sit that far ahead."""
+        run the lblk clamp into a live block; the clamp uses the
+        PESSIMISTIC frontier (host position + every pending launch's
+        maximum advance), since the device may already sit that far
+        ahead."""
         if not self._spec_capable:
             return {}
-        devmeta = self._spec_devmeta
         cand = []
         for b, req in enumerate(self._assignment):
             if (
@@ -3435,24 +3408,21 @@ class ContinuousEngine:
                 or not self._spec_req_ok(req)
             ):
                 continue
-            if devmeta:
-                pending = self._spec_pending.get(b, [])
-                if any(e["req"] is not req for e in pending):
-                    continue  # stale entries from the slot's previous
-                    # tenant: wait for their fetches to drain
-                if not self._draft_mode:
-                    # the n-gram planner needs an ALIGNED optimistic
-                    # history; the draft model needs none of these
-                    # gates (it proposes from true device state)
-                    if self._chunk_unfetched:
-                        continue
-                    if self._row_inflight[b] > len(pending):
-                        continue  # pending PLAIN rows: 1 unpredictable
-                        # token each — drafting would desync the frontier
-                    if any(e["pred"] is None for e in pending):
-                        continue
-            elif b in self._spec_inflight or self._row_inflight[b] != 0:
-                continue
+            pending = self._spec_pending.get(b, [])
+            if any(e["req"] is not req for e in pending):
+                continue  # stale entries from the slot's previous
+                # tenant: wait for their fetches to drain
+            if not self._draft_mode:
+                # the n-gram planner needs an ALIGNED optimistic
+                # history; the draft model needs none of these
+                # gates (it proposes from true device state)
+                if self._chunk_unfetched:
+                    continue
+                if self._row_inflight[b] > len(pending):
+                    continue  # pending PLAIN rows: 1 unpredictable
+                    # token each — drafting would desync the frontier
+                if any(e["pred"] is None for e in pending):
+                    continue
             cand.append(b)
         if not cand:
             return {}
@@ -3478,22 +3448,20 @@ class ContinuousEngine:
             # writes K/V at pos..pos+k, and positions beyond the table
             # tail-redirect to the trash block, but positions past
             # MB*bs would CLAMP into the slot's own last live block.
-            # Device-meta mode: pos is the DEVICE frontier, which may
-            # lead the host model by every pending launch's advance —
-            # clamp against the upper bound, not the lagged host value.
+            # pos is the DEVICE frontier, which may lead the host model
+            # by every pending launch's advance — clamp against the
+            # upper bound, not the lagged host value.
             from .scheduler import spec_block_cap
 
-            pending = self._spec_pending.get(b, []) if devmeta else []
+            pending = self._spec_pending.get(b, [])
             frontier = int(self._host_pos[b]) + sum(
                 e["adv"] for e in pending
             )
             blocks = len(req.block_ids) if req.block_ids else 0
             cap = spec_block_cap(blocks, bs, frontier)
-            kb = min(k, cap)
-            if devmeta:
-                # adaptive drafting: the slot's acceptance EWMA sizes
-                # its next draft (0 = plain decode row, no verify tiles)
-                kb = min(kb, self._sched.spec_slot_k(b, k))
+            # adaptive drafting: the slot's acceptance EWMA sizes its
+            # next draft (0 = plain decode row, no verify tiles)
+            kb = min(k, cap, self._sched.spec_slot_k(b, k))
             if kb < 1:
                 continue
             if self._draft_mode:
@@ -3507,23 +3475,18 @@ class ContinuousEngine:
             from .scheduler import ngram_draft
 
             hist = (req.ids or []) + head + req.tokens
-            if devmeta:
-                # optimistic frontier: assume every pending verify row
-                # fully accepts its predicted window. Wrong guesses only
-                # reject (the verify admits nothing but the model's own
-                # argmax); the fetch replaces prediction with truth.
-                # Draft kb tokens and PREDICT the correction too
-                # (window[-1]) so the next back-to-back plan stays
-                # frontier-aligned under full accept.
-                for e in pending:
-                    hist = hist + e["pred"]
-                window = ngram_draft(hist, kb + 1)
-                if len(window) >= 2:
-                    out[b] = (len(window) - 1, window[:-1], window)
-            else:
-                drafts = ngram_draft(hist, kb)
-                if drafts:
-                    out[b] = (len(drafts), drafts, None)
+            # optimistic frontier: assume every pending verify row
+            # fully accepts its predicted window. Wrong guesses only
+            # reject (the verify admits nothing but the model's own
+            # argmax); the fetch replaces prediction with truth.
+            # Draft kb tokens and PREDICT the correction too
+            # (window[-1]) so the next back-to-back plan stays
+            # frontier-aligned under full accept.
+            for e in pending:
+                hist = hist + e["pred"]
+            window = ngram_draft(hist, kb + 1)
+            if len(window) >= 2:
+                out[b] = (len(window) - 1, window[:-1], window)
         return out
 
     def _launch_mixed(self, spec_rows: Optional[dict] = None):
@@ -3531,29 +3494,21 @@ class ContinuousEngine:
         slice of pending prefill chunks — and, for slots in `spec_rows`
         ({slot: (n_draft, drafts|None, pred|None)}), a [current + draft]
         verify row instead of the 1-token decode row — in one mixed
-        ragged launch. In device-meta mode every decode/verify row's
-        positions are substituted on device (DeviceMeta), so the launch
-        is exact even while earlier verify rows are unfetched. Returns
-        the inflight tuple ("mixed", packed dev, decode snapshot,
+        ragged launch. On a fleet that can speculate every decode/verify
+        row's positions are substituted on device (DeviceMeta), so the
+        launch is exact even while earlier verify rows are unfetched.
+        Returns the inflight tuple ("mixed", packed dev, decode snapshot,
         {slot: req} completions, launch time, mutation seq, spec
         bookkeeping, launch record) or None when the fleet is empty."""
         P = self._P
         spec_rows = spec_rows or {}
-        assigned = [
+        # positions come from slot state on a fleet that can speculate,
+        # so an unfetched verify row never freezes its slot: every
+        # assigned decode slot rows EVERY step
+        active = [
             b for b, r in enumerate(self._assignment)
             if r is not None and b not in self._prefilling
         ]
-        if self._spec_devmeta:
-            # device-derived metadata: positions come from slot state,
-            # so an unfetched verify row never freezes its slot — every
-            # assigned decode slot rows EVERY step (the whole point)
-            active = assigned
-        else:
-            # legacy: a slot with an UNFETCHED verify row is skipped
-            # outright — its device position is unknown to the host
-            # until the packed fetch resyncs it, so it gets no row (and
-            # stays frozen via dec_on)
-            active = [b for b in assigned if b not in self._spec_inflight]
         # speculated tokens debit the step budget exactly like prefill
         # tokens: a verify row reserves ceil((1+k)/tile) query tiles
         tile = self._ragged_tile
@@ -3564,8 +3519,7 @@ class ContinuousEngine:
         plan = self._sched.plan(
             n_decode_tiles, self._jobs,
             active_classes={
-                self._assignment[b].slo for b in assigned
-                if self._assignment[b] is not None
+                self._assignment[b].slo for b in active
             },
         )
         if not active and not plan:
@@ -3601,7 +3555,7 @@ class ContinuousEngine:
             entries, width=W, tile=tile,
         )
         dev_dev = None
-        if self._spec_devmeta:
+        if self._spec_capable:
             # mark every decode/verify entry (the first n_dec) for
             # on-device position substitution — the host start values
             # above are placeholders for those rows
@@ -3672,9 +3626,9 @@ class ContinuousEngine:
             )
         if self._table_dev is None:
             self._table_dev = self._snapshot(self._table)
-        # the spec operands ride only when needed: launches with neither
-        # a verify row nor a frozen (unfetched-verify) slot dispatch the
-        # plain program — the pre-speculation fast path, byte-identical
+        # the spec operands ride only when needed: launches with no
+        # verify row dispatch the plain program — the pre-speculation
+        # fast path, byte-identical
         spec_plan_dev = spec_toks_dev = None
         spec_meta = None
         if self._draft_mode:
@@ -3694,7 +3648,7 @@ class ContinuousEngine:
                 self._table_dev, self.state.token, self.state.pos,
                 dev=dev_dev,
             )
-        if spec_rows or any(b in self._spec_inflight for b in assigned):
+        if spec_rows:
             spec_plan_dev = P.SpecPlan(
                 jnp.asarray(dec_on), jnp.asarray(sp_on),
                 jnp.asarray(sp_idx), jnp.asarray(sp_nd),
@@ -3703,7 +3657,7 @@ class ContinuousEngine:
                 b: (self._assignment[b], spec_rows[b][0])
                 for b in spec_rows
             }
-            if self._draft_mode and spec_rows:
+            if self._draft_mode:
                 # batched greedy draft chain from every slot's current
                 # (token, pos) over the shared block tables; the
                 # proposals feed the mixed program as a device operand —
@@ -3757,24 +3711,20 @@ class ContinuousEngine:
         # host position model + completion bookkeeping AFTER the launch
         # is enqueued (the arming rode the program itself). Verify rows
         # do NOT advance here: their advance is data-dependent (the
-        # accept count), so the host resyncs from the packed fetch —
-        # legacy mode freezes the slot until then (_spec_inflight),
-        # device-meta mode records the pending launch (predicted window
-        # + advance bound) and keeps submitting rows.
+        # accept count), so the host resyncs from the packed fetch; the
+        # pending launch is recorded (predicted window + advance bound)
+        # and the slot keeps submitting rows.
         for b in active:
             self._row_inflight[b] += 1
             if b in spec_rows:
-                if self._spec_devmeta:
-                    nd, _drafts, pred = spec_rows[b]
-                    lst = self._spec_pending.setdefault(b, [])
-                    if lst:
-                        self.spec_pipelined += 1
-                    lst.append({
-                        "req": self._assignment[b], "nd": nd,
-                        "pred": pred, "adv": nd + 1,
-                    })
-                else:
-                    self._spec_inflight[b] = spec_meta[b]
+                nd, _drafts, pred = spec_rows[b]
+                lst = self._spec_pending.setdefault(b, [])
+                if lst:
+                    self.spec_pipelined += 1
+                lst.append({
+                    "req": self._assignment[b], "nd": nd,
+                    "pred": pred, "adv": nd + 1,
+                })
             else:
                 self._host_pos[b] += 1
         if spec_rows:
@@ -3837,9 +3787,7 @@ class ContinuousEngine:
         self._m_ragged_tiles.labels(state="live").inc(live_tiles)
         # decode snapshot: only rows DECODING at launch (mid-prefill rows
         # emit nothing; the completing slot's first decode token arrives
-        # with the NEXT launch; legacy-mode slots frozen behind an
-        # unfetched verify row carry no row at all) — attribution
-        # discipline as ever
+        # with the NEXT launch) — attribution discipline as ever
         snapshot = [
             self._assignment[b] if b in active else None for b in range(B)
         ]
@@ -3847,8 +3795,7 @@ class ContinuousEngine:
             self._prof_note_launch(t_launch, snapshot, rec)
         return (
             "mixed", packed, snapshot, completions, t_launch,
-            self._mutation_seq,
-            spec_meta if spec_plan_dev is not None else None, rec,
+            self._mutation_seq, spec_meta, rec,
         )
 
     def _fresh_arm(self):
@@ -3883,12 +3830,6 @@ class ContinuousEngine:
         # ONE fetch per step
         packed = self._fetch(packed_dev, t_launch, rec)
         emitted, mask, active, firsts, armed = packed[:5]
-        sp_emit = sp_mask = sp_adv = None
-        if spec_meta is not None:
-            K1 = self._spec_k_max + 1
-            sp_emit = packed[5 : 5 + K1]
-            sp_mask = packed[5 + K1 : 5 + 2 * K1].astype(bool)
-            sp_adv = packed[5 + 2 * K1]
         now = time.time()
         for slot, req in completions.items():
             if req.done.is_set() or req.drop_seq > seq:
@@ -3924,6 +3865,9 @@ class ContinuousEngine:
             # deadline/finalize/shadow discipline to both uniformly
             B = self.n_slots
             K1 = self._spec_k_max + 1
+            sp_emit = packed[5 : 5 + K1]
+            sp_mask = packed[5 + K1 : 5 + 2 * K1].astype(bool)
+            sp_adv = packed[5 + 2 * K1]
             em = np.zeros((K1, B), emitted.dtype)
             mk = np.zeros((K1, B), bool)
             em[0] = emitted
@@ -3931,13 +3875,12 @@ class ContinuousEngine:
             for slot, (req, nd) in spec_meta.items():
                 em[:, slot] = sp_emit[:, slot]
                 mk[:, slot] = sp_mask[:, slot]
-                self._spec_inflight.pop(slot, None)
                 pend = self._spec_pending.get(slot)
                 if pend:
-                    # device-meta mode: this fetch confirms the slot's
-                    # OLDEST pending verify launch (fetches are FIFO) —
-                    # its predicted window retires; the actual emissions
-                    # land in req.tokens via _distribute below
+                    # this fetch confirms the slot's OLDEST pending
+                    # verify launch (fetches are FIFO) — its predicted
+                    # window retires; the actual emissions land in
+                    # req.tokens via _distribute below
                     pend.pop(0)
                     if not pend:
                         del self._spec_pending[slot]
@@ -3949,8 +3892,7 @@ class ContinuousEngine:
                 ):
                     # position resync: the verify advanced the slot by
                     # the accepted count (+1 on an EOS step) — the host
-                    # model catches up (and in legacy mode the slot
-                    # re-enters the next launch plan)
+                    # model catches up
                     self._host_pos[slot] += int(sp_adv[slot])
                     # adaptive-K feedback: the slot's acceptance EWMA
                     # sizes its next draft (same packed fetch, zero

@@ -670,8 +670,7 @@ class InferenceEngine:
         # fleet speculative-decoding families (engine/continuous.py
         # labels them when the mixed fleet speculates — ISSUE 13):
         # draft/accept/reject token flow, verify-row launches by draft
-        # source, and the accepted-tokens-per-launch distribution the
-        # bench leg's headline derives from
+        # source, and the accepted-tokens-per-launch distribution
         self.metrics.counter(
             "dli_spec_drafted_tokens_total",
             "draft tokens submitted in mixed-launch verify rows",

@@ -17,8 +17,8 @@ TPU-native replacement for the reference's hot loop
     reference runs lm_head over the whole sequence every step,
     orchestration.py:140-144).
 
-Batch rows share one prompt length (serving uses batch=1; the batched bench
-configs use equal-length prompts).
+Batch rows share one prompt length (serving uses batch=1; batched callers
+pass equal-length prompts).
 """
 
 from __future__ import annotations
@@ -677,8 +677,8 @@ def decode_speculative(
     class of benign divergence as chunked vs tokenwise prefill. Useless
     drafts cost nothing but the already-paid forward; repetitive text
     (code, structured data, chat-with-quoting) accepts often and decodes
-    several tokens per step (~2.2x measured on v5e for a fully-
-    repetitive stream: 260 -> 574 tok/s, TinyLlama bf16).
+    several tokens per step (the gain is not measured on the serving
+    path).
 
     KV discipline: the forward writes K/V for [current, draft] at
     pos..pos+g. Accepted slots hold exactly the accepted tokens' K/V; the

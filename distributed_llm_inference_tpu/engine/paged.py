@@ -1121,15 +1121,11 @@ class SpecPlan(NamedTuple):
     With device-derived launch metadata (ISSUE 15; DeviceMeta below),
     a verify row's positions come from the device-resident slot state,
     so the host submits verify rows EVERY step, back to back — the
-    packed fetch only confirms emissions. The PR-13 skip-until-fetched
-    freeze (a slot with an unfetched verify row carries no row, host
-    q_start stays exact) remains only behind
-    EngineConfig.spec_device_meta=False as the bench baseline."""
+    packed fetch only confirms emissions."""
 
     dec_on: jnp.ndarray  # bool [B]: slot has a PLAIN decode row this
     # launch — slot_step advances exactly these rows; verify rows
-    # advance through spec_verify instead (and, in the legacy
-    # host-planned mode, frozen unfetched-verify slots not at all)
+    # advance through spec_verify instead
     on: jnp.ndarray  # bool [B]: slot carries a verify row this launch
     idx: jnp.ndarray  # i32 [B, K+1]: flat launch indices of the row's
     # [current, draft...] slots (entries past the slot's own draft
@@ -1163,25 +1159,15 @@ class DeviceMeta(NamedTuple):
     value the device already holds post-previous-launch. Marking those
     tiles/slots here and substituting on device (apply_device_meta)
     means the host never needs the fetched result of launch N to plan
-    launch N+1: verify rows ride lag pipelining like plain decode rows,
-    and the SpecPlan.dec_on freeze is deleted. All leaves are plain
-    traced operands — one compiled program for every derivation pattern.
+    launch N+1: verify rows ride lag pipelining like plain decode rows.
+    All leaves are plain traced operands — one compiled program for
+    every derivation pattern.
     """
 
     tile_on: jnp.ndarray  # bool [G]: tile's q_start = pos[row] + tile_off
     tile_off: jnp.ndarray  # i32 [G]: tile's offset within its row entry
     tok_on: jnp.ndarray  # bool [W]: slot's position = pos[row] + tok_off
     tok_off: jnp.ndarray  # i32 [W]: flat slot's offset within its entry
-
-
-def idle_device_meta(width: int, tile: int) -> DeviceMeta:
-    """An all-off DeviceMeta (every position host-planned — the legacy
-    contract, as a fixed-shape operand)."""
-    G_ = width // tile
-    return DeviceMeta(
-        jnp.zeros((G_,), bool), jnp.zeros((G_,), jnp.int32),
-        jnp.zeros((width,), bool), jnp.zeros((width,), jnp.int32),
-    )
 
 
 def build_device_meta(entries, offsets, n_dev: int, *, width: int,
@@ -1340,9 +1326,10 @@ def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
     positions are DERIVED ON DEVICE from state.pos (apply_device_meta) —
     the host plan carries placeholders there and the host never needs a
     fetch to plan the next launch, even for verify rows whose advance is
-    data-dependent. Without `dev` the host position model must be exact
-    (the PR-13 contract: over-advance on rows that went inactive since
-    the last fetch is masked garbage, the frozen-row argument).
+    data-dependent. Without `dev` (a fleet with spec_draft_len 0) the
+    host position model must be exact (over-advance on rows that went
+    inactive since the last fetch is masked garbage, the frozen-row
+    argument).
     dec_idx [B]: flat index of each slot's decode token (0 for slots
     without one — their sampled garbage is gated by state.active exactly
     like idle rows in decode_slots_paged). arm: completing-prefill
@@ -1507,8 +1494,7 @@ def mixed_epilogue(cfg: ModelConfig, state: G.SlotState,
     prev = state
     state, emit, can_emit = G.slot_step(cfg, state, sparams, logits, k_dec)
     if spec is not None:
-        # rows without a plain decode row this launch (verify rows, and
-        # rows skipped while their previous verify row is unfetched)
+        # rows without a plain decode row this launch (verify rows)
         # must not advance through slot_step's garbage logits: freeze
         # them back to the pre-step state, then run the traced verify
         dec_col = spec.dec_on[:, None]

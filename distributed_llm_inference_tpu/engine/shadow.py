@@ -936,26 +936,6 @@ class ShadowStore:
             self._m_tier_bytes["host"].set(self._host_bytes)
             self._m_tier_bytes["disk"].set(self._disk_bytes)
 
-    # jaxlint: decode-unreachable -- host-side tier spill over numpy entries under the store lock (drain-time entry point for embedders and bench.py's kv_tiers leg; no package caller)
-    def demote_host_tier(self) -> int:
-        """Spill every host-tier entry to the disk tier (parents-first —
-        the eviction cascade's natural order) and drop it from tier 1:
-        the graceful-drain shape. A restart over the same --kv-disk-dir
-        then promotes the working set back through tier 2 instead of
-        re-prefilling it. No-op (returns 0) without a disk tier; callers
-        should flush() first so in-flight copies are included. Returns
-        the number of chunk files newly written (entries already
-        persisted on disk spill for free)."""
-        with self._lock:
-            if self.disk_dir is None:
-                return 0
-            before = self.demoted
-            for key in list(self._entries):
-                self._evict_subtree_locked(key)
-            self._note_blocks_locked()
-            self._note_tiers_locked()
-            return self.demoted - before
-
     # -- persistence (graceful drain / --restore-dir) ------------------------
     def save(self, directory: str) -> int:
         """Serialize every HOST-tier entry to `directory`/shadow.npz,

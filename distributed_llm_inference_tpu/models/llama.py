@@ -190,10 +190,8 @@ def default_attn_hook(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
     attention uses the XLA path.
 
     attn_impl="pallas" applies to T>1 chunks only (prefill / chunked
-    ingest / speculative verify — the compute-bound phases where the
-    flash kernel measured 1.5x XLA); every T=1 decode step keeps the XLA
-    einsum, which measured decisively faster (15x on the solo loop, see
-    the inline notes).
+    ingest / speculative verify — the compute-bound phases); every T=1
+    decode step keeps the XLA einsum (see the inline notes).
 
     An int8 cache (ops/kv_quant.KVQuant leaves, cfg.kv_quant="int8")
     dispatches on the leaf type: quantize-on-write, dequantize into the
@@ -229,12 +227,8 @@ def default_attn_hook(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
         new_k, new_v = update_kv_cache_slots(
             cache_k, cache_v, k, v, pos, gate=update_gate
         )
-        # Always the XLA einsum here, even under attn_impl="pallas":
-        # fleet decode is T=1 and measured FASTER on XLA than the per-row
-        # kernel (ops/paged_attention.flash_attend_slots, v5e: 395 vs
-        # 382 tok/s end to end, ~1.00 vs ~1.08 ms at the attention
-        # level). The kernel stays exported/tested and bench.py's fleet
-        # leg tracks the gap every round.
+        # the dense fleet's T=1 decode: the XLA einsum whatever
+        # attn_impl says (the Pallas decode kernels read the paged pool)
         attn = attend(
             q, new_k, new_v, mask,
             scale=cfg.query_scale, softcap=cfg.attn_softcap,
@@ -243,12 +237,10 @@ def default_attn_hook(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
     new_k, new_v = update_kv_cache(cache_k, cache_v, k, v, pos, gate=update_gate)
     if cfg.attn_impl == "pallas" and q.shape[1] > 1:
         # Flash kernel for the COMPUTE-bound chunks only (prefill,
-        # chunked ingest, speculative verify): measured 1.5x the XLA
-        # prefill throughput on v5e at 1k prompts (bench flash leg). At
-        # T=1 the same kernel INSIDE the decode loop measured 15x slower
-        # than the einsum (per-step kernel overhead with no flops to
-        # hide it under), so decode always takes the XLA path — this
-        # gate is what makes "--attn-impl pallas/auto" strictly a win.
+        # chunked ingest, speculative verify). A T=1 step has no flops
+        # to hide a kernel launch under, so solo decode always takes the
+        # XLA einsum (neither side measured on the serving path: the
+        # benchmark's cells decode through the paged kernels).
         attn = _flash(q, new_k, new_v)
     else:
         attn = attend(

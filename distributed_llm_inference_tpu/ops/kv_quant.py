@@ -7,8 +7,7 @@ per (token, kv-head) halves the cache's HBM footprint (int8 data +
 1/head_dim scale overhead), which buys 2x the slots / context at the
 same budget; on read the dequantize (int8 -> f32 multiply) fuses into
 the attention matmuls the same way the weight-only path's does
-(ops/quant.py — measured 1.6x on-chip for weights, same producer-fusion
-shape here).
+(ops/quant.py: the same producer-fusion shape).
 
 Why per-(token, head) granularity: K/V activation outliers are
 token-local (a single position can spike), so one scale per token row
@@ -31,10 +30,8 @@ sets — parallel/context.py); the prefix snapshot store composes too,
 its slices carry the scale leaves. The Pallas flash PREFILL kernel and
 the fused paged DECODE kernel both dequantize int8 tiles/blocks in
 their prologues (ops/flash_attention.py, ops/paged_attention.py — half
-the cache HBM bytes); only the dense fleet kernel (flash_attend_slots,
-which the hook never selects anyway) still reads raw dtypes. The
-reference has no KV cache at all (/root/reference/Worker1.py:132-134);
-this is north-star serving scope.
+the cache HBM bytes). The reference has no KV cache at all
+(/root/reference/Worker1.py:132-134); this is north-star serving scope.
 """
 
 from __future__ import annotations

@@ -1,7 +1,6 @@
 """Pallas TPU paged attention over the block pool: the decode kernel
-(`paged_flash_attend`), the mixed prefill + decode kernel
-(`ragged_paged_attend`) and the dense slot-fleet kernel
-(`flash_attend_slots`).
+(`paged_flash_attend`) and the mixed prefill + decode kernel
+(`ragged_paged_attend`).
 
 The two paged kernels are ONE kernel body under two wrappers. A program
 of the grid is one row's work: a decode slot's single query, or one query
@@ -69,25 +68,6 @@ from .flash_attention import resolve_interpret
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)  # mask fill; avoids inf-inf NaNs
 
 
-def _live_range(pos_b, *, bs: int, MB: int, win):
-    """(first, needed) logical-block bounds for a row at position pos_b:
-    blocks [first, needed) hold at least one attendable position. `win`
-    is a TRACED scalar or a static int (None / <= 0 = full causal) —
-    per-layer window patterns (Gemma-2/3) feed each scan step's width
-    through one compiled kernel, same contract as
-    ops/flash_attention._first_tile."""
-    if win is None:
-        win = -1
-    needed = jnp.minimum(pl.cdiv(pos_b + 1, bs), MB)
-    needed = jnp.maximum(needed, 1)  # pos < 0 never happens; keep clip sane
-    first = jnp.where(
-        win > 0,
-        jnp.minimum(jnp.maximum(pos_b - win + 1, 0) // bs, needed - 1),
-        0,
-    )
-    return first, needed
-
-
 # -- the paged walk: decode rows and mixed query tiles, one kernel body -------
 #
 # The flat query axis of a mixed launch holds every row's tokens back to
@@ -113,7 +93,10 @@ def _ragged_live_range(q_start, q_len, *, bs: int, MB: int, win):
     [first, needed) hold at least one position some query of the tile
     attends. A tile that holds nothing (q_len == 0) evaluates with an
     effective length of 1 to keep the clips sane; the kernel walks
-    nothing for it. `win` is a TRACED scalar (<= 0 = full causal)."""
+    nothing for it. `win` is a TRACED scalar (<= 0 = full causal):
+    per-layer window patterns (Gemma-2/3) feed each scan step's width
+    through one compiled kernel, same contract as
+    ops/flash_attention._first_tile."""
     last = q_start + jnp.maximum(q_len, 1) - 1
     needed = jnp.clip(pl.cdiv(last + 1, bs), 1, MB)
     first = jnp.where(
@@ -604,175 +587,3 @@ def ragged_paged_attend(
         return out.reshape(W, H, -1)
     return out[0].reshape(W, H, -1), *out[1:]
 
-
-# -- the dense slot-fleet cache -------------------------------------------------
-
-
-def _slots_kernel(
-    pos_ref,  # scalar-prefetch [B] int32
-    q_ref,  # [1, 1, KV, group, Dh] VMEM
-    k_ref,  # [1, KV, bk, Dh] VMEM (all kv heads, one seq tile)
-    v_ref,  # [1, KV, bk, Dh] VMEM
-    o_ref,  # [1, 1, KV, group, Dh] VMEM
-    m_ref,  # scratch [H, 1] fp32
-    l_ref,  # scratch [H, 1] fp32
-    acc_ref,  # scratch [H, Dh] fp32
-    *,
-    bk: int,
-    KV: int,
-    group: int,
-    S: int,
-    scale: float,
-    window: int | None,
-):
-    """One (batch row, seq tile) step: ALL kv heads in one MXU matmul.
-
-    Scores are one [H, KV*bk] matmul (rows = all query heads, columns =
-    every kv head's tile) and a block-diagonal mask kills the cross-head
-    terms. The serving hook does not select this kernel: dense-fleet
-    decode stays on the XLA path whatever attn_impl says; bench.py's
-    fleet leg is its only caller.
-    """
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    n_j = pl.num_programs(1)
-    pos_b = pos_ref[b]
-    Dh = q_ref.shape[-1]
-    H = KV * group
-    C = KV * bk
-    first, needed = _live_range(pos_b, bs=bk, MB=n_j, win=window)
-
-    @pl.when(j == 0)
-    def _():
-        m_ref[:] = jnp.full((H, 1), _NEG, jnp.float32)
-        l_ref[:] = jnp.zeros((H, 1), jnp.float32)
-        acc_ref[:] = jnp.zeros((H, Dh), jnp.float32)
-
-    @pl.when((j >= first) & (j < needed))
-    def _():
-        q = q_ref[0, 0].reshape(H, Dh).astype(jnp.float32) * scale
-        ks = k_ref[0].reshape(C, Dh).astype(jnp.float32)
-        vs = v_ref[0].reshape(C, Dh).astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, ks, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [H, C]
-        row = jax.lax.broadcasted_iota(jnp.int32, (H, C), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (H, C), 1)
-        kv_pos = j * bk + col % bk
-        # block-diagonal: row h (kv head h // group) only sees columns of
-        # its own kv head's tile (col // bk)
-        mask = (row // group == col // bk) & (kv_pos <= pos_b)
-        if S % bk != 0:
-            mask &= kv_pos < S
-            vs = jnp.where(
-                j * bk + jax.lax.broadcasted_iota(jnp.int32, (C, Dh), 0) % bk
-                < S,
-                vs, 0.0,
-            )  # BlockSpec pad garbage would ride 0 * NaN into acc
-        if window is not None:
-            mask &= kv_pos > pos_b - window
-        s = jnp.where(mask, s, _NEG)
-        m_prev, l_prev = m_ref[:], l_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, vs, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    @pl.when(j == n_j - 1)
-    def _():
-        l = l_ref[:]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (
-            (acc_ref[:] / l).reshape(KV, group, Dh).astype(o_ref.dtype)
-        )
-
-
-@functools.partial(
-    jax.jit, static_argnames=("block_k", "interpret", "window")
-)
-def flash_attend_slots(
-    q: jnp.ndarray,
-    cache_k: jnp.ndarray,
-    cache_v: jnp.ndarray,
-    pos: jnp.ndarray,
-    *,
-    block_k: int = 0,
-    window: int | None = None,
-    interpret: bool | None = None,
-) -> jnp.ndarray:
-    """Per-row-position flash decode over the DENSE slot-fleet cache.
-
-    The same online-softmax walk as `paged_flash_attend` with the identity
-    layout: the fleet cache is [B, KV, S, Dh] and row b's live prefix is
-    positions 0..pos[b] (ops/attention.slot_causal_mask semantics, the
-    continuous fleet's decode mask). Tiles past each row's causal frontier
-    — or, with a sliding window, before it — clamp to the nearest live
-    tile, so Pallas skips their DMA: HBM traffic per step is each row's
-    LIVE prefix, where the XLA path reads all B*S slots of the fleet
-    cache regardless of occupancy. ops/flash_attention.flash_attend is
-    the shared-scalar-position counterpart (its grid offsets assume one
-    frontier for the whole batch; this kernel's are per-row).
-
-    Not reachable from the serving hook: see `_slots_kernel`.
-
-    q [B,1,H,Dh] (decode, T=1); cache_k/v [B,KV,S,Dh]; pos [B] int32.
-    Returns [B,1,H,Dh] in q.dtype.
-    """
-
-    B, T, H, Dh = q.shape
-    assert T == 1, "slots kernel serves decode steps (T=1) only"
-    KV, S = cache_k.shape[1], cache_k.shape[2]
-    group = H // KV
-
-    interpret = resolve_interpret(interpret)
-    if block_k <= 0:
-        block_k = min(S, 512)
-    MB = pl.cdiv(S, block_k)
-
-    q5 = q.reshape(B, 1, KV, group, Dh)
-    pos = pos.astype(jnp.int32)
-
-    def kv_index(b, j, pos_ref):
-        first, needed = _live_range(pos_ref[b], bs=block_k, MB=MB, win=window)
-        return (b, 0, jnp.clip(j, first, needed - 1), 0)
-
-    kernel = functools.partial(
-        _slots_kernel,
-        bk=block_k,
-        KV=KV,
-        group=group,
-        S=S,
-        scale=Dh**-0.5,
-        window=window,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, MB),
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1, KV, group, Dh), lambda b, j, pos_ref: (b, 0, 0, 0, 0)
-            ),
-            pl.BlockSpec((1, KV, block_k, Dh), kv_index),
-            pl.BlockSpec((1, KV, block_k, Dh), kv_index),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, KV, group, Dh), lambda b, j, pos_ref: (b, 0, 0, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, Dh), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, 1, KV, group, Dh), q.dtype),
-        interpret=interpret,
-    )(pos, q5, cache_k, cache_v)
-    return out.reshape(B, 1, H, Dh)

@@ -1,8 +1,8 @@
 """Weight-only int8 quantization for the decode hot path.
 
 Batch-1 decode is HBM-bandwidth-bound: every step streams every weight
-byte from HBM once (bench.py measures ~75% of the v5e roofline in bf16).
-Halving the bytes halves the floor — so the matmul weights are stored as
+byte from HBM once (its share of the roofline is not measured on the
+serving path). Halving the bytes halves the floor — so the matmul weights are stored as
 **int8 with per-output-channel symmetric scales** and dequantized on-chip:
 
     y = (x @ q.astype(x.dtype)) * s        # scale applied to the OUTPUT
@@ -224,14 +224,12 @@ def q4_matmul_rows(x2d: jnp.ndarray, w: Q4Tensor, interpret: bool = None):
     """Pallas path for y = x2d @ dequant(w), x2d [R, in].
 
     The XLA einsum formulation of the same algebra materializes the
-    unpacked int8 tensor in HBM (measured far slower on v5e; plain
-    dequant-then-dot lands ~62 tok/s end to end), so the decode hot path
-    unpacks in VMEM instead. Honest accounting (chained-call timing,
-    bench.py): int4 decode lands ~330-350 tok/s vs int8's ~450-480 —
-    the R=1 matvec shapes leave the kernel overhead-bound, so int4 is
-    the CAPACITY lever (half int8's weight HBM: 13B-class fits a single
-    v5e) while int8 stays the single-stream speed pick. Caller
-    guarantees the tiling gates."""
+    unpacked int8 tensor in HBM, so the decode hot path unpacks in VMEM
+    instead. The R=1 matvec shapes leave little work per kernel launch,
+    so int4 is the CAPACITY lever (half int8's weight HBM: 13B-class
+    fits a single v5e); neither quantized path is measured on the
+    serving path (no benchmark cell quantizes). Caller guarantees the
+    tiling gates."""
     from jax.experimental import pallas as pl
 
     from .flash_attention import resolve_interpret

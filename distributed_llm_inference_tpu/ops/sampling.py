@@ -200,9 +200,9 @@ def sample_token(
         # costs a full-vocab argsort + softmax + cumsum per step, and a
         # where(greedy, ...) would keep it live even when every step is
         # an argmax. lax.cond runs only the taken branch — greedy decode
-        # skips the sampler entirely (279 -> 321 tok/s solo on v5e; the
-        # slot fleet takes it whenever ALL rows are greedy). The sampled
-        # branch is bit-identical to the fused path.
+        # skips the sampler entirely (the slot fleet takes it whenever
+        # ALL rows are greedy). The sampled branch is bit-identical to
+        # the fused path.
         return jax.lax.cond(all_greedy, _argmax_only, _fused, *operands)
     # Eager call (tests / one-off prefills outside jit): an eager cond
     # re-traces fresh branch closures every call and XLA recompiles the

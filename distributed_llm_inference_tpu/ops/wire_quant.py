@@ -102,7 +102,7 @@ def wire_decode(w: WireQuant, dtype) -> jnp.ndarray:
 
 def wire_roundtrip(x: jnp.ndarray) -> jnp.ndarray:
     """The numerics of ONE wire crossing without the collective — what a
-    receiving stage sees of `x`. The CPU-proxy bench leg and the
+    receiving stage sees of `x`. The CPU proxy below and the
     tolerance tests replay the mesh's error profile with this."""
     return wire_decode(wire_encode(x), x.dtype)
 
@@ -151,8 +151,8 @@ def proxy_stage_generate(cfg, params, prompt_ids, max_new: int,
     tests/test_wire_quant.py), so the proxy's match rate isolates
     exactly the wire quantization.
 
-    Used by the `bench.py wire_quant` leg and the greedy
-    token-match-rate gates: the mesh tests' tolerance is calibrated
+    Used by the greedy token-match-rate gates
+    (tests/test_wire_quant.py): the mesh tests' tolerance is calibrated
     against this.
     """
     ranges, fwd = _proxy_fwd(cfg, n_stages, quant)
@@ -184,8 +184,8 @@ def proxy_stage_generate(cfg, params, prompt_ids, max_new: int,
 @_functools.lru_cache(maxsize=8)
 def _proxy_fwd(cfg, n_stages: int, quant: bool):
     """Memoized stage-sliced forward for the proxy (cfg is a frozen
-    dataclass — hashable), so repeated proxy calls reuse one jit cache
-    and the bench leg times compute, not recompiles."""
+    dataclass — hashable), so repeated proxy calls reuse one jit
+    cache."""
     from ..config import stage_layer_range
 
     ranges = tuple(
@@ -263,8 +263,8 @@ def wire_bytes(shape, itemsize: int, hops: int, *, quant: bool) -> int:
     """Host-side static wire accounting (no tracing cost): bytes one
     activation of `shape` costs crossing `hops` hand-offs. The formula
     itself lives in analysis/comms.wire_link_bytes — the ONE
-    implementation the dli_pp_wire_bytes_total counters, the symbolic
-    link table, and the bench leg's bytes/token headline all evaluate."""
+    implementation the dli_pp_wire_bytes_total counters and the
+    symbolic link table evaluate."""
     from ..analysis.comms import wire_link_bytes
 
     return wire_link_bytes(shape, itemsize, hops, quant=quant)

@@ -349,9 +349,9 @@ class SPMDBackendBase:
                       **launch):
         """Account one launch of a named wire link (the ONE symbolic
         bytes model: analysis/comms.WIRE_LINKS). Shape and hop-count
-        arithmetic live in the link table — the `--comms` report, the
-        bench `comms_report` leg, and these counters all evaluate the
-        same formulas, so they cannot drift. `launch` supplies the
+        arithmetic live in the link table — the `--comms` report and
+        these counters evaluate the same formulas, so they cannot
+        drift. `launch` supplies the
         per-call params (rows/t/steps/...); topology dims default from
         the backend."""
         spec = comms.WIRE_LINKS[name]

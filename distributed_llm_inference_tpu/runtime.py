@@ -1,6 +1,6 @@
 """Top-level factory: model name + mesh shape -> ready InferenceEngine.
 
-The single entry point the serving layer / bench / client tooling use —
+The single entry point the serving layer and client tooling use —
 the reference needed three hand-edited scripts and manual URL wiring to
 assemble the same topology (SURVEY.md §2 C10).
 """
@@ -43,7 +43,7 @@ def create_backend(
     Selection: single device when the mesh is trivial; the SPMD pipeline
     for pp/tp meshes; the microbatched zero-bubble schedule
     (parallel/schedule.py, BASELINE config 5) when microbatches > 1.
-    Batched workloads (bench harness, dryrun, batch-serving callers) use
+    Batched workloads (dryrun, batch-serving callers) use
     the backend interface directly: batch % (dp * microbatches) == 0.
     wire_quant (EngineConfig.pp_wire_quant through create_engine):
     "int8" quantizes every inter-stage activation hand-off on the SPMD

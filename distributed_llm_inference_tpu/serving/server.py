@@ -1466,10 +1466,9 @@ def main(argv: Optional[list] = None):
     ap.add_argument(
         "--quant", default=None, choices=[None, "int8", "int4"],
         help="weight-only quantization: int8 halves decode HBM bytes/token "
-             "(~1.6-1.7x measured decode speedup on v5e; llama family); "
-             "int4 halves the WEIGHT FOOTPRINT again (packed nibbles, "
-             "group-wise scales) — the capacity pick for fitting bigger "
-             "models; int8 decodes faster",
+             "(llama family); int4 halves the WEIGHT FOOTPRINT again "
+             "(packed nibbles, group-wise scales) — the capacity pick for "
+             "fitting bigger models",
     )
     ap.add_argument(
         "--kv-quant", default=None, choices=[None, "int8"],

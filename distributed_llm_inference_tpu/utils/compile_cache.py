@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
-One rule for every launcher (serving/server.py, serving/stage_runtime.py,
-bench.py): where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself and
+One rule for every launcher (serving/server.py, serving/stage_runtime.py):
+where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself and
 the program sets no directory in code — the operator placed the cache.
 Where it is not, the cache is `<checkout>/.xla_cache`, computed from this
 package's location: the directory is part of the cache key, so a temp name,

@@ -8,8 +8,8 @@ metrics/logging). Here every log record is one JSON object on stderr
     log = get_logger("engine")
     log.info("request", model="tinyllama-1.1b", tokens=20, ttft_s=0.01)
 
-Stdout stays clean for tool output (bench.py's single JSON line, the
-client CLI).
+Stdout stays clean for tool output (the client CLI, chip_smoke.py's
+result line).
 """
 
 from __future__ import annotations

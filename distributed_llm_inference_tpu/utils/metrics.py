@@ -5,7 +5,7 @@ Histogram families, labeled (`engine` / `route` / `model` / ...), all
 thread-safe, rendered two ways from ONE store:
 
   * `render()` — Prometheus text exposition (served at `GET /metrics`);
-  * `snapshot()` — the JSON view (`/stats` sections, bench snapshots).
+  * `snapshot()` — the JSON view (`/stats` sections).
 
 Both views read the same family objects, so they cannot diverge: every
 number in `/stats` that has a Prometheus counterpart is computed from the
@@ -383,40 +383,6 @@ class MetricsRegistry:
     def snapshot(self) -> dict:
         """The JSON view over the same families the exposition renders."""
         return {f.name: f.snapshot() for f in self.families()}
-
-
-def latency_summary(registry: MetricsRegistry) -> dict:
-    """Compact benchmark-facing summary of the latency histograms
-    ({metric: {engine: {p50, p90, p99, count}}}) plus the occupancy
-    gauges — the `metrics` section of the bench JSON lines, so BENCH_*
-    rounds capture percentile signal, not just aggregate tok/s."""
-    out: dict = {}
-    for name in (
-        "dli_ttft_seconds", "dli_tpot_seconds",
-        "dli_request_duration_seconds", "dli_decode_step_seconds",
-    ):
-        fam = registry.get(name)
-        if fam is None:
-            continue
-        block = {}
-        for s in fam.snapshot()["series"]:
-            if s["count"]:
-                label = s["labels"].get("engine") or "_"
-                block[label] = {
-                    "p50": s["p50"], "p90": s["p90"], "p99": s["p99"],
-                    "count": s["count"],
-                }
-        if block:
-            out[name] = block
-    for name in (
-        "dli_slots_total", "dli_slots_occupied", "dli_kv_pool_blocks_free",
-        "dli_kv_pool_shared_blocks",
-    ):
-        fam = registry.get(name)
-        if fam is not None:
-            for s in fam.snapshot()["series"]:
-                out[name] = s["value"]
-    return out
 
 
 # Process-global default for callers with no engine in reach (none of the

@@ -1,6 +1,6 @@
 """1F1B through the front door (round-2 review #4): BASELINE config 5's
 microbatched backend served by the ENGINE and the HTTP surface, not just
-the bench harness. Greedy fleets must match the plain pipeline backend
+a backend caller. Greedy fleets must match the plain pipeline backend
 token-for-token (the zero-bubble schedule changes the compute order, not
 the math — equivalence-tested in tests/test_schedule.py at the backend
 level; here through the serving stack).

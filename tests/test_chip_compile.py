@@ -34,7 +34,6 @@ from distributed_llm_inference_tpu.ops import quant as Q
 from distributed_llm_inference_tpu.ops.flash_attention import flash_attend
 from distributed_llm_inference_tpu.ops.kv_quant import KVQuant
 from distributed_llm_inference_tpu.ops.paged_attention import (
-    flash_attend_slots,
     paged_flash_attend,
     ragged_paged_attend,
 )
@@ -188,17 +187,6 @@ def test_ragged_paged_attend_compiles_at_cell_shapes(
         functools.partial(ragged_paged_attend, interpret=False, window=window),
         S((tiles * tq, h, dh), jnp.bfloat16), pool, pool,
         S((slots, mb), jnp.int32), S((tiles, 4), jnp.int32),
-    )
-    assert "tpu_custom_call" in text
-
-
-def test_flash_attend_slots_compiles(one_chip, no_persistent_cache):
-    S = _spec(one_chip)
-    cache = S((SLOTS, KV, 2048, DH), jnp.bfloat16)
-    text = _compile(
-        functools.partial(flash_attend_slots, interpret=False),
-        S((SLOTS, 1, H, DH), jnp.bfloat16), cache, cache,
-        S((SLOTS,), jnp.int32),
     )
     assert "tpu_custom_call" in text
 

@@ -110,6 +110,13 @@ def _clip(n):
     return min(n, WINDOW)
 
 
+def _unfetched(run):
+    """Launches close() found dispatched and not fetched: the steps still
+    in flight are whole chunks, and one step for a mixed launch."""
+    ahead = run["cont"]._steps_inflight
+    return ahead // CHUNK_STEPS + ahead % CHUNK_STEPS
+
+
 def _expected(run):
     """By hand: a prompt of P tokens lands in chunks of the step width W,
     each reading min(tokens so far, window) positions once; its first
@@ -226,16 +233,16 @@ def test_launch_records_walk_no_less_than_they_attend(walk_run):
 @pytest.mark.parametrize("window", [None, 1, 5, 48, 100, 4096])
 @pytest.mark.parametrize("bs,mb", [(8, 4), (16, 16), (32, 3), (128, 50)])
 def test_host_walk_is_the_kernels_live_range(bs, mb, window):
-    """`_kv_walk` (numpy, the launch record) against `_live_range` and
-    `_ragged_live_range` (the kernels' loop bounds) on a sweep of
-    positions and tile lengths, past the table's end included."""
+    """`_kv_walk` (numpy, the launch record) against `_ragged_live_range`
+    (the kernels' loop bounds) on a sweep of positions and tile lengths,
+    past the table's end included; a decode row is a tile of one query."""
     import types
 
     import jax.numpy as jnp
     import numpy as np
 
     from distributed_llm_inference_tpu.ops.paged_attention import (
-        _live_range, _ragged_live_range,
+        _ragged_live_range,
     )
 
     host = types.SimpleNamespace(
@@ -244,7 +251,9 @@ def test_host_walk_is_the_kernels_live_range(bs, mb, window):
     )
     pos = np.arange(0, bs * mb + bs + 3)
     win = jnp.int32(window if window is not None else -1)
-    first, needed = _live_range(jnp.asarray(pos), bs=bs, MB=mb, win=win)
+    first, needed = _ragged_live_range(
+        jnp.asarray(pos), jnp.int32(1), bs=bs, MB=mb, win=win
+    )
     np.testing.assert_array_equal(
         ContinuousEngine._kv_walk(host, pos),
         (np.asarray(needed) - np.asarray(first)) * bs,
@@ -282,7 +291,7 @@ def test_chunk_launches_and_row_steps_are_counted(runs):
     # one fetch per launch of either kind, as ever (close() leaves the
     # chunks the lag had dispatched ahead unfetched)
     _, fetches = _hist(snap, "dli_decode_step_seconds", engine="continuous")
-    unfetched = run["cont"]._steps_inflight // CHUNK_STEPS
+    unfetched = _unfetched(run)
     assert 0 <= unfetched <= LAG
     assert fetches == chunk + want["mixed"] - unfetched
 
@@ -299,11 +308,13 @@ def test_old_series_read_what_the_parent_counted(runs):
         assert _value(snap, "dli_sched_step_tokens_total", kind="prefill") == 233
         assert _value(snap, "dli_sched_prefill_chunks_total") == 7
         # 24 chunk + 7 mixed launches, each fetched once, less those close()
-        # found dispatched ahead: a race with the worker's last fetch, one
-        # on a quiet machine (the parent's 30), two under load
-        unfetched = run["cont"]._steps_inflight // CHUNK_STEPS
+        # found dispatched ahead. How many that is depends on when close()
+        # reaches a worker that is draining its lag (the parent's 30 is
+        # one left, a loaded machine leaves two, an idle one none), so
+        # the test takes it from the run's own record
+        unfetched = _unfetched(run)
         assert _value(snap, "dli_ragged_launches_total", phase="chunk") == 24
-        assert 1 <= unfetched <= LAG
+        assert 0 <= unfetched <= LAG
         assert _hist(snap, "dli_decode_step_seconds", engine="continuous")[1] == 31 - unfetched
         assert _hist(snap, "dli_admission_wait_seconds", queue="continuous")[1] == 5
 
@@ -459,7 +470,11 @@ def test_profiler_trace_holds_launch_fetch_and_phase_events(setup, tmp_path):
             ln, _, l_end, st = launches[seq]
             assert name == "fetch." + ln.split(".", 1)[1] and start >= l_end
             assert int(st["steps"]) in (1, CHUNK_STEPS)
-    # the worker's intervals are contiguous: each begins where one ended
+    # the worker's intervals are contiguous: each begins where one ended.
+    # A hole the clock leaves shows at every iteration (one gap in seven),
+    # so nine gaps in ten are held and not the longest: a loaded machine
+    # takes the thread away between two spans for milliseconds now and then
     worker = sorted((s, e) for n, s, e, _ in events)
-    gaps = [b[0] - a[1] for a, b in zip(worker, worker[1:])]
-    assert max(gaps) < 2e6 and min(gaps) > -2e3  # ns: no hole, no overlap
+    gaps = sorted(b[0] - a[1] for a, b in zip(worker, worker[1:]))
+    assert gaps[0] > -2e3  # ns: no overlap
+    assert gaps[len(gaps) * 9 // 10] < 2e5  # ns: no hole

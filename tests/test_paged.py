@@ -418,34 +418,8 @@ def test_paged_kernel_token_parity(solo_engine):
     np.testing.assert_array_equal(streams[0], streams[1])
 
 
-@pytest.mark.parametrize("window", [None, 13])
-def test_slots_kernel_matches_attend(window):
-    """flash_attend_slots == attend over the dense fleet cache with
-    per-row positions (slot_causal_mask semantics), ragged final tile."""
-    from distributed_llm_inference_tpu.ops.attention import (
-        attend, slot_causal_mask,
-    )
-    from distributed_llm_inference_tpu.ops.paged_attention import (
-        flash_attend_slots,
-    )
-
-    B, H, KV, Dh, S = 3, 8, 2, 16, 44  # S deliberately not a tile multiple
-    ks = jax.random.split(jax.random.PRNGKey(5), 3)
-    q = jax.random.normal(ks[0], (B, 1, H, Dh), jnp.float32)
-    ck = jax.random.normal(ks[1], (B, KV, S, Dh), jnp.float32)
-    cv = jax.random.normal(ks[2], (B, KV, S, Dh), jnp.float32)
-    pos = jnp.asarray([0, 17, S - 1], jnp.int32)
-    got = flash_attend_slots(
-        q, ck, cv, pos, block_k=16, window=window, interpret=True
-    )
-    want = attend(q, ck, cv, slot_causal_mask(pos, 1, S, window))
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
-    )
-
-
 @pytest.mark.slow
-def test_slots_kernel_fleet_token_parity(solo_engine):
+def test_dense_fleet_under_pallas_serves_the_xla_text(solo_engine):
     """Engine-level: the dense continuous fleet under attn_impl='pallas'
     serves the exact greedy text the XLA fleet serves."""
     eng_x = solo_engine

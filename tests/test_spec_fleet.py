@@ -9,14 +9,12 @@ pressure, decode rows stay reserved ahead of prefill chunks, and the
 whole accept/reject decision stays traced (the spec-mixed HLO checks
 pin the artifact half).
 
-Device-derived launch metadata (ISSUE 15, engine_cfg.spec_device_meta):
-decode/verify q_start and positions come from the device-resident slot
-state, so an unfetched verify row never freezes its slot — verify rows
-launch EVERY step, back to back (pinned by the pipelined-launch count:
->0 with the freeze deleted, 0 on the legacy host-planned baseline),
-greedy output stays bit-identical to BOTH the plain fleet and the
-legacy path, and per-slot adaptive K (acceptance-rate EWMA) sizes each
-draft between 0 and spec_draft_len.
+Device-derived launch metadata (ISSUE 15): decode/verify q_start and
+positions come from the device-resident slot state, so an unfetched
+verify row never freezes its slot — verify rows launch EVERY step, back
+to back (pinned by the pipelined-launch count: >0), greedy output stays
+bit-identical to the plain fleet, and per-slot adaptive K
+(acceptance-rate EWMA) sizes each draft between 0 and spec_draft_len.
 """
 
 import threading
@@ -342,23 +340,16 @@ def test_spec_greedy_bit_identical_and_accepts(setup):
     prefix reuse — while verify rows actually launch on the repetitive
     stream (deterministic acceptance itself is pinned by
     test_mixed_verify_accepts_model_argmax and the draft-model leg).
-    Runs THREE ways: plain, device-derived metadata (the default
-    unfrozen back-to-back loop), and the legacy host-planned freeze —
-    all three must be token-identical (the ISSUE 15 bit-exactness leg:
-    device-meta greedy output == host-planned output across threads and
-    warm prefix reuse)."""
+    Runs both ways, plain and speculating (the back-to-back loop on
+    device-derived metadata): token-identical across threads and warm
+    prefix reuse."""
     cfg, params = setup
     shared = " ".join(f"ctx{j}" for j in range(24))
     prompts = MIXED_PROMPTS + [shared + " question one",
                                shared + " question two"]
-    modes = {
-        "plain": (False, {}),
-        "devmeta": (True, {}),
-        "legacy": (True, {"spec_device_meta": False}),
-    }
     outs = {}
-    for name, (spec, extra) in modes.items():
-        cont = _cont(cfg, params, spec, engine_cfg=dict(extra))
+    for name, spec in (("plain", False), ("spec", True)):
+        cont = _cont(cfg, params, spec)
         try:
             warm = [
                 cont.submit(p, max_tokens=12, greedy=True, chat=False)
@@ -388,11 +379,9 @@ def test_spec_greedy_bit_identical_and_accepts(setup):
         if spec:
             sb = st["speculative"]
             assert sb["mode"] == "ngram"
-            assert sb["device_meta"] == (name == "devmeta")
             assert sb["launches"] > 0, st
             assert sb["drafted_tokens"] > 0, st
-    assert outs["devmeta"] == outs["plain"]
-    assert outs["legacy"] == outs["plain"]
+    assert outs["spec"] == outs["plain"]
 
 
 def test_mixed_verify_accepts_model_argmax():
@@ -566,16 +555,14 @@ def test_device_meta_derives_positions_on_device():
 
 
 def test_spec_launches_every_step_back_to_back(setup):
-    """The freeze is deleted (ISSUE 15 acceptance): with device-derived
-    metadata a speculating slot submits a verify row while its previous
-    one is still unfetched (pipelined_launches > 0); the legacy
-    host-planned baseline never does (the skip-until-fetched
-    alternation); and both serve the bit-identical greedy stream."""
+    """No freeze (ISSUE 15 acceptance): a speculating slot submits a
+    verify row while its previous one is still unfetched
+    (pipelined_launches > 0), and serves the plain fleet's greedy stream
+    token for token."""
     cfg, params = setup
     outs, stats = {}, {}
-    for devmeta in (True, False):
-        cont = _cont(cfg, params, True,
-                     engine_cfg={"spec_device_meta": devmeta})
+    for spec in (True, False):
+        cont = _cont(cfg, params, spec)
         try:
             r = cont.submit(REPEAT_PROMPT, max_tokens=24, greedy=True,
                             chat=False)
@@ -583,17 +570,14 @@ def test_spec_launches_every_step_back_to_back(setup):
         finally:
             cont.close()
         assert r["status"] == "success"
-        outs[devmeta] = r["response"]
-        stats[devmeta] = st["speculative"]
+        outs[spec] = r["response"]
+        stats[spec] = st.get("speculative")
     assert outs[True] == outs[False]
-    sb, sb_legacy = stats[True], stats[False]
-    assert sb["launches"] > 0 and sb_legacy["launches"] > 0
-    # every-step verify: back-to-back rows while earlier ones are
-    # unfetched — impossible by construction on the frozen path
+    sb = stats[True]
+    assert stats[False] is None  # spec_draft_len 0: no machinery at all
+    assert sb["launches"] > 0
+    # every-step verify: back-to-back rows while earlier ones are unfetched
     assert sb["pipelined_launches"] > 0, sb
-    assert sb_legacy["pipelined_launches"] == 0, sb_legacy
-    # and the unfrozen loop never launches FEWER verify rows
-    assert sb["launches"] >= sb_legacy["launches"], (sb, sb_legacy)
 
 
 def test_spec_metrics_and_envelope(setup):
@@ -726,18 +710,15 @@ def test_spec_with_long_prompt_interleaving(setup):
 def test_crash_mid_spec_cycle_salvages_bit_identical(setup):
     """A scheduler crash while verify rows are in flight salvages every
     request with greedy output bit-identical to a fault-free plain run —
-    unfetched verify emissions drop exactly like unfetched chunks. Runs
-    the crashed leg on BOTH position disciplines: device-derived
-    metadata (back-to-back pending verify windows die with the fleet)
-    and the legacy host-planned freeze."""
+    unfetched verify emissions drop exactly like unfetched chunks, and
+    the back-to-back pending verify windows die with the fleet."""
     cfg, params = setup
     prompts = [REPEAT_PROMPT, "the quick brown fox"]
 
-    def serve(spec_decode, rules, devmeta=True):
+    def serve(spec_decode, rules):
         faults.disarm()
         cont = _cont(cfg, params, spec_decode,
-                     engine_cfg={"prefix_cache_entries": 0,
-                                 "spec_device_meta": devmeta})
+                     engine_cfg={"prefix_cache_entries": 0})
         try:
             if rules:
                 faults.arm(rules)
@@ -754,19 +735,14 @@ def test_crash_mid_spec_cycle_salvages_bit_identical(setup):
     assert all(r["status"] == "success" for r in clean.values())
     # crash a later decode launch: by then the repetitive stream has
     # fetched history and speculates, so the crash lands mid-spec-cycle
-    for devmeta in (True, False):
-        crashed, restarts, st = serve(
-            True,
-            [faults.FaultRule("decode_launch", "transient", on_call=4)],
-            devmeta=devmeta,
-        )
-        assert restarts >= 1
-        assert st["speculative"]["launches"] > 0
-        for p in prompts:
-            assert crashed[p]["status"] == "success", (devmeta, crashed[p])
-            assert crashed[p]["response"] == clean[p]["response"], (
-                devmeta, p,
-            )
+    crashed, restarts, st = serve(
+        True, [faults.FaultRule("decode_launch", "transient", on_call=4)],
+    )
+    assert restarts >= 1
+    assert st["speculative"]["launches"] > 0
+    for p in prompts:
+        assert crashed[p]["status"] == "success", crashed[p]
+        assert crashed[p]["response"] == clean[p]["response"], p
 
 
 @pytest.mark.chaos
@@ -901,37 +877,29 @@ def test_pp_spec_mixed_step_token_identical(setup, eight_devices):
     backend.params = params
     eng.backend = backend
     mesh = build_mesh(MeshConfig(dp=1, pp=2, tp=1), eight_devices)
-    for device_meta in (False, True):
-        args = _spec_mixed_args(
-            eng, n_spec=1, n_draft=3, chunk=9, device_meta=device_meta
-        )
-        (acfg, aparams, toks, tok_row, tok_pos, dec_flag, meta, pool,
-         table, state, sparams, key, dec_idx, arm, spec), extra = (
-            args[:15], args[15:]
-        )
-        spec_toks, dev = (extra + (None, None))[:2] if extra else (None,
-                                                                   None)
-        cpu_cfg = acfg.replace(attn_impl="xla")
-        packed_s, state_s, _, _ = EP.mixed_step_ragged(
-            cpu_cfg, params, toks, tok_row, tok_pos, dec_flag, meta,
-            EP.init_pool(cpu_cfg, 10, 16), table, state, sparams, key,
-            dec_idx, arm, spec=spec, spec_toks=spec_toks, dev=dev,
-        )
-        pb = PipelineBackend(cpu_cfg, params, mesh)
-        pool_pp = pb.init_paged_pool(10, 16)
-        packed_p, state_p, _, _ = pb.mixed_step_ragged(
-            toks, tok_row, tok_pos, dec_flag, meta, pool_pp, table,
-            state, sparams, key, dec_idx, arm, spec=spec,
-            spec_toks=spec_toks, dev=dev,
-        )
-        assert (
-            np.asarray(packed_s).tolist() == np.asarray(packed_p).tolist()
-        ), device_meta
-        assert (
-            np.asarray(state_s.pos).tolist()
-            == np.asarray(state_p.pos).tolist()
-        )
-        assert (
-            np.asarray(state_s.token).tolist()
-            == np.asarray(state_p.token).tolist()
-        )
+    (acfg, aparams, toks, tok_row, tok_pos, dec_flag, meta, pool,
+     table, state, sparams, key, dec_idx, arm, spec, spec_toks, dev) = (
+        _spec_mixed_args(eng, n_spec=1, n_draft=3, chunk=9)
+    )
+    cpu_cfg = acfg.replace(attn_impl="xla")
+    packed_s, state_s, _, _ = EP.mixed_step_ragged(
+        cpu_cfg, params, toks, tok_row, tok_pos, dec_flag, meta,
+        EP.init_pool(cpu_cfg, 10, 16), table, state, sparams, key,
+        dec_idx, arm, spec=spec, spec_toks=spec_toks, dev=dev,
+    )
+    pb = PipelineBackend(cpu_cfg, params, mesh)
+    pool_pp = pb.init_paged_pool(10, 16)
+    packed_p, state_p, _, _ = pb.mixed_step_ragged(
+        toks, tok_row, tok_pos, dec_flag, meta, pool_pp, table,
+        state, sparams, key, dec_idx, arm, spec=spec,
+        spec_toks=spec_toks, dev=dev,
+    )
+    assert np.asarray(packed_s).tolist() == np.asarray(packed_p).tolist()
+    assert (
+        np.asarray(state_s.pos).tolist()
+        == np.asarray(state_p.pos).tolist()
+    )
+    assert (
+        np.asarray(state_s.token).tolist()
+        == np.asarray(state_p.token).tolist()
+    )

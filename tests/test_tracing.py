@@ -223,7 +223,7 @@ def test_histogram_exemplars_keep_latest_traced_sample():
     assert ex["0.1"]["trace_id"] == "bbbb"
     assert ex["+Inf"]["trace_id"] == "cccc"
     assert ex["0.1"]["value"] == 0.06 or ex["0.1"]["value"] == 0.07
-    # surfaced in the JSON snapshot for /stats + bench captures
+    # surfaced in the JSON snapshot for /stats
     snap = reg.snapshot()["t_seconds"]["series"][0]
     assert snap["exemplars"]["+Inf"]["trace_id"] == "cccc"
 
