@@ -21,6 +21,12 @@ with one chunk already queued behind it the sync overlaps compute.
 Attribution discipline: each launched chunk snapshots the slot->request
 assignment. A chunk in flight when a slot is freed and re-admitted would
 otherwise credit the old tenant's (masked, pad) emissions to the new one.
+On the chunked paged scheduler a slot is let again before its old
+tenant's last launch is fetched, as soon as the host position model has
+seen the row's budget end in a launch already dispatched
+(_release_ended): the old tenant is the slot's RETIRING tenant until
+that fetch, credited by the snapshot and finalized from the launch's own
+active row (ARCHITECTURE.md "Scheduler": who holds a slot).
 
 Backends: the single-device backend runs the fleet as a plain jit
 (engine/generate.decode_slots); the pp PipelineBackend runs the same fleet
@@ -112,7 +118,8 @@ import numpy as np
 from ..utils import faults
 from ..utils.logging import get_logger
 from ..utils.metrics import (
-    ADMISSION_WAIT_HELP, DEFAULT_SIZE_BUCKETS, STEPS_AHEAD_BUCKETS,
+    ADMISSION_WAIT_HELP, DEFAULT_SIZE_BUCKETS, SLOT_RELEASE_HELP,
+    SLOT_TURNOVER_HELP, STEPS_AHEAD_BUCKETS,
 )
 from ..utils.retry import overload_retry_after
 from ..utils.tracing import PhaseClock, Trace, sample_decision
@@ -572,6 +579,13 @@ class ContinuousEngine:
         )
         # guarded-by: _cv
         self._assignment: list[Optional[_Request]] = [None] * self.n_slots
+        # A slot has one owner (_assignment) and at most one RETIRING
+        # tenant: a request whose row the host position model saw end in
+        # a launch already dispatched (_release_ended). Its slot was let
+        # again at that dispatch; its last emissions and its end arrive
+        # with that launch's fetch, attributed by the launch's snapshot.
+        # guarded-by: _cv
+        self._retiring: list[Optional[_Request]] = [None] * self.n_slots
         # Prefix reuse, one planner per fleet mode (both drive the shared
         # engine._prefix_plan seam):
         #   * paged: block-level sharing (engine/block_prefix.py) — a hit
@@ -752,6 +766,13 @@ class ContinuousEngine:
         # scheduler steps dispatched and not yet fetched.
         self._launch_seq = 0
         self._steps_inflight = 0
+        # scheduler steps dispatched since the loop began, and per slot
+        # the count at which the position model put its row's last live
+        # step (None: no row ended there with a queue waiting, or the
+        # next tenant's first prefill chunk has been dispatched) — what
+        # dli_slot_turnover_steps measures from
+        self._steps_dispatched = 0
+        self._ended_at: list[Optional[int]] = [None] * self.n_slots
         # uniform sliding window only: a per-layer pattern has no one
         # width, and is counted as full attention (overstates there)
         self._kv_window = (
@@ -803,6 +824,13 @@ class ContinuousEngine:
             "scheduler iterations that left the head of the queue "
             "waiting, by what it waited for", ("reason",),
         )
+        self._m_slot_release = m.counter(
+            "dli_slot_release_total", SLOT_RELEASE_HELP, ("by",),
+        )
+        self._m_turnover = m.histogram(
+            "dli_slot_turnover_steps", SLOT_TURNOVER_HELP,
+            buckets=STEPS_AHEAD_BUCKETS,
+        ).labels()
         self._m_step = m.histogram(
             "dli_decode_step_seconds",
             "per-token decode step time, chunk launch-to-fetch / "
@@ -1406,6 +1434,7 @@ class ContinuousEngine:
         return bool(
             self._queue
             or any(r is not None for r in self._assignment)
+            or any(r is not None for r in self._retiring)
             or self._admitting is not None
             or self._recovery
             or self._resume
@@ -1471,7 +1500,9 @@ class ContinuousEngine:
             self._queue.clear()
             self._resume.clear()
             self._note_queue_locked()
-        for req in pending + [r for r in self._assignment if r is not None]:
+        for req in pending + [
+            r for r in self._retiring + self._assignment if r is not None
+        ]:
             if req.result is None:
                 req.result = dict(fail)
             self._push_final(req)
@@ -1486,8 +1517,13 @@ class ContinuousEngine:
         are asynchronous: an in-flight launch would then read the NEXT
         admission's table row — a finished slot's lagged decode row wrote
         its garbage K/V through the new occupant's row, into the cached
-        prefix blocks that row maps. jnp.array always copies."""
-        return jnp.array(host_array)
+        prefix blocks that row maps. jnp.array copies, but the copy
+        itself may still be pending when it returns (a host-to-device
+        transfer reads its source until it completes), and a slot's row
+        is rewritten right after the launch that carries its last step
+        is dispatched (_release_ended): the snapshot is therefore taken
+        on the host first, into an array nothing else ever holds."""
+        return jnp.asarray(host_array.copy())
 
     @property
     def ragged(self) -> bool:
@@ -1648,11 +1684,14 @@ class ContinuousEngine:
         this order, so vindicated tenants re-enter before the newest
         (most suspicious) one."""
         with self._cv:
+            # a retiring tenant's last launch died unfetched: salvaged
+            # from its fetched tokens like any tenant (oldest, so first)
             running = [
-                r for r in self._assignment
+                r for r in self._retiring + self._assignment
                 if r is not None and not r.done.is_set()
             ]
             self._assignment = [None] * self.n_slots
+            self._retiring = [None] * self.n_slots
             admitting, self._admitting = self._admitting, None
         # chunked-prefill state dies with the fleet: jobs' requests are
         # casualties above (they sat in _assignment from job start), and
@@ -1663,6 +1702,7 @@ class ContinuousEngine:
         self._prefilling = {}
         self._host_pos[:] = 0
         self._host_end[:] = 0
+        self._ended_at = [None] * self.n_slots
         # speculation bookkeeping dies with the fleet too: unfetched
         # verify rows are unfetched launches (their emissions drop, the
         # salvage record holds fetched tokens only — same contract);
@@ -2741,6 +2781,7 @@ class ContinuousEngine:
         }
         rec.update(fields)
         self._steps_inflight += steps
+        self._steps_dispatched += steps
         self._m_ragged_launches.labels(phase=phase).inc()
         self._m_sched_rows.inc(row_steps)
         self._m_sched_tokens.labels(kind="decode").inc(row_steps)
@@ -2908,7 +2949,58 @@ class ContinuousEngine:
         t_launch = self._clock.mark("plan")
         if self._trace_rate > 0.0:
             self._prof_note_launch(t_launch, snapshot, rec)
+        if self._chunked:
+            # row b's last live step is this chunk's live[b]-th
+            self._release_ended(snapshot, live - K)
         return (packed, snapshot, t_launch, self._mutation_seq, rec)
+
+    def _release_ended(self, snapshot, last_live=0):
+        """Release by the host position model (chunked paged scheduler).
+        Called right after a launch's dispatch and its advance of
+        `_host_pos`: a row of the launch with `_host_pos[b] >=
+        _host_end[b]` has its budget's last step inside a launch that is
+        already dispatched. The device freezes a row at remaining == 0
+        and runs programs in dispatch order, so the row is dead for
+        every later launch whatever the fetch will say (an EOS, a stop
+        or a kill only ends it sooner), and slot b is let again now,
+        not chunk_lag fetches later. The tenant becomes the slot's
+        RETIRING tenant: `_distribute` still credits it the launch's
+        emissions by the snapshot and finalizes it from the launch's own
+        active row, so both kinds of release meet in `_finalize`.
+
+        Its pool blocks go back with the slot: every program that reads
+        them is dispatched, a later tenant's writes ride later programs,
+        and what the prefix index holds stays under the index's own
+        refcounts. The one reader that is not a dispatched program is
+        the shadow capture `_distribute` queues after the fetch: with a
+        shadow store the blocks stay the retiring tenant's until then.
+
+        The bound has to be exact, so the fetch keeps releasing a row
+        whose steps the model does not bound: a constrained row (its FSM
+        row is the slot's device state), a slot with a verify row in
+        this launch or unfetched (`_host_pos` lags by an accept count
+        only the fetch knows), and a slot whose previous retiring tenant
+        is still unfetched (a slot has at most one). `last_live[b]`
+        places the row's last live step among the steps dispatched so
+        far (a chunk's row can end before the chunk does)."""
+        ended = self._host_pos >= self._host_end
+        if not ended.any():
+            return
+        at = self._steps_dispatched + np.broadcast_to(
+            last_live, ended.shape
+        )
+        for b, req in enumerate(snapshot):
+            if (
+                req is None or not ended[b]
+                or self._assignment[b] is not req
+                or self._retiring[b] is not None
+                or b in self._spec_pending or req.cart is not None
+            ):
+                continue
+            self._free_slot_resources(req, by="model")
+            with self._cv:
+                queued = bool(self._queue or self._resume)
+            self._ended_at[b] = int(at[b]) if queued else None
 
     def _loop_inner(self):
         # In-flight decode chunks, oldest first. Launch up to chunk_lag
@@ -3597,6 +3689,13 @@ class ContinuousEngine:
         arm_np = None
         for (job, n, start), off in zip(chunk_list, offsets[n_dec:]):
             toks[off : off + n] = job.ids[start : start + n]
+            if job.done == 0 and self._ended_at[job.slot] is not None:
+                # the slot's next tenant starts to land: the steps
+                # dispatched since the previous row's last live step
+                self._m_turnover.observe(
+                    self._steps_dispatched - self._ended_at[job.slot]
+                )
+                self._ended_at[job.slot] = None
             job.done += n
             if job.remaining == 0:
                 # final chunk: the launch samples this admission's first
@@ -3793,6 +3892,7 @@ class ContinuousEngine:
         ]
         if self._trace_rate > 0.0:
             self._prof_note_launch(t_launch, snapshot, rec)
+        self._release_ended(snapshot)
         return (
             "mixed", packed, snapshot, completions, t_launch,
             self._mutation_seq, spec_meta, rec,
@@ -4331,6 +4431,8 @@ class ContinuousEngine:
             # subsequent mixed launches plan this row from it)
             self._host_pos[slot] = prompt_len
             self._host_end[slot] = prompt_len + req.budget
+            # a whole prefill lands no first chunk to measure a turnover to
+            self._ended_at[slot] = None
         except BaseException:
             if req.block_ids is not None:
                 # admission died after the block grant (failed prefill,
@@ -4566,6 +4668,12 @@ class ContinuousEngine:
                 continue  # freed/killed tenant's masked leftovers
             if seq is not None and req.drop_seq > seq:
                 continue  # preempted after this chunk launched
+            # a retiring tenant's slot was let again when this launch (or
+            # a later one) was dispatched: its emissions and its end
+            # still arrive here by the snapshot, but the slot's device
+            # state is the next tenant's and is never killed from here
+            owns = self._assignment[b] is req
+            mine = owns or self._retiring[b] is req
             new = emitted[mask[:, b], b]
             req.tokens.extend(int(t) for t in new)
             if len(new) and self._shadow is not None:
@@ -4583,7 +4691,7 @@ class ContinuousEngine:
                     # text the client will never see (solo truncates
                     # post-hoc; the chunk boundary makes early
                     # termination actually save here)
-                    if self._assignment[b] is req:
+                    if owns:
                         self.state = G.kill_slot(self.state, b)
                         self._m_preempt.labels(reason="stop").inc()
                     self._finalize(req, pre=gen)
@@ -4592,22 +4700,26 @@ class ContinuousEngine:
                     self._stream_tokens(req, pre=gen)
             elif req.stream_q is not None and len(new):
                 self._stream_tokens(req)
-            if self._assignment[b] is req and not active[b]:
+            if not mine:
+                continue
+            if not active[b]:
                 self._finalize(req, pre=gen)  # reuse this chunk's decode
-            elif req.cancelled and self._assignment[b] is req:
+            elif req.cancelled:
                 # client gone: kill the slot so the fleet admits the next
                 # queued request instead of decoding to the dead request's
                 # full budget
-                self.state = G.kill_slot(self.state, b)
+                if owns:
+                    self.state = G.kill_slot(self.state, b)
                 self._m_preempt.labels(reason="cancelled").inc()
                 log.info("request_cancelled", slot=b, cause=req.cancel_cause)
                 req.result = self._cancel_env(req)
                 self._release(req)
-            elif self._past_deadline(req, now) and self._assignment[b] is req:
+            elif self._past_deadline(req, now):
                 # end-to-end deadline_ms overrun mid-decode: kill the
                 # slot, free blocks/constraint row NOW (checked at the
                 # launch boundary only — never inside compiled code)
-                self.state = G.kill_slot(self.state, b)
+                if owns:
+                    self.state = G.kill_slot(self.state, b)
                 self._m_preempt.labels(reason="deadline").inc()
                 log.info("request_deadline_ms_exceeded", slot=b)
                 req.result = self._deadline_env(req)
@@ -4615,7 +4727,8 @@ class ContinuousEngine:
             elif deadline and now - req.t_start > deadline:
                 # in-flight overrun: kill the slot, fail the request; the
                 # fleet keeps decoding for everyone else
-                self.state = G.kill_slot(self.state, b)
+                if owns:
+                    self.state = G.kill_slot(self.state, b)
                 self._m_preempt.labels(reason="deadline").inc()
                 log.error("request_deadline_exceeded", slot=b, deadline_s=deadline)
                 req.result = {
@@ -4753,24 +4866,35 @@ class ContinuousEngine:
         )
         self._release(req)
 
-    def _free_slot_resources(self, req: _Request):
+    def _free_slot_resources(self, req: _Request, by: str = "fetch"):
         """Return every fleet-held resource of `req` (constraint row +
         FSM reset, pool blocks, block-table row, slot assignment) WITHOUT
-        finalizing it — shared by _release (completion/cancel/deadline)
-        and _preempt_for (the request lives on, parked for resume)."""
-        if self._chunked and req.slot is not None:
+        finalizing it — shared by _release (completion/cancel/deadline),
+        _preempt_for (the request lives on, parked for resume) and
+        _release_ended (`by` "model": the request lives on as the slot's
+        retiring tenant until its last launch is fetched)."""
+        slot = req.slot
+        if slot is not None and self._retiring[slot] is req:
+            # the slot went back when the position model saw the row end:
+            # its table row, adapter page and assignment are the next
+            # tenant's. Only what the retiring tenant kept for a host
+            # reader (its blocks, under a shadow store) is left to return
+            with self._cv:
+                self._retiring[slot] = None
+            slot = None
+        if self._chunked and slot is not None:
             # mid-prefill teardown (cancel / deadline / EOS-on-first of a
             # just-armed admission): drop the job so the planner stops
             # scheduling chunks for a dead tenant
-            job = self._prefilling.pop(req.slot, None)
+            job = self._prefilling.pop(slot, None)
             if job is not None and job in self._jobs:
                 self._jobs.remove(job)
         if req.cart is not None:
             # refcount down; the slot's FSM row back to the free state so
             # the row is inert under any still-constrained chunk program
             self._ctable.release(req.cart[0].key)
-            if req.slot is not None:
-                self._fsm = self._fsm.at[jnp.int32(req.slot)].set(0)
+            if slot is not None:
+                self._fsm = self._fsm.at[jnp.int32(slot)].set(0)
             req.cart = None
         if self.paged and req.block_ids is not None:
             # Worker-thread-only mutation (like all allocator use). DECREF,
@@ -4787,19 +4911,29 @@ class ContinuousEngine:
             # row's overrun clamp only ever writes the request's OWN last
             # allocated block, which is never a registered/shared one —
             # see ARCHITECTURE.md "Block sharing".)
-            self._alloc.decref(req.block_ids)
-            req.block_ids = None
-            if req.slot is not None:
-                self._table[req.slot] = 0
+            # A model release under a shadow store keeps them: the
+            # capture _distribute queues after the tenant's last fetch is
+            # a host reader, not a dispatched program — they go back
+            # when the retiring tenant is finalized.
+            if by != "model" or self._shadow is None:
+                self._alloc.decref(req.block_ids)
+                req.block_ids = None
+            if slot is not None:
+                self._table[slot] = 0
                 self._table_dev = None
-        if self.paged and req.slot is not None:
+        if self.paged and slot is not None:
             # the slot reverts to the base page; later launches carrying
             # the frozen row read page 0 (the all-zero delta — inert)
-            self._slot_pages[req.slot] = 0
+            self._slot_pages[slot] = 0
         self._release_adapter(req)
         with self._cv:
-            if req.slot is not None and self._assignment[req.slot] is req:
-                self._assignment[req.slot] = None
+            if slot is not None and self._assignment[slot] is req:
+                self._assignment[slot] = None
+                if by == "model":
+                    # in the same breath: drain() must never see a
+                    # request in neither place
+                    self._retiring[slot] = req
+                self._m_slot_release.labels(by=by).inc()
             occ = sum(r is not None for r in self._assignment)
             self._cv.notify_all()
         self._m_occupied.set(occ)

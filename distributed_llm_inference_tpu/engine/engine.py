@@ -33,7 +33,8 @@ from ..models import api as M
 from ..utils import faults
 from ..utils.logging import get_logger, request_id_context
 from ..utils.metrics import (
-    ADMISSION_WAIT_HELP, DEFAULT_SIZE_BUCKETS, STEPS_AHEAD_BUCKETS,
+    ADMISSION_WAIT_HELP, DEFAULT_SIZE_BUCKETS, SLOT_RELEASE_HELP,
+    SLOT_TURNOVER_HELP, STEPS_AHEAD_BUCKETS,
     MetricsRegistry,
 )
 from ..utils.probe import device_summary
@@ -660,6 +661,13 @@ class InferenceEngine:
             "dli_launch_steps_ahead",
             "scheduler steps dispatched and unfetched when a launch was "
             "dispatched", ("phase",), buckets=STEPS_AHEAD_BUCKETS,
+        )
+        self.metrics.counter(
+            "dli_slot_release_total", SLOT_RELEASE_HELP, ("by",),
+        )
+        self.metrics.histogram(
+            "dli_slot_turnover_steps", SLOT_TURNOVER_HELP,
+            buckets=STEPS_AHEAD_BUCKETS,
         )
         self.metrics.counter(
             "dli_worker_phase_seconds_total",

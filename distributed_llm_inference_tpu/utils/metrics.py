@@ -57,6 +57,20 @@ ADMISSION_WAIT_HELP = (
     "engine enqueue until the request's first token was fetched, prefill "
     "included: dli_queue_wait_seconds + dli_prefill_seconds"
 )
+# the continuous engine's slot turnover (registered by the engine for a
+# stable scrape schema and by the fleet that counts them)
+SLOT_RELEASE_HELP = (
+    "slots vacated, by what said so: model = the host position model saw "
+    "the row's budget end in a launch already dispatched (the slot is let "
+    "again before that launch is fetched), fetch = every other release "
+    "(a fetched launch showed the row ended or it was killed; a reaped "
+    "prefill; a preemption)"
+)
+SLOT_TURNOVER_HELP = (
+    "scheduler steps dispatched between a row's last live step by the "
+    "host position model and the first prefill chunk of the slot's next "
+    "tenant, for rows that ended while requests were queued"
+)
 
 MAX_SERIES = 64  # label-set cap per family
 WINDOW = 256  # raw-observation window per histogram child (matches

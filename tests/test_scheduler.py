@@ -14,10 +14,12 @@ import threading
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from distributed_llm_inference_tpu import EngineConfig, get_model_config
+from distributed_llm_inference_tpu.engine import generate as G
 from distributed_llm_inference_tpu.engine.continuous import (
     ContinuousEngine,
     _Request,
@@ -575,3 +577,408 @@ def test_crash_at_mixed_decode_launch_salvages(setup):
     for p in prompts:
         assert crashed[p]["status"] == "success", crashed[p]
         assert crashed[p]["response"] == clean[p]["response"], p
+
+
+# -- release by the host position model (ISSUE 31) ---------------------------
+# A row whose budget ends inside a launch already dispatched gives its slot
+# (and its blocks) back at that dispatch; the fetch only finalizes it. The
+# probe below reads the worker's own seams, on the worker thread.
+
+K_STEPS = 8
+
+
+def _probe(cont, monkeypatch=None):
+    """Log launches, fetches, slot releases and job starts with the launch
+    counter each saw; with `monkeypatch`, every kill_slot too."""
+    log = {"launch": [], "fetch": [], "release": [], "start": [], "kill": []}
+    if monkeypatch is not None:
+        kill = G.kill_slot
+
+        def kill_slot(state, slot):
+            log["kill"].append(int(slot))
+            return kill(state, slot)
+
+        monkeypatch.setattr(G, "kill_slot", kill_slot)
+    lr, ft = cont._launch_record, cont._fetch
+    fr, sj = cont._free_slot_resources, cont._start_job
+
+    def launch_record(*a, **k):
+        rec = lr(*a, **k)
+        log["launch"].append(dict(rec))
+        return rec
+
+    def fetch(dev, t_launch, rec):
+        out = ft(dev, t_launch, rec)
+        log["fetch"].append(rec["seq"])
+        return out
+
+    def free(req, by="fetch"):
+        owned = req.slot is not None and cont._assignment[req.slot] is req
+        fr(req, by=by)
+        if owned:
+            log["release"].append((by, req.slot, cont._launch_seq, req))
+
+    def start_job(req, slot):
+        log["start"].append((
+            slot, cont._launch_seq, max(log["fetch"], default=0), req,
+            cont._retiring[slot],
+        ))
+        return sj(req, slot)
+
+    cont._launch_record, cont._fetch = launch_record, fetch
+    cont._free_slot_resources, cont._start_job = free, start_job
+    return log
+
+
+def _serve_together(cont, items):
+    """Enqueue every (prompt, kwargs) in list order while the worker
+    cannot pop one (the queue's lock is re-entrant), then wait for all:
+    which request waits behind which does not depend on the machine."""
+    reqs = [
+        _Request(p, dict(dict(max_tokens=21, greedy=True, chat=False), **kw))
+        for p, kw in items
+    ]
+    with cont._cv:
+        for r in reqs:
+            assert cont._enqueue(r) is None
+    for r in reqs:
+        assert r.done.wait(timeout=300), r.prompt
+    return {r.prompt: r.result for r in reqs}
+
+
+def _serve_all(cont, prompts):
+    return _serve_together(cont, [(p, {}) for p in prompts])
+
+
+def _series(eng, name):
+    return {
+        tuple(sorted(s["labels"].items())): s
+        for s in eng.metrics.snapshot().get(name, {}).get("series", [])
+    }
+
+
+def _released(eng, by):
+    s = _series(eng, "dli_slot_release_total").get((("by", by),))
+    return 0 if s is None else s["value"]
+
+
+RELET_PROMPTS = [f"prompt number {i} says hello" for i in range(6)]
+
+
+@pytest.mark.parametrize("shadow", [False, True], ids=["cold", "shadow"])
+def test_budget_ended_slot_is_relet_before_its_last_fetch(setup, shadow):
+    """Six requests for two slots, every row ending by its budget: the next
+    tenant's first prefill chunk rides the launch right after the one in
+    which the old row ended, while that launch (and, under lag 2, the one
+    before it) is still unfetched. Released by the fetch, as until PR 31,
+    it could start no earlier than two launches later. Tokens are the
+    solo engine's."""
+    cfg, params = setup
+    cont = _cont(cfg, params, True, n_slots=2, chunk_steps=K_STEPS,
+                 kv_shadow=shadow)
+    log = _probe(cont)
+    try:
+        out = _serve_all(cont, RELET_PROMPTS)
+        eng = cont.engine
+        assert _released(eng, "model") == 6 and _released(eng, "fetch") == 0
+        turn = _series(eng, "dli_slot_turnover_steps")[()]
+    finally:
+        cont.close()
+    assert (cont._shadow is not None) is shadow
+    phases = {r["seq"]: r for r in log["launch"]}
+    relets = 0
+    for slot, seq_at_start, fetched, req, retiring in log["start"]:
+        before = [
+            r for r in log["release"]
+            if r[1] == slot and r[2] <= seq_at_start and r[3] is not req
+        ]
+        if not before:
+            continue  # the slot's first tenant
+        by, _, ended_in, old = before[-1]
+        relets += 1
+        assert by == "model" and retiring is old
+        # no launch between the old row's last one and the re-let ...
+        assert seq_at_start == ended_in
+        # ... which is not fetched yet: the fetch-driven release needs it
+        assert fetched < ended_in
+        nxt = phases[ended_in + 1]
+        assert nxt["phase"] == "mixed" and nxt["prefill_chunks"] >= 1
+    assert relets == 4
+    # observed where a request was waiting when the row ended: the four
+    # re-lets, each a chunk's remainder at most
+    assert turn["count"] == 4 and turn["sum"] <= 4 * (K_STEPS - 1)
+    for p in RELET_PROMPTS:
+        solo = cont.engine.generate(p, max_tokens=21, greedy=True, chat=False)
+        assert out[p]["status"] == "success", out[p]
+        assert out[p]["tokens_generated"] == 21
+        assert out[p]["response"] == solo["response"], p
+        assert out[p]["finish_reason"] == "length"
+
+
+@pytest.mark.parametrize("how", ["eos", "stop", "eos_early"])
+def test_row_that_ends_early_beside_its_budget_is_finalized_once(
+        setup, how, monkeypatch):
+    """One slot, a second request waiting. The first row ends by EOS, or
+    by a stop sequence, inside the chunk in which its budget also ends: the
+    model lets the slot again at that chunk's dispatch, and the fetch
+    finalizes the retiring tenant once, with the tokens it had, while the
+    new tenant decodes untouched. `eos_early` is the control: an EOS in the
+    first chunk of a budget of 61 is the fetch's to find."""
+    cfg, params = setup
+    first, second = "a row that ends early", "the tenant after it"
+    n = 61 if how == "eos_early" else 21
+    ecfg = EngineConfig(prefill_buckets=(64, 128, 256))
+    kw = {}
+    if how == "stop":
+        text = InferenceEngine(cfg, params=params, engine_cfg=ecfg).generate(
+            first, max_tokens=n, greedy=True, chat=False)["response"]
+        # a stop string whose first occurrence is at the text's end
+        kw["stop"] = [next(
+            text[i:] for i in range(len(text) - 1, 0, -1)
+            if text.find(text[i:]) == i
+        )]
+    cont0 = _cont(cfg, params, True, n_slots=1, chunk_steps=K_STEPS)
+    try:
+        r = _Request(first, dict(max_tokens=n, greedy=True, chat=False))
+        assert cont0._enqueue(r) is None
+        r.done.wait(timeout=120)
+        toks = [r.first_id] + list(r.tokens)
+    finally:
+        cont0.close()
+    assert len(toks) == n
+    if how != "stop":
+        # generated index 18 is in the last chunk (1 + 8 + 8 + 4), index 5
+        # in the first; the token must not occur earlier in the stream
+        idx = 18 if how == "eos" else 5
+        idx = next(i for i in range(idx, 0, -1) if toks[i] not in toks[:i])
+        assert (idx > 16) if how == "eos" else (idx <= 8)
+        cfg = cfg.replace(eos_token_id=int(toks[idx]))
+    solo_eng = InferenceEngine(cfg, params=params, engine_cfg=ecfg)
+    solo = {
+        first: solo_eng.generate(first, max_tokens=n, greedy=True,
+                                 chat=False, **kw),
+        second: solo_eng.generate(second, max_tokens=21, greedy=True,
+                                  chat=False),
+    }
+    cont = _cont(cfg, params, True, n_slots=1, chunk_steps=K_STEPS)
+    log = _probe(cont, monkeypatch)
+    finals = []
+    push = cont._push_final
+
+    def push_final(req):
+        finals.append(req.prompt)
+        push(req)
+
+    cont._push_final = push_final
+    try:
+        out = _serve_together(
+            cont, [(first, dict(kw, max_tokens=n)), (second, {})])
+    finally:
+        cont.close()
+    assert sorted(finals) == sorted([first, second])  # once each
+    for p in (first, second):
+        assert out[p]["status"] == "success", out[p]
+        assert out[p]["response"] == solo[p]["response"], (how, p)
+        assert out[p]["tokens_generated"] == solo[p]["tokens_generated"]
+    if how == "stop":
+        assert out[first]["stopped"] is True
+    else:
+        assert out[first]["tokens_generated"] < n
+    assert out[first]["finish_reason"] == "stop"
+    by_first = [r[0] for r in log["release"] if r[3].prompt == first]
+    assert by_first == (["fetch"] if how == "eos_early" else ["model"])
+    assert not log["kill"]
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline_ms", "deadline_s"])
+def test_killing_a_retiring_tenant_never_kills_the_new_one(
+        setup, how, monkeypatch):
+    """The client of a retiring tenant goes away (or its deadline passes)
+    after its slot was let again and before its last launch is fetched:
+    it gets the envelope it always got, and the slot, which is the next
+    tenant's by now, is never killed."""
+    cfg, params = setup
+    first, second = "the one that is cancelled", "the tenant after it"
+    extra = {"request_deadline_s": 600.0} if how == "deadline_s" else {}
+    cont = _cont(cfg, params, True, n_slots=1, chunk_steps=K_STEPS,
+                 engine_cfg=extra)
+    log = _probe(cont, monkeypatch)
+    free = cont._free_slot_resources
+
+    def free_then_fail(req, by="fetch"):
+        free(req, by=by)
+        if by == "model" and req.prompt == first:
+            # what the client's disconnect, or the clock, would do just
+            # now: the next fetch (an earlier chunk's) finds it
+            if how == "cancel":
+                req.cancelled = True
+            elif how == "deadline_ms":
+                req.deadline_at = time.time() - 1.0
+            else:
+                req.t_start -= 1000.0
+
+    cont._free_slot_resources = free_then_fail
+    try:
+        out = _serve_all(cont, [first, second])
+        assert cont._retiring == [None] and cont._assignment == [None]
+    finally:
+        cont.close()
+    assert [r[0] for r in log["release"]] == ["model", "model"]
+    assert not log["kill"]
+    assert out[first]["status"] == "failed"
+    assert out[first]["error_type"] == {
+        "cancel": "cancelled", "deadline_ms": "deadline_exceeded",
+        "deadline_s": "timeout",
+    }[how]
+    solo = cont.engine.generate(second, max_tokens=21, greedy=True,
+                                chat=False)
+    assert out[second]["status"] == "success", out[second]
+    assert out[second]["tokens_generated"] == 21
+    assert out[second]["response"] == solo["response"]
+
+
+@pytest.mark.parametrize("shadow", [False, True], ids=["cold", "shadow"])
+def test_pool_is_whole_after_model_releases_with_the_prefix_index_on(
+        setup, shadow):
+    """Blocks go back at the re-let (or, under a shadow store, when the
+    retiring tenant is finalized); what the prefix index caches stays
+    under its own references. After a drained run of prompts that share
+    a head: free + cached is the pool, and evicting the index gives back
+    every block the run began with."""
+    cfg, params = setup
+    head = " ".join(f"ctx{j}" for j in range(24))
+    prompts = [f"{head} question {i}" for i in range(6)] + ["short", "x y z"]
+    cont = _cont(cfg, params, True, n_slots=2, chunk_steps=K_STEPS,
+                 kv_shadow=shadow, kv_pool_blocks=40)
+    try:
+        start = cont._alloc.free_blocks
+        assert start == cont._alloc.n_blocks - 1  # less the trash block
+        out = _serve_all(cont, prompts)
+        assert all(r["status"] == "success" for r in out.values()), out
+        assert any(r.get("prefix_cached_tokens") for r in out.values())
+        assert _released(cont.engine, "model") == len(prompts)
+        st = cont.stats()["paged"]
+        assert st["cached_blocks"] > 0
+        assert st["free_blocks"] + st["cached_blocks"] == start
+        assert cont._alloc.outstanding == st["cached_blocks"]
+        cont._bpx.evict(start)
+        assert cont._alloc.free_blocks == start
+        assert cont._alloc.outstanding == 0
+    finally:
+        cont.close()
+    for p in prompts:
+        solo = cont.engine.generate(p, max_tokens=21, greedy=True, chat=False)
+        assert out[p]["response"] == solo["response"], p
+
+
+@pytest.mark.chaos
+@pytest.mark.parametrize("shadow", [False, True], ids=["cold", "shadow"])
+def test_crash_between_the_relet_and_the_old_fetch_salvages_both(
+        setup, shadow):
+    """The fetch that follows an early re-let dies: the retiring tenant
+    (no slot, its last launches unfetched) and the tenant that took its
+    slot are both salvaged, bit-identical to a clean run; the re-let is
+    the mutation the suspect set counts (the new tenant is struck, the
+    old one, vindicated long ago, is not)."""
+    cfg, params = setup
+    first, second = "the one that retires", "the tenant after it"
+
+    def serve(crash):
+        faults.disarm()
+        cont = _cont(cfg, params, True, n_slots=1, chunk_steps=K_STEPS,
+                     kv_shadow=shadow,
+                     engine_cfg={"prefix_cache_entries": 4 if shadow else 0})
+        seen = {}
+        start = cont._start_job
+
+        def start_job(req, slot):
+            job = start(req, slot)
+            if crash and req.prompt == second and not seen:
+                seen["reqs"] = (cont._retiring[slot], req)
+                faults.arm([faults.FaultRule("fetch", "transient",
+                                             on_call=1)])
+            return job
+
+        cont._start_job = start_job
+        try:
+            out = _serve_all(cont, [first, second])
+            pool = cont.stats()["paged"]
+            return out, cont.restarts_total, seen, pool
+        finally:
+            faults.disarm()
+            cont.close()
+
+    clean, restarts, _, _ = serve(False)
+    assert restarts == 0
+    crashed, restarts, seen, pool = serve(True)
+    assert restarts == 1
+    old, new = seen["reqs"]
+    assert old is not None and old.prompt == first  # it was retiring
+    assert (old.strikes, new.strikes) == (0, 1)
+    for p in (first, second):
+        assert crashed[p]["status"] == "success", crashed[p]
+        assert crashed[p]["tokens_generated"] == 21
+        assert crashed[p]["response"] == clean[p]["response"], p
+    assert crashed[first].get("recovered") is True
+    assert pool["free_blocks"] + pool["cached_blocks"] == pool["pool_blocks"] - 1
+
+
+@pytest.mark.parametrize("fleet", ["whole_prefill", "speculating"])
+def test_rows_the_model_does_not_bound_leave_by_the_fetch(setup, fleet):
+    """The whole-prefill loop has no release by the model; a speculating
+    fleet has it only for a slot with no verify row unfetched. Either way
+    every slot release is counted once, and the tokens are the solo
+    engine's."""
+    cfg, params = setup
+    spec = fleet == "speculating"
+    cont = _cont(
+        cfg, params, spec, n_slots=2, chunk_steps=K_STEPS,
+        engine_cfg=dict(spec_decode=True, spec_draft_len=4) if spec else {},
+    )
+    prompts = ["ab ab ab ab ab ab ab ab", "the cat the cat the cat the",
+               "one more prompt", "and a last one"]
+    try:
+        out = _serve_all(cont, prompts)
+        eng = cont.engine
+        by_model, by_fetch = _released(eng, "model"), _released(eng, "fetch")
+        assert cont._retiring == [None, None]
+    finally:
+        cont.close()
+    assert by_model + by_fetch == len(prompts)
+    if not spec:
+        assert by_model == 0
+    for p in prompts:
+        solo = cont.engine.generate(p, max_tokens=21, greedy=True, chat=False)
+        assert out[p]["status"] == "success", out[p]
+        assert out[p]["response"] == solo["response"], p
+
+
+def test_snapshot_is_taken_on_the_host_before_the_transfer(monkeypatch):
+    """A slot's table row is rewritten right after the launch that carries
+    its last step is dispatched, and a host-to-device transfer may read
+    its source after `jnp.array` has returned (on the CPU backend it does,
+    under load: the rows that rode that launch then walked the trash
+    block). `_snapshot` hands the device an array nothing else holds."""
+    table = np.arange(12, dtype=np.int32).reshape(3, 4)
+    handed = []
+    real = jnp.asarray
+
+    def asarray(a, *args, **kw):
+        handed.append(a)
+        return real(a, *args, **kw)
+
+    monkeypatch.setattr(jnp, "asarray", asarray)
+    dev = ContinuousEngine._snapshot(table)
+    monkeypatch.undo()
+    assert len(handed) == 1 and isinstance(handed[0], np.ndarray)
+    assert not np.shares_memory(handed[0], table)
+    table[:] = 0  # what _release_ended does to a row, a moment later
+    assert np.asarray(dev).tolist() == np.arange(12).reshape(3, 4).tolist()
+    # and under a busy device queue, many times over
+    x = jnp.ones((256, 256))
+    for _ in range(300):
+        t = np.full((4, 32), 7, np.int32)
+        x = x @ x / 256.0
+        d = ContinuousEngine._snapshot(t)
+        t[:] = 0
+        assert int(np.asarray(d).sum()) == 7 * 128
