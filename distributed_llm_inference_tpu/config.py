@@ -125,10 +125,21 @@ class ModelConfig:
     # scores alone (renormalized under moe_renormalize, times
     # routed_scaling); n_shared_experts fused into one SwiGLU of width
     # n_shared_experts * moe_ffn_dim that every token takes.
+    # A llama-family model with moe_ffn_dim > 0 takes the same routed path
+    # (models/experts.py) with softmax scores and no bias: `router_score`.
     moe_ffn_dim: int = 0
     n_shared_experts: int = 0
     first_k_dense: int = 0
     routed_scaling: float = 1.0
+    # Generation by block diffusion (SDAR): 0 = autoregressive. > 0: the
+    # sequence is cut into blocks of this many tokens at absolute positions;
+    # a token attends every position up to the END of its own block; a
+    # forward carries a row's whole open block (mask_token_id where not yet
+    # revealed), reads each masked position's token from the logits AT that
+    # position, and the block's K/V counts only once no mask is left
+    # (engine/paged.diffusion_step). Llama family, paged fleet only.
+    diffusion_block: int = 0
+    mask_token_id: Optional[int] = None
     # GPT-2 only: learned absolute position embeddings.
     use_learned_pos: bool = False
     dtype: str = "float32"  # parameter / activation dtype: "float32" | "bfloat16"
@@ -266,6 +277,16 @@ class ModelConfig:
                     "arch 'mla_moe' needs n_experts, moe_ffn_dim and "
                     "first_k_dense < n_layers (an expert stack)"
                 )
+        if self.diffusion_block:
+            if self.arch != "llama" or self.mask_token_id is None:
+                raise ValueError(
+                    "diffusion_block > 0 needs the llama family and a "
+                    "mask_token_id"
+                )
+            if not 0 <= self.mask_token_id < self.vocab_size:
+                raise ValueError("mask_token_id is outside the vocabulary")
+        if self.moe_ffn_dim and not self.n_experts:
+            raise ValueError("moe_ffn_dim > 0 needs n_experts > 0")
         if self.n_experts:
             if self.arch not in ("llama", "mla_moe"):
                 raise ValueError("MoE (n_experts > 0) is llama-family only")
@@ -278,6 +299,11 @@ class ModelConfig:
     @property
     def head_dim(self) -> int:
         return self.head_dim_override or self.dim // self.n_heads
+
+    @property
+    def router_score(self) -> str:
+        """How routed experts are scored (models/experts.route)."""
+        return "sigmoid" if self.arch == "mla_moe" else "softmax"
 
     @property
     def latent_dim(self) -> int:
@@ -523,6 +549,12 @@ class EngineConfig:
     # (TokenBudgetScheduler.spec_slot_k). spec_draft_len = drafted
     # tokens per verify row (0 disables the machinery entirely).
     spec_draft_len: int = 4
+    # Block-diffusion models (ModelConfig.diffusion_block > 0): the
+    # denoising forwards that reveal a block when a request names none
+    # ("denoise_steps"); each forward reveals block / denoise_steps
+    # masked positions, leftmost first, and one more forward commits the
+    # clean block. 0 = the model's block length.
+    denoise_steps: int = 0
     # Fleet-wide self-speculation: True speculates for EVERY eligible
     # greedy slot; False speculates only for requests that ask
     # ("speculative": true on /generate). Either way the scheduler

@@ -118,8 +118,9 @@ import numpy as np
 from ..utils import faults
 from ..utils.logging import get_logger
 from ..utils.metrics import (
-    ADMISSION_WAIT_HELP, DEFAULT_SIZE_BUCKETS, SLOT_RELEASE_HELP,
-    SLOT_TURNOVER_HELP, STEPS_AHEAD_BUCKETS,
+    ADMISSION_WAIT_HELP, DEFAULT_SIZE_BUCKETS, DIFFUSION_FORWARDS_HELP,
+    DIFFUSION_TOKENS_HELP, SLOT_RELEASE_HELP, SLOT_TURNOVER_HELP,
+    STEPS_AHEAD_BUCKETS,
 )
 from ..utils.retry import overload_retry_after
 from ..utils.tracing import PhaseClock, Trace, sample_decision
@@ -513,6 +514,45 @@ class ContinuousEngine:
         self._spec_k_max = max(0, int(getattr(ecfg, "spec_draft_len", 0)))
         self._spec_auto = bool(getattr(ecfg, "spec_decode", False))
         self._spec_capable = bool(self._chunked and self._spec_k_max > 0)
+        # Generation by diffusion over blocks (cfg.diffusion_block > 0,
+        # engine/paged.DiffState): a decode row is its whole open block,
+        # a step is one forward, and a forward either reveals some of the
+        # block's masked positions or, when none is left, commits the
+        # block and emits it. The count a forward reveals is fixed per
+        # row at admission, so the host's position model is exact in
+        # FORWARDS: for such a fleet `_host_pos[b]` counts the forwards
+        # dispatched for the row and `_host_end[b]` the forwards its
+        # budget takes (`_blk_*`: the row's committed prompt length, the
+        # forwards of its first block, which a prompt's remainder can
+        # shorten, and of every later one), and `_blk_at` turns a forward's
+        # index into the row's length and the forward's kind.
+        self._blk = int(cfg.diffusion_block)
+        self._diff = None
+        if self._blk:
+            if not self._chunked:
+                raise ValueError(
+                    f"{cfg.name}: a block-diffusion model is served by the "
+                    f"chunked ragged paged scheduler (pass kv_pool_blocks; "
+                    f"keep ragged_prefill and chunked_prefill on)"
+                )
+            if self.kv_block_size % self._blk or self._ragged_tile % self._blk:
+                raise ValueError(
+                    f"{cfg.name}: kv_block_size ({self.kv_block_size}) and "
+                    f"the query tile ({self._ragged_tile}) must be whole "
+                    f"multiples of the diffusion block ({self._blk})"
+                )
+            # a forward already carries a whole block a row: no drafting
+            self._spec_k_max, self._spec_auto = 0, False
+            self._spec_capable = False
+            self._diff = self._P.init_diffusion(cfg, self.n_slots)
+            self._denoise_default = int(
+                getattr(ecfg, "denoise_steps", 0) or self._blk
+            )
+            self._check_denoise(self._denoise_default)
+        self._blk_base = np.zeros((self.n_slots,), np.int64)
+        self._blk_first = np.ones((self.n_slots,), np.int64)
+        self._blk_later = np.ones((self.n_slots,), np.int64)
+        self._blk_skip = np.zeros((self.n_slots,), np.int64)
         # slot -> FIFO of dicts per unfetched verify launch ({req, nd,
         # pred (drafts + predicted correction, n-gram mode), adv
         # (position-advance upper bound nd + 1)})
@@ -634,7 +674,8 @@ class ContinuousEngine:
             engine.engine_cfg.kv_shadow if kv_shadow is None else kv_shadow
         )
         if self.paged:
-            # a latent pool (models/mla_moe.py) carries neither of these
+            # a pool with routed counts (a latent pool, models/mla_moe.py;
+            # the llama family's routed layer) carries neither of these
             self._P.refuse_unsupported_latent(
                 cfg, kv_shadow=use_shadow and self._bpx is not None,
                 bucketed=not self._chunked,
@@ -975,6 +1016,13 @@ class ContinuousEngine:
             "expert layers x experts x scheduler steps launched: what "
             "dli_moe_experts_touched_total is a share of", ("phase",),
         )
+        self._m_diff_forwards = m.counter(
+            "dli_diffusion_row_forwards_total", DIFFUSION_FORWARDS_HELP,
+            ("kind",),
+        )
+        self._m_diff_tokens = m.counter(
+            "dli_diffusion_tokens_total", DIFFUSION_TOKENS_HELP,
+        ).labels()
         self._m_steps_ahead = m.histogram(
             "dli_launch_steps_ahead",
             "scheduler steps dispatched and unfetched when a launch was "
@@ -1280,6 +1328,9 @@ class ContinuousEngine:
         prefill_only = bool(kwargs.pop("prefill_only", False))
         if prefill_only:
             kwargs["max_tokens"] = 1
+        err = self._diffusion_reject(kwargs)
+        if err is not None:
+            return err
         if self._needs_solo(kwargs):
             return self.engine.generate(prompt, **kwargs)
         req = _Request(prompt, kwargs,
@@ -1329,6 +1380,10 @@ class ContinuousEngine:
         adapter = kwargs.pop("adapter", None) or None
         tenant = kwargs.pop("tenant", None) or None
         err = self._adapter_reject(adapter, kwargs)
+        if err is not None:
+            yield {**err, "done": True}
+            return
+        err = self._diffusion_reject(kwargs)
         if err is not None:
             yield {**err, "done": True}
             return
@@ -1782,6 +1837,8 @@ class ContinuousEngine:
             self.n_slots, self.cfg.vocab_size
         )
         self._fsm = jnp.zeros((self.n_slots,), jnp.int32)
+        if self._blk:
+            self._diff = self._P.init_diffusion(self.cfg, self.n_slots)
         if self._draft_mode:
             # the draft pool is rebuilt outright like the target pool
             # (it may have been donated mid-crash); its content is pure
@@ -2754,6 +2811,92 @@ class ContinuousEngine:
         finally:
             self._restarting = False
 
+    # -- block diffusion: the host's position model, in forwards --------------
+    def _check_denoise(self, steps: int) -> int:
+        """denoise_steps of a request (or the server's default): a forward
+        reveals block / denoise_steps masked positions, a whole number."""
+        if steps < 1 or self._blk % steps:
+            raise ValueError(
+                f"denoise_steps must divide the model's block length "
+                f"{self._blk}, got {steps}"
+            )
+        return steps
+
+    def _diffusion_reject(self, kwargs: dict) -> Optional[dict]:
+        """What a block-diffusion fleet cannot honor: the solo engine's
+        contracts (it decodes one token a forward) and the penalties (a
+        block's tokens are chosen together, from logits no earlier token
+        of the block conditioned)."""
+        if not self._blk:
+            return None
+        bad = self._needs_solo(kwargs) or any(
+            float(kwargs.get(name, neutral)) != neutral
+            for name, neutral in (("repetition_penalty", 1.0),
+                                  ("frequency_penalty", 0.0),
+                                  ("presence_penalty", 0.0))
+        )
+        if not bad:
+            try:
+                self._check_denoise(int(kwargs.get(
+                    "denoise_steps", self._denoise_default)))
+                return None
+            except (TypeError, ValueError) as e:
+                msg = str(e)
+        else:
+            msg = (f"{self.cfg.name} generates by diffusion over blocks: no "
+                   f"seed / debug / logprobs / logit_bias / beams / "
+                   f"constraint / speculative / penalty contract")
+        return {"error": f"Error: {msg}", "status": "failed",
+                "error_type": "invalid_request"}
+
+    def _blk_plan(self, slot: int, head: int, reveal: int, max_tokens: int):
+        """Arm the position model of a row whose open block starts with
+        `head` prompt tokens: forwards a block = its denoise forwards + the
+        commit; the budget takes ceil((head + max_tokens) / block) blocks."""
+        B = self._blk
+        self._blk_skip[slot] = head
+        self._blk_first[slot] = -(-(B - head) // reveal) + 1
+        self._blk_later[slot] = B // reveal + 1
+        blocks = -(-(head + max_tokens) // B)
+        self._host_pos[slot] = 0
+        self._host_end[slot] = (
+            self._blk_first[slot] + (blocks - 1) * self._blk_later[slot]
+        )
+
+    def _blk_at(self, fwd):
+        """(row length committed before forward `fwd`, whether `fwd` is a
+        commit, masked positions it reveals) for forward indices fwd
+        [slots, ...] (numpy-broadcasting over trailing axes)."""
+        ex = (slice(None),) + (None,) * (np.ndim(fwd) - 1)
+        first, later = self._blk_first[ex], self._blk_later[ex]
+        in_first = fwd < first
+        rest = np.maximum(fwd - first, 0)
+        block = np.where(in_first, 0, 1 + rest // later)
+        within = np.where(in_first, fwd, rest % later)
+        denoise = np.where(in_first, first, later) - 1
+        masks = np.where(in_first, self._blk - self._blk_skip[ex], self._blk)
+        reveal = self._blk // np.maximum(later - 1, 1)
+        shown = np.clip(masks - within * reveal, 0, reveal)
+        commit = within >= denoise
+        return (self._blk_base[ex] + block * self._blk, commit,
+                np.where(commit, 0, shown))
+
+    def _blk_fields(self, fwd, alive, forwards: int) -> dict:
+        """The launch record's diffusion fields for a launch of `forwards`
+        forwards: row-forwards `fwd` of which `alive` are live on the
+        device, counted with the record."""
+        _, commit, shown = self._blk_at(fwd)
+        fields = {
+            "forwards": forwards,
+            "denoise_rows": int(np.sum(alive & ~commit)),
+            "commit_rows": int(np.sum(alive & commit)),
+            "revealed_tokens": int(np.sum(shown * alive)),
+        }
+        self._m_diff_forwards.labels(kind="denoise").inc(
+            fields["denoise_rows"])
+        self._m_diff_forwards.labels(kind="commit").inc(fields["commit_rows"])
+        return fields
+
     # -- the launch record (ISSUE 24) -----------------------------------------
     def _launch_record(self, phase: str, steps: int, kv_tokens: int,
                        kv_grid_tokens: int, row_steps: int,
@@ -2904,18 +3047,32 @@ class ContinuousEngine:
         step = np.arange(K)
         at = self._host_pos[:, None] + step
         alive = step < live[:, None]  # the device holds the row active
+        span, diff_fields = 1, {}
+        if self._blk:
+            # `at` counts forwards: each reads the row's whole cache and
+            # its open block
+            diff_fields = self._blk_fields(at, alive, K)
+            at, span = self._blk_at(at)[0], self._blk
         rec = self._launch_record(
             "chunk", K,
-            kv_tokens=np.sum(self._kv_span(at) * alive),
-            kv_grid_tokens=np.sum(self._kv_walk(at, alive)),
+            kv_tokens=np.sum(self._kv_span(at, span) * alive),
+            kv_grid_tokens=np.sum(self._kv_walk(at, alive * span)),
             row_steps=int(live.sum()),
-            decode_rows=int(np.count_nonzero(live)),
+            decode_rows=int(np.count_nonzero(live)), **diff_fields,
         )
         # every believed-active slot advances K (over-advance on rows
         # that die mid-chunk is masked garbage, the frozen-row rule)
         self._host_pos[rows] += K
         self._clock.mark("dispatch", "launch.chunk", **rec)
-        if self.paged:
+        if self._blk:
+            emitted, mask, self.state, self.cache, self._diff = (
+                self.backend.decode_slots_paged(
+                    self.state, self.cache, self._table_dev,
+                    self._next_key(), self.sparams,
+                    num_steps=self.chunk_steps, diff=self._diff,
+                )
+            )
+        elif self.paged:
             emitted, mask, self.state, self.cache = (
                 self.backend.decode_slots_paged(
                     self.state, self.cache, self._table_dev,
@@ -3345,6 +3502,15 @@ class ContinuousEngine:
             # crash-recovery continuation: prompt + pre-crash tokens
             ids = ids + list(req.salvaged)
         prompt_len = len(ids)
+        diffusion = None
+        if self._blk:
+            # the prompt's whole blocks are what prefill commits; its last
+            # partial block stays open, the head of the first generated one
+            steps = self._check_denoise(
+                int(k.get("denoise_steps", self._denoise_default))
+            )
+            whole = prompt_len // self._blk * self._blk
+            diffusion = (ids[whole:], self._blk // steps)
         if req.kv_hint is not None and req.adapter is None:
             # same remote-hit seam as the whole-prefill admission: a
             # fetched chain becomes a deeper exact-depth hit below.
@@ -3423,6 +3589,11 @@ class ContinuousEngine:
             req, ids, p0, prompt_len, max_tokens, slot, sampling,
             presence_row, table_row, self._sched.classify(req.slo),
         )
+        if diffusion is not None:
+            # the job lands the committed blocks alone (p0, a multiple of
+            # the pool's block size, never passes them)
+            job.ids, job.prompt_len = ids[:whole], whole
+            job.diffusion = diffusion
         self._table[slot] = table_row
         self._table_dev = None
         self._slot_pages[slot] = req.adapter_page or 0
@@ -3614,7 +3785,11 @@ class ContinuousEngine:
                 self._assignment[b].slo for b in active
             },
         )
-        if not active and not plan:
+        # a block-diffusion prompt whose whole blocks are all mapped from
+        # the prefix index (or shorter than a block) has no chunk to land:
+        # its slot is armed by this launch all the same
+        landed = [job for job in self._jobs if job.remaining == 0]
+        if not active and not plan and not landed:
             return None
         faults.check("decode_launch", tag=",".join(
             r.prompt for r in self._assignment if r is not None
@@ -3624,9 +3799,19 @@ class ContinuousEngine:
                 job.req.prompt for job, _ in plan
             ))
         W, B = self._sched_width, self.n_slots
+        Bd = self._blk
+        if Bd:
+            # a decode row is its open block: `block` query tokens at the
+            # row's committed length (a placeholder: the device's own
+            # state.pos is substituted, as for a verify row)
+            fwd = self._host_pos.copy()
+            starts, _, _ = self._blk_at(fwd)
+            alive_now = fwd < self._host_end
         entries = []
         for b in active:
-            if b in spec_rows:
+            if Bd:
+                entries.append((b, int(starts[b]), Bd, P.RAGGED_PREFILL))
+            elif b in spec_rows:
                 # verify row: [current + k drafts] — a short prefill-kind
                 # row over the slot's own block table (the whole point:
                 # the ragged kernel already serves it, no new kernel)
@@ -3647,7 +3832,7 @@ class ContinuousEngine:
             entries, width=W, tile=tile,
         )
         dev_dev = None
-        if self._spec_capable:
+        if self._spec_capable or Bd:
             # mark every decode/verify entry (the first n_dec) for
             # on-device position substitution — the host start values
             # above are placeholders for those rows
@@ -3660,7 +3845,7 @@ class ContinuousEngine:
             )
         toks = np.zeros((W,), np.int32)
         dec_flag = np.zeros((W,), bool)
-        dec_idx = np.zeros((B,), np.int32)
+        dec_idx = np.full((B,), -1 if Bd else 0, np.int32)
         n_dec = len(active)
         K1 = self._spec_k_max + 1
         sp_on = np.zeros((B,), bool)
@@ -3671,6 +3856,11 @@ class ContinuousEngine:
             # the entry's FIRST flat slot is dec_flag-substituted from
             # device state (token AND position) for plain decode rows
             # and verify rows alike
+            if Bd:
+                # every token of the block comes from the device's
+                # DiffState (mixed_step_ragged reads dev.tok_on)
+                dec_idx[b] = off
+                continue
             dec_flag[off] = True
             if b in spec_rows:
                 kb, drafts, _pred = spec_rows[b]
@@ -3687,7 +3877,10 @@ class ContinuousEngine:
         completions = {}
         arm = self._idle_arm
         arm_np = None
-        for (job, n, start), off in zip(chunk_list, offsets[n_dec:]):
+        landing = list(zip(chunk_list, offsets[n_dec:])) + [
+            ((job, 0, job.p0), 0) for job in landed
+        ]
+        for (job, n, start), off in landing:
             toks[off : off + n] = job.ids[start : start + n]
             if job.done == 0 and self._ended_at[job.slot] is not None:
                 # the slot's next tenant starts to land: the steps
@@ -3714,7 +3907,22 @@ class ContinuousEngine:
                  sp[5][s], sp[6][s], sp[7][s]) = job.sampling
                 presence[s] = job.presence_row
                 completions[s] = job.req
-                job.req.budget = job.max_tokens - 1
+                # (a diffusion row's budget is whole: no first token
+                # comes out of its prefill)
+                job.req.budget = job.max_tokens - (0 if Bd else 1)
+        diffusion = {}
+        if Bd:
+            d_open = np.full((B, Bd), self.cfg.mask_token_id, np.int32)
+            d_skip = np.zeros((B,), np.int32)
+            d_reveal = np.ones((B,), np.int32)
+            for s, req in completions.items():
+                head, reveal = self._prefilling[s].diffusion
+                d_open[s, : len(head)] = head
+                d_skip[s], d_reveal[s] = len(head), reveal
+            diffusion = {"diff": self._diff, "darm": P.DiffState(
+                jnp.asarray(d_open), jnp.asarray(d_skip),
+                jnp.asarray(d_reveal),
+            )}
         if arm_np is not None:
             (on, idx, plen, mtk, sp, presence) = arm_np
             arm = P.MixedArm(
@@ -3780,32 +3988,38 @@ class ContinuousEngine:
         # and itself once (a lower bound: the kernel reads per query
         # tile)
         live_tiles = stats["tiles"] - stats["pad_tiles"]
+        diff_fields = {}
+        if Bd:
+            rode = np.zeros((B,), bool)
+            rode[active] = True
+            diff_fields = self._blk_fields(fwd, rode & alive_now, 1)
         rec = self._launch_record(
             "mixed", 1,
             kv_tokens=sum(
                 int(self._kv_span(start, n))
                 for b, start, n, _ in entries[:n_dec]
-                if start < self._host_end[b]
+                if self._host_pos[b] < self._host_end[b]
             ) + sum(int(self._kv_span(st, n)) for _, n, st in chunk_list),
             kv_grid_tokens=np.sum(self._kv_walk(meta[:, 1], meta[:, 2])),
             row_steps=n_dec,
             decode_rows=n_dec, prefill_chunks=len(chunk_list),
             prefill_tokens=sum(n for _, n, _ in chunk_list),
             spec_drafted=sum(nd for nd, _, _ in spec_rows.values()),
-            tiles=stats["tiles"], tiles_live=live_tiles,
+            tiles=stats["tiles"], tiles_live=live_tiles, **diff_fields,
         )
         self._clock.mark("dispatch", "launch.mixed", **rec)
-        packed, self.state, self.sparams, self.cache = (
-            self.backend.mixed_step_ragged(
-                jnp.asarray(toks), jnp.asarray(tok_row),
-                jnp.asarray(tok_pos), jnp.asarray(dec_flag),
-                jnp.asarray(meta), self.cache, self._table_dev,
-                self.state, self.sparams, self._next_key(),
-                jnp.asarray(dec_idx), arm,
-                spec=spec_plan_dev, spec_toks=spec_toks_dev,
-                dev=dev_dev, pages=pages_dev,
-            )
+        out = self.backend.mixed_step_ragged(
+            jnp.asarray(toks), jnp.asarray(tok_row),
+            jnp.asarray(tok_pos), jnp.asarray(dec_flag),
+            jnp.asarray(meta), self.cache, self._table_dev,
+            self.state, self.sparams, self._next_key(),
+            jnp.asarray(dec_idx), arm,
+            spec=spec_plan_dev, spec_toks=spec_toks_dev,
+            dev=dev_dev, pages=pages_dev, **diffusion,
         )
+        if Bd:
+            *out, self._diff = out
+        packed, self.state, self.sparams, self.cache = out
         t_launch = self._clock.mark("plan")
         # host position model + completion bookkeeping AFTER the launch
         # is enqueued (the arming rode the program itself). Verify rows
@@ -3842,8 +4056,13 @@ class ContinuousEngine:
         for slot, req in completions.items():
             job = self._prefilling.pop(slot)
             self._jobs.remove(job)
-            self._host_pos[slot] = job.prompt_len
-            self._host_end[slot] = job.prompt_len + req.budget
+            if Bd:
+                self._blk_base[slot] = job.prompt_len
+                self._blk_plan(slot, len(job.diffusion[0]),
+                               job.diffusion[1], job.max_tokens)
+            else:
+                self._host_pos[slot] = job.prompt_len
+                self._host_end[slot] = job.prompt_len + req.budget
             if self._bpx is not None:
                 # full prompt blocks are complete + immutable once this
                 # launch lands; later gathers serialize behind it on
@@ -3929,7 +4148,9 @@ class ContinuousEngine:
         # [5, B] plain / [5 + 2*(K+1) + 1, B] with a SpecPlan — still the
         # ONE fetch per step
         packed = self._fetch(packed_dev, t_launch, rec)
-        emitted, mask, active, firsts, armed = packed[:5]
+        E = max(1, self._blk)  # emission rows: a block-diffusion forward's block
+        em, mk = packed[:E], packed[E : 2 * E].astype(bool)
+        active, firsts, armed = packed[2 * E : 2 * E + 3]
         now = time.time()
         for slot, req in completions.items():
             if req.done.is_set() or req.drop_seq > seq:
@@ -3937,9 +4158,12 @@ class ContinuousEngine:
                 # launched — its completion bookkeeping is stale (the
                 # resume re-admission regenerates the first token)
                 continue
-            req.first_id = int(firsts[slot])
-            if not req.ttft:
-                req.ttft = now - req.t_start
+            if not self._blk:
+                # (a diffusion row's first tokens come with its first
+                # commit: _distribute stamps its ttft then)
+                req.first_id = int(firsts[slot])
+                if not req.ttft:
+                    req.ttft = now - req.t_start
             prefill_s = req.trace.checkpoint("admission")  # chunked prefill
             with self._cv:
                 self.admitted += 1
@@ -3955,8 +4179,6 @@ class ContinuousEngine:
                 request_id=req.trace.request_id,
             )
             self._post_admit(req)
-        em = emitted[None, :]
-        mk = mask[None, :].astype(bool)
         prof_acc = 0  # accepted draft tokens in THIS launch (attribution)
         if spec_meta:
             # combined emission matrix: decode rows keep their one
@@ -3968,10 +4190,11 @@ class ContinuousEngine:
             sp_emit = packed[5 : 5 + K1]
             sp_mask = packed[5 + K1 : 5 + 2 * K1].astype(bool)
             sp_adv = packed[5 + 2 * K1]
+            emitted, mask = em[0], mk[0]
             em = np.zeros((K1, B), emitted.dtype)
             mk = np.zeros((K1, B), bool)
             em[0] = emitted
-            mk[0] = mask.astype(bool)
+            mk[0] = mask
             for slot, (req, nd) in spec_meta.items():
                 em[:, slot] = sp_emit[:, slot]
                 mk[:, slot] = sp_mask[:, slot]
@@ -4638,7 +4861,7 @@ class ContinuousEngine:
         ))
         # [2K+1, B] — the ONE fetch per chunk
         packed = self._fetch(packed_dev, t_launch, rec)
-        K = self.chunk_steps
+        K = self.chunk_steps * max(1, self._blk)  # a forward's block, row by row
         emitted = packed[:K]
         mask = packed[K : 2 * K].astype(bool)
         active = packed[2 * K].astype(bool)
@@ -4676,6 +4899,10 @@ class ContinuousEngine:
             mine = owns or self._retiring[b] is req
             new = emitted[mask[:, b], b]
             req.tokens.extend(int(t) for t in new)
+            if self._blk and len(new):
+                self._m_diff_tokens.inc(len(new))
+                if not req.ttft:  # the first committed block has arrived
+                    req.ttft = now - req.t_start
             if len(new) and self._shadow is not None:
                 # decode crossed a block boundary? shadow the newly
                 # immutable blocks (token content is host-side now, the

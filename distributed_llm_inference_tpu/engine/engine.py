@@ -33,9 +33,9 @@ from ..models import api as M
 from ..utils import faults
 from ..utils.logging import get_logger, request_id_context
 from ..utils.metrics import (
-    ADMISSION_WAIT_HELP, DEFAULT_SIZE_BUCKETS, SLOT_RELEASE_HELP,
-    SLOT_TURNOVER_HELP, STEPS_AHEAD_BUCKETS,
-    MetricsRegistry,
+    ADMISSION_WAIT_HELP, DEFAULT_SIZE_BUCKETS, DIFFUSION_FORWARDS_HELP,
+    DIFFUSION_TOKENS_HELP, SLOT_RELEASE_HELP, SLOT_TURNOVER_HELP,
+    STEPS_AHEAD_BUCKETS, MetricsRegistry,
 )
 from ..utils.probe import device_summary
 from ..utils.tokenizer import load_tokenizer
@@ -197,12 +197,12 @@ class SingleDeviceBackend:
         )
 
     def decode_slots_paged(self, state, pool, table, key, sparams, *,
-                           num_steps, pages=None):
+                           num_steps, pages=None, **diffusion):
         from . import paged as P
 
         return P.decode_slots_paged(
             self.cfg, self.params, state, pool, table, key, sparams,
-            num_steps=num_steps, pages=pages,
+            num_steps=num_steps, pages=pages, **diffusion,
         )
 
     def fill_scratch_paged(self, pool, table_row):
@@ -270,13 +270,15 @@ class SingleDeviceBackend:
 
     def mixed_step_ragged(self, tokens, tok_row, tok_pos, dec_flag, meta,
                           pool, table, state, sparams, key, dec_idx, arm,
-                          spec=None, spec_toks=None, dev=None, pages=None):
+                          spec=None, spec_toks=None, dev=None, pages=None,
+                          **diffusion):
         from . import paged as P
 
         return P.mixed_step_ragged(
             self.cfg, self.params, tokens, tok_row, tok_pos, dec_flag,
             meta, pool, table, state, sparams, key, dec_idx, arm,
             spec=spec, spec_toks=spec_toks, dev=dev, pages=pages,
+            **diffusion,
         )
 
     # paged adapter pool (engine/adapters.py): the lora leaves live in
@@ -668,6 +670,13 @@ class InferenceEngine:
         self.metrics.histogram(
             "dli_slot_turnover_steps", SLOT_TURNOVER_HELP,
             buckets=STEPS_AHEAD_BUCKETS,
+        )
+        self.metrics.counter(
+            "dli_diffusion_row_forwards_total", DIFFUSION_FORWARDS_HELP,
+            ("kind",),
+        )
+        self.metrics.counter(
+            "dli_diffusion_tokens_total", DIFFUSION_TOKENS_HELP,
         )
         self.metrics.counter(
             "dli_worker_phase_seconds_total",
@@ -1133,6 +1142,15 @@ class InferenceEngine:
         """
         t_start = time.time()
         trace = _trace if _trace is not None else Trace(request_id)
+        if self.cfg.diffusion_block:
+            # this loop decodes one token a forward; a block-diffusion
+            # model is served by the continuous engine's paged fleet
+            return {
+                "error": f"Error: {self.cfg.name} generates by diffusion "
+                "over blocks and is served by the continuous engine "
+                "(--continuous N --kv-pool-blocks M) only",
+                "status": "failed", "error_type": "invalid_request",
+            }
 
         with request_id_context(trace.request_id):
             dl_s, dl_type = self._resolve_deadline(deadline_ms)

@@ -75,7 +75,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config import ModelConfig
-from ..ops.attention import attend
+from ..ops.attention import attend, block_frontier
 from ..ops.kv_quant import KVQuant
 from ..ops.kv_quant import dequantize as kv_dequantize
 from ..ops.kv_quant import quantize_chunk
@@ -117,6 +117,12 @@ def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
         n_layers or cfg.n_layers, n_blocks, cfg.n_kv_heads, block_size,
         cfg.head_dim,
     )
+    if cfg.moe_ffn_dim:  # the llama family's routed layer: the same counts
+        return {
+            "k": jnp.zeros(shape, cfg.jnp_dtype),
+            "v": jnp.zeros(shape, cfg.jnp_dtype),
+            "routed": jnp.zeros((2, shape[0], cfg.n_experts), jnp.int32),
+        }
     if cfg.kv_quant == "int8":
         sshape = shape[:-1]
         leaf = lambda: KVQuant(  # noqa: E731 - two identical leaves
@@ -286,11 +292,13 @@ def pool_block_size(pool) -> int:
 
 
 def refuse_unsupported_latent(cfg: ModelConfig, **asked):
-    """The ONE start-up check of what a latent pool does not carry. Each
-    caller passes what it knows (runtime.create_backend: quant, kv_quant,
-    mesh, lora, adapter_slots; the continuous engine: kv_shadow,
-    ragged); a per-head K/V model passes through."""
-    if not cfg.latent_dim:
+    """The ONE start-up check of what a pool with a "routed" leaf does not
+    carry: a latent pool (models/mla_moe.py) and the llama family's routed
+    layer (cfg.moe_ffn_dim > 0: SDAR). Each caller passes what it knows
+    (runtime.create_backend: quant, kv_quant, mesh, lora, adapter_slots;
+    the continuous engine: kv_shadow, bucketed); a dense per-head K/V
+    model passes through."""
+    if not (cfg.latent_dim or cfg.moe_ffn_dim):
         return
     why = {
         "quant": "weight quantization: ops/quant knows no expert-bank or "
@@ -306,11 +314,23 @@ def refuse_unsupported_latent(cfg: ModelConfig, **asked):
         "bucketed": "the bucketed scratch prefill: a latent model is "
                     "served by ragged chunked prefill only",
     }
+    lead = "a latent-attention model is served on one device from a " \
+           "latent pool"
+    if not cfg.latent_dim:
+        lead = "routed experts behind the llama family's layer are served " \
+               "on one device from the paged pool"
+        why.update({
+            "quant": "weight quantization: ops/quant knows no expert bank",
+            "kv_quant": "the int8 pool: the routed counts ride the pool",
+            "mesh": "pp / tp / ep / sp / dp meshes: the expert banks ride "
+                    "outside the layer scan and are not partitioned",
+            "bucketed": "the bucketed scratch prefill: served by ragged "
+                        "chunked prefill only",
+        })
     bad = [why[name] for name, value in asked.items() if value]
     if bad:
         raise ValueError(
-            f"{cfg.name}: a latent-attention model is served on one device "
-            f"from a latent pool, which does not carry: " + "; ".join(bad)
+            f"{cfg.name}: {lead}, which does not carry: " + "; ".join(bad)
         )
 
 
@@ -670,6 +690,143 @@ def _forward_step_paged(cfg, params, tokens, pool, table, pos, pages=None,
     return logits[:, 0, :], pool
 
 
+# -- generation by diffusion over blocks (cfg.diffusion_block > 0) -------------
+#
+# A row's sequence is cut into blocks of B tokens at absolute positions.
+# Blocks below state.pos are COMMITTED: their K/V is in the pool, computed
+# from clean tokens under the block mask (ops/attention.block_frontier), and
+# never changes. The block at state.pos is OPEN: DiffState.open holds its B
+# tokens, cfg.mask_token_id where nothing is revealed yet. A forward carries
+# the open block as B query tokens of one row through the ragged seam (a
+# q_len-B tile at q_start = state.pos, like a draft-and-verify row), writes
+# their K/V at the block's positions and reads the logits AT each position:
+#
+#   * masks left: DENOISE. The `reveal` leftmost masked positions take their
+#     token (the mask id's logit at -inf); nothing is emitted, state.pos
+#     stays. What this forward wrote to the pool is the K/V of a block still
+#     holding masks: the row's next forward overwrites it before reading it,
+#     no other row's table maps the block, and the prefix index only ever
+#     registers blocks below a prompt's length, which admission committed.
+#   * no mask left: COMMIT. The K/V just written is the clean block's; the
+#     block's generated tokens are emitted (not the prompt's remainder at
+#     its head, `skip`; none past the budget or a stop token), state.pos
+#     moves on by B and the next block opens, all masks.
+#
+# The count a forward reveals is fixed per row at admission (B /
+# denoise_steps), so the host's position model knows every forward's kind
+# and the row's last forward without a fetch (engine/continuous).
+
+
+class DiffState(NamedTuple):
+    """Device-side per-slot state of block diffusion, beside SlotState
+    (whose pos is the open block's first position, and whose token,
+    presence and counts a diffusion row does not use). Also the shape of a
+    completing prefill's arming operands beside MixedArm (`darm`: the
+    prompt's remainder then masks, its length, the row's reveal count;
+    rows with arm.on False are untouched)."""
+
+    open: jnp.ndarray  # i32 [B, block]: the open block's tokens
+    skip: jnp.ndarray  # i32 [B]: prompt tokens at the open block's head
+    # (a prompt's last partial block; 0 from the second block on)
+    reveal: jnp.ndarray  # i32 [B]: masked positions a forward reveals
+
+
+def init_diffusion(cfg: ModelConfig, n_slots: int) -> DiffState:
+    z = jnp.zeros((n_slots,), jnp.int32)
+    return DiffState(
+        jnp.full((n_slots, cfg.diffusion_block), cfg.mask_token_id,
+                 jnp.int32), z, z + cfg.diffusion_block,
+    )
+
+
+def _forward_blocks_paged(cfg, params, state: G.SlotState, diff: DiffState,
+                          pool, table):
+    """One forward of every live row's open block over the paged pool:
+    float32 logits [B, block, V] at the block's positions, and the pool
+    with the block's K/V written. Rows that are not active carry nothing
+    (q_len 0: not walked, not written, no expert)."""
+    from ..models import api as M
+
+    S, Bd = diff.open.shape
+    rows = jnp.arange(S, dtype=jnp.int32)
+    tok_row = jnp.repeat(jnp.where(state.active, rows, -1), Bd)
+    tok_pos = (
+        state.pos[:, None] + jnp.arange(Bd, dtype=jnp.int32)[None, :]
+    ).reshape(-1)
+    meta = jnp.stack(
+        [rows, state.pos, jnp.where(state.active, Bd, 0),
+         jnp.full((S,), RAGGED_PREFILL, jnp.int32)], axis=1,
+    )
+    x = M.embed(cfg, params, diff.open.reshape(-1)[:, None], tok_pos)
+    x, pool = M.forward_layers(
+        cfg, params["layers"], x, pool, tok_pos,
+        attn_hook=make_ragged_fill_hook(table, meta, tok_row),
+        attn_seq_len=1,
+    )
+    logits = M.unembed(cfg, params, x)[:, 0, :]
+    return logits.reshape(S, Bd, -1), pool
+
+
+def diffusion_step(cfg: ModelConfig, state: G.SlotState,
+                   sparams: G.SlotParams, diff: DiffState, logits, key,
+                   on=None):
+    """ONE copy of a block-diffusion forward's bookkeeping (the decode
+    chunk's loop and the mixed launch both call it). logits [B, block, V]:
+    the model's output AT the open block's positions (no shift by one).
+    on: rows that rode this forward (None: every row).
+
+    The token of a masked position is chosen from its own logits with the
+    mask id suppressed, by the row's sampling knobs (penalties do not
+    apply: admission refuses them). A row with masks left reveals its
+    `reveal` leftmost ones; a row with none commits (see the section
+    comment). Returns (state, diff, emit [B, block], emit_ok [B, block])."""
+    from ..ops.sampling import sample_token, suppress_token
+
+    Bd = cfg.diffusion_block
+    mask_id = jnp.int32(cfg.mask_token_id)
+    pad = jnp.int32(cfg.pad_token_id)
+    live = state.active if on is None else state.active & on
+    masked = diff.open == mask_id  # [B, block]
+    any_masked = jnp.any(masked, axis=1)
+    denoise = (live & any_masked)[:, None]
+    commit = live & ~any_masked
+    # denoise: the leftmost `reveal` masked positions take their token
+    cand = sample_token(
+        key, suppress_token(logits.astype(jnp.float32), cfg.mask_token_id),
+        sparams.temperature[:, None, None], sparams.top_k[:, None, None],
+        sparams.top_p[:, None, None], (sparams.greedy | ~live)[:, None],
+        sparams.min_p[:, None, None],
+    )
+    rank = jnp.cumsum(masked.astype(jnp.int32), axis=1) - 1
+    show = denoise & masked & (rank < diff.reveal[:, None])
+    opened = jnp.where(show, cand, diff.open)
+    # commit: emit the clean block's generated tokens
+    j = jnp.arange(Bd, dtype=jnp.int32)[None, :]
+    gen = j >= diff.skip[:, None]
+    order = j - diff.skip[:, None]  # a token's place among the generated
+    stop = G.stop_mask(cfg, diff.open) & gen
+    before = jnp.cumsum(stop.astype(jnp.int32), axis=1) == 0
+    room = order < state.remaining[:, None]
+    emit_ok = commit[:, None] & gen & before & room
+    n_emit = jnp.sum(emit_ok.astype(jnp.int32), axis=1)
+    # a stop token ends the row only where plain decoding would have
+    # reached it: inside the budget
+    saw_stop = commit & jnp.any(stop & room, axis=1)
+    remaining = state.remaining - n_emit
+    emit = jnp.where(emit_ok, diff.open, pad)
+    state = state._replace(
+        pos=state.pos + jnp.where(commit, Bd, 0),
+        active=jnp.where(commit, ~saw_stop & (remaining > 0), state.active),
+        remaining=remaining,
+    )
+    diff = DiffState(
+        open=jnp.where(commit[:, None], mask_id, opened),
+        skip=jnp.where(commit, 0, diff.skip),
+        reveal=diff.reveal,
+    )
+    return state, diff, emit, emit_ok
+
+
 @functools.partial(
     jax.jit, static_argnames=("cfg", "num_steps"), donate_argnames=("pool",)
 )
@@ -684,15 +841,40 @@ def decode_slots_paged(
     *,
     num_steps: int,
     pages=None,
+    diff=None,
 ):
     """Paged twin of generate.decode_slots: advance every slot num_steps
     tokens over the block pool. Same slot_step, same emitted/emit_mask
     contract — only the cache strategy differs, so cross-mode token parity
     is structural. The table is a plain (traced) input: admission changes
     it without recompiling. pages: optional [B] i32 per-slot adapter
-    pages (0 = base), traced like the table."""
+    pages (0 = base), traced like the table.
+
+    A block-diffusion model (cfg.diffusion_block > 0, `diff` its
+    DiffState) runs num_steps FORWARDS instead, each carrying every live
+    row's whole open block (`_forward_blocks_paged`, `diffusion_step`):
+    emitted / emit_mask are [num_steps * block, B] (a forward's block row
+    by row) and the DiffState comes back last."""
 
     pool = _routed_reset(pool)
+    if cfg.diffusion_block:
+        def forward(carry, sub):
+            state, diff, pool = carry
+            logits, pool = _forward_blocks_paged(
+                cfg, params, state, diff, pool, table
+            )
+            state, diff, emit, ok = diffusion_step(
+                cfg, state, sparams, diff, logits, sub
+            )
+            return (state, diff, pool), (emit.T, ok.T)
+
+        subs = jax.random.split(key, num_steps)
+        (state, diff, pool), (emitted, emit_mask) = jax.lax.scan(
+            forward, (state, diff, pool), subs
+        )
+        rows = (num_steps * cfg.diffusion_block, -1)
+        return (emitted.reshape(rows), emit_mask.reshape(rows), state, pool,
+                diff)
 
     def body(carry, sub):
         state, pool = carry
@@ -886,7 +1068,8 @@ def _ragged_attend_xla(cfg, q, cache_k, cache_v, layer, table, tok_row,
 
         kv_pos = jnp.arange(S, dtype=jnp.int32)[None, :]
         q_pos = tok_pos[:, None]
-        mask = (kv_pos <= q_pos) & (tok_row >= 0)[:, None]  # [W, S]
+        q_end = block_frontier(q_pos, cfg.diffusion_block)
+        mask = (kv_pos <= q_end) & (tok_row >= 0)[:, None]  # [W, S]
         mask = win_mask(mask, kv_pos, q_pos)
         out = attend(
             q[:, 0][None], gathered1(cache_k), gathered1(cache_v),
@@ -903,7 +1086,8 @@ def _ragged_attend_xla(cfg, q, cache_k, cache_v, layer, table, tok_row,
 
     kv_pos = jnp.arange(S, dtype=jnp.int32)[None, None, :]
     q_pos = tok_pos[:, None, None]
-    mask = (kv_pos <= q_pos) & (tok_row >= 0)[:, None, None]
+    q_end = block_frontier(q_pos, cfg.diffusion_block)
+    mask = (kv_pos <= q_end) & (tok_row >= 0)[:, None, None]
     mask = win_mask(mask, kv_pos, q_pos)
     return attend(
         q, gathered(cache_k), gathered(cache_v), mask,
@@ -977,6 +1161,7 @@ def make_ragged_fill_hook(table, meta, tok_row):
                     window=w, scale=cfg.query_scale,
                     softcap=None if v is None else cfg.attn_softcap,
                     value_dim=cfg.kv_lora_rank if v is None else None,
+                    block=cfg.diffusion_block,
                 )
                 if write is None:
                     return out[:, None]
@@ -1312,7 +1497,9 @@ def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
                       dec_flag, meta, pool, table, state: G.SlotState,
                       sparams: G.SlotParams, key, dec_idx, arm: MixedArm,
                       spec: Optional[SpecPlan] = None, spec_toks=None,
-                      dev: Optional[DeviceMeta] = None, pages=None):
+                      dev: Optional[DeviceMeta] = None, pages=None,
+                      diff: Optional[DiffState] = None,
+                      darm: Optional[DiffState] = None):
     """One scheduler step: advance every active slot one decode token AND
     write the launch's prefill chunks into the pool, in one program.
 
@@ -1350,10 +1537,20 @@ def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
     (n-gram drafts arrive host-planned in `tokens` instead — either way
     zero extra host syncs).
 
+    A block-diffusion model (cfg.diffusion_block > 0; `diff` its
+    DiffState, `darm` the completing prefills' arming rows): a decode row is
+    its whole open block, a q_len-block entry whose tokens come from
+    diff.open and whose positions from state.pos (`dev` marks them, as it
+    marks a verify row's), dec_idx [B] the flat index of the block's first
+    token, -1 for a slot without a row in this launch; `diffusion_step`
+    takes slot_step's place and a completing prefill samples no first
+    token (`diffusion_epilogue`).
+
     Returns (packed int32 — [5, B] plain, [5 + 2*(K+1) + 1, B] with
     spec: emitted / emit_mask / active / firsts / armed [/ spec_emit /
     spec_mask / position advance], ONE fetch per step — state, sparams,
-    pool)."""
+    pool). Block diffusion: emitted and emit_mask are `block` rows each,
+    and the DiffState comes back last."""
     from ..models import api as M
 
     pool = _routed_reset(pool)
@@ -1375,12 +1572,24 @@ def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
             spec_toks.reshape(-1), mode="drop"
         )
     pos = jnp.where(dec_flag, state.pos[rows_ix], tok_pos)
+    if cfg.diffusion_block:  # a decode row's tokens: its open block
+        toks = jnp.where(dev.tok_on, diff.open[rows_ix, dev.tok_off], toks)
     x = M.embed(cfg, params, toks[:, None], pos)
     x, pool = M.forward_layers(
         cfg, params["layers"], x, pool, pos,
         attn_hook=make_ragged_fill_hook(table, meta, tok_row),
         attn_seq_len=1, lora_pages=_token_pages(pages, tok_row),
     )
+    if cfg.diffusion_block:
+        Bd = cfg.diffusion_block
+        at = jnp.maximum(dec_idx, 0)[:, None] + jnp.arange(Bd)[None, :]
+        logits = M.unembed(cfg, params, x[at.reshape(-1)])[:, 0, :]
+        packed, state, sparams, diff = diffusion_epilogue(
+            cfg, state, sparams, diff, logits.reshape(at.shape + (-1,)),
+            key, dec_idx >= 0, arm, darm,
+        )
+        packed = _pack_rows(packed, pool["routed"])
+        return packed, state, sparams, pool, diff
     # decode: gather each slot's flat position, one shared slot_step —
     # the same sampler/bookkeeping the whole-chunk decode programs run
     logits = M.unembed(cfg, params, x[dec_idx])[:, 0, :]  # [B, V]
@@ -1473,6 +1682,43 @@ def draft_propose_paged(dcfg: ModelConfig, dparams, token, pos, dpool,
         body, (token, pos, dpool), None, length=draft_len + 1
     )
     return props[:draft_len].swapaxes(0, 1), dpool
+
+
+def diffusion_epilogue(cfg: ModelConfig, state: G.SlotState,
+                       sparams: G.SlotParams, diff: DiffState, logits, key,
+                       on, arm: MixedArm, darm: DiffState):
+    """mixed_epilogue's twin for a block-diffusion model: `diffusion_step`
+    advances the rows that rode the launch (`on`), and a completing
+    prefill arms its slot with NO first token (a masked position's token
+    comes from the logits AT that position, which only a later forward
+    computes): its open block is the prompt's remainder followed by masks,
+    its position the prompt's whole blocks, its budget max_tokens whole.
+    Returns (packed [2 * block + 3, B]: emitted, emit_mask, active, a zero
+    row where an autoregressive launch packs its first tokens, armed —
+    state, sparams, diff)."""
+    state, diff, emit, emit_ok = diffusion_step(
+        cfg, state, sparams, diff, logits, key, on
+    )
+    a, a_col = arm.on, arm.on[:, None]
+    state = state._replace(
+        pos=jnp.where(a, arm.prompt_len, state.pos),
+        active=jnp.where(a, arm.max_tokens > 0, state.active),
+        remaining=jnp.where(a, arm.max_tokens, state.remaining),
+    )
+    diff = DiffState(
+        open=jnp.where(a_col, darm.open, diff.open),
+        skip=jnp.where(a, darm.skip, diff.skip),
+        reveal=jnp.where(a, darm.reveal, diff.reveal),
+    )
+    sparams = G.SlotParams(*(
+        jnp.where(a, new, old) for new, old in zip(arm.params, sparams)
+    ))
+    packed = jnp.concatenate([
+        emit.T, emit_ok.astype(jnp.int32).T,
+        state.active.astype(jnp.int32)[None],
+        jnp.zeros_like(state.pos)[None], a.astype(jnp.int32)[None],
+    ], axis=0)
+    return packed, state, sparams, diff
 
 
 def mixed_epilogue(cfg: ModelConfig, state: G.SlotState,

@@ -184,7 +184,7 @@ class PrefillJob:
 
     __slots__ = (
         "req", "ids", "p0", "done", "prompt_len", "max_tokens", "slot",
-        "sampling", "presence_row", "table_row", "cls",
+        "sampling", "presence_row", "table_row", "cls", "diffusion",
     )
 
     def __init__(self, req, ids, p0, prompt_len, max_tokens, slot, sampling,
@@ -200,6 +200,11 @@ class PrefillJob:
         self.presence_row = presence_row  # np bool [V] prompt token set
         self.table_row = table_row
         self.cls = cls  # SLOClass
+        # a block-diffusion model's job: (the prompt's remainder past its
+        # whole blocks, masked positions a forward reveals); `ids` and
+        # `prompt_len` then stop at the whole blocks, and `remaining` can
+        # be 0 from the start (engine/continuous._start_job)
+        self.diffusion = None
 
     @property
     def remaining(self) -> int:

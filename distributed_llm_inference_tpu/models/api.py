@@ -1,11 +1,17 @@
-"""Arch dispatch: one functional interface over the model families.
+"""Arch dispatch: one functional interface over the three model families.
 
 The engine and pipeline runtime call these; cfg.arch picks the family
-(llama: RMSNorm/RoPE/GQA/SwiGLU — gpt2: LayerNorm/learned-pos/MHA/gelu —
-mla_moe: latent attention, routed experts, a leading dense stack).
-llama and gpt2 share the stacked-layer pytree + KV-cache layout, so the
-pipeline partitioner and cache plumbing are agnostic between them;
-mla_moe has two stacks and a latent cache and serves one device only.
+(llama: RMSNorm/RoPE/GQA/SwiGLU, with an every-expert MoE FFN (Mixtral,
+qwen3-30b-a3b) or, at cfg.moe_ffn_dim > 0, routed experts, and at
+cfg.diffusion_block > 0 the block-diffusion mask (SDAR) — gpt2:
+LayerNorm/learned-pos/MHA/gelu — mla_moe: latent attention, routed
+experts, a leading dense stack). llama and gpt2 share the stacked-layer
+pytree + KV-cache layout, so the pipeline partitioner and cache plumbing
+are agnostic between them; mla_moe has two stacks and a latent cache and
+serves one device only. Routed experts are one module for the two
+families that have them (models/experts.py: `route`, `routed_ffn`, the
+grouped product): a configuration that routes serves one device, from the
+paged pool (engine/paged.refuse_unsupported_latent).
 """
 
 from __future__ import annotations
@@ -35,13 +41,16 @@ def embed(cfg, params, tokens, pos=0):
 def forward_layers(cfg, layers, x, cache, pos, update_gate=None, tp_axis=None,
                    attn_hook=None, valid_start=None, ep_axis=None,
                    attn_seq_len=None, lora_pages=None):
-    # Both families expose the same seams now: attn_hook (the shared
+    # All three families expose the same seams: attn_hook (the shared
     # attention/cache strategy hook — parallel/context.py, the paged
-    # pool), attn_seq_len (paged logical window). valid_start (ragged
-    # left-padding), ep_axis (MoE) and lora_pages (paged adapter delta)
-    # stay llama-only — gpt2's forward_layers rejects them loudly
+    # pool) and attn_seq_len (paged logical window). valid_start (ragged
+    # left-padding) is llama's and mla_moe's; ep_axis (the every-expert
+    # MoE FFN's mesh axis) and lora_pages (paged adapter delta) are
+    # llama-only — gpt2's forward_layers rejects all three loudly
     # (learned absolute positions are not shift-invariant; no MoE
-    # blocks; no lora leaves).
+    # blocks; no lora leaves), and the routed-expert paths of llama and
+    # mla_moe reject ep_axis (models/experts.routed_ffn's expert_lo is
+    # the share a mesh would hold; no ep axis sums the parts yet).
     if lora_pages is not None and cfg.arch != "llama":
         raise ValueError(
             f"lora_pages (runtime adapters) requires the llama family; "

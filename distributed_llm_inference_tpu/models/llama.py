@@ -51,14 +51,77 @@ from ..ops.norms import rms_norm
 from ..ops.quant import expert_einsum as eem
 from ..ops.quant import matmul as mm
 from ..ops.rope import apply_rope, rope_cos_sin
+from .experts import BANKS, _normal_slices, route, routed_ffn
 
 Params = dict
 KVCache = dict  # {"k": [L, B, KV, S, Dh], "v": [L, B, KV, S, Dh]}
+
+# _init_routed's key of each drawn leaf: an index into split(key, 16)
+# (cellbench/reference/block_diffusion_moe.py writes the same table down)
+ROUTED_LEAF_KEYS = {
+    "embed": 0, "lm_head": 1, "wq": 2, "wk": 3, "wv": 4, "wo": 5,
+    "w_router": 6, "w_gate": 7, "w_up": 8, "w_down": 9,
+}
+
+
+def _init_routed(cfg: ModelConfig, key: jax.Array) -> Params:
+    """init_params of a routed-expert configuration (cfg.moe_ffn_dim > 0):
+    every drawn leaf slice by slice (models/experts._normal_slices: slice i
+    of a stacked leaf [L, ...] is normal(split(key, L)[i]) * scale in
+    float32, rounded to the dtype; the two vocabulary tables 8 slices of
+    rows), because the float32 draw of a whole expert bank (5.6 GB at 7 x
+    128 x 2048 x 768) does not fit beside the leaves already drawn. Norm
+    weights 1; no biases; per-head qk-norm weights where the family has
+    them."""
+    dt = cfg.jnp_dtype
+    L, D, V = cfg.n_layers, cfg.dim, cfg.vocab_size
+    H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    E, F = cfg.n_experts, cfg.moe_ffn_dim
+    if not (cfg.pre_norms and not cfg.post_norms and not cfg.tie_embeddings
+            and not cfg.attn_qkv_bias and cfg.attn_window is None):
+        raise ValueError(
+            f"{cfg.name}: the routed llama layer is the plain pre-norm block"
+        )
+    ks = jax.random.split(key, 16)
+    s = D ** -0.5
+    shapes = {
+        "wq": ((L, D, H * Dh), s), "wk": ((L, D, KV * Dh), s),
+        "wv": ((L, D, KV * Dh), s), "wo": ((L, H * Dh, D), s),
+        "w_router": ((L, D, E), s), "w_gate": ((L, E, D, F), s),
+        "w_up": ((L, E, D, F), s), "w_down": ((L, E, F, D), F ** -0.5),
+    }
+    layers = {
+        name: _normal_slices(ks[ROUTED_LEAF_KEYS[name]], shape=shape,
+                             scale=float(scale), dtype=dt)
+        for name, (shape, scale) in shapes.items()
+    }
+    layers["attn_norm"] = jnp.ones((L, D), dt)
+    layers["mlp_norm"] = jnp.ones((L, D), dt)
+    if cfg.use_qk_norm:
+        if cfg.qk_norm_dim != "head":
+            raise ValueError(f"{cfg.name}: routed layers take per-head qk-norm")
+        layers["q_norm"] = jnp.ones((L, Dh), dt)
+        layers["k_norm"] = jnp.ones((L, Dh), dt)
+
+    def table(name, shape, scale):
+        cut = 8 if shape[0] % 8 == 0 else 1
+        return _normal_slices(
+            ks[ROUTED_LEAF_KEYS[name]], scale=float(scale), dtype=dt,
+            shape=(cut, shape[0] // cut) + shape[1:],
+        ).reshape(shape)
+
+    return {
+        "embed": table("embed", (V, D), 0.02), "layers": layers,
+        "final_norm": jnp.ones((D,), dt),
+        "lm_head": table("lm_head", (D, V), s),
+    }
 
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     """Random-init params (for tests/benchmarks; real weights come from
     models/convert.py). Scaled-normal init, dtype = cfg.dtype."""
+    if cfg.moe_ffn_dim:
+        return _init_routed(cfg, key)
     dt = cfg.jnp_dtype
     L, D, F, V = cfg.n_layers, cfg.dim, cfg.ffn_dim, cfg.vocab_size
     H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -235,7 +298,8 @@ def default_attn_hook(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
         )
         return attn, new_k, new_v
     new_k, new_v = update_kv_cache(cache_k, cache_v, k, v, pos, gate=update_gate)
-    if cfg.attn_impl == "pallas" and q.shape[1] > 1:
+    if cfg.attn_impl == "pallas" and q.shape[1] > 1 \
+            and not cfg.diffusion_block:  # (the flash kernel is causal)
         # Flash kernel for the COMPUTE-bound chunks only (prefill,
         # chunked ingest, speculative verify). A T=1 step has no flops
         # to hide a kernel launch under, so solo decode always takes the
@@ -313,10 +377,14 @@ def decoder_layer(
     ep_axis: Optional[str] = None,
     lora_pages: Optional[jnp.ndarray] = None,
     layer: Optional[jnp.ndarray] = None,
+    routed: Optional[tuple] = None,
 ):
     """One pre-norm decoder block on a chunk x [B,T,D] at offset `pos`.
 
     lp: this layer's params (no leading L axis). Returns (x, cache_k, cache_v).
+    routed: None, or (banks, layer index, live) of a routed-expert
+    configuration (cfg.moe_ffn_dim > 0: `routed_mlp`); the return then
+    carries a fourth value, the tokens each expert got [E].
     layer: None, and cache_k/v are this layer's slices of the cache; or,
     under a paged hook (forward_layers), the traced index of this layer in
     the STACKED pool leaves that cache_k/v then are, handed on to the hook.
@@ -415,7 +483,10 @@ def decoder_layer(
 
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps, unit_offset=uo) \
         if cfg.pre_norms else x
-    if cfg.n_experts:
+    sizes = None
+    if routed is not None:
+        mlp_out, sizes = routed_mlp(cfg, lp, h, *routed)
+    elif cfg.n_experts:
         mlp_out = moe_ffn(cfg, lp, h, ep_axis)  # psums over ep internally
     else:
         act = jax.nn.silu if cfg.act == "silu" else _gelu_tanh
@@ -428,7 +499,27 @@ def decoder_layer(
     if cfg.residual_multiplier is not None:  # Granite
         mlp_out = mlp_out * jnp.asarray(cfg.residual_multiplier, mlp_out.dtype)
     x = x + mlp_out
+    if routed is not None:
+        return x, new_k, new_v, sizes
     return x, new_k, new_v
+
+
+def routed_mlp(cfg: ModelConfig, lp: Params, h, banks: Params, layer, live):
+    """The routed-expert FFN of a llama-family layer (cfg.moe_ffn_dim > 0:
+    SDAR-30B-A3B, the published Qwen3-MoE layer) on normed h [B, T, D]:
+    float32 softmax over all experts, the n_experts_per_tok largest, their
+    weights renormalized under moe_renormalize, each token through its
+    experts alone (models/experts.routed_ffn over the STACKED banks, which
+    ride outside the layer scan). `moe_ffn` above is the same layer with
+    every expert on every token. Returns (out [B, T, D] in h's dtype,
+    tokens an expert got [E])."""
+    B, T, D = h.shape
+    flat = h.reshape(B * T, D)
+    with jax.named_scope("moe_route"):
+        chosen, weights = route(cfg, flat, lp["w_router"])
+    out, sizes = routed_ffn(cfg, banks, layer, flat, chosen, weights,
+                            live=live)
+    return out.reshape(B, T, D).astype(h.dtype), sizes
 
 
 def _gelu_tanh(x):
@@ -499,11 +590,12 @@ def forward_layers(
         cos, sin = (cos, cos_l), (sin, sin_l)
 
     def make_mask(window):
+        B_ = cfg.diffusion_block
         if pos.ndim == 1:
-            return slot_causal_mask(pos, T, S, window)
+            return slot_causal_mask(pos, T, S, window, B_)
         if valid_start is None:
-            return causal_mask(pos, T, S, window)
-        return ragged_causal_mask(pos, T, S, valid_start, window)
+            return causal_mask(pos, T, S, window, B_)
+        return ragged_causal_mask(pos, T, S, valid_start, window, B_)
 
     mixed_pattern = cfg.attn_window is not None and (
         cfg.attn_window_pattern == "even"
@@ -518,6 +610,11 @@ def forward_layers(
 
     paged = getattr(attn_hook, "paged", False)
 
+    if cfg.moe_ffn_dim:
+        return _forward_routed(cfg, layers, x, cache, pos, cos, sin, mask,
+                               update_gate, tp_axis, attn_hook, valid_start,
+                               ep_axis, lora_pages)
+
     def layer_step(xc, lp, kv, layer):
         xc, ck, cv = decoder_layer(
             cfg, lp, xc, *kv, pos, cos, sin, mask, update_gate, tp_axis,
@@ -530,6 +627,48 @@ def forward_layers(
         layer_step, x, layers, (cache["k"], cache["v"]), paged=paged
     )
     return x, {"k": new_k, "v": new_v}
+
+
+def _forward_routed(cfg, layers, x, cache, pos, cos, sin, mask, update_gate,
+                    tp_axis, attn_hook, valid_start, ep_axis, lora_pages):
+    """forward_layers' scan for a routed-expert configuration: the expert
+    banks are closed over, never sliced by the scan (models/experts.py), and
+    each layer hands back the tokens its experts got. With a "routed" leaf
+    [2, L, E] int32 in the cache (the paged pool's, engine/paged.init_pool)
+    the layers add what they routed to it: [0] tokens an expert got, [1]
+    steps in which it got any (models/mla_moe.forward_layers' contract)."""
+    if tp_axis is not None or ep_axis is not None or lora_pages is not None:
+        raise ValueError(
+            f"{cfg.name}: routed experts are not sharded over tp or ep and "
+            f"take no runtime adapter"
+        )
+    T = x.shape[1]
+    paged = getattr(attn_hook, "paged", False)
+    # rows whose output nothing reads reach no expert (engine/paged's hooks
+    # say which: launch padding, freed slots)
+    live = getattr(attn_hook, "live", None)
+    if live is not None and T > 1:
+        live = jnp.repeat(live, T)
+    banks = {name: layers[name] for name in BANKS}
+    small = {name: leaf for name, leaf in layers.items() if name not in BANKS}
+
+    def layer_step(xc, lp, kv, layer):
+        xc, ck, cv, sizes = decoder_layer(
+            cfg, lp, xc, *kv, pos, cos, sin, mask, update_gate, None,
+            attn_hook, valid_start, None, None, layer if paged else None,
+            routed=(banks, layer, live),
+        )
+        return xc, (ck, cv), sizes
+
+    x, (new_k, new_v), sizes = scan_layers(
+        layer_step, x, small, (cache["k"], cache["v"]), paged=paged
+    )
+    new = {**cache, "k": new_k, "v": new_v}
+    if "routed" in cache:
+        new["routed"] = cache["routed"] + jnp.stack(
+            [sizes, (sizes > 0).astype(jnp.int32)]
+        )
+    return x, new
 
 
 def scan_layers(layer_step, x, layers, cache, *, paged: bool):

@@ -28,15 +28,9 @@ the latent pool; `latent_attn_hook` below is the dense-cache one.
 Expert layers: scores s = sigmoid(h w_router) in float32; the
 n_experts_per_tok largest of s + router_bias are chosen (the bias chooses,
 it does not weigh); weights s_i / sum of the chosen s, times
-routed_scaling. `routed_ffn` is told which experts it holds (the banks'
-leading axis, from `expert_lo`), routes over all n_experts, and computes
-its own experts' part: token-expert pairs sorted by expert, one grouped
-matrix product per projection over the tokens each expert got (operations
-follow tokens x n_experts_per_tok, not x n_experts), combined by weight.
-The grouped product's operand is the STACKED bank [L, E, ...] seen as L x E
-groups of which only this layer's are non-empty: a scan that sliced the
-bank per layer would copy it (1.2 GB here) every step, so the banks stay
-outside the scan's xs. The shared expert is one SwiGLU of width
+routed_scaling. `route` / `routed_ffn` and the grouped product are
+models/experts.py's (shared with the llama family's routed layer); the
+banks stay outside the scan's xs. The shared expert is one SwiGLU of width
 n_shared_experts * moe_ffn_dim on every token.
 
 The residual stream, every sublayer's output and the router's scores are
@@ -56,23 +50,23 @@ Fs = n_shared_experts * F, V vocab):
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as _gmm
 
 from ..config import ModelConfig
 from ..ops.attention import causal_mask, ragged_causal_mask, slot_causal_mask
-from ..ops.flash_attention import resolve_interpret
 from ..ops.norms import rms_norm
 from ..ops.rope import rope_interleaved
+from .experts import (  # noqa: F401 - the family's routed half, re-exported
+    BANKS, _normal_slices, grouped_matmul, route, routed_expert_matmul,
+    routed_ffn,
+)
 
 Params = dict
 F32 = jnp.float32
 
-BANKS = ("w_gate", "w_up", "w_down")  # the routed experts' stacked banks
 # the scale init_params draws the selection bias at (a trained checkpoint
 # brings its own): sigmoid scores of unit-variance logits lie a few
 # hundredths apart near the k-th place, so this changes some choices
@@ -128,19 +122,6 @@ def leaf_shapes(cfg: ModelConfig) -> dict:
         "moe.ws_down": ((Lm, Fs, D), Fs ** -0.5),
     })
     return out
-
-
-@functools.partial(jax.jit, static_argnames=("shape", "scale", "dtype"))
-def _normal_slices(key, *, shape, scale, dtype):
-    """A leaf [n, ...] drawn slice by slice: slice i is
-    normal(split(key, n)[i], shape[1:]) * scale, float32 rounded to
-    `dtype`; the loop writes each slice into the output, so the float32
-    draw of the whole leaf (2.4 GB for an expert bank) never exists."""
-    keys = jax.random.split(key, shape[0])
-    return jax.lax.map(
-        lambda k: (jax.random.normal(k, shape[1:], F32) * scale).astype(dtype),
-        keys,
-    )
 
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
@@ -274,104 +255,6 @@ def swiglu(h, w_gate, w_up, w_down):
     up = jnp.dot(h, w_up, preferred_element_type=F32)
     return jnp.dot((gate * up).astype(h.dtype), w_down,
                    preferred_element_type=F32)
-
-
-def route(cfg: ModelConfig, h, w_router, router_bias):
-    """(chosen experts [N, k] int32, their weights [N, k] float32) for the
-    normed tokens h [N, D]. The scores are float32 whatever the model's
-    dtype: a near-tie decides which expert computes."""
-    logits = jnp.dot(h.astype(F32), w_router.astype(F32),
-                     precision=jax.lax.Precision.HIGHEST)
-    s = jax.nn.sigmoid(logits)
-    _, chosen = jax.lax.top_k(s + router_bias.astype(F32),
-                              cfg.n_experts_per_tok)
-    w = jnp.take_along_axis(s, chosen, axis=-1)
-    if cfg.moe_renormalize:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
-    return chosen.astype(jnp.int32), w * cfg.routed_scaling
-
-
-def _group_tiling(m: int, k: int, n: int, itemsize: int) -> tuple:
-    """(tm, tk, tn) of the grouped product: a whole expert matrix a tile
-    where it stays near 3 MiB (two buffers of it inside the scoped VMEM),
-    so a touched expert costs one grid step a 128-pair tile."""
-    tm = min(m, 128)
-    tk, tn = k, n
-    while tk * tn * itemsize > 13 * 2**18 and tk % 256 == 0:
-        tk //= 2
-    return tm, tk, tn
-
-
-@functools.partial(jax.jit, static_argnames=("tiling", "interpret"))
-def routed_expert_matmul(x, groups_rhs, groups, *, tiling, interpret):
-    """JAX's grouped matrix product kernel (megablox gmm) under a name of
-    this program's, which the device trace then carries: the benchmark
-    tells the expert kernels by it (tests/test_chip_compile.py)."""
-    if x.dtype == F32:
-        return _gmm.__wrapped__(x, groups_rhs, groups, F32, tiling,
-                                interpret=interpret)
-    # bfloat16 products are exact in float32 whatever the ambient matmul
-    # precision asks, and Mosaic refuses "highest" on bfloat16 operands
-    with jax.default_matmul_precision("default"):
-        return _gmm.__wrapped__(x, groups_rhs, groups, F32, tiling,
-                                interpret=interpret)
-
-
-def grouped_matmul(x, bank, sizes, layer):
-    """x [M, K] sorted by expert against this layer's experts of the
-    STACKED bank [L, E, K, N]; sizes [E] int32 rows an expert. Rows past
-    sum(sizes) are not computed (callers mask them). Float32 out."""
-    L, E, K, N = bank.shape
-    groups = jax.lax.dynamic_update_slice(
-        jnp.zeros((L * E,), jnp.int32), sizes, (layer * E,)
-    )
-    return routed_expert_matmul(
-        x, bank.reshape(L * E, K, N), groups,
-        tiling=_group_tiling(x.shape[0], K, N, bank.dtype.itemsize),
-        interpret=resolve_interpret(None),
-    )
-
-
-def routed_ffn(cfg: ModelConfig, banks: Params, layer, h, chosen, weights,
-               live=None, expert_lo=0):
-    """The held experts' part of the routed layer for normed tokens h
-    [N, D]: (float32 [N, D], tokens each held expert got [E_held] int32).
-
-    banks: w_gate / w_up [L, E_held, D, F], w_down [L, E_held, F, D], the
-    stacked banks of the experts held here, published experts expert_lo ..
-    expert_lo + E_held - 1; `layer` (traced) picks the stack's layer.
-    chosen / weights: `route`'s, over all published experts; a pair whose
-    expert is held elsewhere, or whose token is not live ([N] bool: launch
-    padding, a freed slot), reaches no expert and adds nothing."""
-    N, D = h.shape
-    k = cfg.n_experts_per_tok
-    E = banks["w_gate"].shape[1]
-    with jax.named_scope("moe_dispatch"):
-        local = chosen - expert_lo
-        held = (local >= 0) & (local < E)
-        if live is not None:
-            held &= live[:, None]
-        M0 = N * k
-        unit = 128 if M0 >= 128 else 16  # whole tiles of the grouped product
-        M = -(-M0 // unit) * unit
-        ids = jnp.full((M,), E, jnp.int32).at[:M0].set(
-            jnp.where(held, local, E).reshape(M0)
-        )  # E: sorts last, reaches no expert
-        order = jnp.argsort(ids, stable=True)
-        sizes = jnp.zeros((E + 1,), jnp.int32).at[ids].add(1)[:E]
-        xs = h[jnp.minimum(order // k, N - 1)]  # [M, D], sorted by expert
-    with jax.named_scope("moe_experts"):
-        gate = grouped_matmul(xs, banks["w_gate"], sizes, layer)
-        up = grouped_matmul(xs, banks["w_up"], sizes, layer)
-        act = (jax.nn.silu(gate) * up).astype(h.dtype)
-        ys = grouped_matmul(act, banks["w_down"], sizes, layer)
-    with jax.named_scope("moe_combine"):
-        computed = jnp.arange(M) < jnp.sum(sizes)
-        ys = jnp.where(computed[:, None], ys, 0.0)
-        pairs = ys[jnp.argsort(order)[:M0]].reshape(N, k, D)
-        w = jnp.where(held, weights, 0.0)
-        out = jnp.einsum("nkd,nk->nd", pairs, w)
-    return out, sizes
 
 
 def moe_ffn(cfg: ModelConfig, lp: Params, banks: Params, layer, h, live=None):

@@ -154,6 +154,23 @@ register(ModelConfig(
     moe_renormalize=True,
     eos_token_id=151645, bos_token_id=151643, pad_token_id=151643,
 ))
+# --- SDAR (block diffusion over the Qwen3-MoE layer; JetLM/SDAR-30B-A3B-Chat
+# config.json, model_type sdar_moe). qwen3-30b-a3b's widths, but: the experts
+# are ROUTED (moe_ffn_dim > 0: models/experts.py, each token through its 8
+# experts alone), and generation is by diffusion over blocks of 4 (engine/
+# paged.diffusion_step). ffn_dim is the published intermediate_size, which
+# no layer uses (mlp_only_layers is empty). Not in config.json and so
+# assumed: the block length 4 and the mask token id 151669 (the SDAR
+# repository's), per-head qk-norm (the family's modeling code), Qwen's
+# special tokens.
+register(ModelConfig(
+    name="sdar-30b-a3b-chat", arch="llama", vocab_size=151936, dim=2048,
+    n_layers=48, n_heads=32, n_kv_heads=4, ffn_dim=6144, max_seq_len=32768,
+    norm_eps=1e-6, rope_theta=1000000.0, head_dim_override=128,
+    use_qk_norm=True, n_experts=128, n_experts_per_tok=8, moe_ffn_dim=768,
+    moe_renormalize=True, diffusion_block=4, mask_token_id=151669,
+    eos_token_id=151645, bos_token_id=151643, pad_token_id=151643,
+))
 register(ModelConfig(
     name="qwen3-8b", arch="llama", vocab_size=151936, dim=4096,
     n_layers=36, n_heads=32, n_kv_heads=8, ffn_dim=12288, max_seq_len=40960,
@@ -298,6 +315,14 @@ register(ModelConfig(
     kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
     n_experts=16, n_experts_per_tok=3, moe_ffn_dim=32, n_shared_experts=2,
     first_k_dense=1, routed_scaling=2.448,
+    eos_token_id=2, bos_token_id=1,
+))
+register(ModelConfig(
+    name="test-sdar-tiny", arch="llama", vocab_size=256, dim=64,
+    n_layers=3, n_heads=4, n_kv_heads=2, ffn_dim=96, max_seq_len=128,
+    norm_eps=1e-6, rope_theta=1000000.0, head_dim_override=16,
+    use_qk_norm=True, n_experts=16, n_experts_per_tok=3, moe_ffn_dim=32,
+    moe_renormalize=True, diffusion_block=4, mask_token_id=255,
     eos_token_id=2, bos_token_id=1,
 ))
 register(ModelConfig(

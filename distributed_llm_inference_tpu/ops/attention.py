@@ -64,23 +64,36 @@ def update_kv_cache(
     return cache_k, cache_v
 
 
+def block_frontier(q_pos, block: int = 0):
+    """The last position a query at q_pos attends. block 0: itself
+    (causal). block B > 0 (block diffusion, cfg.diffusion_block): the last
+    position of its own block of B at absolute positions, so position t
+    sees s iff s < (t // B + 1) * B. One rule for every mask below, the
+    paged kernels (ops/paged_attention._walk_kernel) and engine/paged's
+    XLA twins."""
+    return q_pos if not block else (q_pos // block + 1) * block - 1
+
+
 def causal_mask(
-    pos: jnp.ndarray, chunk_len: int, max_seq: int, window=None
+    pos: jnp.ndarray, chunk_len: int, max_seq: int, window=None,
+    block: int = 0,
 ) -> jnp.ndarray:
     """[T, S] boolean mask: query at absolute position pos+t may attend to
-    cache slots 0..pos+t inclusive (earlier prompt + itself). With
-    `window` (sliding-window attention, Mistral-style) only the last
-    `window` positions qualify: q_pos - window < kv_pos <= q_pos."""
+    cache slots 0..pos+t inclusive (earlier prompt + itself), or up to its
+    block's end (`block_frontier`). With `window` (sliding-window
+    attention, Mistral-style) only the last `window` positions qualify:
+    q_pos - window < kv_pos <= q_pos."""
     q_pos = pos + jnp.arange(chunk_len, dtype=jnp.int32)  # [T]
     kv_pos = jnp.arange(max_seq, dtype=jnp.int32)  # [S]
-    mask = kv_pos[None, :] <= q_pos[:, None]
+    mask = kv_pos[None, :] <= block_frontier(q_pos, block)[:, None]
     if window is not None:
         mask &= kv_pos[None, :] > q_pos[:, None] - window
     return mask
 
 
 def slot_causal_mask(
-    pos: jnp.ndarray, chunk_len: int, max_seq: int, window=None
+    pos: jnp.ndarray, chunk_len: int, max_seq: int, window=None,
+    block: int = 0,
 ) -> jnp.ndarray:
     """[B, T, S] mask for PER-ROW query offsets (continuous batching).
 
@@ -94,7 +107,7 @@ def slot_causal_mask(
     """
     q_pos = pos[:, None] + jnp.arange(chunk_len, dtype=jnp.int32)[None, :]  # [B, T]
     kv_pos = jnp.arange(max_seq, dtype=jnp.int32)  # [S]
-    mask = kv_pos[None, None, :] <= q_pos[:, :, None]
+    mask = kv_pos[None, None, :] <= block_frontier(q_pos, block)[:, :, None]
     if window is not None:
         mask &= kv_pos[None, None, :] > q_pos[:, :, None] - window
     return mask
@@ -133,13 +146,13 @@ def update_kv_cache_slots(
 
 def ragged_causal_mask(
     pos: jnp.ndarray, chunk_len: int, max_seq: int, valid_start: jnp.ndarray,
-    window=None,
+    window=None, block: int = 0,
 ) -> jnp.ndarray:
     """[B, T, S] mask for LEFT-padded batches: causal AND slot >= the row's
     first real slot. Left-padding aligns ragged prompts to one shared
     position frame (RoPE is relative, so a per-row uniform shift is
     harmless); the pad slots in front must simply never be attended."""
-    causal = causal_mask(pos, chunk_len, max_seq, window)  # [T, S]
+    causal = causal_mask(pos, chunk_len, max_seq, window, block)  # [T, S]
     kv_pos = jnp.arange(max_seq, dtype=jnp.int32)
     valid = kv_pos[None, None, :] >= valid_start[:, None, None]  # [B, 1, S]
     return causal[None, :, :] & valid

@@ -153,6 +153,7 @@ def _walk_kernel(
     quant: bool,
     latent: int = 0,
     write: bool = False,
+    block: int = 0,
 ):
     """One program: query tile g (tq queries of one row; a decode slot is
     a tile of one) against head group hg's KVg KV heads. The walk over
@@ -176,7 +177,14 @@ def _walk_kernel(
     latent > 0 is the latent (MLA, absorbed) form: the pool holds one row
     [c | k_r | pad] a token and there is no V pool; scores run over the
     whole row, values are its first `latent` numbers, so one DMA serves
-    both, and the output is `latent` wide."""
+    both, and the output is `latent` wide.
+
+    block > 0 is the block-diffusion mask (cfg.diffusion_block): a query
+    attends every position up to the END of its own block of `block`
+    tokens (ops/attention.block_frontier). The walk's bounds stay the
+    causal ones: the engine cuts every tile at a multiple of `block`, so a
+    tile's last block ends with the tile, inside the rows `patch` puts
+    into the VMEM copy before the fold."""
     n = 1 if latent else (4 if quant else 2)  # pool leaves
     new_refs, rest = (rest[:n], rest[n:]) if write else ((), rest)
     srcs, o_ref, rest = rest[:n], rest[n], rest[n + 1:]
@@ -269,6 +277,10 @@ def _walk_kernel(
     t_local = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0) // group
     col = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
     q_pos = q_start + t_local
+    if block:  # the last position of the query's own block
+        q_end = (q_pos // block + 1) * block - 1
+    else:
+        q_end = q_pos
     heads = ((0,), (0,))  # dot_general batch dims: the slab's KV heads
 
     def fold_block(j, carry):
@@ -290,7 +302,7 @@ def _walk_kernel(
                     c.start()
 
         kv_pos = j * bs + col
-        mask = (t_local < q_len) & (kv_pos <= q_pos)
+        mask = (t_local < q_len) & (kv_pos <= q_end)
         mask &= (win <= 0) | (kv_pos > q_pos - win)
         ks = kbuf[slot].astype(jnp.float32)  # [KVg, bs, Dh]
         vs = ks[:, :, :latent] if latent else vbuf[slot].astype(jnp.float32)
@@ -365,7 +377,8 @@ def writes_in_place(leaf) -> bool:
 
 
 def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
-                scale, softcap, interpret, value_dim=None, write=None):
+                scale, softcap, interpret, value_dim=None, write=None,
+                block=0):
     """The pallas_call both wrappers share. q [G, tq, H, Dh]: G query
     tiles of tq queries; meta [G, 4]. pool_v None is the latent form.
     write None: the pool leaves are one layer's slices and hold the
@@ -410,7 +423,7 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
         _walk_kernel, bs=bs, MB=MB, tq=tq, KVg=KVg, group=group,
         scale=scale if scale is not None else Dh**-0.5, softcap=softcap,
         quant=quant, latent=value_dim if latent else 0,
-        write=write is not None,
+        write=write is not None, block=block,
     )
 
     def tile(per_head, width):
@@ -465,7 +478,8 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("interpret", "window", "scale", "softcap", "value_dim"),
+    static_argnames=("interpret", "window", "scale", "softcap", "value_dim",
+                     "block"),
 )
 def paged_flash_attend(
     q: jnp.ndarray,
@@ -482,6 +496,7 @@ def paged_flash_attend(
     softcap: float | None = None,
     interpret: bool | None = None,
     value_dim: int | None = None,
+    block: int = 0,
 ) -> jnp.ndarray:
     """Paged GQA decode attention over the block pool.
 
@@ -521,13 +536,14 @@ def paged_flash_attend(
     return _paged_walk(
         q, pool_k, pool_v, table, meta, window, window_dyn, scale=scale,
         softcap=softcap, interpret=resolve_interpret(interpret),
-        value_dim=value_dim, write=write,
+        value_dim=value_dim, write=write, block=block,
     )
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("interpret", "window", "scale", "softcap", "value_dim"),
+    static_argnames=("interpret", "window", "scale", "softcap", "value_dim",
+                     "block"),
 )
 def ragged_paged_attend(
     q: jnp.ndarray,
@@ -543,6 +559,7 @@ def ragged_paged_attend(
     softcap: float | None = None,
     interpret: bool | None = None,
     value_dim: int | None = None,
+    block: int = 0,
 ) -> jnp.ndarray:
     """Mixed prefill + decode GQA attention over the block pool — one
     launch for rows of ARBITRARY per-row length.
@@ -565,6 +582,8 @@ def ragged_paged_attend(
     RAGGED_PREFILL / RAGGED_DECODE (launch accounting; the math is
     uniform — a decode row is simply q_len == 1 at its own position).
     window / window_dyn / scale / softcap: as `paged_flash_attend`.
+    block: the block-diffusion mask (`_walk_kernel`); every tile then
+    starts at a multiple of it.
     Returns [W, H, Dh] in q.dtype: each query token's attention output
     over its row's KV prefix (positions 0..q_pos through the block
     table), which is exactly the bucketed scratch prefill's per-token
@@ -581,7 +600,7 @@ def ragged_paged_attend(
         q.reshape(G, tq, H, Dh), pool_k, pool_v, table, meta, window,
         window_dyn, scale=scale, softcap=softcap,
         interpret=resolve_interpret(interpret), value_dim=value_dim,
-        write=write,
+        write=write, block=block,
     )
     if write is None:
         return out.reshape(W, H, -1)

@@ -107,6 +107,14 @@ def min_p_filter(logits: jnp.ndarray, min_p: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(min_p <= 0.0, logits, filtered)
 
 
+def suppress_token(logits: jnp.ndarray, token_id: int) -> jnp.ndarray:
+    """logits with one token's entry at -inf, so that no choice, greedy or
+    sampled, lands on it: a block-diffusion model's mask token is an input
+    symbol, never an output (engine/paged.diffusion_step; the plain
+    reference does the same before its argmax)."""
+    return logits.at[..., token_id].set(NEG_INF)
+
+
 def sample_token(
     key: jax.Array,
     logits: jnp.ndarray,

@@ -954,6 +954,12 @@ def make_handler(engine, max_tokens_cap: int, profiler: Optional[_Profiler] = No
                             f"{sorted(slo_classes)}"
                         )
                     kwargs["slo_class"] = raw_slo
+                if data.get("denoise_steps") is not None:
+                    # block-diffusion models: forwards that reveal a block
+                    # (it must divide the model's block length; the
+                    # engine says so where it does not); the server's
+                    # default is --denoise-steps
+                    kwargs["denoise_steps"] = int(data["denoise_steps"])
                 raw_tenant = data.get("tenant")
                 if raw_tenant is not None:
                     # multi-tenant identity (engine/scheduler.py):
@@ -1619,6 +1625,14 @@ def main(argv: Optional[list] = None):
              "fleet speculation machinery entirely)",
     )
     ap.add_argument(
+        "--denoise-steps", type=int, default=0, metavar="N",
+        help="block-diffusion models (sdar-30b-a3b-chat): the default "
+             "number of denoising forwards that reveal a block, for "
+             "requests without a \"denoise_steps\" field; it must divide "
+             "the model's block length (0 = the block length: one "
+             "position a forward)",
+    )
+    ap.add_argument(
         "--spec-draft-model", default=None, metavar="NAME",
         help="draft the fleet's verify rows with a small same-tokenizer "
              "model's device-side greedy chain (shares the block tables "
@@ -1823,6 +1837,7 @@ def main(argv: Optional[list] = None):
             replica_class=args.replica_class,
             spec_decode=args.spec_decode,
             spec_draft_len=args.spec_draft_len,
+            denoise_steps=args.denoise_steps,
             spec_draft_model=args.spec_draft_model,
             pp_wire_quant=args.pp_wire_quant,
             adapter_slots=args.adapter_slots,

@@ -72,6 +72,18 @@ SLOT_TURNOVER_HELP = (
     "tenant, for rows that ended while requests were queued"
 )
 
+# block-diffusion fleets (ModelConfig.diffusion_block > 0)
+DIFFUSION_FORWARDS_HELP = (
+    "row-forwards of a block-diffusion fleet by the host position model, "
+    "by kind: denoise = the forward reveals masked positions of the row's "
+    "open block, commit = the block is clean and the forward writes its "
+    "K/V and emits it"
+)
+DIFFUSION_TOKENS_HELP = (
+    "tokens a block-diffusion fleet committed and delivered (counted at "
+    "the fetch of the forward that committed their block)"
+)
+
 MAX_SERIES = 64  # label-set cap per family
 WINDOW = 256  # raw-observation window per histogram child (matches
 # the engine's rolling sample deque, so JSON percentiles line up)

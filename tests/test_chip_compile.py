@@ -547,6 +547,96 @@ def test_latent_step_programs_carry_the_names_a_trace_is_read_by(
             assert scope in stacks, (module, scope)
 
 
+@pytest.mark.parametrize("tq", [4, 8], ids=["decode-chunk", "mixed-step"])
+def test_ragged_kernel_compiles_with_the_block_mask(
+    one_chip, no_persistent_cache, monkeypatch, tq
+):
+    """sdar-batch's attention: 4 KV heads of 128, 128-token blocks, the
+    block-diffusion mask static in the kernel; a decode chunk's tiles are one
+    open block (4 queries), a mixed step's the scheduler's 8."""
+    from distributed_llm_inference_tpu.ops.paged_attention import ragged_paged_attend
+
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    S = _spec(one_chip)
+    slots, width = 32, 32 * tq
+    pool = S((7, 512, 4, 128, 128), jnp.bfloat16)
+    new = S((width, 4, 128), jnp.bfloat16)
+    text = _compile(
+        lambda q, pk, pv, t, m, layer, k, v: ragged_paged_attend(
+            q, pk, pv, t, m, None, (layer, k, v), block=4),
+        S((width, 32, 128), jnp.bfloat16), pool, pool,
+        S((slots, 16), jnp.int32), S((slots, 4), jnp.int32), S((), jnp.int32),
+        new, new)
+    assert any("ragged_paged_attend" in c for c in _custom_call_names(text))
+
+
+def test_block_diffusion_step_programs_carry_the_names_a_trace_is_read_by(
+    one_chip, no_persistent_cache, monkeypatch
+):
+    """sdar-30b-a3b-chat (cut to 2 layers) at sdar-batch's sizes: both step
+    programs compile for the chip, keep their module names, read the pool
+    through the ragged kernel (a decode row is its open block) and run the
+    routed experts' grouped kernel under the scopes kanana's do."""
+    import json
+    import os
+    import re
+
+    import numpy as np
+
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    cfg = resolve_attn_impl(
+        get_model_config("sdar-30b-a3b-chat").replace(n_layers=2, dtype="bfloat16"),
+        "pallas",
+    )
+    S = _spec(one_chip)
+    place = functools.partial(_placed, sharding=one_chip)
+    params = place(jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0))))
+    slots, blocks, context, tile = 32, 512, 2048, 8
+    state, sparams = place(jax.eval_shape(lambda: G.init_slots(slots, cfg.vocab_size)))
+    pool = place(jax.eval_shape(lambda: EP.init_pool(cfg, blocks, 128)))
+    assert pool["routed"].shape == (2, 2, 128)
+    diff = place(jax.eval_shape(lambda: EP.init_diffusion(cfg, slots)))
+    table = S((slots, context // 128), jnp.int32)
+    key = place(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    chunk = EP.decode_slots_paged.lower(
+        cfg, params, state, pool, table, key, sparams, num_steps=2, diff=diff,
+    ).compile().as_text()
+    width = (slots + 1) * tile
+    entries = [(b, 0, 4, EP.RAGGED_PREFILL) for b in range(slots)]
+    meta, tok_row, tok_pos, offsets, _ = EP.build_ragged_meta(
+        entries, width=width, tile=tile)
+    dev = EP.DeviceMeta(*(
+        S(a.shape, a.dtype) for a in EP.build_device_meta(
+            entries, offsets, slots, width=width, tile=tile)))
+    arm = place(jax.eval_shape(lambda: EP.idle_mixed_arm(slots, cfg.vocab_size)))
+    darm = EP.DiffState(S((slots, 4), jnp.int32), S((slots,), jnp.int32),
+                      S((slots,), jnp.int32))
+    flat = lambda a: S(np.shape(a), np.asarray(a).dtype)  # noqa: E731
+    mixed = EP.mixed_step_ragged.lower(
+        cfg, params, S((width,), jnp.int32), flat(tok_row), flat(tok_pos),
+        S((width,), jnp.bool_), flat(meta), pool, table, state, sparams, key,
+        S((slots,), jnp.int32), arm, dev=dev, diff=diff, darm=darm,
+    ).compile().as_text()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "cellbench", "configs",
+                           "sdar-30b-a3b-7l.json")) as f:
+        trace = json.load(f)["serving"]["trace"]
+    assert set(trace["expert_kernels"]) == EXPERT_KERNELS
+    assert set(trace["step_modules"]) == {"decode_slots_paged", "mixed_step_ragged"}
+    for module, text in (("decode_slots_paged", chunk), ("mixed_step_ragged", mixed)):
+        assert module in _module_name(text)
+        calls = _custom_call_names(text)
+        for name in ("ragged_paged_attend", *trace["expert_kernels"]):
+            assert any(name in c for c in calls), (module, name, sorted(calls))
+        assert "ragged_paged_attend" in trace["attention_kernels"]
+        stacks = " ".join(set(re.findall(r'op_name="([^"]*)"', text)))
+        for scope in ("moe_route", "moe_dispatch", "moe_experts", "moe_combine"):
+            assert scope in stacks, (module, scope)
+        # the expert banks ride outside the layer scan: no operation of a
+        # bank's size (2 x 128 experts of 2048 x 768) beside the kernel
+        assert not re.search(r"copy\(.*bf16\[256,(2048,768|768,2048)\]", text), module
+
+
 def test_two_compiles_compare_equal_once_source_positions_are_out(
     one_chip, no_persistent_cache, monkeypatch
 ):
