@@ -119,7 +119,8 @@ from ..utils import faults
 from ..utils.logging import get_logger
 from ..utils.metrics import (
     ADMISSION_WAIT_HELP, DEFAULT_SIZE_BUCKETS, DIFFUSION_FORWARDS_HELP,
-    DIFFUSION_TOKENS_HELP, SLOT_RELEASE_HELP, SLOT_TURNOVER_HELP,
+    DIFFUSION_FUSED_HELP, DIFFUSION_TOKENS_HELP, SLOT_RELEASE_HELP,
+    SLOT_TURNOVER_HELP,
     STEPS_AHEAD_BUCKETS,
 )
 from ..utils.retry import overload_retry_after
@@ -515,17 +516,19 @@ class ContinuousEngine:
         self._spec_auto = bool(getattr(ecfg, "spec_decode", False))
         self._spec_capable = bool(self._chunked and self._spec_k_max > 0)
         # Generation by diffusion over blocks (cfg.diffusion_block > 0,
-        # engine/paged.DiffState): a decode row is its whole open block,
-        # a step is one forward, and a forward either reveals some of the
-        # block's masked positions or, when none is left, commits the
-        # block and emits it. The count a forward reveals is fixed per
-        # row at admission, so the host's position model is exact in
-        # FORWARDS: for such a fleet `_host_pos[b]` counts the forwards
-        # dispatched for the row and `_host_end[b]` the forwards its
-        # budget takes (`_blk_*`: the row's committed prompt length, the
-        # forwards of its first block, which a prompt's remainder can
-        # shorten, and of every later one), and `_blk_at` turns a forward's
-        # index into the row's length and the forward's kind.
+        # engine/paged.DiffState): a decode row is its open block, a step
+        # is one forward, and every forward reveals some of the block's
+        # masked positions; the one that reveals the last emits the
+        # block, and the next block's first forward carries the clean
+        # block in front of the open one and so commits its K/V. The
+        # count a forward reveals is fixed per row at admission, so the
+        # host's position model is exact in FORWARDS: for such a fleet
+        # `_host_pos[b]` counts the forwards dispatched for the row and
+        # `_host_end[b]` the forwards its budget takes (`_blk_*`: the
+        # row's committed prompt length, the forwards of its first
+        # block, which a prompt's remainder can shorten, and of every
+        # later one), and `_blk_at` turns a forward's index into the
+        # row's length and what the forward carries.
         self._blk = int(cfg.diffusion_block)
         self._diff = None
         if self._blk:
@@ -535,16 +538,20 @@ class ContinuousEngine:
                     f"chunked ragged paged scheduler (pass kv_pool_blocks; "
                     f"keep ragged_prefill and chunked_prefill on)"
                 )
-            if self.kv_block_size % self._blk or self._ragged_tile % self._blk:
+            if (self.kv_block_size % self._blk
+                    or self._ragged_tile % (2 * self._blk)):
+                # (a row's forward is one query tile: the owed block and
+                # the open one)
                 raise ValueError(
                     f"{cfg.name}: kv_block_size ({self.kv_block_size}) and "
-                    f"the query tile ({self._ragged_tile}) must be whole "
-                    f"multiples of the diffusion block ({self._blk})"
+                    f"half the query tile ({self._ragged_tile}) must be "
+                    f"whole multiples of the diffusion block ({self._blk})"
                 )
             # a forward already carries a whole block a row: no drafting
             self._spec_k_max, self._spec_auto = 0, False
             self._spec_capable = False
             self._diff = self._P.init_diffusion(cfg, self.n_slots)
+            self._idle_darm = self._P.init_diffusion(cfg, self.n_slots)
             self._denoise_default = int(
                 getattr(ecfg, "denoise_steps", 0) or self._blk
             )
@@ -1020,6 +1027,12 @@ class ContinuousEngine:
             "dli_diffusion_row_forwards_total", DIFFUSION_FORWARDS_HELP,
             ("kind",),
         )
+        if self._blk:
+            # no forward only commits: the kind stays, and is scraped, at 0
+            self._m_diff_forwards.labels(kind="commit")
+        self._m_diff_fused = m.counter(
+            "dli_diffusion_fused_commits_total", DIFFUSION_FUSED_HELP,
+        ).labels()
         self._m_diff_tokens = m.counter(
             "dli_diffusion_tokens_total", DIFFUSION_TOKENS_HELP,
         ).labels()
@@ -2851,12 +2864,13 @@ class ContinuousEngine:
 
     def _blk_plan(self, slot: int, head: int, reveal: int, max_tokens: int):
         """Arm the position model of a row whose open block starts with
-        `head` prompt tokens: forwards a block = its denoise forwards + the
-        commit; the budget takes ceil((head + max_tokens) / block) blocks."""
+        `head` prompt tokens: forwards a block = its denoise forwards (a
+        block's commit rides the next block's first); the budget takes
+        ceil((head + max_tokens) / block) blocks."""
         B = self._blk
         self._blk_skip[slot] = head
-        self._blk_first[slot] = -(-(B - head) // reveal) + 1
-        self._blk_later[slot] = B // reveal + 1
+        self._blk_first[slot] = -(-(B - head) // reveal)
+        self._blk_later[slot] = B // reveal
         blocks = -(-(head + max_tokens) // B)
         self._host_pos[slot] = 0
         self._host_end[slot] = (
@@ -2864,38 +2878,47 @@ class ContinuousEngine:
         )
 
     def _blk_at(self, fwd):
-        """(row length committed before forward `fwd`, whether `fwd` is a
-        commit, masked positions it reveals) for forward indices fwd
-        [slots, ...] (numpy-broadcasting over trailing axes)."""
+        """(the row's clean length before forward `fwd` = the open block's
+        first position, whether `fwd` carries the block below it as an
+        owed one, masked positions it reveals) for forward indices fwd
+        [slots, ...] (numpy-broadcasting over trailing axes). At
+        `_host_end` the length is that of the row's last block's end."""
         ex = (slice(None),) + (None,) * (np.ndim(fwd) - 1)
         first, later = self._blk_first[ex], self._blk_later[ex]
         in_first = fwd < first
         rest = np.maximum(fwd - first, 0)
         block = np.where(in_first, 0, 1 + rest // later)
         within = np.where(in_first, fwd, rest % later)
-        denoise = np.where(in_first, first, later) - 1
         masks = np.where(in_first, self._blk - self._blk_skip[ex], self._blk)
-        reveal = self._blk // np.maximum(later - 1, 1)
+        reveal = self._blk // later
         shown = np.clip(masks - within * reveal, 0, reveal)
-        commit = within >= denoise
-        return (self._blk_base[ex] + block * self._blk, commit,
-                np.where(commit, 0, shown))
+        return (self._blk_base[ex] + block * self._blk,
+                ~in_first & (within == 0), shown)
 
     def _blk_fields(self, fwd, alive, forwards: int) -> dict:
         """The launch record's diffusion fields for a launch of `forwards`
         forwards: row-forwards `fwd` of which `alive` are live on the
-        device, counted with the record."""
-        _, commit, shown = self._blk_at(fwd)
+        device, counted with the record. Every row-forward reveals
+        (`denoise`); none only writes a clean block (`commit`), and
+        `fused_rows` of them carried an owed block."""
+        _, owed, shown = self._blk_at(fwd)
         fields = {
             "forwards": forwards,
-            "denoise_rows": int(np.sum(alive & ~commit)),
-            "commit_rows": int(np.sum(alive & commit)),
+            "denoise_rows": int(np.sum(alive)),
+            "commit_rows": 0,
+            "fused_rows": int(np.sum(alive & owed)),
             "revealed_tokens": int(np.sum(shown * alive)),
         }
         self._m_diff_forwards.labels(kind="denoise").inc(
             fields["denoise_rows"])
-        self._m_diff_forwards.labels(kind="commit").inc(fields["commit_rows"])
+        self._m_diff_fused.inc(fields["fused_rows"])
         return fields
+
+    def _blk_reads(self, fwd):
+        """(first query position, query tokens) of forwards `fwd`: the open
+        block, from the owed block's start where one rides."""
+        at, owed, _ = self._blk_at(fwd)
+        return at - owed * self._blk, (1 + owed) * self._blk
 
     # -- the launch record (ISSUE 24) -----------------------------------------
     def _launch_record(self, phase: str, steps: int, kv_tokens: int,
@@ -3052,7 +3075,7 @@ class ContinuousEngine:
             # `at` counts forwards: each reads the row's whole cache and
             # its open block
             diff_fields = self._blk_fields(at, alive, K)
-            at, span = self._blk_at(at)[0], self._blk
+            at, span = self._blk_reads(at)
         rec = self._launch_record(
             "chunk", K,
             kv_tokens=np.sum(self._kv_span(at, span) * alive),
@@ -3801,16 +3824,19 @@ class ContinuousEngine:
         W, B = self._sched_width, self.n_slots
         Bd = self._blk
         if Bd:
-            # a decode row is its open block: `block` query tokens at the
-            # row's committed length (a placeholder: the device's own
-            # state.pos is substituted, as for a verify row)
+            # a decode row is its open block, behind the owed block where
+            # this forward carries one: `block` or 2 x `block` query
+            # tokens of one tile (the start is a placeholder: the
+            # device's own state.pos is substituted, as for a verify row)
             fwd = self._host_pos.copy()
-            starts, _, _ = self._blk_at(fwd)
+            starts, spans = self._blk_reads(fwd)
             alive_now = fwd < self._host_end
         entries = []
         for b in active:
             if Bd:
-                entries.append((b, int(starts[b]), Bd, P.RAGGED_PREFILL))
+                entries.append(
+                    (b, int(starts[b]), int(spans[b]), P.RAGGED_PREFILL)
+                )
             elif b in spec_rows:
                 # verify row: [current + k drafts] — a short prefill-kind
                 # row over the slot's own block table (the whole point:
@@ -3832,35 +3858,35 @@ class ContinuousEngine:
             entries, width=W, tile=tile,
         )
         dev_dev = None
-        if self._spec_capable or Bd:
-            # mark every decode/verify entry (the first n_dec) for
-            # on-device position substitution — the host start values
-            # above are placeholders for those rows
-            t_on, t_off, k_on, k_off = P.build_device_meta(
-                entries, offsets, len(active), width=W, tile=tile,
-            )
-            dev_dev = P.DeviceMeta(
-                jnp.asarray(t_on), jnp.asarray(t_off),
-                jnp.asarray(k_on), jnp.asarray(k_off),
-            )
         toks = np.zeros((W,), np.int32)
         dec_flag = np.zeros((W,), bool)
         dec_idx = np.full((B,), -1 if Bd else 0, np.int32)
         n_dec = len(active)
+        if Bd:
+            # every token of a decode row comes from the device's
+            # DiffState, at offsets from state.pos that start one block
+            # below it where the owed block rides (mixed_step_ragged)
+            *dev_np, dec_idx[active] = P.build_block_meta(
+                entries, offsets, [spans[b] > Bd for b in active],
+                block=Bd, width=W, tile=tile,
+            )
+            dev_dev = P.DeviceMeta(*map(jnp.asarray, dev_np))
+        elif self._spec_capable:
+            # mark every decode/verify entry (the first n_dec) for
+            # on-device position substitution — the host start values
+            # above are placeholders for those rows
+            dev_dev = P.DeviceMeta(*map(jnp.asarray, P.build_device_meta(
+                entries, offsets, n_dec, width=W, tile=tile,
+            )))
         K1 = self._spec_k_max + 1
         sp_on = np.zeros((B,), bool)
         sp_idx = np.zeros((B, K1), np.int32)
         sp_nd = np.zeros((B,), np.int32)
         dec_on = np.zeros((B,), bool)
-        for b, off in zip(active, offsets[:n_dec]):
+        for b, off in () if Bd else zip(active, offsets[:n_dec]):
             # the entry's FIRST flat slot is dec_flag-substituted from
             # device state (token AND position) for plain decode rows
             # and verify rows alike
-            if Bd:
-                # every token of the block comes from the device's
-                # DiffState (mixed_step_ragged reads dev.tok_on)
-                dec_idx[b] = off
-                continue
             dec_flag[off] = True
             if b in spec_rows:
                 kb, drafts, _pred = spec_rows[b]
@@ -3912,6 +3938,10 @@ class ContinuousEngine:
                 job.req.budget = job.max_tokens - (0 if Bd else 1)
         diffusion = {}
         if Bd:
+            # (completion-free steps reuse the device-resident idle rows,
+            # as they reuse the idle arm)
+            diffusion = {"diff": self._diff, "darm": self._idle_darm}
+        if Bd and completions:
             d_open = np.full((B, Bd), self.cfg.mask_token_id, np.int32)
             d_skip = np.zeros((B,), np.int32)
             d_reveal = np.ones((B,), np.int32)
@@ -3919,10 +3949,12 @@ class ContinuousEngine:
                 head, reveal = self._prefilling[s].diffusion
                 d_open[s, : len(head)] = head
                 d_skip[s], d_reveal[s] = len(head), reveal
-            diffusion = {"diff": self._diff, "darm": P.DiffState(
-                jnp.asarray(d_open), jnp.asarray(d_skip),
-                jnp.asarray(d_reveal),
-            )}
+            # (an armed row owes nothing: admission committed every block
+            # below its open one)
+            diffusion["darm"] = P.DiffState(*map(jnp.asarray, (
+                d_open, d_skip, d_reveal, np.zeros((B, Bd), np.int32),
+                np.zeros((B,), bool),
+            )))
         if arm_np is not None:
             (on, idx, plen, mtk, sp, presence) = arm_np
             arm = P.MixedArm(
@@ -4160,7 +4192,7 @@ class ContinuousEngine:
                 continue
             if not self._blk:
                 # (a diffusion row's first tokens come with its first
-                # commit: _distribute stamps its ttft then)
+                # clean block: _distribute stamps its ttft then)
                 req.first_id = int(firsts[slot])
                 if not req.ttft:
                     req.ttft = now - req.t_start
@@ -4901,7 +4933,7 @@ class ContinuousEngine:
             req.tokens.extend(int(t) for t in new)
             if self._blk and len(new):
                 self._m_diff_tokens.inc(len(new))
-                if not req.ttft:  # the first committed block has arrived
+                if not req.ttft:  # the first clean block has arrived
                     req.ttft = now - req.t_start
             if len(new) and self._shadow is not None:
                 # decode crossed a block boundary? shadow the newly

@@ -34,7 +34,8 @@ from ..utils import faults
 from ..utils.logging import get_logger, request_id_context
 from ..utils.metrics import (
     ADMISSION_WAIT_HELP, DEFAULT_SIZE_BUCKETS, DIFFUSION_FORWARDS_HELP,
-    DIFFUSION_TOKENS_HELP, SLOT_RELEASE_HELP, SLOT_TURNOVER_HELP,
+    DIFFUSION_FUSED_HELP, DIFFUSION_TOKENS_HELP, SLOT_RELEASE_HELP,
+    SLOT_TURNOVER_HELP,
     STEPS_AHEAD_BUCKETS, MetricsRegistry,
 )
 from ..utils.probe import device_summary
@@ -674,6 +675,9 @@ class InferenceEngine:
         self.metrics.counter(
             "dli_diffusion_row_forwards_total", DIFFUSION_FORWARDS_HELP,
             ("kind",),
+        )
+        self.metrics.counter(
+            "dli_diffusion_fused_commits_total", DIFFUSION_FUSED_HELP,
         )
         self.metrics.counter(
             "dli_diffusion_tokens_total", DIFFUSION_TOKENS_HELP,

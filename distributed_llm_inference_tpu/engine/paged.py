@@ -693,28 +693,47 @@ def _forward_step_paged(cfg, params, tokens, pool, table, pos, pages=None,
 # -- generation by diffusion over blocks (cfg.diffusion_block > 0) -------------
 #
 # A row's sequence is cut into blocks of B tokens at absolute positions.
-# Blocks below state.pos are COMMITTED: their K/V is in the pool, computed
-# from clean tokens under the block mask (ops/attention.block_frontier), and
-# never changes. The block at state.pos is OPEN: DiffState.open holds its B
-# tokens, cfg.mask_token_id where nothing is revealed yet. A forward carries
-# the open block as B query tokens of one row through the ragged seam (a
-# q_len-B tile at q_start = state.pos, like a draft-and-verify row), writes
-# their K/V at the block's positions and reads the logits AT each position:
+# Blocks below state.pos are CLEAN: every token is known. All but the last of
+# them are COMMITTED: their K/V is in the pool, computed from clean tokens
+# under the block mask (ops/attention.block_frontier), and never changes. The
+# block at state.pos is OPEN: DiffState.open holds its B tokens,
+# cfg.mask_token_id where nothing is revealed yet. The block just below it
+# may be OWED its commit (DiffState.owe): its tokens (DiffState.owed) are
+# known, but what the pool holds at its positions is what its last denoise
+# forward wrote, computed while it still held masks.
 #
-#   * masks left: DENOISE. The `reveal` leftmost masked positions take their
-#     token (the mask id's logit at -inf); nothing is emitted, state.pos
-#     stays. What this forward wrote to the pool is the K/V of a block still
+# A forward carries, as query tokens of ONE row through the ragged seam (one
+# tile at q_start, like a draft-and-verify row), the owed block at its own
+# positions and then the open block (2B tokens from state.pos - B), or the
+# open block alone (B tokens from state.pos) where nothing is owed: a row's
+# first generated block, whose predecessors admission committed, and every
+# forward of a block after its first. It writes their K/V and reads the
+# logits AT the open block's positions. Under the block mask the owed
+# block's tokens see nothing of the open block and the open block's see all
+# of the owed one, so what lands in the pool for the owed block is what a
+# forward of its clean tokens alone would write, and the open block reads
+# it in the same pass: the commit costs no forward of its own.
+#
+#   * Every forward is a DENOISE forward: the `reveal` leftmost masked
+#     positions of the open block take their token (the mask id's logit at
+#     -inf). What it wrote for the open block is the K/V of a block still
 #     holding masks: the row's next forward overwrites it before reading it,
 #     no other row's table maps the block, and the prefix index only ever
 #     registers blocks below a prompt's length, which admission committed.
-#   * no mask left: COMMIT. The K/V just written is the clean block's; the
-#     block's generated tokens are emitted (not the prompt's remainder at
-#     its head, `skip`; none past the budget or a stop token), state.pos
-#     moves on by B and the next block opens, all masks.
+#   * When it reveals the block's last mask the block is clean: its
+#     generated tokens are emitted by THAT forward (not the prompt's
+#     remainder at its head, `skip`; none past the budget or a stop token),
+#     state.pos moves on by B, the clean block becomes the owed one and the
+#     next block opens, all masks.
+#   * A row that its budget or a stop token ends there owes nothing: ITS
+#     LAST BLOCK IS NEVER COMMITTED. Nothing reads it: the row runs no
+#     further forward, no other table maps the block, a preempted row
+#     resumes from its delivered tokens as a prompt, and the prefix index
+#     holds prompt blocks alone.
 #
 # The count a forward reveals is fixed per row at admission (B /
-# denoise_steps), so the host's position model knows every forward's kind
-# and the row's last forward without a fetch (engine/continuous).
+# denoise_steps), so the host's position model knows what every forward
+# carries and the row's last forward without a fetch (engine/continuous).
 
 
 class DiffState(NamedTuple):
@@ -722,49 +741,83 @@ class DiffState(NamedTuple):
     (whose pos is the open block's first position, and whose token,
     presence and counts a diffusion row does not use). Also the shape of a
     completing prefill's arming operands beside MixedArm (`darm`: the
-    prompt's remainder then masks, its length, the row's reveal count;
-    rows with arm.on False are untouched)."""
+    prompt's remainder then masks, its length, the row's reveal count,
+    nothing owed; rows with arm.on False are untouched)."""
 
     open: jnp.ndarray  # i32 [B, block]: the open block's tokens
     skip: jnp.ndarray  # i32 [B]: prompt tokens at the open block's head
     # (a prompt's last partial block; 0 from the second block on)
     reveal: jnp.ndarray  # i32 [B]: masked positions a forward reveals
+    owed: jnp.ndarray  # i32 [B, block]: the clean block below state.pos
+    owe: jnp.ndarray  # bool [B]: its K/V is not in the pool yet
 
 
 def init_diffusion(cfg: ModelConfig, n_slots: int) -> DiffState:
     z = jnp.zeros((n_slots,), jnp.int32)
-    return DiffState(
-        jnp.full((n_slots, cfg.diffusion_block), cfg.mask_token_id,
-                 jnp.int32), z, z + cfg.diffusion_block,
+    blocks = jnp.full((n_slots, cfg.diffusion_block), cfg.mask_token_id,
+                      jnp.int32)
+    return DiffState(blocks, z, z + cfg.diffusion_block, blocks,
+                     jnp.zeros((n_slots,), bool))
+
+
+def block_row_tokens(diff: DiffState, rows, off):
+    """The token of each flat position of a forward: `off` places from its
+    row's state.pos, the owed block below 0 and the open block from 0."""
+    Bd = diff.open.shape[1]
+    return jnp.where(
+        off < 0, diff.owed[rows, jnp.maximum(off + Bd, 0)],
+        diff.open[rows, jnp.clip(off, 0, Bd - 1)],
     )
+
+
+def open_block_rows(x, dec_idx, Bd: int):
+    """The flat positions [B * block, ...] of every row's OPEN block, whose
+    first stands at dec_idx [B]: the head runs over these alone."""
+    at = jnp.maximum(dec_idx, 0)[:, None] + jnp.arange(Bd)[None, :]
+    return x[at.reshape(-1)]
+
+
+def block_row_layout(state: G.SlotState, diff: DiffState):
+    """The flat axis of a forward that carries every live row, 2 x block
+    positions a row (one query tile): the owed block from state.pos - block
+    then the open one, or the open block from state.pos then launch padding
+    (tok_row -1); a row that is not active carries nothing (q_len 0: not
+    walked, not written, no expert). Returns (tokens, tok_row, tok_pos
+    [B * 2 * block], meta [B, 4], the flat index [B] of each row's open
+    block)."""
+    S, Bd = diff.open.shape
+    rows = jnp.arange(S, dtype=jnp.int32)
+    below = jnp.where(diff.owe & state.active, Bd, 0)  # [S]
+    q_len = jnp.where(state.active, below + Bd, 0)
+    j = jnp.arange(2 * Bd, dtype=jnp.int32)[None, :]
+    off = (j - below[:, None]).reshape(-1)
+    rows_ix = jnp.repeat(rows, 2 * Bd)
+    tok_row = jnp.where(j < q_len[:, None], rows[:, None], -1).reshape(-1)
+    meta = jnp.stack(
+        [rows, state.pos - below, q_len,
+         jnp.full((S,), RAGGED_PREFILL, jnp.int32)], axis=1,
+    )
+    return (block_row_tokens(diff, rows_ix, off), tok_row,
+            state.pos[rows_ix] + off, meta, rows * (2 * Bd) + below)
 
 
 def _forward_blocks_paged(cfg, params, state: G.SlotState, diff: DiffState,
                           pool, table):
-    """One forward of every live row's open block over the paged pool:
-    float32 logits [B, block, V] at the block's positions, and the pool
-    with the block's K/V written. Rows that are not active carry nothing
-    (q_len 0: not walked, not written, no expert)."""
+    """One forward of every live row over the paged pool
+    (`block_row_layout`): float32 logits [B, block, V] at the OPEN block's
+    positions, and the pool with the owed and open blocks' K/V written."""
     from ..models import api as M
 
     S, Bd = diff.open.shape
-    rows = jnp.arange(S, dtype=jnp.int32)
-    tok_row = jnp.repeat(jnp.where(state.active, rows, -1), Bd)
-    tok_pos = (
-        state.pos[:, None] + jnp.arange(Bd, dtype=jnp.int32)[None, :]
-    ).reshape(-1)
-    meta = jnp.stack(
-        [rows, state.pos, jnp.where(state.active, Bd, 0),
-         jnp.full((S,), RAGGED_PREFILL, jnp.int32)], axis=1,
-    )
-    x = M.embed(cfg, params, diff.open.reshape(-1)[:, None], tok_pos)
+    toks, tok_row, tok_pos, meta, open_at = block_row_layout(state, diff)
+    x = M.embed(cfg, params, toks[:, None], tok_pos)
     x, pool = M.forward_layers(
         cfg, params["layers"], x, pool, tok_pos,
         attn_hook=make_ragged_fill_hook(table, meta, tok_row),
         attn_seq_len=1,
     )
-    logits = M.unembed(cfg, params, x)[:, 0, :]
-    return logits.reshape(S, Bd, -1), pool
+    logits = M.unembed(cfg, params, open_block_rows(x, open_at, Bd))
+    return logits[:, 0, :].reshape(S, Bd, -1), pool
 
 
 def diffusion_step(cfg: ModelConfig, state: G.SlotState,
@@ -777,9 +830,11 @@ def diffusion_step(cfg: ModelConfig, state: G.SlotState,
 
     The token of a masked position is chosen from its own logits with the
     mask id suppressed, by the row's sampling knobs (penalties do not
-    apply: admission refuses them). A row with masks left reveals its
-    `reveal` leftmost ones; a row with none commits (see the section
-    comment). Returns (state, diff, emit [B, block], emit_ok [B, block])."""
+    apply: admission refuses them). A row reveals its `reveal` leftmost
+    masked positions; where that leaves no mask the block is clean, is
+    emitted and becomes the owed one (see the section comment). A row that
+    rode carried what it owed. Returns (state, diff, emit [B, block],
+    emit_ok [B, block])."""
     from ..ops.sampling import sample_token, suppress_token
 
     Bd = cfg.diffusion_block
@@ -787,10 +842,7 @@ def diffusion_step(cfg: ModelConfig, state: G.SlotState,
     pad = jnp.int32(cfg.pad_token_id)
     live = state.active if on is None else state.active & on
     masked = diff.open == mask_id  # [B, block]
-    any_masked = jnp.any(masked, axis=1)
-    denoise = (live & any_masked)[:, None]
-    commit = live & ~any_masked
-    # denoise: the leftmost `reveal` masked positions take their token
+    # the leftmost `reveal` masked positions take their token
     cand = sample_token(
         key, suppress_token(logits.astype(jnp.float32), cfg.mask_token_id),
         sparams.temperature[:, None, None], sparams.top_k[:, None, None],
@@ -798,31 +850,35 @@ def diffusion_step(cfg: ModelConfig, state: G.SlotState,
         sparams.min_p[:, None, None],
     )
     rank = jnp.cumsum(masked.astype(jnp.int32), axis=1) - 1
-    show = denoise & masked & (rank < diff.reveal[:, None])
+    show = live[:, None] & masked & (rank < diff.reveal[:, None])
     opened = jnp.where(show, cand, diff.open)
-    # commit: emit the clean block's generated tokens
+    clean = live & ~jnp.any(opened == mask_id, axis=1)
+    # a clean block: emit its generated tokens
     j = jnp.arange(Bd, dtype=jnp.int32)[None, :]
     gen = j >= diff.skip[:, None]
     order = j - diff.skip[:, None]  # a token's place among the generated
-    stop = G.stop_mask(cfg, diff.open) & gen
+    stop = G.stop_mask(cfg, opened) & gen
     before = jnp.cumsum(stop.astype(jnp.int32), axis=1) == 0
     room = order < state.remaining[:, None]
-    emit_ok = commit[:, None] & gen & before & room
+    emit_ok = clean[:, None] & gen & before & room
     n_emit = jnp.sum(emit_ok.astype(jnp.int32), axis=1)
     # a stop token ends the row only where plain decoding would have
     # reached it: inside the budget
-    saw_stop = commit & jnp.any(stop & room, axis=1)
+    saw_stop = clean & jnp.any(stop & room, axis=1)
     remaining = state.remaining - n_emit
-    emit = jnp.where(emit_ok, diff.open, pad)
+    emit = jnp.where(emit_ok, opened, pad)
     state = state._replace(
-        pos=state.pos + jnp.where(commit, Bd, 0),
-        active=jnp.where(commit, ~saw_stop & (remaining > 0), state.active),
+        pos=state.pos + jnp.where(clean, Bd, 0),
+        active=jnp.where(clean, ~saw_stop & (remaining > 0), state.active),
         remaining=remaining,
     )
     diff = DiffState(
-        open=jnp.where(commit[:, None], mask_id, opened),
-        skip=jnp.where(commit, 0, diff.skip),
+        open=jnp.where(clean[:, None], mask_id, opened),
+        skip=jnp.where(clean, 0, diff.skip),
         reveal=diff.reveal,
+        owed=jnp.where(clean[:, None], opened, diff.owed),
+        # a row that goes on owes its clean block; one that ended owes nothing
+        owe=jnp.where(live, clean & state.active, diff.owe),
     )
     return state, diff, emit, emit_ok
 
@@ -1396,6 +1452,27 @@ def build_device_meta(entries, offsets, n_dev: int, *, width: int,
     return tile_on, tile_off, tok_on, tok_off
 
 
+def build_block_meta(entries, offsets, owing, *, block: int, width: int,
+                     tile: int):
+    """build_device_meta for a block-diffusion launch (HOST side): the
+    first len(owing) entries are decode rows, each its open block (`block`
+    tokens from state.pos) or, where owing[i], the owed block in front of
+    it (2 x block tokens from state.pos - block): those entries' offsets
+    from state.pos start at -block. Returns build_device_meta's four
+    arrays and, per decode row, the flat index of its OPEN block's first
+    token (mixed_step_ragged's dec_idx)."""
+    t_on, t_off, k_on, k_off = build_device_meta(
+        entries, offsets, len(owing), width=width, tile=tile,
+    )
+    open_at = []
+    for (_, _, n, _), off, owe in zip(entries, offsets, owing):
+        below = block if owe else 0
+        k_off[off : off + n] -= below
+        t_off[off // tile : off // tile - (-n // tile)] -= below
+        open_at.append(off + below)
+    return t_on, t_off, k_on, k_off, open_at
+
+
 def apply_device_meta(meta, tok_row, tok_pos, dev: DeviceMeta, pos):
     """TRACED half of the device-derived launch metadata: substitute
     `pos[row] + offset` into the marked tiles' q_start column and the
@@ -1539,12 +1616,14 @@ def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
 
     A block-diffusion model (cfg.diffusion_block > 0; `diff` its
     DiffState, `darm` the completing prefills' arming rows): a decode row is
-    its whole open block, a q_len-block entry whose tokens come from
-    diff.open and whose positions from state.pos (`dev` marks them, as it
-    marks a verify row's), dec_idx [B] the flat index of the block's first
-    token, -1 for a slot without a row in this launch; `diffusion_step`
-    takes slot_step's place and a completing prefill samples no first
-    token (`diffusion_epilogue`).
+    its open block, behind the owed block where the host's position model
+    says the forward carries one (`build_block_meta`): an entry of block or
+    2 x block tokens whose tokens come from diff.owed / diff.open and whose
+    positions from state.pos (`dev` marks them, as it marks a verify row's;
+    tok_off runs from -block where the owed block rides), dec_idx [B] the
+    flat index of the OPEN block's first token, -1 for a slot without a row
+    in this launch; `diffusion_step` takes slot_step's place and a
+    completing prefill samples no first token (`diffusion_epilogue`).
 
     Returns (packed int32 — [5, B] plain, [5 + 2*(K+1) + 1, B] with
     spec: emitted / emit_mask / active / firsts / armed [/ spec_emit /
@@ -1572,8 +1651,16 @@ def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
             spec_toks.reshape(-1), mode="drop"
         )
     pos = jnp.where(dec_flag, state.pos[rows_ix], tok_pos)
-    if cfg.diffusion_block:  # a decode row's tokens: its open block
-        toks = jnp.where(dev.tok_on, diff.open[rows_ix, dev.tok_off], toks)
+    if cfg.diffusion_block:
+        # a decode row's tokens: its owed and open blocks. A row the device
+        # holds ended (a stop token the host has not fetched yet) carries
+        # nothing, as in the decode chunk: not walked, not written, no expert
+        toks = jnp.where(
+            dev.tok_on, block_row_tokens(diff, rows_ix, dev.tok_off), toks
+        )
+        tok_row = jnp.where(dev.tok_on & ~state.active[rows_ix], -1, tok_row)
+        ended = dev.tile_on & ~state.active[jnp.maximum(meta[:, 0], 0)]
+        meta = meta.at[:, 2].set(jnp.where(ended, 0, meta[:, 2]))
     x = M.embed(cfg, params, toks[:, None], pos)
     x, pool = M.forward_layers(
         cfg, params["layers"], x, pool, pos,
@@ -1582,10 +1669,10 @@ def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
     )
     if cfg.diffusion_block:
         Bd = cfg.diffusion_block
-        at = jnp.maximum(dec_idx, 0)[:, None] + jnp.arange(Bd)[None, :]
-        logits = M.unembed(cfg, params, x[at.reshape(-1)])[:, 0, :]
+        logits = M.unembed(cfg, params, open_block_rows(x, dec_idx, Bd))
         packed, state, sparams, diff = diffusion_epilogue(
-            cfg, state, sparams, diff, logits.reshape(at.shape + (-1,)),
+            cfg, state, sparams, diff,
+            logits[:, 0, :].reshape(dec_idx.shape[0], Bd, -1),
             key, dec_idx >= 0, arm, darm,
         )
         packed = _pack_rows(packed, pool["routed"])
@@ -1705,11 +1792,10 @@ def diffusion_epilogue(cfg: ModelConfig, state: G.SlotState,
         active=jnp.where(a, arm.max_tokens > 0, state.active),
         remaining=jnp.where(a, arm.max_tokens, state.remaining),
     )
-    diff = DiffState(
-        open=jnp.where(a_col, darm.open, diff.open),
-        skip=jnp.where(a, darm.skip, diff.skip),
-        reveal=jnp.where(a, darm.reveal, diff.reveal),
-    )
+    diff = DiffState(*(
+        jnp.where(a_col if new.ndim > 1 else a, new, old)
+        for new, old in zip(darm, diff)
+    ))
     sparams = G.SlotParams(*(
         jnp.where(a, new, old) for new, old in zip(arm.params, sparams)
     ))

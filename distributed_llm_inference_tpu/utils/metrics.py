@@ -76,12 +76,19 @@ SLOT_TURNOVER_HELP = (
 DIFFUSION_FORWARDS_HELP = (
     "row-forwards of a block-diffusion fleet by the host position model, "
     "by kind: denoise = the forward reveals masked positions of the row's "
-    "open block, commit = the block is clean and the forward writes its "
-    "K/V and emits it"
+    "open block (the one that reveals the last emits the block), commit = "
+    "the forward only writes a clean block's K/V (none: a clean block's "
+    "commit rides the next block's first denoise forward, counted once, "
+    "as denoise)"
+)
+DIFFUSION_FUSED_HELP = (
+    "denoise row-forwards of a block-diffusion fleet that carried the "
+    "clean block below the open one and wrote its K/V (the fused commit), "
+    "by the host position model"
 )
 DIFFUSION_TOKENS_HELP = (
-    "tokens a block-diffusion fleet committed and delivered (counted at "
-    "the fetch of the forward that committed their block)"
+    "tokens a block-diffusion fleet delivered (counted at the fetch of "
+    "the forward that revealed the last mask of their block)"
 )
 
 MAX_SERIES = 64  # label-set cap per family

@@ -210,63 +210,139 @@ def test_reference_rows_against_a_step_by_step_replay(steps, n_prompt):
 
 # ---- the step program against the reference's logits ----------------------------
 
-class Row:
-    """One row through the step programs by hand: chunked prefill of the
-    prompt's whole blocks into the pool, then forwards of the open block
-    (`mutant` takes one rule out)."""
+MIXED_LOGITS = []  # one list: a compiled program keeps what it was traced with
 
-    def __init__(self, cfg, prompt, steps, max_tokens, mutant=None, chunk=16):
-        self.cfg, self.Bd, self.mutant = cfg, cfg.diffusion_block, mutant
+
+@pytest.fixture
+def mixed_logits(monkeypatch):
+    """The logits a mixed launch hands `diffusion_epilogue`, out of the
+    compiled program (a Rows with via="mixed" names its configuration apart,
+    so its programs are traced with this in place)."""
+    inner = P.diffusion_epilogue
+
+    def epilogue(cfg, state, sparams, diff, logits, *rest):
+        jax.debug.callback(lambda a: MIXED_LOGITS.append(np.asarray(a)), logits)
+        return inner(cfg, state, sparams, diff, logits, *rest)
+
+    monkeypatch.setattr(P, "diffusion_epilogue", epilogue)
+    yield
+    MIXED_LOGITS.clear()
+
+
+class Rows:
+    """Rows through the step programs by hand, each with its own prompt,
+    denoise_steps and budget, so one forward holds rows in different phases:
+    chunked prefill of each prompt's whole blocks into the pool, then
+    forwards through the decode chunk's body (`_forward_blocks_paged` +
+    `diffusion_step`) or through `mixed_step_ragged` (`mutant` takes one
+    rule out)."""
+
+    TILE = 8
+
+    def __init__(self, cfg, asks, mutant=None, via="chunk", chunk=16):
+        self.cfg, self.Bd, self.mutant, self.via = cfg, cfg.diffusion_block, mutant, via
+        self.asks = asks
         self.params = M.init_params(cfg, jax.random.PRNGKey(SEED))
-        self.table = jnp.asarray([[0] * 4, [1, 2, 3, 4]], jnp.int32)
-        pool = P.init_pool(cfg, 6, BS)
-        whole = len(prompt) // self.Bd * self.Bd
-        for at in range(0, whole, chunk):
-            n = min(chunk, whole - at)
-            meta, tok_row, tok_pos, _, _ = P.build_ragged_meta(
-                [(1, at, n, P.RAGGED_PREFILL)], width=chunk, tile=8)
-            toks = np.zeros((chunk,), np.int32)
-            toks[:n] = prompt[at:at + n]
-            pool = P.extend_ragged_paged(
-                cfg, self.params, jnp.asarray(toks), jnp.asarray(tok_row),
-                jnp.asarray(tok_pos), jnp.asarray(meta), pool, self.table)
-        self.pool = pool
-        state, self.sparams = G.init_slots(2, cfg.vocab_size)
+        R = len(asks)
+        self.table = jnp.asarray(
+            [[1 + 4 * r + j for j in range(4)] for r in range(R)], jnp.int32)
+        self.pool = self.prefill([p for p, _, _ in asks], chunk)
+        state, self.sparams = G.init_slots(R, cfg.vocab_size)
+        diff = P.init_diffusion(cfg, R)
+        opens, whole = [], []
+        for prompt, _, _ in asks:
+            whole.append(len(prompt) // self.Bd * self.Bd)
+            head = list(prompt[whole[-1]:])
+            opens.append(head + [cfg.mask_token_id] * (self.Bd - len(head)))
         self.state = state._replace(
-            pos=state.pos.at[1].set(whole), active=state.active.at[1].set(True),
-            remaining=state.remaining.at[1].set(max_tokens))
-        head = list(prompt[whole:])
-        diff = P.init_diffusion(cfg, 2)
+            pos=jnp.asarray(whole, jnp.int32), active=jnp.ones((R,), bool),
+            remaining=jnp.asarray([mt for _, _, mt in asks], jnp.int32))
         self.diff = diff._replace(
-            open=diff.open.at[1].set(jnp.asarray(
-                head + [cfg.mask_token_id] * (self.Bd - len(head)))),
-            skip=diff.skip.at[1].set(len(head)),
-            reveal=diff.reveal.at[1].set(self.Bd // steps))
+            open=jnp.asarray(opens, jnp.int32),
+            skip=jnp.asarray([len(p) - w for (p, _, _), w in zip(asks, whole)], jnp.int32),
+            reveal=jnp.asarray([self.Bd // st for _, st, _ in asks], jnp.int32))
+
+    def prefill(self, seqs, chunk=16):
+        """A pool with each row's `seqs[r]` (whole blocks of it) landed by
+        clean chunked prefill under the block mask."""
+        cfg = self.cfg
+        pool = P.init_pool(cfg, 1 + 4 * len(seqs), BS)
+        for r, seq in enumerate(seqs):
+            whole = len(seq) // self.Bd * self.Bd
+            for at in range(0, whole, chunk):
+                n = min(chunk, whole - at)
+                meta, tok_row, tok_pos, _, _ = P.build_ragged_meta(
+                    [(r, at, n, P.RAGGED_PREFILL)], width=chunk, tile=self.TILE)
+                toks = np.zeros((chunk,), np.int32)
+                toks[:n] = seq[at:at + n]
+                pool = P.extend_ragged_paged(
+                    cfg, self.params, jnp.asarray(toks), jnp.asarray(tok_row),
+                    jnp.asarray(tok_pos), jnp.asarray(meta), pool, self.table)
+        return pool
+
+    def _chunk_forward(self, key):
+        cfg = self.cfg
+        logits, self.pool = jax.jit(lambda st, df, pl: P._forward_blocks_paged(
+            cfg, self.params, st, df, pl, self.table))(self.state, self.diff, self.pool)
+        if self.mutant == "shifted-read":  # the autoregressive habit
+            logits = jnp.roll(logits, 1, axis=1)
+        self.state, self.diff, emit, ok = P.diffusion_step(
+            cfg, self.state, self.sparams, self.diff, logits, key)
+        return np.asarray(logits), np.asarray(emit), np.asarray(ok)
+
+    def _mixed_forward(self, key):
+        """The host's half of a mixed launch that carries every live row (the
+        plan engine/continuous._launch_mixed makes from its position model,
+        here from the device's own state)."""
+        cfg, Bd, R = self.cfg, self.Bd, len(self.asks)
+        W = (R + 1) * self.TILE
+        live = np.flatnonzero(np.asarray(self.state.active))
+        owing = [bool(self.diff.owe[r]) and self.mutant != "mixed-drops-owed" for r in live]
+        pos = np.asarray(self.state.pos)
+        entries = [(int(r), int(pos[r]) - Bd * o, Bd * (1 + o), P.RAGGED_PREFILL)
+                   for r, o in zip(live, owing)]
+        meta, tok_row, tok_pos, offsets, _ = P.build_ragged_meta(
+            entries, width=W, tile=self.TILE)
+        *dev, open_at = P.build_block_meta(
+            entries, offsets, owing, block=Bd, width=W, tile=self.TILE)
+        dec_idx = np.full((R,), -1, np.int32)
+        dec_idx[live] = open_at
+        packed, self.state, self.sparams, self.pool, self.diff = P.mixed_step_ragged(
+            cfg, self.params, jnp.zeros((W,), jnp.int32), jnp.asarray(tok_row),
+            jnp.asarray(tok_pos), jnp.zeros((W,), bool), jnp.asarray(meta), self.pool,
+            self.table, self.state, self.sparams, key, jnp.asarray(dec_idx),
+            P.idle_mixed_arm(R, cfg.vocab_size), dev=P.DeviceMeta(*map(jnp.asarray, dev)),
+            diff=self.diff, darm=P.init_diffusion(cfg, R))
+        packed = np.asarray(packed)
+        jax.effects_barrier()
+        return MIXED_LOGITS[-1], packed[:Bd].T, packed[Bd:2 * Bd].T.astype(bool)
 
     def run(self):
-        """(tokens emitted, the logits each was revealed from)."""
-        cfg = self.cfg
-        fwd = jax.jit(lambda st, df, pl: P._forward_blocks_paged(
-            cfg, self.params, st, df, pl, self.table))
-        out, rows = [], []
+        """Per row: (tokens emitted, the logits each was revealed from)."""
+        cfg, R = self.cfg, len(self.asks)
+        out, rows = [[] for _ in range(R)], [[] for _ in range(R)]
+        self.phases = set()  # (masks left, owes) of the rows of each forward
         for it in range(64):
-            if not bool(self.state.active[1]):
+            active = np.asarray(self.state.active)
+            if not active.any():
                 break
-            logits, pool = fwd(self.state, self.diff, self.pool)
-            if self.mutant == "shifted-read":  # the autoregressive habit
-                logits = jnp.roll(logits, 1, axis=1)
-            before = np.asarray(self.diff.open[1])
-            clean = (before != cfg.mask_token_id).all()
-            if self.mutant == "no-commit" and clean:
-                pool = self.pool  # the block's K/V stays the last denoise state's
-            self.pool = pool
-            self.state, self.diff, emit, ok = P.diffusion_step(
-                cfg, self.state, self.sparams, self.diff, logits,
-                jax.random.PRNGKey(it))
-            after = np.asarray(self.diff.open[1])
-            for i in np.flatnonzero((before != after) & ~clean):
-                rows.append(np.asarray(logits[1, i]))
-            out += [int(t) for t, o in zip(np.asarray(emit[1]), np.asarray(ok[1])) if o]
+            if self.mutant == "owed-not-rewritten":
+                # the open block reads the K/V its predecessor's last denoise
+                # forward left, computed from masks
+                self.diff = self.diff._replace(owe=jnp.zeros((R,), bool))
+            before, was = np.asarray(self.diff.open), np.asarray(self.state.pos)
+            self.phases.add(tuple(
+                (int((before[r] == cfg.mask_token_id).sum()), bool(self.diff.owe[r]))
+                for r in np.flatnonzero(active)))
+            forward = self._chunk_forward if self.via == "chunk" else self._mixed_forward
+            logits, emit, ok = forward(jax.random.PRNGKey(it))
+            clean = np.asarray(self.state.pos) > was
+            after = np.where(clean[:, None], np.asarray(self.diff.owed),
+                             np.asarray(self.diff.open))
+            for r in np.flatnonzero(active):
+                shown = (before[r] == cfg.mask_token_id) & (after[r] != cfg.mask_token_id)
+                rows[r] += [logits[r, i] for i in np.flatnonzero(shown)]
+                out[r] += [int(t) for t, o in zip(emit[r], ok[r]) if o]
         return out, rows
 
 
@@ -277,70 +353,156 @@ def _against_reference(cfg, prompt, steps, out, rows):
     return float(np.abs(got - lg).max()), margins(lg, out)
 
 
+def _ask(steps, n_prompt, max_tokens):
+    return ([int(t) for t in np.random.default_rng(n_prompt).integers(3, 250, n_prompt)],
+            steps, max_tokens)
+
+
+# every head (prompt length mod 4) with every denoise_steps, two rows a
+# forward: prompts shorter than a block and of several prefill chunks, budgets
+# that end inside a block and on its edge
+PAIRS = [((1, 20, 14), (2, 21, 9)), ((4, 22, 12), (1, 23, 10)), ((2, 36, 8), (4, 37, 13)),
+         ((1, 2, 6), (2, 3, 6)), ((4, 24, 7), (1, 45, 8)), ((2, 18, 11), (4, 19, 9))]
+
+
+@pytest.mark.parametrize("via", ["chunk", "mixed"])
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-@pytest.mark.parametrize("steps,n_prompt,max_tokens", [
-    (2, 20, 14), (2, 21, 9), (4, 22, 12), (1, 23, 10), (2, 3, 6), (2, 37, 8)])
+@pytest.mark.parametrize("pair", PAIRS, ids=lambda p: "-".join(f"s{s}h{n % 4}" for s, n, _ in p))
 def test_step_program_logits_equal_the_references_at_every_denoise_state(
-        impl, steps, n_prompt, max_tokens):
-    """Chunked block-masked prefill, denoise forwards and commits through
-    the paged pool, a prompt of every remainder mod 4, a budget that ends
-    inside a block: the logits a token is revealed from are the
-    reference's at that denoise state."""
-    cfg = _cfg(impl)
-    prompt = [int(t) for t in np.random.default_rng(n_prompt).integers(3, 250, n_prompt)]
-    row = Row(cfg, prompt, steps, max_tokens)
-    out, rows = row.run()
-    assert len(out) == max_tokens  # exactly, also where the budget ends mid-block
-    worst, m = _against_reference(cfg, prompt, steps, out, rows)
-    assert worst < 5e-5 and m.max() == 0.0
-    # the device's length is the prompt's whole blocks plus the blocks committed
-    head = n_prompt % 4
-    assert int(row.state.pos[1]) == n_prompt - head + 4 * -(-(head + max_tokens) // 4)
+        impl, via, pair, mixed_logits):
+    """Chunked block-masked prefill, then denoise forwards that carry the
+    owed block in front of the open one, through the decode chunk's body and
+    through the mixed launch, two rows in different phases a forward: the
+    logits a token is revealed from are the reference's at that denoise
+    state, and what the pool holds for every block but a row's last is what
+    a clean prefill of the same tokens writes."""
+    cfg = _cfg(impl) if via == "chunk" else _cfg(impl).replace(name="test-sdar-tiny-mixed")
+    asks = [_ask(*a) for a in pair]
+    rows_ = Rows(cfg, asks, via=via)
+    outs, rows = rows_.run()
+    # some forward held one row that owed a block beside one that did not,
+    # or rows with different numbers of masks left
+    assert any(len(set(ph)) > 1 for ph in rows_.phases), rows_.phases
+    seqs = []
+    for r, (prompt, steps, max_tokens) in enumerate(asks):
+        assert len(outs[r]) == max_tokens  # exactly, also where the budget ends mid-block
+        worst, m = _against_reference(cfg, prompt, steps, outs[r], rows[r])
+        assert worst < 5e-5 and m.max() == 0.0
+        # the device's length is the prompt's whole blocks plus the clean blocks
+        head = len(prompt) % 4
+        end = len(prompt) - head + 4 * -(-(head + max_tokens) // 4)
+        assert int(rows_.state.pos[r]) == end and not bool(rows_.diff.owe[r])
+        seqs.append((prompt + outs[r] + [5] * 4)[:end])
+    # the pool: every block but the last is COMMITTED (a clean prefill's
+    # K/V); the last never is (what its last denoise forward wrote stands)
+    clean = rows_.prefill(seqs)
+    for r, seq in enumerate(seqs):
+        for name in ("k", "v"):
+            got, want = (np.asarray(P._gather_blocks(p[name], rows_.table[r]))[:, 0]
+                         for p in (rows_.pool, clean))  # [L, KV, S, Dh]
+            last = len(seq) - 4
+            np.testing.assert_allclose(got[:, :, :last], want[:, :, :last], atol=2e-5)
+            assert np.abs(got[:, :, last:len(seq)] - want[:, :, last:len(seq)]).max() > 1e-3
 
 
-@pytest.mark.parametrize("mutant", ["causal-mask", "no-commit", "shifted-read"])
-def test_the_comparison_fails_once_a_rule_is_taken_out(mutant, monkeypatch):
+MUTANTS = ["causal-mask", "owed-not-rewritten", "shifted-read", "logits-at-owed",
+           "mixed-drops-owed"]
+
+
+@pytest.mark.parametrize("mutant", MUTANTS)
+def test_the_comparison_fails_once_a_rule_is_taken_out(mutant, monkeypatch, mixed_logits):
     cfg = _cfg().replace(name=f"test-sdar-tiny-{mutant}")  # its own programs
     if mutant == "causal-mask":
         monkeypatch.setattr(P, "block_frontier", lambda q_pos, block=0: q_pos)
-    prompt = [int(t) for t in np.random.default_rng(1).integers(3, 250, 20)]
-    out, rows = Row(cfg, prompt, 2, 16, mutant=mutant).run()
-    worst, m = _against_reference(cfg, prompt, 2, out, rows)
+    if mutant == "logits-at-owed":  # the tile's first block, whichever it is
+        inner = P.open_block_rows
+        monkeypatch.setattr(P, "open_block_rows", lambda x, at, Bd: inner(
+            x, at // (2 * Bd) * (2 * Bd), Bd))
+    prompt, steps, _ = ask = _ask(2, 20, 16)
+    via = "mixed" if mutant == "mixed-drops-owed" else "chunk"
+    outs, rows = Rows(cfg, [ask], mutant=mutant, via=via).run()
+    worst, m = _against_reference(cfg, prompt, steps, outs[0], rows[0])
     assert worst > 1e-2 and (m > 0).mean() > 0.2
+
+
+def _one_block(cfg, open_, **state_kw):
+    n = len(open_)
+    state, sparams = G.init_slots(n, cfg.vocab_size)
+    state = state._replace(active=jnp.ones((n,), bool), **state_kw)
+    return state, sparams, P.init_diffusion(cfg, n)._replace(
+        open=jnp.asarray(open_, jnp.int32))
 
 
 def test_the_mask_id_is_never_chosen_and_never_emitted():
     cfg = _cfg()
-    state, sparams = G.init_slots(1, cfg.vocab_size)
-    state = state._replace(active=state.active.at[0].set(True),
-                           remaining=state.remaining.at[0].set(8))
-    diff = P.init_diffusion(cfg, 1)
+    state, sparams, diff = _one_block(
+        cfg, [[cfg.mask_token_id] * 4], remaining=jnp.asarray([8], jnp.int32))
     logits = jnp.zeros((1, 4, cfg.vocab_size)).at[:, :, cfg.mask_token_id].set(9.0)
     logits = logits.at[:, :, 17].set(1.0)
-    for it in range(2):  # a denoise forward (the whole block: the default), a commit
-        state, diff, emit, ok = P.diffusion_step(
-            cfg, state, sparams, diff, logits, jax.random.PRNGKey(it))
+    # ONE forward (the whole block: the default) reveals, emits and moves on
+    state, diff, emit, ok = P.diffusion_step(
+        cfg, state, sparams, diff, logits, jax.random.PRNGKey(0))
     assert np.asarray(ok).all() and (np.asarray(emit) == 17).all()
     assert int(state.pos[0]) == 4 and int(state.remaining[0]) == 4
     assert (np.asarray(diff.open) == cfg.mask_token_id).all()  # the next block
+    # and the clean block is owed its commit, token for token
+    assert bool(diff.owe[0]) and (np.asarray(diff.owed) == 17).all()
 
 
 def test_diffusion_step_stop_token_and_rows_that_did_not_ride():
+    """The forward that reveals a block's last mask emits it. Row 0's comes
+    clean with a stop token in it: it emits up to the stop, ends, and owes
+    nothing (its last block is never committed). Row 1 comes clean and goes
+    on: it owes the block. Row 2 reveals one of two masks: nothing emitted.
+    Row 3 did not ride: untouched, what it owed still owed."""
     cfg = _cfg()
-    state, sparams = G.init_slots(2, cfg.vocab_size)
-    state = state._replace(active=jnp.asarray([True, True]),
-                           remaining=jnp.asarray([9, 9], jnp.int32))
-    diff = P.init_diffusion(cfg, 2)._replace(
-        open=jnp.asarray([[5, 6, cfg.eos_token_id, 7], [5, 6, 7, 8]], jnp.int32))
-    logits = jnp.zeros((2, 4, cfg.vocab_size))
+    mk, eos = cfg.mask_token_id, cfg.eos_token_id
+    state, sparams, diff = _one_block(
+        cfg, [[5, 6, mk, mk], [5, 6, 7, mk], [5, 6, mk, mk], [5, mk, mk, mk]],
+        remaining=jnp.asarray([9, 9, 9, 9], jnp.int32))
+    diff = diff._replace(reveal=jnp.asarray([2, 1, 1, 2], jnp.int32),
+                         owe=jnp.asarray([True, True, False, True]),
+                         owed=jnp.full((4, 4), 9, jnp.int32))
+    logits = jnp.zeros((4, 4, cfg.vocab_size)).at[:, :, 8].set(1.0)
+    logits = logits.at[0, 2, eos].set(2.0)
     new, d2, emit, ok = P.diffusion_step(
         cfg, state, sparams, diff, logits, jax.random.PRNGKey(0),
-        on=jnp.asarray([True, False]))
-    # row 0 commits up to its stop token and ends; row 1 did not ride: untouched
-    assert np.asarray(ok).tolist() == [[True, True, False, False], [False] * 4]
-    assert np.asarray(new.active).tolist() == [False, True]
-    assert np.asarray(new.pos).tolist() == [4, 0]
-    assert (np.asarray(d2.open[1]) == np.asarray(diff.open[1])).all()
+        on=jnp.asarray([True, True, True, False]))
+    assert np.asarray(ok).tolist() == [[True, True, False, False], [True] * 4,
+                                       [False] * 4, [False] * 4]
+    assert np.asarray(emit[1]).tolist() == [5, 6, 7, 8]
+    assert np.asarray(new.active).tolist() == [False, True, True, True]
+    assert np.asarray(new.pos).tolist() == [4, 4, 0, 0]
+    assert np.asarray(new.remaining).tolist() == [7, 5, 9, 9]
+    assert np.asarray(d2.owe).tolist() == [False, True, False, True]
+    assert np.asarray(d2.owed).tolist() == [[5, 6, eos, 8], [5, 6, 7, 8], [9] * 4, [9] * 4]
+    assert np.asarray(d2.open).tolist() == [[mk] * 4, [mk] * 4, [5, 6, 8, mk], [5, mk, mk, mk]]
+
+
+@pytest.mark.parametrize("owe", [False, True])
+def test_a_forward_carries_the_owed_block_or_launch_padding(owe):
+    """`block_row_layout`, the flat axis of a decode chunk's forward: 2 x
+    block positions a row; the owed block from state.pos - block in front of
+    the open one, or the open block from state.pos and four positions of
+    launch padding (no row: not walked, written to the trash block, no
+    expert); a row that is not active carries nothing."""
+    cfg = _cfg()
+    mk = cfg.mask_token_id
+    state, _, diff = _one_block(cfg, [[5, 6, mk, mk]] * 2, pos=jnp.asarray([8, 8], jnp.int32))
+    state = state._replace(active=jnp.asarray([True, False]))
+    diff = diff._replace(owe=jnp.asarray([owe, True]), owed=jnp.full((2, 4), 9, jnp.int32))
+    toks, tok_row, tok_pos, meta, open_at = map(np.asarray, P.block_row_layout(state, diff))
+    if owe:
+        assert meta[0].tolist() == [0, 4, 8, P.RAGGED_PREFILL] and open_at[0] == 4
+        assert toks[:8].tolist() == [9, 9, 9, 9, 5, 6, mk, mk]
+        assert tok_pos[:8].tolist() == list(range(4, 12))
+        assert tok_row.tolist() == [0] * 8 + [-1] * 8
+    else:
+        assert meta[0].tolist() == [0, 8, 4, P.RAGGED_PREFILL] and open_at[0] == 0
+        assert toks[:4].tolist() == [5, 6, mk, mk]
+        assert tok_pos[:4].tolist() == list(range(8, 12))
+        assert tok_row.tolist() == [0] * 4 + [-1] * 12
+    assert meta[1, 2] == 0  # the row that is not active: q_len 0
 
 
 def test_a_diffusion_model_is_refused_where_it_cannot_be_served():

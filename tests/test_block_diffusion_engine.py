@@ -9,17 +9,30 @@ all mapped, prompts of all four remainders mod 4, denoise_steps 1, 2 and
 launch, interpreted Pallas and XLA attention, float32 and bfloat16; and
 the open block's invariants: nothing uncommitted reaches the prefix index,
 another row, a preemption or `_release_ended`, and the host's position
-model agrees with the device after every launch.
+model agrees with the device after every launch. ISSUE 33: a clean block's
+commit rides the next block's first denoise forward, so the position model
+(`_blk_plan`, `_blk_at`) is held against a replay of the device's own state,
+and a closed-loop rehearsal against the tokens the three-forward form served
+(tests/data/sdar_three_forward_tokens.json, recorded from the parent).
 """
+
+import functools
+import json
+import os
+import types
 
 import threading
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from distributed_llm_inference_tpu import EngineConfig, create_engine
+from distributed_llm_inference_tpu.engine import generate as G
+from distributed_llm_inference_tpu.engine import paged as P
 from distributed_llm_inference_tpu.engine.continuous import ContinuousEngine
+from distributed_llm_inference_tpu.models.registry import get_model_config
 
 from sdar_util import margins, ref_logits
 
@@ -166,7 +179,11 @@ def test_served_tokens_are_the_references_choice_at_every_denoise_state(impl, st
     kinds = _series(f.eng, "dli_diffusion_row_forwards_total")
     delivered = _series(f.eng, "dli_diffusion_tokens_total")[()]
     assert delivered >= sum(mt for _, mt in ASKS)
-    assert kinds[(("kind", "denoise"),)] > 0 and kinds[(("kind", "commit"),)] > 0
+    # every row-forward reveals; none only writes a clean block, and the
+    # commits ride: one a block but a request's last
+    assert kinds[(("kind", "denoise"),)] > 0 and kinds[(("kind", "commit"),)] == 0
+    assert _series(f.eng, "dli_diffusion_fused_commits_total")[()] >= sum(
+        -(-(n % B + mt) // B) - 1 for n, mt in ASKS)
     # every row ended by its budget: the position model released every slot
     assert _series(f.eng, "dli_slot_release_total").get((("by", "fetch"),), 0) == 0
 
@@ -236,7 +253,7 @@ def test_denoise_steps_is_a_request_field_with_a_server_default():
     assert events[-1]["done"] and events[-1]["error_type"] == "invalid_request"
 
 
-def test_a_stream_delivers_a_block_when_it_commits():
+def test_a_stream_delivers_a_block_when_it_comes_clean():
     f = fleet(impl="xla", steps=2)
     ids = prompt_ids(21, salt=4)  # one prompt token heads the first block
     events = list(f.ce.stream(words(ids), max_tokens=13, greedy=True, chat=False))
@@ -309,15 +326,120 @@ def test_launch_records_count_forwards_and_kv_once_a_forward():
         ids = prompt_ids(16, salt=13)
         f.ask_all([(ids, 8, {})])
         dec = [r for r in recs if r["decode_rows"]]
-        # 8 tokens = 2 blocks x (2 denoise + 1 commit) row-forwards
+        # 8 tokens = 2 blocks x 2 denoise row-forwards; the first block's
+        # commit rides the second's first forward, the last block has none
         assert sum(r["denoise_rows"] for r in dec) == 4
-        assert sum(r["commit_rows"] for r in dec) == 2
+        assert sum(r["commit_rows"] for r in dec) == 0
+        assert sum(r["fused_rows"] for r in dec) == 1
         assert sum(r["revealed_tokens"] for r in dec) == 8
         assert all(r["forwards"] == r["steps"] for r in dec)
-        # a forward reads the row's whole cache plus its open block, once:
-        # three forwards at length 16, three at 20
-        assert sum(r["kv_tokens"] for r in dec) == 3 * 20 + 3 * 24
+        # a forward reads the row's whole cache plus its open block, once
+        # (the owed block's positions are part of that cache): two forwards
+        # at length 16, two at 20
+        assert sum(r["kv_tokens"] for r in dec) == 2 * 20 + 2 * 24
         # and the kernel walks whole 16-token pool blocks: two of them
-        assert sum(r["kv_grid_tokens"] for r in dec) == 6 * 32
+        assert sum(r["kv_grid_tokens"] for r in dec) == 4 * 32
+    finally:
+        f.close()
+
+
+# ---- ISSUE 33: the position model in forwards, the fused commit ---------------
+
+@functools.lru_cache(None)
+def _step():
+    return jax.jit(functools.partial(P.diffusion_step, get_model_config("test-sdar-tiny")))
+
+
+@pytest.mark.parametrize("budget", [1, 4, 6, 13])
+@pytest.mark.parametrize("steps", [1, 2, 4])
+@pytest.mark.parametrize("head", [0, 1, 2, 3])
+def test_position_model_against_a_replay_of_the_devices_state(head, steps, budget):
+    """`_blk_plan` / `_blk_at` say, without a fetch, what the device's own
+    state says forward by forward: the open block's position, whether the
+    forward carries an owed block, the masks it reveals, and the row's last
+    forward (`_host_end`)."""
+    cfg, base = get_model_config("test-sdar-tiny"), 8
+    host = types.SimpleNamespace(
+        _blk=B, _blk_base=np.array([base]), _blk_first=np.ones(1, np.int64),
+        _blk_later=np.ones(1, np.int64), _blk_skip=np.zeros(1, np.int64),
+        _host_pos=np.zeros(1, np.int64), _host_end=np.zeros(1, np.int64))
+    ContinuousEngine._blk_plan(host, 0, head, B // steps, budget)
+    state, sparams = G.init_slots(1, cfg.vocab_size)
+    state = state._replace(pos=jnp.asarray([base], jnp.int32), active=jnp.asarray([True]),
+                           remaining=jnp.asarray([budget], jnp.int32))
+    mk = cfg.mask_token_id
+    diff = P.init_diffusion(cfg, 1)._replace(
+        open=jnp.asarray([[7] * head + [mk] * (B - head)], jnp.int32),
+        skip=jnp.asarray([head], jnp.int32), reveal=jnp.asarray([B // steps], jnp.int32))
+    logits = jnp.zeros((1, B, cfg.vocab_size)).at[:, :, 17].set(1.0)
+    f = delivered = 0
+    while bool(state.active[0]):
+        at, owed, shown = (a[0] for a in ContinuousEngine._blk_at(host, np.array([f])))
+        assert (int(state.pos[0]), bool(diff.owe[0])) == (at, owed), f
+        masks = int((np.asarray(diff.open) == mk).sum())
+        was = int(state.pos[0])
+        state, diff, _, ok = _step()(state, sparams, diff, logits, jax.random.PRNGKey(f))
+        left = 0 if int(state.pos[0]) > was else int((np.asarray(diff.open) == mk).sum())
+        assert masks - left == shown, f
+        delivered += int(np.asarray(ok).sum())
+        f += 1
+    assert f == host._host_end[0] and delivered == budget
+    # past the last forward the model's length is the last block's end, and
+    # the device owes nothing: the row's last block is never committed
+    assert ContinuousEngine._blk_at(host, host._host_end)[0][0] == int(state.pos[0])
+    assert not bool(diff.owe[0])
+
+
+with open(os.path.join(os.path.dirname(__file__), "data",
+                       "sdar_three_forward_tokens.json")) as _f:
+    THREE_FORWARD = json.load(_f)
+
+
+@pytest.mark.parametrize("steps", [1, 2, 4])
+def test_closed_loop_serves_the_three_forward_forms_tokens_in_fewer_forwards(steps):
+    """Four clients for two slots, each sending its next request when the
+    last returned: the queue is never empty, so slots are re-let by the
+    position model alone; no launch carries a row past its last forward;
+    the commits ride (blocks - 1 a request); and the tokens are what the
+    parent's three-forward form served."""
+    f = Fleet(impl="xla", steps=steps)
+    try:
+        recs, orig = [], f.ce._launch_record
+
+        def keep(*a, **kw):
+            recs.append(orig(*a, **kw))
+            return recs[-1]
+
+        f.ce._launch_record = keep
+        asks = [(prompt_ids(n, salt=THREE_FORWARD["salt"]), mt)
+                for n, mt in THREE_FORWARD["asks"]]
+        got = [None] * len(asks)
+
+        def client(mine):
+            for i in mine:
+                got[i] = f.ask_all([(*asks[i], {})])[0]["ids"]
+
+        ts = [threading.Thread(target=client, args=(range(c, len(asks), 4),))
+              for c in range(4)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(600)
+        assert got == THREE_FORWARD["tokens"][str(steps)]
+        assert not f.disagreements, f.disagreements[:5]
+        released = _series(f.eng, "dli_slot_release_total")
+        assert released == {(("by", "model"),): len(asks)}, released
+        blocks = [-(-(len(ids) % B + mt) // B) for ids, mt in asks]
+        forwards = [-(-(B - len(ids) % B) // (B // steps)) + (n - 1) * steps
+                    for (ids, _), n in zip(asks, blocks)]
+        kinds = _series(f.eng, "dli_diffusion_row_forwards_total")
+        assert kinds[(("kind", "denoise"),)] == sum(forwards)  # three-forward: + sum(blocks)
+        assert kinds[(("kind", "commit"),)] == 0
+        fused = _series(f.eng, "dli_diffusion_fused_commits_total")[()]
+        assert fused == sum(blocks) - len(asks) == sum(r["fused_rows"] for r in recs)
+        # a mixed launch holds an entry for every decode row it is handed:
+        # each is alive by the position model (none past its `_host_end`)
+        mixed = [r for r in recs if r["phase"] == "mixed"]
+        assert mixed and all(r["denoise_rows"] == r["decode_rows"] for r in mixed)
     finally:
         f.close()

@@ -547,13 +547,14 @@ def test_latent_step_programs_carry_the_names_a_trace_is_read_by(
             assert scope in stacks, (module, scope)
 
 
-@pytest.mark.parametrize("tq", [4, 8], ids=["decode-chunk", "mixed-step"])
+@pytest.mark.parametrize("tq", [4, 8], ids=["open-block", "owed-and-open"])
 def test_ragged_kernel_compiles_with_the_block_mask(
     one_chip, no_persistent_cache, monkeypatch, tq
 ):
     """sdar-batch's attention: 4 KV heads of 128, 128-token blocks, the
-    block-diffusion mask static in the kernel; a decode chunk's tiles are one
-    open block (4 queries), a mixed step's the scheduler's 8."""
+    block-diffusion mask static in the kernel; a tile of 8 is a row's owed
+    and open blocks in both step programs (ISSUE 33), a tile of 4 the open
+    block alone (PR 32's decode chunk)."""
     from distributed_llm_inference_tpu.ops.paged_attention import ragged_paged_attend
 
     monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
@@ -575,8 +576,9 @@ def test_block_diffusion_step_programs_carry_the_names_a_trace_is_read_by(
 ):
     """sdar-30b-a3b-chat (cut to 2 layers) at sdar-batch's sizes: both step
     programs compile for the chip, keep their module names, read the pool
-    through the ragged kernel (a decode row is its open block) and run the
-    routed experts' grouped kernel under the scopes kanana's do."""
+    through the ragged kernel (a decode row is one tile of 8: its owed and
+    open blocks, 32 slots x 8 = 256 flat tokens in the decode chunk) and run
+    the routed experts' grouped kernel under the scopes kanana's do."""
     import json
     import os
     import re
@@ -602,15 +604,19 @@ def test_block_diffusion_step_programs_carry_the_names_a_trace_is_read_by(
         cfg, params, state, pool, table, key, sparams, num_steps=2, diff=diff,
     ).compile().as_text()
     width = (slots + 1) * tile
-    entries = [(b, 0, 4, EP.RAGGED_PREFILL) for b in range(slots)]
+    # half the rows carry their owed block in front of the open one
+    owing = [b % 2 == 0 for b in range(slots)]
+    entries = [(b, 0, 8 if owe else 4, EP.RAGGED_PREFILL)
+               for b, owe in zip(range(slots), owing)]
     meta, tok_row, tok_pos, offsets, _ = EP.build_ragged_meta(
         entries, width=width, tile=tile)
-    dev = EP.DeviceMeta(*(
-        S(a.shape, a.dtype) for a in EP.build_device_meta(
-            entries, offsets, slots, width=width, tile=tile)))
+    assert width == 264 and len(offsets) == slots  # one tile a row, as before
+    *dev, open_at = EP.build_block_meta(
+        entries, offsets, owing, block=4, width=width, tile=tile)
+    assert open_at[:2] == [4, 8]
+    dev = EP.DeviceMeta(*(S(a.shape, a.dtype) for a in dev))
     arm = place(jax.eval_shape(lambda: EP.idle_mixed_arm(slots, cfg.vocab_size)))
-    darm = EP.DiffState(S((slots, 4), jnp.int32), S((slots,), jnp.int32),
-                      S((slots,), jnp.int32))
+    darm = diff
     flat = lambda a: S(np.shape(a), np.asarray(a).dtype)  # noqa: E731
     mixed = EP.mixed_step_ragged.lower(
         cfg, params, S((width,), jnp.int32), flat(tok_row), flat(tok_pos),
@@ -623,6 +629,8 @@ def test_block_diffusion_step_programs_carry_the_names_a_trace_is_read_by(
         trace = json.load(f)["serving"]["trace"]
     assert set(trace["expert_kernels"]) == EXPERT_KERNELS
     assert set(trace["step_modules"]) == {"decode_slots_paged", "mixed_step_ragged"}
+    # the decode chunk's flat axis: 32 slots x 2 blocks of 4 = 256 tokens
+    assert "bf16[256,2048]" in chunk
     for module, text in (("decode_slots_paged", chunk), ("mixed_step_ragged", mixed)):
         assert module in _module_name(text)
         calls = _custom_call_names(text)
