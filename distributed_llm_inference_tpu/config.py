@@ -25,7 +25,7 @@ class ModelConfig:
     """
 
     name: str = "tinyllama-1.1b"
-    arch: str = "llama"  # "llama" | "gpt2" | "mla_moe"
+    arch: str = "llama"  # "llama" | "gpt2" | "mla_moe" | "lfm2"
     vocab_size: int = 32000
     dim: int = 2048
     n_layers: int = 22
@@ -131,6 +131,23 @@ class ModelConfig:
     n_shared_experts: int = 0
     first_k_dense: int = 0
     routed_scaling: float = 1.0
+    # How routed experts are scored (models/experts.route): "sigmoid" (the
+    # n_experts_per_tok largest of score + selection bias are chosen, the
+    # scores alone weigh) or "softmax" (no bias). None takes the family's:
+    # sigmoid for mla_moe and lfm2, softmax for the llama family.
+    router_score: Optional[str] = None
+    # added to the sum of the chosen scores before they are renormalized
+    # (lfm2's modelling code: 1e-6; the other routed families add nothing)
+    router_norm_eps: float = 0.0
+    # Gated short convolutions beside attention (arch "lfm2",
+    # models/lfm2.py: LFM2-24B-A2B). layer_types names each layer's
+    # operator, "conv" or "full_attention"; a conv layer keeps the last
+    # conv_kernel - 1 rows of its gated input as recurrent state, a row
+    # and layer (engine/paged.py: a live leaf a slot, a tail a block), and
+    # only the attention layers own K/V. The first first_k_dense layers
+    # carry a dense SwiGLU of ffn_dim, the others routed experts.
+    layer_types: Optional[tuple] = None
+    conv_kernel: int = 0
     # Generation by block diffusion (SDAR): 0 = autoregressive. > 0: the
     # sequence is cut into blocks of this many tokens at absolute positions;
     # a token attends every position up to the END of its own block; a
@@ -184,6 +201,18 @@ class ModelConfig:
     chat_template: Optional[str] = None
 
     def __post_init__(self):
+        if self.router_score is None:
+            object.__setattr__(
+                self, "router_score",
+                "sigmoid" if self.arch in ("mla_moe", "lfm2") else "softmax",
+            )
+        if self.router_score not in ("sigmoid", "softmax"):
+            raise ValueError(
+                f"router_score must be 'sigmoid' or 'softmax', got "
+                f"{self.router_score!r}"
+            )
+        if self.layer_types is not None:  # (a JSON override brings a list)
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
         if self.attn_impl not in ("xla", "pallas"):
             raise ValueError(f"attn_impl must be 'xla' or 'pallas', got {self.attn_impl!r}")
         if self.act not in ("silu", "gelu_tanh"):
@@ -277,6 +306,26 @@ class ModelConfig:
                     "arch 'mla_moe' needs n_experts, moe_ffn_dim and "
                     "first_k_dense < n_layers (an expert stack)"
                 )
+        if self.arch == "lfm2":
+            kinds = self.layer_types or ()
+            if (len(kinds) != self.n_layers or "full_attention" not in kinds
+                    or set(kinds) - {"conv", "full_attention"}):
+                raise ValueError(
+                    f"arch 'lfm2' needs layer_types: n_layers "
+                    f"({self.n_layers}) entries of 'conv' / "
+                    f"'full_attention', at least one of them attention; "
+                    f"got {kinds!r}"
+                )
+            if self.conv_kernel < 2:
+                raise ValueError("arch 'lfm2' needs conv_kernel >= 2")
+            if not (self.n_experts and self.moe_ffn_dim
+                    and 0 <= self.first_k_dense < self.n_layers):
+                raise ValueError(
+                    "arch 'lfm2' needs n_experts, moe_ffn_dim and "
+                    "first_k_dense < n_layers (an expert stack)"
+                )
+        elif self.layer_types is not None or self.conv_kernel:
+            raise ValueError("layer_types / conv_kernel are arch 'lfm2' only")
         if self.diffusion_block:
             if self.arch != "llama" or self.mask_token_id is None:
                 raise ValueError(
@@ -288,7 +337,7 @@ class ModelConfig:
         if self.moe_ffn_dim and not self.n_experts:
             raise ValueError("moe_ffn_dim > 0 needs n_experts > 0")
         if self.n_experts:
-            if self.arch not in ("llama", "mla_moe"):
+            if self.arch not in ("llama", "mla_moe", "lfm2"):
                 raise ValueError("MoE (n_experts > 0) is llama-family only")
             if not 1 <= self.n_experts_per_tok <= self.n_experts:
                 raise ValueError(
@@ -301,9 +350,30 @@ class ModelConfig:
         return self.head_dim_override or self.dim // self.n_heads
 
     @property
-    def router_score(self) -> str:
-        """How routed experts are scored (models/experts.route)."""
-        return "sigmoid" if self.arch == "mla_moe" else "softmax"
+    def conv_layers(self) -> tuple:
+        """The model's layers that are gated short convolutions (their
+        index in the stack); () outside arch 'lfm2'."""
+        return tuple(i for i, kind in enumerate(self.layer_types or ())
+                     if kind == "conv")
+
+    @property
+    def attn_layers(self) -> tuple:
+        """The layers that own K/V: every layer, or arch 'lfm2''s
+        attention layers."""
+        if self.layer_types is None:
+            return tuple(range(self.n_layers))
+        return tuple(i for i, kind in enumerate(self.layer_types)
+                     if kind == "full_attention")
+
+    @property
+    def kv_pack(self) -> int:
+        """K/V heads the paged pool stores side by side on one 128-lane
+        row (arch 'lfm2', head dim 64: 2), so that the paged kernels write
+        in place (ops/paged_attention.writes_in_place); 1: a head a row."""
+        if self.arch != "lfm2" or 128 % self.head_dim:
+            return 1
+        pack = 128 // self.head_dim
+        return pack if self.n_kv_heads % pack == 0 else 1
 
     @property
     def latent_dim(self) -> int:

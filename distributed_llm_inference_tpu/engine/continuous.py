@@ -118,8 +118,9 @@ import numpy as np
 from ..utils import faults
 from ..utils.logging import get_logger
 from ..utils.metrics import (
-    ADMISSION_WAIT_HELP, DEFAULT_SIZE_BUCKETS, DIFFUSION_FORWARDS_HELP,
-    DIFFUSION_FUSED_HELP, DIFFUSION_TOKENS_HELP, SLOT_RELEASE_HELP,
+    ADMISSION_WAIT_HELP, CONV_STATE_RESETS_HELP, CONV_TAIL_WRITES_HELP,
+    DEFAULT_SIZE_BUCKETS, DIFFUSION_FORWARDS_HELP, DIFFUSION_FUSED_HELP,
+    DIFFUSION_TOKENS_HELP, PREFIX_STATE_TOKENS_HELP, SLOT_RELEASE_HELP,
     SLOT_TURNOVER_HELP,
     STEPS_AHEAD_BUCKETS,
 )
@@ -300,11 +301,16 @@ class ContinuousEngine:
         restore_dir: Optional[str] = None,
     ):
         cfg = engine.cfg
-        if cfg.arch not in ("llama", "gpt2", "mla_moe"):
+        if cfg.arch not in ("llama", "gpt2", "mla_moe", "lfm2"):
             raise ValueError(
-                f"continuous batching supports the llama, gpt2 and mla_moe "
-                f"families; model arch is {cfg.arch!r}"
+                f"continuous batching supports the llama, gpt2, mla_moe and "
+                f"lfm2 families; model arch is {cfg.arch!r}"
             )
+        if cfg.conv_layers:
+            # (before the dense fleet would be built for it)
+            from .paged import refuse_unsupported_latent
+
+            refuse_unsupported_latent(cfg, no_pool=kv_pool_blocks is None)
         if cfg.latent_dim and kv_pool_blocks is None:
             raise ValueError(
                 f"{cfg.name}: a latent-attention model's fleet is paged "
@@ -423,9 +429,7 @@ class ContinuousEngine:
                     f"or shrink slot_max_seq"
                 )
             self._pool_blocks = int(kv_pool_blocks)
-            self.cache = self.backend.init_paged_pool(
-                self._pool_blocks, self.kv_block_size
-            )
+            self.cache = self._init_pool()
             self._alloc = P.BlockAllocator(
                 self._pool_blocks, registry=engine.metrics
             )
@@ -515,6 +519,21 @@ class ContinuousEngine:
         self._spec_k_max = max(0, int(getattr(ecfg, "spec_draft_len", 0)))
         self._spec_auto = bool(getattr(ecfg, "spec_decode", False))
         self._spec_capable = bool(self._chunked and self._spec_k_max > 0)
+        # A model with recurrent layers (cfg.conv_layers, models/lfm2.py)
+        # keeps a state a slot and a state tail a block in the pool
+        # (engine/paged.init_pool, StateRows). The host's part: a tenant's
+        # first prefill chunk is launched as RAGGED_FIRST, so the program
+        # starts the row from zeros or from the tail under a prefix hit,
+        # never from what the slot's previous tenant left; no row is
+        # drafted for (a rejected token would already be in the state).
+        self._recurrent = bool(cfg.conv_layers)
+        if self._recurrent:
+            self._P.refuse_unsupported_latent(
+                cfg, spec=self._spec_auto
+                or bool(getattr(ecfg, "spec_draft_model", None)),
+            )
+            self._spec_k_max, self._spec_auto = 0, False
+            self._spec_capable = False
         # Generation by diffusion over blocks (cfg.diffusion_block > 0,
         # engine/paged.DiffState): a decode row is its open block, a step
         # is one forward, and every forward reveals some of the block's
@@ -1035,6 +1054,15 @@ class ContinuousEngine:
         ).labels()
         self._m_diff_tokens = m.counter(
             "dli_diffusion_tokens_total", DIFFUSION_TOKENS_HELP,
+        ).labels()
+        self._m_state_tokens = m.counter(
+            "dli_prefix_state_tokens_total", PREFIX_STATE_TOKENS_HELP,
+        ).labels()
+        self._m_conv_resets = m.counter(
+            "dli_conv_state_resets_total", CONV_STATE_RESETS_HELP,
+        ).labels()
+        self._m_conv_tails = m.counter(
+            "dli_conv_tail_writes_total", CONV_TAIL_WRITES_HELP,
         ).labels()
         self._m_steps_ahead = m.histogram(
             "dli_launch_steps_ahead",
@@ -1831,9 +1859,7 @@ class ContinuousEngine:
         half-executed donation chain left them intact. The dense prefix
         cache keeps its snapshots (standalone arrays, never donated)."""
         if self.paged:
-            self.cache = self.backend.init_paged_pool(
-                self._pool_blocks, self.kv_block_size
-            )
+            self.cache = self._init_pool()
             self._table = np.zeros(
                 (self.n_slots, self._max_blocks), np.int32
             )
@@ -1860,6 +1886,14 @@ class ContinuousEngine:
             self._dpool = self._P.init_pool(
                 self._dcfg, self._pool_blocks, self.kv_block_size
             )
+
+    def _init_pool(self):
+        """The fleet's zeroed pool; with a state a slot where the model has
+        recurrent layers (engine/paged.init_pool)."""
+        state = {"n_slots": self.n_slots} if self.cfg.conv_layers else {}
+        return self.backend.init_paged_pool(
+            self._pool_blocks, self.kv_block_size, **state
+        )
 
     def _shadow_capture(self, req: _Request, written: Optional[int] = None):
         """Hand req's newly FILLED pool blocks to the shadow copier
@@ -2921,6 +2955,21 @@ class ContinuousEngine:
         return at - owed * self._blk, (1 + owed) * self._blk
 
     # -- the launch record (ISSUE 24) -----------------------------------------
+    def _state_fields(self, spans, restored: int = 0, resets: int = 0):
+        """The launch record's fields of a fleet with recurrent layers, by
+        the host position model. spans: (first position, tokens) of every
+        live row of the launch; a token that fills its block's last
+        position leaves the block's state tail (`conv_tail_writes`, blocks,
+        not x layers). restored / resets: what the tenants whose first
+        chunk rides this launch start from: prompt tokens below a restored
+        tail, slots let with zeroed state."""
+        bs = self.kv_block_size
+        tails = sum((st + n) // bs - st // bs for st, n in spans)
+        self._m_conv_tails.inc(tails)
+        return {"conv_tail_writes": int(tails),
+                "state_restored_tokens": int(restored),
+                "conv_state_resets": int(resets)}
+
     def _launch_record(self, phase: str, steps: int, kv_tokens: int,
                        kv_grid_tokens: int, row_steps: int,
                        **fields) -> dict:
@@ -3076,6 +3125,11 @@ class ContinuousEngine:
             # its open block
             diff_fields = self._blk_fields(at, alive, K)
             at, span = self._blk_reads(at)
+        if self._recurrent:
+            diff_fields = self._state_fields(
+                [(int(self._host_pos[b]), int(live[b]))
+                 for b in np.flatnonzero(live)]
+            )
         rec = self._launch_record(
             "chunk", K,
             kv_tokens=np.sum(self._kv_span(at, span) * alive),
@@ -3594,6 +3648,14 @@ class ContinuousEngine:
         req.prefix_hit_tokens = p0
         if p0:
             self._m_ragged_exact.inc()
+        if self._recurrent:
+            # the tail of the last shared block gives the state at p0
+            # back, so every token of the hit is restored; a cold start
+            # lets the slot with zeroed state
+            if p0:
+                self._m_state_tokens.inc(p0)
+            else:
+                self._m_conv_resets.inc()
         rp = float(k.get("repetition_penalty", 1.0))
         presence_row = (
             np.asarray(eng._presence_rows([ids])[0]) if rp != 1.0
@@ -3852,7 +3914,11 @@ class ContinuousEngine:
         chunk_list = []
         for job, n in plan:
             start = job.p0 + job.done
-            entries.append((job.slot, start, n, P.RAGGED_PREFILL))
+            first = self._recurrent and job.done == 0
+            entries.append((
+                job.slot, start, n,
+                P.RAGGED_FIRST if first else P.RAGGED_PREFILL,
+            ))
             chunk_list.append((job, n, start))
         meta, tok_row, tok_pos, offsets, stats = P.build_ragged_meta(
             entries, width=W, tile=tile,
@@ -4025,6 +4091,15 @@ class ContinuousEngine:
             rode = np.zeros((B,), bool)
             rode[active] = True
             diff_fields = self._blk_fields(fwd, rode & alive_now, 1)
+        if self._recurrent:
+            firsts = [(job, st) for job, _, st in chunk_list if st == job.p0]
+            diff_fields = self._state_fields(
+                [(int(self._host_pos[b]), 1) for b in active
+                 if self._host_pos[b] < self._host_end[b]]
+                + [(st, n) for _, n, st in chunk_list],
+                restored=sum(st for _, st in firsts),
+                resets=sum(1 for _, st in firsts if st == 0),
+            )
         rec = self._launch_record(
             "mixed", 1,
             kv_tokens=sum(

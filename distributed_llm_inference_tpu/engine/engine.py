@@ -182,12 +182,14 @@ class SingleDeviceBackend:
     # routes through llama.default_attn_hook since round 5).
     @property
     def supports_paged(self):
-        return self.cfg.arch in ("llama", "gpt2", "mla_moe")
+        return self.cfg.arch in ("llama", "gpt2", "mla_moe", "lfm2")
 
-    def init_paged_pool(self, n_blocks, block_size):
+    def init_paged_pool(self, n_blocks, block_size, n_slots=None):
+        # n_slots: a model with recurrent layers keeps a state a slot
+        # beside the blocks (engine/paged.init_pool)
         from . import paged as P
 
-        return P.init_pool(self.cfg, n_blocks, block_size)
+        return P.init_pool(self.cfg, n_blocks, block_size, n_slots=n_slots)
 
     def insert_slot_paged(self, pool, scratch, state, sparams, slot,
                           table_row, *args):
@@ -1146,6 +1148,16 @@ class InferenceEngine:
         """
         t_start = time.time()
         trace = _trace if _trace is not None else Trace(request_id)
+        if self.cfg.conv_layers:
+            # a row's recurrent state lives in the paged fleet's pool
+            return {
+                "error": f"Error: {self.cfg.name} keeps a recurrent state "
+                "a row and is served by the continuous engine "
+                "(--continuous N --kv-pool-blocks M) only: no seed / debug "
+                "/ logprobs / logit_bias / beams / constraint / speculative "
+                "contract",
+                "status": "failed", "error_type": "invalid_request",
+            }
         if self.cfg.diffusion_block:
             # this loop decodes one token a forward; a block-diffusion
             # model is served by the continuous engine's paged fleet

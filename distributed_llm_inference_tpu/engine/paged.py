@@ -85,7 +85,7 @@ TRASH_BLOCK = 0  # reserved pool block: write-only spill for table tails
 
 
 def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
-              n_layers: Optional[int] = None):
+              n_layers: Optional[int] = None, n_slots: Optional[int] = None):
     """Zeroed block pool, stacked on the layer axis like the dense cache.
     Block 0 is the reserved trash block (never allocated to a slot).
     With cfg.kv_quant the pool leaves are KVQuant pytrees — int8 blocks
@@ -102,7 +102,37 @@ def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
     Allocator, block tables and prefix digests do not see the difference.
     The "routed" leaf [2, L_moe, E] int32 is not cache: the step programs
     zero it and the expert layers add what they routed, so the counts
-    leave the device in the launch's one packed fetch (pack_routed)."""
+    leave the device in the launch's one packed fetch (pack_routed).
+
+    A model with recurrent layers (cfg.conv_layers, models/lfm2.py) keeps
+    two kinds of cache in the one pool. "k" / "v" have the ATTENTION
+    layers' depth alone, with cfg.kv_pack K/V heads side by side on a row
+    ([La, N, KV / pack, bs, pack x Dh]: head dim 64 on whole 128-lane
+    tiles, `ops/paged_attention.writes_in_place`). Beside them, for the
+    Lc convolution layers: "conv" [Lc, n_slots, K-1, D], a SLOT's live
+    state (the row's last K-1 gated inputs; a launch reads it at a row's
+    first token and writes it at its last), and "tail" [Lc, N, K-1, D],
+    the state at the END of each pool block, written by whichever launch
+    fills the block's last position. The physical block id is the tail's
+    key, so allocator, refcounts, eviction and the prefix index carry it
+    unchanged, and a prefix hit at depth p0 (whole blocks) starts the
+    slot's state from the tail of the last shared block: `StateRows`."""
+    if cfg.conv_layers:
+        if n_slots is None:
+            raise ValueError(f"{cfg.name}: the pool holds a state a slot "
+                             f"(pass n_slots)")
+        La, Lc = len(cfg.attn_layers), len(cfg.conv_layers)
+        kv = (La, n_blocks, cfg.n_kv_heads // cfg.kv_pack, block_size,
+              cfg.head_dim * cfg.kv_pack)
+        hist, dt = (cfg.conv_kernel - 1, cfg.dim), cfg.jnp_dtype
+        return {
+            "k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt),
+            "conv": jnp.zeros((Lc, n_slots) + hist, dt),
+            "tail": jnp.zeros((Lc, n_blocks) + hist, dt),
+            "routed": jnp.zeros(
+                (2, cfg.n_layers - cfg.first_k_dense, cfg.n_experts),
+                jnp.int32),
+        }
     if cfg.latent_dim:
         from ..models.mla_moe import stack_depths
 
@@ -327,6 +357,29 @@ def refuse_unsupported_latent(cfg: ModelConfig, **asked):
             "bucketed": "the bucketed scratch prefill: served by ragged "
                         "chunked prefill only",
         })
+    if cfg.conv_layers:
+        lead = "a model with recurrent layers is served on one device " \
+               "from the paged pool by chunked ragged prefill"
+        why.update({
+            "quant": "weight quantization: ops/quant knows no expert bank "
+                     "or convolution leaf",
+            "kv_quant": "the int8 pool: heads are stored in pairs and the "
+                        "recurrent state has no scale",
+            "mesh": "pp / tp / ep / sp / dp meshes: layers of two kinds, "
+                    "the expert banks and a state a slot are not "
+                    "partitioned (parallel/partition.py)",
+            "kv_shadow": "the host shadow store (and swap preemption, /kv "
+                         "export): it copies K/V block pairs and would "
+                         "leave a block's state tail behind; pass "
+                         "--no-kv-shadow",
+            "bucketed": "the bucketed scratch prefill and the unchunked "
+                        "ragged admission: a row's state rides the mixed "
+                        "launch's slot rows only",
+            "spec": "speculative decoding: a rejected draft token would "
+                    "already be in a convolution layer's state",
+            "no_pool": "a dense slot fleet: there is no dense recurrent "
+                       "fleet (pass --kv-pool-blocks)",
+        })
     bad = [why[name] for name, value in asked.items() if value]
     if bad:
         raise ValueError(
@@ -544,6 +597,7 @@ def make_paged_hook(table: jnp.ndarray, active=None):
 
     hook.paged = True  # forward_layers carries the stacked pool (above)
     hook.live = active  # rows routed experts compute for (models/mla_moe)
+    hook.rows = functools.partial(_decode_rows, table, active)
     return hook
 
 
@@ -1015,6 +1069,48 @@ def insert_slot_paged(
 
 RAGGED_PREFILL = 0  # launch-entry kind: a prompt chunk (length >= 1)
 RAGGED_DECODE = 1  # launch-entry kind: one decode token at its own pos
+# a prompt chunk that is its tenant's FIRST: the row's recurrent state does
+# not come from the slot (models/lfm2.py; the kernels do not read the kind)
+RAGGED_FIRST = 2
+
+
+class StateRows(NamedTuple):
+    """How a paged launch's flat tokens fall into fleet rows, for layers
+    that carry a state a row (models/lfm2.conv_mix_rows): what a paged
+    hook's `rows()` returns.
+
+    A row that starts a tenant (`fresh`) takes nothing from its slot's live
+    state, which may still be the previous tenant's (a slot is let again
+    while that tenant's last launch is in flight): it starts from zeros at
+    position 0, and from the tail of block table[row, (start - 1) // bs]
+    after a prefix hit at depth `start` (whole blocks), which is the state
+    a cold prefill would have reached there."""
+
+    tok_row: jnp.ndarray  # i32 [W]: a flat token's fleet row; -1: launch
+    # padding or a row nothing reads, which touches no state
+    table: jnp.ndarray  # i32 [R, MB]: the rows' block tables
+    fresh: jnp.ndarray  # bool [R]: the row starts a tenant in this launch
+    start: jnp.ndarray  # i32 [R]: at this position (read where fresh)
+
+
+def _decode_rows(table, active) -> StateRows:
+    R = table.shape[0]
+    rows = jnp.arange(R, dtype=jnp.int32)
+    if active is not None:
+        rows = jnp.where(active, rows, -1)
+    return StateRows(rows, table, jnp.zeros((R,), bool),
+                     jnp.zeros((R,), jnp.int32))
+
+
+def _ragged_rows(table, meta, tok_row) -> StateRows:
+    R = table.shape[0]
+    first = (meta[:, 3] == RAGGED_FIRST) & (meta[:, 2] > 0)
+    row = jnp.maximum(meta[:, 0], 0)
+    far = jnp.iinfo(jnp.int32).max
+    start = jnp.full((R,), far, jnp.int32).at[row].min(
+        jnp.where(first, meta[:, 1], far))
+    fresh = start < far
+    return StateRows(tok_row, table, fresh, jnp.where(fresh, start, 0))
 
 
 def build_ragged_meta(entries, *, width: int, tile: int):
@@ -1238,6 +1334,7 @@ def make_ragged_fill_hook(table, meta, tok_row):
 
     hook.paged = True  # forward_layers carries the stacked pool
     hook.live = tok_row >= 0  # launch padding reaches no routed expert
+    hook.rows = functools.partial(_ragged_rows, table, meta, tok_row)
     return hook
 
 

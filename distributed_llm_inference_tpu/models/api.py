@@ -1,4 +1,4 @@
-"""Arch dispatch: one functional interface over the three model families.
+"""Arch dispatch: one functional interface over the four model families.
 
 The engine and pipeline runtime call these; cfg.arch picks the family
 (llama: RMSNorm/RoPE/GQA/SwiGLU, with an every-expert MoE FFN (Mixtral,
@@ -8,7 +8,10 @@ LayerNorm/learned-pos/MHA/gelu — mla_moe: latent attention, routed
 experts, a leading dense stack). llama and gpt2 share the stacked-layer
 pytree + KV-cache layout, so the pipeline partitioner and cache plumbing
 are agnostic between them; mla_moe has two stacks and a latent cache and
-serves one device only. Routed experts are one module for the two
+serves one device only; lfm2 (models/lfm2.py) alternates gated short
+convolutions with attention in one unscanned stack, keeps K/V for its
+attention layers alone and a recurrent state a row beside them, and serves
+one device from the paged pool only. Routed experts are one module for the
 families that have them (models/experts.py: `route`, `routed_ffn`, the
 grouped product): a configuration that routes serves one device, from the
 paged pool (engine/paged.refuse_unsupported_latent).
@@ -17,9 +20,9 @@ paged pool (engine/paged.refuse_unsupported_latent).
 from __future__ import annotations
 
 from ..config import ModelConfig
-from . import gpt2, llama, mla_moe
+from . import gpt2, lfm2, llama, mla_moe
 
-_FAMILIES = {"llama": llama, "gpt2": gpt2, "mla_moe": mla_moe}
+_FAMILIES = {"llama": llama, "gpt2": gpt2, "mla_moe": mla_moe, "lfm2": lfm2}
 
 
 def family(cfg: ModelConfig):

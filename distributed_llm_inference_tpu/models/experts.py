@@ -1,7 +1,8 @@
-"""Routed experts, one copy for both families that have them
+"""Routed experts, one copy for the families that have them
 (models/mla_moe.py: sigmoid scores with a selection bias beside a shared
-expert; models/llama.py with cfg.moe_ffn_dim > 0: softmax scores, the
-published SDAR / Qwen3-MoE layer).
+expert; models/lfm2.py: the same router, no shared expert; models/llama.py
+with cfg.moe_ffn_dim > 0: softmax scores, the published SDAR / Qwen3-MoE
+layer).
 
 `route` chooses n_experts_per_tok experts a token and weighs them;
 `routed_ffn` is told which experts it holds (the banks' leading axis, from
@@ -49,9 +50,11 @@ def route(cfg: ModelConfig, h, w_router, router_bias=None):
     """(chosen experts [N, k] int32, their weights [N, k] float32) for the
     normed tokens h [N, D]. The scores are float32 whatever the model's
     dtype: a near-tie decides which expert computes. cfg.router_score:
-    "sigmoid" (mla_moe: the n_experts_per_tok largest of score +
+    "sigmoid" (mla_moe, lfm2: the n_experts_per_tok largest of score +
     router_bias are chosen, the scores alone weigh) or "softmax" (the
-    llama family: the largest probabilities over all experts, no bias)."""
+    llama family: the largest probabilities over all experts, no bias).
+    Under moe_renormalize the chosen scores are divided by their sum (+
+    cfg.router_norm_eps, where the family's code adds one)."""
     logits = jnp.dot(h.astype(F32), w_router.astype(F32),
                      precision=jax.lax.Precision.HIGHEST)
     if cfg.router_score == "sigmoid":
@@ -62,7 +65,10 @@ def route(cfg: ModelConfig, h, w_router, router_bias=None):
     _, chosen = jax.lax.top_k(pick, cfg.n_experts_per_tok)
     w = jnp.take_along_axis(s, chosen, axis=-1)
     if cfg.moe_renormalize:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
+        total = jnp.sum(w, axis=-1, keepdims=True)
+        if cfg.router_norm_eps:  # (lfm2's; the others divide by the sum)
+            total = total + cfg.router_norm_eps
+        w = w / total
     return chosen.astype(jnp.int32), w * cfg.routed_scaling
 
 

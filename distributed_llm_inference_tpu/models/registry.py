@@ -171,6 +171,28 @@ register(ModelConfig(
     moe_renormalize=True, diffusion_block=4, mask_token_id=151669,
     eos_token_id=151645, bos_token_id=151643, pad_token_id=151643,
 ))
+# --- LFM2 (gated short convolutions beside GQA over routed experts;
+# LiquidAI/LFM2-24B-A2B config.json, model_type lfm2_moe: models/lfm2.py).
+# layer_types: conv conv, then (full_attention conv conv conv) nine times,
+# then full_attention conv: 30 convolution layers, 10 that own K/V. ffn_dim
+# is the width of the num_dense_layers = 2 leading dense layers. Not in
+# config.json and so assumed: the split order B | C | X and the gates'
+# places, the 1e-6 in the router's normalisation, per-head qk-norm and the
+# half-rotation (the family's modelling code), tied embeddings and the
+# special tokens (the family's published configurations).
+LFM2_24B_LAYER_TYPES = (
+    ("conv", "conv") + ("full_attention", "conv", "conv", "conv") * 9
+    + ("full_attention", "conv")
+)
+register(ModelConfig(
+    name="lfm2-24b-a2b", arch="lfm2", vocab_size=65536, dim=2048,
+    n_layers=40, n_heads=32, n_kv_heads=8, ffn_dim=11776, max_seq_len=128000,
+    norm_eps=1e-5, rope_theta=1000000.0, use_qk_norm=True,
+    tie_embeddings=True, layer_types=LFM2_24B_LAYER_TYPES, conv_kernel=3,
+    n_experts=64, n_experts_per_tok=4, moe_ffn_dim=1536, first_k_dense=2,
+    moe_renormalize=True, routed_scaling=1.0, router_norm_eps=1e-6,
+    eos_token_id=7, bos_token_id=1, pad_token_id=0,
+))
 register(ModelConfig(
     name="qwen3-8b", arch="llama", vocab_size=151936, dim=4096,
     n_layers=36, n_heads=32, n_kv_heads=8, ffn_dim=12288, max_seq_len=40960,
@@ -323,6 +345,17 @@ register(ModelConfig(
     norm_eps=1e-6, rope_theta=1000000.0, head_dim_override=16,
     use_qk_norm=True, n_experts=16, n_experts_per_tok=3, moe_ffn_dim=32,
     moe_renormalize=True, diffusion_block=4, mask_token_id=255,
+    eos_token_id=2, bos_token_id=1,
+))
+register(ModelConfig(
+    name="test-lfm2-tiny", arch="lfm2", vocab_size=256, dim=64,
+    n_layers=6, n_heads=4, n_kv_heads=2, ffn_dim=96, max_seq_len=256,
+    norm_eps=1e-5, rope_theta=1000000.0, head_dim_override=64,
+    use_qk_norm=True, tie_embeddings=True, conv_kernel=3,
+    layer_types=("conv", "full_attention", "conv", "conv", "full_attention",
+                 "conv"),
+    n_experts=8, n_experts_per_tok=2, moe_ffn_dim=32, first_k_dense=1,
+    moe_renormalize=True, router_norm_eps=1e-6,
     eos_token_id=2, bos_token_id=1,
 ))
 register(ModelConfig(

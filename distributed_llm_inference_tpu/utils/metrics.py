@@ -72,6 +72,23 @@ SLOT_TURNOVER_HELP = (
     "tenant, for rows that ended while requests were queued"
 )
 
+# fleets of a model with recurrent layers (ModelConfig.conv_layers)
+PREFIX_STATE_TOKENS_HELP = (
+    "prompt tokens of prefix hits whose recurrent state at the hit's depth "
+    "came from the last shared block's state tail (every hit of such a "
+    "fleet: the tail's prefill computes on the state a cold prefill would "
+    "have reached)"
+)
+CONV_STATE_RESETS_HELP = (
+    "slots let to a tenant with zeroed recurrent state (a cold start: no "
+    "prefix hit), whatever the previous tenant left in the slot"
+)
+CONV_TAIL_WRITES_HELP = (
+    "pool blocks whose recurrent-state tail a launch wrote (the launch "
+    "that fills the block's last position, prefill or decode), by the host "
+    "position model"
+)
+
 # block-diffusion fleets (ModelConfig.diffusion_block > 0)
 DIFFUSION_FORWARDS_HELP = (
     "row-forwards of a block-diffusion fleet by the host position model, "

@@ -645,6 +645,90 @@ def test_block_diffusion_step_programs_carry_the_names_a_trace_is_read_by(
         assert not re.search(r"copy\(.*bf16\[256,(2048,768|768,2048)\]", text), module
 
 
+# -- gated short convolutions beside head-dim-64 attention (ISSUE 34) -----------
+#
+# lfm2-24b-a2b cut to the cell's 9 layers (cellbench/configs/lfm2-24b-a2b-9l.json)
+# at lfm2-docs-long's sizes. The stack is unrolled (layers of two kinds), K/V
+# belongs to 2 of the 9 layers and stores pairs of 64-number heads side by side
+# on 128 lanes, so both paged kernels write in place; a slot's convolution
+# state and a block's state tail ride the same donated pool.
+def test_conv_hybrid_step_programs_at_cell_sizes_write_in_place(
+    one_chip, no_persistent_cache, monkeypatch
+):
+    import json
+    import os
+    import re
+
+    import numpy as np
+
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "cellbench", "configs", "lfm2-24b-a2b-9l.json")) as f:
+        config = json.load(f)
+    serving, flags = config["serving"], config["serving"]["flags"]
+    flag = lambda name: int(flags[flags.index(name) + 1])  # noqa: E731
+    slots, blocks, context = (flag("--continuous"), flag("--kv-pool-blocks"),
+                              flag("--continuous-max-seq"))
+    cfg = resolve_attn_impl(
+        get_model_config(serving["base"]).replace(dtype="bfloat16", **serving["overrides"]),
+        "pallas",
+    )
+    assert (len(cfg.conv_layers), len(cfg.attn_layers), cfg.kv_pack) == (7, 2, 2)
+    S = _spec(one_chip)
+    place = functools.partial(_placed, sharding=one_chip)
+    params = place(jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0))))
+    state, sparams = place(jax.eval_shape(lambda: G.init_slots(slots, cfg.vocab_size)))
+    pool = place(jax.eval_shape(lambda: EP.init_pool(cfg, blocks, 128, n_slots=slots)))
+    assert pool["k"].shape == (2, blocks, 4, 128, 128)  # whole 128-lane tiles
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
+    tail_bytes = pool["tail"].size * 2
+    table = S((slots, context // 128), jnp.int32)
+    key = place(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    chunk = EP.decode_slots_paged.lower(
+        cfg, params, state, pool, table, key, sparams, num_steps=16,
+    ).compile()
+    tile, width = 8, max(128, (slots + 1) * 8)
+    # (a fleet with recurrent state drafts nothing: no DeviceMeta operand)
+    entries = [(b, 0, 1, EP.RAGGED_DECODE) for b in range(slots - 1)]
+    entries.append((slots - 1, 0, 8, EP.RAGGED_FIRST))
+    meta, tok_row, tok_pos, _, _ = EP.build_ragged_meta(entries, width=width, tile=tile)
+    arm = place(jax.eval_shape(lambda: EP.idle_mixed_arm(slots, cfg.vocab_size)))
+    flat = lambda a: S(np.shape(a), np.asarray(a).dtype)  # noqa: E731
+    mixed = EP.mixed_step_ragged.lower(
+        cfg, params, S((width,), jnp.int32), flat(tok_row), flat(tok_pos),
+        S((width,), jnp.bool_), flat(meta), pool, table, state, sparams, key,
+        S((slots,), jnp.int32), arm,
+    ).compile()
+    trace = serving["trace"]
+    assert set(trace["step_modules"]) == {"decode_slots_paged", "mixed_step_ragged"}
+    assert set(trace["expert_kernels"]) == EXPERT_KERNELS
+    for module, kernel, compiled in (("decode_slots_paged", "paged_flash_attend", chunk),
+                                     ("mixed_step_ragged", "ragged_paged_attend", mixed)):
+        memory = compiled.memory_analysis()
+        print(f"{module}: temporaries {memory.temp_size_in_bytes / 1e6:.1f} MB, "
+              f"aliased {memory.alias_size_in_bytes / 1e9:.3f} GB of a "
+              f"{pool_bytes / 1e9:.3f} GB pool")
+        # the pool (K/V, the slots' state, the blocks' tails) goes in and
+        # comes out as one buffer ...
+        assert memory.alias_size_in_bytes >= pool_bytes - 2**20, (module, memory)
+        # ... and all the temporaries together are smaller than the
+        # smallest thing a copy could be of: the tails, a layer's slice of
+        # K or V (0.46 GB), an expert bank (1.6 GB)
+        assert memory.temp_size_in_bytes < 0.5 * tail_bytes, (module, memory)
+        text = compiled.as_text()
+        assert _pool_sized_instructions(text, pool) == [], module
+        assert not re.search(
+            r"copy\(.*bf16\[(8,64|512),(2048,1536|1536,2048)\]", text), module
+        assert module in _module_name(text)
+        calls = _custom_call_names(text)
+        for name in (kernel, *trace["expert_kernels"]):
+            assert any(name in c for c in calls), (module, name, sorted(calls))
+        assert kernel in trace["attention_kernels"]
+        stacks = " ".join(set(re.findall(r'op_name="([^"]*)"', text)))
+        for scope in ("conv_mix", "moe_route", "moe_dispatch", "moe_experts", "moe_combine"):
+            assert scope in stacks, (module, scope)
+
+
 def test_two_compiles_compare_equal_once_source_positions_are_out(
     one_chip, no_persistent_cache, monkeypatch
 ):

@@ -77,17 +77,20 @@ def test_the_two_readers_on_hand_made_scrapes():
 
 def test_the_manifest_gained_one_configuration_one_cell_and_two_metrics():
     man = manifest.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    assert man["configs"][-1]["name"] == CONFIG
-    assert man["configs"][-1]["reduced"] == ["num_hidden_layers"]
-    assert man["workloads"][-1] == {**man["workloads"][-1], "name": CELL, "config": CONFIG,
-                                    "traffic": "batch-closed-blocks", "chips": 1}
-    assert [m["name"] for m in man["per_layer"][-2:]] == NEW_METRICS
-    for m in man["per_layer"][-2:]:
+    # (looked up by name: later PRs append after them, ISSUE 34)
+    entry = next(c for c in man["configs"] if c["name"] == CONFIG)
+    assert entry["reduced"] == ["num_hidden_layers"]
+    cell = next(w for w in man["workloads"] if w["name"] == CELL)
+    assert cell == {**cell, "config": CONFIG, "traffic": "batch-closed-blocks", "chips": 1}
+    names = [m["name"] for m in man["per_layer"]]
+    at = names.index(NEW_METRICS[0])
+    assert names[at:at + 2] == NEW_METRICS
+    for m in man["per_layer"][at:at + 2]:
         assert m["workloads"] == [CELL] and m["moves"] == "tpot_ms_p50"
         assert m["source"] == "program_counter"
     by_name = {m["name"]: m for m in man["per_layer"]}
     for name in ("moe_ms_per_step", "moe_expert_roofline", "moe_experts_touched_pct"):
-        assert by_name[name]["workloads"] == ["kanana-docs-long", CELL]
+        assert by_name[name]["workloads"][:2] == ["kanana-docs-long", CELL]
     assert by_name["mla_attn_roofline"]["workloads"] == ["kanana-docs-long"]
     cell = manifest.Cell(man, CELL)
     assert {m["name"] for m in cell.end_to_end} == {"tpot_ms_p50", "setup_s"}
