@@ -520,12 +520,21 @@ class EngineConfig:
     # long prompt never stalls the decoding requests' TPOT. False (or a
     # non-ragged fleet) falls back to admit-then-prefill-whole.
     chunked_prefill: bool = True
-    # Per-step flat-token budget of the mixed launch (rounded up to a
-    # whole number of query tiles, and to at least one prefill tile above
-    # the decode fleet — every active slot's decode row is reserved ahead
-    # of any prefill chunk, so decode can never be starved by prefill and
-    # at least one pending prefill always progresses).
-    step_token_budget: int = 128
+    # Per-step flat-token budget of the mixed launch. None (the default)
+    # derives it from what the model streams a step
+    # (engine/scheduler.step_width, the one place a width is decided):
+    # 128 for a dense model, whose launch stops being weight-bound at
+    # 240 flat tokens on a v5e, so a wider one would only add arithmetic
+    # to every decode row's step; 512 for a model whose routed layers
+    # stream four or more experts for each one a token computes, where a
+    # step costs the bytes of the whole bank whatever it carries and a
+    # document's prefill is one pass over the bank a chunk. An explicit
+    # value is obeyed. Either is rounded up to a whole number of query
+    # tiles, and to at least one prefill tile above the decode fleet —
+    # every active slot's decode row is reserved ahead of any prefill
+    # chunk, so decode can never be starved by prefill and at least one
+    # pending prefill always progresses.
+    step_token_budget: Optional[int] = None
     # SLO classes: (name, ttft_target_s, tpot_target_s, weight,
     # sheddable). The scheduler apportions the per-step prefill budget
     # across classes by weight x urgency (urgency = queue head wait over

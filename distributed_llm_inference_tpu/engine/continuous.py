@@ -466,13 +466,16 @@ class ContinuousEngine:
         # feedback, shed decisions, and class-aware Retry-After apply to
         # admission regardless of ingest strategy); only the step
         # planning needs the mixed ragged program.
-        from .scheduler import TokenBudgetScheduler, parse_slo_classes
+        from .scheduler import (
+            TokenBudgetScheduler, parse_slo_classes, step_width,
+        )
 
         self._slo = parse_slo_classes(engine.engine_cfg)
         self._sched = TokenBudgetScheduler(
             self._slo, engine.engine_cfg.slo_default_class,
-            int(engine.engine_cfg.step_token_budget), self._ragged_tile,
-            self.n_slots, registry=engine.metrics,
+            step_width(cfg, self.n_slots, self._ragged_tile,
+                       engine.engine_cfg.step_token_budget),
+            self._ragged_tile, self.n_slots, registry=engine.metrics,
             tenant_weights=engine.engine_cfg.tenant_weights,
         )
         self._chunked = bool(
@@ -1012,6 +1015,22 @@ class ContinuousEngine:
             "decode rows carried by scheduler launches (a pure-decode "
             "chunk counts its row-steps)",
         ).labels()
+        # how far the derived launch width (engine/scheduler.step_width)
+        # engages: the width itself, set once, and per mixed launch the
+        # query tiles it was compiled for against those that carried
+        # tokens (live / launched = the share of the launch that worked)
+        self._m_sched_tiles = m.counter(
+            "dli_sched_step_tiles_total",
+            "query tiles of mixed scheduler launches: launched = the "
+            "compiled width's, live = those that carried tokens",
+            ("state",),
+        )
+        if self._chunked:
+            m.gauge(
+                "dli_sched_step_width_tokens",
+                "flat-token width of the mixed scheduler launch "
+                "(derived from the model unless step_token_budget is set)",
+            ).labels().set(self._sched_width)
         # launch-record families (ISSUE 24, pre-registered in
         # engine/engine.py): KV positions attention had to read against
         # those the kernels' block loops walked, how much work was dispatched
@@ -3007,6 +3026,9 @@ class ContinuousEngine:
             rec["kv_grid_tokens"]
         )
         self._m_steps_ahead.labels(phase=phase).observe(rec["steps_ahead"])
+        if phase == "mixed":
+            self._m_sched_tiles.labels(state="launched").inc(rec["tiles"])
+            self._m_sched_tiles.labels(state="live").inc(rec["tiles_live"])
         return rec
 
     def _kv_span(self, start, length=1):

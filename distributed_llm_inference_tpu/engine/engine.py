@@ -651,6 +651,17 @@ class InferenceEngine:
             "decode rows carried by scheduler launches (a pure-decode "
             "chunk counts its row-steps)",
         )
+        self.metrics.counter(
+            "dli_sched_step_tiles_total",
+            "query tiles of mixed scheduler launches: launched = the "
+            "compiled width's, live = those that carried tokens",
+            ("state",),
+        )
+        self.metrics.gauge(
+            "dli_sched_step_width_tokens",
+            "flat-token width of the mixed scheduler launch "
+            "(derived from the model unless step_token_budget is set)",
+        )
         # launch-record families (engine/continuous.py counts them at
         # every dispatch, mixed step or pure-decode chunk): KV positions
         # attended against walked, work dispatched ahead of a launch,

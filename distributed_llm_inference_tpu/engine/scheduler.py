@@ -12,13 +12,16 @@ that planning:
   * TOKEN-BUDGET STEPS: every scheduler step assembles ONE mixed ragged
     launch (engine/paged.mixed_step_ragged) containing a decode row for
     every active slot plus PREFILL chunks of pending admissions, sliced
-    to `engine_cfg.step_token_budget` flat tokens. Decode rows are
-    reserved FIRST (prefill can never starve decode — the TPOT
-    guarantee); the remaining query tiles are the per-step prefill
-    budget. A prompt of any length therefore costs each decode step at
-    most `budget - n_slots` extra flat tokens instead of a whole-prompt
-    stall, and TTFT degrades gracefully (the prompt lands over several
-    steps) instead of TPOT collapsing.
+    to the launch width `step_width` derives from what the model
+    streams a step (128 flat tokens for a dense model, 512 for one
+    whose routed layers stream four or more experts for each one a
+    token computes; an explicit `engine_cfg.step_token_budget` is
+    obeyed). Decode rows are reserved FIRST (prefill can never starve
+    decode — the TPOT guarantee); the remaining query tiles are the
+    per-step prefill budget. A prompt of any length therefore costs
+    each decode step at most `width - n_slots x tile` extra flat tokens
+    instead of a whole-prompt stall, and TTFT degrades gracefully (the
+    prompt lands over several steps) instead of TPOT collapsing.
   * SLO CLASSES: requests carry an `slo_class` (serving/queue.py field,
     surfaced on /generate and the OpenAI routes) with per-class TTFT /
     TPOT targets from config (engine_cfg.slo_classes). The prefill
@@ -244,6 +247,72 @@ class _ClassFeedback:
         self.samples += 1
 
 
+# The mixed launch's flat width when `engine_cfg.step_token_budget` does
+# not give one (v5e: 197 TFLOP/s bf16 over 819 GB/s = 240 flat tokens of
+# arithmetic hide under one pass over the weights they multiply).
+# DENSE: every token computes every weight the step streams, so the step
+# stops being weight-bound at 240 tokens; 128 still is and 256 would not
+# be: wider adds arithmetic to every decode row's step and saves no
+# stream.
+DENSE_STEP_TOKENS = 128
+# ROUTED (models/experts.routed_ffn): a prefill chunk of 128 tokens
+# already touches nearly every expert, so a mixed step streams the whole
+# bank for n_experts_per_tok / n_experts of its arithmetic, and a
+# document's prefill is as many passes over the bank as it has chunks
+# (lfm2-24b-a2b's cell on a v5e: 16.8 ms a mixed step of 136 flat
+# tokens, 23.0 ms at 512, so a quarter of the passes cost 1.4 times
+# each). By bytes alone 240 x total / active parameters = 1,500-1,900
+# tokens would still be weight-bound (8.0 / 6.2 / 7.0 for lfm2-24b-a2b,
+# kanana-2-30b-a3b, sdar-30b-a3b at their benchmark depths). 512, not
+# more, because past it the step's cost is no longer the banks: a
+# chunk's 8-token query tiles each re-walk the row's prefix, so the
+# ragged kernel's time grows with the width; the longest gap a
+# streaming user sees inside an answer is one mixed step; and
+# `_group_tiling`'s 128-pair tiles load an expert once a (tile, expert)
+# visit, about 80 visits a layer at 512 against 68 at 128.
+ROUTED_STEP_TOKENS = 512
+# a routed model takes the wider launch when its bank holds at least
+# this many experts for each one a token computes (16, 21 and 16 in the
+# three above)
+ROUTED_STREAM_RATIO = 4
+
+
+def _clamp_width(width: int, n_slots: int, tile: int) -> int:
+    """`width` in whole tiles, and at least one prefill tile above the
+    decode fleet: every active slot's decode row costs one tile and one
+    must remain for prefill progress (starvation freedom), so a scheduler
+    is never started that can wedge with a full fleet."""
+    clamped = -(-max(int(width), (int(n_slots) + 1) * tile) // tile) * tile
+    if clamped > width:
+        log.info(
+            "step_budget_clamped", requested=width, width=clamped,
+            reason="decode rows + one prefill tile must fit",
+        )
+    return clamped
+
+
+def step_width(model_cfg, n_slots: int, tile: int = 8,
+               budget: Optional[int] = None) -> int:
+    """Flat-token width of the mixed launch: the ONE place it is decided
+    (the engine, `/stats`, the described-chip compiles and
+    tests/dense_equal.py all ask here). An explicit `budget`
+    (`EngineConfig.step_token_budget`) is obeyed; without one the width
+    follows what a step of the model streams, read from the ModelConfig
+    alone: ROUTED_STEP_TOKENS where the FFN layers route through the
+    grouped kernels (`moe_ffn_dim`: only the chosen experts are computed)
+    and the bank is ROUTED_STREAM_RATIO times what a token computes,
+    DENSE_STEP_TOKENS otherwise. That covers the all-experts einsum of
+    `models/llama.moe_ffn` (`n_experts` without `moe_ffn_dim`), where a
+    token computes every expert it streams. The slot clamp stays on top."""
+    if budget is None:
+        wide = model_cfg.moe_ffn_dim and (
+            model_cfg.n_experts // model_cfg.n_experts_per_tok
+            >= ROUTED_STREAM_RATIO
+        )
+        budget = ROUTED_STEP_TOKENS if wide else DENSE_STEP_TOKENS
+    return _clamp_width(budget, n_slots, tile)
+
+
 class TokenBudgetScheduler:
     """Pure host-side planner: slices the per-step flat-token budget into
     decode rows + class-apportioned prefill chunks, and answers the
@@ -269,17 +338,7 @@ class TokenBudgetScheduler:
         # (the tenant population is open-ended, unlike the class set)
         self.tenant_feedback: dict = {}
         self.tile = int(tile)
-        # every active slot's decode row costs one tile, and at least one
-        # tile must remain for prefill progress (starvation freedom) —
-        # clamp the width up instead of starting a scheduler that can
-        # wedge with a full fleet
-        min_width = (int(n_slots) + 1) * self.tile
-        self.width = -(-max(int(width), min_width) // self.tile) * self.tile
-        if self.width > width:
-            log.info(
-                "step_budget_clamped", requested=width, width=self.width,
-                reason="decode rows + one prefill tile must fit",
-            )
+        self.width = _clamp_width(width, n_slots, self.tile)
         self.n_slots = int(n_slots)
         self.feedback = {name: _ClassFeedback() for name in classes}
         # summary of the most recent non-empty plan() — the flight

@@ -148,6 +148,7 @@ def programs(model, slots, blocks, context, block_size=128, tile=8) -> dict:
     from distributed_llm_inference_tpu.config import resolve_attn_impl
     from distributed_llm_inference_tpu.engine import generate as G
     from distributed_llm_inference_tpu.engine import paged as P
+    from distributed_llm_inference_tpu.engine.scheduler import step_width
     from distributed_llm_inference_tpu.models import api as M
     from distributed_llm_inference_tpu.models.registry import get_model_config
 
@@ -169,7 +170,7 @@ def programs(model, slots, blocks, context, block_size=128, tile=8) -> dict:
     key = place(lambda: jax.random.PRNGKey(0))
     chunk = P.decode_slots_paged.lower(
         cfg, params, state, pool, table, key, sparams, num_steps=16)
-    width = max(128, (slots + 1) * tile)
+    width = step_width(cfg, slots, tile)  # what the server launches
     entries = [(b, 0, 1, P.RAGGED_DECODE) for b in range(slots)]
     meta, tok_row, tok_pos, offsets, _ = P.build_ragged_meta(
         entries, width=width, tile=tile)

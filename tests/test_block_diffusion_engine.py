@@ -199,10 +199,14 @@ def test_served_in_bfloat16_stays_near_the_references_choice(impl):
     assert m.mean() < 0.25 and (m > 0).mean() < 0.45, (m.mean(), (m > 0).mean())
 
 
-def test_chunked_prefill_equals_whole_prefill():
+@pytest.mark.parametrize("budget", [256, None], ids=["256", "derived"])
+def test_chunked_prefill_equals_whole_prefill(budget):
+    """At an explicit 256 and at the width derived from the model (a routed
+    bank of five experts to one computed: 512, engine/scheduler.step_width)."""
     asks = [(prompt_ids(n), mt, {}) for n, mt in ASKS]
     a = fleet(impl="xla", steps=2).ask_all(asks)
-    whole = fleet(impl="xla", steps=2, budget=256)
+    whole = fleet(impl="xla", steps=2, budget=budget)
+    assert whole.ce.stats()["scheduler"]["step_width"] == (budget or 512)
     b = whole.ask_all(asks)
     assert [r["ids"] for r in a] == [r["ids"] for r in b]
     m = np.concatenate([whole.margins(ids, r) for (ids, _, _), r in zip(asks, b)])

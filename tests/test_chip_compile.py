@@ -28,6 +28,7 @@ from jax.sharding import SingleDeviceSharding
 from distributed_llm_inference_tpu.config import resolve_attn_impl
 from distributed_llm_inference_tpu.engine import generate as G
 from distributed_llm_inference_tpu.engine import paged as EP
+from distributed_llm_inference_tpu.engine.scheduler import step_width
 from distributed_llm_inference_tpu.models import api as M
 from distributed_llm_inference_tpu.models.registry import get_model_config
 from distributed_llm_inference_tpu.ops import quant as Q
@@ -176,13 +177,15 @@ def test_paged_flash_attend_compiles_at_cell_shapes(
 def test_ragged_paged_attend_compiles_at_cell_shapes(
     one_chip, no_persistent_cache, cell, quant
 ):
-    """The mixed step's kernel: max(128, (slots + 1) x 8) flat tokens in
-    query tiles of 8 (16 tiles for olmo2's 12 slots, 17 for mistral's 16)."""
+    """The mixed step's kernel: `step_width` flat tokens (a dense model's
+    128, or one tile above the fleet) in query tiles of 8 (16 tiles for
+    olmo2's 12 slots, 17 for mistral's 16)."""
     slots, h, kv, dh, bs, mb, blocks, window = CELL_SHAPES[cell]
     S = _spec(one_chip)
     pool = _kv(S, (blocks, kv, bs, dh), quant)
     tq = 8
-    tiles = max(128, (slots + 1) * tq) // tq
+    tiles = step_width(get_model_config(CELL_PROGRAMS[cell][0]), slots, tq) // tq
+    assert tiles == {"olmo2-7b-16l": 16, "mistral-7b-16l": 17}[cell]
     text = _compile(
         functools.partial(ragged_paged_attend, interpret=False, window=window),
         S((tiles * tq, h, dh), jnp.bfloat16), pool, pool,
@@ -363,6 +366,8 @@ CELL_PROGRAMS = {
     "mistral-7b-16l": ("mistral-7b", 16, 16, 271, 6400),
     "kanana-2-30b-a3b-7l": ("kanana-2-30b-a3b", 7, 8, 1750, 32768),
 }
+CELL_WIDTHS = {"olmo2-7b-16l": 128, "mistral-7b-16l": 136,
+               "kanana-2-30b-a3b-7l": 512}
 # instructions that make no buffer of their own, or are the kernels
 _NO_BUFFER = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
               "custom-call"}
@@ -410,7 +415,10 @@ def test_step_programs_hold_no_copy_of_the_pool_at_cell_sizes(
     chunk = EP.decode_slots_paged.lower(
         cfg, params, state, pool, table, key, sparams, num_steps=16,
     ).compile()
-    tile, width = 8, max(128, (slots + 1) * 8)
+    # the width the server launches (engine/scheduler.step_width): 128 /
+    # 136 for the dense two, 512 for the routed one
+    tile, width = 8, step_width(cfg, slots, 8)
+    assert width == CELL_WIDTHS[config]
     entries = [(b, 0, 1, EP.RAGGED_DECODE) for b in range(slots)]
     meta, tok_row, tok_pos, offsets, _ = EP.build_ragged_meta(
         entries, width=width, tile=tile)
@@ -455,7 +463,9 @@ LATENT_SLOTS, LATENT_BLOCKS, LATENT_CONTEXT = 8, 1750, 32768
 @pytest.mark.parametrize("tq", [1, 8])
 def test_latent_walk_compiles_at_cell_shapes(one_chip, no_persistent_cache, tq):
     S = _spec(one_chip)
-    rows, width, r = 640, 128, 512
+    rows, r = 640, 512
+    width = step_width(get_model_config("kanana-2-30b-a3b"), LATENT_SLOTS, 8)
+    assert width == 512
     pool = S((LATENT_BLOCKS, 1, 128, rows), jnp.bfloat16)
     table = S((LATENT_SLOTS, LATENT_CONTEXT // 128), jnp.int32)
     if tq == 1:
@@ -473,12 +483,13 @@ def test_latent_walk_compiles_at_cell_shapes(one_chip, no_persistent_cache, tq):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("pairs", [48, 768])
+@pytest.mark.parametrize("pairs", [48, 768, 3072])
 def test_routed_expert_matmul_compiles_at_cell_shapes(
     one_chip, no_persistent_cache, monkeypatch, pairs
 ):
-    """A decode chunk's 8 rows x 6 and a mixed step's 128 tokens x 6, over
-    the six expert layers' stacked bank (never a per-layer copy of it)."""
+    """A decode chunk's 8 rows x 6 and a mixed step's 512 tokens x 6 (128
+    until ISSUE 37), over the six expert layers' stacked bank (never a
+    per-layer copy of it)."""
     from distributed_llm_inference_tpu.models import mla_moe as MM
 
     monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
@@ -517,7 +528,8 @@ def test_latent_step_programs_carry_the_names_a_trace_is_read_by(
     chunk = EP.decode_slots_paged.lower(
         cfg, params, state, pool, table, key, sparams, num_steps=2,
     ).compile().as_text()
-    tile, width = 8, 128
+    tile, width = 8, step_width(cfg, slots, 8)
+    assert width == 512
     entries = [(b, 0, 1, EP.RAGGED_DECODE) for b in range(slots)]
     meta, tok_row, tok_pos, offsets, _ = EP.build_ragged_meta(
         entries, width=width, tile=tile)
@@ -603,14 +615,15 @@ def test_block_diffusion_step_programs_carry_the_names_a_trace_is_read_by(
     chunk = EP.decode_slots_paged.lower(
         cfg, params, state, pool, table, key, sparams, num_steps=2, diff=diff,
     ).compile().as_text()
-    width = (slots + 1) * tile
+    width = step_width(cfg, slots, tile)
     # half the rows carry their owed block in front of the open one
     owing = [b % 2 == 0 for b in range(slots)]
     entries = [(b, 0, 8 if owe else 4, EP.RAGGED_PREFILL)
                for b, owe in zip(range(slots), owing)]
     meta, tok_row, tok_pos, offsets, _ = EP.build_ragged_meta(
         entries, width=width, tile=tile)
-    assert width == 264 and len(offsets) == slots  # one tile a row, as before
+    # one tile a row, as before, in a launch of 512 (264 until ISSUE 37)
+    assert width == 512 and len(offsets) == slots
     *dev, open_at = EP.build_block_meta(
         entries, offsets, owing, block=4, width=width, tile=tile)
     assert open_at[:2] == [4, 8]
@@ -687,7 +700,8 @@ def test_conv_hybrid_step_programs_at_cell_sizes_write_in_place(
     chunk = EP.decode_slots_paged.lower(
         cfg, params, state, pool, table, key, sparams, num_steps=16,
     ).compile()
-    tile, width = 8, max(128, (slots + 1) * 8)
+    tile, width = 8, step_width(cfg, slots, 8)
+    assert width == 512
     # (a fleet with recurrent state drafts nothing: no DeviceMeta operand)
     entries = [(b, 0, 1, EP.RAGGED_DECODE) for b in range(slots - 1)]
     entries.append((slots - 1, 0, 8, EP.RAGGED_FIRST))
@@ -712,9 +726,10 @@ def test_conv_hybrid_step_programs_at_cell_sizes_write_in_place(
         # comes out as one buffer ...
         assert memory.alias_size_in_bytes >= pool_bytes - 2**20, (module, memory)
         # ... and all the temporaries together are smaller than the
-        # smallest thing a copy could be of: the tails, a layer's slice of
-        # K or V (0.46 GB), an expert bank (1.6 GB)
-        assert memory.temp_size_in_bytes < 0.5 * tail_bytes, (module, memory)
+        # smallest thing a copy could be of: the tails (0.2 GB), a layer's
+        # slice of K or V (0.46 GB), an expert bank (1.6 GB); the 512-wide
+        # mixed step's are 0.11 GB, twice the 136-wide one's
+        assert memory.temp_size_in_bytes < 0.6 * tail_bytes, (module, memory)
         text = compiled.as_text()
         assert _pool_sized_instructions(text, pool) == [], module
         assert not re.search(

@@ -10,6 +10,7 @@ start-up with a message.
 """
 
 import threading
+import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -174,6 +175,60 @@ def test_a_prefix_hit_is_exact_after_the_first_tenant_has_gone_and_after_an_evic
     finally:
         fresh.ce.close()
     assert cold["ids"] == third["ids"] and not cold.get("prefix_cached_tokens")
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_the_derived_width_serves_the_tokens_a_16_token_budget_serves(impl):
+    """The launch width is derived from what the model streams (a routed
+    bank of four experts to one computed: 512 flat tokens) and is a LAUNCH
+    shape: the same greedy tokens as at step_token_budget=16, each the
+    reference's choice, through a prefix hit's restored tail (RAGGED_FIRST at
+    64) that rides a launch beside a decoding row, over several blocks'
+    tails written by ONE chunk; and the two series count what the launch
+    records say."""
+    doc, row = prompt_ids(70, salt=9), prompt_ids(20, salt=9)
+    got = {}
+    for budget in (16, None):
+        f = Fleet(impl=impl, budget=budget)
+        try:
+            cold = f.ask_all([(doc + [11, 12, 13], 8)])[0]
+            beside, n0 = [], len(f.records)
+            t = threading.Thread(
+                target=lambda: beside.extend(f.ask_all([(row, 120)])))
+            t.start()
+            deadline = time.time() + 120
+            while True:  # the row's own prefill, then a launch that decodes it
+                new = f.records[n0:]
+                armed = [i for i, r in enumerate(new) if r["prefill_chunks"]]
+                if armed and any(r["decode_rows"] for r in new[armed[0] + 1:]):
+                    break
+                assert time.time() < deadline
+                time.sleep(0.002)
+            hit = f.ask_all([(doc + [21, 22, 23, 24], 10)])[0]
+            t.join(300)
+            width = f.ce.stats()["scheduler"]["step_width"]
+            tiles = f.series("dli_sched_step_tiles_total")
+            gauge = f.series("dli_sched_step_width_tokens")
+        finally:
+            f.ce.close()
+        assert hit["prefix_cached_tokens"] == 64 and not cold.get("prefix_cached_tokens")
+        for ids, r in ((doc + [11, 12, 13], cold), (doc + [21, 22, 23, 24], hit),
+                       (row, beside[0])):
+            assert f.margins(ids, r).max() <= 1e-4
+        got[budget] = [cold["ids"], hit["ids"], beside[0]["ids"]]
+        mixed = [r for r in f.records if r["phase"] == "mixed"]
+        assert width == (512 if budget is None else 24)  # 16: two rows + a tile
+        assert gauge == {(): width}
+        assert all(r["tiles"] == width // 8 for r in mixed)
+        restored = [r for r in mixed if r["state_restored_tokens"]]
+        assert [r["state_restored_tokens"] for r in restored] == [64]
+        assert restored[0]["decode_rows"] == 1  # beside the decoding row
+        assert tiles[(("state", "launched"),)] == sum(r["tiles"] for r in mixed)
+        assert tiles[(("state", "live"),)] == sum(r["tiles_live"] for r in mixed)
+        if budget is None:  # each prompt landed in one launch, four tails in one chunk
+            assert [r["prefill_tokens"] for r in mixed if r["prefill_chunks"]] == [73, 20, 10]
+            assert mixed[0]["conv_tail_writes"] == 4
+    assert got[None] == got[16]
 
 
 def test_the_launch_record_carries_the_states_fields():

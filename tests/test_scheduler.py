@@ -31,6 +31,7 @@ from distributed_llm_inference_tpu.engine.scheduler import (
     SLOClass,
     TokenBudgetScheduler,
     parse_slo_classes,
+    step_width,
 )
 from distributed_llm_inference_tpu.models import api as M
 from distributed_llm_inference_tpu.utils import faults
@@ -71,6 +72,47 @@ def test_width_clamps_to_fleet_plus_one_tile():
     assert s.width == (4 + 1) * TILE
     # and always whole tiles
     assert _sched(width=70, n_slots=2).width == 72
+
+
+@pytest.mark.parametrize("model,slots,width", [
+    # dense: the launch stops being weight-bound at 240 flat tokens
+    ("olmo2-7b", 12, 128),
+    ("mistral-7b", 16, 136),  # the slot clamp on top: 17 tiles
+    # routed through the grouped kernels, 16 / 21 / 16 experts streamed for
+    # each one a token computes (llama family, mla_moe, lfm2)
+    ("sdar-30b-a3b-chat", 32, 512),
+    ("kanana-2-30b-a3b", 8, 512),
+    ("lfm2-24b-a2b", 16, 512),
+    ("lfm2-24b-a2b", 80, 648),  # the slot clamp on top of a routed width
+    # the all-experts einsum computes every expert it streams for every
+    # token: dense by its arithmetic whatever n_experts / n_experts_per_tok
+    ("mixtral-8x7b", 8, 128),
+    ("qwen3-30b-a3b", 8, 128),
+    ("test-moe-tiny", 4, 128),
+    # the CI presets of the three routed families: 5, 5 and 4 to one
+    ("test-sdar-tiny", 4, 512),
+    ("test-mla-moe-tiny", 3, 512),
+    ("test-lfm2-tiny", 2, 512),
+])
+def test_step_width_is_derived_from_what_the_model_streams(model, slots,
+                                                           width):
+    cfg = get_model_config(model)
+    assert step_width(cfg, slots, TILE) == width
+    # read from the configuration's numbers, never from its name or family
+    assert step_width(cfg.replace(name="x"), slots, TILE) == width
+    # one routed expert in three is not a bank worth a wider launch
+    if cfg.moe_ffn_dim:
+        few = cfg.replace(n_experts=3 * cfg.n_experts_per_tok)
+        assert step_width(few, 4, TILE) == 128
+
+
+@pytest.mark.parametrize("model", ["test-llama-tiny", "test-lfm2-tiny"])
+@pytest.mark.parametrize("budget,slots,width", [
+    (16, 1, 16), (32, 3, 32), (64, 4, 64), (70, 2, 72), (8, 4, 40),
+    (1024, 4, 1024),
+])
+def test_an_explicit_step_token_budget_is_obeyed(model, budget, slots, width):
+    assert step_width(get_model_config(model), slots, TILE, budget) == width
 
 
 def test_budget_slicing_reserves_decode_rows():
@@ -982,3 +1024,95 @@ def test_snapshot_is_taken_on_the_host_before_the_transfer(monkeypatch):
         d = ContinuousEngine._snapshot(t)
         t[:] = 0
         assert int(np.asarray(d).sum()) == 7 * 128
+
+
+# -- the derived launch width (engine/scheduler.step_width) --------------------
+
+def _routed_fleet(budget):
+    """test-mla-moe-tiny's fleet with every launch record kept."""
+    from distributed_llm_inference_tpu import create_engine
+
+    eng = create_engine(
+        "test-mla-moe-tiny", seed=5, attn_impl="xla", dtype="float32",
+        engine_cfg=EngineConfig(prefix_cache_entries=8, kv_shadow=False,
+                                step_token_budget=budget))
+    cont = ContinuousEngine(eng, n_slots=3, chunk_steps=4, slot_max_seq=128,
+                            kv_pool_blocks=40, kv_block_size=16,
+                            restart_backoff_s=0.01)
+    records = []
+    record = cont._launch_record
+    cont._launch_record = lambda *a, **k: (
+        records.append(record(*a, **k)) or records[-1])
+    return cont, records
+
+
+def _document_beside_a_decoding_row(cont, records):
+    """A document asked cold; then, while another request decodes, asked
+    again (a prefix hit: only the tail is prefilled, beside that row)."""
+    doc = "a document of some length, asked about twice over " * 2
+    ask = dict(greedy=True, chat=False)
+    out = [cont.submit(doc + "?", max_tokens=9, **ask)]
+    row, n0 = [], len(records)
+    t = threading.Thread(target=lambda: row.append(
+        cont.submit("a row that decodes", max_tokens=100, **ask)))
+    t.start()
+    deadline = time.time() + 120
+    while True:  # the row's own prefill, then a launch that decodes it
+        new = records[n0:]
+        armed = [i for i, r in enumerate(new) if r["prefill_chunks"]]
+        if armed and any(r["decode_rows"] for r in new[armed[0] + 1:]):
+            break
+        assert time.time() < deadline
+        time.sleep(0.002)
+    out.append(cont.submit(doc + "!", max_tokens=9, **ask))
+    t.join(300)
+    assert all(r["status"] == "success" for r in out + row), (out, row)
+    return out + row
+
+
+def test_the_derived_width_serves_the_tokens_a_16_token_budget_serves():
+    """The width is a LAUNCH shape: a routed fleet at the derived 512 flat
+    tokens gives the greedy tokens it gives at step_token_budget=16, through
+    a prefix hit and beside a decoding row, and the two series count what
+    the launch records say."""
+    got = {}
+    for budget in (16, None):
+        cont, records = _routed_fleet(budget)
+        try:
+            res = _document_beside_a_decoding_row(cont, records)
+            st = cont.stats()["scheduler"]
+            snap = cont.engine.metrics.snapshot()
+        finally:
+            cont.close()
+        assert res[1]["prefix_cached_tokens"] >= 4 * 16
+        got[budget] = [r["response"] for r in res]
+        mixed = [r for r in records if r["phase"] == "mixed"]
+        width = 32 if budget else 512  # 16 clamps to 3 rows + a tile
+        assert st["step_width"] == width
+        assert all(r["tiles"] == width // TILE for r in mixed)
+        # the hit's tail rode a launch that also carried the decoding row
+        assert any(r["decode_rows"] and r["prefill_chunks"] for r in mixed)
+
+        def series(name):
+            return {tuple(sorted(s["labels"].items())): s["value"]
+                    for s in snap[name]["series"]}
+
+        assert series("dli_sched_step_width_tokens") == {(): width}
+        tiles = series("dli_sched_step_tiles_total")
+        assert tiles[(("state", "launched"),)] == sum(r["tiles"] for r in mixed)
+        assert tiles[(("state", "live"),)] == sum(
+            r["tiles_live"] for r in mixed) > 0
+        if budget is None:  # a prompt's prefill is ONE launch, not four
+            assert sum(r["prefill_chunks"] for r in mixed) == 3
+    assert got[None] == got[16]
+
+
+def test_the_engine_obeys_an_explicit_budget_and_derives_a_dense_one(setup):
+    cfg, params = setup
+    for budget, width in ((64, 64), (None, 128)):
+        cont = _cont(cfg, params, True,
+                     engine_cfg={"step_token_budget": budget})
+        try:
+            assert cont.stats()["scheduler"]["step_width"] == width
+        finally:
+            cont.close()
