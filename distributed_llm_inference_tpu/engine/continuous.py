@@ -105,6 +105,7 @@ with a WARM prefix cache (tests/test_recovery.py chaos matrix).
 from __future__ import annotations
 
 import collections
+import functools
 import json
 import os
 import threading
@@ -125,7 +126,7 @@ from ..utils.metrics import (
     STEPS_AHEAD_BUCKETS,
 )
 from ..utils.retry import overload_retry_after
-from ..utils.tracing import PhaseClock, Trace, sample_decision
+from ..utils.tracing import PhaseClock, Trace, abstract_call, sample_decision
 from . import generate as G
 from .block_prefix import chunk_digests
 
@@ -836,6 +837,9 @@ class ContinuousEngine:
         # scheduler steps dispatched and not yet fetched.
         self._launch_seq = 0
         self._steps_inflight = 0
+        # a profiler session's record of the step programs it dispatched
+        # (`trace_step_programs`); None outside one
+        self._step_calls: Optional[dict] = None
         # scheduler steps dispatched since the loop began, and per slot
         # the count at which the position model put its row's last live
         # step (None: no row ended there with a queue waiting, or the
@@ -3109,6 +3113,33 @@ class ContinuousEngine:
                 parent_id=parent, attrs=span_attrs,
             )
 
+    def trace_step_programs(self, on: bool) -> dict:
+        """The profiler session's seam (serving.server._Profiler). on: from
+        now the first dispatch of each step program keeps its abstract
+        arguments. Off: nothing is kept, and what was comes back as
+        {program: a callable that lowers it again}, from which the session's
+        end writes instruction -> scope beside the profile
+        (utils/tracing.write_program_scopes). A backend that cannot lower
+        its step programs from abstract arguments (a pp mesh's) keeps
+        nothing."""
+        lower = getattr(self.backend, "lower_step", None)
+        calls, self._step_calls = self._step_calls, (
+            {} if on and lower is not None else None
+        )
+        return {
+            name: functools.partial(lower, name, *call)
+            for name, call in (calls or {}).items()
+        }
+
+    def _step_program(self, name: str, *args, **kwargs):
+        """Dispatch the backend's step program `name` (the decode chunk or
+        the mixed step). Outside a profiler session that is all: one
+        attribute is tested."""
+        calls = self._step_calls
+        if calls is not None and name not in calls:
+            calls[name] = abstract_call(args, kwargs)
+        return getattr(self.backend, name)(*args, **kwargs)
+
     def _launch_chunk(self):
         """Launch one decode chunk over the current fleet (paged /
         constrained / plain slot program — state, cache, and fsm chain
@@ -3165,7 +3196,8 @@ class ContinuousEngine:
         self._clock.mark("dispatch", "launch.chunk", **rec)
         if self._blk:
             emitted, mask, self.state, self.cache, self._diff = (
-                self.backend.decode_slots_paged(
+                self._step_program(
+                    "decode_slots_paged",
                     self.state, self.cache, self._table_dev,
                     self._next_key(), self.sparams,
                     num_steps=self.chunk_steps, diff=self._diff,
@@ -3173,7 +3205,8 @@ class ContinuousEngine:
             )
         elif self.paged:
             emitted, mask, self.state, self.cache = (
-                self.backend.decode_slots_paged(
+                self._step_program(
+                    "decode_slots_paged",
                     self.state, self.cache, self._table_dev,
                     self._next_key(), self.sparams,
                     num_steps=self.chunk_steps, pages=pages,
@@ -4137,7 +4170,8 @@ class ContinuousEngine:
             tiles=stats["tiles"], tiles_live=live_tiles, **diff_fields,
         )
         self._clock.mark("dispatch", "launch.mixed", **rec)
-        out = self.backend.mixed_step_ragged(
+        out = self._step_program(
+            "mixed_step_ragged",
             jnp.asarray(toks), jnp.asarray(tok_row),
             jnp.asarray(tok_pos), jnp.asarray(dec_flag),
             jnp.asarray(meta), self.cache, self._table_dev,

@@ -40,7 +40,7 @@ from ..utils.metrics import (
 )
 from ..utils.probe import device_summary
 from ..utils.tokenizer import load_tokenizer
-from ..utils.tracing import FlightRecorder, Trace
+from ..utils.tracing import FlightRecorder, Trace, abstract_call
 from ..serving.trace_store import TraceStore
 from . import generate as G
 from .prefix import PrefixCache
@@ -283,6 +283,17 @@ class SingleDeviceBackend:
             spec=spec, spec_toks=spec_toks, dev=dev, pages=pages,
             **diffusion,
         )
+
+    def lower_step(self, name: str, args: tuple, kwargs: dict):
+        """Lower the step program `name` (engine/paged: decode_slots_paged,
+        mixed_step_ragged) again from a dispatch's abstract arguments
+        (utils/tracing.abstract_call), over this backend's weights: what a
+        profiler session's end compiles for the program's instruction ->
+        scope map. Nothing runs and no buffer is touched."""
+        from . import paged as P
+
+        (params,), _ = abstract_call((self.params,), {})
+        return getattr(P, name).lower(self.cfg, params, *args, **kwargs)
 
     # paged adapter pool (engine/adapters.py): the lora leaves live in
     # self.params["layers"]; a load is one donation-aliased write per

@@ -468,6 +468,7 @@ def decode_slots_constrained(
     return emitted, emit_mask, state, cache, fsm
 
 
+@jax.named_scope("sample")  # the penalty pass, warpers, choice, slot state
 def slot_step(cfg: ModelConfig, state: SlotState, sparams: SlotParams,
               logits, key, allowed=None):
     """ONE copy of the per-step slot sampling/bookkeeping — the single-chip

@@ -874,6 +874,7 @@ def _forward_blocks_paged(cfg, params, state: G.SlotState, diff: DiffState,
     return logits[:, 0, :].reshape(S, Bd, -1), pool
 
 
+@jax.named_scope("sample")
 def diffusion_step(cfg: ModelConfig, state: G.SlotState,
                    sparams: G.SlotParams, diff: DiffState, logits, key,
                    on=None):
@@ -1868,6 +1869,7 @@ def draft_propose_paged(dcfg: ModelConfig, dparams, token, pos, dpool,
     return props[:draft_len].swapaxes(0, 1), dpool
 
 
+@jax.named_scope("sample")
 def diffusion_epilogue(cfg: ModelConfig, state: G.SlotState,
                        sparams: G.SlotParams, diff: DiffState, logits, key,
                        on, arm: MixedArm, darm: DiffState):
@@ -1904,6 +1906,7 @@ def diffusion_epilogue(cfg: ModelConfig, state: G.SlotState,
     return packed, state, sparams, diff
 
 
+@jax.named_scope("sample")
 def mixed_epilogue(cfg: ModelConfig, state: G.SlotState,
                    sparams: G.SlotParams, logits, pf_logits, key,
                    arm: MixedArm, spec: Optional[SpecPlan] = None,

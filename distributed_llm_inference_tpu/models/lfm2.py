@@ -173,12 +173,14 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_seq: Optional[int] = None,
     }
 
 
+@jax.named_scope("embed")
 def embed(cfg: ModelConfig, params: Params, tokens, pos=0):
     """[B, T] -> [B, T, D], float32: the residual stream's dtype."""
     del pos
     return params["embed"][tokens].astype(F32)
 
 
+@jax.named_scope("head")
 def unembed(cfg: ModelConfig, params: Params, x):
     """The last RMSNorm and the tied table: float32 logits."""
     h = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(cfg.jnp_dtype)
@@ -409,7 +411,8 @@ def forward_layers(cfg: ModelConfig, layers: Params, x, cache, pos,
     else:
         positions = pos + jnp.arange(T, dtype=jnp.int32)
         mask = causal_mask(pos, T, S)
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
+    with jax.named_scope("attn"):  # the rotary tables, once a forward
+        cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
     hook = attn_hook or default_attn_hook
     rows = attn_hook.rows() if paged else None
     # rows whose output nothing reads reach no expert (engine/paged's hooks
@@ -427,10 +430,14 @@ def forward_layers(cfg: ModelConfig, layers: Params, x, cache, pos,
     new = dict(cache)
     sizes = []
     ic = ia = 0
+    # the step's scopes (utils/tracing.STEP_SCOPES): an operator with the
+    # norm in front of it; the residual add belongs to the block it feeds
+    scope = {"conv": "conv_mix", "full_attention": "attn"}
     for li, kind in enumerate(cfg.layer_types):
-        h = rms_norm(x, layers["op_norm"][li], cfg.norm_eps).astype(dt)
-        if kind == "conv":
-            with jax.named_scope("conv_mix"):
+        routed = li >= cfg.first_k_dense
+        with jax.named_scope(scope[kind]):
+            h = rms_norm(x, layers["op_norm"][li], cfg.norm_eps).astype(dt)
+            if kind == "conv":
                 lp = row("conv", ic)
                 if paged:
                     out, new["conv"], new["tail"] = conv_mix_rows(
@@ -440,28 +447,32 @@ def forward_layers(cfg: ModelConfig, layers: Params, x, cache, pos,
                 else:
                     out, state = conv_mix(cfg, lp, h, new["conv"][ic])
                     new["conv"] = new["conv"].at[ic].set(state)
-            ic += 1
-        else:
-            # a paged hook takes the pool's leaves whole and the layer's
-            # index in them; the dense cache is cut and put back here
-            ck, cv = (new["k"], new["v"]) if paged else \
-                (new["k"][ia], new["v"][ia])
-            out, ck, cv = attention(
-                cfg, row("attn", ia), h, ck, cv, pos, cos, sin, mask, hook,
-                ia if paged else None,
-            )
-            new["k"] = ck if paged else new["k"].at[ia].set(ck)
-            new["v"] = cv if paged else new["v"].at[ia].set(cv)
-            ia += 1
-        x = x + out
-        h = rms_norm(x, layers["ffn_norm"][li], cfg.norm_eps).astype(dt)
-        if li < cfg.first_k_dense:
-            lp = row("dense", li)
-            x = x + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
-        else:
+                ic += 1
+            else:
+                # a paged hook takes the pool's leaves whole and the layer's
+                # index in them; the dense cache is cut and put back here
+                ck, cv = (new["k"], new["v"]) if paged else \
+                    (new["k"][ia], new["v"][ia])
+                out, ck, cv = attention(
+                    cfg, row("attn", ia), h, ck, cv, pos, cos, sin, mask, hook,
+                    ia if paged else None,
+                )
+                new["k"] = ck if paged else new["k"].at[ia].set(ck)
+                new["v"] = cv if paged else new["v"].at[ia].set(cv)
+                ia += 1
+        with jax.named_scope("moe_route" if routed else "ffn"):
+            x = x + out
+            h = rms_norm(x, layers["ffn_norm"][li], cfg.norm_eps).astype(dt)
+        if routed:
             im = li - cfg.first_k_dense
-            out, routed = moe_ffn(cfg, row("moe", im), banks, im, h, live)
-            sizes.append(routed)
+            out, counts = moe_ffn(cfg, row("moe", im), banks, im, h, live)
+            sizes.append(counts)
+        else:
+            lp = row("dense", li)
+            with jax.named_scope("ffn"):
+                out = swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+        after = cfg.layer_types[li + 1:li + 2]
+        with jax.named_scope(scope[after[0]] if after else "head"):
             x = x + out
     if "routed" in cache:
         sizes = jnp.stack(sizes)

@@ -439,66 +439,78 @@ def decoder_layer(
             (lora_pages > 0)[:, None, None], out + d.astype(out.dtype), out
         )
 
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps, unit_offset=uo) \
-        if cfg.pre_norms else x
-    q, k, v = lmm(h, "wq"), lmm(h, "wk"), lmm(h, "wv")
-    if cfg.attn_qkv_bias:  # Qwen2-style (biases tp-shard with their columns)
-        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    if cfg.use_qk_norm and cfg.qk_norm_dim == "proj":
-        # OLMo-2: RMSNorm over the WHOLE projection before the head split
-        # (weights [H*Dh] / [KV*Dh]; tp-sharded with their columns)
-        q = rms_norm(q, lp["q_norm"], cfg.norm_eps, unit_offset=uo)
-        k = rms_norm(k, lp["k_norm"], cfg.norm_eps, unit_offset=uo)
-    q = q.reshape(B, T, H, Dh)
-    k = k.reshape(B, T, KV, Dh)
-    v = v.reshape(B, T, KV, Dh)
-    if cfg.use_qk_norm and cfg.qk_norm_dim == "head":
-        # Qwen3/Gemma-3: per-head RMSNorm over head_dim on q and k,
-        # BEFORE RoPE (HF Qwen3Attention / Gemma3Attention); weights [Dh]
-        # broadcast over the head axis, invariant under tp. Gemma-3's
-        # norm is the unit-offset (1 + w) flavor like its other norms.
-        q = rms_norm(q, lp["q_norm"], cfg.norm_eps, unit_offset=uo)
-        k = rms_norm(k, lp["k_norm"], cfg.norm_eps, unit_offset=uo)
-    if isinstance(cos, tuple):
-        # Gemma-3 dual RoPE: sliding layers use the local table
-        cos_full, cos_local = cos
-        sin_full, sin_local = sin
-        cos = jnp.where(lp["window_flag"] > 0, cos_local, cos_full)
-        sin = jnp.where(lp["window_flag"] > 0, sin_local, sin_full)
-    q, k = apply_rope(q, k, cos, sin)
+    # the step's scopes (utils/tracing.STEP_SCOPES): a block runs from its
+    # input norm to its output projection, and the residual add between two
+    # blocks belongs to the one it feeds
+    with jax.named_scope("attn"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps, unit_offset=uo) \
+            if cfg.pre_norms else x
+        q, k, v = lmm(h, "wq"), lmm(h, "wk"), lmm(h, "wv")
+        if cfg.attn_qkv_bias:  # Qwen2-style (biases tp-shard with their columns)
+            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+        if cfg.use_qk_norm and cfg.qk_norm_dim == "proj":
+            # OLMo-2: RMSNorm over the WHOLE projection before the head split
+            # (weights [H*Dh] / [KV*Dh]; tp-sharded with their columns)
+            q = rms_norm(q, lp["q_norm"], cfg.norm_eps, unit_offset=uo)
+            k = rms_norm(k, lp["k_norm"], cfg.norm_eps, unit_offset=uo)
+        q = q.reshape(B, T, H, Dh)
+        k = k.reshape(B, T, KV, Dh)
+        v = v.reshape(B, T, KV, Dh)
+        if cfg.use_qk_norm and cfg.qk_norm_dim == "head":
+            # Qwen3/Gemma-3: per-head RMSNorm over head_dim on q and k,
+            # BEFORE RoPE (HF Qwen3Attention / Gemma3Attention); weights [Dh]
+            # broadcast over the head axis, invariant under tp. Gemma-3's
+            # norm is the unit-offset (1 + w) flavor like its other norms.
+            q = rms_norm(q, lp["q_norm"], cfg.norm_eps, unit_offset=uo)
+            k = rms_norm(k, lp["k_norm"], cfg.norm_eps, unit_offset=uo)
+        if isinstance(cos, tuple):
+            # Gemma-3 dual RoPE: sliding layers use the local table
+            cos_full, cos_local = cos
+            sin_full, sin_local = sin
+            cos = jnp.where(lp["window_flag"] > 0, cos_local, cos_full)
+            sin = jnp.where(lp["window_flag"] > 0, sin_local, sin_full)
+        q, k = apply_rope(q, k, cos, sin)
 
-    hook = attn_hook or default_attn_hook
-    attn, new_k, new_v = hook(
-        cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate, valid_start,
-        lp.get("window_flag"), *(() if layer is None else (layer,)),
-    )
-    attn_out = lmm(attn.reshape(B, T, H * Dh), "wo")
-    if tp_axis is not None:
-        attn_out = jax.lax.psum(attn_out, tp_axis)
-    if cfg.post_norms:  # Gemma-2: norm the branch output before the residual
-        attn_out = rms_norm(attn_out, lp["attn_post_norm"], cfg.norm_eps, unit_offset=uo)
-    if cfg.residual_multiplier is not None:  # Granite
-        attn_out = attn_out * jnp.asarray(cfg.residual_multiplier, attn_out.dtype)
-    x = x + attn_out
-
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps, unit_offset=uo) \
-        if cfg.pre_norms else x
+        hook = attn_hook or default_attn_hook
+        attn, new_k, new_v = hook(
+            cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate, valid_start,
+            lp.get("window_flag"), *(() if layer is None else (layer,)),
+        )
+        attn_out = lmm(attn.reshape(B, T, H * Dh), "wo")
+        if tp_axis is not None:
+            attn_out = jax.lax.psum(attn_out, tp_axis)
+        if cfg.post_norms:  # Gemma-2: norm the branch output before the residual
+            attn_out = rms_norm(attn_out, lp["attn_post_norm"], cfg.norm_eps, unit_offset=uo)
+        if cfg.residual_multiplier is not None:  # Granite
+            attn_out = attn_out * jnp.asarray(cfg.residual_multiplier, attn_out.dtype)
+    # (routed experts carry their own moe_* scopes: the norm in front of
+    # them is the router's, what follows them the combine's)
+    ffn_in, ffn_out = ("ffn", "ffn") if routed is None else \
+        ("moe_route", "moe_combine")
+    with jax.named_scope(ffn_in):
+        x = x + attn_out
+        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps, unit_offset=uo) \
+            if cfg.pre_norms else x
     sizes = None
     if routed is not None:
         mlp_out, sizes = routed_mlp(cfg, lp, h, *routed)
-    elif cfg.n_experts:
-        mlp_out = moe_ffn(cfg, lp, h, ep_axis)  # psums over ep internally
     else:
-        act = jax.nn.silu if cfg.act == "silu" else _gelu_tanh
-        gate = act(lmm(h, "w_gate").astype(jnp.float32)).astype(h.dtype)
-        mlp_out = lmm(gate * lmm(h, "w_up"), "w_down")
-        if tp_axis is not None:
-            mlp_out = jax.lax.psum(mlp_out, tp_axis)
-    if cfg.post_norms:
-        mlp_out = rms_norm(mlp_out, lp["mlp_post_norm"], cfg.norm_eps, unit_offset=uo)
-    if cfg.residual_multiplier is not None:  # Granite
-        mlp_out = mlp_out * jnp.asarray(cfg.residual_multiplier, mlp_out.dtype)
-    x = x + mlp_out
+        with jax.named_scope("ffn"):
+            if cfg.n_experts:
+                mlp_out = moe_ffn(cfg, lp, h, ep_axis)  # psums over ep internally
+            else:
+                act = jax.nn.silu if cfg.act == "silu" else _gelu_tanh
+                gate = act(lmm(h, "w_gate").astype(jnp.float32)).astype(h.dtype)
+                mlp_out = lmm(gate * lmm(h, "w_up"), "w_down")
+                if tp_axis is not None:
+                    mlp_out = jax.lax.psum(mlp_out, tp_axis)
+    with jax.named_scope(ffn_out):
+        if cfg.post_norms:
+            mlp_out = rms_norm(mlp_out, lp["mlp_post_norm"], cfg.norm_eps, unit_offset=uo)
+        if cfg.residual_multiplier is not None:  # Granite
+            mlp_out = mlp_out * jnp.asarray(cfg.residual_multiplier, mlp_out.dtype)
+    with jax.named_scope("attn"):  # the next layer's (the head's, after the last)
+        x = x + mlp_out
     if routed is not None:
         return x, new_k, new_v, sizes
     return x, new_k, new_v
@@ -572,14 +584,15 @@ def forward_layers(
         positions = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # [B, T]
     else:
         positions = pos + jnp.arange(T, dtype=jnp.int32)
-    cos, sin = rope_cos_sin(
-        positions, cfg.head_dim, cfg.rope_theta,
-        scaling=cfg.rope_scaling,
-        scaling_factor=cfg.rope_scaling_factor,
-        low_freq_factor=cfg.rope_low_freq_factor,
-        high_freq_factor=cfg.rope_high_freq_factor,
-        original_max_len=cfg.rope_original_max_len,
-    )
+    with jax.named_scope("attn"):  # the rotary tables, once a forward
+        cos, sin = rope_cos_sin(
+            positions, cfg.head_dim, cfg.rope_theta,
+            scaling=cfg.rope_scaling,
+            scaling_factor=cfg.rope_scaling_factor,
+            low_freq_factor=cfg.rope_low_freq_factor,
+            high_freq_factor=cfg.rope_high_freq_factor,
+            original_max_len=cfg.rope_original_max_len,
+        )
     if cfg.rope_local_theta is not None:
         # Gemma-3: sliding layers rotate with their own UNSCALED local
         # theta; both tables built once, each layer selects by its
@@ -698,6 +711,7 @@ def scan_layers(layer_step, x, layers, cache, *, paged: bool):
     return x, cache, ys
 
 
+@jax.named_scope("embed")
 def embed(cfg: ModelConfig, params: Params, tokens: jnp.ndarray, pos=0) -> jnp.ndarray:
     """Token embedding lookup: [B, T] -> [B, T, D]
     (reference orchestration.py:111). `pos` is accepted for interface parity
@@ -711,6 +725,7 @@ def embed(cfg: ModelConfig, params: Params, tokens: jnp.ndarray, pos=0) -> jnp.n
     return x
 
 
+@jax.named_scope("head")
 def unembed(cfg: ModelConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:
     """Final RMSNorm + LM head: [B, T, D] -> [B, T, V] logits
     (reference orchestration.py:140-141). Gemma-2 softcaps the final

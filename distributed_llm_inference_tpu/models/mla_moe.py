@@ -163,12 +163,14 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_seq: Optional[int] = None,
     }
 
 
+@jax.named_scope("embed")
 def embed(cfg: ModelConfig, params: Params, tokens, pos=0):
     """[B, T] -> [B, T, D], float32: the residual stream's dtype."""
     del pos
     return params["embed"][tokens].astype(F32)
 
 
+@jax.named_scope("head")
 def unembed(cfg: ModelConfig, params: Params, x):
     """Final RMSNorm and the (untied) output head: float32 logits."""
     h = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(cfg.jnp_dtype)
@@ -210,6 +212,7 @@ def latent_attn_hook(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
     return latent_attend(cfg, q, new, mask), new[:, None], None
 
 
+@jax.named_scope("attn")
 def attention(cfg: ModelConfig, lp: Params, x, cache, pos, positions, mask,
               update_gate, hook, layer=None):
     """The attention sublayer on the float32 residual x [B, T, D]; returns
@@ -312,23 +315,32 @@ def forward_layers(cfg: ModelConfig, layers: Params, x, cache, pos,
 
     paged = getattr(attn_hook, "paged", False)
 
-    def attn_part(xc, lp, ck, layer):
+    # the step's scopes (utils/tracing.STEP_SCOPES): the residual add and
+    # the norm between two blocks belong to the block they feed (`feeds`;
+    # in front of the routed experts that is the router)
+    def attn_part(xc, lp, ck, layer, feeds):
         out, ck = attention(cfg, lp, xc, ck, pos, positions, mask,
                             update_gate, hook, layer if paged else None)
-        xc = xc + out
-        return xc, rms_norm(xc, lp["mlp_norm"], cfg.norm_eps).astype(dt), ck
+        with jax.named_scope(feeds):
+            xc = xc + out
+            h = rms_norm(xc, lp["mlp_norm"], cfg.norm_eps).astype(dt)
+        return xc, h, ck
 
     def dense_layer(xc, lp, ck, layer):
-        xc, h, ck = attn_part(xc, lp, ck, layer)
-        return xc + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"]), ck, None
+        xc, h, ck = attn_part(xc, lp, ck, layer, "ffn")
+        with jax.named_scope("ffn"):
+            out = swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+        with jax.named_scope("attn"):  # the next layer's
+            return xc + out, ck, None
 
     moe = layers["moe"]
     banks = {name: moe[name] for name in BANKS}  # closed over, never sliced
 
     def moe_layer(xc, lp, ck, layer):
-        xc, h, ck = attn_part(xc, lp, ck, layer)
+        xc, h, ck = attn_part(xc, lp, ck, layer, "moe_route")
         out, sizes = moe_ffn(cfg, lp, banks, layer, h, live)
-        return xc + out, ck, sizes
+        with jax.named_scope("attn"):  # the next layer's (the head's, after the last)
+            return xc + out, ck, sizes
 
     new = dict(cache)
     if stack_depths(cfg)[0]:

@@ -84,6 +84,12 @@ class _Profiler:
         self._lock = threading.Lock()
         self.base = base
         self.dir: Optional[str] = None
+        # the continuous engine (make_handler sets it): a session keeps the
+        # step programs it dispatched and, once the profile is on disk,
+        # writes instruction -> scope of each beside it
+        # (utils/tracing.write_program_scopes), since a device trace's
+        # events carry an instruction's name and no scope
+        self.programs = None
 
     def _resolve(self, name: str) -> str:
         import os
@@ -108,6 +114,8 @@ class _Profiler:
             except Exception as e:
                 return {"error": f"profiler start failed: {e}"}
             self.dir = resolved
+            if self.programs is not None:
+                self.programs.trace_step_programs(True)
             return {"status": "tracing", "trace_dir": resolved}
 
     def stop(self) -> dict:
@@ -129,7 +137,21 @@ class _Profiler:
                     "trace_dir": out,
                 }
             self.dir = None
-            return {"status": "stopped", "trace_dir": out}
+            reply = {"status": "stopped", "trace_dir": out}
+            if self.programs is not None:
+                # the profile is complete; compiling the map may take a
+                # minute a program the first time, and never fails the stop
+                from ..utils.tracing import write_program_scopes
+
+                t0 = time.monotonic()
+                try:
+                    reply["scopes"] = write_program_scopes(
+                        out, self.programs.trace_step_programs(False)
+                    )
+                except Exception as e:  # noqa: BLE001 - reported, not raised
+                    reply["scopes"] = f"error: {e}"
+                reply["scopes_s"] = round(time.monotonic() - t0, 3)
+            return reply
 
 
 # the fixed route set for the http counter's `route` label: anything else
@@ -168,6 +190,7 @@ def make_handler(engine, max_tokens_cap: int, profiler: Optional[_Profiler] = No
     from .trace_store import assemble_tree, span_tree_total, to_chrome_trace
 
     profiler = profiler or _Profiler()
+    profiler.programs = continuous
     if state is None:  # embedding callers without an InferenceServer
         state = _ServerState()
     started_at = int(time.time())

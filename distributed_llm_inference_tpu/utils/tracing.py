@@ -43,15 +43,27 @@ continuous engine's worker thread marks the phase it enters, the time up
 to the next mark goes to `dli_worker_phase_seconds_total{phase}`, and
 each interval is one `jax.profiler.TraceAnnotation`, so a `/profiler`
 trace shows what the host did beside the device's own events.
+
+The device's half of a launch (ISSUE 38): a trace's `XLA Ops` events carry
+an instruction's name (`%fusion.12`) and nothing of the `jax.named_scope`
+labels the step programs are written under (`STEP_SCOPES`). So while a
+profiler session is open the engine's two launch seams keep the abstract
+arguments of each step program's first dispatch (`abstract_call`), and when
+the session ends `serving.server._Profiler` compiles those programs anew
+(`fresh_hlo_text`), maps instruction -> scope (`scope_map`) and writes
+`program_scopes.json` beside the profile (`write_program_scopes`).
 """
 
 from __future__ import annotations
 
 import collections
+import json
+import os
 import re
 import threading
 import time
 import uuid
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 _SAFE_ID = re.compile(r"^[A-Za-z0-9_\-\.:]{1,128}$")
@@ -230,6 +242,160 @@ WORKER_PHASES = (
 # the phases in which the thread blocks: one may outlast a profiler
 # session, which keeps no interval still open at its end
 WAIT_PHASES = ("wait_work", "fetch_wait")
+
+
+# the scopes of a step program, as `jax.named_scope` labels where the work
+# is written (models/, engine/paged.py, engine/generate.py): a block runs
+# from its input norm to its output projection, and a residual add or a
+# norm between two blocks belongs to the block it feeds. `mla_absorb` nests
+# under `attn`. A device trace is read by these names (`scope_map`).
+STEP_SCOPES = (
+    "embed", "attn", "mla_absorb", "ffn", "moe_route", "moe_dispatch",
+    "moe_experts", "moe_combine", "moe_shared", "conv_mix", "head", "sample",
+)
+PROGRAM_SCOPES_FILE = "program_scopes.json"
+
+_HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)", re.M)
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{\s*$")
+_HLO_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?(%?[\w.\-]+) = (?:\(.*?\)|\S+) ([\w\-]+)\("
+)
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_FUSED = re.compile(r"\bcalls=%?([\w.\-]+)")
+# instructions that never run as an operation of their own
+_HLO_NO_OP = frozenset(
+    ("parameter", "get-tuple-element", "tuple", "bitcast", "constant")
+)
+
+
+def _labels(op_name: str) -> list:
+    """`jit(f)/while/body/attn/mla_absorb/dot_general` -> [attn, mla_absorb]
+    (a label nested in itself, a labelled function calling another, once)."""
+    out = []
+    for part in op_name.split("/"):
+        if part in STEP_SCOPES and out[-1:] != [part]:
+            out.append(part)
+    return out
+
+
+def scope_map(hlo_text: str) -> dict:
+    """Optimized HLO text (`compiled.as_text()`; one module or several) ->
+    {module name: {instruction name: {"scope": [...], "mixed": n}}}.
+
+    An instruction's name is what a device trace prints (`%fusion.12`;
+    names are unique within a module). `scope` holds the `STEP_SCOPES`
+    labels of the instruction's own `op_name`, outermost first; a fusion
+    whose own `op_name` holds none takes the labels most of its fused
+    instructions hold. `mixed` (fusions only, else 0) is the number of
+    DIFFERENT innermost labels among the fused instructions: 0 or 1 is a
+    fusion of one scope's work, more says its time belongs to several.
+    Instructions inside a fused computation are no operations of their own
+    and are left out, as are parameters, tuples and constants."""
+    out = {}
+    for chunk in re.split(r"(?m)^(?=HloModule )", hlo_text):
+        m = _HLO_MODULE.match(chunk)
+        if m:
+            out[m.group(1)] = _module_scopes(chunk)
+    return out
+
+
+def _module_scopes(text: str) -> dict:
+    computations = {}  # name -> [(instruction, opcode, labels, fused or None)]
+    body = None
+    for line in text.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            body = computations.setdefault(head.group(1), [])
+            continue
+        inst = _HLO_INSTRUCTION.match(line) if body is not None else None
+        if inst is None:
+            continue
+        name, opcode = inst.groups()
+        op = _HLO_OP_NAME.search(line)
+        fused = _HLO_FUSED.search(line) if opcode == "fusion" else None
+        body.append((name, opcode, _labels(op.group(1)) if op else [],
+                     fused.group(1) if fused else None))
+    inside = {f for rows in computations.values() for _, _, _, f in rows if f}
+    out = {}
+    for comp, rows in computations.items():
+        if comp in inside:
+            continue
+        for name, opcode, labels, fused in rows:
+            if opcode in _HLO_NO_OP:
+                continue
+            mixed = 0
+            if fused is not None:
+                held = [tuple(r[2]) for r in computations.get(fused, ())
+                        if r[2]]
+                mixed = len({h[-1] for h in held})
+                if not labels and held:
+                    labels = list(collections.Counter(held).most_common(1)[0][0])
+            out[name] = {"scope": labels, "mixed": mixed}
+    return out
+
+
+def abstract_call(args: tuple, kwargs: dict) -> tuple:
+    """A dispatch's arguments with every array replaced by its shape and
+    dtype (nothing of a donated buffer is kept alive); static arguments and
+    None pass through. No sharding is kept: a one-device program lowers
+    from these as its dispatch did."""
+    import jax
+
+    def abstract(a):
+        if isinstance(a, jax.Array):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype)
+        return a
+
+    return jax.tree.map(abstract, (tuple(args), dict(kwargs)))
+
+
+def fresh_hlo_text(lowered) -> str:
+    """Compile a lowered program for its optimized HLO text WITH THIS
+    TREE'S metadata. Two caches stand in the way of that. The persistent
+    compile cache's key strips debug info (jax/_src/cache_key.py), so a
+    plain `.compile()` may hand back an executable an OLDER tree compiled,
+    under that tree's `op_name`s: the metadata goes into the key for this
+    one compile (thread-local), whose entry is then this tree's own, and
+    warm on the next session of the same tree. And a lowering of the same
+    function and shapes is one object in the process, which keeps its first
+    executable: a compiler option (set to its default, so the program is the
+    same) makes JAX compile past that."""
+    from jax._src import config as jax_config
+
+    with jax_config.compilation_cache_include_metadata_in_key(True):
+        return lowered.compile(
+            compiler_options={"xla_embed_ir_in_executable": False}
+        ).as_text()
+
+
+def write_program_scopes(trace_dir: str, lowerings: dict) -> str:
+    """{program: a callable that lowers it} -> `<trace_dir>/
+    program_scopes.json`: {"vocabulary": [...], "programs": {module name:
+    {instruction: {"scope", "mixed"}}}}. Returns the file's path.
+
+    Each program is compiled anew with this tree's metadata
+    (`fresh_hlo_text`); the instruction names of that compile are the ones a
+    trace of the running program prints, because the compiler numbers its
+    instructions from the program and its metadata, and both are this
+    tree's. (Where the process runs an executable that ANOTHER tree compiled,
+    a metadata-free cache hit, a few small instructions may be numbered
+    otherwise: read on described-chip compiles of two trees, ISSUE 38. That
+    takes a program with no Mosaic kernel, whose body carries the file and
+    line of its call stack into the cache's key; the trace's reader counts
+    the seconds whose name the map does not hold, and refuses a map that
+    names under 99%.) The programs compile side by side, a thread each: the
+    caller is a `/profiler/stop` that a client waits for."""
+    def program(lower):
+        return scope_map(fresh_hlo_text(lower()))
+
+    programs = {}
+    with ThreadPoolExecutor(max(1, len(lowerings))) as pool:
+        for part in pool.map(program, lowerings.values()):
+            programs.update(part)
+    path = os.path.join(trace_dir, PROGRAM_SCOPES_FILE)
+    with open(path, "w") as f:
+        json.dump({"vocabulary": list(STEP_SCOPES), "programs": programs}, f)
+    return path
 
 
 class PhaseClock:

@@ -39,7 +39,12 @@ run the same bits on the chip, and a token that differs between two runs
 there is the scheduler's timing (which program served it), not arithmetic.
 
     python tests/dense_equal.py --programs <checkout root> <model> <slots> <blocks> <context> <out dir>
+    python tests/dense_equal.py --programs <checkout root> cellbench/configs/<name>.json <layers, 0: all> <out dir>
     python tests/dense_equal.py --compare-programs <dir a> <dir b>   # exit 1 if unequal
+
+The second form (ISSUE 38) takes a benchmark configuration's file: its
+registry entry, overrides and sizes, so the routed, block-diffusion and
+convolution-hybrid configurations' programs compare as the dense two's do.
 """
 import base64
 import json
@@ -137,9 +142,14 @@ def unequal(a, b) -> list:
                   or a[k].tobytes() != b[k].tobytes())
 
 
-def programs(model, slots, blocks, context, block_size=128, tile=8) -> dict:
+def programs(model, slots, blocks, context, block_size=128, tile=8,
+             layers=2, described=True, **overrides) -> dict:
     """{program name: optimized HLO text} of `model`'s decode chunk and
-    mixed step, compiled for one chip of a described v5e:2x2."""
+    mixed step (cut to `layers` layers, 0: as registered or overridden),
+    compiled for one chip of a described v5e:2x2 (described False: for the
+    backend that is there, in the registry's own dtype). Every family the
+    paged fleet serves: a block-diffusion model's programs carry its
+    DiffState, a model with recurrent layers a pool with a state a slot."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
@@ -153,7 +163,7 @@ def programs(model, slots, blocks, context, block_size=128, tile=8) -> dict:
     from distributed_llm_inference_tpu.models.registry import get_model_config
 
     chip = SingleDeviceSharding(topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2").devices[0])
+        platform="tpu", topology_name="v5e:2x2").devices[0]) if described else None
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -161,21 +171,44 @@ def programs(model, slots, blocks, context, block_size=128, tile=8) -> dict:
     def place(make):
         return jax.tree.map(lambda a: S(a.shape, a.dtype), jax.eval_shape(make))
 
-    cfg = resolve_attn_impl(
-        get_model_config(model).replace(n_layers=2, dtype="bfloat16"), "pallas")
+    if layers:
+        overrides["n_layers"] = layers
+    if described:
+        overrides["dtype"] = "bfloat16"
+    cfg = resolve_attn_impl(get_model_config(model).replace(**overrides), "pallas")
     params = place(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
     state, sparams = place(lambda: G.init_slots(slots, cfg.vocab_size))
-    pool = place(lambda: P.init_pool(cfg, blocks, block_size))
+    pool = place(lambda: P.init_pool(cfg, blocks, block_size, n_slots=slots))
     table = S((slots, context // block_size), jnp.int32)
     key = place(lambda: jax.random.PRNGKey(0))
-    chunk = P.decode_slots_paged.lower(
-        cfg, params, state, pool, table, key, sparams, num_steps=16)
     width = step_width(cfg, slots, tile)  # what the server launches
-    entries = [(b, 0, 1, P.RAGGED_DECODE) for b in range(slots)]
-    meta, tok_row, tok_pos, offsets, _ = P.build_ragged_meta(
-        entries, width=width, tile=tile)
-    dev = P.DeviceMeta(*(S(a.shape, a.dtype) for a in P.build_device_meta(
-        entries, offsets, slots, width=width, tile=tile)))
+    chunk_kw, mixed_kw = {}, {}
+    if cfg.diffusion_block:
+        # a decode row is one tile: its open block, behind the owed one in
+        # every second row
+        Bd = cfg.diffusion_block
+        diff = place(lambda: P.init_diffusion(cfg, slots))
+        owing = [b % 2 == 0 for b in range(slots)]
+        entries = [(b, 0, 2 * Bd if owe else Bd, P.RAGGED_PREFILL)
+                   for b, owe in enumerate(owing)]
+        meta, tok_row, tok_pos, offsets, _ = P.build_ragged_meta(
+            entries, width=width, tile=tile)
+        *dev, _ = P.build_block_meta(entries, offsets, owing, block=Bd,
+                                     width=width, tile=tile)
+        chunk_kw = {"diff": diff}
+        mixed_kw = {"dev": dev, "diff": diff, "darm": diff}
+    else:
+        entries = [(b, 0, 1, P.RAGGED_DECODE) for b in range(slots)]
+        meta, tok_row, tok_pos, offsets, _ = P.build_ragged_meta(
+            entries, width=width, tile=tile)
+        if cfg.arch != "lfm2":  # (a fleet with recurrent state drafts nothing)
+            mixed_kw = {"dev": P.build_device_meta(
+                entries, offsets, slots, width=width, tile=tile)}
+    if "dev" in mixed_kw:
+        mixed_kw["dev"] = P.DeviceMeta(
+            *(S(a.shape, a.dtype) for a in mixed_kw["dev"]))
+    chunk = P.decode_slots_paged.lower(
+        cfg, params, state, pool, table, key, sparams, num_steps=16, **chunk_kw)
 
     def flat(a):
         return S(np.shape(a), np.asarray(a).dtype)
@@ -184,9 +217,26 @@ def programs(model, slots, blocks, context, block_size=128, tile=8) -> dict:
         cfg, params, S((width,), jnp.int32), flat(tok_row), flat(tok_pos),
         S((width,), jnp.bool_), flat(meta), pool, table, state, sparams, key,
         S((slots,), jnp.int32),
-        place(lambda: P.idle_mixed_arm(slots, cfg.vocab_size)), dev=dev)
+        place(lambda: P.idle_mixed_arm(slots, cfg.vocab_size)), **mixed_kw)
     return {"decode_slots_paged": chunk.compile().as_text(),
             "mixed_step_ragged": mixed.compile().as_text()}
+
+
+def cell_programs(config_path: str, layers: int) -> dict:
+    """`programs` of a benchmark configuration (cellbench/configs/<name>.json:
+    its registry entry, overrides and serving flags), cut to `layers` layers
+    (0: the configuration's own depth)."""
+    with open(config_path) as f:
+        serving = json.load(f)["serving"]
+    flags = serving["flags"]
+
+    def flag(name):
+        return int(flags[flags.index(name) + 1])
+
+    return programs(
+        serving["base"], flag("--continuous"), flag("--kv-pool-blocks"),
+        flag("--continuous-max-seq"), flag("--kv-block-size"), layers=layers,
+        **serving.get("overrides", {}))
 
 
 def canon(text: str) -> tuple:
@@ -215,6 +265,16 @@ def canon(text: str) -> tuple:
     return head + body, kernels
 
 
+def renumbered(body: str) -> str:
+    """`canon`'s instructions with every name replaced by its place of
+    first appearance: two compiles whose metadata alone differs may number
+    a few instructions differently (`%broadcast_in_dim.62` / `.63`, read
+    between 704043c and ISSUE 38's labels) while printing the same
+    instructions, operands and order."""
+    seen = {}
+    return re.sub(r"%[\w.\-]+", lambda m: seen.setdefault(m.group(0), f"%v{len(seen)}"), body)
+
+
 def _under(root: str) -> None:
     """Import the package of the checkout at `root`, on the CPU, its fusion
     pass off (the module's docstring says why)."""
@@ -241,10 +301,13 @@ def main() -> None:
         for name in sorted(set(os.listdir(a)) | set(os.listdir(b))):
             with open(os.path.join(a, name)) as fa, open(os.path.join(b, name)) as fb:
                 (ta, ka), (tb, kb) = canon(fa.read()), canon(fb.read())
+            same = ("identical" if ta == tb else
+                    "identical but for instruction numbering"
+                    if renumbered(ta) == renumbered(tb) else "DIFFER")
             print(f"{name}: {len(ta.splitlines())} lines of instructions "
-                  f"{'identical' if ta == tb else 'DIFFER'}; {len(ka)} Mosaic kernel(s), "
+                  f"{same}; {len(ka)} Mosaic kernel(s), "
                   f"{sum(map(len, ka))} characters, {'identical' if ka == kb else 'DIFFER'}")
-            bad += [name] * (ta != tb or ka != kb)
+            bad += [name] * (same == "DIFFER" or ka != kb)
         sys.exit(1 if bad else 0)
     if mode == "--programs":
         _under(os.path.abspath(sys.argv[2]))
@@ -252,9 +315,14 @@ def main() -> None:
         import jax
 
         jax.config.update("jax_enable_compilation_cache", False)  # unreadable without a chip
-        model, out = sys.argv[3], sys.argv[7]
+        model, out = sys.argv[3], sys.argv[-1]
         os.makedirs(out, exist_ok=True)
-        for name, text in programs(model, *map(int, sys.argv[4:7])).items():
+        if model.endswith(".json"):  # <configuration file> <layers> <out dir>
+            texts = cell_programs(model, int(sys.argv[4]))
+            model = os.path.basename(model)[:-len(".json")]
+        else:
+            texts = programs(model, *map(int, sys.argv[4:7]))
+        for name, text in texts.items():
             with open(os.path.join(out, f"{model}.{name}.hlo.txt"), "w") as f:
                 f.write(text)
             print(model, name, len(text), "bytes of HLO text")

@@ -401,11 +401,16 @@ with open(os.path.join(os.path.dirname(__file__), "data",
 
 @pytest.mark.parametrize("steps", [1, 2, 4])
 def test_closed_loop_serves_the_three_forward_forms_tokens_in_fewer_forwards(steps):
-    """Four clients for two slots, each sending its next request when the
-    last returned: the queue is never empty, so slots are re-let by the
-    position model alone; no launch carries a row past its last forward;
-    the commits ride (blocks - 1 a request); and the tokens are what the
-    parent's three-forward form served."""
+    """Eight requests for two slots, all queued before the worker sees the
+    first (under the engine's own condition, on which it sleeps): the queue
+    is never empty while a slot is let again, BY CONSTRUCTION and not by
+    how fast client threads come back under a loaded machine, and the whole
+    launch sequence follows from the queue alone. So slots are re-let by
+    the position model alone; no launch carries a row past its last
+    forward; the commits ride (blocks - 1 a request); and the tokens are
+    what the parent's three-forward form served."""
+    from distributed_llm_inference_tpu.engine.continuous import _Request
+
     f = Fleet(impl="xla", steps=steps)
     try:
         recs, orig = [], f.ce._launch_record
@@ -417,18 +422,14 @@ def test_closed_loop_serves_the_three_forward_forms_tokens_in_fewer_forwards(ste
         f.ce._launch_record = keep
         asks = [(prompt_ids(n, salt=THREE_FORWARD["salt"]), mt)
                 for n, mt in THREE_FORWARD["asks"]]
-        got = [None] * len(asks)
-
-        def client(mine):
-            for i in mine:
-                got[i] = f.ask_all([(*asks[i], {})])[0]["ids"]
-
-        ts = [threading.Thread(target=client, args=(range(c, len(asks), 4),))
-              for c in range(4)]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join(600)
+        reqs = [_Request(words(ids), dict(max_tokens=mt, greedy=True, chat=False))
+                for ids, mt in asks]
+        with f.ce._cv:
+            for req in reqs:
+                assert f.ce._enqueue(req) is None
+        for req in reqs:
+            assert req.done.wait(600) and req.result.get("status") == "success", req.result
+        got = [WordTok().encode(req.result["response"]) for req in reqs]
         assert got == THREE_FORWARD["tokens"][str(steps)]
         assert not f.disagreements, f.disagreements[:5]
         released = _series(f.eng, "dli_slot_release_total")

@@ -292,6 +292,30 @@ def _custom_call_names(hlo_text):
     return set(re.findall(r"%([\w.\-]+) = [^\n]*custom-call\(", hlo_text))
 
 
+def _assert_scopes(hlo_text, module, labels):
+    """Every label of the family's vocabulary (utils/tracing.STEP_SCOPES)
+    labels at least one instruction of the compiled step program: what
+    `program_scopes.json` is made from when a profiler session ends
+    (ISSUE 38), and the six per-layer metrics read."""
+    from distributed_llm_inference_tpu.utils import tracing
+
+    assert set(labels) <= set(tracing.STEP_SCOPES)
+    (name, insts), = tracing.scope_map(hlo_text).items()
+    assert module in name
+    held = {label for v in insts.values() for label in v["scope"]}
+    assert held >= set(labels), (module, sorted(set(labels) - held))
+    # a kernel is an instruction of its block
+    for inst, v in insts.items():
+        if "paged_attend" in inst or "paged_flash_attend" in inst:
+            assert v["scope"][:1] == ["attn"], (inst, v)
+        if "routed_expert_matmul" in inst:
+            assert v["scope"] == ["moe_experts"], (inst, v)
+
+
+DENSE_SCOPES = ("embed", "attn", "ffn", "head", "sample")
+ROUTED_SCOPES = ("moe_route", "moe_dispatch", "moe_experts", "moe_combine")
+
+
 def test_step_programs_and_kernels_carry_the_names_a_trace_is_read_by(
     one_chip, no_persistent_cache, tinyllama, monkeypatch
 ):
@@ -342,6 +366,7 @@ def test_step_programs_and_kernels_carry_the_names_a_trace_is_read_by(
         assert module in _module_name(text), _module_name(text)
         calls = _custom_call_names(text)
         assert any(kernel in c for c in calls), (module, sorted(calls))
+        _assert_scopes(text, module, DENSE_SCOPES)
     # and these are the strings the benchmark's configurations name
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     files = glob.glob(os.path.join(root, "cellbench", "configs", "*.json"))
@@ -439,7 +464,11 @@ def test_step_programs_hold_no_copy_of_the_pool_at_cell_sizes(
         assert memory.alias_size_in_bytes >= pool_bytes - 2**20, (name, memory)
         # ... and the temporaries hold nothing of its size
         assert memory.temp_size_in_bytes < 0.45 * pool_bytes, (name, memory)
-        assert _pool_sized_instructions(compiled.as_text(), pool) == [], name
+        text = compiled.as_text()
+        assert _pool_sized_instructions(text, pool) == [], name
+        _assert_scopes(text, name, DENSE_SCOPES + (
+            ROUTED_SCOPES + ("moe_shared", "mla_absorb")
+            if config.startswith("kanana") else ()))
 
 
 # -- the latent-attention, routed-expert family (ISSUE 28) ---------------------
@@ -557,6 +586,7 @@ def test_latent_step_programs_carry_the_names_a_trace_is_read_by(
         stacks = " ".join(set(re.findall(r'op_name="([^"]*)"', text)))
         for scope in STEP_SCOPES:
             assert scope in stacks, (module, scope)
+        _assert_scopes(text, module, DENSE_SCOPES + tuple(STEP_SCOPES))
 
 
 @pytest.mark.parametrize("tq", [4, 8], ids=["open-block", "owed-and-open"])
@@ -653,6 +683,9 @@ def test_block_diffusion_step_programs_carry_the_names_a_trace_is_read_by(
         stacks = " ".join(set(re.findall(r'op_name="([^"]*)"', text)))
         for scope in ("moe_route", "moe_dispatch", "moe_experts", "moe_combine"):
             assert scope in stacks, (module, scope)
+        # (every layer routes: the family's programs hold no dense `ffn`)
+        _assert_scopes(text, module,
+                       ("embed", "attn", "head", "sample") + ROUTED_SCOPES)
         # the expert banks ride outside the layer scan: no operation of a
         # bank's size (2 x 128 experts of 2048 x 768) beside the kernel
         assert not re.search(r"copy\(.*bf16\[256,(2048,768|768,2048)\]", text), module
@@ -742,6 +775,7 @@ def test_conv_hybrid_step_programs_at_cell_sizes_write_in_place(
         stacks = " ".join(set(re.findall(r'op_name="([^"]*)"', text)))
         for scope in ("conv_mix", "moe_route", "moe_dispatch", "moe_experts", "moe_combine"):
             assert scope in stacks, (module, scope)
+        _assert_scopes(text, module, DENSE_SCOPES + ROUTED_SCOPES + ("conv_mix",))
 
 
 def test_two_compiles_compare_equal_once_source_positions_are_out(
@@ -766,3 +800,12 @@ def test_two_compiles_compare_equal_once_source_positions_are_out(
         assert moved != text and dense_equal.canon(moved) == (body, kernels)
         changed = text.replace(" multiply(", " add(", 1)
         assert changed != text and dense_equal.canon(changed)[0] != body
+        # two trees whose metadata alone differs may number an instruction
+        # differently (ISSUE 38): the same instructions in the same order
+        # compare equal once renumbered by place, a changed one does not
+        shifted = re.sub(r"(%[a-z_\-]+)\.(\d+)",
+                         lambda m: f"{m.group(1)}.{int(m.group(2)) + 1}", body)
+        assert shifted != body
+        assert dense_equal.renumbered(shifted) == dense_equal.renumbered(body)
+        assert dense_equal.renumbered(dense_equal.canon(changed)[0]) \
+            != dense_equal.renumbered(body)

@@ -37,6 +37,9 @@ JOINED_COUNTERS = ["mixed_step_pct", "host_ms_per_step", "fetch_wait_pct", "gen_
                    "attn_grid_live_pct"]
 LIST_LESS = ["batch_rows_mean", "prefill_tok_pct", "step_device_ms_p50",
              "attn_kernel_ms_per_step", "device_idle_pct"]
+# ... and the program's scopes (PR 38): every one of the six lists this cell
+SCOPES = ["scoped_device_pct", "attn_layer_ms_per_step", "ffn_ms_per_step", "moe_layer_ms_per_step",
+          "conv_mix_ms_per_step", "head_sample_ms_per_step"]
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 PEAKS = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
 # a scrape of what JOINED_COUNTERS' readers read
@@ -246,10 +249,12 @@ def test_the_manifest_gained_one_configuration_one_cell_and_one_metric():
     assert man["workloads"][-1] == {**man["workloads"][-1], "name": CELL, "config": CONFIG,
                                     "traffic": "docs-repeat-long", "chips": 1}
     assert len(man["workloads"][-1]["why"]) <= 200
-    assert [m["name"] for m in man["per_layer"][-1:]] == NEW_METRICS
-    assert man["per_layer"][-1]["workloads"] == [CELL]
-    assert man["per_layer"][-1]["moves"] == "tpot_ms_p50"
     by_name = {m["name"]: m for m in man["per_layer"]}
+    # looked up by name, not counted from the end: later PRs append after it
+    # (PR 38's six scope metrics)
+    for name in NEW_METRICS:
+        assert by_name[name]["workloads"] == [CELL]
+        assert by_name[name]["moves"] == "tpot_ms_p50"
     # a share that could only read 100 is no metric: the restore is held to exactness by the check's
     # `repeat` sequence, and counted by dli_prefix_state_tokens_total
     assert "state_restored_tok_pct" not in by_name
@@ -268,7 +273,8 @@ def test_the_manifest_gained_one_configuration_one_cell_and_one_metric():
     cell = manifest.Cell(man, CELL)
     assert {m["name"] for m in cell.end_to_end} == {"tpot_ms_p50", "setup_s"}
     reported = {m["name"] for m in cell.per_layer}
-    assert reported == set(LIST_LESS) | set(NEW_METRICS) | set(JOINED) | set(JOINED_COUNTERS)
+    assert reported == set(LIST_LESS) | set(NEW_METRICS) | set(JOINED) | set(JOINED_COUNTERS) \
+        | set(SCOPES)
     for other in ACCEPTED:
         assert "step_weight_roofline" in {m["name"] for m in manifest.Cell(man, other).per_layer}
     # the same trace as kanana-docs-long, at a rate of its own

@@ -444,6 +444,20 @@ def _get(base, path, timeout=15):
         return e.code, json.loads(e.read()), dict(e.headers)
 
 
+def _trace_with_its_root(base, trace_id):
+    """The router's view of a trace once `router.request` is in it: that
+    span closes (and is recorded) AFTER the response is written, so a
+    client that asks at once may be ahead of the handler's thread."""
+    deadline = time.monotonic() + 10
+    while True:
+        code, tr, hdrs = _get(base, f"/debug/traces/{trace_id}")
+        rooted = code == 200 and any(
+            sp["name"] == "router.request" for sp in tr["spans"])
+        if rooted or time.monotonic() > deadline:
+            return code, tr, hdrs
+        time.sleep(0.05)
+
+
 def _get_text(base, path, timeout=15):
     with urllib.request.urlopen(base + path, timeout=timeout) as r:
         return r.read().decode()
@@ -485,7 +499,7 @@ def test_fleet_round_trip_single_tree(fleet):
     assert body.get("kv_promoted_blocks", 0) > 0
     assert body.get("prefix_cached_tokens", 0) > 0
 
-    code, tr, _ = _get(base, f"/debug/traces/{ctx.trace_id}")
+    code, tr, _ = _trace_with_its_root(base, ctx.trace_id)
     assert code == 200
     names = {(s["service"], s["name"]) for s in tr["spans"]}
     # every hop of the disaggregated request is present
@@ -595,7 +609,7 @@ def test_fleet_failover_hop_is_retry_span(fleet):
     assert code == 200 and body["status"] == "success", body
     assert body["replica"] == "p0"  # availability beats specialization
     assert body.get("router_attempts", 1) > 1
-    code, tr, _ = _get(base, f"/debug/traces/{ctx.trace_id}")
+    code, tr, _ = _trace_with_its_root(base, ctx.trace_id)
     assert code == 200
     by_name = {}
     for s in tr["spans"]:
