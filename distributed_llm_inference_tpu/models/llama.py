@@ -410,6 +410,12 @@ def decoder_layer(
     +0.0 — IEEE -0.0 + 0.0 would break bit-identity with the no-adapter
     program). Deltas apply BEFORE the tp psums: a/b shard so the partial
     products sum correctly by linearity (parallel/partition.py).
+
+    Under the layer scan `lp`'s leaves are the scan's slices of the stacked
+    parameters, and every projection's dot reads its slice in place. A
+    reshape straight after a scanned weight's product would be moved onto
+    the weight, and the slice then cannot fuse into the dot: q, k and v
+    pass `pin_products` before their head split (its docstring).
     """
     B, T, D = x.shape
     Dh = cfg.head_dim  # invariant under tp (heads shard, head_dim doesn't)
@@ -445,7 +451,7 @@ def decoder_layer(
     with jax.named_scope("attn"):
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps, unit_offset=uo) \
             if cfg.pre_norms else x
-        q, k, v = lmm(h, "wq"), lmm(h, "wk"), lmm(h, "wv")
+        q, k, v = pin_products(lmm(h, "wq"), lmm(h, "wk"), lmm(h, "wv"))
         if cfg.attn_qkv_bias:  # Qwen2-style (biases tp-shard with their columns)
             q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
         if cfg.use_qk_norm and cfg.qk_norm_dim == "proj":
@@ -684,6 +690,26 @@ def _forward_routed(cfg, layers, x, cache, pos, cos, sin, mask, update_gate,
     return x, new
 
 
+def pin_products(*products):
+    """The products of a scanned layer's weights, handed on as they are:
+    whatever reshapes them next stays behind them (ISSUE 39).
+
+    The trap this closes: a reshape straight after `h @ w` (the head split)
+    is moved by the TPU compiler THROUGH the dot onto the weight. Inside
+    the layer scan the weight is the scan's dynamic slice of the stacked
+    leaf, and with a bitcast between that slice and the dot the slice can
+    no longer be an operand fused into the dot: it becomes a loop fusion of
+    its own that copies one layer's weights out a layer-step (the dot then
+    reads the copy), and layout assignment relays the WHOLE stack out once
+    a launch. `wo` / `w_gate` / `w_up` / `w_down` never met it (nothing
+    reshapes their products), nor OLMo-2's q and k (a whole-projection norm
+    stands in between). An einsum onto a `[L, D, H, Dh]` view of the leaf
+    fuses the slice and keeps the stack's relayout; the barrier keeps
+    neither, and is the same `dot_general`s, bit for bit.
+    tests/test_chip_compile.py holds the cells' programs to it."""
+    return jax.lax.optimization_barrier(products)
+
+
 def scan_layers(layer_step, x, layers, cache, *, paged: bool):
     """The layer scan of every family (llama here, gpt2, mla_moe's stacks):
     `layer_step(x, lp, cache, layer) -> (x, cache, ys)` over the stacked
@@ -691,7 +717,12 @@ def scan_layers(layer_step, x, layers, cache, *, paged: bool):
     Dense cache: `layer_step` gets the layer's slices, which are scanned
     over and stacked again. paged: the stacked leaves ride the carry whole
     and `layer` says which layer to touch (forward_layers' docstring).
-    Returns (x, cache, the stacked ys)."""
+    Returns (x, cache, the stacked ys).
+
+    `lp` is the scan's dynamic slice of each stacked leaf, and a dot reads
+    it in place only while nothing stands between the slice and the dot: a
+    `layer_step` that reshapes a weight's product at once (a head split)
+    passes the product through `pin_products` first."""
     index = jnp.arange(jax.tree.leaves(cache)[0].shape[0], dtype=jnp.int32)
     if paged:
         def body(carry, xs):

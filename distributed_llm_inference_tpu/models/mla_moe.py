@@ -63,6 +63,7 @@ from .experts import (  # noqa: F401 - the family's routed half, re-exported
     BANKS, _normal_slices, grouped_matmul, route, routed_expert_matmul,
     routed_ffn,
 )
+from .llama import pin_products, scan_layers  # one scan, dense cache or pool
 
 Params = dict
 F32 = jnp.float32
@@ -218,14 +219,23 @@ def attention(cfg: ModelConfig, lp: Params, x, cache, pos, positions, mask,
     """The attention sublayer on the float32 residual x [B, T, D]; returns
     (its float32 output [B, T, D], the new cache). cache: the layer's slice
     of the dense cache, or under a paged hook the stack's whole pool leaf
-    with `layer`, the layer's index in it."""
+    with `layer`, the layer's index in it.
+
+    `lp`'s leaves are the layer scan's slices of the stacked parameters. A
+    reshape straight after a scanned weight's product is moved onto the
+    weight, and the slice then cannot fuse into the dot: the query's product
+    passes `llama.pin_products` before its head split (its docstring).
+    `w_kvb` below is reshaped and sliced per head by this code itself (the
+    absorbed form's batched dots want the head axis major): its relayout a
+    layer-step is another cause and stays (PERF.md section 7)."""
     B, T, _ = x.shape
     dt = cfg.jnp_dtype
     H, r = cfg.n_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     pad = cfg.latent_row - cfg.latent_dim
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps).astype(dt)
-    q = (h @ lp["wq"]).reshape(B, T, H, dn + dr)
+    (q,) = pin_products(h @ lp["wq"])
+    q = q.reshape(B, T, H, dn + dr)
     q_r = rope_interleaved(q[..., dn:], positions[..., None], cfg.rope_theta)
     kva = h @ lp["w_kva"]
     c = rms_norm(kva[..., :r], lp["kv_norm"], cfg.norm_eps)
@@ -310,8 +320,6 @@ def forward_layers(cfg: ModelConfig, layers: Params, x, cache, pos,
     if live is not None:
         live = jnp.repeat(live, T) if T > 1 else live
     dt = cfg.jnp_dtype
-
-    from .llama import scan_layers  # one scan for the dense cache and the pool
 
     paged = getattr(attn_hook, "paged", False)
 

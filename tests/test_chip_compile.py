@@ -416,13 +416,59 @@ def _pool_sized_instructions(hlo_text, pool):
                   if shape in shapes and op not in _NO_BUFFER)
 
 
-@pytest.mark.parametrize("config", sorted(CELL_PROGRAMS))
-def test_step_programs_hold_no_copy_of_the_pool_at_cell_sizes(
-    one_chip, no_persistent_cache, monkeypatch, config
-):
+def _projection_sized_instructions(hlo_text, layers):
+    """Instructions of a compiled module that write out an attention
+    projection's weights (ISSUE 39): a result with the shape of the stacked
+    leaf `wq` / `wk` / `wv` / `wo` of `layers` (the parameters' tree; the
+    latent family's two stacks each) or of one layer's slice of it, made by
+    anything but a fusion that holds the dot itself. Where a dot reads its
+    layer in place, the scan's `dynamic-slice` sits INSIDE the dot's fused
+    computation and no such instruction exists; a `copy` of a stack (its
+    relayout, once a launch) or a loop fusion around the slice (one layer's
+    weights copied out a layer-step) is what this lists. Not listed: the
+    compiler's own asynchronous prefetches (`copy-start` / `slice-start`
+    and their `-done`: the same layout into another memory space,
+    overlapped), and `w_kvb` / `w_kva` of the latent family, whose copies
+    have other causes (PERF.md section 7)."""
+    import re
+
+    shapes = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(layers)[0]:
+        if getattr(path[-1], "key", None) in ("wq", "wk", "wv", "wo") \
+                and leaf.ndim == 3:
+            dims = [str(d) for d in leaf.shape]
+            shapes |= {",".join(dims), ",".join(["1"] + dims[1:])}
+    assert shapes
+    blocks = re.findall(r"^(?:ENTRY )?%([\w.\-]+) \([^\n]*\{\n(.*?)^\}",
+                        hlo_text, re.M | re.S)
+    fused = {name for name, _ in blocks if "fused_computation" in name}
+    with_dot = {name for name, body in blocks
+                if re.search(r" (dot|convolution)\(", body)}
+    assert fused and fused & with_dot
+    found = []
+    for name, body in blocks:
+        if name in fused:
+            continue  # (a fusion's inner instructions make no buffer)
+        for inst, shape, op, rest in re.findall(
+                r"%([\w.\-]+) = \w+\[([\d,]+)\]\{[^}]*\} ([\w\-]+)\(([^\n]*)",
+                body):
+            if shape not in shapes or op in _NO_BUFFER \
+                    or op.endswith(("-start", "-done")):
+                continue
+            calls = re.search(r"calls=%([\w.\-]+)", rest)
+            if op == "fusion" and calls and calls.group(1) in with_dot:
+                continue
+            found.append(f"{op} {inst} [{shape}]")
+    return sorted(found)
+
+
+@functools.cache  # (several tests of this file read them: a minute a compile)
+def _cell_step_programs(one_chip, config):
+    """(params, pool, {module: compiled}) of a CELL_PROGRAMS configuration:
+    both step programs at the cell's sizes and depth. The caller has set
+    DLI_PALLAS_INTERPRET=0."""
     import numpy as np
 
-    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
     model, layers, slots, blocks, context = CELL_PROGRAMS[config]
     cfg = resolve_attn_impl(
         get_model_config(model).replace(n_layers=layers, dtype="bfloat16"),
@@ -433,8 +479,6 @@ def test_step_programs_hold_no_copy_of_the_pool_at_cell_sizes(
     params = place(jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0))))
     state, sparams = place(jax.eval_shape(lambda: G.init_slots(slots, cfg.vocab_size)))
     pool = place(jax.eval_shape(lambda: EP.init_pool(cfg, blocks, 128)))
-    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
-    assert pool_bytes > 2e9
     table = S((slots, context // 128), jnp.int32)
     key = place(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     chunk = EP.decode_slots_paged.lower(
@@ -457,8 +501,19 @@ def test_step_programs_hold_no_copy_of_the_pool_at_cell_sizes(
         S((width,), jnp.bool_), flat(meta), pool, table, state, sparams, key,
         S((slots,), jnp.int32), arm, dev=dev,
     ).compile()
-    for name, compiled in (("decode_slots_paged", chunk),
-                           ("mixed_step_ragged", mixed)):
+    return params, pool, {"decode_slots_paged": chunk,
+                          "mixed_step_ragged": mixed}
+
+
+@pytest.mark.parametrize("config", sorted(CELL_PROGRAMS))
+def test_step_programs_hold_no_copy_of_the_pool_at_cell_sizes(
+    one_chip, no_persistent_cache, monkeypatch, config
+):
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    _, pool, programs = _cell_step_programs(one_chip, config)
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
+    assert pool_bytes > 2e9
+    for name, compiled in programs.items():
         memory = compiled.memory_analysis()
         # the pool goes in and comes out as one buffer ...
         assert memory.alias_size_in_bytes >= pool_bytes - 2**20, (name, memory)
@@ -469,6 +524,27 @@ def test_step_programs_hold_no_copy_of_the_pool_at_cell_sizes(
         _assert_scopes(text, name, DENSE_SCOPES + (
             ROUTED_SCOPES + ("moe_shared", "mla_absorb")
             if config.startswith("kanana") else ()))
+
+
+@pytest.mark.parametrize("config", sorted(CELL_PROGRAMS) + ["sdar-30b-a3b-7l"])
+def test_step_programs_read_the_attention_projections_in_place(
+    one_chip, no_persistent_cache, monkeypatch, config
+):
+    """ISSUE 39: in both step programs of the four scanned configurations,
+    at the cells' sizes and with every routed or dense stack but kanana's
+    one leading layer longer than one, q / k / v (kanana: the query
+    projection) and `wo` are read by their dots from the stacked parameter:
+    no slice copy a layer-step, no relayout of a stack a launch
+    (`models/llama.pin_products` says what made them)."""
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    if config in CELL_PROGRAMS:
+        params, _, programs = _cell_step_programs(one_chip, config)
+        texts = {name: c.as_text() for name, c in programs.items()}
+    else:
+        params, texts = _block_diffusion_step_programs(one_chip)
+    assert set(texts) == {"decode_slots_paged", "mixed_step_ragged"}
+    for name, text in texts.items():
+        assert _projection_sized_instructions(text, params["layers"]) == [], name
 
 
 # -- the latent-attention, routed-expert family (ISSUE 28) ---------------------
@@ -613,21 +689,15 @@ def test_ragged_kernel_compiles_with_the_block_mask(
     assert any("ragged_paged_attend" in c for c in _custom_call_names(text))
 
 
-def test_block_diffusion_step_programs_carry_the_names_a_trace_is_read_by(
-    one_chip, no_persistent_cache, monkeypatch
-):
-    """sdar-30b-a3b-chat (cut to 2 layers) at sdar-batch's sizes: both step
-    programs compile for the chip, keep their module names, read the pool
-    through the ragged kernel (a decode row is one tile of 8: its owed and
-    open blocks, 32 slots x 8 = 256 flat tokens in the decode chunk) and run
-    the routed experts' grouped kernel under the scopes kanana's do."""
-    import json
-    import os
-    import re
-
+@functools.cache
+def _block_diffusion_step_programs(one_chip):
+    """(params, {module: optimized HLO text}) of sdar-30b-a3b-chat cut to 2
+    layers at sdar-batch's sizes: a decode row is one tile of 8 (its owed
+    and open blocks, 32 slots x 8 = 256 flat tokens in the decode chunk),
+    the mixed launch 512 wide. Compiled once for the tests that read them;
+    the caller has set DLI_PALLAS_INTERPRET=0."""
     import numpy as np
 
-    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
     cfg = resolve_attn_impl(
         get_model_config("sdar-30b-a3b-chat").replace(n_layers=2, dtype="bfloat16"),
         "pallas",
@@ -666,6 +736,23 @@ def test_block_diffusion_step_programs_carry_the_names_a_trace_is_read_by(
         S((width,), jnp.bool_), flat(meta), pool, table, state, sparams, key,
         S((slots,), jnp.int32), arm, dev=dev, diff=diff, darm=darm,
     ).compile().as_text()
+    return params, {"decode_slots_paged": chunk, "mixed_step_ragged": mixed}
+
+
+def test_block_diffusion_step_programs_carry_the_names_a_trace_is_read_by(
+    one_chip, no_persistent_cache, monkeypatch
+):
+    """sdar-30b-a3b-chat (cut to 2 layers) at sdar-batch's sizes: both step
+    programs compile for the chip, keep their module names, read the pool
+    through the ragged kernel (a decode row is one tile of 8: its owed and
+    open blocks, 32 slots x 8 = 256 flat tokens in the decode chunk) and run
+    the routed experts' grouped kernel under the scopes kanana's do."""
+    import json
+    import os
+    import re
+
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    _, texts = _block_diffusion_step_programs(one_chip)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "cellbench", "configs",
                            "sdar-30b-a3b-7l.json")) as f:
@@ -673,8 +760,8 @@ def test_block_diffusion_step_programs_carry_the_names_a_trace_is_read_by(
     assert set(trace["expert_kernels"]) == EXPERT_KERNELS
     assert set(trace["step_modules"]) == {"decode_slots_paged", "mixed_step_ragged"}
     # the decode chunk's flat axis: 32 slots x 2 blocks of 4 = 256 tokens
-    assert "bf16[256,2048]" in chunk
-    for module, text in (("decode_slots_paged", chunk), ("mixed_step_ragged", mixed)):
+    assert "bf16[256,2048]" in texts["decode_slots_paged"]
+    for module, text in texts.items():
         assert module in _module_name(text)
         calls = _custom_call_names(text)
         for name in ("ragged_paged_attend", *trace["expert_kernels"]):
