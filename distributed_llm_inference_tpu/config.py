@@ -25,7 +25,7 @@ class ModelConfig:
     """
 
     name: str = "tinyllama-1.1b"
-    arch: str = "llama"  # "llama" | "gpt2" | "mla_moe" | "lfm2"
+    arch: str = "llama"  # "llama" | "gpt2" | "mla_moe" | "lfm2" | "afmoe"
     vocab_size: int = 32000
     dim: int = 2048
     n_layers: int = 22
@@ -139,6 +139,14 @@ class ModelConfig:
     # added to the sum of the chosen scores before they are renormalized
     # (lfm2's modelling code: 1e-6; the other routed families add nothing)
     router_norm_eps: float = 0.0
+    # One chip's share of an expert-parallel deployment (arch "afmoe"): the
+    # router stays n_experts wide and this chip holds published experts
+    # expert_lo .. expert_lo + n_experts_held - 1 (0: all of them). A pair
+    # routed to an expert held elsewhere is left out, in the program and in
+    # the reference alike (models/experts.routed_ffn); nothing stands in for
+    # the other chips or their exchange.
+    expert_lo: int = 0
+    n_experts_held: int = 0
     # Gated short convolutions beside attention (arch "lfm2",
     # models/lfm2.py: LFM2-24B-A2B). layer_types names each layer's
     # operator, "conv" or "full_attention"; a conv layer keeps the last
@@ -146,6 +154,11 @@ class ModelConfig:
     # and layer (engine/paged.py: a live leaf a slot, a tail a block), and
     # only the attention layers own K/V. The first first_k_dense layers
     # carry a dense SwiGLU of ffn_dim, the others routed experts.
+    # Arch "afmoe" (models/afmoe.py: Trinity) names each layer
+    # "sliding_attention" (reads the last attn_window positions, takes RoPE)
+    # or "full_attention" (reads everything, takes no position encoding);
+    # every layer owns K/V, and the paged pool keeps the two kinds in two
+    # groups of blocks (engine/paged.py: `kv_groups`).
     layer_types: Optional[tuple] = None
     conv_kernel: int = 0
     # Generation by block diffusion (SDAR): 0 = autoregressive. > 0: the
@@ -204,7 +217,8 @@ class ModelConfig:
         if self.router_score is None:
             object.__setattr__(
                 self, "router_score",
-                "sigmoid" if self.arch in ("mla_moe", "lfm2") else "softmax",
+                "sigmoid" if self.arch in ("mla_moe", "lfm2", "afmoe")
+                else "softmax",
             )
         if self.router_score not in ("sigmoid", "softmax"):
             raise ValueError(
@@ -324,8 +338,37 @@ class ModelConfig:
                     "arch 'lfm2' needs n_experts, moe_ffn_dim and "
                     "first_k_dense < n_layers (an expert stack)"
                 )
+        elif self.arch == "afmoe":
+            kinds = self.layer_types or ()
+            if (len(kinds) != self.n_layers
+                    or set(kinds) - {"sliding_attention", "full_attention"}):
+                raise ValueError(
+                    f"arch 'afmoe' needs layer_types: n_layers "
+                    f"({self.n_layers}) entries of 'sliding_attention' / "
+                    f"'full_attention'; got {kinds!r}"
+                )
+            if not (self.n_experts and self.moe_ffn_dim
+                    and 0 <= self.first_k_dense < self.n_layers):
+                raise ValueError(
+                    "arch 'afmoe' needs n_experts, moe_ffn_dim and "
+                    "first_k_dense < n_layers (an expert stack)"
+                )
+            if self.conv_kernel:
+                raise ValueError("conv_kernel is arch 'lfm2' only")
         elif self.layer_types is not None or self.conv_kernel:
-            raise ValueError("layer_types / conv_kernel are arch 'lfm2' only")
+            raise ValueError(
+                "layer_types is arch 'lfm2' / 'afmoe' only, conv_kernel "
+                "arch 'lfm2' only")
+        if self.expert_lo or self.n_experts_held:
+            if self.arch != "afmoe":
+                raise ValueError("an expert share (expert_lo, "
+                                 "n_experts_held) is arch 'afmoe' only")
+            if not (0 <= self.expert_lo
+                    and self.expert_lo + self.experts_held <= self.n_experts):
+                raise ValueError(
+                    f"experts {self.expert_lo} .. {self.expert_lo} + "
+                    f"{self.experts_held} are not all of the router's "
+                    f"{self.n_experts}")
         if self.diffusion_block:
             if self.arch != "llama" or self.mask_token_id is None:
                 raise ValueError(
@@ -337,7 +380,7 @@ class ModelConfig:
         if self.moe_ffn_dim and not self.n_experts:
             raise ValueError("moe_ffn_dim > 0 needs n_experts > 0")
         if self.n_experts:
-            if self.arch not in ("llama", "mla_moe", "lfm2"):
+            if self.arch not in ("llama", "mla_moe", "lfm2", "afmoe"):
                 raise ValueError("MoE (n_experts > 0) is llama-family only")
             if not 1 <= self.n_experts_per_tok <= self.n_experts:
                 raise ValueError(
@@ -360,10 +403,38 @@ class ModelConfig:
     def attn_layers(self) -> tuple:
         """The layers that own K/V: every layer, or arch 'lfm2''s
         attention layers."""
-        if self.layer_types is None:
+        if self.layer_types is None or self.arch == "afmoe":
             return tuple(range(self.n_layers))
         return tuple(i for i, kind in enumerate(self.layer_types)
                      if kind == "full_attention")
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose banks live here (n_experts: no share)."""
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def kv_groups(self) -> tuple:
+        """The paged pool's groups of K/V layers, each with its own blocks
+        and block table (engine/paged.py): ("global",) for a model whose
+        layers all keep their whole context (a uniform window is masked,
+        never given back), ("global", "window") for arch 'afmoe' with both
+        kinds of layer, where a window layer gives back the blocks it can
+        no longer read."""
+        kinds = set(self.layer_types or ()) if self.arch == "afmoe" else ()
+        if kinds == {"sliding_attention", "full_attention"} \
+                and self.attn_window:
+            return ("global", "window")
+        return ("global",)
+
+    def group_layers(self, group: str) -> tuple:
+        """The layers (indices in the stack) whose K/V the pool's `group`
+        holds: with two groups the full-attention layers / the sliding
+        ones, else every layer that owns K/V."""
+        if len(self.kv_groups) == 1:
+            return self.attn_layers
+        kind = "full_attention" if group == "global" else "sliding_attention"
+        return tuple(i for i, k in enumerate(self.layer_types) if k == kind)
 
     @property
     def kv_pack(self) -> int:

@@ -114,11 +114,28 @@ class BlockPrefixIndex:
     mapping instead of prefill+scatter).
     """
 
-    def __init__(self, alloc, block_size: int, registry=None):
+    def __init__(self, alloc, block_size: int, registry=None, side=None,
+                 window: int = 0):
         if block_size < 1:
             raise ValueError("block prefix index needs block_size >= 1")
         self._alloc = alloc
         self.block_size = int(block_size)
+        # A grouped pool (engine/paged.WindowBlocks): `side` is the window
+        # group's allocator, and a shared depth's entry names one block of
+        # each group: _side_of maps a cached global block to the window
+        # block of the same positions, one index reference each. A window
+        # block leaves when its entry is evicted, or alone when the window
+        # group runs dry (`evict_side`); its entry then stays, and a hit
+        # at block-floored depth p0 is valid only where every block that
+        # overlaps [p0 - (window - 1), p0) is still here (`lookup`).
+        # Insertion order is the LRU order, promoted with the entries'.
+        self._side = side
+        self._window = int(window)
+        self._side_of: "collections.OrderedDict[int, int]" = (
+            collections.OrderedDict()
+        )
+        self._cut = None  # the last lookup's hit lost depth to evictions
+        self.side_cut_hits = 0
         # planner-protocol granularity (engine._prefix_plan degrades the
         # reuse depth in steps of `chunk` when the deepest offset leaves
         # a tail no prefill bucket fits)
@@ -165,6 +182,17 @@ class BlockPrefixIndex:
                 "prompt tokens served by mapping shared blocks instead "
                 "of prefilling them",
             ).labels()
+        self._m_window = None
+        if registry is not None and side is not None:
+            self._m_window = registry.counter(
+                "dli_prefix_hits_total",
+                "lookups that found a cached chain, by the window group's "
+                "part: resident = every window block the depth needs was "
+                "there, evicted = the hit was shortened or lost because "
+                "some were gone", ("window",),
+            )
+            for state in ("resident", "evicted"):
+                self._m_window.labels(window=state)
 
     # -- planner interface (engine._prefix_plan) ----------------------------
     def lookup(self, ids: list, adapter=None) -> tuple[int, Optional[list],
@@ -199,9 +227,69 @@ class BlockPrefixIndex:
                 blocks.append(b)
                 keys.append(key)
                 parent = b
+            n = self._side_depth(blocks)
+        self._cut = None if not blocks or self._side is None else \
+            n < len(blocks)
+        blocks, keys = blocks[:n], keys[:n]
         if not blocks:
             return 0, None, None
         return len(blocks) * bs, blocks, tuple(keys)
+
+    def _side_lo(self, n: int) -> int:
+        """The first logical block a query at depth n blocks still reads in
+        the window group."""
+        return max(0, n * self.block_size - self._window + 1) \
+            // self.block_size
+
+    def _side_depth(self, blocks: list) -> int:
+        """The deepest depth (in blocks) of a cached chain at which the
+        window group still holds every block the depth's first query reads
+        (lock held). A pool of one group: the chain's own."""
+        if self._side is None:
+            return len(blocks)
+        gone = [0]
+        for b in blocks:
+            gone.append(gone[-1] + (b not in self._side_of))
+        for n in range(len(blocks), 0, -1):
+            if gone[n] == gone[self._side_lo(n)]:
+                return n
+        return 0
+
+    def side_blocks(self, blocks: list) -> tuple:
+        """(first logical block, the window group's blocks) a hit on the
+        cached chain `blocks` (lookup's) maps."""
+        lo = self._side_lo(len(blocks))
+        with self._lock:
+            return lo, [self._side_of[b] for b in blocks[lo:]]
+
+    def side_evictable(self) -> int:
+        with self._lock:
+            return sum(1 for w in self._side_of.values()
+                       if self._side.refcount(w) == 1)
+
+    def evict_side(self, n: int) -> int:
+        """Free >= n of the window group's cached blocks that no row maps:
+        least recently used document first and from its shallow end, so
+        the blocks no reachable hit depth needs (those below a depth's
+        window whose neighbours already went) go before the last window of
+        a document, which every deep hit reads."""
+        with self._lock:
+            victims = []
+            for g, w in self._side_of.items():
+                if len(victims) >= n:
+                    break
+                if self._side.refcount(w) == 1:
+                    victims.append((g, w))
+            for g, w in victims:
+                del self._side_of[g]
+            if victims:
+                self._side.decref([w for _, w in victims])
+        return len(victims)
+
+    def side_stats(self) -> dict:
+        with self._lock:
+            return {"cached_blocks": len(self._side_of),
+                    "cut_hits": self.side_cut_hits}
 
     def mark(self, key: Optional[tuple], hit: bool, depth: int = 0) -> None:
         """Record the request outcome; a REAL hit (tail planned and
@@ -211,12 +299,19 @@ class BlockPrefixIndex:
         the full chain (engine._prefix_plan), and only the mapped tokens
         count as saved."""
         saved = 0
+        cut, self._cut = self._cut, None
+        if cut is not None and self._m_window is not None:
+            self._m_window.labels(
+                window="evicted" if cut else "resident").inc()
+        self.side_cut_hits += bool(cut)
         with self._lock:
             if hit:
                 self.hits += 1
                 for k in key or ():
                     if k in self._entries:
                         self._entries.move_to_end(k)
+                        if self._entries[k] in self._side_of:
+                            self._side_of.move_to_end(self._entries[k])
                 saved = (
                     depth if depth else len(key or ()) * self.block_size
                 )
@@ -232,40 +327,54 @@ class BlockPrefixIndex:
 
     # -- cache mutation (worker thread) --------------------------------------
     def register(self, ids: list, prompt_len: int, row_blocks: list,
-                 adapter=None) -> int:
+                 adapter=None, side_blocks=None, resume=None):
         """Index the admitted prompt's FULL blocks (positions below
         prompt_len // bs * bs — complete, immutable once the insert
         scatter lands). Blocks already cached (the mapped shared head, or
         a chain another request registered) are promoted, not re-added;
         each newly cached block gains the index's own reference. Adapter
         chains register under their adapter's root (see lookup). Returns
-        the number of newly cached blocks."""
+        the number of newly cached blocks.
+
+        side_blocks (a grouped pool): {logical block: the window group's
+        block the row holds there}; the cached block of that depth, new or
+        old, takes it as its window block where it has none (one index
+        reference), so a document registered chunk by chunk keeps the
+        blocks its row gives back. resume: (depth in blocks, that depth's
+        parent) a former call of the same prompt returned through
+        `self.resume`: the walk starts there."""
         bs = self.block_size
         n_full = prompt_len // bs
-        parent = _root_for(adapter)
+        first, parent = resume or (0, _root_for(adapter))
         new = 0
         with self._lock:
-            for i in range(n_full):
+            for i in range(first, n_full):
                 key = (parent, tuple(ids[i * bs : (i + 1) * bs]))
                 b = self._entries.get(key)
                 if b is not None:
                     self._entries.move_to_end(key)
-                    parent = b
-                    continue
-                b = int(row_blocks[i])
-                if b in self._block_key:
-                    # a block can hold at most one entry (free-listed
-                    # blocks are never cached; eviction removes the entry
-                    # before the block can recycle) — defensive skip
-                    parent = b
-                    continue
-                self._entries[key] = b
-                self._block_key[b] = key
-                self._children.setdefault(parent, set()).add(key)
-                self._alloc.incref([b])
-                new += 1
+                    if b in self._side_of:
+                        self._side_of.move_to_end(b)
+                else:
+                    b = int(row_blocks[i])
+                    if b in self._block_key:
+                        # a block can hold at most one entry (free-listed
+                        # blocks are never cached; eviction removes the
+                        # entry before the block can recycle) — defensive
+                        parent = b
+                        continue
+                    self._entries[key] = b
+                    self._block_key[b] = key
+                    self._children.setdefault(parent, set()).add(key)
+                    self._alloc.incref([b])
+                    new += 1
+                if side_blocks and i in side_blocks \
+                        and b not in self._side_of:
+                    self._side_of[b] = side_blocks[i]
+                    self._side.incref([side_blocks[i]])
                 parent = b
             n_entries = len(self._entries)
+        self.resume = (max(first, n_full), parent)
         if self._m_entries is not None:
             self._m_entries.set(n_entries)
         return new
@@ -360,6 +469,9 @@ class BlockPrefixIndex:
         decref returns each block to the free list — refcount was 1."""
         b = self._entries.pop(key)
         self._block_key.pop(b, None)
+        w = self._side_of.pop(b, None)
+        if w is not None:
+            self._side.decref([w])
         parent_children = self._children.get(key[0])
         if parent_children is not None:
             parent_children.discard(key)
@@ -389,6 +501,9 @@ class BlockPrefixIndex:
             self._entries.clear()
             self._children.clear()
             self._block_key.clear()
+            if self._side_of:
+                self._side.decref(list(self._side_of.values()))
+                self._side_of.clear()
             self.evictions += len(blocks)
             if blocks:
                 self._alloc.decref(blocks)

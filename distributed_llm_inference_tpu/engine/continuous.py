@@ -121,9 +121,9 @@ from ..utils.logging import get_logger
 from ..utils.metrics import (
     ADMISSION_WAIT_HELP, CONV_STATE_RESETS_HELP, CONV_TAIL_WRITES_HELP,
     DEFAULT_SIZE_BUCKETS, DIFFUSION_FORWARDS_HELP, DIFFUSION_FUSED_HELP,
-    DIFFUSION_TOKENS_HELP, PREFIX_STATE_TOKENS_HELP, SLOT_RELEASE_HELP,
-    SLOT_TURNOVER_HELP,
-    STEPS_AHEAD_BUCKETS,
+    DIFFUSION_TOKENS_HELP, KV_GROUP_BLOCKS_HELP, KV_WINDOW_RELEASED_HELP,
+    MOE_PAIRS_HELP, PREFIX_STATE_TOKENS_HELP, SLOT_RELEASE_HELP,
+    SLOT_TURNOVER_HELP, STEPS_AHEAD_BUCKETS,
 )
 from ..utils.retry import overload_retry_after
 from ..utils.tracing import PhaseClock, Trace, abstract_call, sample_decision
@@ -302,9 +302,9 @@ class ContinuousEngine:
         restore_dir: Optional[str] = None,
     ):
         cfg = engine.cfg
-        if cfg.arch not in ("llama", "gpt2", "mla_moe", "lfm2"):
+        if cfg.arch not in ("llama", "gpt2", "mla_moe", "lfm2", "afmoe"):
             raise ValueError(
-                f"continuous batching supports the llama, gpt2, mla_moe and "
+                f"continuous batching supports the llama, gpt2, mla_moe, afmoe and "
                 f"lfm2 families; model arch is {cfg.arch!r}"
             )
         if cfg.conv_layers:
@@ -430,6 +430,28 @@ class ContinuousEngine:
                     f"or shrink slot_max_seq"
                 )
             self._pool_blocks = int(kv_pool_blocks)
+            # A pool grouped by layer kind (cfg.kv_groups: window and
+            # global layers in one stack, models/afmoe.py): this
+            # allocator, `_table` and `req.block_ids` are the GLOBAL
+            # group's, as for every model; the window group has its own
+            # free list and tables (engine/paged.WindowBlocks), sized from
+            # the same number (`group_blocks`), and a launch carries both
+            # tables side by side (`_launch_table`).
+            self._wgrp = None
+            if len(cfg.kv_groups) > 1:
+                from .scheduler import step_width
+
+                launch = max(self.chunk_steps, step_width(
+                    cfg, self.n_slots, 8,
+                    engine.engine_cfg.step_token_budget))
+                self._group_blocks = P.group_blocks(
+                    cfg, self._pool_blocks, P.window_row_budget(
+                        cfg.attn_window, launch, self.kv_block_size),
+                    self.n_slots)
+                self._wgrp = P.WindowBlocks(
+                    self._group_blocks[1], self.n_slots, self._max_blocks,
+                    self.kv_block_size, cfg.attn_window, launch,
+                )
             self.cache = self._init_pool()
             self._alloc = P.BlockAllocator(
                 self._pool_blocks, registry=engine.metrics
@@ -453,6 +475,7 @@ class ContinuousEngine:
                 // self._ragged_tile
             ) * self._ragged_tile
         else:
+            self._wgrp = None
             self._ragged = False
             self._ragged_tile = 8
             self._scratch_seq = self.slot_max_seq
@@ -531,7 +554,9 @@ class ContinuousEngine:
         # never from what the slot's previous tenant left; no row is
         # drafted for (a rejected token would already be in the state).
         self._recurrent = bool(cfg.conv_layers)
-        if self._recurrent:
+        if self._recurrent or self._wgrp is not None:
+            # (a grouped pool: the position model decides which window
+            # blocks a row holds, so it has to be exact: no verify rows)
             self._P.refuse_unsupported_latent(
                 cfg, spec=self._spec_auto
                 or bool(getattr(ecfg, "spec_draft_model", None)),
@@ -672,10 +697,14 @@ class ContinuousEngine:
             if self.paged:
                 from .block_prefix import BlockPrefixIndex
 
+                side = {} if self._wgrp is None else {
+                    "side": self._wgrp.alloc, "window": cfg.attn_window}
                 self._bpx = BlockPrefixIndex(
                     self._alloc, self.kv_block_size,
-                    registry=engine.metrics,
+                    registry=engine.metrics, **side,
                 )
+                if self._wgrp is not None:
+                    self._wgrp.index = self._bpx
             else:
                 from .prefix import PrefixCache
 
@@ -854,6 +883,16 @@ class ContinuousEngine:
             if self.cfg.attn_window_pattern == "all"
             and self.cfg.attn_window_layer_types is None else None
         )
+        # a stack of window and global layers (cfg.layer_types) is counted
+        # per kind: (layers, window) of each, the global kind first
+        self._kv_kinds = None
+        if self.cfg.arch == "afmoe":
+            self._kv_window = None
+            slide = sum(k == "sliding_attention"
+                        for k in self.cfg.layer_types)
+            if self.cfg.attn_window and slide:
+                self._kv_kinds = ((self.cfg.n_layers - slide, None),
+                                  (slide, self.cfg.attn_window))
         # whether attention reads the pool through the paged kernels'
         # block walk (_kv_walk) or gathers whole tables
         self._kv_walks = self.paged and self.cfg.attn_impl == "pallas"
@@ -1051,6 +1090,22 @@ class ContinuousEngine:
         # without them. The counts ride each launch's packed fetch.
         routed = self.cache.get("routed") if self.paged else None
         self._routed_shape = None if routed is None else tuple(routed.shape)
+        # one chip's share of the experts: the counts' last column is the
+        # pairs routed to experts held elsewhere (models/afmoe.py)
+        self._expert_share = bool(
+            routed is not None and self.cfg.experts_held < self.cfg.n_experts
+        )
+        self._m_moe_pairs = m.counter(
+            "dli_moe_pairs_total", MOE_PAIRS_HELP, ("where",),
+        )
+        self._m_group_blocks = m.gauge(
+            "dli_kv_group_blocks", KV_GROUP_BLOCKS_HELP, ("group", "state"),
+        )
+        self._m_win_released = m.counter(
+            "dli_kv_window_blocks_released_total", KV_WINDOW_RELEASED_HELP,
+        ).labels()
+        self._groups_noted = 0.0
+        self._note_groups()
         self._m_moe_tokens = m.counter(
             "dli_moe_expert_tokens_total",
             "token-expert pairs the routed experts computed", ("phase",),
@@ -1718,6 +1773,16 @@ class ContinuousEngine:
             }
             if self._ragged:
                 out["paged"]["ragged_width"] = self._ragged_width
+            if self._wgrp is not None:
+                # (the numbers above are the global group's)
+                out["paged"]["window_group"] = {
+                    "pool_blocks": self._wgrp.alloc.n_blocks,
+                    "free_blocks": self._wgrp.alloc.free_blocks,
+                    "row_budget": self._wgrp.row_budget,
+                    "released_blocks": self._wgrp.released,
+                    **(self._bpx.side_stats() if self._bpx is not None
+                       else {}),
+                }
         if self._shadow is not None:
             out["shadow"] = {
                 **self._shadow.stats(),
@@ -1861,6 +1926,8 @@ class ContinuousEngine:
             # cached chains point into the pool buffer the rebuild below
             # replaces — drop them (and the index's refs) wholesale
             self._bpx.clear()
+        if self._wgrp is not None:
+            self._wgrp.reset()  # (the index dropped its references above)
         if self.paged:
             self._table[:] = 0
             self._table_dev = None
@@ -1914,9 +1981,65 @@ class ContinuousEngine:
         """The fleet's zeroed pool; with a state a slot where the model has
         recurrent layers (engine/paged.init_pool)."""
         state = {"n_slots": self.n_slots} if self.cfg.conv_layers else {}
+        blocks = self._pool_blocks if self._wgrp is None \
+            else self._group_blocks
         return self.backend.init_paged_pool(
-            self._pool_blocks, self.kv_block_size, **state
+            blocks, self.kv_block_size, **state
         )
+
+    def _launch_table(self):
+        """The block tables a launch carries: the global group's, with the
+        window group's beside it on the block axis where the pool has one
+        (engine/paged._group_table cuts them apart again)."""
+        if self._wgrp is None:
+            return self._snapshot(self._table)
+        return self._snapshot(
+            np.concatenate([self._table, self._wgrp.table], axis=1))
+
+    def _note_groups(self, every: float = 0.5):
+        """dli_kv_group_blocks, at most every `every` seconds (the cached
+        count walks the index)."""
+        now = time.monotonic()
+        if not self.paged or now - self._groups_noted < every:
+            return
+        self._groups_noted = now
+        bpx = self._bpx
+        groups = [("global", self._alloc,
+                   bpx.evictable_blocks() if bpx is not None else 0)]
+        if self._wgrp is not None:
+            groups.append(("window", self._wgrp.alloc,
+                           bpx.side_evictable() if bpx is not None else 0))
+        for group, alloc, cached in groups:
+            g = self._m_group_blocks
+            g.labels(group=group, state="free").set(alloc.free_blocks)
+            g.labels(group=group, state="cached").set(cached)
+            g.labels(group=group, state="live").set(
+                alloc.outstanding - cached)
+
+    def _window_ensure(self, rows):
+        """Before a launch is built: a window-group block for every
+        position the launch's rows write ((slot, first position, tokens)
+        each; a pool of one group has nothing to do)."""
+        if self._wgrp is None:
+            return
+        changed = False
+        for b, start, n in rows:
+            changed |= self._wgrp.ensure(b, start, n)
+        if changed:
+            self._table_dev = None
+
+    def _window_release(self, rows):
+        """After the launch's dispatch: each row gives back the window
+        blocks no later query of it can read. The launch holds its own
+        snapshot of the tables, and the device runs launches in order."""
+        if self._wgrp is None:
+            return
+        before = self._wgrp.released
+        for b, start, n in rows:
+            self._wgrp.release_below(b, start + n - 1)
+        if self._wgrp.released != before:
+            self._m_win_released.inc(self._wgrp.released - before)
+            self._table_dev = None
 
     def _shadow_capture(self, req: _Request, written: Optional[int] = None):
         """Hand req's newly FILLED pool blocks to the shadow copier
@@ -2809,6 +2932,14 @@ class ContinuousEngine:
         isolates a poison request within poison_strikes restarts while
         the rest of the fleet survives."""
         try:
+            if self._wgrp is not None and self._recovery:
+                # a grouped pool takes chunked admissions only: the
+                # salvaged requests go back to the FRONT of the queue and
+                # re-enter as jobs (prompt + what they had generated)
+                with self._cv:
+                    self._queue[:0] = self._recovery
+                    self._recovery.clear()
+                    self._note_queue_locked()
             while self._recovery:
                 if self._closed:
                     # close() fails queued + assigned requests, but the
@@ -3035,16 +3166,37 @@ class ContinuousEngine:
             self._m_sched_tiles.labels(state="live").inc(rec["tiles_live"])
         return rec
 
-    def _kv_span(self, start, length=1):
+    def _kv_fields(self, attended, walked) -> dict:
+        """The launch record's KV counts from attended(window) /
+        walked(window), the launch's sums under a layer's window (None:
+        the whole context). A stack of one kind: `kv_tokens`,
+        `kv_grid_tokens`, per layer and K/V head. A stack of window and
+        global layers adds each kind's own (`kv_tokens_global`, ...,
+        per layer OF THE KIND), and `kv_tokens` / `kv_grid_tokens` are
+        then the kinds' sums by their layer counts, so that attended /
+        walked stays a share of one thing."""
+        if self._kv_kinds is None:
+            return {"kv_tokens": attended(self._kv_window),
+                    "kv_grid_tokens": walked(self._kv_window)}
+        out = {"kv_tokens": 0, "kv_grid_tokens": 0}
+        for name, (layers, window) in zip(("global", "window"),
+                                          self._kv_kinds):
+            a, w = int(attended(window)), int(walked(window))
+            out[f"kv_tokens_{name}"], out[f"kv_grid_tokens_{name}"] = a, w
+            out["kv_tokens"] += layers * a
+            out["kv_grid_tokens"] += layers * w
+        return out
+
+    def _kv_span(self, start, length=1, window=-1):
         """KV positions a row must read whose last query sits at
         `start + length - 1`: everything up to and including it, clipped
-        to a uniform sliding window (numpy-broadcasting)."""
+        to the sliding window (-1: the model's uniform one, None: none;
+        numpy-broadcasting)."""
         n = start + length
-        return n if self._kv_window is None else np.minimum(
-            n, self._kv_window
-        )
+        window = self._kv_window if window == -1 else window
+        return n if window is None else np.minimum(n, window)
 
-    def _kv_walk(self, start, length=1):
+    def _kv_walk(self, start, length=1, window=-1):
         """KV positions the paged kernels' block loop covers for a query
         tile of `length` queries from position `start`: (needed - first)
         x block size, ops/paged_attention._ragged_live_range's arithmetic
@@ -3056,10 +3208,11 @@ class ContinuousEngine:
             return np.full(np.broadcast(start, length).shape,
                            self._scratch_seq)
         bs = self.kv_block_size
+        window = self._kv_window if window == -1 else window
         last = start + np.maximum(length, 1) - 1
         needed = np.clip(-(-(last + 1) // bs), 1, self._max_blocks)
-        first = 0 if self._kv_window is None else np.minimum(
-            np.maximum(start - self._kv_window + 1, 0) // bs, needed - 1
+        first = 0 if window is None else np.minimum(
+            np.maximum(start - window + 1, 0) // bs, needed - 1
         )
         return np.where(length > 0, (needed - first) * bs, 0)
 
@@ -3153,9 +3306,19 @@ class ContinuousEngine:
             r.prompt for r in self._assignment if r is not None
         ))
         pages = None
+        wrows = []
+        if self._wgrp is not None:
+            wrows = [
+                (b, int(self._host_pos[b]),
+                 int(min(self.chunk_steps,
+                         self._host_end[b] - self._host_pos[b])))
+                for b, r in enumerate(self._assignment)
+                if r is not None and self._host_pos[b] < self._host_end[b]
+            ]
+            self._window_ensure(wrows)
         if self.paged:
             if self._table_dev is None:
-                self._table_dev = self._snapshot(self._table)
+                self._table_dev = self._launch_table()
             # adapter serving: the per-slot page snapshot rides every
             # launch (pages=None when no pool is attached — a DISTINCT
             # compiled program that lowers byte-identically to the
@@ -3185,8 +3348,9 @@ class ContinuousEngine:
             )
         rec = self._launch_record(
             "chunk", K,
-            kv_tokens=np.sum(self._kv_span(at, span) * alive),
-            kv_grid_tokens=np.sum(self._kv_walk(at, alive * span)),
+            **self._kv_fields(
+                lambda w: np.sum(self._kv_span(at, span, w) * alive),
+                lambda w: np.sum(self._kv_walk(at, alive * span, w))),
             row_steps=int(live.sum()),
             decode_rows=int(np.count_nonzero(live)), **diff_fields,
         )
@@ -3235,6 +3399,7 @@ class ContinuousEngine:
         packed = G.pack_chunk(emitted, mask, self.state.active)
         if self._routed_shape is not None:
             packed = self._P.pack_routed(packed, self.cache["routed"])
+        self._window_release(wrows)
         t_launch = self._clock.mark("plan")
         if self._trace_rate > 0.0:
             self._prof_note_launch(t_launch, snapshot, rec)
@@ -3337,6 +3502,7 @@ class ContinuousEngine:
                     self._clock.mark(None)
                     return
                 queue_head = bool(self._queue or self._resume)
+            self._note_groups()
             self._clock.mark("admit")
             if queue_head:
                 self._admit()
@@ -3385,6 +3551,7 @@ class ContinuousEngine:
                     return
             self._clock.mark("reap")
             self._reap_jobs()
+            self._note_groups()
             self._clock.mark("admit")
             self._start_jobs()
             self._clock.mark("plan")
@@ -3687,10 +3854,23 @@ class ContinuousEngine:
             # crash inside the pressure ladder releases them cleanly
             self._alloc.incref(shared)
             req.block_ids = list(shared)
+        if self._wgrp is not None and not self._wgrp.admit(
+            slot, need_total,
+            *(self._bpx.side_blocks(shared) if shared else (0, [])),
+        ):
+            # the window group cannot promise the row its budget yet
+            if shared:
+                self._alloc.decref(shared)
+            req.block_ids = None
+            self._release_adapter(req)
+            self._m_blocked.labels(reason="blocks").inc()
+            return _BLOCKED
         # same pressure ladder as the whole-prefill admission: evict
         # cached chains, then preempt a decoding victim before stalling
         blk_ids = self._alloc_with_pressure(req)
         if blk_ids is None:
+            if self._wgrp is not None:
+                self._wgrp.release_row(slot)
             if shared:
                 self._alloc.decref(shared)
             req.block_ids = None
@@ -3978,6 +4158,12 @@ class ContinuousEngine:
         meta, tok_row, tok_pos, offsets, stats = P.build_ragged_meta(
             entries, width=W, tile=tile,
         )
+        # (a decode row whose budget ran out is dead on the device)
+        wrows = [] if self._wgrp is None else [
+            (b, start, n) for i, (b, start, n, _) in enumerate(entries)
+            if i >= len(active) or self._host_pos[b] < self._host_end[b]
+        ]
+        self._window_ensure(wrows)
         dev_dev = None
         toks = np.zeros((W,), np.int32)
         dec_flag = np.zeros((W,), bool)
@@ -4085,7 +4271,7 @@ class ContinuousEngine:
                 jnp.asarray(presence),
             )
         if self._table_dev is None:
-            self._table_dev = self._snapshot(self._table)
+            self._table_dev = self._launch_table()
         # the spec operands ride only when needed: launches with no
         # verify row dispatch the plain program — the pre-speculation
         # fast path, byte-identical
@@ -4157,12 +4343,14 @@ class ContinuousEngine:
             )
         rec = self._launch_record(
             "mixed", 1,
-            kv_tokens=sum(
-                int(self._kv_span(start, n))
-                for b, start, n, _ in entries[:n_dec]
-                if self._host_pos[b] < self._host_end[b]
-            ) + sum(int(self._kv_span(st, n)) for _, n, st in chunk_list),
-            kv_grid_tokens=np.sum(self._kv_walk(meta[:, 1], meta[:, 2])),
+            **self._kv_fields(
+                lambda w: sum(
+                    int(self._kv_span(start, n, w))
+                    for b, start, n, _ in entries[:n_dec]
+                    if self._host_pos[b] < self._host_end[b]
+                ) + sum(int(self._kv_span(st, n, w))
+                        for _, n, st in chunk_list),
+                lambda w: np.sum(self._kv_walk(meta[:, 1], meta[:, 2], w))),
             row_steps=n_dec,
             decode_rows=n_dec, prefill_chunks=len(chunk_list),
             prefill_tokens=sum(n for _, n, _ in chunk_list),
@@ -4216,6 +4404,21 @@ class ContinuousEngine:
                 if req is not None:
                     req.spec_launches += 1
                     req.spec_drafted += nd
+        if self._wgrp is not None and self._bpx is not None:
+            # a grouped pool registers a prompt chunk by chunk: a window
+            # block has to be the index's before its row gives it back
+            # (below), or a document longer than a window would keep only
+            # its last one. Whole blocks only, complete once this launch
+            # lands; later launches serialize behind it on the device.
+            for job, _, _ in chunk_list:
+                self._bpx.register(
+                    job.ids, job.p0 + job.done, job.req.block_ids,
+                    adapter=job.req.adapter,
+                    side_blocks=dict(self._wgrp.held(job.slot)),
+                    resume=job.registered,
+                )
+                job.registered = self._bpx.resume
+        self._window_release(wrows)
         for slot, req in completions.items():
             job = self._prefilling.pop(slot)
             self._jobs.remove(job)
@@ -4226,7 +4429,7 @@ class ContinuousEngine:
             else:
                 self._host_pos[slot] = job.prompt_len
                 self._host_end[slot] = job.prompt_len + req.budget
-            if self._bpx is not None:
+            if self._bpx is not None and self._wgrp is None:
                 # full prompt blocks are complete + immutable once this
                 # launch lands; later gathers serialize behind it on
                 # device — same register point as the whole-prefill path.
@@ -4541,6 +4744,10 @@ class ContinuousEngine:
 
     def _admit_one(self, req: _Request, slot: int):
         eng, cfg = self.engine, self.cfg
+        if self._wgrp is not None:
+            raise ValueError(
+                f"{cfg.name}: a pool grouped by layer kind takes chunked "
+                f"admissions only (no constraint, no whole prefill)")
         faults.check("admission", tag=req.prompt)
         # everything before this point (bounded queue + worker pickup) is
         # queueing delay; a _BLOCKED retry folds its re-wait in here too
@@ -4987,7 +5194,13 @@ class ContinuousEngine:
             # the fetch, with the launch's seq
             packed, counts = self._P.unpack_routed(packed, self._routed_shape)
             phase = rec["phase"]
+            away = 0
+            if self._expert_share:
+                away, counts = int(counts[0, :, -1].sum()), counts[:, :, :-1]
             rec["moe_pairs"] = int(counts[0].sum())
+            self._m_moe_pairs.labels(where="held").inc(rec["moe_pairs"])
+            self._m_moe_pairs.labels(where="routed").inc(
+                rec["moe_pairs"] + away)
             rec["moe_experts_touched"] = int(counts[1].sum())
             slots = counts[1].size * rec["steps"]
             self._m_moe_tokens.labels(phase=phase).inc(rec["moe_pairs"])
@@ -5311,6 +5524,9 @@ class ContinuousEngine:
             if slot is not None:
                 self._table[slot] = 0
                 self._table_dev = None
+        if self._wgrp is not None and slot is not None:
+            self._wgrp.release_row(slot)
+            self._table_dev = None
         if self.paged and slot is not None:
             # the slot reverts to the base page; later launches carrying
             # the frozen row read page 0 (the all-zero delta — inert)

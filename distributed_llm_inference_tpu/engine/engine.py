@@ -182,7 +182,7 @@ class SingleDeviceBackend:
     # routes through llama.default_attn_hook since round 5).
     @property
     def supports_paged(self):
-        return self.cfg.arch in ("llama", "gpt2", "mla_moe", "lfm2")
+        return self.cfg.arch in ("llama", "gpt2", "mla_moe", "lfm2", "afmoe")
 
     def init_paged_pool(self, n_blocks, block_size, n_slots=None):
         # n_slots: a model with recurrent layers keeps a state a slot

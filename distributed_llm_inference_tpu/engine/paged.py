@@ -82,6 +82,8 @@ from ..ops.kv_quant import quantize_chunk
 from . import generate as G
 
 TRASH_BLOCK = 0  # reserved pool block: write-only spill for table tails
+# a grouped pool's K/V leaves, group by group (cfg.kv_groups' order)
+GROUP_LEAVES = (("k", "v"), ("kw", "vw"))
 
 
 def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
@@ -117,6 +119,23 @@ def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
     key, so allocator, refcounts, eviction and the prefix index carry it
     unchanged, and a prefix hit at depth p0 (whole blocks) starts the
     slot's state from the tail of the last shared block: `StateRows`."""
+    if cfg.arch == "afmoe":
+        # K/V in groups by layer kind, each with its own blocks and block
+        # table (the module docstring's "Groups"); n_blocks: one count a
+        # group (`group_blocks`). The routed counts are of the experts held
+        # here, with one more column under a share: pairs routed elsewhere.
+        sizes = (n_blocks,) if isinstance(n_blocks, int) else tuple(n_blocks)
+        row = (cfg.n_kv_heads, block_size, cfg.head_dim)
+        pool = {}
+        for group, (kn, vn), n in zip(cfg.kv_groups, GROUP_LEAVES, sizes):
+            shape = (len(cfg.group_layers(group)), n) + row
+            pool[kn] = jnp.zeros(shape, cfg.jnp_dtype)
+            pool[vn] = jnp.zeros(shape, cfg.jnp_dtype)
+        share = cfg.experts_held < cfg.n_experts
+        pool["routed"] = jnp.zeros(
+            (2, cfg.n_layers - cfg.first_k_dense, cfg.experts_held + share),
+            jnp.int32)
+        return pool
     if cfg.conv_layers:
         if n_slots is None:
             raise ValueError(f"{cfg.name}: the pool holds a state a slot "
@@ -316,6 +335,161 @@ def blocks_needed(prompt_len: int, max_tokens: int, block_size: int) -> int:
     return -(-(prompt_len + max_tokens) // block_size)
 
 
+def window_row_budget(window: int, launch_tokens: int, block_size: int) -> int:
+    """The most window-group blocks a row ever holds: the positions its
+    widest launch's queries read and write, block-rounded at both ends."""
+    return -(-(window + launch_tokens) // block_size) + 1
+
+
+def group_blocks(cfg: ModelConfig, n_blocks: int, row_budget: int,
+                 n_slots: int) -> tuple:
+    """The one rule that sizes a pool's groups from `kv_pool_blocks`: the
+    global group gets n_blocks (the number's meaning for every model: the
+    context tokens the pool holds, over the block size); a window group
+    gets a quarter as many, so that every cached context of four windows
+    or more keeps its last window, and never fewer than the slots' budgets
+    (+ its own null block)."""
+    if len(cfg.kv_groups) == 1:
+        return (n_blocks,)
+    return (n_blocks, max(-(-n_blocks // 4), n_slots * row_budget + 1))
+
+
+class WindowBlocks:
+    """Host side of a grouped pool's WINDOW group: its own free list
+    (`alloc`, a BlockAllocator over the group's blocks; block 0 is the
+    group's null block) and its own block tables [n_slots, max_blocks],
+    indexed by LOGICAL block like the global group's, so the kernels take
+    either table unchanged.
+
+    A row holds global-group blocks for its whole context and window-group
+    blocks only for the positions a later query of the row can still read.
+    The host's position model decides at dispatch: before a launch that
+    carries the row's queries [start, start + n) `ensure` gives it a block
+    for every logical block they write; after the launch is dispatched
+    `release_below` takes back every block wholly below last - (window -
+    1), whose table entry then points at the null block. The kernels' live
+    range starts at the window, so they never walk such an entry; the
+    device runs launches in order, so a block let to another row is
+    written only after the last launch that read it. A row never holds
+    more than `row_budget` blocks: ceil((window + the widest launch) /
+    block) + 1, whatever its prompt's length, which is what admission
+    reserves (`admit`): free + evictable cached blocks never fall below
+    what the admitted rows may still ask for, so `ensure` cannot fail.
+
+    A block the prefix index also holds (engine/block_prefix.py: a shared
+    depth's entry names one block of each group) stays resident after the
+    row gives it back, until this free list runs dry: `index.evict_side`
+    then takes cached blocks, least recently used document first, from its
+    shallow end. Worker-thread only, like BlockAllocator."""
+
+    def __init__(self, n_blocks: int, n_slots: int, max_blocks: int,
+                 block_size: int, window: int, launch_tokens: int):
+        import numpy as np
+
+        self.alloc = BlockAllocator(n_blocks)
+        self.block_size, self.window = int(block_size), int(window)
+        self.row_budget = window_row_budget(window, launch_tokens, block_size)
+        if n_blocks - 1 < self.row_budget:
+            raise ValueError(
+                f"the window group's {n_blocks} blocks cannot hold one row "
+                f"({self.row_budget} blocks of {block_size} + the null "
+                f"block); raise kv_pool_blocks")
+        self.table = np.zeros((n_slots, max_blocks), np.int32)
+        self._lo = np.zeros((n_slots,), np.int64)  # below: given back
+        self._end = np.zeros((n_slots,), np.int64)  # logical blocks a row has
+        self._budget = np.zeros((n_slots,), np.int64)
+        self._held = np.zeros((n_slots,), np.int64)
+        self.index = None  # the BlockPrefixIndex that caches this group too
+        self.released = 0
+
+    def _evictable(self) -> int:
+        return 0 if self.index is None else self.index.side_evictable()
+
+    def reserved(self) -> int:
+        """Blocks the admitted rows may still ask for."""
+        short = self._budget - self._held
+        return int(short[short > 0].sum())
+
+    def admit(self, slot: int, need_blocks: int, first: int,
+              shared: list) -> bool:
+        """Let `slot` to a row of need_blocks logical blocks whose blocks
+        [first, first + len(shared)) are mapped from the prefix index
+        (`shared`: the cached blocks a hit reads, one more holder each).
+        False, with nothing held, where the group cannot promise the row
+        its budget."""
+        self.release_row(slot)
+        if shared:
+            self.alloc.incref(shared)
+        budget = min(self.row_budget, need_blocks)
+        spare = self.alloc.free_blocks + self._evictable() - self.reserved()
+        if spare < budget - len(shared):
+            if shared:
+                self.alloc.decref(shared)
+            return False
+        self.table[slot, first:first + len(shared)] = shared
+        self._lo[slot], self._end[slot] = first, need_blocks
+        self._budget[slot], self._held[slot] = budget, len(shared)
+        return True
+
+    def ensure(self, slot: int, start: int, n: int) -> bool:
+        """A block for every logical block positions [start, start + n) of
+        the row fall in (below the row's end); True if the table changed."""
+        bs, row = self.block_size, self.table[slot]
+        changed = False
+        hi = min((start + n - 1) // bs + 1, int(self._end[slot]))
+        for b in range(max(start // bs, int(self._lo[slot])), hi):
+            if row[b]:
+                continue
+            if not self.alloc.free_blocks and self.index is not None:
+                self.index.evict_side(8)
+            got = self.alloc.alloc(1)
+            if got is None:
+                raise RuntimeError(
+                    "window group exhausted under its own reservations")
+            row[b] = got[0]
+            self._held[slot] += 1
+            changed = True
+        return changed
+
+    def release_below(self, slot: int, last: int) -> bool:
+        """After a launch whose last query of the row stands at `last`:
+        give back every block wholly below last - (window - 1)."""
+        lo = int(self._lo[slot])
+        new_lo = min((last - self.window + 1) // self.block_size,
+                     int(self._end[slot]))
+        if new_lo <= lo:
+            return False
+        row = self.table[slot]
+        mine = [int(b) for b in row[lo:new_lo] if b]
+        if mine:
+            self.alloc.decref(mine)
+            self._held[slot] -= len(mine)
+            self.released += len(mine)
+        row[lo:new_lo] = 0
+        self._lo[slot] = new_lo
+        return bool(mine)
+
+    def release_row(self, slot: int):
+        row = self.table[slot]
+        mine = [int(b) for b in row if b]
+        if mine:
+            self.alloc.decref(mine)
+        row[:] = 0
+        self._lo[slot] = self._end[slot] = 0
+        self._budget[slot] = self._held[slot] = 0
+
+    def held(self, slot: int) -> list:
+        """(logical block, physical block) of every block the row holds."""
+        row = self.table[slot]
+        return [(int(b), int(row[b])) for b in row.nonzero()[0]]
+
+    def reset(self):
+        self.alloc.reset()
+        self.table[:] = 0
+        for a in (self._lo, self._end, self._budget, self._held):
+            a[:] = 0
+
+
 def pool_block_size(pool) -> int:
     """Tokens a block of the pool holds (either layout)."""
     return (pool["k"] if "k" in pool else pool["moe"]).shape[3]
@@ -379,6 +553,22 @@ def refuse_unsupported_latent(cfg: ModelConfig, **asked):
                     "already be in a convolution layer's state",
             "no_pool": "a dense slot fleet: there is no dense recurrent "
                        "fleet (pass --kv-pool-blocks)",
+        })
+    if len(cfg.kv_groups) > 1:
+        lead = "a model with window and global layers is served on one " \
+               "device from a pool grouped by layer kind, by chunked " \
+               "ragged prefill"
+        why.update({
+            "kv_shadow": "the host shadow store (and swap preemption, /kv "
+                         "export, the KV fabric): it copies one group's "
+                         "block pairs and knows no second table; pass "
+                         "--no-kv-shadow",
+            "bucketed": "the bucketed scratch prefill and the unchunked "
+                        "ragged admission: window blocks are given out "
+                        "and taken back launch by launch",
+            "spec": "speculative decoding: the host's position model "
+                    "decides which window blocks a row holds, and has to "
+                    "be exact",
         })
     bad = [why[name] for name, value in asked.items() if value]
     if bad:
@@ -598,7 +788,17 @@ def make_paged_hook(table: jnp.ndarray, active=None):
     hook.paged = True  # forward_layers carries the stacked pool (above)
     hook.live = active  # rows routed experts compute for (models/mla_moe)
     hook.rows = functools.partial(_decode_rows, table, active)
+    # a grouped pool's launch carries its groups' tables side by side
+    hook.group = lambda g, n: make_paged_hook(_group_table(table, g, n),
+                                              active)
     return hook
+
+
+def _group_table(table, g: int, n: int):
+    """Group g's block table of a launch table [R, n x MB] that carries n
+    groups' side by side (models/afmoe.py; a uniform model's has one)."""
+    MB = table.shape[1] // n
+    return table[:, g * MB:(g + 1) * MB]
 
 
 def scatter_scratch(pool, scratch, table_row):
@@ -1336,6 +1536,8 @@ def make_ragged_fill_hook(table, meta, tok_row):
     hook.paged = True  # forward_layers carries the stacked pool
     hook.live = tok_row >= 0  # launch padding reaches no routed expert
     hook.rows = functools.partial(_ragged_rows, table, meta, tok_row)
+    hook.group = lambda g, n: make_ragged_fill_hook(
+        _group_table(table, g, n), meta, tok_row)
     return hook
 
 

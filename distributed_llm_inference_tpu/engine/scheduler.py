@@ -188,6 +188,7 @@ class PrefillJob:
     __slots__ = (
         "req", "ids", "p0", "done", "prompt_len", "max_tokens", "slot",
         "sampling", "presence_row", "table_row", "cls", "diffusion",
+        "registered",
     )
 
     def __init__(self, req, ids, p0, prompt_len, max_tokens, slot, sampling,
@@ -208,6 +209,9 @@ class PrefillJob:
         # `prompt_len` then stop at the whole blocks, and `remaining` can
         # be 0 from the start (engine/continuous._start_job)
         self.diffusion = None
+        # a grouped pool registers the prompt chunk by chunk: where the
+        # prefix index's walk goes on (BlockPrefixIndex.register's resume)
+        self.registered = None
 
     @property
     def remaining(self) -> int:

@@ -11,8 +11,11 @@ are agnostic between them; mla_moe has two stacks and a latent cache and
 serves one device only; lfm2 (models/lfm2.py) alternates gated short
 convolutions with attention in one unscanned stack, keeps K/V for its
 attention layers alone and a recurrent state a row beside them, and serves
-one device from the paged pool only. Routed experts are one module for the
-families that have them (models/experts.py: `route`, `routed_ffn`, the
+one device from the paged pool only; afmoe (models/afmoe.py) mixes
+sliding-window and global gated attention layers in one unscanned stack,
+holds one chip's share of the routed experts where the configuration says
+so, and serves one device from a pool grouped by layer kind. Routed experts
+are one module for the families that have them (models/experts.py: `route`, `routed_ffn`, the
 grouped product): a configuration that routes serves one device, from the
 paged pool (engine/paged.refuse_unsupported_latent).
 """
@@ -20,9 +23,10 @@ paged pool (engine/paged.refuse_unsupported_latent).
 from __future__ import annotations
 
 from ..config import ModelConfig
-from . import gpt2, lfm2, llama, mla_moe
+from . import afmoe, gpt2, lfm2, llama, mla_moe
 
-_FAMILIES = {"llama": llama, "gpt2": gpt2, "mla_moe": mla_moe, "lfm2": lfm2}
+_FAMILIES = {"llama": llama, "gpt2": gpt2, "mla_moe": mla_moe, "lfm2": lfm2,
+             "afmoe": afmoe}
 
 
 def family(cfg: ModelConfig):

@@ -193,6 +193,29 @@ register(ModelConfig(
     moe_renormalize=True, routed_scaling=1.0, router_norm_eps=1e-6,
     eos_token_id=7, bos_token_id=1, pad_token_id=0,
 ))
+# --- Trinity (gated GQA, three sliding-window layers to one global, 256
+# routed experts beside a shared one; arcee-ai/Trinity-Large-Preview
+# config.json, model_type afmoe: models/afmoe.py). Sliding layers take RoPE
+# and read the last 4,096 positions, global layers take no position
+# encoding; the first num_dense_layers = 6 layers carry a dense SwiGLU of
+# ffn_dim. Not in config.json and so assumed (the family's modelling code):
+# mup_enabled = the sqrt(dim) on the embedding and nothing else at
+# inference, the four norms' places, the gate on the concatenated heads in
+# front of W_o, the window's convention (i - j < 4096), the 1e-20 in the
+# router's normalisation, per-head qk-norm before the half-rotation.
+register(ModelConfig(
+    name="trinity-large-preview", arch="afmoe", vocab_size=200192, dim=3072,
+    n_layers=60, n_heads=48, n_kv_heads=8, ffn_dim=12288,
+    max_seq_len=262144, norm_eps=1e-5, rope_theta=10000.0,
+    head_dim_override=128, use_qk_norm=True, post_norms=True,
+    embed_scale=True, attn_window=4096,
+    layer_types=tuple(
+        "full_attention" if i % 4 == 3 else "sliding_attention"
+        for i in range(60)),
+    n_experts=256, n_experts_per_tok=4, moe_ffn_dim=3072, n_shared_experts=1,
+    first_k_dense=6, moe_renormalize=True, routed_scaling=2.448,
+    router_norm_eps=1e-20, eos_token_id=2, bos_token_id=1, pad_token_id=0,
+))
 register(ModelConfig(
     name="qwen3-8b", arch="llama", vocab_size=151936, dim=4096,
     n_layers=36, n_heads=32, n_kv_heads=8, ffn_dim=12288, max_seq_len=40960,
@@ -357,6 +380,17 @@ register(ModelConfig(
     n_experts=8, n_experts_per_tok=2, moe_ffn_dim=32, first_k_dense=1,
     moe_renormalize=True, router_norm_eps=1e-6,
     eos_token_id=2, bos_token_id=1,
+))
+register(ModelConfig(
+    name="test-trinity-tiny", arch="afmoe", vocab_size=256, dim=64,
+    n_layers=5, n_heads=4, n_kv_heads=2, ffn_dim=96, max_seq_len=256,
+    norm_eps=1e-5, rope_theta=10000.0, head_dim_override=16,
+    use_qk_norm=True, post_norms=True, embed_scale=True, attn_window=8,
+    layer_types=("sliding_attention", "sliding_attention", "full_attention",
+                 "sliding_attention", "sliding_attention"),
+    n_experts=8, n_experts_per_tok=2, moe_ffn_dim=32, n_shared_experts=1,
+    first_k_dense=1, moe_renormalize=True, routed_scaling=2.448,
+    router_norm_eps=1e-20, eos_token_id=2, bos_token_id=1,
 ))
 register(ModelConfig(
     name="test-gemma2-tiny", arch="llama", vocab_size=256, dim=64,

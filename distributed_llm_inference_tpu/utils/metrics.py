@@ -89,6 +89,23 @@ CONV_TAIL_WRITES_HELP = (
     "position model"
 )
 
+# a pool grouped by layer kind, and one chip's share of the experts
+KV_GROUP_BLOCKS_HELP = (
+    "blocks of each group of the paged pool by state: live = held by a "
+    "row's table, cached = held by the prefix index alone, free; a pool "
+    "of one group has the global group only"
+)
+KV_WINDOW_RELEASED_HELP = (
+    "window-group blocks rows gave back while they went on (every "
+    "position of the block below the row's last query's window), by the "
+    "host position model"
+)
+MOE_PAIRS_HELP = (
+    "live token-expert pairs the routers chose: held = those whose expert "
+    "lives on this chip (computed here), routed = all of them; equal where "
+    "every expert is held"
+)
+
 # block-diffusion fleets (ModelConfig.diffusion_block > 0)
 DIFFUSION_FORWARDS_HELP = (
     "row-forwards of a block-diffusion fleet by the host position model, "

@@ -392,7 +392,11 @@ CELL_PROGRAMS = {
     "kanana-2-30b-a3b-7l": ("kanana-2-30b-a3b", 7, 8, 1750, 32768),
 }
 CELL_WIDTHS = {"olmo2-7b-16l": 128, "mistral-7b-16l": 136,
-               "kanana-2-30b-a3b-7l": 512}
+               "kanana-2-30b-a3b-7l": 512, "trinity-large-ep8-5l": 512}
+# configurations read from their benchmark file (registry entry, overrides,
+# flags): a pool grouped by layer kind, two tables side by side, no verify
+# rows (ISSUE 40)
+CELL_FILES = ("trinity-large-ep8-5l",)
 # instructions that make no buffer of their own, or are the kernels
 _NO_BUFFER = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
               "custom-call"}
@@ -467,19 +471,37 @@ def _cell_step_programs(one_chip, config):
     """(params, pool, {module: compiled}) of a CELL_PROGRAMS configuration:
     both step programs at the cell's sizes and depth. The caller has set
     DLI_PALLAS_INTERPRET=0."""
+    import json
+    import os
+
     import numpy as np
 
-    model, layers, slots, blocks, context = CELL_PROGRAMS[config]
-    cfg = resolve_attn_impl(
-        get_model_config(model).replace(n_layers=layers, dtype="bfloat16"),
-        "pallas",
-    )
+    grouped = config in CELL_FILES
+    if grouped:
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "cellbench", "configs", f"{config}.json")) as f:
+            serving = json.load(f)["serving"]
+        flags = serving["flags"]
+        flag = lambda name: int(flags[flags.index(name) + 1])  # noqa: E731
+        slots, blocks, context = (flag("--continuous"), flag("--kv-pool-blocks"),
+                                  flag("--continuous-max-seq"))
+        cfg = get_model_config(serving["base"]).replace(
+            dtype="bfloat16", **serving["overrides"])
+    else:
+        model, layers, slots, blocks, context = CELL_PROGRAMS[config]
+        cfg = get_model_config(model).replace(n_layers=layers, dtype="bfloat16")
+    cfg = resolve_attn_impl(cfg, "pallas")
     S = _spec(one_chip)
     place = functools.partial(_placed, sharding=one_chip)
     params = place(jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0))))
     state, sparams = place(jax.eval_shape(lambda: G.init_slots(slots, cfg.vocab_size)))
+    if grouped:
+        width = step_width(cfg, slots, 8)
+        budget = EP.window_row_budget(cfg.attn_window, width, 128)
+        blocks = EP.group_blocks(cfg, blocks, budget, slots)
+        assert blocks == (4608, 1152) and budget == 37
     pool = place(jax.eval_shape(lambda: EP.init_pool(cfg, blocks, 128)))
-    table = S((slots, context // 128), jnp.int32)
+    table = S((slots, len(cfg.kv_groups) * (context // 128)), jnp.int32)
     key = place(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     chunk = EP.decode_slots_paged.lower(
         cfg, params, state, pool, table, key, sparams, num_steps=16,
@@ -491,7 +513,7 @@ def _cell_step_programs(one_chip, config):
     entries = [(b, 0, 1, EP.RAGGED_DECODE) for b in range(slots)]
     meta, tok_row, tok_pos, offsets, _ = EP.build_ragged_meta(
         entries, width=width, tile=tile)
-    dev = EP.DeviceMeta(*(
+    dev = None if grouped else EP.DeviceMeta(*(
         S(a.shape, a.dtype) for a in EP.build_device_meta(
             entries, offsets, slots, width=width, tile=tile)))
     arm = place(jax.eval_shape(lambda: EP.idle_mixed_arm(slots, cfg.vocab_size)))
@@ -505,10 +527,12 @@ def _cell_step_programs(one_chip, config):
                           "mixed_step_ragged": mixed}
 
 
-@pytest.mark.parametrize("config", sorted(CELL_PROGRAMS))
+@pytest.mark.parametrize("config", sorted(CELL_PROGRAMS) + list(CELL_FILES))
 def test_step_programs_hold_no_copy_of_the_pool_at_cell_sizes(
     one_chip, no_persistent_cache, monkeypatch, config
 ):
+    import re
+
     monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
     _, pool, programs = _cell_step_programs(one_chip, config)
     pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
@@ -523,10 +547,20 @@ def test_step_programs_hold_no_copy_of_the_pool_at_cell_sizes(
         assert _pool_sized_instructions(text, pool) == [], name
         _assert_scopes(text, name, DENSE_SCOPES + (
             ROUTED_SCOPES + ("moe_shared", "mla_absorb")
-            if config.startswith("kanana") else ()))
+            if config.startswith("kanana") else
+            ROUTED_SCOPES + ("moe_shared",) if config in CELL_FILES else ()))
+        if config in CELL_FILES:
+            mem = compiled.memory_analysis()
+            print(f"{config} {name}: arguments {mem.argument_size_in_bytes / 1e9:.3f} GB, "
+                  f"temporaries {mem.temp_size_in_bytes / 1e6:.1f} MB, aliased "
+                  f"{mem.alias_size_in_bytes / 1e9:.3f} GB of a {pool_bytes / 1e9:.3f} GB pool")
+            # the sliced head: 25,024 columns are 195.5 lane tiles of 128
+            print("head product shapes:", sorted(set(re.findall(
+                r"(?:f32|bf16)\[\d+,250(?:24|88)\]", text))))
 
 
-@pytest.mark.parametrize("config", sorted(CELL_PROGRAMS) + ["sdar-30b-a3b-7l"])
+@pytest.mark.parametrize("config", sorted(CELL_PROGRAMS) + list(CELL_FILES)
+                         + ["sdar-30b-a3b-7l"])
 def test_step_programs_read_the_attention_projections_in_place(
     one_chip, no_persistent_cache, monkeypatch, config
 ):
@@ -537,7 +571,7 @@ def test_step_programs_read_the_attention_projections_in_place(
     no slice copy a layer-step, no relayout of a stack a launch
     (`models/llama.pin_products` says what made them)."""
     monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
-    if config in CELL_PROGRAMS:
+    if config in CELL_PROGRAMS or config in CELL_FILES:
         params, _, programs = _cell_step_programs(one_chip, config)
         texts = {name: c.as_text() for name, c in programs.items()}
     else:

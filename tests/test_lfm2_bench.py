@@ -243,12 +243,12 @@ def test_a_reader_of_the_cell_reads_the_tiny_configuration(tmp_path, name):
 
 def test_the_manifest_gained_one_configuration_one_cell_and_one_metric():
     man = manifest.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    assert man["configs"][-1]["name"] == CONFIG
-    assert man["configs"][-1]["reduced"] == ["num_hidden_layers", "num_dense_layers",
-                                             "layer_types"]
-    assert man["workloads"][-1] == {**man["workloads"][-1], "name": CELL, "config": CONFIG,
-                                    "traffic": "docs-repeat-long", "chips": 1}
-    assert len(man["workloads"][-1]["why"]) <= 200
+    # looked up by name, not counted from the end: later PRs append after them (PR 40's cell)
+    entry = {c["name"]: c for c in man["configs"]}[CONFIG]
+    assert entry["reduced"] == ["num_hidden_layers", "num_dense_layers", "layer_types"]
+    own = {w["name"]: w for w in man["workloads"]}[CELL]
+    assert own == {**own, "config": CONFIG, "traffic": "docs-repeat-long", "chips": 1}
+    assert len(own["why"]) <= 200
     by_name = {m["name"]: m for m in man["per_layer"]}
     # looked up by name, not counted from the end: later PRs append after it
     # (PR 38's six scope metrics)
@@ -261,8 +261,7 @@ def test_the_manifest_gained_one_configuration_one_cell_and_one_metric():
     assert by_name["hybrid_attn_kv_roofline"]["source"] == "device_trace"
     assert by_name["hybrid_attn_kv_roofline"]["layer"] == "kernels"
     for name in JOINED + JOINED_COUNTERS:
-        assert by_name[name]["workloads"][-1] == CELL, name
-        assert CELL not in by_name[name]["workloads"][:-1]
+        assert by_name[name]["workloads"].count(CELL) == 1, name
     for name in ("attn_kv_roofline", "ragged_attn_roofline.batch", "mla_attn_roofline"):
         assert CELL not in by_name[name]["workloads"], name
     # the dense weight formula counts an 11,776-wide FFN at all 9 layers: 1.76 GB where a

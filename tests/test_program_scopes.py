@@ -159,7 +159,14 @@ def test_a_launch_records_nothing_outside_a_session_and_both_programs_inside(
         params = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), fleet.backend.params)
         plain = getattr(P, name).lower(fleet.cfg, params, *args, **kwargs)
-        assert instructions(plain.compile().as_text()) == instructions(text)
+        # compiled as the map's own text is, past both caches: a plain
+        # `.compile()` hands back the executable the fleet's first dispatch
+        # made, and where a neighbour in this worker has switched the
+        # persistent cache on (utils/compile_cache.enable sets it for the
+        # process) that one may be a metadata-free hit of what another call
+        # stack compiled, with a few instructions numbered otherwise: the
+        # test then failed in the suite and passed alone (ISSUE 40)
+        assert instructions(tracing.fresh_hlo_text(plain)) == instructions(text)
     assert lowered == {"decode_slots_paged": 1, "mixed_step_ragged": 1}
 
 
