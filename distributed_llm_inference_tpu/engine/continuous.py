@@ -119,11 +119,11 @@ import numpy as np
 from ..utils import faults
 from ..utils.logging import get_logger
 from ..utils.metrics import (
-    ADMISSION_WAIT_HELP, CONV_STATE_RESETS_HELP, CONV_TAIL_WRITES_HELP,
-    DEFAULT_SIZE_BUCKETS, DIFFUSION_FORWARDS_HELP, DIFFUSION_FUSED_HELP,
-    DIFFUSION_TOKENS_HELP, KV_GROUP_BLOCKS_HELP, KV_WINDOW_RELEASED_HELP,
-    MOE_PAIRS_HELP, PREFIX_STATE_TOKENS_HELP, SLOT_RELEASE_HELP,
-    SLOT_TURNOVER_HELP, STEPS_AHEAD_BUCKETS,
+    ADMISSION_WAIT_HELP, ATTN_WALK_STEPS_HELP, CONV_STATE_RESETS_HELP,
+    CONV_TAIL_WRITES_HELP, DEFAULT_SIZE_BUCKETS, DIFFUSION_FORWARDS_HELP,
+    DIFFUSION_FUSED_HELP, DIFFUSION_TOKENS_HELP, KV_GROUP_BLOCKS_HELP,
+    KV_WINDOW_RELEASED_HELP, MOE_PAIRS_HELP, PREFIX_STATE_TOKENS_HELP,
+    SLOT_RELEASE_HELP, SLOT_TURNOVER_HELP, STEPS_AHEAD_BUCKETS,
 )
 from ..utils.retry import overload_retry_after
 from ..utils.tracing import PhaseClock, Trace, abstract_call, sample_decision
@@ -896,6 +896,15 @@ class ContinuousEngine:
         # whether attention reads the pool through the paged kernels'
         # block walk (_kv_walk) or gathers whole tables
         self._kv_walks = self.paged and self.cfg.attn_impl == "pallas"
+        # pages a loop step of the walk folds, by step program: the decode
+        # chunk's tile is one query (a block-diffusion row's owed and open
+        # blocks), a mixed launch's the scheduler's
+        self._walk_pages = {}
+        if self._kv_walks:
+            self._walk_pages = {
+                "chunk": self._walk_pages_of(2 * self._blk or 1),
+                "mixed": self._walk_pages_of(self._ragged_tile),
+            }
         # observability
         self.admitted = 0  # guarded-by: _cv
         self.completed = 0  # guarded-by: _cv
@@ -1084,6 +1093,9 @@ class ContinuousEngine:
             "the launch's rows need (host position model, window-"
             "clipped), walked = what the kernels' block loops cover",
             ("phase", "state"),
+        )
+        self._m_walk_steps = m.counter(
+            "dli_attn_walk_steps_total", ATTN_WALK_STEPS_HELP, ("phase",),
         )
         # routed experts (a latent pool's "routed" leaf, models/mla_moe.py):
         # its shape [2, expert layers, experts], or None for a model
@@ -3125,8 +3137,8 @@ class ContinuousEngine:
                 "conv_state_resets": int(resets)}
 
     def _launch_record(self, phase: str, steps: int, kv_tokens: int,
-                       kv_grid_tokens: int, row_steps: int,
-                       **fields) -> dict:
+                       kv_grid_tokens: int, kv_walk_steps: int,
+                       row_steps: int, **fields) -> dict:
         """The ONE record of a launch, built at the dispatch seam before
         the jitted call: host integers the loop already holds, no device
         read. `kv_tokens` is the fewest KV positions (per layer and KV
@@ -3134,7 +3146,11 @@ class ContinuousEngine:
         position model; `kv_grid_tokens` the positions attention covers
         to read them (`_kv_walk`: whole blocks of each live row's live
         range under the paged kernels, every row's whole table under
-        the gather path); `steps_ahead` the scheduler steps dispatched
+        the gather path) and `kv_walk_steps` the loop steps the kernels'
+        walk takes over them (per layer; 0 under the gather path: pages
+        a loop step = kv_grid_tokens / block size / kv_walk_steps, 1.0
+        where a shape's compute block is one page);
+        `steps_ahead` the scheduler steps dispatched
         and not yet fetched; `row_steps` the decode row-steps it carries
         (a chunk's rows run up to `steps` each). Counted here; the
         caller hands it to the `launch.<phase>` annotation, the flight
@@ -3147,6 +3163,7 @@ class ContinuousEngine:
             "spec_drafted": 0, "steps_ahead": self._steps_inflight,
             "kv_tokens": int(kv_tokens),
             "kv_grid_tokens": int(kv_grid_tokens),
+            "kv_walk_steps": int(kv_walk_steps),
         }
         rec.update(fields)
         self._steps_inflight += steps
@@ -3160,31 +3177,39 @@ class ContinuousEngine:
         self._m_kv_tokens.labels(phase=phase, state="walked").inc(
             rec["kv_grid_tokens"]
         )
+        self._m_walk_steps.labels(phase=phase).inc(rec["kv_walk_steps"])
         self._m_steps_ahead.labels(phase=phase).observe(rec["steps_ahead"])
         if phase == "mixed":
             self._m_sched_tiles.labels(state="launched").inc(rec["tiles"])
             self._m_sched_tiles.labels(state="live").inc(rec["tiles_live"])
         return rec
 
-    def _kv_fields(self, attended, walked) -> dict:
-        """The launch record's KV counts from attended(window) /
-        walked(window), the launch's sums under a layer's window (None:
-        the whole context). A stack of one kind: `kv_tokens`,
-        `kv_grid_tokens`, per layer and K/V head. A stack of window and
-        global layers adds each kind's own (`kv_tokens_global`, ...,
-        per layer OF THE KIND), and `kv_tokens` / `kv_grid_tokens` are
-        then the kinds' sums by their layer counts, so that attended /
-        walked stays a share of one thing."""
+    def _kv_fields(self, phase: str, attended, walked) -> dict:
+        """The launch record's KV counts under a layer's window (None: the
+        whole context): attended(window) the launch's sum, walked(window)
+        what each of its tiles walks (`_kv_walk`). A stack of one kind:
+        `kv_tokens`, `kv_grid_tokens`, per layer and K/V head, and
+        `kv_walk_steps`, the loop steps the tiles' walks take a layer
+        (`_kv_walk_steps`). A stack of window and global layers adds each
+        kind's own (`kv_tokens_global`, ..., per layer OF THE KIND), and
+        the three are then the kinds' sums by their layer counts, so that
+        attended / walked and walked pages / steps stay shares of one
+        thing."""
+        def counts(window):
+            walk = walked(window)
+            return (int(attended(window)), int(np.sum(walk)),
+                    int(np.sum(self._kv_walk_steps(phase, walk))))
+
+        names = ("kv_tokens", "kv_grid_tokens", "kv_walk_steps")
         if self._kv_kinds is None:
-            return {"kv_tokens": attended(self._kv_window),
-                    "kv_grid_tokens": walked(self._kv_window)}
-        out = {"kv_tokens": 0, "kv_grid_tokens": 0}
+            return dict(zip(names, counts(self._kv_window)))
+        out = dict.fromkeys(names, 0)
         for name, (layers, window) in zip(("global", "window"),
                                           self._kv_kinds):
-            a, w = int(attended(window)), int(walked(window))
+            a, w, n = counts(window)
             out[f"kv_tokens_{name}"], out[f"kv_grid_tokens_{name}"] = a, w
-            out["kv_tokens"] += layers * a
-            out["kv_grid_tokens"] += layers * w
+            for key, count in zip(names, (a, w, n)):
+                out[key] += layers * count
         return out
 
     def _kv_span(self, start, length=1, window=-1):
@@ -3215,6 +3240,31 @@ class ContinuousEngine:
             np.maximum(start - window + 1, 0) // bs, needed - 1
         )
         return np.where(length > 0, (needed - first) * bs, 0)
+
+    def _walk_pages_of(self, tq: int) -> int:
+        """P of the paged kernels' walk for query tiles of tq tokens over
+        this fleet's pool (every K/V or latent leaf has one row shape),
+        from the function the kernels take it from."""
+        from ..ops.kv_quant import KVQuant
+        from ..ops.paged_attention import walk_pages_per_step
+
+        leaf = next(
+            a for a in jax.tree.leaves(
+                self.cache, is_leaf=lambda a: isinstance(a, KVQuant))
+            if isinstance(a, KVQuant) or a.ndim == 5
+        )
+        return walk_pages_per_step(leaf, self.cfg.n_heads, tq,
+                                   self._max_blocks,
+                                   latent=self.cfg.latent_dim > 0)
+
+    def _kv_walk_steps(self, phase: str, walk):
+        """Loop steps the paged kernels run over `walk`, the positions
+        `_kv_walk` counts a tile: its pages over the pages a step of
+        `phase`'s program folds, rounded up (ops/paged_attention.
+        _walk_shape's P). The gather path loops over nothing."""
+        if not self._kv_walks:
+            return np.zeros_like(walk)
+        return -(-(walk // self.kv_block_size) // self._walk_pages[phase])
 
     # -- launch-level device-time attribution (ISSUE 17) ---------------------
     def _prof_note_launch(self, t_launch: float, snapshot, rec: dict):
@@ -3349,8 +3399,9 @@ class ContinuousEngine:
         rec = self._launch_record(
             "chunk", K,
             **self._kv_fields(
+                "chunk",
                 lambda w: np.sum(self._kv_span(at, span, w) * alive),
-                lambda w: np.sum(self._kv_walk(at, alive * span, w))),
+                lambda w: self._kv_walk(at, alive * span, w)),
             row_steps=int(live.sum()),
             decode_rows=int(np.count_nonzero(live)), **diff_fields,
         )
@@ -4344,13 +4395,14 @@ class ContinuousEngine:
         rec = self._launch_record(
             "mixed", 1,
             **self._kv_fields(
+                "mixed",
                 lambda w: sum(
                     int(self._kv_span(start, n, w))
                     for b, start, n, _ in entries[:n_dec]
                     if self._host_pos[b] < self._host_end[b]
                 ) + sum(int(self._kv_span(st, n, w))
                         for _, n, st in chunk_list),
-                lambda w: np.sum(self._kv_walk(meta[:, 1], meta[:, 2], w))),
+                lambda w: self._kv_walk(meta[:, 1], meta[:, 2], w)),
             row_steps=n_dec,
             decode_rows=n_dec, prefill_chunks=len(chunk_list),
             prefill_tokens=sum(n for _, n, _ in chunk_list),

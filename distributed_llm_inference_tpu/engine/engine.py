@@ -33,9 +33,9 @@ from ..models import api as M
 from ..utils import faults
 from ..utils.logging import get_logger, request_id_context
 from ..utils.metrics import (
-    ADMISSION_WAIT_HELP, DEFAULT_SIZE_BUCKETS, DIFFUSION_FORWARDS_HELP,
-    DIFFUSION_FUSED_HELP, DIFFUSION_TOKENS_HELP, SLOT_RELEASE_HELP,
-    SLOT_TURNOVER_HELP,
+    ADMISSION_WAIT_HELP, ATTN_WALK_STEPS_HELP, DEFAULT_SIZE_BUCKETS,
+    DIFFUSION_FORWARDS_HELP, DIFFUSION_FUSED_HELP, DIFFUSION_TOKENS_HELP,
+    SLOT_RELEASE_HELP, SLOT_TURNOVER_HELP,
     STEPS_AHEAD_BUCKETS, MetricsRegistry,
 )
 from ..utils.probe import device_summary
@@ -683,6 +683,9 @@ class InferenceEngine:
             "the launch's rows need (host position model, window-"
             "clipped), walked = what the kernels' block loops cover",
             ("phase", "state"),
+        )
+        self.metrics.counter(
+            "dli_attn_walk_steps_total", ATTN_WALK_STEPS_HELP, ("phase",),
         )
         self.metrics.histogram(
             "dli_launch_steps_ahead",

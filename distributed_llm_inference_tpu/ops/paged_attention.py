@@ -12,14 +12,25 @@ walks its row's block table itself:
   * `first, needed` bound the row's LIVE logical blocks: the causal
     frontier above, the sliding window below (static, or a traced
     per-layer width riding as a scalar-prefetch operand — Gemma-2/3);
-  * `lax.fori_loop(first, needed, ...)` copies block `table[row, j]` —
-    the slab of all the group's KV heads, contiguous in that layout —
-    into one of two VMEM buffers, and starts block j + 1's copy before it
-    waits for block j's; an int8 pool's scale slabs (ops/kv_quant) walk
-    the same loop;
-  * the slab's heads fold into their online-softmax accumulators in one
-    batched matmul pair: K and V raised to float32, float32 scores,
-    softcap before the mask, float32 accumulators, output in q.dtype;
+  * a loop step covers a COMPUTE BLOCK of P consecutive logical blocks
+    (pages) of the row, `first + i * P ..`: `lax.fori_loop(0,
+    ceil((needed - first) / P), ...)`. Page `table[row, j]` — the slab of
+    all the group's KV heads, contiguous in that layout — is one copy of
+    its own (the table scatters the pages) into its P-th of one half of a
+    two-slot VMEM buffer [2, KVg, P x bs, Dh]; a step starts the NEXT
+    compute block's live pages before it waits for its own (scalar loops
+    over the live pages: a step's code does not grow with P). Only live
+    pages are copied: a page at or past `needed` starts no DMA, so the
+    walk reads from HBM what a walk of one page a step reads. An int8
+    pool's scale slabs (ops/kv_quant) walk the same loop, at P = 1;
+  * the compute block's P x bs positions of every head fold into the
+    online-softmax accumulators in ONE batched matmul pair, one max / exp
+    / sum and one rescale: K and V raised to float32, float32 scores,
+    softcap before the mask, float32 accumulators, output in q.dtype. The
+    step's fixed cost (the waits, the chain score -> max -> exp -> sum ->
+    value product -> rescale, each stage waiting for the one before) is
+    paid once per P pages; (KVg, P) come from the shapes alone
+    (`_walk_shape`), P = 1 being the same body;
   * a row that holds nothing (a launch-padding tile, a decode slot whose
     `active` flag is false) copies nothing and loops zero times; its
     output is zeros, which the caller discards.
@@ -38,8 +49,9 @@ and the kernel reads that slice (`writes_in_place`).
 
 So the device's work follows the rows' live blocks, not slots x KV heads
 x table width. What the walk covers is counted on the host by
-engine/continuous._kv_walk (the launch record's `kv_grid_tokens`), which
-repeats `_ragged_live_range`'s arithmetic in numpy;
+engine/continuous._kv_walk (the launch record's `kv_grid_tokens`, and
+with `walk_pages_per_step` its `kv_walk_steps`), which repeats
+`_ragged_live_range`'s arithmetic in numpy;
 tests/test_launch_record.py holds the two together. Times on the chip are
 in PERF.md (section 6, PR 25) and the ledger, not here.
 
@@ -81,9 +93,10 @@ _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)  # mask fill; avoids inf-inf NaN
 RAGGED_PREFILL = 0  # metadata `kind`: a prompt-chunk row (length >= 1)
 RAGGED_DECODE = 1  # metadata `kind`: a single-token decode row
 
-# VMEM one program's working set may take (_heads_per_slab counts it and
-# sizes the KV-head group by it): inside the default scoped limit, 16 MiB
-# on v5e, with room for the query and output blocks' second buffers.
+# VMEM one program's working set may take (_walk_shape counts it and sizes
+# the KV-head group and the compute block by it): inside the default scoped
+# limit, 16 MiB on v5e, with room for the query and output blocks' second
+# buffers.
 _WALK_VMEM_BYTES = 12 * 2**20
 
 
@@ -107,32 +120,81 @@ def _ragged_live_range(q_start, q_len, *, bs: int, MB: int, win):
     return first, needed
 
 
-def _heads_per_slab(KV: int, bs: int, Dh: int, itemsize: int, quant: bool,
-                    rows: int) -> int:
-    """KV heads one program folds at a time: the largest divisor of KV
-    whose working set stays inside _WALK_VMEM_BYTES as VMEM tiles it
-    (sublanes 32 / itemsize, 128 lanes). Per head: the K and V slabs, each
-    held twice in the pool's dtype and once in float32, and per query row
-    the float32 query, accumulator, running max and sum, and a block's
-    scores and probabilities."""
+# What one loop step folds at most: pages (a step's copies, waits and dead-
+# page tests are unrolled, P of each: pages of 16 tokens would make 64),
+# positions (past this the score tiles outgrow the slabs), and bytes of K/V
+# slabs in the pool's dtype. A step's
+# fixed cost is 0.3-0.5 us; a step that carries 1-1.5 MB copies for 1.3-1.9 us
+# at 819 GB/s, and a larger one only adds to what the last step folds for
+# nothing (its dead pages: half a compute block on average) and to the
+# temporaries (PERF.md section 6, PR 45: lfm2's decode row 56 us at 1 MB a
+# step, 62 at 2 MB; sdar's mixed launch 116 / 133; mistral's 231 / 244).
+_WALK_STEP_PAGES = 8
+_WALK_STEP_TOKENS = 1024
+_WALK_STEP_BYTES = 3 * 2**19
+
+
+def _walk_shape(KV: int, bs: int, Dh: int, itemsize: int, quant: bool,
+                rows: int, MB: int, latent: bool = False) -> tuple[int, int]:
+    """(KVg, P) of one program's walk, from the shapes and the stated
+    count alone. KVg, the KV heads a program folds at a time: the largest
+    divisor of KV whose working set at one page a step stays inside
+    _WALK_VMEM_BYTES as VMEM tiles it (sublanes 32 / itemsize, 128 lanes).
+    Then P, the pages a loop step folds (the compute block): the largest
+    power of two, at most MB and _WALK_STEP_PAGES pages, _WALK_STEP_TOKENS
+    positions and _WALK_STEP_BYTES of the KVg heads' K and V slabs (of K alone, which
+    is all the latent form copies, where `latent`), at which the working
+    set still fits. Per head: the K and V slabs of P pages, each held
+    twice in the pool's dtype and once in float32, and per query row the
+    float32 query, accumulator, running max and sum, and the compute
+    block's scores and probabilities. An int8 pool walks a page a step:
+    its scale slabs hold the tokens on lanes, and blocks under 128 tokens
+    would not lie side by side there."""
 
     def up(n, m):
         return -(-n // m) * m
 
-    lanes, toks = up(Dh, 128), up(bs, 128)
-    head = 4 * up(bs, 32 // itemsize) * lanes * itemsize
-    head += 2 * up(bs, 8) * lanes * 4
-    head += up(rows, 8) * 4 * (2 * lanes + 2 * 128 + 3 * toks)
-    if quant:  # a [heads, bs] float32 scale slab beside each int8 slab
-        head += 4 * toks * 4
-    fit = max(1, _WALK_VMEM_BYTES // head)
+    def head(P):
+        lanes, toks = up(Dh, 128), up(P * bs, 128)
+        n = 4 * up(P * bs, 32 // itemsize) * lanes * itemsize
+        n += 2 * up(P * bs, 8) * lanes * 4
+        n += up(rows, 8) * 4 * (2 * lanes + 2 * 128 + 3 * toks)
+        if quant:  # a [heads, bs] float32 scale slab beside each int8 slab
+            n += 4 * toks * 4
+        return n
+
+    fit = max(1, _WALK_VMEM_BYTES // head(1))
     # a [heads, bs] scale slab is cut from [KV, bs] along float32 sublanes
     step = 8 if quant else 1
-    return max(
+    KVg = max(
         (d for d in range(1, min(KV, fit) + 1)
          if KV % d == 0 and (d % step == 0 or d == KV)),
         default=KV,
     )
+    page = KVg * bs * up(Dh, 128) * itemsize * (1 if latent else 2)
+    P = 1
+    while (not quant and 2 * P <= min(MB, _WALK_STEP_PAGES)
+           and 2 * P * bs <= _WALK_STEP_TOKENS
+           and 2 * P * page <= _WALK_STEP_BYTES
+           and KVg * head(2 * P) <= _WALK_VMEM_BYTES):
+        P *= 2
+    return KVg, P
+
+
+def walk_pages_per_step(leaf, n_heads: int, tq: int, MB: int,
+                        latent: bool = False) -> int:
+    """P of the walk over pool leaf `leaf` ([..., KV, bs, Dh], or
+    ops/kv_quant's int8 pair; `latent`: a pool of latent rows, no V) for
+    tiles of tq queries of n_heads heads under a table MB pages wide: what
+    `_paged_walk` gives its kernel, for the host's count of loop steps
+    (engine/continuous: `kv_walk_steps`)."""
+    from .kv_quant import KVQuant
+
+    quant = isinstance(leaf, KVQuant)
+    a = leaf.q if quant else leaf
+    KV, bs, Dh = a.shape[-3:]
+    return _walk_shape(KV, bs, Dh + -Dh % 128, a.dtype.itemsize, quant,
+                       tq * (n_heads // KV), MB, latent)[1]
 
 
 def _walk_kernel(
@@ -147,6 +209,7 @@ def _walk_kernel(
     MB: int,
     tq: int,
     KVg: int,
+    P: int,
     group: int,
     scale: float,
     softcap: float | None,
@@ -157,22 +220,34 @@ def _walk_kernel(
 ):
     """One program: query tile g (tq queries of one row; a decode slot is
     a tile of one) against head group hg's KVg KV heads. The walk over
-    the row's live blocks is the fori_loop below; every head of a slab
-    folds in one batched matmul pair. Row r of a head's score tile is
-    (local query t = r // group, query head r % group of the KV head),
-    its absolute position q_start + t.
+    the row's live pages is the fori_loop below, P pages (a compute block)
+    a step; every head of the compute block's slabs folds in one batched
+    matmul pair. Row r of a head's score tile is (local query t = r //
+    group, query head r % group of the KV head), its absolute position
+    q_start + t; column c is position (first + i * P) * bs + c of the row.
+
+    Compute block i holds logical pages first + i * P .. + P - 1. Its
+    pages at or past `needed` (the last step's tail) are DEAD: they start
+    no copy, their positions lie past every query's frontier and are
+    masked like any other, and their rows of the VALUE operand (V; the K
+    rows in latent form) are ZEROED in VMEM before the fold, because
+    uninitialised VMEM may hold NaN and 0 x NaN is NaN. Dead K rows only
+    reach scores the mask replaces.
 
     The pool leaves are one layer's slices, k_hbm / v_hbm [N, KV, bs, Dh]
-    (an int8 pool: and ks_hbm / vs_hbm [N, KV, bs]), already holding the
-    launch's tokens; or, with `write`, the STACKED pool [L, N, KV, bs, Dh]
-    that does not hold them yet: the tile's q_len tokens, positions
-    q_start .. q_start + q_len - 1 of the row, arrive as new_refs
-    [1, tq, KVg, 1, Dh] and this program puts them where the table says.
-    A block they fall in is patched in VMEM once its copy has landed, and
-    its touched sublane tiles go back to HBM while the block folds. The
-    grid runs in order on the one core, so a later tile of the row reads
-    what an earlier one wrote; the pool is read and written through its
-    aliased OUTPUT refs (in interpret mode the inputs are copies).
+    (an int8 pool: and ks_hbm / vs_hbm [N, KV, bs]; P = 1 there), already
+    holding the launch's tokens; or, with `write`, the STACKED pool
+    [L, N, KV, bs, Dh] that does not hold them yet: the tile's q_len
+    tokens, positions q_start .. q_start + q_len - 1 of the row, arrive as
+    new_refs [1, tq, KVg, 1, Dh] and this program puts them where the
+    table says. A page they fall in (one, or the next too where the tile
+    straddles a page edge, inside a compute block or across two) is
+    patched in VMEM once its copy has landed, and its touched sublane
+    tiles go back to HBM while the compute block folds; the step waits
+    for them before its buffer half is filled again. The grid runs in
+    order on the one core, so a later tile of the row reads what an
+    earlier one wrote; the pool is read and written through its aliased
+    OUTPUT refs (in interpret mode the inputs are copies).
 
     latent > 0 is the latent (MLA, absorbed) form: the pool holds one row
     [c | k_r | pad] a token and there is no V pool; scores run over the
@@ -214,29 +289,61 @@ def _walk_kernel(
     l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
     acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    def copies(j, slot):
-        # logical block j of the row -> buffer `slot`: the head group's
-        # slab of the physical block, one contiguous run of HBM per pool
+    def page(buf, slot, p):
+        # page p of buffer half `slot`: rows p * bs .. of every head (an
+        # int8 pool's scale slabs are whole: P = 1 there)
+        if P == 1:
+            return buf.at[slot]
+        return buf.at[slot, :, pl.ds(pl.multiple_of(p * bs, bs), bs)]
+
+    def for_pages(lo, hi, body):
+        # body(p) for pages lo <= p < hi of a compute block (traced bounds):
+        # a scalar loop, so a step's code does not grow with P
+        if P == 1:
+            pl.when(lo < hi)(lambda: body(0))
+        else:
+            jax.lax.fori_loop(lo, hi, lambda p, c: (body(p), c)[1], 0)
+
+    def copies(j, slot, p):
+        # logical page j of the row -> page p of buffer half `slot`: the
+        # head group's slab of the physical block, one contiguous run of
+        # HBM per pool. A half's pages share its semaphore.
         blk = table_ref[row, j]
         return [
             pltpu.make_async_copy(
-                src.at[(*layer, blk, pl.ds(hg * KVg, KVg))], buf.at[slot],
-                sem.at[i, slot],
+                src.at[(*layer, blk, pl.ds(hg * KVg, KVg))],
+                page(buf, slot, p), sem.at[i, slot],
             )
             for i, (src, buf) in enumerate(zip(srcs, bufs))
         ]
 
-    # `write`: the sublane tiles of a block that go back to HBM. The new
-    # rows of a block are at most tq consecutive ones, so a static span of
-    # whole tiles holds them wherever they start.
+    def start_block(j0, slot):
+        """Starts the copies of compute block j0 .. j0 + P - 1's live
+        pages into buffer half `slot`."""
+        def start(p):
+            for c in copies(j0 + p, slot, p):
+                c.start()
+
+        for_pages(0, jnp.clip(needed - j0, 0, P), start)
+
+    # `write`: the sublane tiles of a page that go back to HBM. The new
+    # rows of a page are at most tq consecutive ones, so a static span of
+    # whole tiles holds them wherever they start; the tile's rows fall in
+    # `touched` consecutive pages at most.
     sub = 32 // kbuf.dtype.itemsize  # rows of one (sublane, 128-lane) tile
     span = min(bs, sub * (pl.cdiv(tq, sub) + 1))
+    touched = pl.cdiv(tq - 1, bs) + 1
 
-    def put_back(j, slot, start):
+    def rows_of(j, j0, start):
+        # rows start .. start + span of page j, where the buffer half that
+        # holds the compute block from j0 has them
+        return pl.ds(pl.multiple_of((j - j0) * bs + start, sub), span)
+
+    def put_back(j, j0, slot, start):
         blk = table_ref[row, j]
         return [
             pltpu.make_async_copy(
-                buf.at[slot, :, pl.ds(start, span)],
+                buf.at[slot, :, rows_of(j, j0, start)],
                 dst.at[(*layer, blk, pl.ds(hg * KVg, KVg),
                         pl.ds(start, span))],
                 wsem.at[i],
@@ -244,71 +351,82 @@ def _walk_kernel(
             for i, (dst, buf) in enumerate(zip(srcs, bufs))
         ]
 
-    def patch(j, slot):
-        """The tile's new rows that fall in block j, into the block's VMEM
-        copy: only the span that goes back is touched. Returns the span's
-        first row."""
-        r0 = q_start - j * bs  # block row of the tile's first token
+    def patch(j, j0, slot):
+        """The tile's new rows that fall in page j of the compute block
+        from j0, into the page's VMEM copy: only the span that goes back
+        is touched. Returns the span's first row in the page."""
+        r0 = q_start - j * bs  # page row of the tile's first token
         start = 0
         if span < bs:
             start = pl.multiple_of(
                 jnp.clip(r0, 0, bs - span) // sub * sub, sub
             )
-        at = jax.lax.broadcasted_iota(
+        ix = jax.lax.broadcasted_iota(
             jnp.int32, (span, kbuf.shape[3]), 0) + (start - r0)
         for new_ref, buf in zip(new_refs, bufs):
-            rows_ = (slot, slice(None), pl.ds(start, span))
+            rows_ = (slot, slice(None), rows_of(j, j0, start))
             cur = buf[rows_].astype(jnp.float32)  # [KVg, span, Dh]
             for t in range(tq):
                 new = new_ref[0, t].astype(jnp.float32)  # [KVg, 1, Dh]
-                cur = jnp.where(((at == t) & (t < q_len))[None], new, cur)
+                cur = jnp.where(((ix == t) & (t < q_len))[None], new, cur)
             buf[rows_] = cur.astype(buf.dtype)
         return start
 
-    @pl.when(first < needed)
-    def _():
-        for c in copies(first, first % 2):
-            c.start()
+    start_block(first, 0)
 
     # the tile's queries, KV heads first: [KVg, rows, Dh]
     q = q_ref[0]
     q = q[0] if tq == 1 else jnp.swapaxes(q, 0, 1)
     q = q.reshape(KVg, rows, Dh).astype(jnp.float32) * scale
-    t_local = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0) // group
-    col = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
+    t_local = jax.lax.broadcasted_iota(jnp.int32, (rows, P * bs), 0) // group
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, P * bs), 1)
     q_pos = q_start + t_local
     if block:  # the last position of the query's own block
         q_end = (q_pos // block + 1) * block - 1
     else:
         q_end = q_pos
     heads = ((0,), (0,))  # dot_general batch dims: the slab's KV heads
+    value_buf = kbuf if latent else vbuf
 
-    def fold_block(j, carry):
-        slot = j % 2
+    def fold_block(i, carry):
+        slot = i % 2
+        j0 = first + i * P
+        start_block(j0 + P, 1 - slot)
+        live = jnp.minimum(needed - j0, P)  # >= 1: pages that land
 
-        @pl.when(j + 1 < needed)
-        def _():
-            for c in copies(j + 1, 1 - slot):
-                c.start()
+        def wait(p):
+            for c in copies(j0 + p, slot, p):
+                c.wait()
 
-        for c in copies(j, slot):
-            c.wait()
+        def zero(p):  # a dead page: nothing lands, the value rows read 0
+            dead = page(value_buf, slot, p)
+            dead[...] = jnp.zeros(dead.shape, dead.dtype)
+
+        for_pages(0, live, wait)
+        if P > 1:
+            for_pages(live, P, zero)
+
         if write:
-            has_new = (j * bs < q_start + q_len) & ((j + 1) * bs > q_start)
+            # the pages of this compute block that hold new rows
+            news = []
+            for k in range(touched):
+                j = q_start // bs + k
+                news.append((j, (j >= j0) & (j < jnp.minimum(j0 + P, needed))
+                             & (j * bs < q_start + q_len)))
+            for j, has_new in news:
+                @pl.when(has_new)
+                def _():
+                    for c in put_back(j, j0, slot, patch(j, j0, slot)):
+                        c.start()
 
-            @pl.when(has_new)
-            def _():
-                for c in put_back(j, slot, patch(j, slot)):
-                    c.start()
-
-        kv_pos = j * bs + col
+        kv_pos = j0 * bs + col
         mask = (t_local < q_len) & (kv_pos <= q_end)
         mask &= (win <= 0) | (kv_pos > q_pos - win)
-        ks = kbuf[slot].astype(jnp.float32)  # [KVg, bs, Dh]
+        ks = kbuf[slot].astype(jnp.float32)  # [KVg, P x bs, Dh]
         vs = ks[:, :, :latent] if latent else vbuf[slot].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, ks, (((2,), (2,)), heads), preferred_element_type=jnp.float32
-        )  # [KVg, rows, bs]
+        )  # [KVg, rows, P x bs]
         if quant:
             # a token's scale is common to its Dh products, so it scales
             # the score (and below the probability) with the tokens on
@@ -330,16 +448,17 @@ def _walk_kernel(
             p, vs, (((2,), (1,)), heads), preferred_element_type=jnp.float32
         )
         if write:
-            # before the slot is filled again, and before a later program
-            # of the row reads the block
-            @pl.when(has_new)
-            def _():
-                for c in put_back(j, slot, 0):
-                    c.wait()
+            # before the half is filled again, and before a later program
+            # of the row reads the page
+            for j, has_new in news:
+                @pl.when(has_new)
+                def _():
+                    for c in put_back(j, j0, slot, 0):
+                        c.wait()
 
         return carry
 
-    jax.lax.fori_loop(first, needed, fold_block, 0)
+    jax.lax.fori_loop(0, pl.cdiv(needed - first, P), fold_block, 0)
 
     l = l_ref[:]
     l = jnp.where(l == 0.0, 1.0, l)  # padding queries, rows not walked
@@ -417,10 +536,11 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
     Dp = q5.shape[-1]
     Dv = value_dim if latent else Dp
     rows = tq * group
-    KVg = _heads_per_slab(KV, bs, Dp, leaves[0].dtype.itemsize, quant, rows)
+    KVg, P = _walk_shape(KV, bs, Dp, leaves[0].dtype.itemsize, quant, rows,
+                         MB, latent)
 
     kernel = functools.partial(
-        _walk_kernel, bs=bs, MB=MB, tq=tq, KVg=KVg, group=group,
+        _walk_kernel, bs=bs, MB=MB, tq=tq, KVg=KVg, P=P, group=group,
         scale=scale if scale is not None else Dh**-0.5, softcap=softcap,
         quant=quant, latent=value_dim if latent else 0,
         write=write is not None, block=block,
@@ -438,7 +558,9 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
         pltpu.VMEM((KVg, rows, Dv), jnp.float32),
         pltpu.SemaphoreType.DMA((n, 2)),
     ]
-    scratch += [pltpu.VMEM((2, KVg, bs, Dp), a.dtype) for a in leaves[:2]]
+    scratch += [
+        pltpu.VMEM((2, KVg, P * bs, Dp), a.dtype) for a in leaves[:2]
+    ]
     scratch += [
         pltpu.VMEM((2, KVg) + a.shape[2:], jnp.float32) for a in leaves[2:]
     ]

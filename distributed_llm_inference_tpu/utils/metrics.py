@@ -66,6 +66,13 @@ SLOT_RELEASE_HELP = (
     "(a fetched launch showed the row ended or it was killed; a reaped "
     "prefill; a preemption)"
 )
+# the paged kernels' loop steps (engine/continuous._kv_walk_steps)
+ATTN_WALK_STEPS_HELP = (
+    "loop steps of the paged kernels' walk per layer (host position "
+    "model): a step folds up to P pages, P from the shapes "
+    "(ops/paged_attention._walk_shape), so walked KV positions / block "
+    "size / this = pages a loop step"
+)
 SLOT_TURNOVER_HELP = (
     "scheduler steps dispatched between a row's last live step by the "
     "host position model and the first prefill chunk of the slot's next "

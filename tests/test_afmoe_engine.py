@@ -144,6 +144,11 @@ def test_served_rows_agree_with_the_reference_over_given_back_blocks(impl):
         for r in kinds)
     # a decode row at position p reads min(p + 1, 8) positions a window layer
     assert any(r["kv_tokens_window"] < r["kv_tokens_global"] for r in kinds)
+    # the walk's loop steps, the kinds summed by their layers like the pages:
+    # none under the gather path, at most one a page under the kernels
+    bs = f.ce.kv_block_size
+    assert all((0 < r["kv_walk_steps"] <= r["kv_grid_tokens"] // bs)
+               if impl == "pallas" else r["kv_walk_steps"] == 0 for r in kinds)
     f.ce._note_groups(every=0.0)
     groups = f.series("dli_kv_group_blocks")
     for group, alloc in (("global", f.ce._alloc), ("window", wg.alloc)):

@@ -39,6 +39,8 @@ from distributed_llm_inference_tpu.ops.paged_attention import (
     ragged_paged_attend,
 )
 
+from paged_walk_cases import CELL_CONFIGS, cell_pool
+
 # TinyLlama-1.1B widths: 32 query heads over 4 kv heads, head_dim 64
 H, KV, DH = 32, 4, 64
 POOL_BLOCKS = 3072  # chip_smoke.py's pool: >= 1 GiB of bf16 KV at bs 16
@@ -593,6 +595,51 @@ def test_step_programs_read_the_attention_projections_in_place(
 # `__wrapped__` (the undecorated function under `gmm`'s own jit), only so that
 # the custom call carries this program's name: a JAX release that drops the
 # attribute fails the two tests below first, not the benchmark's readers.
+# The six configurations as their cells serve them: both kernels as the
+# step programs call them (the stacked pool, written in place), at the
+# (KV heads, pages a loop step) their shapes get (ops/paged_attention.
+# _walk_shape; tests/test_launch_record.py pins the numbers), by the step
+# program that calls them. A grouped pool (trinity) compiles its window
+# group's leaf under the window.
+@pytest.mark.parametrize("program", ["decode_slots_paged", "mixed_step_ragged"])
+@pytest.mark.parametrize("config", CELL_CONFIGS)
+def test_paged_kernels_compile_writing_in_place_at_every_cells_shapes(
+    one_chip, no_persistent_cache, monkeypatch, config, program
+):
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    cfg, slots, mb, pool = cell_pool(config)
+    names = EP.GROUP_LEAVES[1] if len(cfg.kv_groups) > 1 else (
+        ("k", "v") if "k" in pool else ("moe", None))
+    S = _spec(one_chip)
+    pool_k, pool_v = (n and S(pool[n].shape, pool[n].dtype) for n in names)
+    kv, _, width = pool_k.shape[-3:]
+    kw = dict(window=cfg.attn_window or None, scale=cfg.query_scale,
+              value_dim=cfg.kv_lora_rank if pool_v is None else None)
+    table = S((slots, mb), jnp.int32)
+    kernel = "ragged_paged_attend"
+    if program == "decode_slots_paged" and not cfg.diffusion_block:
+        kernel = "paged_flash_attend"
+        new = S((slots, 1, kv, width), jnp.bfloat16)
+        text = _compile(
+            lambda q, pk, pv, t, pos, live, layer, k, v: paged_flash_attend(
+                q, pk, pv, t, pos, None, live,
+                (layer, k, None if pv is None else v), **kw),
+            S((slots, 1, cfg.n_heads, width), jnp.bfloat16), pool_k, pool_v,
+            table, S((slots,), jnp.int32), S((slots,), jnp.bool_),
+            S((), jnp.int32), new, new)
+    else:  # a block-diffusion row's forward is a query tile in both programs
+        flat = slots * 8 if program == "decode_slots_paged" else step_width(
+            cfg, slots, 8)
+        new = S((flat, kv, width), jnp.bfloat16)
+        text = _compile(
+            lambda q, pk, pv, t, m, layer, k, v: ragged_paged_attend(
+                q, pk, pv, t, m, None, (layer, k, None if pv is None else v),
+                block=cfg.diffusion_block, **kw),
+            S((flat, cfg.n_heads, width), jnp.bfloat16), pool_k, pool_v,
+            table, S((flat // 8, 4), jnp.int32), S((), jnp.int32), new, new)
+    assert any(kernel in c for c in _custom_call_names(text))
+
+
 EXPERT_KERNELS = {"routed_expert_matmul"}
 STEP_SCOPES = {"moe_route", "moe_dispatch", "moe_experts", "moe_combine",
                "moe_shared", "mla_absorb"}

@@ -277,6 +277,123 @@ def test_host_walk_is_the_kernels_live_range(bs, mb, window):
     assert np.all(ContinuousEngine._kv_walk(host, pos, 0) == bs * mb)
 
 
+# -- (a3) loop steps of the walk: pages over the pages a step folds -------------
+
+def test_walk_steps_equal_the_hand_sum(walk_run, setup):
+    """`kv_walk_steps` / `dli_attn_walk_steps_total`: every tile's pages
+    over P, rounded up, P from the function the kernels take it from. The
+    tiny fleet's pool (2 KV heads of 16 numbers, 16-token blocks, a table
+    of 16) gives 8 pages a step in both programs, the most any shape gets,
+    so with a 48-token window every live tile is one loop step."""
+    from distributed_llm_inference_tpu.engine import paged as EP
+    from distributed_llm_inference_tpu.ops.paged_attention import (
+        walk_pages_per_step,
+    )
+
+    cfg, _ = setup
+    leaf = jax.eval_shape(lambda: EP.init_pool(cfg, 64, BLOCK))["k"]
+    P = {"chunk": walk_pages_per_step(leaf, cfg.n_heads, 1, MAX_SEQ // BLOCK),
+         "mixed": walk_pages_per_step(leaf, cfg.n_heads, 8, MAX_SEQ // BLOCK)}
+    assert P == {"chunk": 8, "mixed": 8}
+    run = walk_run
+    W, snap = run["width"], run["snap"]
+    steps = {"mixed": 0, "chunk": 0}
+    for (_, answer), r in zip(REQUESTS, run["results"]):
+        n = r["prompt_tokens"]
+        for c in range(math.ceil(n / W)):
+            end = min(n, (c + 1) * W)
+            steps["mixed"] += sum(
+                -(-_walk(t, min(8, end - t)) // BLOCK // P["mixed"])
+                for t in range(c * W, end, 8))
+        steps["chunk"] += sum(-(-_walk(p) // BLOCK // P["chunk"])
+                              for p in range(n, n + answer - 1))
+    for phase in ("mixed", "chunk"):
+        assert _value(snap, "dli_attn_walk_steps_total", phase=phase) \
+            == steps[phase] > 0
+    # the flight recorder's `plan` events are the mixed launches' records
+    ev = [e for e in run["flight"] if e["kind"] == "plan"]
+    assert sum(e["kv_walk_steps"] for e in ev) == steps["mixed"]
+    for e in ev:  # pages a loop step: between 1 and P
+        pages = e["kv_grid_tokens"] // BLOCK
+        assert e["kv_walk_steps"] <= pages <= 8 * e["kv_walk_steps"]
+
+
+def test_walk_steps_are_zero_under_the_gather_path(runs):
+    snap = runs[0]["snap"]
+    for phase in ("mixed", "chunk"):
+        assert _value(snap, "dli_attn_walk_steps_total", phase=phase) == 0
+    assert all(e["kv_walk_steps"] == 0 for e in runs[0]["flight"]
+               if e["kind"] == "plan")
+
+
+@pytest.mark.parametrize("P", [1, 2, 4, 8])
+def test_host_walk_steps_round_the_pages_up(P):
+    """`_kv_walk_steps` against the kernels' trip count
+    ceil((needed - first) / P), on a sweep of positions under a window."""
+    import types
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_llm_inference_tpu.ops.paged_attention import (
+        _ragged_live_range,
+    )
+
+    bs, mb, window = 16, 24, 100
+    host = types.SimpleNamespace(
+        _kv_walks=True, kv_block_size=bs, _max_blocks=mb, _kv_window=window,
+        _scratch_seq=bs * mb, _walk_pages={"chunk": P, "mixed": 1},
+    )
+    host._kv_walk = types.MethodType(ContinuousEngine._kv_walk, host)
+    pos = np.arange(0, bs * mb)
+    for n in (0, 1, 8):
+        first, needed = _ragged_live_range(
+            jnp.asarray(pos), jnp.int32(n), bs=bs, MB=mb, win=jnp.int32(window))
+        trips = -(-(np.asarray(needed) - np.asarray(first)) // P)
+        walk = host._kv_walk(pos, n)
+        got = ContinuousEngine._kv_walk_steps(host, "chunk", walk)
+        np.testing.assert_array_equal(got, trips if n else 0 * trips)
+        pages = ContinuousEngine._kv_walk_steps(host, "mixed", walk)
+        np.testing.assert_array_equal(pages * bs, walk)
+    host._kv_walks = False
+    walk = host._kv_walk(pos)  # the gather path: every row's whole table
+    assert np.all(ContinuousEngine._kv_walk_steps(host, "chunk", walk) == 0)
+
+
+# (KV heads a program, pages a loop step) of the decode chunk's kernel and of
+# the mixed launch's (query tiles of 8), at the benchmark's configurations as
+# their cells serve them (cellbench/configs/<name>.json); PERF.md, PR 45
+CELL_WALK_SHAPES = {
+    "olmo2-7b-16l": ((32, 1), (32, 1)),  # 2 MB a page already: olmo2-chat, -batch
+    "mistral-7b-16l": ((8, 2), (8, 2)),
+    "kanana-2-30b-a3b-7l": ((1, 8), (1, 4)),  # 164 KB a page: the latent row
+    "sdar-30b-a3b-7l": ((4, 4), (4, 4)),  # a row's forward is a tile of 8
+    "lfm2-24b-a2b-9l": ((4, 4), (4, 4)),  # pairs of 64-number heads a row
+    "trinity-large-ep8-5l": ((8, 2), (8, 2)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CELL_WALK_SHAPES))
+def test_walk_shape_at_the_cells_shapes(name):
+    """`_walk_shape` pinned where the benchmark runs it, through the
+    engine's own reading of its pool (`_walk_pages_of`): a change of the
+    rule or of the stated VMEM count shows here as the P each cell gets."""
+    import types
+
+    from paged_walk_cases import cell_pool
+
+    from distributed_llm_inference_tpu.ops.paged_attention import _walk_shape
+
+    cfg, _, mb, pool = cell_pool(name)
+    host = types.SimpleNamespace(cfg=cfg, _max_blocks=mb, cache=pool)
+    kv, bs, dh = next(a for a in jax.tree.leaves(pool) if a.ndim == 5).shape[-3:]
+    tiles = (2 * cfg.diffusion_block or 1, 8)  # the decode chunk's, a mixed launch's
+    for tq, want in zip(tiles, CELL_WALK_SHAPES[name]):
+        assert ContinuousEngine._walk_pages_of(host, tq) == want[1]
+        assert _walk_shape(kv, bs, dh, 2, False, tq * (cfg.n_heads // kv), mb,
+                           cfg.latent_dim > 0) == want
+
+
 # -- (b) both kinds of launch are counted; the old series keep their values ----
 
 def test_chunk_launches_and_row_steps_are_counted(runs):

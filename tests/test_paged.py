@@ -19,6 +19,8 @@ from distributed_llm_inference_tpu.engine import paged as P
 from distributed_llm_inference_tpu.engine.continuous import ContinuousEngine
 from distributed_llm_inference_tpu.engine.engine import InferenceEngine
 
+from paged_walk_cases import DECODE_BLOCK_CASES, check_decode_block_case
+
 PROMPTS = [
     "the quick brown fox",
     "jumps over",
@@ -296,12 +298,18 @@ WALK_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(WALK_CASES))
+@pytest.mark.parametrize(
+    "case", sorted(WALK_CASES) + sorted(DECODE_BLOCK_CASES))
 def test_paged_kernel_walk_matches_gather(case):
     """Kernel-level: the block walk == gather + attend on every live row;
     a row whose active flag is false is not walked (its output, which the
     caller discards, is zeros) and leaves the live rows' outputs as they
-    are without the mask."""
+    are without the mask. WALK_CASES' shapes walk 4 pages a loop step (an
+    int8 pool 1); the `blocks-` cases (tests/paged_walk_cases.py) walk 1,
+    2, 4 and 8 by their shapes, with contexts that end at, past and short
+    of a compute block."""
+    if case in DECODE_BLOCK_CASES:
+        return check_decode_block_case(case)
     from distributed_llm_inference_tpu.ops.attention import (
         attend, slot_causal_mask,
     )

@@ -35,6 +35,8 @@ from distributed_llm_inference_tpu.ops.paged_attention import (
     ragged_paged_attend,
 )
 
+from paged_walk_cases import RAGGED_BLOCK_CASES, check_ragged_block_case
+
 
 # -- kernel-level bit-exactness (ragged vs dense reference) -------------------
 
@@ -145,11 +147,17 @@ RAGGED_WALK_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(RAGGED_WALK_CASES))
+@pytest.mark.parametrize(
+    "case", sorted(RAGGED_WALK_CASES) + sorted(RAGGED_BLOCK_CASES))
 def test_ragged_kernel_walk_matches_dense_reference(case):
     """Every live query == the dense reference at its position; a tile
     that holds nothing is not walked and writes zeros; queries past a
-    tile's q_len are padding (fully masked: zeros)."""
+    tile's q_len are padding (fully masked: zeros). RAGGED_WALK_CASES'
+    shapes walk 4 pages a loop step (an int8 pool 1); the `blocks-` cases
+    (tests/paged_walk_cases.py) walk 1, 2, 4 and 8 by their shapes and
+    write their tiles' rows into a stacked pool as the step programs do."""
+    if case in RAGGED_BLOCK_CASES:
+        return check_ragged_block_case(case)
     tiles, window, dyn, quant, *rest = RAGGED_WALK_CASES[case]
     softcap = rest[0] if rest else None
     (pool_k, pool_v, table, _, _, _, _, _, _, q, bs, MB, KV, Dh) = \
