@@ -119,7 +119,8 @@ import numpy as np
 from ..utils import faults
 from ..utils.logging import get_logger
 from ..utils.metrics import (
-    ADMISSION_WAIT_HELP, ATTN_WALK_STEPS_HELP, CONV_STATE_RESETS_HELP,
+    ADMISSION_WAIT_HELP, ATTN_WALK_STEPS_HELP, CHUNK_STEPS_HELP,
+    CONV_STATE_RESETS_HELP,
     CONV_TAIL_WRITES_HELP, DEFAULT_SIZE_BUCKETS, DIFFUSION_FORWARDS_HELP,
     DIFFUSION_FUSED_HELP, DIFFUSION_TOKENS_HELP, KV_GROUP_BLOCKS_HELP,
     KV_WINDOW_RELEASED_HELP, MOE_PAIRS_HELP, PREFIX_STATE_TOKENS_HELP,
@@ -955,8 +956,8 @@ class ContinuousEngine:
         ).labels()
         self._m_step = m.histogram(
             "dli_decode_step_seconds",
-            "per-token decode step time, chunk launch-to-fetch / "
-            "chunk_steps (includes pipelining lag)", ("engine",),
+            "per-token decode step time, chunk launch-to-fetch / the "
+            "steps the chunk ran (includes pipelining lag)", ("engine",),
         ).labels(engine="continuous")
         self._m_preempt = m.counter(
             "dli_preemptions_total",
@@ -1096,6 +1097,9 @@ class ContinuousEngine:
         )
         self._m_walk_steps = m.counter(
             "dli_attn_walk_steps_total", ATTN_WALK_STEPS_HELP, ("phase",),
+        )
+        self._m_chunk_steps = m.counter(
+            "dli_decode_chunk_steps_total", CHUNK_STEPS_HELP, ("state",),
         )
         # routed experts (a latent pool's "routed" leaf, models/mla_moe.py):
         # its shape [2, expert layers, experts], or None for a model
@@ -3347,9 +3351,19 @@ class ContinuousEngine:
         """Launch one decode chunk over the current fleet (paged /
         constrained / plain slot program — state, cache, and fsm chain
         device-side between launches, so no fetch is needed to launch the
-        next chunk). Returns the inflight tuple (packed results dev
-        array, assignment snapshot, launch time, mutation seq, launch
-        record) or None when no slot is active."""
+        next chunk). K = chunk_steps is what is DISPATCHED; the paged
+        program runs steps only while a row of the fleet is active
+        (engine/paged.steps_while_active), so a chunk whose last live row
+        ends at its j-th step costs j forwards, and a dead fleet's none.
+        The record says what the position model expects of it
+        (`steps_live`, the largest of the rows' live steps; `steps` stays
+        K) and the fetch what the device did (`steps_run`). Host
+        bookkeeping that counts steps (`_steps_dispatched`,
+        `_steps_inflight`, `_ended_at`, dli_slot_turnover_steps) counts
+        DISPATCHED steps: they are known here, without a fetch. Returns
+        the inflight tuple (packed results dev array, assignment
+        snapshot, launch time, mutation seq, launch record) or None when
+        no slot is active."""
         if not any(r is not None for r in self._assignment):
             return None
         faults.check("decode_launch", tag=",".join(
@@ -3403,14 +3417,17 @@ class ContinuousEngine:
                 lambda w: np.sum(self._kv_span(at, span, w) * alive),
                 lambda w: self._kv_walk(at, alive * span, w)),
             row_steps=int(live.sum()),
-            decode_rows=int(np.count_nonzero(live)), **diff_fields,
+            decode_rows=int(np.count_nonzero(live)),
+            steps_live=int(live.max()), **diff_fields,
         )
-        # every believed-active slot advances K (over-advance on rows
-        # that die mid-chunk is masked garbage, the frozen-row rule)
+        # every believed-active slot advances K: a row that outlives the
+        # chunk forces all K steps, one that dies mid-chunk is frozen
+        # whether the steps behind it run or not (the frozen-row rule)
         self._host_pos[rows] += K
         self._clock.mark("dispatch", "launch.chunk", **rec)
+        steps_run = K  # the dense slot programs scan: every step runs
         if self._blk:
-            emitted, mask, self.state, self.cache, self._diff = (
+            emitted, mask, self.state, self.cache, self._diff, steps_run = (
                 self._step_program(
                     "decode_slots_paged",
                     self.state, self.cache, self._table_dev,
@@ -3419,7 +3436,7 @@ class ContinuousEngine:
                 )
             )
         elif self.paged:
-            emitted, mask, self.state, self.cache = (
+            emitted, mask, self.state, self.cache, steps_run = (
                 self._step_program(
                     "decode_slots_paged",
                     self.state, self.cache, self._table_dev,
@@ -3447,7 +3464,7 @@ class ContinuousEngine:
                     self.sparams, num_steps=self.chunk_steps,
                 )
             )
-        packed = G.pack_chunk(emitted, mask, self.state.active)
+        packed = G.pack_chunk(emitted, mask, self.state.active, steps_run)
         if self._routed_shape is not None:
             packed = self._P.pack_routed(packed, self.cache["routed"])
         self._window_release(wrows)
@@ -3487,7 +3504,10 @@ class ContinuousEngine:
         only the fetch knows), and a slot whose previous retiring tenant
         is still unfetched (a slot has at most one). `last_live[b]`
         places the row's last live step among the steps dispatched so
-        far (a chunk's row can end before the chunk does)."""
+        far (a chunk's row can end before the chunk does, and the chunk
+        itself ends on the device with its last live row: the count stays
+        in DISPATCHED steps, the one unit the host knows without a
+        fetch)."""
         ended = self._host_pos >= self._host_end
         if not ended.any():
             return
@@ -5231,21 +5251,35 @@ class ContinuousEngine:
     def _fetch(self, packed_dev, t_launch: float, rec: dict):
         """The ONE blocking fetch that closes a launch record: the wait
         is the `fetch.<phase>` interval of the worker's clock, everything
-        after it `distribute`. dli_decode_step_seconds is launch-to-fetch
-        over the launch's steps: under lag-N pipelining this includes
-        queue wait behind earlier launches, so it is the EFFECTIVE
-        per-token step time the fleet delivers, not raw compute."""
+        after it `distribute`. A chunk's packed rows end in the count of
+        steps the device ran (it stops at its last live row's end): the
+        record closes with `steps_run`, which rides the span that follows
+        the fetch, and dli_decode_chunk_steps_total counts the dispatched
+        steps as run | cut. dli_decode_step_seconds is launch-to-fetch
+        over the steps that RAN (a mixed launch: 1; a dead fleet's empty
+        chunk counts as 1): under lag-N pipelining this includes queue
+        wait behind earlier launches, so it is the EFFECTIVE per-token
+        step time the fleet delivers, not raw compute."""
         self._clock.mark(
             "fetch_wait", f"fetch.{rec['phase']}", seq=rec["seq"]
         )
         packed = np.asarray(packed_dev)
-        routed = {}
+        phase, steps_run = rec["phase"], rec["steps"]
+        after = {}  # what the fetch learned: on the span that follows it
+        if phase == "chunk":
+            # the last of the chunk's own rows (G.pack_chunk: [2K+2, B]),
+            # in front of whatever pack_routed appended
+            K = self.chunk_steps * max(1, self._blk)
+            steps_run = rec["steps_run"] = int(packed[2 * K + 1, 0])
+            self._m_chunk_steps.labels(state="run").inc(steps_run)
+            self._m_chunk_steps.labels(state="cut").inc(
+                rec["steps"] - steps_run)
+            after = {"seq": rec["seq"], "steps_run": steps_run}
         if self._routed_shape is not None:
             # what the launch's expert layers routed came in the same
             # array: it closes the record and rides the span that follows
             # the fetch, with the launch's seq
             packed, counts = self._P.unpack_routed(packed, self._routed_shape)
-            phase = rec["phase"]
             away = 0
             if self._expert_share:
                 away, counts = int(counts[0, :, -1].sum()), counts[:, :, :-1]
@@ -5254,20 +5288,20 @@ class ContinuousEngine:
             self._m_moe_pairs.labels(where="routed").inc(
                 rec["moe_pairs"] + away)
             rec["moe_experts_touched"] = int(counts[1].sum())
-            slots = counts[1].size * rec["steps"]
+            slots = counts[1].size * steps_run
             self._m_moe_tokens.labels(phase=phase).inc(rec["moe_pairs"])
             self._m_moe_touched.labels(phase=phase).inc(
                 rec["moe_experts_touched"]
             )
             self._m_moe_slots.labels(phase=phase).inc(slots)
-            routed = {
-                "seq": rec["seq"], "moe_pairs": rec["moe_pairs"],
-                "moe_experts_touched": rec["moe_experts_touched"],
-                "moe_expert_slots": slots,
-            }
-        now = self._clock.mark("distribute", **routed)
+            after.update(
+                seq=rec["seq"], moe_pairs=rec["moe_pairs"],
+                moe_experts_touched=rec["moe_experts_touched"],
+                moe_expert_slots=slots,
+            )
+        now = self._clock.mark("distribute", **after)
         self._steps_inflight -= rec["steps"]
-        self._m_step.observe(max(0.0, now - t_launch) / rec["steps"])
+        self._m_step.observe(max(0.0, now - t_launch) / max(1, steps_run))
         return packed
 
     def _observe_admission(self, req: _Request, wait_s: float,
@@ -5287,7 +5321,7 @@ class ContinuousEngine:
         faults.check("fetch", tag=",".join(
             r.prompt for r in snapshot if r is not None
         ))
-        # [2K+1, B] — the ONE fetch per chunk
+        # [2K+2, B] — the ONE fetch per chunk
         packed = self._fetch(packed_dev, t_launch, rec)
         K = self.chunk_steps * max(1, self._blk)  # a forward's block, row by row
         emitted = packed[:K]

@@ -33,7 +33,8 @@ from ..models import api as M
 from ..utils import faults
 from ..utils.logging import get_logger, request_id_context
 from ..utils.metrics import (
-    ADMISSION_WAIT_HELP, ATTN_WALK_STEPS_HELP, DEFAULT_SIZE_BUCKETS,
+    ADMISSION_WAIT_HELP, ATTN_WALK_STEPS_HELP, CHUNK_STEPS_HELP,
+    DEFAULT_SIZE_BUCKETS,
     DIFFUSION_FORWARDS_HELP, DIFFUSION_FUSED_HELP, DIFFUSION_TOKENS_HELP,
     SLOT_RELEASE_HELP, SLOT_TURNOVER_HELP,
     STEPS_AHEAD_BUCKETS, MetricsRegistry,
@@ -686,6 +687,9 @@ class InferenceEngine:
         )
         self.metrics.counter(
             "dli_attn_walk_steps_total", ATTN_WALK_STEPS_HELP, ("phase",),
+        )
+        self.metrics.counter(
+            "dli_decode_chunk_steps_total", CHUNK_STEPS_HELP, ("state",),
         )
         self.metrics.histogram(
             "dli_launch_steps_ahead",

@@ -622,16 +622,18 @@ def kill_slot(state: SlotState, slot):
 
 
 @jax.jit
-def pack_chunk(emitted, emit_mask, active):
+def pack_chunk(emitted, emit_mask, active, steps_run):
     """Pack one decode chunk's host-bound results into a single int32 array
-    [2K+1, B] (emitted / mask / final active), so the per-chunk
-    device->host cost is ONE transfer — each fetch is a host sync, and
-    three of them would triple the loop's per-chunk overhead."""
+    [2K+2, B] (emitted / mask / final active / the steps the chunk ran, the
+    count in every column), so the per-chunk device->host cost is ONE
+    transfer — each fetch is a host sync, and three of them would triple
+    the loop's per-chunk overhead."""
     return jnp.concatenate(
         [
             emitted,
             emit_mask.astype(jnp.int32),
             active.astype(jnp.int32)[None, :],
+            jnp.full((1, active.shape[0]), steps_run, jnp.int32),
         ],
         axis=0,
     )

@@ -1138,6 +1138,40 @@ def diffusion_step(cfg: ModelConfig, state: G.SlotState,
     return state, diff, emit, emit_ok
 
 
+def steps_while_active(step, state: G.SlotState, carry, key, *,
+                       num_steps: int, emit_shape: tuple, pad):
+    """A decode chunk's step loop, ONE copy for both bodies of
+    decode_slots_paged: run `step(state, carry, key_i) -> (state, carry,
+    emit, ok)` for i = 0, 1, ... while i < num_steps AND some row of the
+    fleet is active. num_steps is the static upper bound (one program a
+    configuration, as under the scan this replaced); the exit is what the
+    program observes in its own state. Step i takes
+    jax.random.split(key, num_steps)[i] whatever the trip count, and writes
+    row i of emitted / emit_mask [num_steps, *emit_shape], which hold pad /
+    False from the exit on. A step that runs is the step the scan ran; one
+    that does not would have changed frozen rows' garbage alone (slot_step
+    freezes an inactive row). Returns (emitted, emit_mask, state, carry,
+    steps_run i32 [])."""
+    subs = jax.random.split(key, num_steps)
+    shape = (num_steps, *emit_shape)
+
+    def live(c):
+        return (c[0] < num_steps) & jnp.any(c[1].active)
+
+    def body(c):
+        i, state, carry, emitted, emit_mask = c
+        state, carry, emit, ok = step(state, carry, subs[i])
+        return (i + 1, state, carry, emitted.at[i].set(emit),
+                emit_mask.at[i].set(ok))
+
+    i, state, carry, emitted, emit_mask = jax.lax.while_loop(
+        live, body,
+        (jnp.int32(0), state, carry, jnp.full(shape, pad, jnp.int32),
+         jnp.zeros(shape, bool)),
+    )
+    return emitted, emit_mask, state, carry, i
+
+
 @functools.partial(
     jax.jit, static_argnames=("cfg", "num_steps"), donate_argnames=("pool",)
 )
@@ -1154,53 +1188,62 @@ def decode_slots_paged(
     pages=None,
     diff=None,
 ):
-    """Paged twin of generate.decode_slots: advance every slot num_steps
-    tokens over the block pool. Same slot_step, same emitted/emit_mask
-    contract — only the cache strategy differs, so cross-mode token parity
-    is structural. The table is a plain (traced) input: admission changes
-    it without recompiling. pages: optional [B] i32 per-slot adapter
-    pages (0 = base), traced like the table.
+    """Paged twin of generate.decode_slots: advance every slot UP TO
+    num_steps tokens over the block pool. Same slot_step, same
+    emitted/emit_mask contract — only the cache strategy differs, so
+    cross-mode token parity is structural. The table is a plain (traced)
+    input: admission changes it without recompiling. pages: optional [B]
+    i32 per-slot adapter pages (0 = base), traced like the table.
+
+    The chunk ends when its last live row does (`steps_while_active`): no
+    forward runs for a fleet with no active row, emitted / emit_mask hold
+    pad / False from there on, and the count of steps that ran comes back
+    LAST (steps_run, i32 []: num_steps where a row outlives the chunk, 0
+    for a fleet that was dead at dispatch).
 
     A block-diffusion model (cfg.diffusion_block > 0, `diff` its
-    DiffState) runs num_steps FORWARDS instead, each carrying every live
-    row's whole open block (`_forward_blocks_paged`, `diffusion_step`):
-    emitted / emit_mask are [num_steps * block, B] (a forward's block row
-    by row) and the DiffState comes back last."""
+    DiffState) runs up to num_steps FORWARDS instead, each carrying every
+    live row's whole open block (`_forward_blocks_paged`,
+    `diffusion_step`): emitted / emit_mask are [num_steps * block, B] (a
+    forward's block row by row) and the DiffState comes back before the
+    count."""
 
     pool = _routed_reset(pool)
+    B = state.active.shape[0]
+    pad = jnp.int32(cfg.pad_token_id)
     if cfg.diffusion_block:
-        def forward(carry, sub):
-            state, diff, pool = carry
+        def forward(state, carry, sub):
+            diff, pool = carry
             logits, pool = _forward_blocks_paged(
                 cfg, params, state, diff, pool, table
             )
             state, diff, emit, ok = diffusion_step(
                 cfg, state, sparams, diff, logits, sub
             )
-            return (state, diff, pool), (emit.T, ok.T)
+            return state, (diff, pool), emit.T, ok.T
 
-        subs = jax.random.split(key, num_steps)
-        (state, diff, pool), (emitted, emit_mask) = jax.lax.scan(
-            forward, (state, diff, pool), subs
+        emitted, emit_mask, state, (diff, pool), steps_run = (
+            steps_while_active(
+                forward, state, (diff, pool), key, num_steps=num_steps,
+                emit_shape=(cfg.diffusion_block, B), pad=pad,
+            )
         )
-        rows = (num_steps * cfg.diffusion_block, -1)
+        rows = (num_steps * cfg.diffusion_block, B)
         return (emitted.reshape(rows), emit_mask.reshape(rows), state, pool,
-                diff)
+                diff, steps_run)
 
-    def body(carry, sub):
-        state, pool = carry
+    def body(state, pool, sub):
         logits, pool = _forward_step_paged(
             cfg, params, state.token[:, None], pool, table, state.pos,
             pages=pages, active=state.active,
         )
         new, emit, can_emit = G.slot_step(cfg, state, sparams, logits, sub)
-        return (new, pool), (emit, can_emit)
+        return new, pool, emit, can_emit
 
-    subs = jax.random.split(key, num_steps)
-    (state, pool), (emitted, emit_mask) = jax.lax.scan(
-        body, (state, pool), subs
+    return steps_while_active(
+        body, state, pool, key, num_steps=num_steps, emit_shape=(B,),
+        pad=pad,
     )
-    return emitted, emit_mask, state, pool
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnames=("pool",))

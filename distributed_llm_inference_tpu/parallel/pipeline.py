@@ -757,7 +757,9 @@ class PipelineBackend(SPMDBackendBase):
         args = [self.shared, self.layers, state, pool, table, key, sparams]
         if pages is not None:
             args.append(pages)
-        return fn(*args)
+        # the mesh twin keeps its scan (an exit every stage of the ring has
+        # to agree on: ROADMAP C), so it ran every step it was given
+        return (*fn(*args), num_steps)
 
     def fill_scratch_paged(self, pool, table_row):
         fn = self._programs.get("fill_paged")

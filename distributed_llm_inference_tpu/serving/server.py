@@ -1693,7 +1693,8 @@ def main(argv: Optional[list] = None):
     )
     ap.add_argument(
         "--continuous-chunk", type=int, default=16,
-        help="decode steps per device round-trip in continuous mode",
+        help="decode steps per device round-trip in continuous mode (the "
+             "most: a chunk ends when its last live row does)",
     )
     ap.add_argument(
         "--continuous-max-seq", type=int, default=None, metavar="N",

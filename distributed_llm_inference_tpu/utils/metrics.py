@@ -66,6 +66,13 @@ SLOT_RELEASE_HELP = (
     "(a fetched launch showed the row ended or it was killed; a reaped "
     "prefill; a preemption)"
 )
+# a decode chunk ends when its last live row does (engine/paged.
+# steps_while_active): how often that engages
+CHUNK_STEPS_HELP = (
+    "steps of dispatched decode chunks by what the device did with them: "
+    "run = forwards that ran, cut = those the chunk's exit saved (no row "
+    "of the fleet was active any more); run + cut = chunks x chunk_steps"
+)
 # the paged kernels' loop steps (engine/continuous._kv_walk_steps)
 ATTN_WALK_STEPS_HELP = (
     "loop steps of the paged kernels' walk per layer (host position "

@@ -274,10 +274,16 @@ def test_a_stream_delivers_a_block_when_it_comes_clean():
 def test_sampled_rows_never_emit_the_mask_id():
     f = fleet(impl="xla", steps=2)
     ids = prompt_ids(20, salt=5)
+    # the engine's key starts from the clock; at this temperature one draw in
+    # 255 is the stop token, and a row that ends there ends where only the
+    # fetch can see it (3 keys of 80 leave the fleet's check a disagreement,
+    # on the parent of PR 46 too). A pinned key draws all 24 tokens.
+    f.ce._key = jax.random.PRNGKey(0)
     r = f.ce.submit(words(ids), max_tokens=24, temperature=5.0, top_k=0,
                     top_p=1.0, chat=False)
     out = WordTok().encode(r["response"])
     assert r["status"] == "success" and f.cfg.mask_token_id not in out
+    assert r["tokens_generated"] == 24
 
 
 def test_a_preempted_row_resumes_on_committed_blocks_only():

@@ -245,33 +245,80 @@ def test_tinyllama_prefill_step_compiles_with_kernel(
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_tinyllama_paged_decode_step_compiles_with_kernel(
-    one_chip, no_persistent_cache, tinyllama, monkeypatch
-):
-    """One whole decode step (T 1 per slot) over the block pool — the fleet's
-    decode program, whose attention is the paged kernel walking the table."""
-    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+def _tinyllama_fleet(tinyllama, one_chip):
+    """(cfg, params, state, sparams, pool, table, key) of chip_smoke.py's
+    paged fleet, as shapes placed on the described chip."""
     cfg, params = tinyllama
-    S = _spec(one_chip)
     place = functools.partial(_placed, sharding=one_chip)
     state, sparams = place(
         jax.eval_shape(lambda: G.init_slots(SLOTS, cfg.vocab_size))
     )
     pool = place(jax.eval_shape(lambda: EP.init_pool(cfg, POOL_BLOCKS, 16)))
+    table = _spec(one_chip)((SLOTS, 2048 // 16), jnp.int32)
+    key = place(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    return cfg, params, state, sparams, pool, table, key
+
+
+# Head dim 64 is not whole 128-lane tiles, so the kernel reads a padded copy
+# of a layer's slice of each pool leaf (ops/paged_attention.writes_in_place)
+PADDED_SLICE = POOL_BLOCKS * KV * 16 * 128 * 2
+
+
+def test_tinyllama_paged_decode_chunk_compiles_with_kernel(
+    one_chip, no_persistent_cache, tinyllama, monkeypatch
+):
+    """The fleet's decode program as it is served: `decode_slots_paged` at
+    `--continuous-chunk`'s default 16 steps over the block pool, whose
+    attention is the paged kernel walking the table."""
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    cfg, params, state, sparams, pool, table, key = _tinyllama_fleet(
+        tinyllama, one_chip
+    )
     compiled = EP.decode_slots_paged.lower(
-        cfg, params, state, pool, S((SLOTS, 2048 // 16), jnp.int32),
-        place(jax.eval_shape(lambda: jax.random.PRNGKey(0))), sparams,
-        num_steps=1,
+        cfg, params, state, pool, table, key, sparams, num_steps=16,
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "jit_decode_slots_paged" in text
+    # Around a chunk loop (the scan before PR 46, `steps_while_active`
+    # since: 2.317 GB either way) the compiler carries this head dim's pool
+    # in the kernel's padded layout: ONE relayout of both leaves at the
+    # chunk's entry and exit, 22 layers x 2 leaves x 50 MB = 2.2 GB, in
+    # place of a padded copy of a layer's slice at each of 16 x 22 layer
+    # steps. Held here: that copy and one step's temporaries, never a
+    # second one (a carry that is not written in place).
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps < (2 * cfg.n_layers + 2) * PADDED_SLICE + 2**28, temps
+
+
+def test_tinyllama_paged_decode_step_compiles_with_kernel(
+    one_chip, no_persistent_cache, tinyllama, monkeypatch
+):
+    """One whole decode step (T 1 per slot), the body of the chunk's loop,
+    compiled as a program of its own: what `decode_slots_paged(num_steps=1)`
+    was while a chunk was a scan, which the compiler unrolled at length one.
+    A loop whose trip count the device decides stays a loop at a bound of
+    one, and its pool carry takes the chunk's relayout (the test above)."""
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    cfg, params, state, sparams, pool, table, key = _tinyllama_fleet(
+        tinyllama, one_chip
+    )
+
+    def one_step(params, state, pool, table, key, sparams):
+        logits, pool = EP._forward_step_paged(
+            cfg, params, state.token[:, None], pool, table, state.pos,
+            active=state.active,
+        )
+        return G.slot_step(cfg, state, sparams, logits, key), pool
+
+    compiled = jax.jit(one_step, donate_argnums=(2,)).lower(
+        params, state, pool, table, key, sparams,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    # Head dim 64 is not whole 128-lane tiles, so the kernel reads a padded
-    # copy of ONE layer's slice (ops/paged_attention.writes_in_place), as it
-    # did before the pool became a carry (temporaries 1.41 GB then, with the
-    # scan's second pool; 0.10 GB now): never a padded copy of the stacked
-    # pool, which would be 2.2 GB (22 layers x 2 leaves x 50 MB).
-    padded_slice = POOL_BLOCKS * KV * 16 * 128 * 2
+    # the padded copy is of ONE layer's slice, as it was before the pool
+    # became a carry (temporaries 1.41 GB then, with the scan's second pool;
+    # 0.10 GB now): never a padded copy of the stacked pool inside a step
     temps = compiled.memory_analysis().temp_size_in_bytes
-    assert temps < 2 * padded_slice + 2**28, temps
+    assert temps < 2 * PADDED_SLICE + 2**28, temps
 
 
 # What a device trace calls the fleet's two step programs and their two

@@ -413,6 +413,28 @@ def test_chunk_launches_and_row_steps_are_counted(runs):
     assert fetches == chunk + want["mixed"] - unfetched
 
 
+def test_a_chunks_steps_are_counted_as_run_or_cut(runs):
+    """ISSUE 46: a chunk is dispatched with CHUNK_STEPS and ends on the device
+    with its last live row. Served one at a time, a request's chunks run its
+    answer - 1 decode steps between them and the exit saves the rest; an
+    answer of one token dispatches a chunk of dead rows that runs nothing.
+    The counter is kept at the fetch: what close() found unfetched (whole
+    chunks of the steps still in flight) is in neither state."""
+    for run in runs:
+        want, snap = _expected(run), run["snap"]
+        chunk = _value(snap, "dli_ragged_launches_total", phase="chunk")
+        unfetched = run["cont"]._steps_inflight // CHUNK_STEPS
+        ran = _value(snap, "dli_decode_chunk_steps_total", state="run")
+        cut = _value(snap, "dli_decode_chunk_steps_total", state="cut")
+        assert ran + cut == (chunk - unfetched) * CHUNK_STEPS
+        assert 0 <= want["row_steps"] - ran <= unfetched * CHUNK_STEPS
+        # (9, 13, 1, 30, 5) tokens: 8 + 12 + 0 + 29 + 4 live steps in 15
+        # chunks of 4
+        assert want["row_steps"] == 53 and chunk * CHUNK_STEPS == 60
+        if not unfetched:
+            assert (ran, cut) == (53, 7)
+
+
 def test_old_series_read_what_the_parent_counted(runs):
     """The series the benchmark's readers name: the numbers the parent
     commit (a38196f) gave for this list on both of two runs, read there by
@@ -593,6 +615,19 @@ def test_profiler_trace_holds_launch_fetch_and_phase_events(setup, tmp_path):
             ln, _, l_end, st = launches[seq]
             assert name == "fetch." + ln.split(".", 1)[1] and start >= l_end
             assert int(st["steps"]) in (1, CHUNK_STEPS)
+    # a chunk's record carries the position model's forecast, and the span
+    # that follows its fetch what the device ran (ISSUE 46): no stop token
+    # here, so the two agree, and steps stays what was dispatched
+    ran = {int(st["seq"]): int(st["steps_run"]) for n, _, _, st in events
+           if n == "phase.distribute" and "steps_run" in st}
+    chunks = {seq: st for seq, (n, _, _, st) in launches.items() if n == "launch.chunk"}
+    assert chunks and all("steps_live" not in st for seq, (n, _, _, st) in launches.items()
+                          if n == "launch.mixed")
+    for seq, st in chunks.items():
+        assert int(st["steps"]) == CHUNK_STEPS and 0 <= int(st["steps_live"]) <= CHUNK_STEPS
+        if seq in ran:
+            assert ran[seq] == int(st["steps_live"])
+    assert set(ran) & set(chunks) and set(ran) <= {seq for seq, *_ in fetches.items()}
     # the worker's intervals are contiguous: each begins where one ended.
     # A hole the clock leaves shows at every iteration (one gap in seven),
     # so nine gaps in ten are held and not the longest: a loaded machine

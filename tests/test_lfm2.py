@@ -218,12 +218,12 @@ def test_the_sixteen_step_decode_chunk_equals_sixteen_single_steps(model):
     rows = [ids_of(14, 1), ids_of(27, 2)]
     key = jax.random.PRNGKey(0)
     pool, table, state, sparams, first = _prefilled(cfg, params, rows)
-    em16, mask16, st16, pool16 = P.decode_slots_paged(
+    em16, mask16, st16, pool16, _ = P.decode_slots_paged(
         cfg, params, state, pool, table, key, sparams, num_steps=16)
     pool, table, state, sparams, _ = _prefilled(cfg, params, rows)
     em1 = []
     for _ in range(16):
-        e, m, state, pool = P.decode_slots_paged(
+        e, m, state, pool, _ = P.decode_slots_paged(
             cfg, params, state, pool, table, key, sparams, num_steps=1)
         em1.append(np.asarray(e[0]) * np.asarray(m[0]))
     assert (np.asarray(em16) * np.asarray(mask16) == np.stack(em1)).all()

@@ -340,6 +340,7 @@ def test_metrics_route_exposition(served):
         "dli_tokens_generated_total",
         "dli_slots_occupied",               # continuous fleet
         "dli_decode_step_seconds",
+        "dli_decode_chunk_steps_total",     # a chunk's steps: run | cut
         "dli_preemptions_total",
         "dli_constraint_states_resident",   # constrain fleet
     }
@@ -443,7 +444,12 @@ def test_bare_engine_exposes_full_catalog_schema():
         "dli_kv_tier_entries", "dli_kv_tier_bytes",
         "dli_kv_tier_promotions_total", "dli_kv_tier_demotions_total",
         "dli_kv_tier_disk_hits_total",
+        # the decode chunk's exit (ISSUE 46): pre-registered like the
+        # other launch-record families
+        "dli_decode_chunk_steps_total",
     } <= fams
+    chunk_steps = engine.metrics.get("dli_decode_chunk_steps_total")
+    assert chunk_steps.type == "counter" and chunk_steps.labelnames == ("state",)
 
 
 def test_queue_metrics_and_member_timings():

@@ -131,7 +131,7 @@ def test_decode_slots_paged_matches_dense(solo_engine):
         pool, scratch2, state2, sparams2, 1, jnp.asarray(row),
         first2[0], plen, jnp.int32(steps + 1), *knobs,
     )
-    em_p, mask_p, state_p, _ = backend.decode_slots_paged(
+    em_p, mask_p, state_p, _, _ = backend.decode_slots_paged(
         state2, pool, jnp.asarray(table), jax.random.PRNGKey(3), sparams2,
         num_steps=steps,
     )
@@ -418,7 +418,7 @@ def test_paged_kernel_token_parity(solo_engine):
             pool, scratch, state, sparams, 1, jnp.asarray(table[1]),
             first[0], plen, jnp.int32(steps + 1), *knobs,
         )
-        em, mask, _, _ = be.decode_slots_paged(
+        em, mask, _, _, _ = be.decode_slots_paged(
             state, pool, jnp.asarray(table), jax.random.PRNGKey(3),
             sparams, num_steps=steps,
         )
@@ -513,7 +513,7 @@ def test_pp_decode_slots_paged_matches_dense(eight_devices):
         pool, scratch2, state2, sparams2, 1, jnp.asarray(row),
         first2[0], plen, jnp.int32(steps + 1), *knobs,
     )
-    em_p, mask_p, _, _ = backend.decode_slots_paged(
+    em_p, mask_p, _, _, _ = backend.decode_slots_paged(
         state2, pool, jnp.asarray(table), jax.random.PRNGKey(3), sparams2,
         num_steps=steps,
     )
