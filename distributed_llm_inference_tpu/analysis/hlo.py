@@ -201,9 +201,9 @@ def _ragged_args(engine, tail: int, width: int = 32):
 
 
 def lower_ragged_prefill(engine=None, tail: int = 20, width: int = 32) -> str:
-    """StableHLO of the REAL ragged paged prefill launch (the program the
-    paged admission path dispatches when engine_cfg.ragged_prefill is on)
-    — declared donation intact, ragged kernel selected."""
+    """StableHLO of the REAL ragged paged prefill launch (the program a
+    paged fleet's whole-prefill admission dispatches) — declared donation
+    intact, ragged kernel selected."""
     from ..engine import paged as EP
 
     engine = engine or tiny_engine()

@@ -540,7 +540,6 @@ class EngineConfig:
     """Decode-engine settings."""
 
     max_seq_len: int = 2048
-    max_batch_size: int = 1
     # Prompt-length buckets for prefill compilation (TTFT: avoids recompiling
     # per prompt length; prompts are right-padded up to the bucket).
     prefill_buckets: tuple = (64, 128, 256, 512, 1024, 2048)
@@ -569,15 +568,10 @@ class EngineConfig:
     # resident set transiently fills. Memory: 2 tables x capacity x vocab
     # (bool + int32).
     constraint_fleet_states: int = 1024
-    # Ragged paged ingest (engine/paged.py ragged programs + the
-    # ops/paged_attention ragged kernel): paged-fleet admission prefills
-    # straight into the pool in fixed-width flat-token launches — no
-    # scratch cache, no insert scatter, no prefill-bucket ladder, and the
-    # block-prefix planner reuses at EXACT chunk depth. False falls back
-    # to the bucketed scratch path (prefill_buckets), which also serves
-    # any backend without the ragged fill programs.
-    ragged_prefill: bool = True
-    # Flat-token launch width of the ragged ingest programs: one compiled
+    # Flat-token launch width of a paged fleet's ragged ingest programs
+    # (engine/paged.py + the ops/paged_attention ragged kernel: admission
+    # prefills straight into the pool, no scratch cache, no prefill-bucket
+    # ladder, prefix reuse at EXACT chunk depth): one compiled
     # (extend, prefill) program pair per width serves every tail length
     # (longer tails loop whole-width launches; the final launch pads with
     # dead tiles the kernel's DMA skips). Rounded up to a multiple of the

@@ -379,18 +379,18 @@ class ContinuousEngine:
         self.slot_max_seq = min(
             int(slot_max_seq or cfg.max_seq_len), cfg.max_seq_len
         )
+        # Block-paged KV (engine/paged.py): fleet memory becomes a function
+        # of the POOL (aggregate in-flight tokens), not n_slots x window —
+        # the round-2 "n_slots x max_seq pinned HBM" review item's stretch
+        # goal. Admission allocates blocks, release frees them, and a
+        # request that can't get blocks waits in the queue (backpressure).
+        # A paged fleet's admissions prefill straight into the pool in
+        # fixed-width flat-token launches through the ragged kernel (one
+        # program for any prompt length); the prefill-bucket ladder is the
+        # dense fleet's.
+        self.paged = kv_pool_blocks is not None
         buckets = engine._buckets()
-        # Ragged paged ingest (engine/paged.py): admission prefills
-        # straight into the pool in fixed-width flat-token launches — the
-        # prefill-bucket ladder (and its scratch gather/scatter) becomes
-        # the cfg-gated fallback. Decided here because the bucket guard
-        # below only applies when the bucketed plan is what admission runs.
-        ragged_planned = bool(
-            kv_pool_blocks is not None
-            and engine.engine_cfg.ragged_prefill
-            and getattr(engine.backend, "supports_ragged_fill", False)
-        )
-        if not ragged_planned and buckets and self.slot_max_seq < buckets[0]:
+        if not self.paged and buckets and self.slot_max_seq < buckets[0]:
             # the bucketed ingest plan needs at least one prefill bucket
             # inside the slot class — a smaller budget would start a
             # healthy-looking server that rejects EVERY request
@@ -399,12 +399,6 @@ class ContinuousEngine:
                 f"smallest prefill bucket {buckets[0]}; raise it or shrink "
                 f"engine_cfg.prefill_buckets"
             )
-        # Block-paged KV (engine/paged.py): fleet memory becomes a function
-        # of the POOL (aggregate in-flight tokens), not n_slots x window —
-        # the round-2 "n_slots x max_seq pinned HBM" review item's stretch
-        # goal. Admission allocates blocks, release frees them, and a
-        # request that can't get blocks waits in the queue (backpressure).
-        self.paged = kv_pool_blocks is not None
         if self.paged:
             if not getattr(engine.backend, "supports_paged", False):
                 raise ValueError(
@@ -419,8 +413,8 @@ class ContinuousEngine:
             self.kv_block_size = int(kv_block_size)
             if self.kv_block_size < 1:
                 raise ValueError("kv_block_size must be >= 1")
-            # logical blocks per slot; scratch rounds up to a whole number
-            # of blocks so the insert scatter is an exact block reshape
+            # logical blocks per slot, and a row's table in tokens (what
+            # the gather path reads a row: _kv_walk)
             self._max_blocks = -(-self.slot_max_seq // self.kv_block_size)
             self._scratch_seq = self._max_blocks * self.kv_block_size
             if int(kv_pool_blocks) - 1 < self._max_blocks:
@@ -467,7 +461,6 @@ class ContinuousEngine:
             # zeroed with it at release. Worker-thread-mutated like
             # _table; every paged launch carries a snapshot of it.
             self._slot_pages = np.zeros((self.n_slots,), np.int32)
-            self._ragged = ragged_planned
             # query-tile granularity of the ragged kernel's flat token
             # axis; the launch width rounds up to a whole number of tiles
             self._ragged_tile = 8
@@ -477,7 +470,6 @@ class ContinuousEngine:
             ) * self._ragged_tile
         else:
             self._wgrp = None
-            self._ragged = False
             self._ragged_tile = 8
             self._scratch_seq = self.slot_max_seq
             self.cache = self.backend.init_cache(
@@ -504,9 +496,7 @@ class ContinuousEngine:
             tenant_weights=engine.engine_cfg.tenant_weights,
         )
         self._chunked = bool(
-            self._ragged
-            and engine.engine_cfg.chunked_prefill
-            and getattr(engine.backend, "supports_mixed_step", False)
+            self.paged and engine.engine_cfg.chunked_prefill
         )
         # chunked-mode host state: pending PrefillJobs (arrival order),
         # slot -> job for slots whose prompt is still landing, and the
@@ -585,7 +575,7 @@ class ContinuousEngine:
                 raise ValueError(
                     f"{cfg.name}: a block-diffusion model is served by the "
                     f"chunked ragged paged scheduler (pass kv_pool_blocks; "
-                    f"keep ragged_prefill and chunked_prefill on)"
+                    f"keep chunked_prefill on)"
                 )
             if (self.kv_block_size % self._blk
                     or self._ragged_tile % (2 * self._blk)):
@@ -664,13 +654,11 @@ class ContinuousEngine:
             max_states=engine.engine_cfg.constraint_fleet_states,
             registry=engine.metrics,
         )
-        # scratch must match the fleet's logical extent: the insert splices
-        # the whole row (dense) / scatters every logical block (paged).
-        # The RAGGED paged path prefills straight into the pool, so it
-        # carries no scratch cache at all — one slot-class of HBM saved
-        # on top of deleting the gather/scatter admission moves.
+        # the dense fleet's scratch matches its logical extent: the insert
+        # splices the whole row. A paged fleet prefills straight into the
+        # pool and carries no scratch cache at all.
         self._scratch = (
-            None if self._ragged
+            None if self.paged
             else self.backend.init_cache(1, self._scratch_seq)
         )
         # guarded-by: _cv
@@ -738,7 +726,7 @@ class ContinuousEngine:
             # the llama family's routed layer) carries neither of these
             self._P.refuse_unsupported_latent(
                 cfg, kv_shadow=use_shadow and self._bpx is not None,
-                bucketed=not self._chunked,
+                unchunked=not self._chunked,
             )
         if (
             self.paged and use_shadow and self._bpx is not None
@@ -823,7 +811,7 @@ class ContinuousEngine:
         # fleet rejects adapter requests at submit with a 400 envelope).
         self._adapters = (
             getattr(engine, "adapters", None)
-            if (self.paged and self._ragged) else None
+            if self.paged else None
         )
         self._tenant_max_share = float(
             engine.engine_cfg.tenant_max_queue_share
@@ -1715,13 +1703,6 @@ class ContinuousEngine:
         on the host first, into an array nothing else ever holds."""
         return jnp.asarray(host_array.copy())
 
-    @property
-    def ragged(self) -> bool:
-        """True when admissions ingest through the ragged paged launch
-        (one program for any prompt length) instead of the engine's
-        prefill-bucket programs."""
-        return self._ragged
-
     def warmup(self) -> dict:
         """Compile the slot programs (scratch prefill for the smallest
         bucket, insert_slot, decode_slots chunk, pack_chunk) by serving one
@@ -1785,10 +1766,8 @@ class ContinuousEngine:
                     self._bpx.stats()["cached_blocks"]
                     if self._bpx is not None else 0
                 ),
-                "ragged_prefill": self._ragged,
+                "ragged_width": self._ragged_width,
             }
-            if self._ragged:
-                out["paged"]["ragged_width"] = self._ragged_width
             if self._wgrp is not None:
                 # (the numbers above are the global group's)
                 out["paged"]["window_group"] = {
@@ -1975,7 +1954,7 @@ class ContinuousEngine:
                 self.n_slots, self.slot_max_seq
             )
         self._scratch = (
-            None if self._ragged
+            None if self.paged
             else self.backend.init_cache(1, self._scratch_seq)
         )
         self.state, self.sparams = G.init_slots(
@@ -4884,13 +4863,13 @@ class ContinuousEngine:
         # prefix lookup + ingest plan: the solo engine's shared planner
         # helper (one copy of the lookup/cold-fallback/mark discipline);
         # the planner is mode-specific — block-chain index (paged) or
-        # dense snapshot cache. ragged=True (paged ragged ingest) plans
-        # the tail as fixed-width launches with NO bucket ladder, so the
+        # dense snapshot cache. ragged=True (a paged fleet) plans the
+        # tail as fixed-width launches with NO bucket ladder, so the
         # deepest cached chain is reused at EXACT chunk depth — the
-        # degradation walk only runs for the bucketed fallback.
+        # degradation walk only runs for the dense fleet's buckets.
         p0, entry, plan = eng._prefix_plan(
             self._bpx if self.paged else self._prefix, ids,
-            capacity=self.slot_max_seq, ragged=self._ragged,
+            capacity=self.slot_max_seq, ragged=self.paged,
             adapter=req.adapter,
         )
         if plan is None:
@@ -4913,14 +4892,13 @@ class ContinuousEngine:
             # restored/mapped head; cold recovery recomputes it all)
             self._m_recovery_recomputed.inc(prompt_len - p0)
             req.recovering = False
-        table_row = insert_row = None
+        table_row = None
         if self.paged:
             faults.check("alloc", tag=req.prompt)
             need_total = self._P.blocks_needed(
                 prompt_len, max_tokens, self.kv_block_size
             )
-            # entry may be deeper than the PLANNED depth (bucket limits
-            # degrade p0 — engine._prefix_plan): map exactly p0 worth
+            # map exactly the planned depth's worth of the entry
             shared = list(entry)[: p0 // self.kv_block_size] if p0 else []
             n_shared = len(shared)
             # need records the FRESH-block shortfall for the head-of-queue
@@ -4948,14 +4926,6 @@ class ContinuousEngine:
             req.block_ids = shared + blk_ids
             table_row = np.zeros((self._max_blocks,), np.int32)
             table_row[: need_total] = req.block_ids  # tail stays at trash
-            # insert scatters the WHOLE scratch row; the shared head must
-            # not be rewritten (other tables read those exact blocks), so
-            # the insert's view of the row redirects head entries to the
-            # write-only trash block — the DECODE table keeps the real row
-            insert_row = table_row
-            if n_shared:
-                insert_row = table_row.copy()
-                insert_row[:n_shared] = self._P.TRASH_BLOCK
         if k.get("constraint") is not None:
             # compiled-artifact reuse by constraint hash (the engine LRU),
             # then residency in the fleet's combined table; a full table
@@ -4982,9 +4952,8 @@ class ContinuousEngine:
             k.get("frequency_penalty", 0.0), k.get("presence_penalty", 0.0),
         )
         key = self._next_key()
-        use_ragged = self.paged and self._ragged
         scratch = None
-        if not use_ragged:
+        if not self.paged:
             scratch = self._scratch
             self._scratch = None
         req.prefix_hit_tokens = p0
@@ -5007,7 +4976,7 @@ class ContinuousEngine:
                 for t in req.salvaged:
                     st = art.advance(st, t)
                 bias = jnp.asarray(art.state_bias(st))
-            if use_ragged:
+            if self.paged:
                 # ragged ingest: the tail prefills STRAIGHT INTO THE POOL
                 # (flat-token launches through the ragged kernel) — no
                 # scratch, no shared-head gather, no insert scatter. A
@@ -5018,21 +4987,6 @@ class ContinuousEngine:
                 first = self._ragged_ingest(
                     ids, p0, table_row, key, sampling, presence, bias,
                     page=req.adapter_page,
-                )
-            elif self.paged:
-                if p0:
-                    # block-level hit: the shared physical blocks are
-                    # already MAPPED into table_row — no splice, no copy
-                    # into the pool. One gather assembles the scratch's
-                    # contiguous view of the shared head so the chunked
-                    # tail prefill below attends real KV; garbage past the
-                    # head is overwritten by the tail or never attended.
-                    scratch = self.backend.fill_scratch_paged(
-                        self.cache, jnp.asarray(table_row)
-                    )
-                first, _, scratch = eng._ingest(
-                    ids, p0, plan, scratch, key, sampling,
-                    presence=presence, bias=bias,
                 )
             else:
                 # shared splice/ingest/store sequence (engine/engine.py) —
@@ -5060,7 +5014,7 @@ class ContinuousEngine:
                 sampling.freq_penalty, sampling.pres_penalty,
                 presence_row,
             )
-            if use_ragged:
+            if self.paged:
                 # the prompt's K/V is ALREADY in the pool blocks: arm the
                 # slot's state only (shared generate.arm_slot semantics)
                 self.state, self.sparams = self.backend.arm_slot_paged(
@@ -5074,21 +5028,11 @@ class ContinuousEngine:
                 # chunked mode reaches here through RECOVERY's serialized
                 # whole-prefill re-admissions: seed the host position
                 # model so subsequent mixed launches plan this row exactly
-            elif self.paged:
-                self.cache, self.state, self.sparams = (
-                    self.backend.insert_slot_paged(
-                        self.cache, scratch, self.state, self.sparams, slot,
-                        jnp.asarray(insert_row), *arm,
-                    )
-                )
-                self._table[slot] = table_row
-                self._table_dev = None  # rebuilt at the next chunk launch
             else:
                 self.cache, self.state, self.sparams = G.insert_slot(
                     cfg, self.cache, scratch, self.state, self.sparams, slot,
                     *arm,
                 )
-            if not use_ragged:
                 self._scratch = scratch
             # seed the host position model (every fleet mode: the launch
             # record reads it; chunked mode reaches here through
@@ -5112,11 +5056,11 @@ class ContinuousEngine:
             self._release_adapter(req)  # and the adapter-page refcount
             raise
         finally:
-            if not use_ragged and self._scratch is None:
+            if not self.paged and self._scratch is None:
                 # a failed extend/prefill may have consumed (donated) the
                 # scratch buffer mid-sequence; a permanently-None scratch
-                # would fail every later admission — reallocate (the
-                # ragged path never holds a scratch at all)
+                # would fail every later admission — reallocate (a paged
+                # fleet never holds a scratch at all)
                 self._scratch = self.backend.init_cache(1, self._scratch_seq)
         if self.paged and self._bpx is not None:
             # index the prompt's full blocks (complete + immutable once

@@ -192,14 +192,6 @@ class SingleDeviceBackend:
 
         return P.init_pool(self.cfg, n_blocks, block_size, n_slots=n_slots)
 
-    def insert_slot_paged(self, pool, scratch, state, sparams, slot,
-                          table_row, *args):
-        from . import paged as P
-
-        return P.insert_slot_paged(
-            self.cfg, pool, scratch, state, sparams, slot, table_row, *args
-        )
-
     def decode_slots_paged(self, state, pool, table, key, sparams, *,
                            num_steps, pages=None, **diffusion):
         from . import paged as P
@@ -208,14 +200,6 @@ class SingleDeviceBackend:
             self.cfg, self.params, state, pool, table, key, sparams,
             num_steps=num_steps, pages=pages, **diffusion,
         )
-
-    def fill_scratch_paged(self, pool, table_row):
-        # block-level prefix sharing: assemble a contiguous scratch view
-        # of a hit's mapped blocks (the pool is read — NOT donated; other
-        # block tables keep reading those exact buffers)
-        from . import paged as P
-
-        return P.gather_scratch_blocks(pool, table_row)
 
     # warm-recovery shadow seam (engine/shadow.py): single-device only
     # for now — the pp backend's layer-sharded pool would need shard_map
@@ -233,12 +217,7 @@ class SingleDeviceBackend:
 
     # ragged ingest (engine/paged.py): admission prefills straight into
     # the pool through the ragged kernel/gather — no scratch, no insert
-    # scatter, no bucket ladder. Gated per engine by
-    # engine_cfg.ragged_prefill; PipelineBackend provides shard_map twins.
-    @property
-    def supports_ragged_fill(self):
-        return self.supports_paged
-
+    # scatter, no bucket ladder. PipelineBackend provides shard_map twins.
     def extend_ragged_paged(self, tokens, tok_row, tok_pos, meta, pool,
                             table, pages=None):
         from . import paged as P
@@ -268,10 +247,6 @@ class SingleDeviceBackend:
     # row plus budget-sliced prefill chunks in ONE ragged program —
     # decode tokens/positions gathered from slot state on device,
     # completing admissions sample + arm in the same pass.
-    @property
-    def supports_mixed_step(self):
-        return self.supports_ragged_fill
-
     def mixed_step_ragged(self, tokens, tok_row, tok_pos, dec_flag, meta,
                           pool, table, state, sparams, key, dec_idx, arm,
                           spec=None, spec_toks=None, dev=None, pages=None,

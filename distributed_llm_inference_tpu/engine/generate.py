@@ -579,8 +579,8 @@ def arm_slot(cfg, state, sparams, slot, first_token, prompt_len, max_tokens,
              freq_penalty, pres_penalty, presence_row):
     """Arm slot row `slot`'s decode state + sampling knobs after its prompt
     K/V landed. ONE copy of the budget / EOS-on-first / presence arming —
-    insert_slot (dense fleet) and engine/paged.insert_slot_paged (block
-    pool) both call this, so the admission semantics can't drift."""
+    insert_slot (dense fleet) and engine/paged.arm_slot_only (block pool)
+    both call this, so the admission semantics can't drift."""
     budget = jnp.where(
         stop_mask(cfg, first_token), jnp.int32(0), jnp.maximum(max_tokens - 1, 0)
     )

@@ -17,13 +17,12 @@ blocks waits in the queue until a running request completes (after the
 block-prefix index has evicted what it can — engine/block_prefix.py).
 
 Block-level prefix sharing rides the refcounts: full prompt blocks are
-immutable once the insert scatter lands, so a prefix hit MAPS the cached
-physical blocks into the new request's table (one more holder each),
-gathers a contiguous scratch view of the shared head
-(gather_scratch_blocks) for the tail prefill, and scatters the scratch
-back with the head entries of the insert's row redirected to the trash
-block. Both decode paths run unchanged over shared tables. See
-ARCHITECTURE.md "Block sharing" for the invariant walk-through.
+immutable once the launch that wrote them lands, so a prefix hit MAPS the
+cached physical blocks into the new request's table (one more holder
+each) and the tail's ragged launches attend them in place: the shared
+head is never copied and never rewritten. Both decode paths run unchanged
+over shared tables. See ARCHITECTURE.md "Block sharing" for the invariant
+walk-through.
 
 TPU/XLA design notes (why this shape, not a translation of vLLM's CUDA
 paged attention):
@@ -57,8 +56,8 @@ Paged mode serves BOTH families: the hook seam is shared
 (models/llama.default_attn_hook; gpt2's block routes through it since
 round 5). It runs on the single device AND on dp=1 pp/tp meshes: the pool
 shards its layer axis over pp / kv heads over tp exactly like the dense
-cache (parallel/partition.pool_spec), the scratch→pool scatter is
-layer-local, and ungated ring microsteps redirect their block writes to
+cache (parallel/partition.pool_spec), and ungated ring microsteps
+redirect their block writes to
 the trash block (parallel/pipeline._build_decode_slots_paged).
 
 Reference contrast: /root/reference has no KV cache at all
@@ -500,7 +499,7 @@ def refuse_unsupported_latent(cfg: ModelConfig, **asked):
     carry: a latent pool (models/mla_moe.py) and the llama family's routed
     layer (cfg.moe_ffn_dim > 0: SDAR). Each caller passes what it knows
     (runtime.create_backend: quant, kv_quant, mesh, lora, adapter_slots;
-    the continuous engine: kv_shadow, bucketed); a dense per-head K/V
+    the continuous engine: kv_shadow, unchunked); a dense per-head K/V
     model passes through."""
     if not (cfg.latent_dim or cfg.moe_ffn_dim):
         return
@@ -515,8 +514,9 @@ def refuse_unsupported_latent(cfg: ModelConfig, **asked):
         "kv_shadow": "the host shadow store (and swap preemption, "
                      "/kv export): it copies K/V block pairs; pass "
                      "--no-kv-shadow",
-        "bucketed": "the bucketed scratch prefill: a latent model is "
-                    "served by ragged chunked prefill only",
+        "unchunked": "the unchunked ragged admission: a latent model is "
+                     "served by chunked prefill only (keep "
+                     "chunked_prefill on)",
     }
     lead = "a latent-attention model is served on one device from a " \
            "latent pool"
@@ -528,8 +528,8 @@ def refuse_unsupported_latent(cfg: ModelConfig, **asked):
             "kv_quant": "the int8 pool: the routed counts ride the pool",
             "mesh": "pp / tp / ep / sp / dp meshes: the expert banks ride "
                     "outside the layer scan and are not partitioned",
-            "bucketed": "the bucketed scratch prefill: served by ragged "
-                        "chunked prefill only",
+            "unchunked": "the unchunked ragged admission: served by "
+                         "chunked prefill only (keep chunked_prefill on)",
         })
     if cfg.conv_layers:
         lead = "a model with recurrent layers is served on one device " \
@@ -546,9 +546,8 @@ def refuse_unsupported_latent(cfg: ModelConfig, **asked):
                          "export): it copies K/V block pairs and would "
                          "leave a block's state tail behind; pass "
                          "--no-kv-shadow",
-            "bucketed": "the bucketed scratch prefill and the unchunked "
-                        "ragged admission: a row's state rides the mixed "
-                        "launch's slot rows only",
+            "unchunked": "the unchunked ragged admission: a row's state "
+                         "rides the mixed launch's slot rows only",
             "spec": "speculative decoding: a rejected draft token would "
                     "already be in a convolution layer's state",
             "no_pool": "a dense slot fleet: there is no dense recurrent "
@@ -563,9 +562,8 @@ def refuse_unsupported_latent(cfg: ModelConfig, **asked):
                          "export, the KV fabric): it copies one group's "
                          "block pairs and knows no second table; pass "
                          "--no-kv-shadow",
-            "bucketed": "the bucketed scratch prefill and the unchunked "
-                        "ragged admission: window blocks are given out "
-                        "and taken back launch by launch",
+            "unchunked": "the unchunked ragged admission: window blocks "
+                         "are given out and taken back launch by launch",
             "spec": "speculative decoding: the host's position model "
                     "decides which window blocks a row holds, and has to "
                     "be exact",
@@ -801,41 +799,16 @@ def _group_table(table, g: int, n: int):
     return table[:, g * MB:(g + 1) * MB]
 
 
-def scatter_scratch(pool, scratch, table_row):
-    """Scatter a CONTIGUOUS batch-1 scratch cache into `table_row`'s pool
-    blocks, leaf by leaf (shared by the single-device insert and the pp
-    backend's shard_map insert — the scatter is layer-local, so it runs
-    unchanged on a layer-sharded pool slice)."""
-
-    def scatter(pl, sc):
-        # sc [L, 1, KV, S, Dh] -> [L, MB, KV, bs, Dh] block view; the
-        # int8 pool's scale leaves ride the same recipe one rank down
-        # ([L, 1, KV, S] -> [L, MB, KV, bs])
-        bs = pl.shape[3]
-        if sc.ndim == 5:
-            L, _, KV, S, Dh = sc.shape
-            MB = S // bs
-            blocks = (
-                sc[:, 0].reshape(L, KV, MB, bs, Dh).transpose(0, 2, 1, 3, 4)
-            )
-        else:
-            L, _, KV, S = sc.shape
-            MB = S // bs
-            blocks = sc[:, 0].reshape(L, KV, MB, bs).transpose(0, 2, 1, 3)
-        return pl.at[:, table_row].set(blocks)
-
-    return jax.tree.map(scatter, pool, scratch)
-
-
 def _gather_blocks(shared_pool, table_row):
-    """Core of gather_scratch_blocks (un-jitted so the pp backend's
-    shard_map body can trace it layer-locally — the gather reads whole
-    blocks, so it runs unchanged on a layer-sharded pool slice)."""
+    """`table_row`'s pool blocks as one CONTIGUOUS batch-1 cache, leaf by
+    leaf (the serving path reads the pool through its tables in place;
+    this is the tests' tool for holding what a row's blocks hold against
+    a dense cache)."""
 
     def g(pl):
         # pl [L, N, KV, bs(, Dh)] -> row blocks [L, MB, KV, bs(, Dh)] ->
-        # contiguous batch-1 scratch layout [L, 1, KV, MB*bs(, Dh)]; the
-        # int8 pool's scale leaves ride the same recipe one rank down
+        # contiguous batch-1 layout [L, 1, KV, MB*bs(, Dh)]; the int8
+        # pool's scale leaves ride the same recipe one rank down
         blocks = pl[:, table_row]
         if pl.ndim == 5:
             L, MB, KV, bs, Dh = blocks.shape
@@ -846,26 +819,6 @@ def _gather_blocks(shared_pool, table_row):
         return flat[:, None]
 
     return jax.tree.map(g, shared_pool)
-
-
-@jax.jit
-def gather_scratch_blocks(shared_pool, table_row):
-    """Assemble a CONTIGUOUS batch-1 scratch cache from `table_row`'s pool
-    blocks — the exact inverse of scatter_scratch. Block-level prefix
-    sharing uses it on a hit: the request's table maps the shared physical
-    blocks directly (no splice, no copy into the pool), and this one
-    gather hands the tail prefill a contiguous view of the shared head so
-    the chunked-prefill machinery runs unchanged. Entries past the shared
-    head (fresh private blocks, trash tails) gather stale garbage that
-    the tail prefill/scatter overwrite or the slot mask discards — same
-    stale-region argument as insert_slot_paged's whole-row scatter.
-
-    shared_pool is a READ-ONLY view of live mapped blocks and must NOT be
-    donated: other requests' block tables keep reading these exact
-    buffers (analysis/rules/donation.py enforces the inverse of its usual
-    donate-your-cache rule for this parameter name).
-    """
-    return _gather_blocks(shared_pool, table_row)
 
 
 def _gather_shadow(shared_pool, block_ids):
@@ -903,7 +856,8 @@ def gather_shadow_blocks(shared_pool, block_ids):
 
     shared_pool is a READ-ONLY view of live mapped blocks and must NOT
     be donated: live block tables keep reading these exact buffers
-    (same inverse-donation rule as gather_scratch_blocks). block_ids is
+    (analysis/rules/donation.py enforces the inverse of its usual
+    donate-your-cache rule for this parameter name). block_ids is
     a fixed-width operand (callers pad by repeating a real id) so one
     compiled program serves every capture batch.
     """
@@ -1246,61 +1200,13 @@ def decode_slots_paged(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",), donate_argnames=("pool",))
-def insert_slot_paged(
-    cfg: ModelConfig,
-    pool,
-    scratch,
-    state: G.SlotState,
-    sparams: G.SlotParams,
-    slot,
-    table_row: jnp.ndarray,
-    first_token,
-    prompt_len,
-    max_tokens,
-    temperature,
-    top_k,
-    top_p,
-    greedy,
-    min_p,
-    rep_penalty,
-    freq_penalty,
-    pres_penalty,
-    presence_row,
-):
-    """Scatter a freshly prefilled CONTIGUOUS scratch cache (batch=1,
-    max_seq = max_blocks*bs) into the slot's pool blocks and arm its state
-    (generate.arm_slot — shared with the dense fleet).
-
-    table_row: [max_blocks] int32 — the slot's physical blocks; tail
-    entries past the allocation point at the trash block, whose colliding
-    writes are write-only garbage (positions there are beyond every
-    owner's budget). One compiled program per prompt bucket is avoided the
-    same way insert_slot does it: the WHOLE scratch row is scattered, and
-    stale high blocks are never attended. On a block-sharing hit the
-    caller passes a row whose SHARED HEAD entries are redirected to the
-    trash block too (the decode table keeps the real ids): the mapped
-    blocks already hold exactly this content and must not be rewritten
-    while other tables read them.
-    """
-    slot = jnp.int32(slot)
-    pool = scatter_scratch(pool, scratch, table_row)
-    state, sparams = G.arm_slot(
-        cfg, state, sparams, slot, first_token, prompt_len, max_tokens,
-        temperature, top_k, top_p, greedy, min_p, rep_penalty,
-        freq_penalty, pres_penalty, presence_row,
-    )
-    return pool, state, sparams
-
-
 # -- ragged ingest: prefill straight into the pool, no bucket ladder ----------
 #
-# The bucketed admission path above prefills a request on a CONTIGUOUS
-# batch-1 scratch cache (chunked through the prefill-bucket ladder), then
-# scatters the whole scratch row into the slot's pool blocks — and on a
-# block-prefix hit first GATHERS the mapped shared head back out of the
-# pool so the tail chunks can attend it. The ragged path deletes all
-# three moves: the prompt tail is laid out on a FLAT token axis (each
+# The dense fleet prefills a request on a CONTIGUOUS batch-1 scratch cache
+# (chunked through the prefill-bucket ladder) and splices the scratch row
+# into its slot. A paged fleet has no scratch, no scatter into the pool
+# and no gather of a shared head back out of it: the prompt tail is laid
+# out on a FLAT token axis (each
 # token is a batch row of one — forward_layers' slots mode, so RoPE and
 # the learned-position families take per-token positions for free), each
 # token's K/V scatters directly into its row's pool block, and attention
@@ -1645,8 +1551,9 @@ def arm_slot_only(cfg: ModelConfig, state: G.SlotState,
                   sparams: G.SlotParams, slot, *arm):
     """Arm a slot with NO cache movement — the ragged ingest already wrote
     the prompt's K/V into the pool blocks, so admission needs only the
-    state-side half of insert_slot_paged (same shared generate.arm_slot,
-    so the budget / EOS-on-first semantics cannot drift)."""
+    state-side half of the dense fleet's insert (the same shared
+    generate.arm_slot, so the budget / EOS-on-first semantics cannot
+    drift)."""
     state, sparams = G.arm_slot(cfg, state, sparams, jnp.int32(slot), *arm)
     return state, sparams
 

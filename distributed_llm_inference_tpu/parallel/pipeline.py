@@ -701,51 +701,6 @@ class PipelineBackend(SPMDBackendBase):
 
         return init_sharded_pool(self.cfg, self.mesh, n_blocks, block_size)
 
-    def insert_slot_paged(self, pool, scratch, state, sparams, slot,
-                          table_row, *args):
-        fn = self._programs.get("insert_paged")
-        if fn is None:
-            fn = self._build_insert_paged()
-            self._programs["insert_paged"] = fn
-        return fn(pool, scratch, state, sparams, jnp.int32(slot), table_row,
-                  *args)
-
-    def _build_insert_paged(self):
-        """shard_map twin of engine/paged.insert_slot_paged: the scratch →
-        pool block scatter is LAYER-LOCAL (each stage scatters its own
-        layer shard of the prefilled scratch into its pool slice), and
-        arm_slot runs replicated so every device derives identical slot
-        state."""
-        cfg = self.cfg
-        from ..engine import generate as G
-        from ..engine import paged as EP
-        from .partition import pool_spec
-
-        def body(pool, scratch, state, sparams, slot, table_row,
-                 first_token, prompt_len, max_tokens, temperature, top_k,
-                 top_p, greedy, min_p, rep_penalty, freq_penalty,
-                 pres_penalty, presence_row):
-            pool = EP.scatter_scratch(pool, scratch, table_row)
-            state, sparams = G.arm_slot(
-                cfg, state, sparams, slot, first_token, prompt_len,
-                max_tokens, temperature, top_k, top_p, greedy, min_p,
-                rep_penalty, freq_penalty, pres_penalty, presence_row,
-            )
-            return pool, state, sparams
-
-        from ..engine.generate import SlotParams, SlotState
-
-        state_specs = _replicated_specs(SlotState)
-        sparam_specs = _replicated_specs(SlotParams)
-        shmapped = self._shard(
-            body,
-            in_specs=(
-                pool_spec(cfg), cache_spec(cfg), state_specs, sparam_specs,
-            ) + (P(),) * 14,
-            out_specs=(pool_spec(cfg), state_specs, sparam_specs),
-        )
-        return jax.jit(shmapped, donate_argnums=(0,))
-
     def decode_slots_paged(self, state, pool, table, key, sparams, *,
                            num_steps, pages=None):
         mkey = ("slots_paged", num_steps, pages is not None)
@@ -760,33 +715,6 @@ class PipelineBackend(SPMDBackendBase):
         # the mesh twin keeps its scan (an exit every stage of the ring has
         # to agree on: ROADMAP C), so it ran every step it was given
         return (*fn(*args), num_steps)
-
-    def fill_scratch_paged(self, pool, table_row):
-        fn = self._programs.get("fill_paged")
-        if fn is None:
-            fn = self._build_fill_paged()
-            self._programs["fill_paged"] = fn
-        return fn(pool, table_row)
-
-    def _build_fill_paged(self):
-        """shard_map twin of engine/paged.gather_scratch_blocks: the pool →
-        scratch block gather is LAYER-LOCAL (each stage reads its own
-        layer shard of the pool into its slice of the contiguous scratch),
-        so block-level prefix sharing serves the pp fleet unchanged. The
-        pool is mapped shared state — read, never donated."""
-        cfg = self.cfg
-        from ..engine import paged as EP
-        from .partition import pool_spec
-
-        def body(shared_pool, table_row):
-            return EP._gather_blocks(shared_pool, table_row)
-
-        shmapped = self._shard(
-            body,
-            in_specs=(pool_spec(cfg), P()),
-            out_specs=cache_spec(cfg),
-        )
-        return jax.jit(shmapped)
 
     # -- warm-recovery shadow gather/scatter on the pp ring ------------------
     # shard_map twins of engine/paged.gather_shadow_blocks /
@@ -843,16 +771,12 @@ class PipelineBackend(SPMDBackendBase):
         return jax.jit(shmapped, donate_argnums=(0,))
 
     # -- ragged paged ingest on the pp ring (engine/paged.py twins) ----------
-    @property
-    def supports_ragged_fill(self) -> bool:
-        """Ragged pool prefill on the pipeline mesh: same dp == 1 / family
-        constraints as the rest of the paged fleet. The flat token axis is
-        fleet-shaped (W rows of T=1 at per-token positions), so it rides
-        the same gated microstep ring as paged slot decode — ungated
-        microsteps redirect their block writes to the trash block through
-        the ragged hook's update_gate, exactly like the decode hook."""
-        return self.supports_paged
-
+    # Same dp == 1 / family constraints as the rest of the paged fleet
+    # (`supports_paged`). The flat token axis is fleet-shaped (W rows of
+    # T=1 at per-token positions), so it rides the same gated microstep
+    # ring as paged slot decode — ungated microsteps redirect their block
+    # writes to the trash block through the ragged hook's update_gate,
+    # exactly like the decode hook.
     def extend_ragged_paged(self, tokens, tok_row, tok_pos, meta, pool,
                             table, pages=None):
         mkey = ("extend_ragged_paged", pages is not None)
@@ -1044,13 +968,8 @@ class PipelineBackend(SPMDBackendBase):
         )
 
     # -- mixed scheduler step on the pp ring (engine/scheduler.py) -----------
-    @property
-    def supports_mixed_step(self) -> bool:
-        """The chunked-prefill scheduler's mixed launch (decode rows +
-        prefill chunks in one program): same dp == 1 / family constraints
-        as the rest of the ragged paged fleet."""
-        return self.supports_ragged_fill
-
+    # The chunked-prefill scheduler's mixed launch: decode rows + prefill
+    # chunks in one program.
     def mixed_step_ragged(self, tokens, tok_row, tok_pos, dec_flag, meta,
                           pool, table, state, sparams, key, dec_idx, arm,
                           spec=None, spec_toks=None, dev=None, pages=None):

@@ -1968,9 +1968,9 @@ def main(argv: Optional[list] = None):
             restore_dir=args.restore_dir,
         )
         if args.warmup:
-            if not continuous.ragged:
-                # dense and bucketed fleets ingest through the engine's
-                # own bucket programs. A ragged paged fleet runs ONE mixed
+            if not continuous.paged:
+                # a dense fleet ingests through the engine's own bucket
+                # programs. A paged fleet runs ONE mixed
                 # program whatever the prompt length: the ~100-program
                 # solo/batched ladder (tens of seconds each to compile at
                 # real widths on the chip) is not its serving path, and

@@ -1,0 +1,282 @@
+"""The rules a benchmark configuration's two step programs keep, compiled
+at the cell's sizes and depth for a described v5e (tests/described_chip.py
+has the rules of such compiles): every rule is one test over every
+configuration of `dense_equal.CELL_FILES`, and a configuration a rule does
+not apply to is named beside the rule with the reason, which the test
+asserts in the rule's place.
+
+A test file of the benchmark's configurations is three lines: it imports
+this module's names (`from cell_program_checks import *`: the tests, the
+fixtures and `pytest_generate_tests`, which gives the file the
+configurations `CELL_FILES` lists under its name). A configuration is
+compiled once in the whole suite: by `dense_equal.cell_programs`, memoised
+in the worker that runs its file. A `model_config` PR adds its
+configuration's name to `CELL_FILES` (and, past two a file, one such file).
+"""
+
+import re
+
+import jax
+import pytest  # noqa: F401 - the fixtures below are pytest's
+
+from dense_equal import CELL_FILES, cell_programs, cell_serving
+from described_chip import (  # noqa: F401 - fixtures, collected by the importer
+    ATTENTION_KERNELS, DENSE_SCOPES, EXPERT_KERNELS, ROUTED_SCOPES,
+    STEP_MODULES, assert_scopes, custom_call_names, module_name,
+    no_persistent_cache, one_chip, topo,
+)
+
+
+def pytest_generate_tests(metafunc):
+    if "config" in metafunc.fixturenames:
+        metafunc.parametrize(
+            "config", CELL_FILES[metafunc.module.__name__.rsplit(".", 1)[-1]])
+
+
+# -- what a cell is ------------------------------------------------------------
+#
+# Asserted on the builder's result, which read the sizes from
+# cellbench/configs/<name>.json: the mixed launch's width
+# (engine/scheduler.step_width: 128 / 136 for the dense two, 512 where the
+# experts route), the pool's blocks (a grouped pool: the window group a
+# quarter of the global one's, its row budget 37), one pool leaf's shape, the
+# labels of the family (utils/tracing.STEP_SCOPES) and the least the pool
+# holds (2 GB and more; sdar-batch's 32 rows of 2,048 tokens: 0.94 GB).
+CELLS = {
+    "kanana-2-30b-a3b-7l": dict(
+        width=512, blocks=1750, leaf=("moe", (6, 1750, 1, 128, 640)),
+        scopes=DENSE_SCOPES + ROUTED_SCOPES + ("moe_shared", "mla_absorb")),
+    # (K/V belongs to 2 of the 9 layers and stores pairs of 64-number heads
+    # side by side: whole 128-lane tiles)
+    "lfm2-24b-a2b-9l": dict(
+        width=512, blocks=3500, leaf=("k", (2, 3500, 4, 128, 128)),
+        scopes=DENSE_SCOPES + ROUTED_SCOPES + ("conv_mix",)),
+    "mistral-7b-16l": dict(
+        width=136, blocks=271, leaf=("k", (16, 271, 8, 128, 128)),
+        scopes=DENSE_SCOPES),
+    "olmo2-7b-16l": dict(
+        width=128, blocks=61, leaf=("k", (16, 61, 32, 128, 128)),
+        scopes=DENSE_SCOPES),
+    # (every layer routes: the family's programs hold no dense `ffn`)
+    "sdar-30b-a3b-7l": dict(
+        width=512, blocks=512, leaf=("routed", (2, 7, 128)), pool_bytes=0.9e9,
+        scopes=("embed", "attn", "head", "sample") + ROUTED_SCOPES),
+    "trinity-large-ep8-5l": dict(
+        width=512, blocks=(4608, 1152), leaf=("kw", (4, 1152, 8, 128, 128)),
+        scopes=DENSE_SCOPES + ROUTED_SCOPES + ("moe_shared",)),
+}
+
+
+def _pool_bytes(pool):
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
+
+
+def test_the_programs_are_the_cells(one_chip, no_persistent_cache, config):
+    from distributed_llm_inference_tpu.engine import paged as EP
+
+    built, cell = cell_programs(config), CELLS[config]
+    cfg = built.cfg
+    assert set(built.compiled) == STEP_MODULES
+    assert (built.width, built.blocks) == (cell["width"], cell["blocks"])
+    name, shape = cell["leaf"]
+    assert built.pool[name].shape == shape
+    if config.startswith("lfm2"):
+        assert (len(cfg.conv_layers), len(cfg.attn_layers), cfg.kv_pack) == (7, 2, 2)
+    if config.startswith("trinity"):
+        assert EP.window_row_budget(cfg.attn_window, built.width, 128) == 37
+    if cfg.diffusion_block:
+        # the decode chunk's flat axis: 32 slots x 2 blocks of 4 = 256 tokens
+        assert "bf16[256,2048]" in built.texts["decode_slots_paged"]
+
+
+# -- the pool is a loop carry that the kernels write in place (ISSUE 29) --------
+#
+# Before ISSUE 29 each step program held a second pool as a temporary (2.56 /
+# 2.97 GB for olmo2's 2.05 GB pool, 2.70 / 3.51 for mistral's 2.27, 2.549 /
+# 2.302 for kanana's 2.007) and moved the pool about five times a step; what
+# is left is weights relaid out once a launch.
+
+# instructions that make no buffer of their own, or are the kernels
+_NO_BUFFER = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+              "custom-call"}
+
+
+def _pool_sized_instructions(hlo_text, pool):
+    """Instructions of a compiled module whose result has the shape of a
+    pool leaf or of one layer's slice of it (`copy`, `dynamic-slice`,
+    `dynamic-update-slice`, `scatter`, bare or as a fusion's root)."""
+    shapes = set()
+    for leaf in jax.tree.leaves(pool):
+        if leaf.ndim == 5:
+            dims = [str(d) for d in leaf.shape]
+            shapes |= {",".join(dims), ",".join(dims[1:]),
+                       ",".join(["1"] + dims[1:])}
+    found = re.findall(
+        r"%([\w.\-]+) = \w+\[([\d,]+)\]\{[^}]*\} ([\w\-]+)\(", hlo_text)
+    return sorted(f"{op} {name} [{shape}]" for name, shape, op in found
+                  if shape in shapes and op not in _NO_BUFFER)
+
+
+def _temporaries_bound(config, built):
+    """The most a step program's temporaries may hold: nothing of the pool's
+    size. lfm2's pool is K/V, the slots' state and the blocks' tails: all
+    its temporaries together are smaller than the smallest thing a copy
+    could be of: the tails (0.2 GB), a layer's slice of K or V (0.46 GB),
+    an expert bank (1.6 GB); the 512-wide mixed step's are 0.11 GB. sdar's
+    pool is under a gigabyte and its vocabulary 151,936 wide: the head's
+    relayout, once a launch, is 0.62 GB of its 0.86 GB alone (the 32 rows'
+    sampling the rest), so its bound is that beside the others' share of
+    the pool."""
+    if config.startswith("lfm2"):
+        return 0.6 * built.pool["tail"].size * 2
+    bound = 0.45 * _pool_bytes(built.pool)
+    if config.startswith("sdar"):
+        bound += built.cfg.dim * built.cfg.vocab_size * 2
+    return bound
+
+
+def test_step_programs_hold_no_copy_of_the_pool_at_cell_sizes(
+    one_chip, no_persistent_cache, config
+):
+    built = cell_programs(config)
+    pool, pool_bytes = built.pool, _pool_bytes(built.pool)
+    assert pool_bytes > CELLS[config].get("pool_bytes", 2e9)
+    for name, compiled in built.compiled.items():
+        memory = compiled.memory_analysis()
+        print(f"{config} {name}: arguments {memory.argument_size_in_bytes / 1e9:.3f} GB, "
+              f"temporaries {memory.temp_size_in_bytes / 1e6:.1f} MB, aliased "
+              f"{memory.alias_size_in_bytes / 1e9:.3f} GB of a {pool_bytes / 1e9:.3f} GB pool")
+        # the pool goes in and comes out as one buffer ...
+        assert memory.alias_size_in_bytes >= pool_bytes - 2**20, (name, memory)
+        # ... and the temporaries hold nothing of its size
+        assert memory.temp_size_in_bytes < _temporaries_bound(config, built), (
+            name, memory)
+        assert _pool_sized_instructions(built.texts[name], pool) == [], name
+        if config.startswith("trinity"):
+            # the sliced head: 25,024 columns are 195.5 lane tiles of 128
+            print("head product shapes:", sorted(set(re.findall(
+                r"(?:f32|bf16)\[\d+,250(?:24|88)\]", built.texts[name]))))
+
+
+# -- the expert banks ride outside the layer scan (ISSUE 32, 34) ----------------
+
+def _bank_shapes(params):
+    """The shapes a copy of a routed expert bank would have: the stacked
+    leaf [layers, experts, in, out], or layers x experts flattened."""
+    shapes = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params["layers"])[0]:
+        if getattr(path[-1], "key", None) in ("w_gate", "w_up", "w_down") \
+                and leaf.ndim == 4:
+            layers, experts, a, b = leaf.shape
+            shapes |= {f"{layers},{experts},{a},{b}", f"{layers * experts},{a},{b}"}
+    return shapes
+
+
+def test_step_programs_copy_no_expert_bank(one_chip, no_persistent_cache, config):
+    """No operation of a bank's size beside the kernel that reads it."""
+    built = cell_programs(config)
+    shapes = _bank_shapes(built.params)
+    if config in ("mistral-7b-16l", "olmo2-7b-16l"):  # dense: no bank
+        assert not built.cfg.n_experts and not shapes
+        return
+    assert shapes
+    for name, text in built.texts.items():
+        for shape in shapes:
+            assert not re.search(rf"copy\(.*bf16\[{shape}\]", text), (name, shape)
+
+
+# -- q / k / v and `wo` are read in place (ISSUE 39) ----------------------------
+
+def _projection_sized_instructions(hlo_text, layers):
+    """Instructions of a compiled module that write out an attention
+    projection's weights (ISSUE 39): a result with the shape of the stacked
+    leaf `wq` / `wk` / `wv` / `wo` of `layers` (the parameters' tree; the
+    latent family's two stacks each) or of one layer's slice of it, made by
+    anything but a fusion that holds the dot itself. Where a dot reads its
+    layer in place, the scan's `dynamic-slice` sits INSIDE the dot's fused
+    computation and no such instruction exists; a `copy` of a stack (its
+    relayout, once a launch) or a loop fusion around the slice (one layer's
+    weights copied out a layer-step) is what this lists. Not listed: the
+    compiler's own asynchronous prefetches (`copy-start` / `slice-start`
+    and their `-done`: the same layout into another memory space,
+    overlapped), and `w_kvb` / `w_kva` of the latent family, whose copies
+    have other causes (PERF.md section 7)."""
+    shapes = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(layers)[0]:
+        if getattr(path[-1], "key", None) in ("wq", "wk", "wv", "wo") \
+                and leaf.ndim == 3:
+            dims = [str(d) for d in leaf.shape]
+            shapes |= {",".join(dims), ",".join(["1"] + dims[1:])}
+    assert shapes
+    blocks = re.findall(r"^(?:ENTRY )?%([\w.\-]+) \([^\n]*\{\n(.*?)^\}",
+                        hlo_text, re.M | re.S)
+    fused = {name for name, _ in blocks if "fused_computation" in name}
+    with_dot = {name for name, body in blocks
+                if re.search(r" (dot|convolution)\(", body)}
+    assert fused and fused & with_dot
+    found = []
+    for name, body in blocks:
+        if name in fused:
+            continue  # (a fusion's inner instructions make no buffer)
+        for inst, shape, op, rest in re.findall(
+                r"%([\w.\-]+) = \w+\[([\d,]+)\]\{[^}]*\} ([\w\-]+)\(([^\n]*)",
+                body):
+            if shape not in shapes or op in _NO_BUFFER \
+                    or op.endswith(("-start", "-done")):
+                continue
+            calls = re.search(r"calls=%([\w.\-]+)", rest)
+            if op == "fusion" and calls and calls.group(1) in with_dot:
+                continue
+            found.append(f"{op} {inst} [{shape}]")
+    return sorted(found)
+
+
+def test_step_programs_read_the_attention_projections_in_place(
+    one_chip, no_persistent_cache, config
+):
+    """ISSUE 39: in both step programs, at the cells' sizes and depth (every
+    routed or dense stack but kanana's one leading layer longer than one),
+    q / k / v (kanana: the query projection) and `wo` are read by their dots
+    from the stacked parameter: no slice copy a layer-step, no relayout of a
+    stack a launch (`models/llama.pin_products` says what made them).
+    The rule is the scanned families'. lfm2's layers are unrolled (two
+    kinds) and its attention stack is 2 layers: no slice is copied a
+    layer-step there either, but the decode chunk relays the stacks of q,
+    k and v out once a launch (25 MB in all)."""
+    built = cell_programs(config)
+    found = {name: _projection_sized_instructions(text, built.params["layers"])
+             for name, text in built.texts.items()}
+    if config == "lfm2-24b-a2b-9l":
+        assert found["mixed_step_ragged"] == []
+        assert all(inst.startswith("copy ") and "[2," in inst
+                   for inst in found["decode_slots_paged"]), found
+        return
+    assert found == {"decode_slots_paged": [], "mixed_step_ragged": []}
+
+
+# -- the names a trace is read by ----------------------------------------------
+
+def test_step_programs_carry_the_names_a_trace_is_read_by(
+    one_chip, no_persistent_cache, config
+):
+    """Both step programs keep their module names, read the pool through
+    the kernel the configuration's file names for them (a block-diffusion
+    row is a query tile in both programs: the ragged kernel) and, where the
+    experts route, run the grouped kernel, under the family's scopes."""
+    built, trace = cell_programs(config), cell_serving(config)["trace"]
+    assert set(trace["step_modules"]) == STEP_MODULES
+    assert set(trace["attention_kernels"]) == ATTENTION_KERNELS
+    experts = trace.get("expert_kernels", [])
+    assert set(experts) == (EXPERT_KERNELS if built.cfg.n_experts else set())
+    for module, text in built.texts.items():
+        kernel = "ragged_paged_attend" if (
+            module == "mixed_step_ragged" or built.cfg.diffusion_block
+        ) else "paged_flash_attend"
+        assert module in module_name(text)
+        calls = custom_call_names(text)
+        for name in (kernel, *experts):
+            assert any(name in c for c in calls), (module, name, sorted(calls))
+        stacks = " ".join(set(re.findall(r'op_name="([^"]*)"', text)))
+        for scope in set(CELLS[config]["scopes"]) - set(DENSE_SCOPES):
+            assert scope in stacks, (module, scope)
+        assert_scopes(text, module, CELLS[config]["scopes"])

@@ -25,13 +25,16 @@ follows the shape of the surrounding graph, not the program's arithmetic
     python tests/dense_equal.py <checkout root> <out.npz>      # once a side
     python tests/dense_equal.py --compare <a.npz> <b.npz>      # exit 1 if unequal
 
-tests/test_mla_moe.py runs `dump` twice on this tree and `unequal` on the
-two, so that a difference between two checkouts is the programs' and not
-the script's.
+tests/test_carried_pool.py runs `dump` twice on this tree and `unequal`
+on the two, so that a difference between two checkouts is the programs'
+and not the script's.
 
 And the device programs themselves, with no chip attached: `programs`
-compiles a dense model's two step programs (cut to 2 layers) for a
-described v5e at a cell's sizes, and `canon` takes the source positions out
+compiles a model's two step programs (from the command line: cut to 2
+layers) for a described v5e at a cell's sizes, the ONE place the tests
+lower them (`cell_programs`: a benchmark configuration's, once a process;
+tests/cell_program_checks.py holds every configuration's to the rules the
+chip needs), and `canon` takes the source positions out
 of the optimized HLO (the tables of files and stack frames, each
 instruction's metadata, the debug locations inside each Mosaic kernel's
 serialized module), so that two checkouts whose texts are then the same
@@ -47,10 +50,13 @@ registry entry, overrides and sizes, so the routed, block-diffusion and
 convolution-hybrid configurations' programs compare as the dense two's do.
 """
 import base64
+import dataclasses
+import functools
 import json
 import os
 import re
 import sys
+from unittest import mock
 
 import numpy as np
 
@@ -66,8 +72,8 @@ BS, TILE = 16, 8
 def dump(names=tuple(PRESETS), impls=IMPLS, wrap=lambda hook: hook) -> dict:
     """{"<preset>.<impl>.<tokens | logits | pool_<leaf>>": array}. wrap: what
     the ragged and the decode hook pass through before forward_layers gets
-    them (tests/test_paged.py holds the carried pool against a hook that
-    cuts each layer's slice out and puts it back)."""
+    them (tests/test_carried_pool.py holds the carried pool against a hook
+    that cuts each layer's slice out and puts it back)."""
     import jax
     import jax.numpy as jnp
 
@@ -90,19 +96,39 @@ def dump(names=tuple(PRESETS), impls=IMPLS, wrap=lambda hook: hook) -> dict:
             table = np.zeros((2, 8), np.int32)
             table[0, :6] = [3, 7, 2, 9, 5, 13]
 
+            # one program a kind of launch, as the fleet has: compiled once
+            # for the 4 ragged launches and once for the 12 decode steps (run
+            # eagerly, every launch traced, lowered and compiled the layer
+            # scan anew with the interpreted kernel inside it: its hook is a
+            # fresh closure)
+            @jax.jit
+            def ragged(pool, table, flat, tok_pos, meta, tok_row):
+                x = M.embed(cfg, params, flat[:, None], tok_pos)
+                hook = wrap(P.make_ragged_fill_hook(table, meta, tok_row))
+                x, pool = M.forward_layers(cfg, params["layers"], x, pool,
+                                           tok_pos, attn_hook=hook,
+                                           attn_seq_len=1)
+                return M.unembed(cfg, params, x)[:, 0], pool
+
+            @jax.jit
+            def decode(pool, table, tok, pos):
+                x = M.embed(cfg, params, tok, pos)
+                x, pool = M.forward_layers(
+                    cfg, params["layers"], x, pool, pos,
+                    attn_hook=wrap(P.make_paged_hook(table)),
+                    attn_seq_len=table.shape[1] * BS)
+                return M.unembed(cfg, params, x[:, -1:, :])[:, 0, :], pool
+
             def launch(pool, entries, toks, width=32):
                 meta, tok_row, tok_pos, offs, _ = P.build_ragged_meta(
                     entries, width=width, tile=TILE)
                 flat = np.zeros((width,), np.int32)
                 for (_, _, n, _), off, t in zip(entries, offs, toks):
                     flat[off:off + n] = t
-                x = M.embed(cfg, params, jnp.asarray(flat)[:, None], jnp.asarray(tok_pos))
-                hook = wrap(P.make_ragged_fill_hook(
-                    jnp.array(table), jnp.asarray(meta), jnp.asarray(tok_row)))
-                x, pool = M.forward_layers(cfg, params["layers"], x, pool,
-                                           jnp.asarray(tok_pos), attn_hook=hook,
-                                           attn_seq_len=1)
-                return np.asarray(M.unembed(cfg, params, x)[:, 0]), pool, offs
+                lg, pool = ragged(pool, jnp.array(table), jnp.asarray(flat),
+                                  jnp.asarray(tok_pos), jnp.asarray(meta),
+                                  jnp.asarray(tok_row))
+                return np.asarray(lg), pool, offs
 
             logits = []
             for start, n in ((0, 24), (24, 24), (48, 22)):
@@ -111,13 +137,8 @@ def dump(names=tuple(PRESETS), impls=IMPLS, wrap=lambda hook: hook) -> dict:
                 logits.append(lg[offs[0]:offs[0] + n])
             tok, toks = int(logits[-1][-1].argmax()), []
             for p in range(70, 82):
-                pos = jnp.asarray([p], jnp.int32)
-                x = M.embed(cfg, params, jnp.asarray([[tok]]), pos)
-                x, pool = M.forward_layers(
-                    cfg, params["layers"], x, pool, pos,
-                    attn_hook=wrap(P.make_paged_hook(jnp.array(table[:1]))),
-                    attn_seq_len=table.shape[1] * BS)
-                lg = M.unembed(cfg, params, x[:, -1:, :])[:, 0, :]
+                lg, pool = decode(pool, jnp.array(table[:1]),
+                                  jnp.asarray([[tok]]), jnp.asarray([p], jnp.int32))
                 logits.append(np.asarray(lg))
                 tok = int(np.asarray(lg)[0].argmax())
                 toks.append(tok)
@@ -142,14 +163,35 @@ def unequal(a, b) -> list:
                   or a[k].tobytes() != b[k].tobytes())
 
 
+@dataclasses.dataclass
+class StepPrograms:
+    """A configuration's two step programs as `programs` compiled them,
+    with what an assertion needs beside their text."""
+    cfg: object
+    chip: object  # the described chip's sharding (None: the backend there)
+    params: dict  # the abstract operands the programs were lowered with
+    pool: dict
+    blocks: object  # the pool's blocks (a grouped pool: one number a group)
+    width: int  # the mixed launch's flat tokens
+    compiled: dict  # {program name: jax.stages.Compiled}
+
+    @functools.cached_property
+    def texts(self) -> dict:
+        """{program name: optimized HLO text}."""
+        return {name: c.as_text() for name, c in self.compiled.items()}
+
+
 def programs(model, slots, blocks, context, block_size=128, tile=8,
-             layers=2, described=True, **overrides) -> dict:
-    """{program name: optimized HLO text} of `model`'s decode chunk and
-    mixed step (cut to `layers` layers, 0: as registered or overridden),
-    compiled for one chip of a described v5e:2x2 (described False: for the
-    backend that is there, in the registry's own dtype). Every family the
-    paged fleet serves: a block-diffusion model's programs carry its
-    DiffState, a model with recurrent layers a pool with a state a slot."""
+             layers=2, described=True, **overrides) -> StepPrograms:
+    """`model`'s decode chunk and mixed step (cut to `layers` layers, 0: as
+    registered or overridden), compiled for one chip of a described v5e:2x2
+    with the kernels lowered for it (described False: for the backend that
+    is there, in the registry's or the overrides' dtype). THE place the
+    tests lower the two programs. Every family the paged fleet serves: a
+    block-diffusion model's programs carry its DiffState, a model with
+    recurrent layers a pool with a state a slot, a pool grouped by layer
+    kind its groups' blocks (engine/paged.group_blocks) under two tables
+    side by side; neither of the last two drafts, so no DeviceMeta."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
@@ -178,10 +220,14 @@ def programs(model, slots, blocks, context, block_size=128, tile=8,
     cfg = resolve_attn_impl(get_model_config(model).replace(**overrides), "pallas")
     params = place(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
     state, sparams = place(lambda: G.init_slots(slots, cfg.vocab_size))
-    pool = place(lambda: P.init_pool(cfg, blocks, block_size, n_slots=slots))
-    table = S((slots, context // block_size), jnp.int32)
-    key = place(lambda: jax.random.PRNGKey(0))
     width = step_width(cfg, slots, tile)  # what the server launches
+    grouped = len(cfg.kv_groups) > 1
+    if grouped:
+        blocks = P.group_blocks(cfg, blocks, P.window_row_budget(
+            cfg.attn_window, width, block_size), slots)
+    pool = place(lambda: P.init_pool(cfg, blocks, block_size, n_slots=slots))
+    table = S((slots, len(cfg.kv_groups) * (context // block_size)), jnp.int32)
+    key = place(lambda: jax.random.PRNGKey(0))
     chunk_kw, mixed_kw = {}, {}
     if cfg.diffusion_block:
         # a decode row is one tile: its open block, behind the owed one in
@@ -193,41 +239,70 @@ def programs(model, slots, blocks, context, block_size=128, tile=8,
                    for b, owe in enumerate(owing)]
         meta, tok_row, tok_pos, offsets, _ = P.build_ragged_meta(
             entries, width=width, tile=tile)
-        *dev, _ = P.build_block_meta(entries, offsets, owing, block=Bd,
-                                     width=width, tile=tile)
+        *dev, open_at = P.build_block_meta(entries, offsets, owing, block=Bd,
+                                           width=width, tile=tile)
+        assert open_at[:2] == [Bd, tile]
         chunk_kw = {"diff": diff}
         mixed_kw = {"dev": dev, "diff": diff, "darm": diff}
     else:
         entries = [(b, 0, 1, P.RAGGED_DECODE) for b in range(slots)]
         meta, tok_row, tok_pos, offsets, _ = P.build_ragged_meta(
             entries, width=width, tile=tile)
-        if cfg.arch != "lfm2":  # (a fleet with recurrent state drafts nothing)
+        if not (cfg.conv_layers or grouped):
             mixed_kw = {"dev": P.build_device_meta(
                 entries, offsets, slots, width=width, tile=tile)}
+    assert len(offsets) == slots  # one tile a row
     if "dev" in mixed_kw:
         mixed_kw["dev"] = P.DeviceMeta(
             *(S(a.shape, a.dtype) for a in mixed_kw["dev"]))
-    chunk = P.decode_slots_paged.lower(
-        cfg, params, state, pool, table, key, sparams, num_steps=16, **chunk_kw)
 
     def flat(a):
         return S(np.shape(a), np.asarray(a).dtype)
 
-    mixed = P.mixed_step_ragged.lower(
-        cfg, params, S((width,), jnp.int32), flat(tok_row), flat(tok_pos),
-        S((width,), jnp.bool_), flat(meta), pool, table, state, sparams, key,
-        S((slots,), jnp.int32),
-        place(lambda: P.idle_mixed_arm(slots, cfg.vocab_size)), **mixed_kw)
-    return {"decode_slots_paged": chunk.compile().as_text(),
-            "mixed_step_ragged": mixed.compile().as_text()}
+    # (described: resolve_interpret would see the CPU backend here and
+    # lower the interpreter)
+    with mock.patch.dict(os.environ,
+                         {"DLI_PALLAS_INTERPRET": "0"} if described else {}):
+        chunk = P.decode_slots_paged.lower(
+            cfg, params, state, pool, table, key, sparams, num_steps=16,
+            **chunk_kw).compile()
+        mixed = P.mixed_step_ragged.lower(
+            cfg, params, S((width,), jnp.int32), flat(tok_row), flat(tok_pos),
+            S((width,), jnp.bool_), flat(meta), pool, table, state, sparams,
+            key, S((slots,), jnp.int32),
+            place(lambda: P.idle_mixed_arm(slots, cfg.vocab_size)),
+            **mixed_kw).compile()
+    return StepPrograms(cfg, chip, params, pool, blocks, width, {
+        "decode_slots_paged": chunk, "mixed_step_ragged": mixed})
 
 
-def cell_programs(config_path: str, layers: int) -> dict:
-    """`programs` of a benchmark configuration (cellbench/configs/<name>.json:
-    its registry entry, overrides and serving flags), cut to `layers` layers
+# The benchmark's configurations (cellbench/configs/<name>.json), by the test
+# file that compiles their programs (tests/cell_program_checks.py): at most
+# two a file, each in one, so that the suite's workers share them out.
+CELL_FILES = {
+    "test_cell_programs_kanana_lfm2": ("kanana-2-30b-a3b-7l", "lfm2-24b-a2b-9l"),
+    "test_cell_programs_mistral_olmo2": ("mistral-7b-16l", "olmo2-7b-16l"),
+    "test_cell_programs_sdar_trinity": ("sdar-30b-a3b-7l", "trinity-large-ep8-5l"),
+}
+CELL_CONFIGS = tuple(sorted(sum(CELL_FILES.values(), ())))
+
+
+def cell_serving(config: str) -> dict:
+    """The `serving` entry of a benchmark configuration: a name under this
+    tree's cellbench/configs, or a path to such a file."""
+    if not config.endswith(".json"):
+        config = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "cellbench", "configs", config + ".json")
+    with open(config) as f:
+        return json.load(f)["serving"]
+
+
+@functools.cache  # (one compile a configuration in a process: minutes each)
+def cell_programs(config: str, layers: int = 0) -> StepPrograms:
+    """`programs` of a benchmark configuration (`cell_serving`: its
+    registry entry, overrides and serving flags), cut to `layers` layers
     (0: the configuration's own depth)."""
-    with open(config_path) as f:
-        serving = json.load(f)["serving"]
+    serving = cell_serving(config)
     flags = serving["flags"]
 
     def flag(name):
@@ -318,10 +393,10 @@ def main() -> None:
         model, out = sys.argv[3], sys.argv[-1]
         os.makedirs(out, exist_ok=True)
         if model.endswith(".json"):  # <configuration file> <layers> <out dir>
-            texts = cell_programs(model, int(sys.argv[4]))
+            texts = cell_programs(model, int(sys.argv[4])).texts
             model = os.path.basename(model)[:-len(".json")]
         else:
-            texts = programs(model, *map(int, sys.argv[4:7]))
+            texts = programs(model, *map(int, sys.argv[4:7])).texts
         for name, text in texts.items():
             with open(os.path.join(out, f"{model}.{name}.hlo.txt"), "w") as f:
                 f.write(text)

@@ -14,9 +14,6 @@ compute block and across two; rows that hold nothing lie beside live ones.
 as NaN: what a dead page's VMEM rows hold must not reach the output.
 """
 
-import json
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -31,9 +28,7 @@ from distributed_llm_inference_tpu.ops.paged_attention import (
     _walk_shape, paged_flash_attend, ragged_paged_attend,
 )
 
-# the benchmark's configurations (cellbench/configs/<name>.json)
-CELL_CONFIGS = ("kanana-2-30b-a3b-7l", "lfm2-24b-a2b-9l", "mistral-7b-16l",
-                "olmo2-7b-16l", "sdar-30b-a3b-7l", "trinity-large-ep8-5l")
+from dense_equal import cell_serving
 
 
 def cell_pool(name):
@@ -42,9 +37,7 @@ def cell_pool(name):
     overrides under `--attn-impl pallas`, the pool `init_pool` makes from
     its flags (a grouped pool's window group a quarter of the global one's
     blocks), nothing allocated."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "cellbench", "configs", name + ".json")) as f:
-        serving = json.load(f)["serving"]
+    serving = cell_serving(name)
     flags = serving["flags"]
     slots, context, blocks, bs = (
         int(flags[flags.index(k) + 1]) for k in

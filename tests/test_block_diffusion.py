@@ -9,6 +9,8 @@ commit, the read at the position itself or the mask id's suppression is
 taken out.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -229,6 +231,15 @@ def mixed_logits(monkeypatch):
     MIXED_LOGITS.clear()
 
 
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _forward_blocks(cfg, params, state, diff, pool, table):
+    """The decode chunk's forward, one program a configuration (a jit made
+    anew at every forward compiled it anew: 380 s of this file's 547 until
+    ISSUE 47). A mutant names its configuration apart, so its program is
+    traced with the rule taken out."""
+    return P._forward_blocks_paged(cfg, params, state, diff, pool, table)
+
+
 class Rows:
     """Rows through the step programs by hand, each with its own prompt,
     denoise_steps and budget, so one forward holds rows in different phases:
@@ -282,8 +293,8 @@ class Rows:
 
     def _chunk_forward(self, key):
         cfg = self.cfg
-        logits, self.pool = jax.jit(lambda st, df, pl: P._forward_blocks_paged(
-            cfg, self.params, st, df, pl, self.table))(self.state, self.diff, self.pool)
+        logits, self.pool = _forward_blocks(
+            cfg, self.params, self.state, self.diff, self.pool, self.table)
         if self.mutant == "shifted-read":  # the autoregressive habit
             logits = jnp.roll(logits, 1, axis=1)
         self.state, self.diff, emit, ok = P.diffusion_step(

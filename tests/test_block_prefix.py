@@ -12,8 +12,6 @@ holder is the index itself; and block accounting must conserve the pool
 import threading
 import time
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -448,55 +446,10 @@ def test_sharing_disabled_without_prefix_entries(base_engine):
 
 
 @pytest.mark.slow
-def test_hit_depth_degrades_to_fit_buckets(base_engine):
-    """A hit whose deepest offset leaves a tail no prefill bucket fits
-    inside the slot window must degrade to a shallower block-aligned
-    depth instead of falling all the way back to cold — found driving
-    the HTTP surface with the default bucket ladder (smallest bucket 64,
-    window 128: a 96-token-deep hit can never plan, a 64-token one can).
-    BUCKETED FALLBACK ONLY (ragged_prefill=False): the ragged ingest has
-    no bucket ladder and reuses at exact depth — that contract is pinned
-    in tests/test_ragged_attention.py's exact-depth regression.
-    """
-    eng = InferenceEngine(
-        base_engine.cfg, params=base_engine.backend.params,
-        engine_cfg=EngineConfig(
-            prefill_buckets=(64,), prefix_cache_entries=4,
-            ragged_prefill=False,
-        ),
-    )
-    p = SHARED + "first question"  # ~98 tokens; full-depth reuse = 96
-    cold = ContinuousEngine(
-        base_engine, n_slots=2, chunk_steps=4, slot_max_seq=128,
-        kv_pool_blocks=40, kv_block_size=BS,
-    )
-    try:
-        want = cold.submit(p, greedy=True, chat=False, max_tokens=10)
-    finally:
-        cold.close()
-    warm = ContinuousEngine(
-        eng, n_slots=2, chunk_steps=4, slot_max_seq=128,
-        kv_pool_blocks=40, kv_block_size=BS,
-    )
-    try:
-        first = warm.submit(p, greedy=True, chat=False, max_tokens=10)
-        again = warm.submit(p, greedy=True, chat=False, max_tokens=10)
-        st = warm.stats()
-    finally:
-        warm.close()
-    assert first["status"] == again["status"] == "success"
-    assert "prefix_cached_tokens" not in first
-    # 96 and 80 cannot plan (offset + 64-bucket > 128); 64 can
-    assert again["prefix_cached_tokens"] == 4 * BS
-    assert again["response"] == want["response"] == first["response"]
-    assert st["prefix_cache"]["dedup_saved_tokens"] == 4 * BS
-
-
-@pytest.mark.slow
 def test_pp_block_sharing_matches_dense(eight_devices):
-    """Block sharing on the pp=2 mesh: the layer-local fill gather + the
-    trash-head insert compose with the gated ring — hit streams match a
-    sharing-free pp paged fleet exactly."""
+    """Block sharing on the pp=2 mesh: the ragged launches that attend the
+    mapped head in place compose with the gated ring — hit streams match
+    the solo pp engine exactly."""
     from distributed_llm_inference_tpu import MeshConfig
     from distributed_llm_inference_tpu.runtime import create_engine
 
@@ -528,27 +481,3 @@ def test_pp_block_sharing_matches_dense(eight_devices):
         assert w["status"] == g["status"] == "success"
         assert g["response"] == w["response"]
     assert got[1]["prefix_cached_tokens"] >= BS
-
-
-@pytest.mark.slow
-def test_gather_scratch_blocks_inverts_scatter(base_engine):
-    """Device-level: gather_scratch_blocks(scatter_scratch(x)) == x on an
-    out-of-order block row — the contiguous view a tail prefill attends
-    is byte-identical to the scratch the blocks came from."""
-    be = base_engine.backend
-    scratch = be.init_cache(1, 4 * BS)
-    # fill with distinguishable content
-    scratch = {
-        k: jnp.asarray(
-            np.random.RandomState(i).standard_normal(v.shape), v.dtype
-        )
-        for i, (k, v) in enumerate(scratch.items())
-    }
-    pool = be.init_paged_pool(9, BS)
-    row = jnp.asarray([5, 2, 7, 3], jnp.int32)
-    pool = P.scatter_scratch(pool, scratch, row)
-    back = P.gather_scratch_blocks(pool, row)
-    for k in ("k", "v"):
-        np.testing.assert_array_equal(
-            np.asarray(back[k]), np.asarray(scratch[k])
-        )

@@ -269,7 +269,7 @@ def _engine(**kw):
     ("shadow", lambda: ContinuousEngine(
         _engine(engine_cfg=EngineConfig(prefix_cache_entries=8)), n_slots=2,
         kv_pool_blocks=16, kv_block_size=BS, kv_shadow=True, slot_max_seq=64)),
-    ("bucketed", lambda: ContinuousEngine(
+    ("unchunked", lambda: ContinuousEngine(
         _engine(engine_cfg=EngineConfig(chunked_prefill=False)), n_slots=2,
         kv_pool_blocks=16, kv_block_size=BS, kv_shadow=False, slot_max_seq=64)),
     ("speculative", lambda: ContinuousEngine(

@@ -7,6 +7,7 @@ And the other side of the same change: a per-head K/V model still builds
 the pool, the programs and the outputs it built before (the last section).
 """
 
+import functools
 import json
 import os
 import sys
@@ -18,7 +19,6 @@ import pytest
 
 from distributed_llm_inference_tpu import EngineConfig, get_model_config
 from distributed_llm_inference_tpu.config import MeshConfig, resolve_attn_impl
-from distributed_llm_inference_tpu.engine import generate as G
 from distributed_llm_inference_tpu.engine import paged as P
 from distributed_llm_inference_tpu.engine.continuous import ContinuousEngine
 from distributed_llm_inference_tpu.engine.engine import InferenceEngine
@@ -114,18 +114,30 @@ def test_absorbed_attention_is_plain_attention(tiny):
 
 # -- prefill in chunks + decode through the latent pool, then a prefix hit ----
 
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _ragged_forward(cfg, params, pool, table, flat, tok_pos, meta, tok_row):
+    """One program for every ragged launch of a test (eagerly, each launch
+    compiled the layer scan anew)."""
+    x = M.embed(cfg, params, flat[:, None], tok_pos)
+    x, pool = M.forward_layers(
+        cfg, params["layers"], x, pool, tok_pos,
+        attn_hook=P.make_ragged_fill_hook(table, meta, tok_row), attn_seq_len=1)
+    return M.unembed(cfg, params, x)[:, 0], pool
+
+
+_decode_step = jax.jit(P._forward_step_paged, static_argnums=0)
+
+
 def _launch(cfg, params, pool, table, entries, toks, width):
     meta, tok_row, tok_pos, offsets, _ = P.build_ragged_meta(
         entries, width=width, tile=TILE)
     flat = np.zeros((width,), np.int32)
     for (_, _, n, _), off, t in zip(entries, offsets, toks):
         flat[off:off + n] = t
-    x = M.embed(cfg, params, jnp.asarray(flat)[:, None], jnp.asarray(tok_pos))
-    x, pool = M.forward_layers(
-        cfg, params["layers"], x, pool, jnp.asarray(tok_pos),
-        attn_hook=P.make_ragged_fill_hook(table, jnp.asarray(meta), jnp.asarray(tok_row)),
-        attn_seq_len=1)
-    return M.unembed(cfg, params, x)[:, 0], pool, offsets
+    lg, pool = _ragged_forward(
+        cfg, params, pool, table, jnp.asarray(flat), jnp.asarray(tok_pos),
+        jnp.asarray(meta), jnp.asarray(tok_row))
+    return lg, pool, offsets
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -148,7 +160,7 @@ def test_chunked_prefill_decode_and_prefix_hit_agree_with_the_reference(tiny, im
     _close(got, want[:40])
     # decode: teacher-forced single steps through the decode hook
     for p in range(40, 52):
-        lg, pool = P._forward_step_paged(
+        lg, pool = _decode_step(
             cfg, params, jnp.asarray(ids[p:p + 1])[:, None], pool,
             jnp.asarray(table[:1]), jnp.asarray([p], jnp.int32))
         _close(lg[0], want[p])
@@ -335,36 +347,22 @@ def test_start_up_refuses_what_a_latent_pool_does_not_carry(tiny):
 
 # -- the dense path is untouched ------------------------------------------------
 
-def _step_programs(cfg, params, slots=3, blocks=12):
-    """(decode chunk, mixed step) of the fleet, compiled for the CPU, and the
-    shapes of what the mixed step returns."""
-    state, sparams = G.init_slots(slots, cfg.vocab_size)
-    pool = P.init_pool(cfg, blocks, BS)
-    table = jnp.zeros((slots, 4), jnp.int32)
-    key = jax.random.PRNGKey(0)
-    chunk = P.decode_slots_paged.lower(
-        cfg, params, state, pool, table, key, sparams, num_steps=2)
-    width = (slots + 1) * TILE
-    entries = [(b, 0, 1, P.RAGGED_DECODE) for b in range(slots)]
-    meta, tok_row, tok_pos, offsets, _ = P.build_ragged_meta(
-        entries, width=width, tile=TILE)
-    dev = P.DeviceMeta(*(jnp.asarray(a) for a in P.build_device_meta(
-        entries, offsets, slots, width=width, tile=TILE)))
-    args = (cfg, params, jnp.zeros((width,), jnp.int32), jnp.asarray(tok_row),
-            jnp.asarray(tok_pos), jnp.zeros((width,), bool), jnp.asarray(meta),
-            pool, table, state, sparams, key, jnp.zeros((slots,), jnp.int32),
-            P.idle_mixed_arm(slots, cfg.vocab_size))
-    mixed = P.mixed_step_ragged.lower(*args, dev=dev)
-    packed = jax.eval_shape(lambda: P.mixed_step_ragged(*args, dev=dev))[0]
-    return chunk, mixed, pool, packed
+def _step_programs(name):
+    """`dense_equal.programs` of a tiny preset's fleet (3 slots over 12
+    blocks of BS, float32, the registry's depth), compiled for the CPU, and
+    the shape of the mixed step's one fetch."""
+    import dense_equal
+
+    built = dense_equal.programs(name, 3, 12, 4 * BS, block_size=BS, tile=TILE,
+                                 layers=0, described=False, dtype="float32")
+    return built, built.compiled["mixed_step_ragged"].out_info[0]
 
 
-def _compiled(lowered):
+def _names(text):
     """(module name, the name stacks its instructions were traced under:
     scopes and inner jits included)."""
     import re
 
-    text = lowered.compile().as_text()
     # the instructions' own name stacks only: the text also ends in a table
     # of every frame the PROCESS has traced, whatever program it was for
     stacks = "\n".join(sorted(set(re.findall(r'op_name="([^"]*)"', text))))
@@ -379,53 +377,31 @@ def test_a_dense_configuration_keeps_its_pool_its_programs_and_their_names(name,
     programs keep the module names a device trace is read by, the mixed
     step's one fetch is its five rows, and none of the scopes or outputs
     the latent family added appears in them."""
-    cfg = resolve_attn_impl(get_model_config(name, dtype="float32"), "pallas")
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-    chunk, mixed, pool, packed = _step_programs(cfg, params)
+    built, packed = _step_programs(name)
+    cfg, pool = built.cfg, built.pool
     assert set(pool) == {"k", "v"}
     for leaf in pool.values():
         assert leaf.shape == (cfg.n_layers, 12, kv, BS, cfg.head_dim)
     assert packed.shape == (5, 3)
-    for lowered, module in ((chunk, "jit_decode_slots_paged"),
-                            (mixed, "jit_mixed_step_ragged")):
-        got, text = _compiled(lowered)
-        assert got == module
+    for program, text in built.texts.items():
+        got, text = _names(text)
+        assert got == f"jit_{program}"
         for word in SCOPES + ("routed_expert_matmul", "routed"):
-            assert word not in text, (module, word)
-
-
-@pytest.mark.parametrize("name", ["test-llama-tiny", "test-olmo2-tiny", "mistral-shaped"])
-def test_the_dense_dump_repeats_bit_for_bit_and_the_comparison_sees_one_bit(name):
-    """tests/dense_equal.py is what holds a checkout's dense path against
-    another's (ISSUE 28: tokens, logits and K and V pool of a chunked
-    prefill + decode + prefix-hit repeat, bit-equal). Here: two dumps of
-    this tree are the same bits, so a difference between two checkouts is
-    the programs'; and one flipped bit of one array is reported."""
-    import dense_equal
-
-    a = dense_equal.dump((name,), ("pallas",))
-    b = dense_equal.dump((name,), ("pallas",))
-    assert sorted(a) == [f"{name}.pallas.{k}" for k in ("logits", "pool_k", "pool_v", "tokens")]
-    assert a[f"{name}.pallas.pool_k"].any() and dense_equal.unequal(a, b) == []
-    key = f"{name}.pallas.pool_v"
-    b[key] = b[key].copy()
-    b[key].view(np.uint32).reshape(-1)[5] ^= 1
-    assert dense_equal.unequal(a, b) == [key]
+            assert word not in text, (program, word)
 
 
 def test_the_latent_family_carries_the_names_the_benchmark_reads(tiny):
-    cfg = resolve_attn_impl(tiny[0], "pallas")
-    chunk, mixed, pool, packed = _step_programs(cfg, tiny[1])
+    built, packed = _step_programs(tiny[0].name)
+    cfg, pool = built.cfg, built.pool
     Lm = cfg.n_layers - cfg.first_k_dense
     assert set(pool) == {"dense", "moe", "routed"}
     assert pool["moe"].shape == (Lm, 12, 1, BS, cfg.latent_row)
     assert pool["routed"].shape == (2, Lm, cfg.n_experts)
     assert cfg.latent_row % 128 == 0 and cfg.latent_row >= cfg.latent_dim
     assert packed.shape == (5 + -(-2 * Lm * cfg.n_experts // 3), 3)
-    for lowered, module in ((chunk, "jit_decode_slots_paged"),
-                            (mixed, "jit_mixed_step_ragged")):
-        got, text = _compiled(lowered)
-        assert got == module
+    for program, text in built.texts.items():
+        got, text = _names(text)
+        assert got == f"jit_{program}"
         for scope in SCOPES:
-            assert scope in text, (module, scope)
+            assert scope in text, (program, scope)
         assert "routed_expert_matmul" in text

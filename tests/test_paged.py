@@ -81,6 +81,27 @@ def test_blocks_needed():
     assert P.blocks_needed(1, 1, 16) == 1
 
 
+def _fill_slot_paged(backend, pool, state, sparams, slot, row, tokens, plen,
+                     key, sampling, max_tokens, knobs):
+    """What a paged fleet's admission runs: ONE ragged launch prefills
+    tokens[0, :plen] straight into `row`'s pool blocks and samples the
+    first token off the last one, then the slot's state is armed.
+    Returns (first, pool, state, sparams)."""
+    n = int(plen)
+    meta, tok_row, tok_pos, _, _ = P.build_ragged_meta(
+        [(0, 0, n, P.RAGGED_PREFILL)], width=tokens.shape[1], tile=8,
+    )
+    first, _, pool = backend.prefill_ragged_paged(
+        tokens[0], jnp.asarray(tok_row), jnp.asarray(tok_pos),
+        jnp.asarray(meta), pool, jnp.asarray(row)[None, :],
+        jnp.int32(n - 1), key, sampling,
+    )
+    state, sparams = backend.arm_slot_paged(
+        state, sparams, slot, first[0], plen, max_tokens, *knobs
+    )
+    return first, pool, state, sparams
+
+
 @pytest.mark.slow
 def test_decode_slots_paged_matches_dense(solo_engine):
     """Device-level: one occupied slot decoding over the block pool emits
@@ -118,18 +139,16 @@ def test_decode_slots_paged_matches_dense(solo_engine):
         num_steps=steps,
     )
 
-    # paged pool: same scratch content, scattered into blocks
-    scratch2 = backend.init_cache(1, MB * bs)
-    first2, _, scratch2 = backend.prefill(tokens, plen, scratch2, key, sampling)
+    # paged pool: the same prompt prefilled straight into its blocks
     pool = backend.init_paged_pool(2 * MB + 1, bs)
     # non-trivial physical placement: out-of-order block ids
     table = np.zeros((n_slots, MB), np.int32)
     row = np.asarray([5, 2, 7, 3], np.int32)
     table[1] = row
     state2, sparams2 = G.init_slots(n_slots, cfg.vocab_size)
-    pool, state2, sparams2 = backend.insert_slot_paged(
-        pool, scratch2, state2, sparams2, 1, jnp.asarray(row),
-        first2[0], plen, jnp.int32(steps + 1), *knobs,
+    first2, pool, state2, sparams2 = _fill_slot_paged(
+        backend, pool, state2, sparams2, 1, row, tokens, plen, key,
+        sampling, jnp.int32(steps + 1), knobs,
     )
     em_p, mask_p, state_p, _, _ = backend.decode_slots_paged(
         state2, pool, jnp.asarray(table), jax.random.PRNGKey(3), sparams2,
@@ -410,13 +429,11 @@ def test_paged_kernel_token_parity(solo_engine):
     streams = []
     for eng in (eng_x, eng_p):
         be = eng.backend
-        scratch = be.init_cache(1, MB * bs)
-        first, _, scratch = be.prefill(tokens, plen, scratch, key, sampling)
         state, sparams = G.init_slots(n_slots, eng.cfg.vocab_size)
         pool = be.init_paged_pool(2 * MB + 1, bs)
-        pool, state, sparams = be.insert_slot_paged(
-            pool, scratch, state, sparams, 1, jnp.asarray(table[1]),
-            first[0], plen, jnp.int32(steps + 1), *knobs,
+        _, pool, state, sparams = _fill_slot_paged(
+            be, pool, state, sparams, 1, table[1], tokens, plen, key,
+            sampling, jnp.int32(steps + 1), knobs,
         )
         em, mask, _, _, _ = be.decode_slots_paged(
             state, pool, jnp.asarray(table), jax.random.PRNGKey(3),
@@ -501,17 +518,15 @@ def test_pp_decode_slots_paged_matches_dense(eight_devices):
         state, cache, jax.random.PRNGKey(3), sparams, num_steps=steps
     )
 
-    # paged pp pool: same scratch content scattered into out-of-order blocks
-    scratch2 = backend.init_cache(1, MB * bs)
-    first2, _, scratch2 = backend.prefill(tokens, plen, scratch2, key, sampling)
+    # paged pp pool: the same prompt prefilled into out-of-order blocks
     pool = backend.init_paged_pool(2 * MB + 1, bs)
     table = np.zeros((n_slots, MB), np.int32)
     row = np.asarray([5, 2, 7, 3], np.int32)
     table[1] = row
     state2, sparams2 = G.init_slots(n_slots, cfg.vocab_size)
-    pool, state2, sparams2 = backend.insert_slot_paged(
-        pool, scratch2, state2, sparams2, 1, jnp.asarray(row),
-        first2[0], plen, jnp.int32(steps + 1), *knobs,
+    first2, pool, state2, sparams2 = _fill_slot_paged(
+        backend, pool, state2, sparams2, 1, row, tokens, plen, key,
+        sampling, jnp.int32(steps + 1), knobs,
     )
     em_p, mask_p, _, _, _ = backend.decode_slots_paged(
         state2, pool, jnp.asarray(table), jax.random.PRNGKey(3), sparams2,
@@ -918,53 +933,3 @@ def test_ragged_fill_hook_writes_its_layer_of_the_stacked_pool(kind, impl):
                              L - 1)
         np.testing.assert_allclose(np.asarray(attn[L - 1])[live],
                                    np.asarray(want)[live], atol=1e-4, rtol=1e-4)
-
-
-def _slice_and_restack(hook):
-    """The contract before ISSUE 29, through today's hook: cut the layer's
-    slice out of the pool, run the hook on that one-layer pool, put the
-    slice back. What the layer scan did with the pool as its xs and ys."""
-
-    def cut(leaf, layer):
-        return None if leaf is None else jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0), leaf)
-
-    def back(leaf, new, layer):
-        return None if leaf is None else jax.tree.map(
-            lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n[0], layer, 0),
-            leaf, new)
-
-    def sliced(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
-               valid_start, window_flag, layer):
-        attn, nk, nv = hook(cfg, q, k, v, cut(cache_k, layer),
-                            cut(cache_v, layer), pos, mask, update_gate,
-                            valid_start, window_flag, jnp.int32(0))
-        return attn, back(cache_k, nk, layer), back(cache_v, nv, layer)
-
-    sliced.paged, sliced.live = True, hook.live
-    return sliced
-
-
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-@pytest.mark.parametrize(
-    "preset", ["test-llama-tiny", "test-olmo2-tiny", "mistral-shaped",
-               "test-mla-moe-tiny", "wide-head"])
-def test_carried_pool_equals_slice_and_restack(preset, impl):
-    """tests/dense_equal.py's chunked prefill + decode + prefix-hit repeat:
-    the pool carried through the layer scan and indexed by the layer holds,
-    and yields, what a pool cut into layer slices and stacked again does.
-    The same greedy tokens; logits and pool leaves to the last bits (the
-    CPU backend fuses the two graphs differently, dense_equal.py's
-    docstring: run from the command line, against a checkout of the parent
-    and unfused, the two dumps are the same bits)."""
-    import dense_equal
-
-    carried = dense_equal.dump((preset,), (impl,))
-    sliced = dense_equal.dump((preset,), (impl,), wrap=_slice_and_restack)
-    assert sorted(carried) == sorted(sliced) and len(carried) >= 4
-    for key, got in carried.items():
-        if key.endswith(".tokens"):
-            np.testing.assert_array_equal(got, sliced[key])
-        else:
-            assert got.any()
-            np.testing.assert_allclose(got, sliced[key], rtol=0, atol=1e-5)

@@ -7,7 +7,7 @@ arguments of the step programs it dispatches (engine/continuous
 `_step_program`), and its end compiles them again, maps instruction ->
 scope (`scope_map`) and writes `program_scopes.json` beside the profile
 (serving/server._Profiler.stop). Held here on the CPU at the registry's tiny
-models; tests/test_chip_compile.py holds the labels at the cells' shapes
+models; tests/cell_program_checks.py holds the labels at the cells' shapes
 for the chip.
 """
 
@@ -49,7 +49,7 @@ def test_the_vocabulary_names_every_scope_once():
 @pytest.mark.parametrize("preset", sorted(FAMILIES))
 def test_scope_map_finds_every_label_of_the_family_in_both_step_programs(preset):
     texts = dense_equal.programs(preset, 3, 24, 64, block_size=16, layers=0,
-                                 described=False)
+                                 described=False).texts
     assert set(texts) == {"decode_slots_paged", "mixed_step_ragged"}
     for program, text in texts.items():
         (module, insts), = tracing.scope_map(text).items()

@@ -5,8 +5,9 @@ The bar: the ragged path is a LAUNCH strategy, not a semantics change —
 mixed prefill+decode rows of arbitrary length in one kernel launch must
 match the dense reference bit-for-fp32-tolerance (incl. int8 kv_quant and
 sliding windows), the engine's ragged admission must be greedy-identical
-to the bucketed fallback, and the block-prefix planner must reuse at
-EXACT chunk depth where the bucketed plan degrades to a bucket boundary.
+to the solo engine on the same weights, and the block-prefix planner must
+reuse at EXACT chunk depth where a bucketed plan would degrade to a bucket
+boundary.
 Every kernel here runs under interpret=True on CPU (tests/conftest.py
 pins DLI_PALLAS_INTERPRET=1 — the tier-1 bit-exactness switch).
 """
@@ -266,7 +267,7 @@ def test_interpret_env_switch():
         os.environ["DLI_PALLAS_INTERPRET"] = old
 
 
-# -- engine-level: ragged admission vs bucketed fallback ----------------------
+# -- engine-level: ragged admission vs the solo engine --------------------------
 
 PREFIX_CFG = dict(dtype="float32", eos_token_id=-1, max_seq_len=256)
 
@@ -278,17 +279,18 @@ def family_setup(request):
     return cfg, params
 
 
-def _cont(cfg, params, ragged, attn_impl=None, **ecfg):
+def _cont(cfg, params, attn_impl=None, **ecfg):
+    """(the paged fleet, its solo engine: the greedy reference on the same
+    weights)."""
     if attn_impl is not None:
         cfg = cfg.replace(attn_impl=attn_impl)
     eng = InferenceEngine(
         cfg, params=params,
         engine_cfg=EngineConfig(
-            prefix_cache_entries=4, ragged_prefill=ragged,
-            prefill_buckets=(64, 128, 256), **ecfg,
+            prefix_cache_entries=4, prefill_buckets=(64, 128, 256), **ecfg,
         ),
     )
-    return ContinuousEngine(
+    return eng, ContinuousEngine(
         eng, n_slots=4, chunk_steps=8, slot_max_seq=256,
         kv_pool_blocks=48, kv_block_size=16,
     )
@@ -310,10 +312,10 @@ def _submit_all(cont, prompts, **kw):
     return out
 
 
-def test_ragged_greedy_identical_to_bucketed(family_setup):
+def test_ragged_greedy_identical_to_solo(family_setup):
     """Mixed fleet (concurrent prompts of different lengths, warm prefix
-    reuse) — the ragged path must be token-identical to the bucketed
-    scratch path, both families."""
+    reuse) — the ragged path must be token-identical to the solo engine's
+    bucketed prefill on a dense cache, both families."""
     cfg, params = family_setup
     shared = " ".join(f"ctx{j}" for j in range(16))
     prompts = [
@@ -322,28 +324,27 @@ def test_ragged_greedy_identical_to_bucketed(family_setup):
         shared + " question two",
         "short",
     ]
-    outs = {}
-    for ragged in (False, True):
-        cont = _cont(cfg, params, ragged)
-        try:
-            # serial first pass warms the prefix chains; the threaded wave
-            # exercises a mixed fleet on the warm path
-            warm = [
-                cont.submit(p, max_tokens=10, greedy=True, chat=False)
-                for p in prompts
-            ]
-            wave = _submit_all(cont, prompts, max_tokens=10)
-            st = cont.stats()
-        finally:
-            cont.close()
-        assert all(r["status"] == "success" for r in warm + wave), (
-            ragged, warm, wave,
-        )
-        assert st["paged"]["ragged_prefill"] is ragged
-        outs[ragged] = [r["response"] for r in warm] + [
-            r["response"] for r in wave
+    eng, cont = _cont(cfg, params)
+    try:
+        want = [
+            eng.generate(p, max_tokens=10, greedy=True, chat=False)["response"]
+            for p in prompts
         ]
-    assert outs[True] == outs[False]
+        # serial first pass warms the prefix chains; the threaded wave
+        # exercises a mixed fleet on the warm path
+        warm = [
+            cont.submit(p, max_tokens=10, greedy=True, chat=False)
+            for p in prompts
+        ]
+        wave = _submit_all(cont, prompts, max_tokens=10)
+        st = cont.stats()
+    finally:
+        cont.close()
+    assert all(r["status"] == "success" for r in warm + wave), (warm, wave)
+    assert st["paged"]["ragged_width"] == 64
+    assert any(r.get("prefix_cached_tokens") for r in wave)
+    assert [r["response"] for r in warm] == want
+    assert [r["response"] for r in wave] == want
 
 
 def test_ragged_kernel_path_greedy_identical(family_setup):
@@ -357,7 +358,7 @@ def test_ragged_kernel_path_greedy_identical(family_setup):
     prompts = ["a b c d e f", "the quick brown fox jumps"]
     outs = {}
     for impl in ("xla", "pallas"):
-        cont = _cont(cfg, params, True, attn_impl=impl)
+        _, cont = _cont(cfg, params, attn_impl=impl)
         try:
             outs[impl] = [
                 cont.submit(p, max_tokens=8, greedy=True, chat=False)[
@@ -372,73 +373,70 @@ def test_ragged_kernel_path_greedy_identical(family_setup):
 
 def test_ragged_int8_pool_greedy_identical(family_setup):
     """int8 kv_quant composes with the ragged path: quantize-on-scatter
-    into the pool must serve the same greedy stream as the bucketed
-    scratch path (which quantizes into the scratch, then block-copies)."""
+    into the pool must serve the same greedy stream as the solo engine
+    (which quantizes into its dense cache)."""
     cfg, params = family_setup
     if cfg.arch == "gpt2":
         pytest.skip("kv_quant is a llama-family config knob")
     qcfg = cfg.replace(kv_quant="int8")
     prompts = ["the quick brown fox", "hello world"]
-    outs = {}
-    for ragged in (False, True):
-        cont = _cont(qcfg, params, ragged)
-        try:
-            outs[ragged] = [
-                cont.submit(p, max_tokens=8, greedy=True, chat=False)[
-                    "response"
-                ]
-                for p in prompts
-            ]
-        finally:
-            cont.close()
-    assert outs[True] == outs[False]
+    eng, cont = _cont(qcfg, params)
+    try:
+        want = [
+            eng.generate(p, max_tokens=8, greedy=True, chat=False)["response"]
+            for p in prompts
+        ]
+        got = [
+            cont.submit(p, max_tokens=8, greedy=True, chat=False)["response"]
+            for p in prompts
+        ]
+    finally:
+        cont.close()
+    assert got == want
 
 
 def test_exact_depth_reuse_no_bucket_degradation():
     """The planner regression the ragged path exists to fix: a hit whose
-    tail no prefill bucket fits degrades the reuse depth on the bucketed
-    path, but reuses at EXACT chunk depth on the ragged path — and
-    mark() accounting matches the planned depth in both modes."""
+    tail no prefill bucket fits would degrade the reuse depth under a
+    bucketed plan, but reuses at EXACT chunk depth on the ragged path —
+    mark() accounting matches the planned depth, and the hit serves the
+    solo engine's greedy text."""
     cfg = get_model_config(
         "test-llama-tiny", dtype="float32", eos_token_id=-1,
         max_seq_len=128,
     )
     params = M.init_params(cfg, jax.random.PRNGKey(1))
 
-    def serve(ragged):
-        eng = InferenceEngine(
-            cfg, params=params,
-            engine_cfg=EngineConfig(
-                prefix_cache_entries=4, ragged_prefill=ragged,
-                prefill_buckets=(64,),
-            ),
-        )
-        cont = ContinuousEngine(
-            eng, n_slots=2, chunk_steps=4, slot_max_seq=128,
-            kv_pool_blocks=24, kv_block_size=16,
-        )
-        try:
-            # 96-token shared head (6 full blocks), ~100-token prompts:
-            # the 4-token tail needs the 64 bucket, and 96 + 64 > 128, so
-            # the bucketed plan must degrade the depth to 64
-            base = "x" * 96
-            r1 = cont.submit(base + "abcd", max_tokens=4, greedy=True,
-                             chat=False)
-            r2 = cont.submit(base + "wxyz", max_tokens=4, greedy=True,
-                             chat=False)
-            st = cont.stats()["prefix_cache"]
-        finally:
-            cont.close()
-        assert r1["status"] == "success" and r2["status"] == "success"
-        return r2.get("prefix_cached_tokens", 0), st
-
-    ragged_depth, ragged_st = serve(True)
-    bucketed_depth, bucketed_st = serve(False)
-    assert ragged_depth == 96  # exact chunk depth: 6 blocks of 16
-    assert bucketed_depth == 64  # degraded to fit the 64 bucket
-    # mark() accounting follows the PLANNED depth, not the chain depth
-    assert ragged_st["dedup_saved_tokens"] == 96
-    assert bucketed_st["dedup_saved_tokens"] == 64
+    eng = InferenceEngine(
+        cfg, params=params,
+        engine_cfg=EngineConfig(
+            prefix_cache_entries=4, prefill_buckets=(64,),
+        ),
+    )
+    cont = ContinuousEngine(
+        eng, n_slots=2, chunk_steps=4, slot_max_seq=128,
+        kv_pool_blocks=24, kv_block_size=16,
+    )
+    try:
+        # 96-token shared head (6 full blocks), ~100-token prompts: the
+        # 4-token tail would need the 64 bucket, and 96 + 64 > 128, so a
+        # bucketed plan would degrade the depth to 64
+        base = "x" * 96
+        want = eng.generate(base + "wxyz", max_tokens=4, greedy=True,
+                            chat=False)
+        r1 = cont.submit(base + "abcd", max_tokens=4, greedy=True,
+                         chat=False)
+        r2 = cont.submit(base + "wxyz", max_tokens=4, greedy=True,
+                         chat=False)
+        st = cont.stats()["prefix_cache"]
+    finally:
+        cont.close()
+    assert r1["status"] == "success" and r2["status"] == "success"
+    # exact chunk depth: 6 blocks of 16
+    assert r2.get("prefix_cached_tokens", 0) == 96
+    # mark() accounting follows the PLANNED depth
+    assert st["dedup_saved_tokens"] == 96
+    assert r2["response"] == want["response"]
 
 
 def test_ragged_single_program_any_tail():
@@ -458,8 +456,7 @@ def test_ragged_single_program_any_tail():
         # ingest launches (extend/prefill pair); the chunked scheduler's
         # mixed-launch counting lives in tests/test_scheduler.py
         engine_cfg=EngineConfig(
-            prefix_cache_entries=0, ragged_prefill=True,
-            chunked_prefill=False,
+            prefix_cache_entries=0, chunked_prefill=False,
         ),
     )
     cont = ContinuousEngine(
@@ -512,8 +509,7 @@ def test_ragged_metrics_and_pool_hygiene():
         # the chunked scheduler's phase=mixed accounting is covered in
         # tests/test_scheduler.py
         engine_cfg=EngineConfig(
-            prefix_cache_entries=0, ragged_prefill=True,
-            chunked_prefill=False,
+            prefix_cache_entries=0, chunked_prefill=False,
         ),
     )
     cont = ContinuousEngine(

@@ -1,7 +1,7 @@
 """ISSUE 39: the q / k / v products of a scanned layer go through
 `models/llama.pin_products` before their head split, so that the chip's
 compiler leaves the split behind the dot and the dot reads its layer's
-weights in place (tests/test_chip_compile.py holds the compiled programs to
+weights in place (tests/cell_program_checks.py holds the compiled programs to
 that). Here, on the CPU: it is the same arithmetic. Every kind of leaf and
 of layer that passes the helper gives, bit for bit, what the old
 `(h @ w).reshape(B, T, H, Dh)` gave, through the jitted layer scan."""
