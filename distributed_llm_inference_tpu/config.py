@@ -25,7 +25,8 @@ class ModelConfig:
     """
 
     name: str = "tinyllama-1.1b"
-    arch: str = "llama"  # "llama" | "gpt2" | "mla_moe" | "lfm2" | "afmoe"
+    # "llama" | "gpt2" | "mla_moe" | "lfm2" | "afmoe" | "minicpm_sala"
+    arch: str = "llama"
     vocab_size: int = 32000
     dim: int = 2048
     n_layers: int = 22
@@ -159,8 +160,26 @@ class ModelConfig:
     # or "full_attention" (reads everything, takes no position encoding);
     # every layer owns K/V, and the paged pool keeps the two kinds in two
     # groups of blocks (engine/paged.py: `kv_groups`).
+    # Arch "minicpm_sala" (models/minicpm_sala.py: MiniCPM-SALA) names each
+    # layer "minicpm4" (GQA without rotary whose queries past sparse_dense_len
+    # positions read only the sparse_topk blocks of sparse_block tokens that a
+    # score over mean-pooled keys selects; owns K/V and the compressed keys)
+    # or "lightning-attn" (decayed linear attention over linear_heads heads:
+    # a float32 matrix state a row and layer, no K/V).
     layer_types: Optional[tuple] = None
     conv_kernel: int = 0
+    linear_heads: int = 0
+    # the selection's constants (the family's published sparse_config): a
+    # compressed key is the mean of sparse_kernel keys, one every
+    # sparse_stride tokens; block 0.. sparse_init_blocks - 1 and the blocks
+    # of the last sparse_window positions are always read
+    sparse_kernel: int = 32
+    sparse_stride: int = 16
+    sparse_block: int = 64
+    sparse_topk: int = 64
+    sparse_window: int = 2048
+    sparse_init_blocks: int = 1
+    sparse_dense_len: int = 8192
     # Generation by block diffusion (SDAR): 0 = autoregressive. > 0: the
     # sequence is cut into blocks of this many tokens at absolute positions;
     # a token attends every position up to the END of its own block; a
@@ -355,10 +374,40 @@ class ModelConfig:
                 )
             if self.conv_kernel:
                 raise ValueError("conv_kernel is arch 'lfm2' only")
+        elif self.arch == "minicpm_sala":
+            kinds = self.layer_types or ()
+            # (at least one of EACH: the family's pool, host position model
+            # and start-up refusals are keyed on `linear_layers`)
+            if (len(kinds) != self.n_layers
+                    or set(kinds) != {"minicpm4", "lightning-attn"}):
+                raise ValueError(
+                    f"arch 'minicpm_sala' needs layer_types: n_layers "
+                    f"({self.n_layers}) entries of 'minicpm4' / "
+                    f"'lightning-attn', at least one of each; "
+                    f"got {kinds!r}"
+                )
+            if self.linear_heads < 1:
+                raise ValueError("arch 'minicpm_sala' needs linear_heads")
+            k, st, blk = (self.sparse_kernel, self.sparse_stride,
+                          self.sparse_block)
+            if not (0 < st <= k <= blk and k % st == 0 and blk % st == 0
+                    and self.sparse_window % blk == 0
+                    and self.sparse_topk >= self.sparse_init_blocks
+                    + self.sparse_window // blk + 1):
+                raise ValueError(
+                    f"arch 'minicpm_sala': the selection needs stride | "
+                    f"kernel <= block, stride | block | window and topk "
+                    f"above the forced blocks; got kernel {k}, stride {st}, "
+                    f"block {blk}, window {self.sparse_window}, topk "
+                    f"{self.sparse_topk}")
+            if self.conv_kernel:
+                raise ValueError("conv_kernel is arch 'lfm2' only")
         elif self.layer_types is not None or self.conv_kernel:
             raise ValueError(
-                "layer_types is arch 'lfm2' / 'afmoe' only, conv_kernel "
-                "arch 'lfm2' only")
+                "layer_types is arch 'lfm2' / 'afmoe' / 'minicpm_sala' "
+                "only, conv_kernel arch 'lfm2' only")
+        if self.linear_heads and self.arch != "minicpm_sala":
+            raise ValueError("linear_heads is arch 'minicpm_sala' only")
         if self.expert_lo or self.n_experts_held:
             if self.arch != "afmoe":
                 raise ValueError("an expert share (expert_lo, "
@@ -400,13 +449,26 @@ class ModelConfig:
                      if kind == "conv")
 
     @property
+    def recurrent(self) -> bool:
+        """The model keeps a state a row beside (or in place of) K/V: gated
+        short convolutions or linear attention layers."""
+        return bool(self.conv_layers or self.linear_layers)
+
+    @property
+    def linear_layers(self) -> tuple:
+        """The model's decayed linear-attention layers (their index in the
+        stack); () outside arch 'minicpm_sala'."""
+        return tuple(i for i, kind in enumerate(self.layer_types or ())
+                     if kind == "lightning-attn")
+
+    @property
     def attn_layers(self) -> tuple:
-        """The layers that own K/V: every layer, or arch 'lfm2''s
-        attention layers."""
+        """The layers that own K/V: every layer, arch 'lfm2''s attention
+        layers, or arch 'minicpm_sala''s sparse ones."""
         if self.layer_types is None or self.arch == "afmoe":
             return tuple(range(self.n_layers))
         return tuple(i for i, kind in enumerate(self.layer_types)
-                     if kind == "full_attention")
+                     if kind in ("full_attention", "minicpm4"))
 
     @property
     def experts_held(self) -> int:
@@ -577,6 +639,10 @@ class EngineConfig:
     # dead tiles the kernel's DMA skips). Rounded up to a multiple of the
     # query tile (8).
     ragged_width: int = 64
+    # States a paged fleet keeps beside its pool for the prefix index to
+    # restore (a model whose recurrent state is too large to keep one a
+    # block: models/minicpm_sala.py, engine/block_prefix.py). 0: two a slot.
+    state_snapshots: int = 0
     # SLO-aware chunked-prefill scheduler (engine/scheduler.py): ragged
     # paged fleets stop prefilling an admission whole before it joins the
     # decode fleet — each scheduler step assembles ONE mixed ragged launch

@@ -115,7 +115,7 @@ class BlockPrefixIndex:
     """
 
     def __init__(self, alloc, block_size: int, registry=None, side=None,
-                 window: int = 0):
+                 window: int = 0, snapshots: int = 0):
         if block_size < 1:
             raise ValueError("block prefix index needs block_size >= 1")
         self._alloc = alloc
@@ -134,6 +134,39 @@ class BlockPrefixIndex:
         self._side_of: "collections.OrderedDict[int, int]" = (
             collections.OrderedDict()
         )
+        # A fleet whose recurrent state is too large to keep one a block
+        # (models/minicpm_sala.py: a matrix state a layer) keeps `snapshots`
+        # of them in a pool of its own, each the state at the END of one
+        # cached block: _snap_of maps that block to its snapshot's index. A
+        # snapshot lives and dies with its block's entry, or goes alone when
+        # the pool runs dry (`snap_alloc`: least recently used first, never
+        # one a planned launch still reads, `_snap_pins`); a hit is usable
+        # only to the deepest block that has one (`lookup`). An index a
+        # prefill has written but whose block is not registered yet is its
+        # job's (`_snap_jobs`).
+        self._snaps = int(snapshots)
+        self._snap_of: "collections.OrderedDict[int, int]" = (
+            collections.OrderedDict()
+        )
+        self._snap_free = list(range(self._snaps - 1, -1, -1))
+        self._snap_pins: dict = {}
+        self._snap_jobs: set = set()
+        self._m_snaps = self._m_snaps_held = None
+        if registry is not None and self._snaps:
+            self._m_snaps = registry.counter(
+                "dli_state_snapshots_total",
+                "recurrent-state snapshots of the prefix index by event: "
+                "taken = a prefill launch left a row's state at a block "
+                "boundary in the snapshot pool, restored = a prefix hit "
+                "started its row from one, evicted = one gave way (its "
+                "block left the index, or the pool was full)", ("event",),
+            )
+            for event in ("taken", "restored", "evicted"):
+                self._m_snaps.labels(event=event)
+            self._m_snaps_held = registry.gauge(
+                "dli_state_snapshots_held",
+                "recurrent-state snapshots the prefix index holds",
+            ).labels()
         self._cut = None  # the last lookup's hit lost depth to evictions
         self.side_cut_hits = 0
         # planner-protocol granularity (engine._prefix_plan degrades the
@@ -228,6 +261,9 @@ class BlockPrefixIndex:
                 keys.append(key)
                 parent = b
             n = self._side_depth(blocks)
+            if self._snaps:  # as deep as the deepest snapshot
+                n = max((i + 1 for i, b in enumerate(blocks)
+                         if b in self._snap_of), default=0)
         self._cut = None if not blocks or self._side is None else \
             n < len(blocks)
         blocks, keys = blocks[:n], keys[:n]
@@ -261,6 +297,73 @@ class BlockPrefixIndex:
         lo = self._side_lo(len(blocks))
         with self._lock:
             return lo, [self._side_of[b] for b in blocks[lo:]]
+
+    # -- the snapshot pool (worker thread) ------------------------------------
+    def snap_of(self, block: int, pin: bool = False) -> int:
+        """The snapshot of the state at cached block `block`'s end (-1:
+        none). pin: a planned launch will read it; `snap_unpin` once that
+        launch is dispatched."""
+        with self._lock:
+            ix = self._snap_of.get(block, -1)
+            if pin and ix >= 0:
+                self._snap_pins[ix] = self._snap_pins.get(ix, 0) + 1
+                self._snap_of.move_to_end(block)
+                self._count_snap("restored")
+            return ix
+
+    def snap_unpin(self, ix: int) -> None:
+        with self._lock:
+            if self._snap_pins.get(ix, 0) <= 1:
+                self._snap_pins.pop(ix, None)
+            else:
+                self._snap_pins[ix] -= 1
+
+    def snap_alloc(self) -> int:
+        """An index of the snapshot pool for a prefill launch to write (-1:
+        every snapshot is pinned): a free one, else the least recently
+        used snapshot's, whose block keeps its entry and shortens the hits
+        through it. The index is its job's until `register` gives it a
+        block or `snap_release` takes it back."""
+        with self._lock:
+            if self._snap_free:
+                ix = self._snap_free.pop()
+            else:
+                victim = next((b for b, i in self._snap_of.items()
+                               if i not in self._snap_pins), None)
+                if victim is None:
+                    return -1
+                ix = self._snap_of.pop(victim)
+                self._count_snap("evicted")
+            self._snap_jobs.add(ix)
+            self._count_snap("taken")
+            return ix
+
+    def snap_release(self, indices) -> None:
+        """Give back snapshot indices no block was registered for."""
+        with self._lock:
+            for ix in indices:
+                if ix in self._snap_jobs:
+                    self._snap_jobs.discard(ix)
+                    self._snap_free.append(ix)
+            self._count_snap(None)
+
+    def _count_snap(self, event) -> None:
+        if self._m_snaps is not None:
+            if event:
+                self._m_snaps.labels(event=event).inc()
+            self._m_snaps_held.set(len(self._snap_of))
+
+    def _drop_snap(self, block: int) -> None:
+        """A block leaves the index: its snapshot goes with it (lock held)."""
+        ix = self._snap_of.pop(block, None)
+        if ix is not None:
+            self._snap_free.append(ix)
+            self._count_snap("evicted")
+
+    def snap_stats(self) -> dict:
+        with self._lock:
+            return {"held": len(self._snap_of), "free": len(self._snap_free),
+                    "pool": self._snaps}
 
     def side_evictable(self) -> int:
         with self._lock:
@@ -327,7 +430,7 @@ class BlockPrefixIndex:
 
     # -- cache mutation (worker thread) --------------------------------------
     def register(self, ids: list, prompt_len: int, row_blocks: list,
-                 adapter=None, side_blocks=None, resume=None):
+                 adapter=None, side_blocks=None, resume=None, snaps=None):
         """Index the admitted prompt's FULL blocks (positions below
         prompt_len // bs * bs — complete, immutable once the insert
         scatter lands). Blocks already cached (the mapped shared head, or
@@ -342,7 +445,11 @@ class BlockPrefixIndex:
         reference), so a document registered chunk by chunk keeps the
         blocks its row gives back. resume: (depth in blocks, that depth's
         parent) a former call of the same prompt returned through
-        `self.resume`: the walk starts there."""
+        `self.resume`: the walk starts there. snaps (a fleet with a
+        snapshot pool): {logical block: the snapshot index that holds the
+        state at its end}, written by this prompt's prefill launches; the
+        cached block of that depth takes it where it has none, else the
+        index goes back to the pool."""
         bs = self.block_size
         n_full = prompt_len // bs
         first, parent = resume or (0, _root_for(adapter))
@@ -372,8 +479,15 @@ class BlockPrefixIndex:
                         and b not in self._side_of:
                     self._side_of[b] = side_blocks[i]
                     self._side.incref([side_blocks[i]])
+                if snaps and i in snaps and snaps[i] in self._snap_jobs:
+                    self._snap_jobs.discard(snaps[i])
+                    if b in self._snap_of:  # the depth has one already
+                        self._snap_free.append(snaps[i])
+                    else:
+                        self._snap_of[b] = snaps[i]
                 parent = b
             n_entries = len(self._entries)
+            self._count_snap(None)
         self.resume = (max(first, n_full), parent)
         if self._m_entries is not None:
             self._m_entries.set(n_entries)
@@ -472,6 +586,7 @@ class BlockPrefixIndex:
         w = self._side_of.pop(b, None)
         if w is not None:
             self._side.decref([w])
+        self._drop_snap(b)
         parent_children = self._children.get(key[0])
         if parent_children is not None:
             parent_children.discard(key)
@@ -504,6 +619,14 @@ class BlockPrefixIndex:
             if self._side_of:
                 self._side.decref(list(self._side_of.values()))
                 self._side_of.clear()
+            for b in list(self._snap_of):
+                self._drop_snap(b)
+            # (no job outlives this call holding an index: the one caller,
+            # engine/continuous._release_fleet_resources, runs after
+            # `_casualties` has dropped every prefill job)
+            self._snap_free.extend(self._snap_jobs)
+            self._snap_jobs.clear()
+            self._snap_pins.clear()
             self.evictions += len(blocks)
             if blocks:
                 self._alloc.decref(blocks)

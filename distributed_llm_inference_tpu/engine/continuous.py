@@ -123,8 +123,9 @@ from ..utils.metrics import (
     CONV_STATE_RESETS_HELP,
     CONV_TAIL_WRITES_HELP, DEFAULT_SIZE_BUCKETS, DIFFUSION_FORWARDS_HELP,
     DIFFUSION_FUSED_HELP, DIFFUSION_TOKENS_HELP, KV_GROUP_BLOCKS_HELP,
-    KV_WINDOW_RELEASED_HELP, MOE_PAIRS_HELP, PREFIX_STATE_TOKENS_HELP,
-    SLOT_RELEASE_HELP, SLOT_TURNOVER_HELP, STEPS_AHEAD_BUCKETS,
+    KV_WINDOW_RELEASED_HELP, LINEAR_STATE_RESETS_HELP, MOE_PAIRS_HELP,
+    PREFIX_STATE_TOKENS_HELP, SLOT_RELEASE_HELP, SLOT_TURNOVER_HELP,
+    SPARSE_ROWS_HELP, STEPS_AHEAD_BUCKETS,
 )
 from ..utils.retry import overload_retry_after
 from ..utils.tracing import PhaseClock, Trace, abstract_call, sample_decision
@@ -303,12 +304,13 @@ class ContinuousEngine:
         restore_dir: Optional[str] = None,
     ):
         cfg = engine.cfg
-        if cfg.arch not in ("llama", "gpt2", "mla_moe", "lfm2", "afmoe"):
+        if cfg.arch not in ("llama", "gpt2", "mla_moe", "lfm2", "afmoe",
+                            "minicpm_sala"):
             raise ValueError(
-                f"continuous batching supports the llama, gpt2, mla_moe, afmoe and "
-                f"lfm2 families; model arch is {cfg.arch!r}"
+                f"continuous batching supports the llama, gpt2, mla_moe, afmoe, "
+                f"lfm2 and minicpm_sala families; model arch is {cfg.arch!r}"
             )
-        if cfg.conv_layers:
+        if cfg.recurrent:
             # (before the dense fleet would be built for it)
             from .paged import refuse_unsupported_latent
 
@@ -447,6 +449,16 @@ class ContinuousEngine:
                     self._group_blocks[1], self.n_slots, self._max_blocks,
                     self.kv_block_size, cfg.attn_window, launch,
                 )
+            # A model of linear-attention layers (models/minicpm_sala.py) keeps
+            # a matrix state a slot, too large to keep one a block: a pool of
+            # state snapshots beside the K/V pool, which the prefix index gives
+            # out (engine/block_prefix.py). A prompt's prefill leaves the state
+            # at its last two whole-block boundaries there (`_snap_points`), and
+            # a hit is as deep as the deepest block that has one.
+            self._snap_pool = 0
+            if cfg.linear_layers:
+                self._snap_pool = int(engine.engine_cfg.state_snapshots) \
+                    or 2 * self.n_slots
             self.cache = self._init_pool()
             self._alloc = P.BlockAllocator(
                 self._pool_blocks, registry=engine.metrics
@@ -544,7 +556,7 @@ class ContinuousEngine:
         # starts the row from zeros or from the tail under a prefix hit,
         # never from what the slot's previous tenant left; no row is
         # drafted for (a rejected token would already be in the state).
-        self._recurrent = bool(cfg.conv_layers)
+        self._recurrent = cfg.recurrent
         if self._recurrent or self._wgrp is not None:
             # (a grouped pool: the position model decides which window
             # blocks a row holds, so it has to be exact: no verify rows)
@@ -688,6 +700,8 @@ class ContinuousEngine:
 
                 side = {} if self._wgrp is None else {
                     "side": self._wgrp.alloc, "window": cfg.attn_window}
+                if cfg.linear_layers:
+                    side["snapshots"] = self._snap_pool
                 self._bpx = BlockPrefixIndex(
                     self._alloc, self.kv_block_size,
                     registry=engine.metrics, **side,
@@ -882,6 +896,11 @@ class ContinuousEngine:
             if self.cfg.attn_window and slide:
                 self._kv_kinds = ((self.cfg.n_layers - slide, None),
                                   (slide, self.cfg.attn_window))
+        # sparse attention layers (models/minicpm_sala.py) read a SELECTED
+        # set of blocks past the dense length: the position model counts
+        # what is read (`_kv_span`, `_kv_walk`) and, beside it, what a range
+        # walk would (`_sparse_fields`)
+        self._sparse = self.cfg if self.cfg.linear_layers else None
         # whether attention reads the pool through the paged kernels'
         # block walk (_kv_walk) or gathers whole tables
         self._kv_walks = self.paged and self.cfg.attn_impl == "pallas"
@@ -1080,7 +1099,10 @@ class ContinuousEngine:
             "dli_attn_kv_tokens_total",
             "KV positions per layer and KV head: attended = the fewest "
             "the launch's rows need (host position model, window-"
-            "clipped), walked = what the kernels' block loops cover",
+            "clipped), walked = what the kernels' block loops cover; a "
+            "fleet with sparse attention layers adds visible = every "
+            "position at or below the query (what a range walk reads) and "
+            "selected = what the selection lets it read (attended, again)",
             ("phase", "state"),
         )
         self._m_walk_steps = m.counter(
@@ -1146,6 +1168,15 @@ class ContinuousEngine:
         self._m_conv_tails = m.counter(
             "dli_conv_tail_writes_total", CONV_TAIL_WRITES_HELP,
         ).labels()
+        self._m_lin_resets = m.counter(
+            "dli_linear_state_resets_total", LINEAR_STATE_RESETS_HELP,
+        ).labels()
+        self._m_sparse_rows = m.counter(
+            "dli_sparse_rows_total", SPARSE_ROWS_HELP, ("branch",),
+        )
+        if cfg.linear_layers:
+            for branch in ("dense", "sparse"):
+                self._m_sparse_rows.labels(branch=branch)
         self._m_steps_ahead = m.histogram(
             "dli_launch_steps_ahead",
             "scheduler steps dispatched and unfetched when a launch was "
@@ -1975,7 +2006,9 @@ class ContinuousEngine:
     def _init_pool(self):
         """The fleet's zeroed pool; with a state a slot where the model has
         recurrent layers (engine/paged.init_pool)."""
-        state = {"n_slots": self.n_slots} if self.cfg.conv_layers else {}
+        state = {"n_slots": self.n_slots} if self.cfg.recurrent else {}
+        if self.cfg.linear_layers:
+            state["n_snapshots"] = self._snap_pool
         blocks = self._pool_blocks if self._wgrp is None \
             else self._group_blocks
         return self.backend.init_paged_pool(
@@ -3104,6 +3137,18 @@ class ContinuousEngine:
         return at - owed * self._blk, (1 + owed) * self._blk
 
     # -- the launch record (ISSUE 24) -----------------------------------------
+    def _snap_points(self, p0: int, prompt_len: int) -> tuple:
+        """The positions at which a prompt's prefill leaves its state in
+        the snapshot pool: where its last whole block ends and one block
+        below (a later prompt that shares all but this one's last few
+        tokens, a document asked another question, hits at one of the
+        two), each at least two blocks past the hit's depth p0 (a prompt
+        that adds less to a cached one adds no depth worth a snapshot)."""
+        bs = self.kv_block_size
+        last = prompt_len // bs
+        low = p0 // bs + 2 if p0 else 1
+        return tuple(b * bs for b in (last - 1, last) if b >= low)
+
     def _state_fields(self, spans, restored: int = 0, resets: int = 0):
         """The launch record's fields of a fleet with recurrent layers, by
         the host position model. spans: (first position, tokens) of every
@@ -3195,12 +3240,34 @@ class ContinuousEngine:
                 out[key] += layers * count
         return out
 
+    def _sparse_fields(self, phase: str, visible) -> dict:
+        """The launch record's fields of a fleet with sparse attention
+        layers: `visible`, the positions at or below each live row-step's
+        (last) query; `kv_tokens` beside it counts what is read."""
+        visible = np.asarray(visible).reshape(-1)
+        sparse = int(np.sum(visible >= self._sparse.sparse_dense_len))
+        read = int(np.sum(self._kv_span(visible, 0)))
+        # (dli_attn_kv_tokens_total's further states for such a fleet)
+        self._m_kv_tokens.labels(phase=phase, state="visible").inc(
+            int(visible.sum()))
+        self._m_kv_tokens.labels(phase=phase, state="selected").inc(read)
+        self._m_sparse_rows.labels(branch="sparse").inc(sparse)
+        self._m_sparse_rows.labels(branch="dense").inc(len(visible) - sparse)
+        return {"kv_tokens_visible": int(visible.sum()),
+                "sparse_rows": sparse, "state_rows": len(visible)}
+
     def _kv_span(self, start, length=1, window=-1):
         """KV positions a row must read whose last query sits at
         `start + length - 1`: everything up to and including it, clipped
         to the sliding window (-1: the model's uniform one, None: none;
         numpy-broadcasting)."""
         n = start + length
+        if self._sparse is not None:
+            # a selected read: top-k blocks, the last of them the query's
+            # own as far as it is filled
+            c, bs = self._sparse, self.kv_block_size
+            most = (c.sparse_topk - 1) * bs + (n - 1) % bs + 1
+            return np.where(n < c.sparse_dense_len, n, np.minimum(n, most))
         window = self._kv_window if window == -1 else window
         return n if window is None else np.minimum(n, window)
 
@@ -3219,6 +3286,14 @@ class ContinuousEngine:
         window = self._kv_window if window == -1 else window
         last = start + np.maximum(length, 1) - 1
         needed = np.clip(-(-(last + 1) // bs), 1, self._max_blocks)
+        if self._sparse is not None:
+            # the page list of the tile's LAST query: a decode row's own,
+            # the least a tile of several queries walks (the union of its
+            # queries' choices is the device's to know)
+            c = self._sparse
+            pages = np.where(last + 1 < c.sparse_dense_len, needed,
+                             np.minimum(needed, c.sparse_topk))
+            return np.where(length > 0, pages * bs, 0)
         first = 0 if window is None else np.minimum(
             np.maximum(start - window + 1, 0) // bs, needed - 1
         )
@@ -3231,6 +3306,9 @@ class ContinuousEngine:
         from ..ops.kv_quant import KVQuant
         from ..ops.paged_attention import walk_pages_per_step
 
+        if self._sparse is not None:  # a page list a KV head
+            return walk_pages_per_step(self.cache["k"], self.cfg.n_heads, tq,
+                                       self._max_blocks, listed=True)
         leaf = next(
             a for a in jax.tree.leaves(
                 self.cache, is_leaf=lambda a: isinstance(a, KVQuant))
@@ -3389,6 +3467,8 @@ class ContinuousEngine:
                 [(int(self._host_pos[b]), int(live[b]))
                  for b in np.flatnonzero(live)]
             )
+        if self._sparse is not None:
+            diff_fields.update(self._sparse_fields("chunk", (at + 1)[alive]))
         rec = self._launch_record(
             "chunk", K,
             **self._kv_fields(
@@ -3934,11 +4014,13 @@ class ContinuousEngine:
         if p0:
             self._m_ragged_exact.inc()
         if self._recurrent:
-            # the tail of the last shared block gives the state at p0
-            # back, so every token of the hit is restored; a cold start
-            # lets the slot with zeroed state
+            # the tail of the last shared block (or its snapshot) gives the
+            # state at p0 back, so every token of the hit is restored; a
+            # cold start lets the slot with zeroed state
             if p0:
                 self._m_state_tokens.inc(p0)
+            elif self._snap_pool:
+                self._m_lin_resets.inc()
             else:
                 self._m_conv_resets.inc()
         rp = float(k.get("repetition_penalty", 1.0))
@@ -3964,6 +4046,10 @@ class ContinuousEngine:
             # the pool's block size, never passes them)
             job.ids, job.prompt_len = ids[:whole], whole
             job.diffusion = diffusion
+        if self._snap_pool and self._bpx is not None:
+            if p0:  # (the lookup cut the hit to a block that has one)
+                job.snap_from = self._bpx.snap_of(shared[-1], pin=True)
+            job.snap_at = self._snap_points(p0, prompt_len)
         self._table[slot] = table_row
         self._table_dev = None
         self._slot_pages[slot] = req.adapter_page or 0
@@ -4197,13 +4283,31 @@ class ContinuousEngine:
                     (b, int(self._host_pos[b]), 1, P.RAGGED_DECODE)
                 )
         chunk_list = []
+        snaps_taken = 0
+        if self._snap_pool:
+            # (by slot: the snapshot a row starts from, and the one it leaves)
+            snap_restore = np.full((B,), -1, np.int32)
+            snap_take = np.full((B,), -1, np.int32)
         for job, n in plan:
             start = job.p0 + job.done
             first = self._recurrent and job.done == 0
-            entries.append((
-                job.slot, start, n,
-                P.RAGGED_FIRST if first else P.RAGGED_PREFILL,
-            ))
+            kind = P.RAGGED_FIRST if first else P.RAGGED_PREFILL
+            if self._snap_pool:
+                # a chunk ends where a snapshot is due, and the launch
+                # leaves the row's state there in the snapshot pool
+                due = min((at for at in job.snap_at if at > start),
+                          default=None)
+                if due is not None and start + n >= due:
+                    n = due - start
+                    take = self._bpx.snap_alloc()
+                    if take >= 0:
+                        job.snaps[due // self.kv_block_size - 1] = take
+                        snap_take[job.slot] = take
+                        snaps_taken += 1
+                if first and job.snap_from >= 0:
+                    snap_restore[job.slot] = job.snap_from
+                    self._bpx.snap_unpin(job.snap_from)
+            entries.append((job.slot, start, n, kind))
             chunk_list.append((job, n, start))
         meta, tok_row, tok_pos, offsets, stats = P.build_ragged_meta(
             entries, width=W, tile=tile,
@@ -4391,6 +4495,13 @@ class ContinuousEngine:
                 restored=sum(st for _, st in firsts),
                 resets=sum(1 for _, st in firsts if st == 0),
             )
+            if self._snap_pool:
+                diff_fields["state_snapshots_taken"] = snaps_taken
+        if self._sparse is not None:
+            diff_fields.update(self._sparse_fields(
+                "mixed", [int(self._host_pos[b]) + 1 for b in active
+                 if self._host_pos[b] < self._host_end[b]]
+                + [st + n for _, n, st in chunk_list]))
         rec = self._launch_record(
             "mixed", 1,
             **self._kv_fields(
@@ -4418,6 +4529,8 @@ class ContinuousEngine:
             jnp.asarray(dec_idx), arm,
             spec=spec_plan_dev, spec_toks=spec_toks_dev,
             dev=dev_dev, pages=pages_dev, **diffusion,
+            **({"snaps": (jnp.asarray(snap_restore), jnp.asarray(snap_take))}
+               if self._snap_pool else {}),
         )
         if Bd:
             *out, self._diff = out
@@ -4489,8 +4602,10 @@ class ContinuousEngine:
                 # of the same adapter may reuse them.
                 self._bpx.register(
                     job.ids, job.prompt_len, req.block_ids,
-                    adapter=req.adapter,
+                    adapter=req.adapter, snaps=job.snaps,
                 )
+                # (an index past the prompt's whole blocks found no block)
+                self._bpx.snap_release(job.snaps.values())
         if self._shadow is not None:
             # chunk crossed a block boundary -> those blocks are now
             # immutable; the capture gather dispatches BEHIND the mixed
@@ -5522,6 +5637,11 @@ class ContinuousEngine:
             job = self._prefilling.pop(slot, None)
             if job is not None and job in self._jobs:
                 self._jobs.remove(job)
+                if self._snap_pool and self._bpx is not None:
+                    # snapshots its launches wrote find no block now
+                    self._bpx.snap_release(job.snaps.values())
+                    if job.done == 0 and job.snap_from >= 0:
+                        self._bpx.snap_unpin(job.snap_from)
         if req.cart is not None:
             # refcount down; the slot's FSM row back to the free state so
             # the row is inert under any still-constrained chunk program

@@ -183,14 +183,18 @@ class SingleDeviceBackend:
     # routes through llama.default_attn_hook since round 5).
     @property
     def supports_paged(self):
-        return self.cfg.arch in ("llama", "gpt2", "mla_moe", "lfm2", "afmoe")
+        return self.cfg.arch in ("llama", "gpt2", "mla_moe", "lfm2", "afmoe",
+                                 "minicpm_sala")
 
-    def init_paged_pool(self, n_blocks, block_size, n_slots=None):
+    def init_paged_pool(self, n_blocks, block_size, n_slots=None,
+                        **snapshots):
         # n_slots: a model with recurrent layers keeps a state a slot
-        # beside the blocks (engine/paged.init_pool)
+        # beside the blocks (engine/paged.init_pool); n_snapshots: and a
+        # pool of states the prefix index restores
         from . import paged as P
 
-        return P.init_pool(self.cfg, n_blocks, block_size, n_slots=n_slots)
+        return P.init_pool(self.cfg, n_blocks, block_size, n_slots=n_slots,
+                           **snapshots)
 
     def decode_slots_paged(self, state, pool, table, key, sparams, *,
                            num_steps, pages=None, **diffusion):
@@ -250,14 +254,14 @@ class SingleDeviceBackend:
     def mixed_step_ragged(self, tokens, tok_row, tok_pos, dec_flag, meta,
                           pool, table, state, sparams, key, dec_idx, arm,
                           spec=None, spec_toks=None, dev=None, pages=None,
-                          **diffusion):
+                          snaps=None, **diffusion):
         from . import paged as P
 
         return P.mixed_step_ragged(
             self.cfg, self.params, tokens, tok_row, tok_pos, dec_flag,
             meta, pool, table, state, sparams, key, dec_idx, arm,
             spec=spec, spec_toks=spec_toks, dev=dev, pages=pages,
-            **diffusion,
+            snaps=snaps, **diffusion,
         )
 
     def lower_step(self, name: str, args: tuple, kwargs: dict):
@@ -1152,7 +1156,7 @@ class InferenceEngine:
         """
         t_start = time.time()
         trace = _trace if _trace is not None else Trace(request_id)
-        if self.cfg.conv_layers:
+        if self.cfg.recurrent:
             # a row's recurrent state lives in the paged fleet's pool
             return {
                 "error": f"Error: {self.cfg.name} keeps a recurrent state "

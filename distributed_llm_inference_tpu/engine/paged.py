@@ -86,7 +86,8 @@ GROUP_LEAVES = (("k", "v"), ("kw", "vw"))
 
 
 def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
-              n_layers: Optional[int] = None, n_slots: Optional[int] = None):
+              n_layers: Optional[int] = None, n_slots: Optional[int] = None,
+              n_snapshots: int = 0):
     """Zeroed block pool, stacked on the layer axis like the dense cache.
     Block 0 is the reserved trash block (never allocated to a slot).
     With cfg.kv_quant the pool leaves are KVQuant pytrees — int8 blocks
@@ -117,7 +118,47 @@ def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
     fills the block's last position. The physical block id is the tail's
     key, so allocator, refcounts, eviction and the prefix index carry it
     unchanged, and a prefix hit at depth p0 (whole blocks) starts the
-    slot's state from the tail of the last shared block: `StateRows`."""
+    slot's state from the tail of the last shared block: `StateRows`.
+
+    A model of sparse and linear attention layers (cfg.linear_layers,
+    models/minicpm_sala.py) keeps three kinds: "k" / "v" of its SPARSE
+    layers alone, with cfg.sparse_block tokens a block (a page of the
+    kernels' walk is a block of the selection); beside them a third leaf
+    of the same blocks, "ck" (a leaf a sparse layer,
+    [N x bs / stride, KV x Dh]), the compressed keys the selection scores
+    against, each with the block that holds its last token, so allocator,
+    refcounts, eviction and the prefix index carry it as they carry K/V;
+    and for the Ll linear layers "lin" (a leaf a layer,
+    [n_slots, Hl, Dh, Dh] FLOAT32), a slot's live matrix state, and "snap"
+    ([n_snapshots, Hl, Dh, Dh] a layer), the snapshot pool: a state is
+    2 MB a layer at 32 heads of 128, far too large to keep one a block as
+    lfm2's tails are kept, so the prefix index decides which block
+    boundaries have one (engine/block_prefix.py) and a hit is as deep as
+    the deepest that has."""
+    if cfg.linear_layers:
+        if n_slots is None:
+            raise ValueError(f"{cfg.name}: the pool holds a state a slot "
+                             f"(pass n_slots)")
+        if block_size != cfg.sparse_block:
+            raise ValueError(
+                f"{cfg.name}: a pool block is one block of the selection "
+                f"(pass a block size of {cfg.sparse_block}, not "
+                f"{block_size})")
+        Ls, Ll = len(cfg.attn_layers), len(cfg.linear_layers)
+        dt, Dh = cfg.jnp_dtype, cfg.head_dim
+        kv = (Ls, n_blocks, cfg.n_kv_heads, block_size, Dh)
+        state = (cfg.linear_heads, Dh, Dh)
+        rows = n_blocks * (block_size // cfg.sparse_stride)
+        return {
+            "k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt),
+            # (a leaf a layer: models/minicpm_sala.py says why)
+            "ck": tuple(jnp.zeros((rows, cfg.n_kv_heads * Dh), dt)
+                        for _ in range(Ls)),
+            "lin": tuple(jnp.zeros((n_slots,) + state, jnp.float32)
+                         for _ in range(Ll)),
+            "snap": tuple(jnp.zeros((max(1, n_snapshots),) + state,
+                                    jnp.float32) for _ in range(Ll)),
+        }
     if cfg.arch == "afmoe":
         # K/V in groups by layer kind, each with its own blocks and block
         # table (the module docstring's "Groups"); n_blocks: one count a
@@ -501,7 +542,7 @@ def refuse_unsupported_latent(cfg: ModelConfig, **asked):
     (runtime.create_backend: quant, kv_quant, mesh, lora, adapter_slots;
     the continuous engine: kv_shadow, unchunked); a dense per-head K/V
     model passes through."""
-    if not (cfg.latent_dim or cfg.moe_ffn_dim):
+    if not (cfg.latent_dim or cfg.moe_ffn_dim or cfg.linear_layers):
         return
     why = {
         "quant": "weight quantization: ops/quant knows no expert-bank or "
@@ -552,6 +593,30 @@ def refuse_unsupported_latent(cfg: ModelConfig, **asked):
                     "already be in a convolution layer's state",
             "no_pool": "a dense slot fleet: there is no dense recurrent "
                        "fleet (pass --kv-pool-blocks)",
+        })
+    if cfg.linear_layers:
+        lead = "a model of sparse and linear attention layers is served " \
+               "on one device from the paged pool by chunked ragged prefill"
+        why.update({
+            "quant": "weight quantization: ops/quant knows no leaf of "
+                     "either mixer",
+            "kv_quant": "the int8 pool: the selected read walks raw pages "
+                        "and the compressed keys have no scale",
+            "mesh": "pp / tp / ep / sp / dp meshes: layers of two kinds, "
+                    "the compressed-key leaf, a matrix state a slot and "
+                    "the snapshot pool are not partitioned "
+                    "(parallel/partition.py)",
+            "kv_shadow": "the host shadow store (and swap preemption, /kv "
+                         "export, the KV fabric): it copies K/V block "
+                         "pairs and would leave the compressed keys and "
+                         "the state snapshots behind; pass --no-kv-shadow",
+            "unchunked": "the unchunked ragged admission: a row's state "
+                         "rides the mixed launch's slot rows only",
+            "spec": "speculative decoding: a rejected draft token would "
+                    "already be in a linear layer's state",
+            "no_pool": "a dense slot fleet: there is no dense fleet of "
+                       "compressed keys and matrix states (pass "
+                       "--kv-pool-blocks)",
         })
     if len(cfg.kv_groups) > 1:
         lead = "a model with window and global layers is served on one " \
@@ -679,6 +744,33 @@ def _gather_blocks_of(leaf, layer, ids):
     return leaf[layer, ids]
 
 
+def _chosen_positions(pages, MB: int, bs: int):
+    """A selected read's page list (ops/paged_attention: plist [N, KV, L],
+    count [N, KV] or None, chosen_at [N, KV, L] or None, a query each) as a
+    mask over the row's logical positions [N, KV, MB x bs]: the gather
+    path's form of the list."""
+    plist, count, chosen_at = pages
+    if chosen_at is None:
+        chosen_at = jnp.arange(plist.shape[-1]) < count[..., None]
+    N, KV, _ = plist.shape
+    blocks = jnp.zeros((N, KV, MB + 1), bool).at[
+        jnp.arange(N)[:, None, None], jnp.arange(KV)[None, :, None],
+        jnp.where(chosen_at, plist, MB)].set(True)[..., :MB]
+    return jnp.repeat(blocks, bs, axis=-1)
+
+
+def _attend_chosen(cfg, q, keys, values, mask):
+    """The gather path's attention under a mask a KV head: q [N, 1, H, Dh],
+    keys / values [N, KV, S, Dh], mask [N, KV, 1, S]."""
+    KV = keys.shape[1]
+    group = q.shape[2] // KV
+    return jnp.concatenate([
+        attend(q[:, :, h * group:(h + 1) * group], keys[:, h:h + 1],
+               values[:, h:h + 1], mask[:, h], scale=cfg.query_scale,
+               softcap=cfg.attn_softcap)
+        for h in range(KV)], axis=2)
+
+
 def make_paged_hook(table: jnp.ndarray, active=None):
     """attn_hook for a decode step over a paged pool (llama family, gpt2,
     mla_moe).
@@ -704,7 +796,7 @@ def make_paged_hook(table: jnp.ndarray, active=None):
     """
 
     def hook(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
-             valid_start, window_flag=None, layer=None):
+             valid_start, window_flag=None, layer=None, pages=None):
         del valid_start  # slots never left-pad
         # window_flag (mixed per-layer patterns): the XLA gather path
         # ignores it — decoder_layer resolved `mask` per layer already —
@@ -750,6 +842,7 @@ def make_paged_hook(table: jnp.ndarray, active=None):
             return _kernel_step(
                 lambda pool_k, pool_v, write: paged_flash_attend(
                     q, pool_k, pool_v, table, pos, wd, active, write,
+                    None if pages is None else pages[:2],
                     window=w, scale=cfg.query_scale,
                     softcap=None if v is None else cfg.attn_softcap,
                     value_dim=cfg.kv_lora_rank if v is None else None,
@@ -777,6 +870,13 @@ def make_paged_hook(table: jnp.ndarray, active=None):
             g = _gather_blocks_of(leaf, layer, table)  # [B, MB, KV, bs, Dh]
             return g.transpose(0, 2, 1, 3, 4).reshape(B, KV_, MB * bs, Dh)
 
+        if pages is not None:  # a selected read: the mask is the list's
+            kv_pos = jnp.arange(MB * bs, dtype=jnp.int32)
+            causal = (kv_pos[None, :] <= pos[:, None])[:, None, None]
+            attn = _attend_chosen(
+                cfg, q, gathered(new_k), gathered(new_v),
+                causal & _chosen_positions(pages, MB, bs)[:, :, None])
+            return attn, new_k, new_v
         attn = attend(
             q, gathered(new_k), gathered(new_v), mask,
             scale=cfg.query_scale, softcap=cfg.attn_softcap,
@@ -784,6 +884,7 @@ def make_paged_hook(table: jnp.ndarray, active=None):
         return attn, new_k, new_v
 
     hook.paged = True  # forward_layers carries the stacked pool (above)
+    hook.tile = 1  # queries a tile of the walk: a decode row's one
     hook.live = active  # rows routed experts compute for (models/mla_moe)
     hook.rows = functools.partial(_decode_rows, table, active)
     # a grouped pool's launch carries its groups' tables side by side
@@ -1241,6 +1342,13 @@ class StateRows(NamedTuple):
     table: jnp.ndarray  # i32 [R, MB]: the rows' block tables
     fresh: jnp.ndarray  # bool [R]: the row starts a tenant in this launch
     start: jnp.ndarray  # i32 [R]: at this position (read where fresh)
+    # a state too large to keep a block (models/minicpm_sala.py: "snap"): the
+    # snapshot a fresh row starts from after a prefix hit (-1: zeros), and
+    # the one the row's state after this launch is kept in (-1: none): the
+    # mixed launch's `snaps` operand; None in a decode chunk, which starts no
+    # tenant and keeps no snapshot
+    restore: Optional[jnp.ndarray] = None  # i32 [R]
+    take: Optional[jnp.ndarray] = None  # i32 [R]
 
 
 def _decode_rows(table, active) -> StateRows:
@@ -1416,7 +1524,7 @@ def _ragged_latent_xla(cfg, q, pool_c, layer, table, tok_row, tok_pos):
     return latent_attend(cfg, q, rows, mask[:, None, :])
 
 
-def make_ragged_fill_hook(table, meta, tok_row):
+def make_ragged_fill_hook(table, meta, tok_row, snaps=None):
     """attn_hook for the ragged ingest programs: flat-token layout
     ([W, 1] chunks — each token is a batch row at its own position, the
     slots-mode contract), per-token K/V scatter into the owning row's
@@ -1427,11 +1535,13 @@ def make_ragged_fill_hook(table, meta, tok_row):
     table [R, MB]: the launch's fleet rows' block tables; meta [G, 4]:
     the per-tile launch plan (build_ragged_meta); tok_row [W]: per-token
     owning row, -1 for launch padding — padding writes are redirected to
-    the write-only TRASH block, exactly like ungated pp microsteps.
+    the write-only TRASH block, exactly like ungated pp microsteps; snaps
+    (restore [R], take [R]): StateRows' snapshot indices, for a fleet
+    whose state has a snapshot pool.
     """
 
     def hook(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
-             valid_start, window_flag=None, layer=None):
+             valid_start, window_flag=None, layer=None, pages=None):
         del mask, valid_start  # mask derived from pos/tok_row in-kernel
         W, T = q.shape[0], q.shape[1]
         assert T == 1, "ragged fill runs the flat token layout (T=1 rows)"
@@ -1459,7 +1569,7 @@ def make_ragged_fill_hook(table, meta, tok_row):
 
             def kernel(pool_k, pool_v, write):
                 out = ragged_paged_attend(
-                    q[:, 0], pool_k, pool_v, table, meta, wd, write,
+                    q[:, 0], pool_k, pool_v, table, meta, wd, write, pages,
                     window=w, scale=cfg.query_scale,
                     softcap=None if v is None else cfg.attn_softcap,
                     value_dim=cfg.kv_lora_rank if v is None else None,
@@ -1476,6 +1586,22 @@ def make_ragged_fill_hook(table, meta, tok_row):
         if v is None:
             attn = _ragged_latent_xla(cfg, q, new_k, layer, table, tok_row,
                                       pos)
+        elif pages is not None:
+            rows_tab = table[rows_ix]  # [W, MB]
+            KV_, S = cache_k.shape[2], MB * bs
+
+            def gathered(leaf):
+                g = _gather_blocks_of(leaf, layer, rows_tab)
+                return g.transpose(0, 2, 1, 3, 4).reshape(W, KV_, S, -1)
+
+            kv_pos = jnp.arange(S, dtype=jnp.int32)
+            causal = ((kv_pos[None, :] <= pos[:, None])
+                      & (tok_row >= 0)[:, None])[:, None, None]
+            attn = _attend_chosen(
+                cfg, q, gathered(new_k), gathered(new_v),
+                causal & _chosen_positions(
+                    (jnp.repeat(pages[0], W // meta.shape[0], axis=0),
+                     None, pages[2]), MB, bs)[:, :, None])
         else:
             attn = _ragged_attend_xla(
                 cfg, q, new_k, new_v, layer, table, tok_row, pos, window_flag
@@ -1483,8 +1609,11 @@ def make_ragged_fill_hook(table, meta, tok_row):
         return attn, new_k, new_v
 
     hook.paged = True  # forward_layers carries the stacked pool
+    hook.tile = tok_row.shape[0] // meta.shape[0]
     hook.live = tok_row >= 0  # launch padding reaches no routed expert
-    hook.rows = functools.partial(_ragged_rows, table, meta, tok_row)
+    rows = functools.partial(_ragged_rows, table, meta, tok_row)
+    hook.rows = rows if snaps is None else (
+        lambda: rows()._replace(restore=snaps[0], take=snaps[1]))
     hook.group = lambda g, n: make_ragged_fill_hook(
         _group_table(table, g, n), meta, tok_row)
     return hook
@@ -1826,7 +1955,7 @@ def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
                       spec: Optional[SpecPlan] = None, spec_toks=None,
                       dev: Optional[DeviceMeta] = None, pages=None,
                       diff: Optional[DiffState] = None,
-                      darm: Optional[DiffState] = None):
+                      darm: Optional[DiffState] = None, snaps=None):
     """One scheduler step: advance every active slot one decode token AND
     write the launch's prefill chunks into the pool, in one program.
 
@@ -1875,6 +2004,11 @@ def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
     in this launch; `diffusion_step` takes slot_step's place and a
     completing prefill samples no first token (`diffusion_epilogue`).
 
+    snaps ((restore, take), i32 [B] each; a model of linear attention
+    layers): by slot, the snapshot a row that starts a tenant in this launch
+    starts from (-1: zeros) and the one its state after the launch is kept
+    in (-1: none): `StateRows.restore` / `.take`.
+
     Returns (packed int32 — [5, B] plain, [5 + 2*(K+1) + 1, B] with
     spec: emitted / emit_mask / active / firsts / armed [/ spec_emit /
     spec_mask / position advance], ONE fetch per step — state, sparams,
@@ -1912,9 +2046,11 @@ def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
         ended = dev.tile_on & ~state.active[jnp.maximum(meta[:, 0], 0)]
         meta = meta.at[:, 2].set(jnp.where(ended, 0, meta[:, 2]))
     x = M.embed(cfg, params, toks[:, None], pos)
+    if cfg.linear_layers and snaps is None:  # restores none, keeps none
+        snaps = (jnp.full((table.shape[0],), -1, jnp.int32),) * 2
     x, pool = M.forward_layers(
         cfg, params["layers"], x, pool, pos,
-        attn_hook=make_ragged_fill_hook(table, meta, tok_row),
+        attn_hook=make_ragged_fill_hook(table, meta, tok_row, snaps),
         attn_seq_len=1, lora_pages=_token_pages(pages, tok_row),
     )
     if cfg.diffusion_block:

@@ -188,7 +188,7 @@ class PrefillJob:
     __slots__ = (
         "req", "ids", "p0", "done", "prompt_len", "max_tokens", "slot",
         "sampling", "presence_row", "table_row", "cls", "diffusion",
-        "registered",
+        "registered", "snap_from", "snap_at", "snaps",
     )
 
     def __init__(self, req, ids, p0, prompt_len, max_tokens, slot, sampling,
@@ -212,6 +212,14 @@ class PrefillJob:
         # a grouped pool registers the prompt chunk by chunk: where the
         # prefix index's walk goes on (BlockPrefixIndex.register's resume)
         self.registered = None
+        # a fleet with a snapshot pool (engine/block_prefix.py): the
+        # snapshot the row's first chunk restores (-1: none, zeros), the
+        # positions at which a chunk must end so that the launch leaves the
+        # state there in a snapshot, and {logical block: index} of those
+        # written so far
+        self.snap_from = -1
+        self.snap_at = ()
+        self.snaps = {}
 
     @property
     def remaining(self) -> int:

@@ -14,7 +14,12 @@ attention layers alone and a recurrent state a row beside them, and serves
 one device from the paged pool only; afmoe (models/afmoe.py) mixes
 sliding-window and global gated attention layers in one unscanned stack,
 holds one chip's share of the routed experts where the configuration says
-so, and serves one device from a pool grouped by layer kind. Routed experts
+so, and serves one device from a pool grouped by layer kind; minicpm_sala
+(models/minicpm_sala.py) alternates sparse attention layers, which read the
+blocks a score over mean-pooled keys selects, with decayed linear-attention
+layers, keeps K/V and compressed keys for the first and a float32 matrix
+state a row for the second, and serves one device from the paged pool only.
+Routed experts
 are one module for the families that have them (models/experts.py: `route`, `routed_ffn`, the
 grouped product): a configuration that routes serves one device, from the
 paged pool (engine/paged.refuse_unsupported_latent).
@@ -23,10 +28,10 @@ paged pool (engine/paged.refuse_unsupported_latent).
 from __future__ import annotations
 
 from ..config import ModelConfig
-from . import afmoe, gpt2, lfm2, llama, mla_moe
+from . import afmoe, gpt2, lfm2, llama, minicpm_sala, mla_moe
 
 _FAMILIES = {"llama": llama, "gpt2": gpt2, "mla_moe": mla_moe, "lfm2": lfm2,
-             "afmoe": afmoe}
+             "afmoe": afmoe, "minicpm_sala": minicpm_sala}
 
 
 def family(cfg: ModelConfig):

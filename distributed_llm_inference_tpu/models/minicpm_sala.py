@@ -1,0 +1,559 @@
+"""Sparse attention beside decayed linear attention (openbmb/MiniCPM-SALA,
+model_type minicpm_sala) in pure JAX.
+
+Layers of two kinds alternate in one stack (cfg.layer_types), so the layers
+are not scanned: a Python loop over the pattern, each layer reading its own
+row of the stacked leaves of its kind. RMSNorm eps cfg.norm_eps everywhere;
+x a layer's input, D = cfg.dim, r = cfg.residual_multiplier (the family's
+scale_depth / sqrt(the PUBLISHED depth)):
+
+  embed     table[token] x cfg.embed_multiplier (scale_emb)
+  layer l   h = x + r Mixer_l(RMSNorm_op(x));  y = h + r SwiGLU(RMSNorm_ffn(h))
+  head      RMSNorm, the untied table, / cfg.logits_divider
+
+  Mixer, "lightning-attn" (Hl = cfg.linear_heads heads of Dh):
+            q, k, v = u wq, u wk, u wv; RMSNorm with a weight on every q and
+            k head; RoPE (half-rotation) on q, k; q x Dh^-0.5; per head
+            S_t = a_h S_{t-1} + k_t^T v_t (S [Dh, Dh] FLOAT32), o_t = q_t S_t
+            (ops/linear_attention.py); Mixer = (RMSNorm(o) * sigmoid(u wg)) wo,
+            the output norm over the heads side by side.
+            STATE a row and layer: S, [Hl, Dh, Dh] float32.
+  Mixer, "minicpm4" (H query heads, KV key/value heads of Dh, NO rotary):
+            the same per-head RMSNorm on q and k; a query at position t
+            (n = t + 1 positions visible) reads every position where
+            n < cfg.sparse_dense_len, and otherwise the cfg.sparse_topk
+            blocks of cfg.sparse_block tokens that `select_blocks` chooses
+            for its KV head; causal softmax at Dh^-0.5 over what it reads;
+            Mixer = (o * sigmoid(u wg)) wo.
+            CACHE: K/V, and the compressed keys the selection scores
+            against: c = mean of cfg.sparse_kernel consecutive keys, one
+            every cfg.sparse_stride tokens, a key head each.
+
+The selection (`select_blocks`), per query and KV head: p^h = softmax over
+the compressed keys whose tokens all lie at or before t of q^h . c / sqrt(Dh);
+r = the sum of p^h over the KV head's query heads; a block's score is the
+largest r among the compressed keys whose tokens overlap it; the first
+cfg.sparse_init_blocks blocks and the blocks that hold the last
+cfg.sparse_window positions score +inf; the sparse_topk highest are read,
+ties to the lower block.
+
+The family is served from the paged pool alone (engine/paged.py), with
+cfg.sparse_block tokens a pool block, so that a page of the paged kernels'
+walk is one block of the selection. The pool's leaves:
+  "k" / "v"  [Ls, N, KV, bs, Dh]      the sparse layers' K/V
+  "ck"       a leaf a sparse layer, [N x bs / stride, KV x Dh] (a row the KV
+             heads' keys side by side: whole lanes, rows a gather reads whole):
+             the compressed key whose LAST token is position e (e % stride ==
+             stride - 1) sits with the block that holds e, at row
+             block x bs / stride + (e % bs) // stride: every token it covers
+             lies at or before its block's end, so a block shared by the
+             prefix index brings its compressed keys with it, and the launch
+             that writes e has all of them (this launch's keys, or the
+             pool's)
+  "lin"      a leaf a linear layer, [slots, Hl, Dh, Dh] float32: a slot's
+             live state
+  "snap"     a leaf a linear layer, [snapshots, Hl, Dh, Dh] float32: states
+             kept at block boundaries, which a prefix hit starts a row from
+             (engine/paged.StateRows.restore / .take)
+A leaf a layer (a tuple of them), and not one stacked over the layers as K/V
+are for the kernels: a step replaces a layer's state whole, and an update in
+place of one layer of a stacked leaf made the decode loop copy the leaf.
+
+Params pytree (L layers, Ls / Ll sparse / linear layers, F ffn_dim, V vocab):
+  embed [V, D]   lm_head [V, D]   final_norm [D]
+  layers: op_norm ffn_norm [L, D]
+    sparse: w_in [Ls, D, (2 H + 2 KV) Dh] = [wq | wg | wk | wv]
+            wo [Ls, H*Dh, D]  q_norm k_norm [Ls, Dh]
+    linear: w_in [Ll, D, 4 Hl*Dh] = [wq | wk | wv | wg]  wo [Ll, Hl*Dh, D]
+            q_norm k_norm [Ll, Dh]  o_norm [Ll, Hl*Dh]
+    ffn:    w_gate w_up [L, D, F]  w_down [L, F, D]
+each leaf of a kind a TUPLE of its layers' arrays (the shapes above without
+their first axis): the stack is not scanned, and a layer's slice of a
+stacked matrix inside the decode chunk's loop cost a copy of the stack. A
+mixer's input projections are drawn one by one (`leaf_shapes`, LEAF_KEYS:
+wq, wk, wv, wg) and held side by side as ONE matrix `w_in`: one product a
+mixer, and the decode chunk relaid each [D, D] projection out once a launch
+where it leaves a [D, 4 D] one alone.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from ..config import ModelConfig
+from ..ops.linear_attention import linear_attend_rows, linear_attend_step
+from ..ops.norms import rms_norm
+from ..ops.rope import apply_rope, rope_cos_sin
+from .experts import _normal_slices
+from .mla_moe import swiglu
+
+Params = dict
+F32 = jnp.float32
+
+# init_params' key of each drawn leaf: an index into split(key, 24)
+# (cellbench/reference/sparse_linear_hybrid.py writes the same table down)
+LEAF_KEYS = {
+    "embed": 0, "lm_head": 1,
+    "sparse.wq": 2, "sparse.wk": 3, "sparse.wv": 4, "sparse.wo": 5,
+    "sparse.wg": 6,
+    "linear.wq": 7, "linear.wk": 8, "linear.wv": 9, "linear.wo": 10,
+    "linear.wg": 11,
+    "ffn.w_gate": 12, "ffn.w_up": 13, "ffn.w_down": 14,
+}
+
+
+# a mixer's input projections in the order `w_in` holds them
+W_IN = {"sparse": ("wq", "wg", "wk", "wv"), "linear": ("wq", "wk", "wv", "wg")}
+
+
+def stack_depths(cfg: ModelConfig) -> dict:
+    return {"sparse": len(cfg.attn_layers), "linear": len(cfg.linear_layers)}
+
+
+def leaf_shapes(cfg: ModelConfig) -> dict:
+    """{leaf path: (shape, init scale or None for ones)}, stacked leaves
+    with their layer axis first."""
+    D, V, F, L = cfg.dim, cfg.vocab_size, cfg.ffn_dim, cfg.n_layers
+    H, KV, Dh, Hl = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.linear_heads
+    n = stack_depths(cfg)
+    Ls, Ll = n["sparse"], n["linear"]
+    s = D ** -0.5
+    return {
+        "embed": ((V, D), 0.02), "lm_head": ((V, D), s),
+        "final_norm": ((D,), None),
+        "op_norm": ((L, D), None), "ffn_norm": ((L, D), None),
+        "sparse.wq": ((Ls, D, H * Dh), s), "sparse.wk": ((Ls, D, KV * Dh), s),
+        "sparse.wv": ((Ls, D, KV * Dh), s),
+        "sparse.wo": ((Ls, H * Dh, D), (H * Dh) ** -0.5),
+        "sparse.wg": ((Ls, D, H * Dh), s),
+        "sparse.q_norm": ((Ls, Dh), None), "sparse.k_norm": ((Ls, Dh), None),
+        "linear.wq": ((Ll, D, Hl * Dh), s), "linear.wk": ((Ll, D, Hl * Dh), s),
+        "linear.wv": ((Ll, D, Hl * Dh), s),
+        "linear.wo": ((Ll, Hl * Dh, D), (Hl * Dh) ** -0.5),
+        "linear.wg": ((Ll, D, Hl * Dh), s),
+        "linear.q_norm": ((Ll, Dh), None), "linear.k_norm": ((Ll, Dh), None),
+        "linear.o_norm": ((Ll, Hl * Dh), None),
+        "ffn.w_gate": ((L, D, F), s), "ffn.w_up": ((L, D, F), s),
+        "ffn.w_down": ((L, F, D), F ** -0.5),
+    }
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _normal(key, shape, scale, dtype):
+    return (jax.random.normal(key, shape, F32) * scale).astype(dtype)
+
+
+def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
+    """Seeded random parameters (tests and benchmarks): a stacked shape
+    [n, ...] of `leaf_shapes` is n arrays, array i a scaled normal drawn from
+    split(key, n)[i] in float32 and rounded to the dtype (the slices
+    models/experts._normal_slices draws as one leaf); norm weights 1; the
+    two vocabulary tables 8 such slices of rows each where the vocabulary
+    divides."""
+    if cfg.tie_embeddings:
+        raise ValueError(f"{cfg.name}: the minicpm_sala family's head is "
+                         f"untied")
+    dt = cfg.jnp_dtype
+    ks = jax.random.split(key, 24)
+    layers: Params = {"sparse": {}, "linear": {}, "ffn": {}}
+    params: Params = {"layers": layers}
+    for path, (shape, scale) in leaf_shapes(cfg).items():
+        kind, _, name = path.rpartition(".")
+        if scale is None:
+            leaf = jnp.ones(shape, dt)
+            if kind:
+                leaf = tuple(leaf)
+        elif kind:
+            keys = jax.random.split(ks[LEAF_KEYS[path]], shape[0])
+            leaf = tuple(_normal(keys[i], shape[1:], float(scale), dt)
+                         for i in range(shape[0]))
+        else:
+            cut = 8 if shape[0] % 8 == 0 else 1
+            leaf = _normal_slices(
+                ks[LEAF_KEYS[path]], scale=float(scale),
+                shape=(cut, shape[0] // cut) + shape[1:], dtype=dt,
+            ).reshape(shape)
+        if kind:
+            layers[kind][name] = leaf
+        elif name in ("embed", "lm_head", "final_norm"):
+            params[name] = leaf
+        else:
+            layers[name] = leaf
+    for kind, order in W_IN.items():  # the input projections side by side
+        drawn = [layers[kind].pop(name) for name in order]
+        layers[kind]["w_in"] = tuple(
+            jnp.concatenate(parts, axis=1) for parts in zip(*drawn))
+    return params
+
+
+def init_kv_cache(cfg: ModelConfig, batch: int, max_seq: Optional[int] = None,
+                  n_layers: Optional[int] = None):
+    raise ValueError(
+        f"{cfg.name}: the minicpm_sala family is served from the paged pool "
+        f"by the continuous engine only (--continuous N --kv-pool-blocks M): "
+        f"there is no dense cache of compressed keys and matrix states"
+    )
+
+
+@jax.named_scope("embed")
+def embed(cfg: ModelConfig, params: Params, tokens, pos=0):
+    """[B, T] -> [B, T, D], float32: the residual stream's dtype."""
+    del pos
+    return params["embed"][tokens].astype(F32) * (cfg.embed_multiplier or 1.0)
+
+
+@jax.named_scope("head")
+def unembed(cfg: ModelConfig, params: Params, x):
+    """The last RMSNorm and the untied table: float32 logits."""
+    h = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(cfg.jnp_dtype)
+    logits = jax.lax.dot_general(
+        h, params["lm_head"], (((h.ndim - 1,), (1,)), ((), ())),
+        preferred_element_type=F32,
+    )
+    return logits / (cfg.logits_divider or 1.0)
+
+
+# -- the selection ------------------------------------------------------------
+
+
+def ck_slots(cfg: ModelConfig) -> int:
+    """Compressed keys a pool block holds (those whose last token it holds)."""
+    return cfg.sparse_block // cfg.sparse_stride
+
+
+def select_blocks(cfg: ModelConfig, q, ck, pos):
+    """Which blocks each query reads. q [G, tq, KV, group, Dh] (normed, not
+    scaled): G tiles of tq queries, a tile one row's; ck
+    [G, KV, MB x slots, Dh]: the compressed keys of the tile's row by the
+    block and slot that hold them (module docstring); pos [G, tq] the
+    queries' positions. Returns chosen [G, tq, KV, MB] bool: block b holds
+    a position the query's KV head reads (every block up to the query's own
+    where fewer than cfg.sparse_dense_len positions are visible). Scores,
+    softmax and sums in float32: a near-tie decides which block is read."""
+    G, tq, KV = q.shape[:3]
+    bs, stride, kernel = cfg.sparse_block, cfg.sparse_stride, cfg.sparse_kernel
+    slots = bs // stride
+    MB = ck.shape[2] // slots
+    blocks = jnp.arange(MB, dtype=jnp.int32)
+    visible = blocks <= (pos // bs)[..., None]  # [G, tq, MB]
+    # the compressed key at (block b, slot s) ends at b * bs + s * stride +
+    # stride - 1 and covers `kernel` tokens
+    end = (blocks[:, None] * bs
+           + jnp.arange(slots, dtype=jnp.int32)[None, :] * stride
+           + stride - 1)  # [MB, slots]
+    valid = (end <= pos[..., None, None]) & (end >= kernel - 1)
+    # (the keys stay on one flat axis J = MB x slots through the softmax: a
+    # minor axis of `slots` numbers would fill 4 of a tile's 128 lanes)
+    J = MB * slots
+    ok = valid.reshape(G, tq, 1, 1, J)
+    s = jnp.einsum("gtkhd,gkjd->gtkhj", q, ck.astype(q.dtype),
+                   preferred_element_type=F32) * cfg.head_dim ** -0.5
+    s = jnp.where(ok, s, -jnp.inf)
+    top = jnp.max(s, axis=-1, keepdims=True)
+    e = jnp.where(ok, jnp.exp(s - jnp.where(jnp.isfinite(top), top, 0.0)),
+                  0.0)
+    total = jnp.sum(e, axis=-1, keepdims=True)
+    r = jnp.sum(e / jnp.where(total > 0, total, 1.0), axis=3)  # [G,tq,KV,J]
+    r = jnp.where(ok[:, :, 0], r, -jnp.inf).reshape(G, tq, KV, MB, slots)
+    # a block's score: the keys that end in it, and those that end in the
+    # next block's first slots but begin in this one
+    score = jnp.max(r, axis=4)
+    over = (kernel - 1) // stride
+    if over:
+        nxt = jnp.max(r[..., :over], axis=4)
+        nxt = jnp.concatenate(
+            [nxt[..., 1:], jnp.full_like(nxt[..., :1], -jnp.inf)], axis=3)
+        score = jnp.maximum(score, nxt)
+    forced = (blocks < cfg.sparse_init_blocks) | (
+        blocks >= (jnp.maximum(pos - (cfg.sparse_window - 1), 0)
+                   // bs)[..., None])
+    score = jnp.where(forced[:, :, None], jnp.inf, score)
+    score = jnp.where(visible[:, :, None], score, -jnp.inf)
+    # -inf scores (blocks past the query) may fill the k where few blocks
+    # are visible: `visible` cuts them again
+    picked = top_mask(score, min(cfg.sparse_topk, MB))  # [G, tq, KV, MB]
+    dense = (pos + 1 < cfg.sparse_dense_len)[..., None, None]
+    return jnp.where(dense, True, picked) & visible[:, :, None]
+
+
+def top_mask(score, k: int):
+    """The k largest of score [..., n] float32 along its last axis as a bool
+    mask, equal scores to the lower index: what `lax.top_k` picks, without
+    its sort (0.8 ms a layer of a mixed step on the chip). The k-th largest
+    value is found bit by bit on the floats' order-preserving integer keys
+    (32 counts of "how many are at least this"); everything above it is in,
+    and of its equals the first few by index."""
+    bits = jax.lax.bitcast_convert_type(score, jnp.int32)
+    # a float's bits as an unsigned key of the same order (-inf lowest)
+    key = jax.lax.bitcast_convert_type(
+        jnp.where(bits < 0, ~bits, bits ^ jnp.int32(-2 ** 31)), jnp.uint32)
+
+    def bit(i, t):
+        cand = t | jnp.left_shift(jnp.uint32(1), jnp.uint32(31) - i.astype(
+            jnp.uint32))
+        enough = jnp.sum(key >= cand[..., None], axis=-1) >= k
+        return jnp.where(enough, cand, t)
+
+    kth = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros(score.shape[:-1], jnp.uint32))[..., None]
+    above = key > kth
+    equal = key == kth
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    return above | (equal & (jnp.cumsum(equal, axis=-1) <= room))
+
+
+def page_lists(chosen, width: int):
+    """The paged kernels' operand of a selected read: chosen
+    [G, tq, KV, MB] bool -> (plist [G, KV, width] int32: the logical pages
+    some query of the tile chose, ascending, count [G, KV] how many, and
+    chosen_at [G * tq, KV, width] bool: the query chose list entry l).
+    width: a static bound on a tile's union, a multiple of 128."""
+    G, tq, KV, MB = chosen.shape
+    union = jnp.any(chosen, axis=1)  # [G, KV, MB]
+    count = jnp.minimum(jnp.sum(union, axis=-1), width).astype(jnp.int32)
+    # list entry l is the chosen page of rank l (ascending): its one-hot row
+    # over the pages, with no sort and no gather (each took milliseconds of
+    # a mixed step on the chip)
+    rank = jnp.cumsum(union, axis=-1, dtype=jnp.int32) - 1
+    entry = union[:, :, None, :] & (
+        rank[:, :, None, :] == jnp.arange(width, dtype=jnp.int32)[:, None]
+    )  # [G, KV, width, MB]
+    plist = jnp.sum(jnp.where(entry, jnp.arange(MB, dtype=jnp.int32), 0),
+                    axis=-1)
+    chosen_at = jnp.einsum("gtkb,gklb->gtkl", chosen.astype(jnp.bfloat16),
+                           entry.astype(jnp.bfloat16),
+                           preferred_element_type=F32) > 0.5
+    return plist, count, chosen_at.reshape(G * tq, KV, width)
+
+
+def list_width(cfg: ModelConfig, tq: int, MB: int) -> int:
+    """The bound `page_lists` is given: a tile of tq queries reads at most
+    tq x sparse_topk pages past the dense length and every page below it."""
+    most = max(tq * cfg.sparse_topk,
+               -(-cfg.sparse_dense_len // cfg.sparse_block))
+    return -(-min(most, MB) // 128) * 128
+
+
+@jax.named_scope("sparse_select")
+def compressed_keys(cfg: ModelConfig, k, pool_k, pool_ck, layer: int, rows,
+                    pos, tq: int):
+    """Write the compressed keys that END at this launch's tokens and gather
+    each query tile's row's. k [W, KV, Dh]: the launch's new keys (normed);
+    pool_k [Ls, N, KV, bs, Dh] does not hold them yet (`layer` this layer's
+    index in it), pool_ck [N x slots, KV x Dh] this layer's leaf. A key
+    window's tokens are this launch's
+    (side by side on the flat axis where they are the same row's) or older
+    ones of the row, read from the pool's current and previous block.
+    Returns (pool_ck, ck [G, KV, MB x slots, Dh] by query tile of tq tokens:
+    a tile's tokens are one row's)."""
+    W = k.shape[0]
+    bs, stride, kernel = cfg.sparse_block, cfg.sparse_stride, cfg.sparse_kernel
+    tok_row, table = rows.tok_row, rows.table
+    MB = table.shape[1]
+    live = tok_row >= 0
+    rix = jnp.maximum(tok_row, 0)
+    lblk = jnp.minimum(pos // bs, MB - 1)
+    cur = table[rix, lblk]
+    prev = table[rix, jnp.maximum(lblk - 1, 0)]
+    ends = live & (pos % stride == stride - 1) & (pos >= kernel - 1)
+    # the two blocks' keys, oldest first: [W, 2 bs, KV, Dh]
+    old = jnp.concatenate(
+        [pool_k[layer, prev], pool_k[layer, cur]], axis=2
+    ).transpose(0, 2, 1, 3).astype(F32)
+    back = jnp.arange(kernel, dtype=jnp.int32)
+    # the token j back: this launch's where it is the same row's (a row's
+    # tokens lie side by side on the flat axis), else the pool's
+    at = (bs + pos % bs)[:, None] - back[None, :]  # in `old`: >= 0
+    was = jnp.take_along_axis(old, at[:, :, None, None], axis=1)
+    flat = jnp.arange(W, dtype=jnp.int32)[:, None] - back[None, :]
+    same = live[:, None] & (flat >= 0) & (
+        tok_row[jnp.maximum(flat, 0)] == tok_row[:, None])
+    now = k.astype(F32)[jnp.maximum(flat, 0)]  # [W, kernel, KV, Dh]
+    total = jnp.sum(jnp.where(same[:, :, None, None], now, was), axis=1)
+    mean = total / kernel
+    slots = bs // stride
+    at = jnp.where(ends, cur * slots + (pos % bs) // stride,
+                   pool_ck.shape[0])  # out of range: dropped
+    pool_ck = pool_ck.at[at].set(
+        mean.reshape(W, -1).astype(pool_ck.dtype), mode="drop")
+    tile_row = jnp.maximum(jnp.max(tok_row.reshape(W // tq, tq), axis=1), 0)
+    held = (table[tile_row][:, :, None] * slots
+            + jnp.arange(slots, dtype=jnp.int32)).reshape(W // tq, MB * slots)
+    KV, Dh = k.shape[1:]
+    return pool_ck, pool_ck[held].reshape(
+        W // tq, MB * slots, KV, Dh).transpose(0, 2, 1, 3)
+
+
+# -- the mixers ---------------------------------------------------------------
+
+
+def _put(leaves: tuple, i: int, leaf) -> tuple:
+    return leaves[:i] + (leaf,) + leaves[i + 1:]
+
+
+def _project(cfg, lp, h, kind: str, heads_q: int, heads_k: int):
+    """(q, k, v normed where the family norms them, each [W, 1, heads, Dh],
+    and the gate's logits [W, heads_q x Dh] float32) of normed h [W, D]: one
+    product with `w_in`, cut in W_IN's order."""
+    W, Dh = h.shape[0], cfg.head_dim
+    width = {"wq": heads_q, "wg": heads_q, "wk": heads_k, "wv": heads_k}
+    cuts, at = {}, 0
+    # (handed on as it is: a slice straight after the product is moved
+    # through the dot onto the weight, and each part's product then reads the
+    # whole matrix again: models/llama.pin_products)
+    out = jax.lax.optimization_barrier(
+        jnp.dot(h, lp["w_in"], preferred_element_type=F32))
+    for name in W_IN[kind]:
+        cuts[name] = out[:, at:at + width[name] * Dh]
+        at += width[name] * Dh
+    dt = cfg.jnp_dtype
+    q = cuts["wq"].astype(dt).reshape(W, 1, heads_q, Dh)
+    k = cuts["wk"].astype(dt).reshape(W, 1, heads_k, Dh)
+    v = cuts["wv"].astype(dt).reshape(W, 1, heads_k, Dh)
+    q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+    k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+    return q, k, v, cuts["wg"]
+
+
+def sparse_attention(cfg: ModelConfig, lp: Params, h, pool, layer: int, hook,
+                     rows, pos, tq: int):
+    """The "minicpm4" mixer over a paged launch's flat tokens: normed h
+    [W, 1, D] at positions pos [W]. Returns (float32 [W, 1, D], pool)."""
+    W = h.shape[0]
+    H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    q, k, v, gate = _project(cfg, lp, h[:, 0], "sparse", H, KV)
+    MB = rows.table.shape[1]
+    with jax.named_scope("sparse_select"):
+        G = W // tq
+        ck_pool, ck = compressed_keys(cfg, k[:, 0], pool["k"],
+                                      pool["ck"][layer], layer, rows, pos,
+                                      tq)
+        chosen = select_blocks(
+            cfg, q[:, 0].reshape(G, tq, KV, H // KV, Dh), ck,
+            pos.reshape(G, tq))
+        chosen &= (rows.tok_row >= 0).reshape(G, tq, 1, 1)
+        pages = page_lists(chosen, list_width(cfg, tq, MB))
+    attn, new_k, new_v = hook(
+        cfg, q, k, v, pool["k"], pool["v"], pos, None, None, None, None,
+        layer, pages=pages,
+    )
+    y = (attn.reshape(W, H * Dh).astype(F32)
+         * jax.nn.sigmoid(gate)).astype(cfg.jnp_dtype)
+    out = jnp.dot(y, lp["wo"], preferred_element_type=F32)
+    return out[:, None], {**pool, "k": new_k, "v": new_v,
+                          "ck": _put(pool["ck"], layer, ck_pool)}
+
+
+def linear_attention(cfg: ModelConfig, lp: Params, h, pool, layer: int, rows,
+                     pos, cos, sin, tq: int):
+    """The "lightning-attn" mixer over a paged launch's flat tokens (normed
+    h [W, 1, D]). A row that starts a tenant (rows.fresh) starts from zeros,
+    or from snapshot rows.restore after a prefix hit, never from what the
+    slot's previous tenant left; a row with rows.take >= 0 leaves its state
+    after this launch in that snapshot. Returns (float32 [W, 1, D], pool)."""
+    W = h.shape[0]
+    Hl, Dh = cfg.linear_heads, cfg.head_dim
+    q, k, v, gate = _project(cfg, lp, h[:, 0], "linear", Hl, Hl)
+    q, k = apply_rope(q, k, cos, sin)
+    q = (q.astype(F32) * Dh ** -0.5).astype(cfg.jnp_dtype)
+    lin, snap = pool["lin"][layer], pool["snap"][layer]
+    R = rows.table.shape[0]
+
+    start = lin
+    if rows.restore is not None:  # a mixed launch: rows may start tenants
+        def restored():
+            held = snap[jnp.clip(rows.restore, 0, snap.shape[0] - 1)]
+            first = jnp.where((rows.restore >= 0)[:, None, None, None], held,
+                              0.0)
+            return jnp.where(rows.fresh[:, None, None, None], first, lin)
+
+        start = jax.lax.cond(jnp.any(rows.fresh), restored, lambda: lin)
+    if tq == 1 and W == R:  # a decode step: one token a row, row w's at w
+        o, lin = linear_attend_step(q[:, 0], k[:, 0], v[:, 0], start,
+                                    rows.tok_row >= 0)
+    else:
+        o, lin = linear_attend_rows(q[:, 0], k[:, 0], v[:, 0], start,
+                                    rows.tok_row, tq)
+    if rows.take is not None:
+        at = jnp.where(rows.take >= 0, rows.take, snap.shape[0])  # dropped
+        snap = jax.lax.cond(
+            jnp.any(rows.take >= 0),
+            lambda: snap.at[at].set(lin, mode="drop"), lambda: snap)
+    o = rms_norm(o.reshape(W, Hl * Dh), lp["o_norm"], cfg.norm_eps)
+    y = (o.astype(F32) * jax.nn.sigmoid(gate)).astype(cfg.jnp_dtype)
+    out = jnp.dot(y, lp["wo"], preferred_element_type=F32)
+    return out[:, None], {**pool, "lin": _put(pool["lin"], layer, lin),
+                          "snap": _put(pool["snap"], layer, snap)}
+
+
+# -- the stack ----------------------------------------------------------------
+
+
+def forward_layers(cfg: ModelConfig, layers: Params, x, cache, pos,
+                   update_gate=None, tp_axis=None, attn_hook=None,
+                   valid_start=None, ep_axis=None, attn_seq_len=None):
+    """Every layer over a paged launch's flat tokens x [W, 1, D] (float32
+    residual) at positions pos [W]; cache the pool (module docstring);
+    attn_hook a paged hook (engine/paged.py) whose `rows()` says how the
+    tokens fall into fleet rows. Returns (x, the pool)."""
+    if tp_axis is not None or ep_axis is not None or update_gate is not None:
+        raise ValueError("the minicpm_sala family is not sharded over pp, "
+                         "tp or ep")
+    if valid_start is not None or not getattr(attn_hook, "paged", False):
+        raise ValueError(
+            "the minicpm_sala family is served from the paged pool only: "
+            "flat tokens under a paged hook, no left-padded rows")
+    del attn_seq_len
+    W, T = x.shape[:2]
+    assert T == 1, "the paged launches carry one token a batch row"
+    pos = jnp.asarray(pos, jnp.int32)
+    rows = attn_hook.rows()
+    tq = attn_hook.tile
+    with jax.named_scope("linear_attn"):  # the rotary tables, once a forward
+        cos, sin = rope_cos_sin(pos[:, None], cfg.head_dim, cfg.rope_theta)
+    dt = cfg.jnp_dtype
+    r = cfg.residual_multiplier or 1.0
+
+    def row(kind, i):
+        return {name: leaf[i] for name, leaf in layers[kind].items()}
+
+    new = dict(cache)
+    scope = {"lightning-attn": "linear_attn", "minicpm4": "attn"}
+    il = ia = 0
+    for li, kind in enumerate(cfg.layer_types):
+        with jax.named_scope(scope[kind]):
+            h = rms_norm(x, layers["op_norm"][li], cfg.norm_eps).astype(dt)
+            if kind == "minicpm4":
+                out, new = sparse_attention(cfg, row("sparse", ia), h, new,
+                                            ia, attn_hook, rows, pos, tq)
+                ia += 1
+            else:
+                out, new = linear_attention(cfg, row("linear", il), h, new,
+                                            il, rows, pos, cos, sin, tq)
+                il += 1
+        with jax.named_scope("ffn"):
+            x = x + r * out
+            h = rms_norm(x, layers["ffn_norm"][li], cfg.norm_eps).astype(dt)
+            lp = row("ffn", li)
+            # (rows x D: a batch of one-token rows made the last product a
+            # multiply-and-reduce at half the bandwidth in the decode chunk)
+            # (... and handed on as it is: fused with the residual add and
+            # the next norm's sum of squares the same product took twice its
+            # time: my chip run, PR 48)
+            out = jax.lax.optimization_barrier(
+                swiglu(h[:, 0], lp["w_gate"], lp["w_up"], lp["w_down"])
+            )[:, None]
+        after = cfg.layer_types[li + 1:li + 2]
+        with jax.named_scope(scope[after[0]] if after else "head"):
+            x = x + r * out
+    return x, new
+
+
+def forward(cfg: ModelConfig, params: Params, tokens, cache, pos):
+    raise ValueError(
+        f"{cfg.name}: the minicpm_sala family has no dense-cache forward; "
+        f"it is served from the paged pool (engine/paged.py)")

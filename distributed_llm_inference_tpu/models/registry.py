@@ -216,6 +216,30 @@ register(ModelConfig(
     first_k_dense=6, moe_renormalize=True, routed_scaling=2.448,
     router_norm_eps=1e-20, eos_token_id=2, bos_token_id=1, pad_token_id=0,
 ))
+# --- MiniCPM-SALA (sparse attention beside decayed linear attention;
+# openbmb/MiniCPM-SALA config.json, model_type minicpm_sala:
+# models/minicpm_sala.py). mixer_types as published: 8 "minicpm4" layers
+# (InfLLM-v2 selection, GQA 32/2, no rotary, an output gate) among 24
+# "lightning-attn" ones (32 heads of 128, rotary, qk-norm, output norm and
+# gate). muP: scale_emb 12 on the embedding, scale_depth 1.4 / sqrt(32) on
+# every residual branch, logits over hidden_size / dim_model_base = 16. Not
+# in config.json and so assumed: the selection's constants (the family's
+# published sparse_config, MiniCPM4: kernel 32, stride 16, block 64, top-64,
+# window 2048, 1 initial block, dense below 8192), the decay slopes
+# (ops/linear_attention.decay_slopes), the gates' width and the output
+# norm's (over the heads side by side), the special tokens.
+MINICPM_SALA_MIXERS = tuple(
+    "minicpm4" if i in (0, 9, 16, 17, 22, 29, 30, 31) else "lightning-attn"
+    for i in range(32))
+register(ModelConfig(
+    name="minicpm-sala", arch="minicpm_sala", vocab_size=73448, dim=4096,
+    n_layers=32, n_heads=32, n_kv_heads=2, ffn_dim=16384,
+    max_seq_len=524288, norm_eps=1e-6, rope_theta=10000.0,
+    head_dim_override=128, use_qk_norm=True, layer_types=MINICPM_SALA_MIXERS,
+    linear_heads=32, embed_multiplier=12.0,
+    residual_multiplier=1.4 / 32 ** 0.5, logits_divider=16.0,
+    eos_token_id=2, bos_token_id=1, pad_token_id=0,
+))
 register(ModelConfig(
     name="qwen3-8b", arch="llama", vocab_size=151936, dim=4096,
     n_layers=36, n_heads=32, n_kv_heads=8, ffn_dim=12288, max_seq_len=40960,
@@ -380,6 +404,21 @@ register(ModelConfig(
     n_experts=8, n_experts_per_tok=2, moe_ffn_dim=32, first_k_dense=1,
     moe_renormalize=True, router_norm_eps=1e-6,
     eos_token_id=2, bos_token_id=1,
+))
+# (the selection at toy constants: a compressed key of 4 keys every 2
+# tokens, blocks of 8, 4 of them read past 24 visible positions, the
+# last 8 positions' and the first block forced)
+register(ModelConfig(
+    name="test-sala-tiny", arch="minicpm_sala", vocab_size=256, dim=64,
+    n_layers=4, n_heads=4, n_kv_heads=2, ffn_dim=96, max_seq_len=256,
+    norm_eps=1e-6, rope_theta=10000.0, head_dim_override=16,
+    use_qk_norm=True, linear_heads=4,
+    layer_types=("minicpm4", "lightning-attn", "lightning-attn",
+                 "minicpm4"),
+    sparse_kernel=4, sparse_stride=2, sparse_block=8, sparse_topk=4,
+    sparse_window=8, sparse_init_blocks=1, sparse_dense_len=24,
+    embed_multiplier=12.0, residual_multiplier=1.4 / 32 ** 0.5,
+    logits_divider=4.0, eos_token_id=2, bos_token_id=1,
 ))
 register(ModelConfig(
     name="test-trinity-tiny", arch="afmoe", vocab_size=256, dim=64,

@@ -182,19 +182,21 @@ def _walk_shape(KV: int, bs: int, Dh: int, itemsize: int, quant: bool,
 
 
 def walk_pages_per_step(leaf, n_heads: int, tq: int, MB: int,
-                        latent: bool = False) -> int:
+                        latent: bool = False, listed: bool = False) -> int:
     """P of the walk over pool leaf `leaf` ([..., KV, bs, Dh], or
     ops/kv_quant's int8 pair; `latent`: a pool of latent rows, no V) for
     tiles of tq queries of n_heads heads under a table MB pages wide: what
     `_paged_walk` gives its kernel, for the host's count of loop steps
-    (engine/continuous: `kv_walk_steps`)."""
+    (engine/continuous: `kv_walk_steps`). listed: the selected read's walk,
+    a program a KV head."""
     from .kv_quant import KVQuant
 
     quant = isinstance(leaf, KVQuant)
     a = leaf.q if quant else leaf
     KV, bs, Dh = a.shape[-3:]
-    return _walk_shape(KV, bs, Dh + -Dh % 128, a.dtype.itemsize, quant,
-                       tq * (n_heads // KV), MB, latent)[1]
+    return _walk_shape(1 if listed else KV, bs, Dh + -Dh % 128,
+                       a.dtype.itemsize, quant, tq * (n_heads // KV), MB,
+                       latent)[1]
 
 
 def _walk_kernel(
@@ -202,9 +204,10 @@ def _walk_kernel(
     table_ref,  # scalar-prefetch [R, MB] int32
     win_ref,  # scalar-prefetch [1] int32: sliding window (<= 0 = full);
     # [2] with `write`: and the layer of the stacked pool
-    q_ref,  # [1, tq, KVg, group, Dh] VMEM: one query tile, one head group
-    *rest,  # ([new K, V rows,] the pool leaves in HBM, o_ref, [the pool's
-    # aliased outputs,] scratch...)
+    *rest,  # ([plist_ref, count_ref: `pages`' scalar-prefetch operands,]
+    # q_ref [1, tq, KVg, group, Dh] VMEM: one query tile, one head group,
+    # [sel_ref: `pages` and tq > 1,] [new K, V rows,] the pool leaves in
+    # HBM, o_ref, [the pool's aliased outputs,] scratch...)
     bs: int,
     MB: int,
     tq: int,
@@ -217,6 +220,7 @@ def _walk_kernel(
     latent: int = 0,
     write: bool = False,
     block: int = 0,
+    pages: int = 0,
 ):
     """One program: query tile g (tq queries of one row; a decode slot is
     a tile of one) against head group hg's KVg KV heads. The walk over
@@ -259,8 +263,27 @@ def _walk_kernel(
     tokens (ops/attention.block_frontier). The walk's bounds stay the
     causal ones: the engine cuts every tile at a multiple of `block`, so a
     tile's last block ends with the tile, inside the rows `patch` puts
-    into the VMEM copy before the fold."""
+    into the VMEM copy before the fold.
+
+    pages > 0 is the SELECTED read (models/minicpm_sala.py): the program
+    walks a LIST of the row's logical pages in place of the range
+    first .. needed: plist_ref [G, KV, pages] int32, ascending, the first
+    count_ref [G, KV] of them live, one list a tile and KV head (KVg is 1:
+    each KV head chose its own pages). Compute block i holds list entries
+    i * P .. + P - 1; an entry's positions are its logical page's. A tile
+    of several queries walks the union of their choices and sel_ref
+    [1, 1, pages / 128, rows, 128] (1.0 where score row r's query chose
+    list entry 128 * a + b) masks per query what it did not choose; a tile
+    of one query chose its whole list. The tile's own pages (where its new
+    rows fall) are the list's last entries: every query's forced window
+    holds them."""
     n = 1 if latent else (4 if quant else 2)  # pool leaves
+    plist_ref = count_ref = sel_ref = None
+    if pages:
+        plist_ref, count_ref, *rest = rest
+    q_ref, *rest = rest
+    if pages and tq > 1:
+        sel_ref, *rest = rest
     new_refs, rest = (rest[:n], rest[n:]) if write else ((), rest)
     srcs, o_ref, rest = rest[:n], rest[n], rest[n + 1:]
     if write:
@@ -283,7 +306,15 @@ def _walk_kernel(
     rows = tq * group
     Dh = q_ref.shape[-1]
     first, needed = _ragged_live_range(q_start, q_len, bs=bs, MB=MB, win=win)
+    if pages:
+        first, needed = 0, count_ref[g, hg]
     needed = jnp.where(q_len > 0, needed, first)
+
+    def logical(j):
+        # the row's logical page that walk index j stands for
+        if pages:
+            return plist_ref[g, hg, jnp.minimum(j, pages - 1)]
+        return j
 
     m_ref[:] = jnp.full(m_ref.shape, _NEG, jnp.float32)
     l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
@@ -308,7 +339,7 @@ def _walk_kernel(
         # logical page j of the row -> page p of buffer half `slot`: the
         # head group's slab of the physical block, one contiguous run of
         # HBM per pool. A half's pages share its semaphore.
-        blk = table_ref[row, j]
+        blk = table_ref[row, logical(j)]
         return [
             pltpu.make_async_copy(
                 src.at[(*layer, blk, pl.ds(hg * KVg, KVg))],
@@ -340,7 +371,7 @@ def _walk_kernel(
         return pl.ds(pl.multiple_of((j - j0) * bs + start, sub), span)
 
     def put_back(j, j0, slot, start):
-        blk = table_ref[row, j]
+        blk = table_ref[row, logical(j)]
         return [
             pltpu.make_async_copy(
                 buf.at[slot, :, rows_of(j, j0, start)],
@@ -355,7 +386,7 @@ def _walk_kernel(
         """The tile's new rows that fall in page j of the compute block
         from j0, into the page's VMEM copy: only the span that goes back
         is touched. Returns the span's first row in the page."""
-        r0 = q_start - j * bs  # page row of the tile's first token
+        r0 = q_start - logical(j) * bs  # page row of the tile's first token
         start = 0
         if span < bs:
             start = pl.multiple_of(
@@ -410,18 +441,41 @@ def _walk_kernel(
             # the pages of this compute block that hold new rows
             news = []
             for k in range(touched):
-                j = q_start // bs + k
+                at = q_start // bs + k  # the logical page
+                j = at
+                if pages:  # the tile's own pages end its list
+                    j = needed - 1 - ((q_start + q_len - 1) // bs - at)
                 news.append((j, (j >= j0) & (j < jnp.minimum(j0 + P, needed))
-                             & (j * bs < q_start + q_len)))
+                             & (at * bs < q_start + q_len)))
             for j, has_new in news:
                 @pl.when(has_new)
                 def _():
                     for c in put_back(j, j0, slot, patch(j, j0, slot)):
                         c.start()
 
-        kv_pos = j0 * bs + col
+        if pages:
+            # a list entry's positions are its logical page's; entries at or
+            # past `needed` hold nothing
+            kv_pos = jnp.full(col.shape, MB * bs, jnp.int32)
+            for p in range(P):
+                here = (col // bs == p) & (j0 + p < needed)
+                kv_pos = jnp.where(here, logical(j0 + p) * bs + col % bs,
+                                   kv_pos)
+        else:
+            kv_pos = j0 * bs + col
         mask = (t_local < q_len) & (kv_pos <= q_end)
         mask &= (win <= 0) | (kv_pos > q_pos - win)
+        if sel_ref is not None:
+            # score row r's query chose list entry j0 + p: the entries of
+            # this step lie in one 128-entry part of the list
+            part = sel_ref[0, 0, j0 // 128]  # [rows, 128]
+            entry = jax.lax.broadcasted_iota(jnp.int32, (128, P * bs), 0)
+            spread = (entry == j0 % 128 + jax.lax.broadcasted_iota(
+                jnp.int32, (128, P * bs), 1) // bs).astype(part.dtype)
+            # (0 / 1 in bfloat16: exact at one pass, whatever precision the
+            # process asks of its float32 products)
+            mask &= jnp.dot(part, spread, precision=jax.lax.Precision.DEFAULT,
+                            preferred_element_type=jnp.float32) > 0.5
         ks = kbuf[slot].astype(jnp.float32)  # [KVg, P x bs, Dh]
         vs = ks[:, :, :latent] if latent else vbuf[slot].astype(jnp.float32)
         s = jax.lax.dot_general(
@@ -497,7 +551,7 @@ def writes_in_place(leaf) -> bool:
 
 def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
                 scale, softcap, interpret, value_dim=None, write=None,
-                block=0):
+                block=0, pages=None):
     """The pallas_call both wrappers share. q [G, tq, H, Dh]: G query
     tiles of tq queries; meta [G, 4]. pool_v None is the latent form.
     write None: the pool leaves are one layer's slices and hold the
@@ -505,7 +559,9 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
     (layer, new_k, new_v): the leaves are the stacked pool, new_k / new_v
     [G, tq, KV, Dh] the launch's rows, which the kernel writes (see
     `_walk_kernel`); returns (output, pool_k, pool_v), the pool updated in
-    place (donate it)."""
+    place (donate it). pages (plist [G, KV, L], count [G, KV], chosen
+    [G, tq, KV, L] bool or None): the selected read; L a multiple of 128.
+    Absent, the call is what it was before the list existed."""
     from .kv_quant import KVQuant
 
     latent = pool_v is None
@@ -536,20 +592,32 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
     Dp = q5.shape[-1]
     Dv = value_dim if latent else Dp
     rows = tq * group
-    KVg, P = _walk_shape(KV, bs, Dp, leaves[0].dtype.itemsize, quant, rows,
-                         MB, latent)
+    KVg, P = _walk_shape(1 if pages else KV, bs, Dp,
+                         leaves[0].dtype.itemsize, quant, rows, MB, latent)
+    lists, sel, L = [], [], 0
+    if pages is not None:
+        assert not (latent or quant), "a selected read is of raw K/V pages"
+        plist, count, chosen = pages
+        L = plist.shape[-1]
+        assert L % 128 == 0, L
+        lists = [plist.astype(jnp.int32), count.astype(jnp.int32)]
+        if tq > 1:
+            # [G, tq, KV, L] -> a score row's choices, 128 entries a part
+            c = jnp.repeat(chosen.transpose(0, 2, 1, 3), group, axis=2)
+            sel = [c.reshape(G, KV, rows, L // 128, 128)
+                   .transpose(0, 1, 3, 2, 4).astype(jnp.bfloat16)]
 
     kernel = functools.partial(
         _walk_kernel, bs=bs, MB=MB, tq=tq, KVg=KVg, P=P, group=group,
         scale=scale if scale is not None else Dh**-0.5, softcap=softcap,
         quant=quant, latent=value_dim if latent else 0,
-        write=write is not None, block=block,
+        write=write is not None, block=block, pages=L,
     )
 
     def tile(per_head, width):
         return pl.BlockSpec(
             (1, tq, KVg, per_head, width),
-            lambda g, hg, meta_ref, table_ref, win_ref: (g, 0, hg, 0, 0),
+            lambda g, hg, *refs: (g, 0, hg, 0, 0),
         )
 
     scratch = [
@@ -574,12 +642,18 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
         out_shape = [out_shape] + [
             jax.ShapeDtypeStruct(a.shape, a.dtype) for a in leaves
         ]
-        # operands: 3 prefetched scalars, q, the new rows, the pool leaves
-        aliases = {4 + n + i: 1 + i for i in range(n)}
+        # operands: the prefetched scalars (3, and a page list's 2), q,
+        # the list's choices, the new rows, the pool leaves
+        at = 3 + len(lists) + 1 + len(sel) + n
+        aliases = {at + i: 1 + i for i in range(n)}
+    sel_spec = [pl.BlockSpec(
+        (1, 1, L // 128, rows, 128), lambda g, hg, *refs: (g, hg, 0, 0, 0),
+    )] * len(sel)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=3 + len(lists),
         grid=(G, KV // KVg),
-        in_specs=[tile(group, Dp)] + [tile(1, Dp)] * len(news) + in_hbm,
+        in_specs=[tile(group, Dp)] + sel_spec + [tile(1, Dp)] * len(news)
+        + in_hbm,
         out_specs=out_specs,
         scratch_shapes=scratch,
     )
@@ -589,8 +663,8 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
-    )(meta.astype(jnp.int32), table.astype(jnp.int32), scalars, q5, *news,
-      *leaves)
+    )(meta.astype(jnp.int32), table.astype(jnp.int32), scalars, *lists, q5,
+      *sel, *news, *leaves)
     width = Dv if latent else Dh
     if write is None:
         return out[..., :width].reshape(G, tq, H, width)
@@ -612,6 +686,7 @@ def paged_flash_attend(
     window_dyn: jnp.ndarray | None = None,
     active: jnp.ndarray | None = None,
     write: tuple | None = None,
+    pages: tuple | None = None,
     *,
     window: int | None = None,
     scale: float | None = None,
@@ -646,6 +721,10 @@ def paged_flash_attend(
     pool_v None is the latent form: pool_k [N,1,bs,R] rows [c | k_r | 0],
     q [B,1,H,R] absorbed queries, the output [B,1,H,value_dim]; new_v and
     the returned pool_v are None.
+    pages (plist [B, KV, L], count [B, KV]): the selected read
+    (`_walk_kernel`): row b's KV head h reads the first count[b, h] logical
+    pages of plist[b, h] (ascending; the page of pos[b] the last of them)
+    in place of the range.
     """
     B, T, H, Dh = q.shape
     assert T == 1, "paged kernel serves decode steps (T=1) only"
@@ -659,6 +738,7 @@ def paged_flash_attend(
         q, pool_k, pool_v, table, meta, window, window_dyn, scale=scale,
         softcap=softcap, interpret=resolve_interpret(interpret),
         value_dim=value_dim, write=write, block=block,
+        pages=None if pages is None else (*pages, None),
     )
 
 
@@ -675,6 +755,7 @@ def ragged_paged_attend(
     meta: jnp.ndarray,
     window_dyn: jnp.ndarray | None = None,
     write: tuple | None = None,
+    pages: tuple | None = None,
     *,
     window: int | None = None,
     scale: float | None = None,
@@ -713,16 +794,23 @@ def ragged_paged_attend(
     A prefill chunk's tiles each walk the row's prefix: the tile size is
     the scheduler's. pool_v None is the latent form, as in
     `paged_flash_attend`: the output is [W, H, value_dim].
+    pages (plist [G, KV, L], count [G, KV], chosen [W, KV, L] bool): the
+    selected read (`_walk_kernel`): tile g's KV head h walks the first
+    count[g, h] logical pages of plist[g, h], the union of its queries'
+    choices, and query w attends list entry l where chosen[w, h, l].
     """
     W, H, Dh = q.shape
     G = meta.shape[0]
     tq = W // G
     assert tq * G == W, "flat query axis must be a whole number of tiles"
+    if pages is not None:
+        plist, count, chosen = pages
+        pages = (plist, count, chosen.reshape((G, tq) + chosen.shape[1:]))
     out = _paged_walk(
         q.reshape(G, tq, H, Dh), pool_k, pool_v, table, meta, window,
         window_dyn, scale=scale, softcap=softcap,
         interpret=resolve_interpret(interpret), value_dim=value_dim,
-        write=write, block=block,
+        write=write, block=block, pages=pages,
     )
     if write is None:
         return out.reshape(W, H, -1)

@@ -1713,6 +1713,11 @@ def main(argv: Optional[list] = None):
              "admission backpressures on pool exhaustion (engine/paged.py)",
     )
     ap.add_argument(
+        "--state-snapshots", type=int, default=0,
+        help="recurrent states a paged fleet keeps for prefix hits to "
+             "restore (a model of linear-attention layers; 0: two a slot)",
+    )
+    ap.add_argument(
         "--kv-block-size", type=int, default=16,
         help="tokens per KV pool block (with --kv-pool-blocks)",
     )
@@ -1851,6 +1856,7 @@ def main(argv: Optional[list] = None):
         engine_cfg=EngineConfig(
             request_deadline_s=args.deadline,
             prefix_cache_entries=args.prefix_cache,
+            state_snapshots=args.state_snapshots,
             kv_shadow=not args.no_kv_shadow,
             kv_fabric=not args.no_kv_fabric,
             kv_fabric_timeout_s=args.kv_fabric_timeout,

@@ -103,6 +103,19 @@ CONV_TAIL_WRITES_HELP = (
     "position model"
 )
 
+# fleets of a model of sparse and linear attention layers
+# (ModelConfig.linear_layers, models/minicpm_sala.py)
+LINEAR_STATE_RESETS_HELP = (
+    "slots let to a tenant with a zeroed linear-attention state (a cold "
+    "start: no prefix hit restored a snapshot), whatever the previous "
+    "tenant left in the slot"
+)
+SPARSE_ROWS_HELP = (
+    "row-steps of the sparse attention layers by branch: dense = fewer "
+    "positions visible than the dense length (plain causal attention), "
+    "sparse = the selected read"
+)
+
 # a pool grouped by layer kind, and one chip's share of the experts
 KV_GROUP_BLOCKS_HELP = (
     "blocks of each group of the paged pool by state: live = held by a "

@@ -51,6 +51,12 @@ CELLS = {
     "lfm2-24b-a2b-9l": dict(
         width=512, blocks=3500, leaf=("k", (2, 3500, 4, 128, 128)),
         scopes=DENSE_SCOPES + ROUTED_SCOPES + ("conv_mix",)),
+    # (K/V and the compressed keys belong to 4 of the 16 layers; a pool block
+    # is one 64-token block of the selection; the linear layers' states and
+    # snapshots are a leaf a layer)
+    "minicpm-sala-9b-16l": dict(
+        width=136, blocks=9216, leaf=("k", (4, 9216, 2, 64, 128)),
+        scopes=DENSE_SCOPES + ("linear_attn", "linear_scan", "sparse_select")),
     "mistral-7b-16l": dict(
         width=136, blocks=271, leaf=("k", (16, 271, 8, 128, 128)),
         scopes=DENSE_SCOPES),
@@ -84,6 +90,11 @@ def test_the_programs_are_the_cells(one_chip, no_persistent_cache, config):
         assert (len(cfg.conv_layers), len(cfg.attn_layers), cfg.kv_pack) == (7, 2, 2)
     if config.startswith("trinity"):
         assert EP.window_row_budget(cfg.attn_window, built.width, 128) == 37
+    if config.startswith("minicpm-sala"):
+        assert (len(cfg.attn_layers), len(cfg.linear_layers)) == (4, 12)
+        assert built.pool["ck"][0].shape == (9216 * 4, 2 * 128)
+        assert built.pool["lin"][0].shape == (16, 32, 128, 128)
+        assert built.pool["snap"][0].shape[1:] == (32, 128, 128)
     if cfg.diffusion_block:
         # the decode chunk's flat axis: 32 slots x 2 blocks of 4 = 256 tokens
         assert "bf16[256,2048]" in built.texts["decode_slots_paged"]
@@ -129,6 +140,12 @@ def _temporaries_bound(config, built):
     the pool."""
     if config.startswith("lfm2"):
         return 0.6 * built.pool["tail"].size * 2
+    if config.startswith("minicpm-sala"):
+        # nothing of the K/V leaves' (1.21 GB each), the snapshot pool's
+        # (1.0 GB) or the live states' size (0.4 GB): what is left is the
+        # decode chunk's relayout of [4096, 4096] projections, once a launch
+        # (34 MB each; PERF.md section 6, PR 48)
+        return 0.9 * built.pool["k"].size * 2
     bound = 0.45 * _pool_bytes(built.pool)
     if config.startswith("sdar"):
         bound += built.cfg.dim * built.cfg.vocab_size * 2
@@ -176,7 +193,8 @@ def test_step_programs_copy_no_expert_bank(one_chip, no_persistent_cache, config
     """No operation of a bank's size beside the kernel that reads it."""
     built = cell_programs(config)
     shapes = _bank_shapes(built.params)
-    if config in ("mistral-7b-16l", "olmo2-7b-16l"):  # dense: no bank
+    if config in ("mistral-7b-16l", "olmo2-7b-16l",
+                  "minicpm-sala-9b-16l"):  # dense: no bank
         assert not built.cfg.n_experts and not shapes
         return
     assert shapes
@@ -244,6 +262,14 @@ def test_step_programs_read_the_attention_projections_in_place(
     layer-step there either, but the decode chunk relays the stacks of q,
     k and v out once a launch (25 MB in all)."""
     built = cell_programs(config)
+    if config == "minicpm-sala-9b-16l":
+        # the family's leaves are a layer's own (models/minicpm_sala.py: no
+        # stack to slice or relay out): the rule's shapes do not exist
+        # (and a mixer's input projections are one matrix, `w_in`)
+        assert all(leaf.ndim == 2 for name in ("w_in", "wo")
+                   for kind in ("sparse", "linear")
+                   for leaf in built.params["layers"][kind][name])
+        return
     found = {name: _projection_sized_instructions(text, built.params["layers"])
              for name, text in built.texts.items()}
     if config == "lfm2-24b-a2b-9l":

@@ -182,15 +182,17 @@ class StepPrograms:
 
 
 def programs(model, slots, blocks, context, block_size=128, tile=8,
-             layers=2, described=True, **overrides) -> StepPrograms:
+             layers=2, described=True, snapshots=0,
+             **overrides) -> StepPrograms:
     """`model`'s decode chunk and mixed step (cut to `layers` layers, 0: as
     registered or overridden), compiled for one chip of a described v5e:2x2
     with the kernels lowered for it (described False: for the backend that
     is there, in the registry's or the overrides' dtype). THE place the
     tests lower the two programs. Every family the paged fleet serves: a
     block-diffusion model's programs carry its DiffState, a model with
-    recurrent layers a pool with a state a slot, a pool grouped by layer
-    kind its groups' blocks (engine/paged.group_blocks) under two tables
+    recurrent layers a pool with a state a slot (and `snapshots` of them
+    where it keeps a snapshot pool), a pool grouped by layer kind its
+    groups' blocks (engine/paged.group_blocks) under two tables
     side by side; neither of the last two drafts, so no DeviceMeta."""
     import jax
     import jax.numpy as jnp
@@ -225,7 +227,9 @@ def programs(model, slots, blocks, context, block_size=128, tile=8,
     if grouped:
         blocks = P.group_blocks(cfg, blocks, P.window_row_budget(
             cfg.attn_window, width, block_size), slots)
-    pool = place(lambda: P.init_pool(cfg, blocks, block_size, n_slots=slots))
+    pool = place(lambda: P.init_pool(
+        cfg, blocks, block_size, n_slots=slots,
+        **({"n_snapshots": snapshots} if cfg.linear_layers else {})))
     table = S((slots, len(cfg.kv_groups) * (context // block_size)), jnp.int32)
     key = place(lambda: jax.random.PRNGKey(0))
     chunk_kw, mixed_kw = {}, {}
@@ -248,9 +252,11 @@ def programs(model, slots, blocks, context, block_size=128, tile=8,
         entries = [(b, 0, 1, P.RAGGED_DECODE) for b in range(slots)]
         meta, tok_row, tok_pos, offsets, _ = P.build_ragged_meta(
             entries, width=width, tile=tile)
-        if not (cfg.conv_layers or grouped):
+        if not (cfg.recurrent or grouped):
             mixed_kw = {"dev": P.build_device_meta(
                 entries, offsets, slots, width=width, tile=tile)}
+        if cfg.linear_layers:  # by slot: the snapshot restored, the one kept
+            mixed_kw = {"snaps": (S((slots,), jnp.int32),) * 2}
     assert len(offsets) == slots  # one tile a row
     if "dev" in mixed_kw:
         mixed_kw["dev"] = P.DeviceMeta(
@@ -282,6 +288,7 @@ def programs(model, slots, blocks, context, block_size=128, tile=8,
 CELL_FILES = {
     "test_cell_programs_kanana_lfm2": ("kanana-2-30b-a3b-7l", "lfm2-24b-a2b-9l"),
     "test_cell_programs_mistral_olmo2": ("mistral-7b-16l", "olmo2-7b-16l"),
+    "test_cell_programs_sala": ("minicpm-sala-9b-16l",),
     "test_cell_programs_sdar_trinity": ("sdar-30b-a3b-7l", "trinity-large-ep8-5l"),
 }
 CELL_CONFIGS = tuple(sorted(sum(CELL_FILES.values(), ())))
@@ -311,6 +318,8 @@ def cell_programs(config: str, layers: int = 0) -> StepPrograms:
     return programs(
         serving["base"], flag("--continuous"), flag("--kv-pool-blocks"),
         flag("--continuous-max-seq"), flag("--kv-block-size"), layers=layers,
+        snapshots=flag("--state-snapshots") if "--state-snapshots" in flags
+        else 0,
         **serving.get("overrides", {}))
 
 

@@ -289,3 +289,54 @@ def test_ragged_kernel_compiles_with_the_block_mask(
         S((slots, 16), jnp.int32), S((slots, 4), jnp.int32), S((), jnp.int32),
         new, new)
     assert any("ragged_paged_attend" in c for c in _custom_call_names(text))
+
+
+# -- the selected read (ISSUE 48) -----------------------------------------------
+#
+# minicpm-sala-9b-16l's sparse layers: both kernels walking a page LIST a KV
+# head (2 of them, 16 query heads each) over 64-token pages, writing in
+# place: a decode row's list is at most 128 pages (the dense length's), a
+# mixed tile of 8 queries walks their union (at most 512) under the
+# per-query choices; the scan and the selection are XLA's and compile with
+# the step programs (tests/test_cell_programs_sala.py).
+@pytest.mark.parametrize("program", ["decode_slots_paged", "mixed_step_ragged"])
+def test_the_selected_read_compiles_at_the_sala_cells_shapes(
+    one_chip, no_persistent_cache, monkeypatch, program
+):
+    from distributed_llm_inference_tpu.models.minicpm_sala import list_width
+
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    cfg, slots, mb, pool = cell_pool("minicpm-sala-9b-16l")
+    S = _spec(one_chip)
+    pool_k = S(pool["k"].shape, pool["k"].dtype)
+    layers, blocks, kv, bs, dh = pool_k.shape
+    assert (layers, blocks, kv, bs, dh, slots, mb) == (4, 9216, 2, 64, 128, 16, 1032)
+    table = S((slots, mb), jnp.int32)
+    if program == "decode_slots_paged":
+        L = list_width(cfg, 1, mb)
+        assert L == 128
+        new = S((slots, 1, kv, dh), jnp.bfloat16)
+        text = _compile(
+            lambda q, pk, pv, t, pos, live, layer, k, v, plist, count:
+            paged_flash_attend(q, pk, pv, t, pos, None, live, (layer, k, v),
+                               (plist, count)),
+            S((slots, 1, cfg.n_heads, dh), jnp.bfloat16), pool_k, pool_k,
+            table, S((slots,), jnp.int32), S((slots,), jnp.bool_),
+            S((), jnp.int32), new, new, S((slots, kv, L), jnp.int32),
+            S((slots, kv), jnp.int32))
+        kernel = "paged_flash_attend"
+    else:
+        flat, tq = step_width(cfg, slots, 8), 8
+        L = list_width(cfg, tq, mb)
+        assert (flat, L) == (136, 512)
+        new = S((flat, kv, dh), jnp.bfloat16)
+        text = _compile(
+            lambda q, pk, pv, t, m, layer, k, v, plist, count, chosen:
+            ragged_paged_attend(q, pk, pv, t, m, None, (layer, k, v),
+                                (plist, count, chosen)),
+            S((flat, cfg.n_heads, dh), jnp.bfloat16), pool_k, pool_k, table,
+            S((flat // tq, 4), jnp.int32), S((), jnp.int32), new, new,
+            S((flat // tq, kv, L), jnp.int32), S((flat // tq, kv), jnp.int32),
+            S((flat, kv, L), jnp.bool_))
+        kernel = "ragged_paged_attend"
+    assert any(kernel in c for c in _custom_call_names(text))

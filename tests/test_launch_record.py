@@ -247,7 +247,7 @@ def test_host_walk_is_the_kernels_live_range(bs, mb, window):
 
     host = types.SimpleNamespace(
         _kv_walks=True, kv_block_size=bs, _max_blocks=mb, _kv_window=window,
-        _scratch_seq=bs * mb,
+        _scratch_seq=bs * mb, _sparse=None,
     )
     pos = np.arange(0, bs * mb + bs + 3)
     win = jnp.int32(window if window is not None else -1)
@@ -343,6 +343,7 @@ def test_host_walk_steps_round_the_pages_up(P):
     host = types.SimpleNamespace(
         _kv_walks=True, kv_block_size=bs, _max_blocks=mb, _kv_window=window,
         _scratch_seq=bs * mb, _walk_pages={"chunk": P, "mixed": 1},
+        _sparse=None,
     )
     host._kv_walk = types.MethodType(ContinuousEngine._kv_walk, host)
     pos = np.arange(0, bs * mb)
@@ -370,6 +371,8 @@ CELL_WALK_SHAPES = {
     "sdar-30b-a3b-7l": ((4, 4), (4, 4)),  # a row's forward is a tile of 8
     "lfm2-24b-a2b-9l": ((4, 4), (4, 4)),  # pairs of 64-number heads a row
     "trinity-large-ep8-5l": ((8, 2), (8, 2)),
+    # a page LIST a KV head: a program is one head, 8 pages of 64 tokens a step
+    "minicpm-sala-9b-16l": ((1, 8), (1, 8)),
 }
 
 
@@ -385,12 +388,15 @@ def test_walk_shape_at_the_cells_shapes(name):
     from distributed_llm_inference_tpu.ops.paged_attention import _walk_shape
 
     cfg, _, mb, pool = cell_pool(name)
-    host = types.SimpleNamespace(cfg=cfg, _max_blocks=mb, cache=pool)
+    listed = bool(cfg.linear_layers)  # (the selected read: `_walk_kernel`)
+    host = types.SimpleNamespace(cfg=cfg, _max_blocks=mb, cache=pool,
+                                 _sparse=cfg if listed else None)
     kv, bs, dh = next(a for a in jax.tree.leaves(pool) if a.ndim == 5).shape[-3:]
     tiles = (2 * cfg.diffusion_block or 1, 8)  # the decode chunk's, a mixed launch's
     for tq, want in zip(tiles, CELL_WALK_SHAPES[name]):
         assert ContinuousEngine._walk_pages_of(host, tq) == want[1]
-        assert _walk_shape(kv, bs, dh, 2, False, tq * (cfg.n_heads // kv), mb,
+        assert _walk_shape(1 if listed else kv, bs, dh, 2, False,
+                           tq * (cfg.n_heads // kv), mb,
                            cfg.latent_dim > 0) == want
 
 

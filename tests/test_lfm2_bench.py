@@ -73,11 +73,16 @@ class Ctx:
 
 # ---- the cell, rehearsed -----------------------------------------------------
 
-def test_the_new_cell_runs_every_phase_at_a_tiny_size_and_refuses_a_cpu():
+def test_the_new_cell_runs_every_phase_at_a_tiny_size_and_refuses_a_cpu(tmp_path):
+    # (at a rate a machine shared with the suite's other workers still serves:
+    # tests/bench_rehearsal.py says what the tiny cell's own 2 sessions/s did)
+    from bench_rehearsal import light_manifest
+
     p = subprocess.run(
-        [sys.executable, os.path.join(BENCH, "run.py"), "--manifest", TEST_MANIFEST,
+        [sys.executable, os.path.join(BENCH, "run.py"), "--manifest",
+         light_manifest(tmp_path, TEST_MANIFEST, CELL, 1.0),
          "--platform", "cpu", "--workload", CELL, "--seed", "4242424242",
-         "--seconds", "8", "--trace", "0"],
+         "--seconds", "14", "--trace", "0"],
         cwd=ROOT, env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
         text=True, timeout=900)
     out = p.stdout

@@ -37,7 +37,12 @@ FAMILIES = {
     "test-mla-moe-tiny": BLOCKS | ROUTED | {"ffn", "moe_shared", "mla_absorb"},
     "test-sdar-tiny": BLOCKS | ROUTED,
     "test-lfm2-tiny": BLOCKS | ROUTED | {"ffn", "conv_mix"},
+    "test-sala-tiny": BLOCKS | {"ffn", "linear_attn", "linear_scan",
+                                "sparse_select"},
 }
+# a label that only ever nests in another
+NESTED = {"mla_absorb": "attn", "sparse_select": "attn",
+          "linear_scan": "linear_attn"}
 
 
 def test_the_vocabulary_names_every_scope_once():
@@ -48,8 +53,11 @@ def test_the_vocabulary_names_every_scope_once():
 
 @pytest.mark.parametrize("preset", sorted(FAMILIES))
 def test_scope_map_finds_every_label_of_the_family_in_both_step_programs(preset):
-    texts = dense_equal.programs(preset, 3, 24, 64, block_size=16, layers=0,
-                                 described=False).texts
+    # (a pool block of the sparse family is one block of its selection)
+    block = get_model_config(preset).sparse_block \
+        if preset == "test-sala-tiny" else 16
+    texts = dense_equal.programs(preset, 3, 24, 64, block_size=block, layers=0,
+                                 described=False, snapshots=2).texts
     assert set(texts) == {"decode_slots_paged", "mixed_step_ragged"}
     for program, text in texts.items():
         (module, insts), = tracing.scope_map(text).items()
@@ -63,9 +71,9 @@ def test_scope_map_finds_every_label_of_the_family_in_both_step_programs(preset)
         assert set(insts) <= set(names)
         for name, v in insts.items():
             assert set(v) == {"scope", "mixed"} and v["mixed"] >= 0, name
-        if "mla_absorb" in held:  # nested: outermost first
-            assert all(v["scope"][0] == "attn" for v in insts.values()
-                       if "mla_absorb" in v["scope"])
+        for inner, outer in NESTED.items():  # nested: outermost first
+            assert all(v["scope"][0] == outer for v in insts.values()
+                       if inner in v["scope"]), inner
 
 
 def test_scope_map_on_a_hand_made_module():
