@@ -123,7 +123,8 @@ from ..utils.metrics import (
     CONV_STATE_RESETS_HELP,
     CONV_TAIL_WRITES_HELP, DEFAULT_SIZE_BUCKETS, DIFFUSION_FORWARDS_HELP,
     DIFFUSION_FUSED_HELP, DIFFUSION_TOKENS_HELP, KV_GROUP_BLOCKS_HELP,
-    KV_WINDOW_RELEASED_HELP, LINEAR_STATE_RESETS_HELP, MOE_PAIRS_HELP,
+    KV_WINDOW_RELEASED_HELP, LINEAR_STATE_RESETS_HELP, LINEAR_STATE_ROWS_HELP,
+    MOE_PAIRS_HELP,
     PREFIX_STATE_TOKENS_HELP, SLOT_RELEASE_HELP, SLOT_TURNOVER_HELP,
     SPARSE_ROWS_HELP, STEPS_AHEAD_BUCKETS,
 )
@@ -1174,9 +1175,14 @@ class ContinuousEngine:
         self._m_sparse_rows = m.counter(
             "dli_sparse_rows_total", SPARSE_ROWS_HELP, ("branch",),
         )
+        self._m_lin_rows = m.counter(
+            "dli_linear_state_rows_total", LINEAR_STATE_ROWS_HELP, ("state",),
+        )
         if cfg.linear_layers:
             for branch in ("dense", "sparse"):
                 self._m_sparse_rows.labels(branch=branch)
+            for state in ("touched", "held"):
+                self._m_lin_rows.labels(state=state)
         self._m_steps_ahead = m.histogram(
             "dli_launch_steps_ahead",
             "scheduler steps dispatched and unfetched when a launch was "
@@ -3240,10 +3246,11 @@ class ContinuousEngine:
                 out[key] += layers * count
         return out
 
-    def _sparse_fields(self, phase: str, visible) -> dict:
+    def _sparse_fields(self, phase: str, visible, steps: int) -> dict:
         """The launch record's fields of a fleet with sparse attention
         layers: `visible`, the positions at or below each live row-step's
-        (last) query; `kv_tokens` beside it counts what is read."""
+        (last) query; `kv_tokens` beside it counts what is read. `steps`:
+        the launch's, for the share of the state leaf its rows are."""
         visible = np.asarray(visible).reshape(-1)
         sparse = int(np.sum(visible >= self._sparse.sparse_dense_len))
         read = int(np.sum(self._kv_span(visible, 0)))
@@ -3253,6 +3260,8 @@ class ContinuousEngine:
         self._m_kv_tokens.labels(phase=phase, state="selected").inc(read)
         self._m_sparse_rows.labels(branch="sparse").inc(sparse)
         self._m_sparse_rows.labels(branch="dense").inc(len(visible) - sparse)
+        self._m_lin_rows.labels(state="touched").inc(len(visible))
+        self._m_lin_rows.labels(state="held").inc(self.n_slots * steps)
         return {"kv_tokens_visible": int(visible.sum()),
                 "sparse_rows": sparse, "state_rows": len(visible)}
 
@@ -3468,7 +3477,8 @@ class ContinuousEngine:
                  for b in np.flatnonzero(live)]
             )
         if self._sparse is not None:
-            diff_fields.update(self._sparse_fields("chunk", (at + 1)[alive]))
+            diff_fields.update(
+                self._sparse_fields("chunk", (at + 1)[alive], K))
         rec = self._launch_record(
             "chunk", K,
             **self._kv_fields(
@@ -4501,7 +4511,7 @@ class ContinuousEngine:
             diff_fields.update(self._sparse_fields(
                 "mixed", [int(self._host_pos[b]) + 1 for b in active
                  if self._host_pos[b] < self._host_end[b]]
-                + [st + n for _, n, st in chunk_list]))
+                + [st + n for _, n, st in chunk_list], 1))
         rec = self._launch_record(
             "mixed", 1,
             **self._kv_fields(

@@ -85,7 +85,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config import ModelConfig
-from ..ops.linear_attention import linear_attend_rows, linear_attend_step
+from ..ops.linear_attention import linear_attend_rows
 from ..ops.norms import rms_norm
 from ..ops.rope import apply_rope, rope_cos_sin
 from .experts import _normal_slices
@@ -461,7 +461,6 @@ def linear_attention(cfg: ModelConfig, lp: Params, h, pool, layer: int, rows,
     q, k = apply_rope(q, k, cos, sin)
     q = (q.astype(F32) * Dh ** -0.5).astype(cfg.jnp_dtype)
     lin, snap = pool["lin"][layer], pool["snap"][layer]
-    R = rows.table.shape[0]
 
     start = lin
     if rows.restore is not None:  # a mixed launch: rows may start tenants
@@ -472,12 +471,9 @@ def linear_attention(cfg: ModelConfig, lp: Params, h, pool, layer: int, rows,
             return jnp.where(rows.fresh[:, None, None, None], first, lin)
 
         start = jax.lax.cond(jnp.any(rows.fresh), restored, lambda: lin)
-    if tq == 1 and W == R:  # a decode step: one token a row, row w's at w
-        o, lin = linear_attend_step(q[:, 0], k[:, 0], v[:, 0], start,
-                                    rows.tok_row >= 0)
-    else:
-        o, lin = linear_attend_rows(q[:, 0], k[:, 0], v[:, 0], start,
-                                    rows.tok_row, tq)
+    # (a decode step is the same call: one token a row, a tile each)
+    o, lin = linear_attend_rows(q[:, 0], k[:, 0], v[:, 0], start,
+                                rows.tok_row, tq)
     if rows.take is not None:
         at = jnp.where(rows.take >= 0, rows.take, snap.shape[0])  # dropped
         snap = jax.lax.cond(

@@ -110,6 +110,12 @@ LINEAR_STATE_RESETS_HELP = (
     "start: no prefix hit restored a snapshot), whatever the previous "
     "tenant left in the slot"
 )
+LINEAR_STATE_ROWS_HELP = (
+    "slot-steps of the linear-attention layers' state leaf by state: "
+    "touched = a row that carried a token, whose state the scan read and "
+    "wrote (the launch records' state_rows), held = slots x the launch's "
+    "steps; touched / held is the share of the leaf a launch has to move"
+)
 SPARSE_ROWS_HELP = (
     "row-steps of the sparse attention layers by branch: dense = fewer "
     "positions visible than the dense length (plain causal attention), "

@@ -175,6 +175,35 @@ def test_step_programs_hold_no_copy_of_the_pool_at_cell_sizes(
                 r"(?:f32|bf16)\[\d+,250(?:24|88)\]", built.texts[name]))))
 
 
+# -- a linear layer's states go through the scan in place (ISSUE 49) ------------
+
+def test_the_linear_scan_takes_its_leaf_in_place(one_chip, no_persistent_cache,
+                                                 config):
+    """Both step programs of a configuration with linear-attention layers
+    run the scan's kernel once a linear layer, under its scope's name (what
+    `linear_attn_roofline` finds it by), with the layer's `lin` leaf as the
+    call's aliased output; no `copy` makes a buffer of a state leaf's or the
+    snapshot pool's shape (the test above holds the leaves among the
+    program's aliased arguments)."""
+    built = cell_programs(config)
+    if not built.cfg.linear_layers:
+        assert "lin" not in built.pool
+        return
+    lin, snap = built.pool["lin"][0], built.pool["snap"][0]
+    shapes = {",".join(map(str, leaf.shape)) for leaf in (lin, snap)}
+    for name, text in built.texts.items():
+        calls = [line for line in text.splitlines()
+                 if re.search(r"%linear_scan[\w.\-]* = .*custom-call\(", line)]
+        assert len(calls) == len(built.cfg.linear_layers), (name, len(calls))
+        for line in calls:
+            # (operands: 4 prefetched scalars, q, k, v under their decays,
+            # the decay's power, the state: ops/linear_attention.linear_scan)
+            assert "output_to_operand_aliasing={{1}: (8, {})}" in line, line
+            assert "linear_attn/linear_scan" in line, line
+        copies = re.findall(r"= f32\[([\d,]+)\]\{[^}]*\} copy\(", text)
+        assert not shapes & set(copies), (name, shapes & set(copies))
+
+
 # -- the expert banks ride outside the layer scan (ISSUE 32, 34) ----------------
 
 def _bank_shapes(params):

@@ -8,6 +8,7 @@ configurations), so that the suite's workers share the minutes out.
 import functools
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -297,8 +298,8 @@ def test_ragged_kernel_compiles_with_the_block_mask(
 # head (2 of them, 16 query heads each) over 64-token pages, writing in
 # place: a decode row's list is at most 128 pages (the dense length's), a
 # mixed tile of 8 queries walks their union (at most 512) under the
-# per-query choices; the scan and the selection are XLA's and compile with
-# the step programs (tests/test_cell_programs_sala.py).
+# per-query choices; the selection is XLA's and compiles with the step
+# programs (tests/test_cell_programs_sala.py); the scan is the test below.
 @pytest.mark.parametrize("program", ["decode_slots_paged", "mixed_step_ragged"])
 def test_the_selected_read_compiles_at_the_sala_cells_shapes(
     one_chip, no_persistent_cache, monkeypatch, program
@@ -340,3 +341,39 @@ def test_the_selected_read_compiles_at_the_sala_cells_shapes(
             S((flat, kv, L), jnp.bool_))
         kernel = "ragged_paged_attend"
     assert any(kernel in c for c in _custom_call_names(text))
+
+
+# minicpm-sala-9b-16l's linear layers: the scan's program at the cell's
+# shapes (16 slots, 32 heads of 128, a float32 state a row and head): a mixed
+# launch's 17 tiles of 8 and the decode chunk's one token a row. The state
+# leaf goes in and comes out as one buffer, and the call is named by its
+# scope: the label `linear_attn_roofline` finds it by.
+@pytest.mark.parametrize("flat,tq", [(136, 8), (16, 1)], ids=["mixed", "decode"])
+def test_the_linear_scan_compiles_at_the_sala_cells_shapes(
+    one_chip, no_persistent_cache, flat, tq
+):
+    from distributed_llm_inference_tpu.ops.linear_attention import (
+        linear_attend_rows,
+    )
+
+    cfg, slots, _, pool = cell_pool("minicpm-sala-9b-16l")
+    S = _spec(one_chip)
+    lin = pool["lin"][0]
+    assert (slots, step_width(cfg, slots, 8)) == (16, 136)
+    assert (lin.shape, lin.dtype) == ((16, 32, 128, 128), jnp.float32)
+    tokens = S((flat, cfg.linear_heads, cfg.head_dim), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, state, tok_row: linear_attend_rows(
+            q, k, v, state, tok_row, tq, interpret=False),
+        donate_argnums=(3,),
+    ).lower(tokens, tokens, tokens, S(lin.shape, lin.dtype),
+            S((flat,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert any("linear_scan" in c for c in _custom_call_names(text))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == lin.size * 4, memory
+    # nothing of a leaf's size beside the leaf: the tokens and their masks
+    assert memory.temp_size_in_bytes < lin.size * 4 // 8, memory
+    # ... and no instruction but the call makes a buffer of a leaf's shape
+    made = re.findall(r"= f32\[16,32,128,128\]\{[^}]*\} ([\w\-]+)\(", text)
+    assert set(made) <= {"parameter", "get-tuple-element", "bitcast"}, made

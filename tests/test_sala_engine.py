@@ -199,6 +199,24 @@ def test_the_launch_records_counts_are_the_hand_count():
     assert steps == sum(r["sparse_rows"] for r in chunk) and steps >= 1
 
 
+def test_the_state_row_counter_is_the_launch_records():
+    """`dli_linear_state_rows_total` over two requests side by side: touched
+    = the launch records' `state_rows` (a row-step that carried a token: the
+    states the scan moves), held = slots x the launches' steps (the leaf a
+    pass over it would move); both series are on /metrics."""
+    f = Fleet()
+    f.ask_all([(prompt_ids(40, 3), 6), (prompt_ids(20, 5), 9)])
+    touched, held = (f.ce._m_lin_rows.labels(state=s).value
+                     for s in ("touched", "held"))
+    assert touched == sum(r["state_rows"] for r in f.records) > 0
+    assert held == 2 * sum(r["steps"] for r in f.records)
+    assert any(r["phase"] == "chunk" and r["steps"] > 1 for r in f.records)
+    assert touched < held
+    text = f.eng.metrics.render()
+    for s in ("touched", "held"):
+        assert f'dli_linear_state_rows_total{{state="{s}"}}' in text
+
+
 def test_start_up_refuses_what_the_family_does_not_carry():
     eng = create_engine("test-sala-tiny", seed=SEED)
     with pytest.raises(ValueError, match="no dense fleet"):

@@ -9,6 +9,7 @@ against the gather path under a selection a query.
 import os
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -16,7 +17,7 @@ import pytest
 from distributed_llm_inference_tpu.models import minicpm_sala as MS
 from distributed_llm_inference_tpu.models.registry import get_model_config
 from distributed_llm_inference_tpu.ops.linear_attention import (
-    decay_slopes, linear_attend_rows, linear_attend_step,
+    decay_slopes, linear_attend_rows,
 )
 from distributed_llm_inference_tpu.ops.paged_attention import (
     paged_flash_attend, ragged_paged_attend,
@@ -34,46 +35,122 @@ CFG = get_model_config("test-sala-tiny")
 # -- the scan -------------------------------------------------------------------
 
 def _recurrence(q, k, v, S):
-    """One row's tokens through `linear_attend_step`, one at a time."""
+    """One row's tokens q, k, v [n, H, Dh] from the state S [H, Dh, Dh],
+    one at a time in float64: S = a S + k^T v, o = q S."""
+    a = np.exp(-np.asarray(decay_slopes(q.shape[1]), np.float64))
+    q, k, v, S = (np.asarray(x, np.float64) for x in (q, k, v, S))
     out = []
     for t in range(q.shape[0]):
-        o, S = linear_attend_step(q[t][None], k[t][None], v[t][None], S)
-        out.append(o[0])
-    return jnp.stack(out), S
+        S = a[:, None, None] * S + k[t][:, :, None] * v[t][:, None, :]
+        out.append(np.einsum("hd,hde->he", q[t], S))
+    return np.stack(out), S
 
 
-@pytest.mark.parametrize("tq", [1, 4])
-def test_the_chunked_scan_is_the_recurrence(tq):
-    """Three rows side by side on a launch's flat axis (a long chunk, a
-    decode token, a dead tile between, a row with no token), each carrying
-    on from its own state: outputs and states equal the recurrence's."""
+# a launch's flat axis: (tile, [(fleet row or -1 for a tile of launch padding,
+# its tokens)]), 4 fleet rows, row 1 never carrying a token
+LAUNCHES = {
+    # a long chunk, a decode token, a dead tile between, a row with no token
+    "tq1": (1, [(0, 12), (-1, 0), (2, 1), (3, 8)]),
+    "tq4": (4, [(0, 12), (-1, 0), (2, 4), (3, 8)]),
+    # a row whose tokens span 25 tiles (two blocks of the program's 128, the
+    # second ending with the flat axis) beside rows of one token
+    "tiles-of-8": (8, [(2, 1), (0, 200), (-1, 0), (3, 1)]),
+    # a chunk of two tokens: shorter than a tile, longer than the recurrence
+    "short-chunk": (8, [(3, 2), (0, 1)]),
+    # the decode chunk's form: one token a row, row w's at w, row 1 not active
+    "decode-rows": (1, [(0, 1), (-1, 0), (2, 1), (3, 1)]),
+    "no-token": (8, [(-1, 0), (-1, 0)]),
+}
+
+
+@pytest.mark.parametrize("launch", list(LAUNCHES))
+def test_the_chunked_scan_is_the_recurrence(launch):
+    """Rows side by side on a launch's flat axis, each carrying on from its
+    own state: outputs and states equal the recurrence's, a dead token reads
+    zeros, and a row with no token of the launch keeps its state bit for
+    bit (the leaf is the scan's aliased output)."""
     rng = np.random.default_rng(0)
     H, Dh, R = 3, 8, 4
-    lens = {0: 12, 2: tq, 3: 8}  # row 1 has no token
-    order = [0, -1, 2, 3]  # -1: a tile of launch padding
-    tok_row = np.concatenate([
-        np.full(-(-lens.get(r, tq) // tq) * tq if r >= 0 else tq, -1, np.int32)
-        for r in order])
-    at, spans = 0, {}
-    for r in order:
-        n = lens.get(r, tq) if r >= 0 else 0
+    tq, order = LAUNCHES[launch]
+    tok_row, spans = [], {}
+    for r, n in order:
         width = -(-max(n, 1) // tq) * tq
-        tok_row[at:at + n] = r
         if r >= 0:
-            spans[r] = (at, n)
-        at += width
+            spans[r] = (len(tok_row), n)
+        tok_row += [r] * n + [-1] * (width - n)
+    tok_row = np.asarray(tok_row, np.int32)
     W = len(tok_row)
     q, k, v = (jnp.asarray(rng.normal(size=(W, H, Dh)), jnp.float32)
                for _ in range(3))
-    state = jnp.asarray(rng.normal(size=(R, H, Dh, Dh)), jnp.float32)
-    o, new = linear_attend_rows(q, k, v, state, jnp.asarray(tok_row), tq)
+    state = np.asarray(rng.normal(size=(R, H, Dh, Dh)), np.float32)
+    o, new = linear_attend_rows(q, k, v, jnp.asarray(state),
+                                jnp.asarray(tok_row), tq)
     for r, (a, n) in spans.items():
         want_o, want_S = _recurrence(q[a:a + n], k[a:a + n], v[a:a + n],
-                                     state[r][None])
+                                     state[r])
         np.testing.assert_allclose(o[a:a + n], want_o, rtol=2e-5, atol=2e-5)
-        np.testing.assert_allclose(new[r], want_S[0], rtol=2e-5, atol=2e-5)
-    np.testing.assert_array_equal(new[1], state[1])  # no token: untouched
-    assert not np.any(np.asarray(o)[tok_row < 0])
+        np.testing.assert_allclose(new[r], want_S, rtol=2e-5, atol=2e-5)
+    for r in set(range(R)) - set(spans):  # no token: untouched
+        np.testing.assert_array_equal(new[r], state[r])
+    assert 1 not in spans and not np.any(np.asarray(o)[tok_row < 0])
+
+
+def _linear_layer(rows, pool):
+    """`MS.linear_attention` of the tiny preset's first linear layer over
+    a launch of 16 flat tokens in tiles of 8 (row 1's chunk, row 0's
+    decode token)."""
+    from distributed_llm_inference_tpu.ops.rope import rope_cos_sin
+
+    params = MS.init_params(CFG, jax.random.PRNGKey(3))
+    lp = {name: leaf[0] for name, leaf in params["layers"]["linear"].items()}
+    h = jax.random.normal(jax.random.PRNGKey(4), (16, 1, CFG.dim), jnp.float32)
+    pos = jnp.arange(16, dtype=jnp.int32)
+    cos, sin = rope_cos_sin(pos[:, None], CFG.head_dim, CFG.rope_theta)
+    return MS.linear_attention(CFG, lp, h.astype(CFG.jnp_dtype), pool, 0,
+                               rows, pos, cos, sin, 8)
+
+
+def test_a_fresh_row_starts_from_its_snapshot_and_a_taken_one_holds_the_state():
+    """Around the scan (`models/minicpm_sala.linear_attention`): a fresh row
+    with a snapshot to restore reads and leaves what a row carrying that
+    state on does; a fresh row without one starts from zeros; a row with
+    `take` leaves its state after the launch in that snapshot; the other
+    snapshots and the row with no token stay bit for bit."""
+    from distributed_llm_inference_tpu.engine.paged import StateRows
+
+    rng = np.random.default_rng(5)
+    R, N = 3, 4
+    shape = (CFG.linear_heads, CFG.head_dim, CFG.head_dim)
+    n_lin = len(CFG.linear_layers)
+    lin = np.asarray(rng.normal(size=(R,) + shape), np.float32)
+    snap = np.asarray(rng.normal(size=(N,) + shape), np.float32)
+    tok_row = np.full((16,), -1, np.int32)
+    tok_row[:5], tok_row[8] = 1, 0
+
+    def run(lin, fresh, restore, take):
+        pool = {"lin": (jnp.asarray(lin),) * n_lin,
+                "snap": (jnp.asarray(snap),) * n_lin}
+        rows = StateRows(
+            jnp.asarray(tok_row), jnp.zeros((R, 2), jnp.int32),
+            jnp.asarray(fresh), jnp.zeros((R,), jnp.int32),
+            jnp.asarray(restore, jnp.int32), jnp.asarray(take, jnp.int32))
+        out, pool = _linear_layer(rows, pool)
+        return (np.asarray(out, np.float32), np.asarray(pool["lin"][0]),
+                np.asarray(pool["snap"][0]))
+
+    none = [-1] * R
+    # row 1 restores snapshot 2 and keeps its state in snapshot 0; row 0
+    # starts from zeros
+    out, new, kept = run(lin, [True, True, False], [-1, 2, -1], [-1, 0, -1])
+    carried = lin.copy()
+    carried[0], carried[1] = 0.0, snap[2]
+    want_out, want_new, same = run(carried, [False] * R, none, none)
+    np.testing.assert_allclose(out, want_out, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(new, want_new, rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(new[2], lin[2])
+    np.testing.assert_array_equal(kept[0], new[1])
+    np.testing.assert_array_equal(kept[1:], snap[1:])
+    np.testing.assert_array_equal(same, snap)
 
 
 def test_a_row_that_is_not_active_keeps_its_state_and_the_slopes_are_the_familys():
@@ -83,7 +160,8 @@ def test_a_row_that_is_not_active_keeps_its_state_and_the_slopes_are_the_familys
     q, k, v = (jnp.asarray(rng.normal(size=(2, 3, 8)), jnp.float32)
                for _ in range(3))
     S = jnp.asarray(rng.normal(size=(2, 3, 8, 8)), jnp.float32)
-    o, new = linear_attend_step(q, k, v, S, jnp.asarray([True, False]))
+    # a decode step: one token a row, row 1 not active
+    o, new = linear_attend_rows(q, k, v, S, jnp.asarray([0, -1]), 1)
     np.testing.assert_array_equal(new[1], S[1])
     assert not np.any(np.asarray(o[1])) and np.any(np.asarray(new[0] != S[0]))
 
