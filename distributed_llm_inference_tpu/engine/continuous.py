@@ -126,7 +126,7 @@ from ..utils.metrics import (
     KV_WINDOW_RELEASED_HELP, LINEAR_STATE_RESETS_HELP, LINEAR_STATE_ROWS_HELP,
     MOE_PAIRS_HELP,
     PREFIX_STATE_TOKENS_HELP, SLOT_RELEASE_HELP, SLOT_TURNOVER_HELP,
-    SPARSE_ROWS_HELP, STEPS_AHEAD_BUCKETS,
+    SPARSE_ROWS_HELP, SPARSE_SCORED_KEYS_HELP, STEPS_AHEAD_BUCKETS,
 )
 from ..utils.retry import overload_retry_after
 from ..utils.tracing import PhaseClock, Trace, abstract_call, sample_decision
@@ -1178,6 +1178,9 @@ class ContinuousEngine:
         self._m_lin_rows = m.counter(
             "dli_linear_state_rows_total", LINEAR_STATE_ROWS_HELP, ("state",),
         )
+        self._m_ck_scored = m.counter(
+            "dli_sparse_scored_keys_total", SPARSE_SCORED_KEYS_HELP,
+        ).labels()
         if cfg.linear_layers:
             for branch in ("dense", "sparse"):
                 self._m_sparse_rows.labels(branch=branch)
@@ -3250,10 +3253,16 @@ class ContinuousEngine:
         """The launch record's fields of a fleet with sparse attention
         layers: `visible`, the positions at or below each live row-step's
         (last) query; `kv_tokens` beside it counts what is read. `steps`:
-        the launch's, for the share of the state leaf its rows are."""
+        the launch's, for the share of the state leaf its rows are.
+        `ck_scored`: the compressed keys the selection's scoring reads, a
+        sparse layer and KV head: a row's, once a step the row is in, up to
+        its length (ops/sparse_select.py), where a gather of every tile's
+        whole table read tiles x the table's width / the stride."""
         visible = np.asarray(visible).reshape(-1)
         sparse = int(np.sum(visible >= self._sparse.sparse_dense_len))
         read = int(np.sum(self._kv_span(visible, 0)))
+        scored = int(np.sum(-(-visible // self._sparse.sparse_stride)))
+        self._m_ck_scored.inc(scored)
         # (dli_attn_kv_tokens_total's further states for such a fleet)
         self._m_kv_tokens.labels(phase=phase, state="visible").inc(
             int(visible.sum()))
@@ -3262,7 +3271,7 @@ class ContinuousEngine:
         self._m_sparse_rows.labels(branch="dense").inc(len(visible) - sparse)
         self._m_lin_rows.labels(state="touched").inc(len(visible))
         self._m_lin_rows.labels(state="held").inc(self.n_slots * steps)
-        return {"kv_tokens_visible": int(visible.sum()),
+        return {"kv_tokens_visible": int(visible.sum()), "ck_scored": scored,
                 "sparse_rows": sparse, "state_rows": len(visible)}
 
     def _kv_span(self, start, length=1, window=-1):

@@ -78,6 +78,7 @@ from ..ops.attention import attend, block_frontier
 from ..ops.kv_quant import KVQuant
 from ..ops.kv_quant import dequantize as kv_dequantize
 from ..ops.kv_quant import quantize_chunk
+from ..ops.sparse_select import leaf_rows
 from . import generate as G
 
 TRASH_BLOCK = 0  # reserved pool block: write-only spill for table tails
@@ -124,9 +125,11 @@ def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
     models/minicpm_sala.py) keeps three kinds: "k" / "v" of its SPARSE
     layers alone, with cfg.sparse_block tokens a block (a page of the
     kernels' walk is a block of the selection); beside them a third leaf
-    of the same blocks, "ck" (a leaf a sparse layer,
-    [N x bs / stride, KV x Dh]), the compressed keys the selection scores
-    against, each with the block that holds its last token, so allocator,
+    of the same blocks, "ck" (a leaf a sparse layer, [N, rows, Dh]: a
+    block's KV x bs / stride keys padded to whole tiles, which the
+    scoring's kernel copies block by block), the compressed keys the
+    selection scores against, each with the block that holds its last
+    token, so allocator,
     refcounts, eviction and the prefix index carry it as they carry K/V;
     and for the Ll linear layers "lin" (a leaf a layer,
     [n_slots, Hl, Dh, Dh] FLOAT32), a slot's live matrix state, and "snap"
@@ -148,12 +151,12 @@ def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
         dt, Dh = cfg.jnp_dtype, cfg.head_dim
         kv = (Ls, n_blocks, cfg.n_kv_heads, block_size, Dh)
         state = (cfg.linear_heads, Dh, Dh)
-        rows = n_blocks * (block_size // cfg.sparse_stride)
+        ck = (n_blocks, leaf_rows(cfg.n_kv_heads,
+                                  block_size // cfg.sparse_stride, dt), Dh)
         return {
             "k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt),
             # (a leaf a layer: models/minicpm_sala.py says why)
-            "ck": tuple(jnp.zeros((rows, cfg.n_kv_heads * Dh), dt)
-                        for _ in range(Ls)),
+            "ck": tuple(jnp.zeros(ck, dt) for _ in range(Ls)),
             "lin": tuple(jnp.zeros((n_slots,) + state, jnp.float32)
                          for _ in range(Ll)),
             "snap": tuple(jnp.zeros((max(1, n_snapshots),) + state,

@@ -22,14 +22,16 @@ scale_depth / sqrt(the PUBLISHED depth)):
             the same per-head RMSNorm on q and k; a query at position t
             (n = t + 1 positions visible) reads every position where
             n < cfg.sparse_dense_len, and otherwise the cfg.sparse_topk
-            blocks of cfg.sparse_block tokens that `select_blocks` chooses
+            blocks of cfg.sparse_block tokens that the selection chooses
             for its KV head; causal softmax at Dh^-0.5 over what it reads;
             Mixer = (o * sigmoid(u wg)) wo.
             CACHE: K/V, and the compressed keys the selection scores
             against: c = mean of cfg.sparse_kernel consecutive keys, one
             every cfg.sparse_stride tokens, a key head each.
 
-The selection (`select_blocks`), per query and KV head: p^h = softmax over
+The selection (ops/sparse_select.select_blocks: one Pallas program a query
+tile, over the row's compressed keys where the pool holds them, through the
+block table), per query and KV head: p^h = softmax over
 the compressed keys whose tokens all lie at or before t of q^h . c / sqrt(Dh);
 r = the sum of p^h over the KV head's query heads; a block's score is the
 largest r among the compressed keys whose tokens overlap it; the first
@@ -41,15 +43,18 @@ The family is served from the paged pool alone (engine/paged.py), with
 cfg.sparse_block tokens a pool block, so that a page of the paged kernels'
 walk is one block of the selection. The pool's leaves:
   "k" / "v"  [Ls, N, KV, bs, Dh]      the sparse layers' K/V
-  "ck"       a leaf a sparse layer, [N x bs / stride, KV x Dh] (a row the KV
-             heads' keys side by side: whole lanes, rows a gather reads whole):
-             the compressed key whose LAST token is position e (e % stride ==
-             stride - 1) sits with the block that holds e, at row
-             block x bs / stride + (e % bs) // stride: every token it covers
-             lies at or before its block's end, so a block shared by the
-             prefix index brings its compressed keys with it, and the launch
-             that writes e has all of them (this launch's keys, or the
-             pool's)
+  "ck"       a leaf a sparse layer, [N, rows, Dh]: a block's compressed keys
+             a whole tile of the pool's dtype (16 rows of
+             bfloat16, the KV x bs / stride = 8 keys and 8 rows of zeros),
+             so that the scoring's kernel (ops/sparse_select.py, the leaf's
+             one reader) copies a block's keys as it copies a K/V page. The
+             compressed key whose LAST token is position e (e % stride ==
+             stride - 1) sits with the block that holds e, KV head kv's at
+             row kv x bs / stride + (e % bs) // stride: every token it
+             covers lies at or before its block's end, so a block shared by
+             the prefix index brings its compressed keys with it, and the
+             launch that writes e (`compressed_keys`) has all of them (this
+             launch's keys, or the pool's)
   "lin"      a leaf a linear layer, [slots, Hl, Dh, Dh] float32: a slot's
              live state
   "snap"     a leaf a linear layer, [snapshots, Hl, Dh, Dh] float32: states
@@ -85,6 +90,8 @@ import jax
 import jax.numpy as jnp
 
 from ..config import ModelConfig
+from ..ops import sparse_select
+from ..ops.flash_attention import resolve_interpret
 from ..ops.linear_attention import linear_attend_rows
 from ..ops.norms import rms_norm
 from ..ops.rope import apply_rope, rope_cos_sin
@@ -220,90 +227,23 @@ def unembed(cfg: ModelConfig, params: Params, x):
 # -- the selection ------------------------------------------------------------
 
 
-def ck_slots(cfg: ModelConfig) -> int:
-    """Compressed keys a pool block holds (those whose last token it holds)."""
-    return cfg.sparse_block // cfg.sparse_stride
-
-
-def select_blocks(cfg: ModelConfig, q, ck, pos):
-    """Which blocks each query reads. q [G, tq, KV, group, Dh] (normed, not
-    scaled): G tiles of tq queries, a tile one row's; ck
-    [G, KV, MB x slots, Dh]: the compressed keys of the tile's row by the
-    block and slot that hold them (module docstring); pos [G, tq] the
-    queries' positions. Returns chosen [G, tq, KV, MB] bool: block b holds
-    a position the query's KV head reads (every block up to the query's own
-    where fewer than cfg.sparse_dense_len positions are visible). Scores,
-    softmax and sums in float32: a near-tie decides which block is read."""
-    G, tq, KV = q.shape[:3]
-    bs, stride, kernel = cfg.sparse_block, cfg.sparse_stride, cfg.sparse_kernel
-    slots = bs // stride
-    MB = ck.shape[2] // slots
-    blocks = jnp.arange(MB, dtype=jnp.int32)
-    visible = blocks <= (pos // bs)[..., None]  # [G, tq, MB]
-    # the compressed key at (block b, slot s) ends at b * bs + s * stride +
-    # stride - 1 and covers `kernel` tokens
-    end = (blocks[:, None] * bs
-           + jnp.arange(slots, dtype=jnp.int32)[None, :] * stride
-           + stride - 1)  # [MB, slots]
-    valid = (end <= pos[..., None, None]) & (end >= kernel - 1)
-    # (the keys stay on one flat axis J = MB x slots through the softmax: a
-    # minor axis of `slots` numbers would fill 4 of a tile's 128 lanes)
-    J = MB * slots
-    ok = valid.reshape(G, tq, 1, 1, J)
-    s = jnp.einsum("gtkhd,gkjd->gtkhj", q, ck.astype(q.dtype),
-                   preferred_element_type=F32) * cfg.head_dim ** -0.5
-    s = jnp.where(ok, s, -jnp.inf)
-    top = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.where(ok, jnp.exp(s - jnp.where(jnp.isfinite(top), top, 0.0)),
-                  0.0)
-    total = jnp.sum(e, axis=-1, keepdims=True)
-    r = jnp.sum(e / jnp.where(total > 0, total, 1.0), axis=3)  # [G,tq,KV,J]
-    r = jnp.where(ok[:, :, 0], r, -jnp.inf).reshape(G, tq, KV, MB, slots)
-    # a block's score: the keys that end in it, and those that end in the
-    # next block's first slots but begin in this one
-    score = jnp.max(r, axis=4)
-    over = (kernel - 1) // stride
-    if over:
-        nxt = jnp.max(r[..., :over], axis=4)
-        nxt = jnp.concatenate(
-            [nxt[..., 1:], jnp.full_like(nxt[..., :1], -jnp.inf)], axis=3)
-        score = jnp.maximum(score, nxt)
-    forced = (blocks < cfg.sparse_init_blocks) | (
-        blocks >= (jnp.maximum(pos - (cfg.sparse_window - 1), 0)
-                   // bs)[..., None])
-    score = jnp.where(forced[:, :, None], jnp.inf, score)
-    score = jnp.where(visible[:, :, None], score, -jnp.inf)
-    # -inf scores (blocks past the query) may fill the k where few blocks
-    # are visible: `visible` cuts them again
-    picked = top_mask(score, min(cfg.sparse_topk, MB))  # [G, tq, KV, MB]
-    dense = (pos + 1 < cfg.sparse_dense_len)[..., None, None]
-    return jnp.where(dense, True, picked) & visible[:, :, None]
-
-
-def top_mask(score, k: int):
-    """The k largest of score [..., n] float32 along its last axis as a bool
-    mask, equal scores to the lower index: what `lax.top_k` picks, without
-    its sort (0.8 ms a layer of a mixed step on the chip). The k-th largest
-    value is found bit by bit on the floats' order-preserving integer keys
-    (32 counts of "how many are at least this"); everything above it is in,
-    and of its equals the first few by index."""
-    bits = jax.lax.bitcast_convert_type(score, jnp.int32)
-    # a float's bits as an unsigned key of the same order (-inf lowest)
-    key = jax.lax.bitcast_convert_type(
-        jnp.where(bits < 0, ~bits, bits ^ jnp.int32(-2 ** 31)), jnp.uint32)
-
-    def bit(i, t):
-        cand = t | jnp.left_shift(jnp.uint32(1), jnp.uint32(31) - i.astype(
-            jnp.uint32))
-        enough = jnp.sum(key >= cand[..., None], axis=-1) >= k
-        return jnp.where(enough, cand, t)
-
-    kth = jax.lax.fori_loop(0, 32, bit,
-                            jnp.zeros(score.shape[:-1], jnp.uint32))[..., None]
-    above = key > kth
-    equal = key == kth
-    room = k - jnp.sum(above, axis=-1, keepdims=True)
-    return above | (equal & (jnp.cumsum(equal, axis=-1) <= room))
+def tile_meta(cfg: ModelConfig, rows, pos, tq: int):
+    """The scoring kernel's scalars, once a forward: [G, 4] int32 a query
+    tile of tq flat tokens, (fleet row, the tile's first position, its live
+    queries, the blocks that hold the row up to its LAST position in this
+    launch: the compressed keys a query of the launch may score)."""
+    tok_row, (R, MB) = rows.tok_row, rows.table.shape
+    live = tok_row >= 0
+    rix = jnp.maximum(tok_row, 0)
+    last = jnp.full((R,), -1, jnp.int32).at[rix].max(jnp.where(live, pos, -1))
+    tile_live = live.reshape(-1, tq)
+    row = jnp.maximum(jnp.max(tok_row.reshape(-1, tq), axis=1), 0)
+    far = jnp.iinfo(jnp.int32).max
+    start = jnp.min(jnp.where(tile_live, pos.reshape(-1, tq), far), axis=1)
+    n = jnp.sum(tile_live, axis=1, dtype=jnp.int32)
+    return jnp.stack([row, jnp.where(n > 0, start, 0), n,
+                      jnp.minimum(last[row] // cfg.sparse_block + 1, MB)],
+                     axis=1)
 
 
 def page_lists(chosen, width: int):
@@ -340,17 +280,15 @@ def list_width(cfg: ModelConfig, tq: int, MB: int) -> int:
 
 @jax.named_scope("sparse_select")
 def compressed_keys(cfg: ModelConfig, k, pool_k, pool_ck, layer: int, rows,
-                    pos, tq: int):
-    """Write the compressed keys that END at this launch's tokens and gather
-    each query tile's row's. k [W, KV, Dh]: the launch's new keys (normed);
-    pool_k [Ls, N, KV, bs, Dh] does not hold them yet (`layer` this layer's
-    index in it), pool_ck [N x slots, KV x Dh] this layer's leaf. A key
-    window's tokens are this launch's
-    (side by side on the flat axis where they are the same row's) or older
-    ones of the row, read from the pool's current and previous block.
-    Returns (pool_ck, ck [G, KV, MB x slots, Dh] by query tile of tq tokens:
-    a tile's tokens are one row's)."""
-    W = k.shape[0]
+                    pos):
+    """Write the compressed keys that END at this launch's tokens. k
+    [W, KV, Dh]: the launch's new keys (normed); pool_k [Ls, N, KV, bs, Dh]
+    does not hold them yet (`layer` this layer's index in it), pool_ck
+    [N, rows, Dh] this layer's leaf (module docstring). A key window's
+    tokens are this launch's (side by side on the flat axis where they are
+    the same row's) or older ones of the row, read from the pool's current
+    and previous block. Returns the leaf."""
+    W, KV, Dh = k.shape
     bs, stride, kernel = cfg.sparse_block, cfg.sparse_stride, cfg.sparse_kernel
     tok_row, table = rows.tok_row, rows.table
     MB = table.shape[1]
@@ -375,17 +313,13 @@ def compressed_keys(cfg: ModelConfig, k, pool_k, pool_ck, layer: int, rows,
     now = k.astype(F32)[jnp.maximum(flat, 0)]  # [W, kernel, KV, Dh]
     total = jnp.sum(jnp.where(same[:, :, None, None], now, was), axis=1)
     mean = total / kernel
-    slots = bs // stride
-    at = jnp.where(ends, cur * slots + (pos % bs) // stride,
-                   pool_ck.shape[0])  # out of range: dropped
-    pool_ck = pool_ck.at[at].set(
-        mean.reshape(W, -1).astype(pool_ck.dtype), mode="drop")
-    tile_row = jnp.maximum(jnp.max(tok_row.reshape(W // tq, tq), axis=1), 0)
-    held = (table[tile_row][:, :, None] * slots
-            + jnp.arange(slots, dtype=jnp.int32)).reshape(W // tq, MB * slots)
-    KV, Dh = k.shape[1:]
-    return pool_ck, pool_ck[held].reshape(
-        W // tq, MB * slots, KV, Dh).transpose(0, 2, 1, 3)
+    slots, N, RB = bs // stride, *pool_ck.shape[:2]
+    # the leaf's rows side by side: KV head kv's key at row kv x slots + slot
+    # of its block (out of range: dropped)
+    at = jnp.where(ends, cur * RB + (pos % bs) // stride, N * RB)[:, None] + (
+        jnp.arange(KV, dtype=jnp.int32) * slots)[None, :]
+    return pool_ck.reshape(N * RB, Dh).at[at].set(
+        mean.astype(pool_ck.dtype), mode="drop").reshape(N, RB, Dh)
 
 
 # -- the mixers ---------------------------------------------------------------
@@ -420,21 +354,25 @@ def _project(cfg, lp, h, kind: str, heads_q: int, heads_k: int):
 
 
 def sparse_attention(cfg: ModelConfig, lp: Params, h, pool, layer: int, hook,
-                     rows, pos, tq: int):
+                     rows, pos, tq: int, tiles):
     """The "minicpm4" mixer over a paged launch's flat tokens: normed h
-    [W, 1, D] at positions pos [W]. Returns (float32 [W, 1, D], pool)."""
+    [W, 1, D] at positions pos [W]; tiles: `tile_meta` of the launch.
+    Returns (float32 [W, 1, D], pool)."""
     W = h.shape[0]
     H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q, k, v, gate = _project(cfg, lp, h[:, 0], "sparse", H, KV)
     MB = rows.table.shape[1]
     with jax.named_scope("sparse_select"):
         G = W // tq
-        ck_pool, ck = compressed_keys(cfg, k[:, 0], pool["k"],
-                                      pool["ck"][layer], layer, rows, pos,
-                                      tq)
-        chosen = select_blocks(
-            cfg, q[:, 0].reshape(G, tq, KV, H // KV, Dh), ck,
-            pos.reshape(G, tq))
+        ck_pool = compressed_keys(cfg, k[:, 0], pool["k"],
+                                  pool["ck"][layer], layer, rows, pos)
+        _, chosen = sparse_select.select_blocks(
+            q[:, 0].reshape(G, tq, KV, H // KV, Dh), ck_pool, rows.table,
+            tiles, block=cfg.sparse_block, stride=cfg.sparse_stride,
+            kernel=cfg.sparse_kernel, topk=cfg.sparse_topk,
+            window=cfg.sparse_window, init=cfg.sparse_init_blocks,
+            dense_len=cfg.sparse_dense_len,
+            interpret=resolve_interpret(None))
         chosen &= (rows.tok_row >= 0).reshape(G, tq, 1, 1)
         pages = page_lists(chosen, list_width(cfg, tq, MB))
     attn, new_k, new_v = hook(
@@ -511,6 +449,8 @@ def forward_layers(cfg: ModelConfig, layers: Params, x, cache, pos,
     tq = attn_hook.tile
     with jax.named_scope("linear_attn"):  # the rotary tables, once a forward
         cos, sin = rope_cos_sin(pos[:, None], cfg.head_dim, cfg.rope_theta)
+    with jax.named_scope("attn"), jax.named_scope("sparse_select"):
+        tiles = tile_meta(cfg, rows, pos, tq)
     dt = cfg.jnp_dtype
     r = cfg.residual_multiplier or 1.0
 
@@ -525,7 +465,8 @@ def forward_layers(cfg: ModelConfig, layers: Params, x, cache, pos,
             h = rms_norm(x, layers["op_norm"][li], cfg.norm_eps).astype(dt)
             if kind == "minicpm4":
                 out, new = sparse_attention(cfg, row("sparse", ia), h, new,
-                                            ia, attn_hook, rows, pos, tq)
+                                            ia, attn_hook, rows, pos, tq,
+                                            tiles)
                 ia += 1
             else:
                 out, new = linear_attention(cfg, row("linear", il), h, new,

@@ -116,6 +116,11 @@ LINEAR_STATE_ROWS_HELP = (
     "wrote (the launch records' state_rows), held = slots x the launch's "
     "steps; touched / held is the share of the leaf a launch has to move"
 )
+SPARSE_SCORED_KEYS_HELP = (
+    "compressed keys the sparse layers' selection scored, a layer and KV "
+    "head: each row of a launch's once a step, up to the row's length (the "
+    "launch records' ck_scored)"
+)
 SPARSE_ROWS_HELP = (
     "row-steps of the sparse attention layers by branch: dense = fewer "
     "positions visible than the dense length (plain causal attention), "

@@ -92,7 +92,7 @@ def test_the_programs_are_the_cells(one_chip, no_persistent_cache, config):
         assert EP.window_row_budget(cfg.attn_window, built.width, 128) == 37
     if config.startswith("minicpm-sala"):
         assert (len(cfg.attn_layers), len(cfg.linear_layers)) == (4, 12)
-        assert built.pool["ck"][0].shape == (9216 * 4, 2 * 128)
+        assert built.pool["ck"][0].shape == (9216, 16, 128)
         assert built.pool["lin"][0].shape == (16, 32, 128, 128)
         assert built.pool["snap"][0].shape[1:] == (32, 128, 128)
     if cfg.diffusion_block:
@@ -202,6 +202,47 @@ def test_the_linear_scan_takes_its_leaf_in_place(one_chip, no_persistent_cache,
             assert "linear_attn/linear_scan" in line, line
         copies = re.findall(r"= f32\[([\d,]+)\]\{[^}]*\} copy\(", text)
         assert not shapes & set(copies), (name, shapes & set(copies))
+
+
+# -- the selection scores the compressed keys where they lie (ISSUE 50) ---------
+
+def test_the_selection_gathers_no_table_of_compressed_keys(
+        one_chip, no_persistent_cache, config):
+    """Both step programs of a configuration with sparse attention layers
+    run the scoring's kernel once a sparse layer under the selection's
+    scope, each call the SAME lowered kernel (a `jax.jit` of its own: a
+    stack's layers trace and lower it once), on the layer's `ck` leaf as it
+    lies in the pool; no instruction makes a buffer of a gathered table of
+    compressed keys (tiles, or slots, x the table's 4,128 keys x the KV
+    heads' 256 numbers: what `pool_ck[held]` and its relayout were), nor of
+    the scores over one (`f32[17,8,2,16,4128]`). The six other
+    configurations have no such leaf: their programs are the parent's
+    (`tests/dense_equal.py --compare-programs`)."""
+    from dense_equal import canon
+
+    built = cell_programs(config)
+    if not built.cfg.linear_layers:
+        assert "ck" not in built.pool
+        return
+    cfg, MB = built.cfg, 66048 // 64
+    keys = MB * (cfg.sparse_block // cfg.sparse_stride)
+    assert keys == 4128 and built.pool["ck"][0].shape == (9216, 16, 128)
+    for name, text in built.texts.items():
+        calls = [line for line in text.splitlines()
+                 if re.search(r"%select_blocks[\w.\-]* = .*custom-call\(", line)]
+        assert len(calls) == len(cfg.attn_layers) == 4, (name, len(calls))
+        assert all("attn/sparse_select" in line for line in calls)
+        assert all("bf16[9216,16,128]" in line for line in calls)
+        body, kernels = canon(text)
+        bodies = {kernels[int(n) - 1] for n in re.findall(
+            r"%select_blocks[\w.\-]* = [^\n]*<kernel (\d+)>", body)}
+        assert len(bodies) == 1, (name, len(bodies))
+        G = built.width // 8 if name == "mixed_step_ragged" else 16
+        gathered = re.findall(
+            rf"= \w+\[((?:{G},)?(?:{G * keys}|{keys}|{G},{keys})"
+            rf",(?:256|2,128|2,16|2,\d+,128)[\d,]*)\]", text)
+        assert gathered == [], (name, sorted(set(gathered)))
+        assert not re.search(rf"f32\[{G},\d+,2,16,{keys}\]", text), name
 
 
 # -- the expert banks ride outside the layer scan (ISSUE 32, 34) ----------------

@@ -377,3 +377,42 @@ def test_the_linear_scan_compiles_at_the_sala_cells_shapes(
     # ... and no instruction but the call makes a buffer of a leaf's shape
     made = re.findall(r"= f32\[16,32,128,128\]\{[^}]*\} ([\w\-]+)\(", text)
     assert set(made) <= {"parameter", "get-tuple-element", "bitcast"}, made
+
+
+# minicpm-sala-9b-16l's sparse layers: the selection's program at the cell's
+# shapes (a table of 1,032 blocks, a leaf of 9,216 whole (16, 128) tiles of
+# bfloat16, 2 KV heads of 16 query heads): a mixed launch's 17 tiles of 8 and
+# the decode chunk's tile a slot. What interpret mode cannot show: the copies
+# of a leaf's block (a whole tile), the stores of the laid-out keys, the
+# search's integer keys, and the working set (the row's keys, 2.4 MB, two
+# buffers of 16 blocks and the tile's scores and choices).
+@pytest.mark.parametrize("flat,tq", [(136, 8), (16, 1)], ids=["mixed", "decode"])
+def test_the_selection_compiles_at_the_sala_cells_shapes(
+    one_chip, no_persistent_cache, flat, tq
+):
+    from distributed_llm_inference_tpu.ops.sparse_select import select_blocks
+
+    cfg, slots, mb, pool = cell_pool("minicpm-sala-9b-16l")
+    S = _spec(one_chip)
+    leaf = pool["ck"][0]
+    assert (slots, mb) == (16, 1032)
+    assert (leaf.shape, leaf.dtype) == ((9216, 16, 128), jnp.bfloat16)
+    kv, group = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+    compiled = jax.jit(
+        lambda q, leaf, table, tiles: select_blocks(
+            q, leaf, table, tiles, block=cfg.sparse_block,
+            stride=cfg.sparse_stride, kernel=cfg.sparse_kernel,
+            topk=cfg.sparse_topk, window=cfg.sparse_window,
+            init=cfg.sparse_init_blocks, dense_len=cfg.sparse_dense_len,
+            interpret=False),
+    ).lower(S((flat // tq, tq, kv, group, cfg.head_dim), jnp.bfloat16),
+            S(leaf.shape, leaf.dtype), S((slots, mb), jnp.int32),
+            S((flat // tq, 4), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert any("select_blocks" in c for c in _custom_call_names(text))
+    # the leaf is read where it lies: no buffer of its shape, or of a
+    # gathered table's, beside the parameter
+    made = re.findall(r"= bf16\[(?:9216,16,128|\d+,4128,[\d,]+)\]\{[^}]*\} "
+                      r"([\w\-]+)\(", text)
+    assert set(made) <= {"parameter", "get-tuple-element", "bitcast"}, made
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**22

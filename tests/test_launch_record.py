@@ -400,6 +400,44 @@ def test_walk_shape_at_the_cells_shapes(name):
                            cfg.latent_dim > 0) == want
 
 
+# -- (a4) the compressed keys a selection's scoring reads (ISSUE 50) -------------
+
+def test_ck_scored_is_the_rows_keys_up_to_their_lengths():
+    """`ck_scored` of launches of known rows at `sala-docs-xlong`'s sizes,
+    a sparse layer and KV head: a row's compressed keys once a step, up to
+    the row's length (what ops/sparse_select.py copies), against what the
+    gather it replaced read: every tile's (a decode chunk: every slot's)
+    whole table of 66,048 / 16 = 4,128 keys, live or not, at any length."""
+    import types
+
+    import numpy as np
+
+    from distributed_llm_inference_tpu.models.registry import get_model_config
+
+    cfg = get_model_config("minicpm-sala")
+    tally = types.SimpleNamespace(total=0)
+    tally.inc = lambda n=1: setattr(tally, "total", tally.total + n)
+    series = types.SimpleNamespace(labels=lambda **kw: types.SimpleNamespace(
+        inc=lambda n=1: None))
+    host = types.SimpleNamespace(
+        _sparse=cfg, kv_block_size=64, n_slots=16, _m_ck_scored=tally,
+        _m_kv_tokens=series, _m_sparse_rows=series, _m_lin_rows=series)
+    host._kv_span = lambda *a: ContinuousEngine._kv_span(host, *a)
+    # a mixed launch: two decode rows at 30,000 beside a 120-token chunk
+    # that ends at 20,120 (17 tiles of 8)
+    mixed = ContinuousEngine._sparse_fields(
+        host, "mixed", [30001, 30001, 20120], 1)
+    assert mixed["ck_scored"] == 2 * 1876 + 1258 == 5010
+    assert 17 * 4128 / mixed["ck_scored"] > 14
+    # a 16-step decode chunk with two rows live of 16 slots, from 30,000
+    at = 30000 + np.arange(16)[None, :] + np.zeros((2, 1), int)
+    chunk = ContinuousEngine._sparse_fields(host, "chunk", at + 1, 16)
+    assert chunk["ck_scored"] == 2 * 16 * 1876  # (30,016 visible: still 1,876)
+    assert 16 * 16 * 4128 / chunk["ck_scored"] > 17
+    assert tally.total == mixed["ck_scored"] + chunk["ck_scored"]
+    assert chunk["state_rows"] == 32 and mixed["sparse_rows"] == 3
+
+
 # -- (b) both kinds of launch are counted; the old series keep their values ----
 
 def test_chunk_launches_and_row_steps_are_counted(runs):

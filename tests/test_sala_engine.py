@@ -193,10 +193,15 @@ def test_the_launch_records_counts_are_the_hand_count():
         assert r["kv_tokens_visible"] == n and r["state_rows"] == 1
         assert r["kv_tokens"] == (n if n < 24 else min(n, 3 * 8 + (n - 1) % 8 + 1))
         assert r["sparse_rows"] == int(n >= 24)
+        assert r["ck_scored"] == -(-n // 2)  # a key every 2 tokens
     assert mixed[0]["conv_state_resets"] == 1  # (the field of every such fleet)
     chunk = [r for r in f.records if r["phase"] == "chunk"]
     steps = sum(r["state_rows"] for r in chunk)
     assert steps == sum(r["sparse_rows"] for r in chunk) and steps >= 1
+    # the answer's positions 71 .. 74 visible, a step each: 36 + 36 + 37 + 37
+    assert sum(r["ck_scored"] for r in chunk) == 146
+    assert f.ce._m_ck_scored.value == sum(r["ck_scored"] for r in f.records)
+    assert "dli_sparse_scored_keys_total" in f.eng.metrics.render()
 
 
 def test_the_state_row_counter_is_the_launch_records():

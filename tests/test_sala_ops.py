@@ -1,9 +1,11 @@
 """The operators ISSUE 48 adds, each against a plain form of itself at tiny
 sizes on the CPU: the chunked decayed linear-attention scan against the
 token-by-token recurrence; the block selection against the reference's
-(cellbench/reference/sparse_linear_hybrid.py), tie rule included; the paged
-walk over a page LIST against the range walk (a full list: bit for bit) and
-against the gather path under a selection a query.
+(cellbench/reference/sparse_linear_hybrid.py), tie rule included; the
+scoring's kernel (ISSUE 50) against the XLA form it replaced, kept here, over
+launches of the engine's shapes; the paged walk over a page LIST against the
+range walk (a full list: bit for bit) and against the gather path under a
+selection a query.
 """
 
 import os
@@ -22,6 +24,7 @@ from distributed_llm_inference_tpu.ops.linear_attention import (
 from distributed_llm_inference_tpu.ops.paged_attention import (
     paged_flash_attend, ragged_paged_attend,
 )
+from distributed_llm_inference_tpu.ops.sparse_select import select_blocks
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "cellbench"))
@@ -193,12 +196,95 @@ def _ref_selection(cfg, q, k, t):
                              n_blocks=T // cfg.sparse_block, **sel)
 
 
+def _xla_block_scores(cfg, q, ck, pos):
+    """The block scores as `select_blocks` computed them in XLA before
+    ISSUE 50 gave them a kernel: q [G, tq, KV, group, Dh], ck
+    [G, KV, MB x slots, Dh] each tile's row's compressed keys gathered by
+    block and slot, pos [G, tq] -> [G, tq, KV, MB] float32."""
+    F32 = jnp.float32
+    G, tq, KV = q.shape[:3]
+    bs, stride, kernel = cfg.sparse_block, cfg.sparse_stride, cfg.sparse_kernel
+    slots = bs // stride
+    MB = ck.shape[2] // slots
+    blocks = jnp.arange(MB, dtype=jnp.int32)
+    end = (blocks[:, None] * bs
+           + jnp.arange(slots, dtype=jnp.int32)[None, :] * stride
+           + stride - 1)  # [MB, slots]
+    valid = (end <= pos[..., None, None]) & (end >= kernel - 1)
+    J = MB * slots
+    ok = valid.reshape(G, tq, 1, 1, J)
+    s = jnp.einsum("gtkhd,gkjd->gtkhj", q, ck.astype(q.dtype),
+                   preferred_element_type=F32) * cfg.head_dim ** -0.5
+    s = jnp.where(ok, s, -jnp.inf)
+    top = jnp.max(s, axis=-1, keepdims=True)
+    e = jnp.where(ok, jnp.exp(s - jnp.where(jnp.isfinite(top), top, 0.0)),
+                  0.0)
+    total = jnp.sum(e, axis=-1, keepdims=True)
+    r = jnp.sum(e / jnp.where(total > 0, total, 1.0), axis=3)  # [G,tq,KV,J]
+    r = jnp.where(ok[:, :, 0], r, -jnp.inf).reshape(G, tq, KV, MB, slots)
+    score = jnp.max(r, axis=4)
+    over = (kernel - 1) // stride
+    if over:
+        nxt = jnp.max(r[..., :over], axis=4)
+        nxt = jnp.concatenate(
+            [nxt[..., 1:], jnp.full_like(nxt[..., :1], -jnp.inf)], axis=3)
+        score = jnp.maximum(score, nxt)
+    return score
+
+
+def _xla_select_blocks(cfg, score, pos):
+    """Which blocks each query reads, as the model chose them in XLA before
+    ISSUE 50: score [G, tq, KV, MB] float32 block scores, pos [G, tq] ->
+    chosen [G, tq, KV, MB] bool (every block up to the query's own where
+    fewer than cfg.sparse_dense_len positions are visible)."""
+    bs, MB = cfg.sparse_block, score.shape[3]
+    blocks = jnp.arange(MB, dtype=jnp.int32)
+    visible = blocks <= (pos // bs)[..., None]  # [G, tq, MB]
+    forced = (blocks < cfg.sparse_init_blocks) | (
+        blocks >= (jnp.maximum(pos - (cfg.sparse_window - 1), 0)
+                   // bs)[..., None])
+    score = jnp.where(forced[:, :, None], jnp.inf, score)
+    score = jnp.where(visible[:, :, None], score, -jnp.inf)
+    # -inf scores (blocks past the query) may fill the k where few blocks
+    # are visible: `visible` cuts them again
+    picked = _xla_top_mask(score, min(cfg.sparse_topk, MB))  # [G, tq, KV, MB]
+    dense = (pos + 1 < cfg.sparse_dense_len)[..., None, None]
+    return jnp.where(dense, True, picked) & visible[:, :, None]
+
+
+def _xla_top_mask(score, k: int):
+    """The k largest of score [..., n] float32 along its last axis as a bool
+    mask, equal scores to the lower index: what `lax.top_k` picks, without
+    its sort (0.8 ms a layer of a mixed step on the chip). The k-th largest
+    value is found bit by bit on the floats' order-preserving integer keys
+    (32 counts of "how many are at least this"); everything above it is in,
+    and of its equals the first few by index."""
+    bits = jax.lax.bitcast_convert_type(score, jnp.int32)
+    # a float's bits as an unsigned key of the same order (-inf lowest)
+    key = jax.lax.bitcast_convert_type(
+        jnp.where(bits < 0, ~bits, bits ^ jnp.int32(-2 ** 31)), jnp.uint32)
+
+    def bit(i, t):
+        cand = t | jnp.left_shift(jnp.uint32(1), jnp.uint32(31) - i.astype(
+            jnp.uint32))
+        enough = jnp.sum(key >= cand[..., None], axis=-1) >= k
+        return jnp.where(enough, cand, t)
+
+    kth = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros(score.shape[:-1], jnp.uint32))[..., None]
+    above = key > kth
+    equal = key == kth
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    return above | (equal & (jnp.cumsum(equal, axis=-1) <= room))
+
+
 @pytest.mark.parametrize("tie", [False, True], ids=["random", "ties"])
 def test_the_selection_is_the_references(tie):
     """Every query of a 96-token row (12 blocks of 8; the first 23 below
-    the tiny dense length): the blocks `select_blocks` picks from the
-    pool's compressed keys are the reference's. `ties`: every key the same,
-    so every block scores alike and the lower block wins, in both."""
+    the tiny dense length): the blocks `select_blocks` picks from the block
+    scores over the pool's compressed keys are the reference's. `ties`:
+    every key the same, so every block scores alike and the lower block
+    wins, in both."""
     cfg, T = CFG, 96
     rng = np.random.default_rng(2)
     KV, g, Dh = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim
@@ -211,8 +297,9 @@ def test_the_selection_is_the_references(tie):
     tq = 8
     ck = jnp.broadcast_to(_keys_by_block(cfg, k)[None],
                           (T // tq,) + _keys_by_block(cfg, k).shape)
-    got = np.asarray(MS.select_blocks(
-        cfg, q.reshape(T // tq, tq, KV, g, Dh), ck, t.reshape(T // tq, tq)))
+    pos = t.reshape(T // tq, tq)
+    got = np.asarray(_xla_select_blocks(cfg, _xla_block_scores(
+        cfg, q.reshape(T // tq, tq, KV, g, Dh), ck, pos), pos))
     got = got.reshape(T, KV, -1)
     np.testing.assert_array_equal(got, want)
     reads = got.sum(-1)
@@ -223,6 +310,175 @@ def test_the_selection_is_the_references(tie):
     if tie:  # block 0, the window's block (88-95), the two lowest others
         np.testing.assert_array_equal(np.flatnonzero(got[95, 0]), [0, 1, 2, 11])
         np.testing.assert_array_equal(np.flatnonzero(got[92, 1]), [0, 1, 10, 11])
+
+
+# -- the scoring's kernel ---------------------------------------------------------
+
+# a launch over 4 fleet rows under a table 16 blocks wide (128 positions):
+# (tile, [(row, first position, tokens)] in flat order, options)
+SCORED = {
+    # rows of different lengths side by side: a chunk of three tiles (the
+    # last of 4 tokens) between decode tokens, launch padding behind
+    "lengths": (8, [(2, 100, 1), (0, 40, 20), (3, 9, 1), (1, 77, 1)], {}),
+    # a tile in the middle that carries nothing (a row switched off on the
+    # device), between two tiles of ONE row: its keys stay where they are
+    "dead-tile": (8, [(1, 48, 24), (3, 60, 1)], {"dead": [1]}),
+    # below the dense length (24): every visible block, whatever it scores
+    "below-dense": (8, [(0, 3, 8), (2, 22, 1), (1, 0, 1)], {}),
+    # a row at the table's full width, its last query at the last position
+    "full-width": (8, [(3, 112, 16), (0, 127, 1)], {}),
+    # every key alike: every block scores alike, the lower block wins
+    "ties": (8, [(0, 80, 16), (1, 95, 1)], {"tie": True}),
+    # the chunk's own compressed keys, written by `compressed_keys` in the
+    # same launch from the launch's keys and the pool's two blocks behind
+    "own-keys": (8, [(2, 37, 27), (1, 64, 1)], {"write": True}),
+    # the decode chunk's form: a tile a row, row 1 not active
+    "decode-rows": (1, [(0, 101, 1), (1, 50, 1), (2, 30, 1), (3, 127, 1)],
+                    {"dead": [1]}),
+    # the served dtype: a block's 8 keys and 8 rows of padding a tile
+    "bfloat16": (8, [(0, 64, 16), (1, 120, 1)], {"dtype": "bfloat16"}),
+}
+
+
+@pytest.mark.parametrize("case", list(SCORED))
+def test_the_kernels_block_scores_are_the_gathered_forms(case):
+    """`ops/sparse_select.select_blocks` over the pool's leaf through the
+    block table against the XLA form over each tile's row's gathered keys:
+    the same scores (-inf in the same places) and the same blocks, block
+    for block (the kernel's own choice, and the XLA rules over the kernel's
+    scores); in float32 they are the reference's too. Blocks the launch's rows do not hold are NaN in the leaf, and no
+    copy brings them; a held block's rows that are no key of the row yet
+    (and the leaf's padding rows) hold 1e30, which no score shows."""
+    from distributed_llm_inference_tpu.engine import paged as EP
+
+    tq, entries, opt = SCORED[case]
+    cfg = CFG.replace(dtype=opt.get("dtype", "float32"))
+    dt = cfg.jnp_dtype
+    rng = np.random.default_rng(7)
+    KV, g, Dh = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim
+    bs, st, kn = cfg.sparse_block, cfg.sparse_stride, cfg.sparse_kernel
+    slots, R, MB = bs // st, 4, 16
+    T = MB * bs
+    N = R * MB + 1
+    table = 1 + rng.permutation(N - 1).reshape(R, MB).astype(np.int32)
+    ks = rng.normal(size=(R, T, KV, Dh)).astype(np.float32)
+    if opt.get("tie"):
+        ks[:] = ks[:, :1]
+    ks = np.asarray(jnp.asarray(ks, dt).astype(jnp.float32))  # as the pool's
+    qs = np.asarray(jnp.asarray(rng.normal(size=(R, T, KV, g, Dh)), dt)
+                    .astype(jnp.float32))
+    width = -(-sum(-(-n // tq) for _, _, n in entries) // 2) * 2 * tq + tq
+    meta, tok_row, tok_pos, _, _ = EP.build_ragged_meta(
+        [(r, p, n, EP.RAGGED_PREFILL if n > 1 else EP.RAGGED_DECODE)
+         for r, p, n in entries], width=width, tile=tq)
+    for g_dead in opt.get("dead", []):
+        tok_row[g_dead * tq:(g_dead + 1) * tq] = -1
+    live = tok_row >= 0
+    # the leaf: a row's keys up to the launch's first token of the row
+    # (`write`) or its last
+    first = {r: p for r, p, _ in entries}
+    upto = {r: (p if opt.get("write") else p + n) for r, p, n in entries}
+    RB = EP.init_pool(cfg, 2, bs, n_slots=1)["ck"][0].shape[1]
+    assert RB == (16 if dt == jnp.bfloat16 else 8)
+    leaf = np.full((N, RB, Dh), np.nan, np.float32)
+    leaf[table[list(upto)]] = 1e30
+    by_block = {r: np.asarray(_keys_by_block(cfg, jnp.asarray(ks[r])))
+                for r in range(R)}  # [KV, MB x slots, Dh]
+    for r, n in upto.items():
+        for e in range(kn - 1, n, st):
+            blk, slot = table[r, e // bs], (e % bs) // st
+            leaf[blk, np.arange(KV) * slots + slot] = by_block[r][:, e // st]
+    leaf = jnp.asarray(leaf, dt)
+    W = len(tok_row)
+    rix = np.maximum(tok_row, 0)
+    q = jnp.asarray(np.where(live[:, None, None, None],
+                             qs[rix, tok_pos], np.nan), dt)
+    rows = EP.StateRows(jnp.asarray(tok_row), jnp.asarray(table),
+                        jnp.zeros((R,), bool), jnp.zeros((R,), jnp.int32))
+    pos = jnp.asarray(tok_pos)
+    if opt.get("write"):
+        # the pool's K of the positions before the launch; the launch's own
+        pool_k = np.zeros((1, N, KV, bs, Dh), np.float32)
+        for r, p in first.items():
+            for b in range(-(-p // bs)):
+                n = min(bs, p - b * bs)
+                pool_k[0, table[r, b], :, :n] = ks[r, b * bs:b * bs + n] \
+                    .transpose(1, 0, 2)
+        new_k = jnp.asarray(np.where(live[:, None, None], ks[rix, tok_pos],
+                                     np.nan), dt)
+        leaf = MS.compressed_keys(cfg, new_k, jnp.asarray(pool_k, dt), leaf,
+                                  0, rows, pos)
+    tiles = MS.tile_meta(cfg, rows, pos, tq)
+    G = W // tq
+    got, chosen = (np.asarray(a).reshape(W, KV, MB) for a in select_blocks(
+        q.reshape(G, tq, KV, g, Dh), leaf, rows.table, tiles, block=bs,
+        stride=st, kernel=kn, topk=cfg.sparse_topk, window=cfg.sparse_window,
+        init=cfg.sparse_init_blocks, dense_len=cfg.sparse_dense_len,
+        interpret=True))
+    # the gathered form, each tile's row's keys whole
+    tile_row = np.maximum(tok_row.reshape(G, tq).max(axis=1), 0)
+    gathered = jnp.asarray(np.stack([by_block[r] for r in tile_row]), dt)
+    want = np.asarray(_xla_block_scores(
+        cfg, jnp.where(live[:, None, None, None], q, 0).reshape(
+            G, tq, KV, g, Dh), gathered, pos.reshape(G, tq))
+    ).reshape(W, KV, MB)
+    assert not np.any(np.isnan(got[live]))
+    np.testing.assert_array_equal(np.isneginf(got[live]),
+                                  np.isneginf(want[live]))
+    np.testing.assert_allclose(
+        got[live], want[live], rtol=1e-5 if dt == jnp.float32 else 2e-2)
+    dead = np.repeat(~live.reshape(G, tq).any(axis=1), tq)
+    assert np.all(np.isneginf(got[dead])) and not np.any(chosen[dead])
+    pick = lambda score: np.asarray(_xla_select_blocks(  # noqa: E731
+        cfg, jnp.asarray(score).reshape(G, tq, KV, MB),
+        pos.reshape(G, tq))).reshape(W, KV, MB)[live]
+    chosen = chosen[live]
+    np.testing.assert_array_equal(chosen, pick(got))
+    if dt == jnp.float32:
+        np.testing.assert_array_equal(chosen, pick(want))
+        for r in upto:  # the reference, over the row's whole sequence
+            mine = live & (tok_row == r)
+            ref = np.asarray(_ref_selection(
+                cfg, jnp.asarray(qs[r]), jnp.asarray(ks[r]),
+                jnp.arange(T, dtype=jnp.int32)))
+            np.testing.assert_array_equal(
+                chosen[(tok_row == r)[live]], ref[tok_pos[mine]])
+    else:  # a near-tie may fall either way at bfloat16 products' rounding
+        assert np.mean(chosen == pick(want)) > 0.99
+    if opt.get("tie"):  # position 95: block 0, the window's, the two lowest
+        np.testing.assert_array_equal(np.flatnonzero(chosen[-1, 0]),
+                                      [0, 1, 2, 11])
+
+
+def test_a_stacks_sparse_layers_trace_the_scoring_kernel_once(monkeypatch):
+    """Two sparse layers' calls at one shape inside one program: the kernel's
+    body is traced once (`select_blocks` is a `jax.jit` of its own), so a
+    step program's start pays one trace and lowering of it, not one a
+    layer."""
+    from distributed_llm_inference_tpu.ops import sparse_select as SS
+
+    traced = []
+    body = SS._select_kernel
+
+    def counted(*refs, **static):
+        traced.append(static["tq"])
+        return body(*refs, **static)
+
+    monkeypatch.setattr(SS, "_select_kernel", counted)
+    KV, g, Dh, MB = 2, 2, 16, 16
+    q = jnp.zeros((3, 8, KV, g, Dh), jnp.float32)
+    leaf = jnp.zeros((9, 8, Dh), jnp.float32)
+    table = jnp.zeros((2, MB), jnp.int32)
+    meta = jnp.asarray([[0, 40, 8, 6], [0, 48, 2, 7], [1, 3, 0, 1]], jnp.int32)
+
+    def stack(q, leaf_a, leaf_b):
+        call = lambda leaf: SS.select_blocks(  # noqa: E731
+            q, leaf, table, meta, block=8, stride=2, kernel=4, topk=4,
+            window=8, init=1, dense_len=24, interpret=True)[0]
+        return call(leaf_a) + call(leaf_b)
+
+    jax.jit(stack).lower(q, leaf, leaf + 1)
+    assert traced == [8]
 
 
 def test_page_lists_hold_a_tiles_union_in_order():
