@@ -15,6 +15,15 @@ from typing import Optional
 import jax.numpy as jnp
 
 
+# the layer kinds (ModelConfig.layer_types) by what a layer of the kind KEEPS
+# for a row, whatever the arch: the pool's leaves, the prefix index's
+# snapshots and the start-up refusals are keyed on these, not on arch names
+KEEPS_CONV_STATE = frozenset({"conv", "mamba"})
+KEEPS_MATRIX_STATE = frozenset({"lightning-attn", "mamba"})
+KEEPS_KV = frozenset({"full_attention", "minicpm4", "attention"})
+SELECTS_BLOCKS = frozenset({"minicpm4"})
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters for a decoder-only causal LM.
@@ -26,6 +35,7 @@ class ModelConfig:
 
     name: str = "tinyllama-1.1b"
     # "llama" | "gpt2" | "mla_moe" | "lfm2" | "afmoe" | "minicpm_sala"
+    # | "granite_hybrid"
     arch: str = "llama"
     vocab_size: int = 32000
     dim: int = 2048
@@ -166,9 +176,22 @@ class ModelConfig:
     # score over mean-pooled keys selects; owns K/V and the compressed keys)
     # or "lightning-attn" (decayed linear attention over linear_heads heads:
     # a float32 matrix state a row and layer, no K/V).
+    # Arch "granite_hybrid" (models/granite_hybrid.py: granite-4.0-h) names
+    # each layer "mamba" (a Mamba-2 state-space mixer: a causal depthwise
+    # convolution of conv_kernel taps, with a bias under conv_bias, over
+    # [x | B | C], then a scan over ssm_heads heads of ssm_head_dim whose
+    # decay every token sets for itself; keeps the convolution's last
+    # conv_kernel - 1 inputs AND a float32 matrix state of ssm_heads x
+    # ssm_head_dim x ssm_state numbers a row, no K/V) or "attention" (GQA
+    # with no position encoding at attn_scale_override; owns K/V).
     layer_types: Optional[tuple] = None
     conv_kernel: int = 0
     linear_heads: int = 0
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    conv_bias: bool = False
     # the selection's constants (the family's published sparse_config): a
     # compressed key is the mean of sparse_kernel keys, one every
     # sparse_stride tokens; block 0.. sparse_init_blocks - 1 and the blocks
@@ -402,12 +425,36 @@ class ModelConfig:
                     f"{self.sparse_topk}")
             if self.conv_kernel:
                 raise ValueError("conv_kernel is arch 'lfm2' only")
+        elif self.arch == "granite_hybrid":
+            kinds = self.layer_types or ()
+            if (len(kinds) != self.n_layers
+                    or set(kinds) != {"mamba", "attention"}):
+                raise ValueError(
+                    f"arch 'granite_hybrid' needs layer_types: n_layers "
+                    f"({self.n_layers}) entries of 'mamba' / 'attention', "
+                    f"at least one of each; got {kinds!r}")
+            if min(self.ssm_heads, self.ssm_head_dim, self.ssm_state) < 1 \
+                    or self.conv_kernel < 2:
+                raise ValueError(
+                    "arch 'granite_hybrid' needs ssm_heads, ssm_head_dim, "
+                    "ssm_state and conv_kernel >= 2")
+            if self.ssm_groups != 1:
+                raise ValueError(
+                    f"arch 'granite_hybrid': the scan carries ONE group of "
+                    f"B and C for all heads (ops/ssm_scan.py); got "
+                    f"ssm_groups {self.ssm_groups}")
         elif self.layer_types is not None or self.conv_kernel:
             raise ValueError(
-                "layer_types is arch 'lfm2' / 'afmoe' / 'minicpm_sala' "
-                "only, conv_kernel arch 'lfm2' only")
+                "layer_types is arch 'lfm2' / 'afmoe' / 'minicpm_sala' / "
+                "'granite_hybrid' only, conv_kernel arch 'lfm2' / "
+                "'granite_hybrid' only")
         if self.linear_heads and self.arch != "minicpm_sala":
             raise ValueError("linear_heads is arch 'minicpm_sala' only")
+        if self.arch != "granite_hybrid" and (
+                self.ssm_heads or self.ssm_head_dim or self.ssm_state
+                or self.conv_bias):
+            raise ValueError("ssm_heads, ssm_head_dim, ssm_state and "
+                             "conv_bias are arch 'granite_hybrid' only")
         if self.expert_lo or self.n_experts_held:
             if self.arch != "afmoe":
                 raise ValueError("an expert share (expert_lo, "
@@ -441,34 +488,73 @@ class ModelConfig:
     def head_dim(self) -> int:
         return self.head_dim_override or self.dim // self.n_heads
 
-    @property
-    def conv_layers(self) -> tuple:
-        """The model's layers that are gated short convolutions (their
-        index in the stack); () outside arch 'lfm2'."""
+    def _layers_of(self, kinds) -> tuple:
         return tuple(i for i, kind in enumerate(self.layer_types or ())
-                     if kind == "conv")
+                     if kind in kinds)
 
     @property
-    def recurrent(self) -> bool:
-        """The model keeps a state a row beside (or in place of) K/V: gated
-        short convolutions or linear attention layers."""
-        return bool(self.conv_layers or self.linear_layers)
+    def conv_layers(self) -> tuple:
+        """The layers that KEEP a convolution state a row, the last
+        conv_kernel - 1 inputs of a causal convolution (their index in the
+        stack): gated short convolutions, state-space mixers."""
+        return self._layers_of(KEEPS_CONV_STATE)
 
     @property
     def linear_layers(self) -> tuple:
-        """The model's decayed linear-attention layers (their index in the
-        stack); () outside arch 'minicpm_sala'."""
-        return tuple(i for i, kind in enumerate(self.layer_types or ())
-                     if kind == "lightning-attn")
+        """The layers that KEEP a float32 matrix state a row (their index in
+        the stack): decayed linear attention, state-space mixers. Such a
+        state is too large to keep one a pool block: `state_tails`."""
+        return self._layers_of(KEEPS_MATRIX_STATE)
+
+    @property
+    def sparse_layers(self) -> tuple:
+        """The attention layers whose queries read a SELECTED set of blocks
+        and keep compressed keys for the selection beside their K/V."""
+        return self._layers_of(SELECTS_BLOCKS)
+
+    @property
+    def recurrent(self) -> bool:
+        """The model keeps a state a row beside (or in place of) K/V."""
+        return bool(self.conv_layers or self.linear_layers)
+
+    @property
+    def state_tails(self) -> bool:
+        """The pool keeps a row's state at the END of every block (a tail a
+        block, which a prefix hit restores): a convolution state alone is
+        small enough. A model with a matrix state keeps a few snapshots of
+        ALL its states instead (engine/block_prefix.py)."""
+        return bool(self.conv_layers) and not self.linear_layers
+
+    @property
+    def conv_channels(self) -> int:
+        """Channels of a kept convolution state (its last conv_kernel - 1
+        inputs): a state-space mixer's [x | B | C], else the model's width."""
+        if self.ssm_heads:
+            return (self.ssm_heads * self.ssm_head_dim
+                    + 2 * self.ssm_groups * self.ssm_state)
+        return self.dim
+
+    @property
+    def matrix_state_shape(self) -> tuple:
+        """A row's float32 matrix state in one layer, as the pool's leaf
+        holds it: a state-space mixer's [H / pack, N, pack x P]
+        (ops/ssm_scan.state_shape owns that layout), else linear attention's
+        [heads, Dh, Dh]."""
+        if self.ssm_heads:
+            from .ops.ssm_scan import state_shape
+
+            return state_shape(self.ssm_heads, self.ssm_head_dim,
+                               self.ssm_state)
+        return (self.linear_heads, self.head_dim, self.head_dim)
 
     @property
     def attn_layers(self) -> tuple:
-        """The layers that own K/V: every layer, arch 'lfm2''s attention
-        layers, or arch 'minicpm_sala''s sparse ones."""
+        """The layers that own K/V: every layer, or those of a kind that
+        keeps K/V where layer_types tells kinds that do from kinds that do
+        not."""
         if self.layer_types is None or self.arch == "afmoe":
             return tuple(range(self.n_layers))
-        return tuple(i for i, kind in enumerate(self.layer_types)
-                     if kind in ("full_attention", "minicpm4"))
+        return self._layers_of(KEEPS_KV)
 
     @property
     def experts_held(self) -> int:
@@ -501,9 +587,12 @@ class ModelConfig:
     @property
     def kv_pack(self) -> int:
         """K/V heads the paged pool stores side by side on one 128-lane
-        row (arch 'lfm2', head dim 64: 2), so that the paged kernels write
-        in place (ops/paged_attention.writes_in_place); 1: a head a row."""
-        if self.arch != "lfm2" or 128 % self.head_dim:
+        row (head dim 64: 2), so that the paged kernels write in place
+        (ops/paged_attention.writes_in_place); 1: a head a row. The
+        families that keep a state a row beside K/V pack (their attention
+        goes through models/lfm2.pack_heads), but for a selected read,
+        whose scoring reads a key head a row."""
+        if not self.recurrent or self.sparse_layers or 128 % self.head_dim:
             return 1
         pack = 128 // self.head_dim
         return pack if self.n_kv_heads % pack == 0 else 1
@@ -659,8 +748,10 @@ class EngineConfig:
     # to every decode row's step; 512 for a model whose routed layers
     # stream four or more experts for each one a token computes, where a
     # step costs the bytes of the whole bank whatever it carries and a
-    # document's prefill is one pass over the bank a chunk. An explicit
-    # value is obeyed. Either is rounded up to a whole number of query
+    # document's prefill is one pass over the bank a chunk; where a full
+    # fleet's float32 matrix states are more bytes than the weights, that
+    # budget on top of the fleet's decode tiles. An explicit value is
+    # obeyed. Either is rounded up to a whole number of query
     # tiles, and to at least one prefill tile above the decode fleet —
     # every active slot's decode row is reserved ahead of any prefill
     # chunk, so decode can never be starved by prefill and at least one

@@ -126,7 +126,8 @@ from ..utils.metrics import (
     KV_WINDOW_RELEASED_HELP, LINEAR_STATE_RESETS_HELP, LINEAR_STATE_ROWS_HELP,
     MOE_PAIRS_HELP,
     PREFIX_STATE_TOKENS_HELP, SLOT_RELEASE_HELP, SLOT_TURNOVER_HELP,
-    SPARSE_ROWS_HELP, SPARSE_SCORED_KEYS_HELP, STEPS_AHEAD_BUCKETS,
+    SPARSE_ROWS_HELP, SPARSE_SCORED_KEYS_HELP, SSM_STATE_RESETS_HELP,
+    STEPS_AHEAD_BUCKETS,
 )
 from ..utils.retry import overload_retry_after
 from ..utils.tracing import PhaseClock, Trace, abstract_call, sample_decision
@@ -293,7 +294,7 @@ class ContinuousEngine:
         engine: Any,
         n_slots: int = 8,
         chunk_steps: int = 16,
-        max_queue: int = 64,
+        max_queue: Optional[int] = None,
         chunk_lag: int = 2,
         slot_max_seq: Optional[int] = None,
         kv_pool_blocks: Optional[int] = None,
@@ -306,10 +307,11 @@ class ContinuousEngine:
     ):
         cfg = engine.cfg
         if cfg.arch not in ("llama", "gpt2", "mla_moe", "lfm2", "afmoe",
-                            "minicpm_sala"):
+                            "minicpm_sala", "granite_hybrid"):
             raise ValueError(
                 f"continuous batching supports the llama, gpt2, mla_moe, afmoe, "
-                f"lfm2 and minicpm_sala families; model arch is {cfg.arch!r}"
+                f"lfm2, minicpm_sala and granite_hybrid families; model arch "
+                f"is {cfg.arch!r}"
             )
         if cfg.recurrent:
             # (before the dense fleet would be built for it)
@@ -332,7 +334,12 @@ class ContinuousEngine:
         self.backend = engine.backend
         self.n_slots = int(n_slots)
         self.chunk_steps = int(chunk_steps)
-        self.max_queue = int(max_queue)
+        # The waiting room, where none is given: at least a fleet's worth,
+        # so a closed loop of twice as many callers as slots is never
+        # refused (a caller whose answer ends sends its next request before
+        # the freed slot has taken one from the queue).
+        self.max_queue = (max(64, 2 * self.n_slots) if max_queue is None
+                          else int(max_queue))
         # How many decode chunks may be in flight on the device before the
         # worker blocks on the oldest chunk's fetch. 1 = classic lag-1
         # (fetch N-1 overlaps compute N). Higher absorbs a host-side
@@ -450,10 +457,11 @@ class ContinuousEngine:
                     self._group_blocks[1], self.n_slots, self._max_blocks,
                     self.kv_block_size, cfg.attn_window, launch,
                 )
-            # A model of linear-attention layers (models/minicpm_sala.py) keeps
-            # a matrix state a slot, too large to keep one a block: a pool of
-            # state snapshots beside the K/V pool, which the prefix index gives
-            # out (engine/block_prefix.py). A prompt's prefill leaves the state
+            # A model whose layers keep a matrix state a slot
+            # (cfg.linear_layers: linear attention, state-space mixers), too
+            # large to keep one a block: a pool of state snapshots beside the
+            # K/V pool, which the prefix index gives out
+            # (engine/block_prefix.py). A prompt's prefill leaves its states
             # at its last two whole-block boundaries there (`_snap_points`), and
             # a hit is as deep as the deepest block that has one.
             self._snap_pool = 0
@@ -550,13 +558,14 @@ class ContinuousEngine:
         self._spec_k_max = max(0, int(getattr(ecfg, "spec_draft_len", 0)))
         self._spec_auto = bool(getattr(ecfg, "spec_decode", False))
         self._spec_capable = bool(self._chunked and self._spec_k_max > 0)
-        # A model with recurrent layers (cfg.conv_layers, models/lfm2.py)
-        # keeps a state a slot and a state tail a block in the pool
-        # (engine/paged.init_pool, StateRows). The host's part: a tenant's
-        # first prefill chunk is launched as RAGGED_FIRST, so the program
-        # starts the row from zeros or from the tail under a prefix hit,
-        # never from what the slot's previous tenant left; no row is
-        # drafted for (a rejected token would already be in the state).
+        # A model with recurrent layers (cfg.recurrent) keeps a state a slot
+        # in the pool, and a state tail a block (cfg.state_tails) or a pool
+        # of snapshots (engine/paged.init_pool, StateRows). The host's
+        # part: a tenant's first prefill chunk is launched as RAGGED_FIRST,
+        # so the program starts the row from zeros or from the tail or
+        # snapshot under a prefix hit, never from what the slot's previous
+        # tenant left; no row is drafted for (a rejected token would
+        # already be in the state).
         self._recurrent = cfg.recurrent
         if self._recurrent or self._wgrp is not None:
             # (a grouped pool: the position model decides which window
@@ -901,7 +910,7 @@ class ContinuousEngine:
         # set of blocks past the dense length: the position model counts
         # what is read (`_kv_span`, `_kv_walk`) and, beside it, what a range
         # walk would (`_sparse_fields`)
-        self._sparse = self.cfg if self.cfg.linear_layers else None
+        self._sparse = self.cfg if self.cfg.sparse_layers else None
         # whether attention reads the pool through the paged kernels'
         # block walk (_kv_walk) or gathers whole tables
         self._kv_walks = self.paged and self.cfg.attn_impl == "pallas"
@@ -1172,6 +1181,15 @@ class ContinuousEngine:
         self._m_lin_resets = m.counter(
             "dli_linear_state_resets_total", LINEAR_STATE_RESETS_HELP,
         ).labels()
+        self._m_ssm_resets = m.counter(
+            "dli_ssm_state_resets_total", SSM_STATE_RESETS_HELP,
+        ).labels()
+        # the one a cold start of this fleet counts, by what its layers keep
+        self._m_state_resets = {
+            (True, False): self._m_conv_resets,
+            (False, True): self._m_lin_resets,
+            (True, True): self._m_ssm_resets,
+        }.get((bool(cfg.conv_layers), bool(cfg.linear_layers)))
         self._m_sparse_rows = m.counter(
             "dli_sparse_rows_total", SPARSE_ROWS_HELP, ("branch",),
         )
@@ -1181,9 +1199,10 @@ class ContinuousEngine:
         self._m_ck_scored = m.counter(
             "dli_sparse_scored_keys_total", SPARSE_SCORED_KEYS_HELP,
         ).labels()
-        if cfg.linear_layers:
+        if cfg.sparse_layers:
             for branch in ("dense", "sparse"):
                 self._m_sparse_rows.labels(branch=branch)
+        if cfg.linear_layers:
             for state in ("touched", "held"):
                 self._m_lin_rows.labels(state=state)
         self._m_steps_ahead = m.histogram(
@@ -3158,20 +3177,36 @@ class ContinuousEngine:
         low = p0 // bs + 2 if p0 else 1
         return tuple(b * bs for b in (last - 1, last) if b >= low)
 
-    def _state_fields(self, spans, restored: int = 0, resets: int = 0):
+    def _state_fields(self, spans, state_rows: int, steps: int = 1,
+                      restored: int = 0, resets: int = 0, fresh: int = 0):
         """The launch record's fields of a fleet with recurrent layers, by
         the host position model. spans: (first position, tokens) of every
-        live row of the launch; a token that fills its block's last
-        position leaves the block's state tail (`conv_tail_writes`, blocks,
-        not x layers). restored / resets: what the tenants whose first
-        chunk rides this launch start from: prompt tokens below a restored
-        tail, slots let with zeroed state."""
-        bs = self.kv_block_size
-        tails = sum((st + n) // bs - st // bs for st, n in spans)
-        self._m_conv_tails.inc(tails)
-        return {"conv_tail_writes": int(tails),
-                "state_restored_tokens": int(restored),
-                "conv_state_resets": int(resets)}
+        live row of the launch; restored / resets: what the tenants whose
+        first chunk rides this launch start from: prompt tokens below a
+        restored tail or snapshot, slots let with zeroed state. Where the
+        pool keeps a tail a block (cfg.state_tails), a token that fills its
+        block's last position leaves it (`conv_tail_writes`, blocks, not x
+        layers). Where it keeps a matrix state a slot (a snapshot pool):
+        `state_fresh_rows`, the `fresh` rows that start from zeros or a
+        snapshot in this launch, and, unless `_sparse_fields` counts them,
+        `state_rows`, the row-steps that read and write a state (a decode
+        row a step, a prefill chunk once) of the slots x `steps` held."""
+        fields = {}
+        if self.cfg.state_tails:
+            bs = self.kv_block_size
+            tails = sum((st + n) // bs - st // bs for st, n in spans)
+            self._m_conv_tails.inc(tails)
+            fields["conv_tail_writes"] = int(tails)
+        fields.update(state_restored_tokens=int(restored),
+                      conv_state_resets=int(resets))
+        if self._snap_pool:
+            fields["state_fresh_rows"] = int(fresh)
+            if self._sparse is None:
+                self._m_lin_rows.labels(state="touched").inc(state_rows)
+                self._m_lin_rows.labels(state="held").inc(
+                    self.n_slots * steps)
+                fields["state_rows"] = int(state_rows)
+        return fields
 
     def _launch_record(self, phase: str, steps: int, kv_tokens: int,
                        kv_grid_tokens: int, kv_walk_steps: int,
@@ -3483,7 +3518,7 @@ class ContinuousEngine:
         if self._recurrent:
             diff_fields = self._state_fields(
                 [(int(self._host_pos[b]), int(live[b]))
-                 for b in np.flatnonzero(live)]
+                 for b in np.flatnonzero(live)], int(live.sum()), steps=K,
             )
         if self._sparse is not None:
             diff_fields.update(
@@ -4038,10 +4073,8 @@ class ContinuousEngine:
             # cold start lets the slot with zeroed state
             if p0:
                 self._m_state_tokens.inc(p0)
-            elif self._snap_pool:
-                self._m_lin_resets.inc()
             else:
-                self._m_conv_resets.inc()
+                self._m_state_resets.inc()
         rp = float(k.get("repetition_penalty", 1.0))
         presence_row = (
             np.asarray(eng._presence_rows([ids])[0]) if rp != 1.0
@@ -4507,12 +4540,14 @@ class ContinuousEngine:
             diff_fields = self._blk_fields(fwd, rode & alive_now, 1)
         if self._recurrent:
             firsts = [(job, st) for job, _, st in chunk_list if st == job.p0]
+            touched = [(int(self._host_pos[b]), 1) for b in active
+                       if self._host_pos[b] < self._host_end[b]] \
+                + [(st, n) for _, n, st in chunk_list]
             diff_fields = self._state_fields(
-                [(int(self._host_pos[b]), 1) for b in active
-                 if self._host_pos[b] < self._host_end[b]]
-                + [(st, n) for _, n, st in chunk_list],
+                touched, len(touched),
                 restored=sum(st for _, st in firsts),
                 resets=sum(1 for _, st in firsts if st == 0),
+                fresh=len(firsts),
             )
             if self._snap_pool:
                 diff_fields["state_snapshots_taken"] = snaps_taken

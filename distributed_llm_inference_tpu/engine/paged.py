@@ -121,9 +121,11 @@ def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
     unchanged, and a prefix hit at depth p0 (whole blocks) starts the
     slot's state from the tail of the last shared block: `StateRows`.
 
-    A model of sparse and linear attention layers (cfg.linear_layers,
-    models/minicpm_sala.py) keeps three kinds: "k" / "v" of its SPARSE
-    layers alone, with cfg.sparse_block tokens a block (a page of the
+    A model whose layers keep a float32 matrix state (cfg.linear_layers)
+    keeps a leaf a layer of them, "lin" a slot and "snap" a snapshot, the
+    second a pool of its own that the prefix index gives out. One of sparse
+    and linear attention layers (models/minicpm_sala.py) keeps three kinds:
+    "k" / "v" of its SPARSE layers alone, with cfg.sparse_block tokens a block (a page of the
     kernels' walk is a block of the selection); beside them a third leaf
     of the same blocks, "ck" (a leaf a sparse layer, [N, rows, Dh]: a
     block's KV x bs / stride keys padded to whole tiles, which the
@@ -137,31 +139,49 @@ def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
     2 MB a layer at 32 heads of 128, far too large to keep one a block as
     lfm2's tails are kept, so the prefix index decides which block
     boundaries have one (engine/block_prefix.py) and a hit is as deep as
-    the deepest that has."""
+    the deepest that has.
+
+    A model of state-space and attention layers (models/granite_hybrid.py:
+    its "mamba" layers are in cfg.conv_layers AND cfg.linear_layers) keeps
+    "k" / "v" of its attention layers alone, heads packed cfg.kv_pack a
+    row; and a leaf a mamba layer each of "conv" [n_slots, K-1, C] (the
+    convolution's last inputs, the parameter dtype), "lin" [n_slots,
+    H / pack, N, pack x P] FLOAT32 (cfg.matrix_state_shape), and "csnap" /
+    "snap", the same two by snapshot: ONE snapshot index names both states
+    of every layer at one block boundary, so a prefix hit restores the
+    convolution state with the matrix state (no tail a block: at 36 layers
+    a snapshot is 76 MB)."""
     if cfg.linear_layers:
         if n_slots is None:
             raise ValueError(f"{cfg.name}: the pool holds a state a slot "
                              f"(pass n_slots)")
-        if block_size != cfg.sparse_block:
-            raise ValueError(
-                f"{cfg.name}: a pool block is one block of the selection "
-                f"(pass a block size of {cfg.sparse_block}, not "
-                f"{block_size})")
-        Ls, Ll = len(cfg.attn_layers), len(cfg.linear_layers)
         dt, Dh = cfg.jnp_dtype, cfg.head_dim
-        kv = (Ls, n_blocks, cfg.n_kv_heads, block_size, Dh)
-        state = (cfg.linear_heads, Dh, Dh)
-        ck = (n_blocks, leaf_rows(cfg.n_kv_heads,
-                                  block_size // cfg.sparse_stride, dt), Dh)
-        return {
-            "k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt),
+        snaps = max(1, n_snapshots)
+
+        def a_layer(layers: tuple, first: int, shape: tuple, dtype) -> tuple:
             # (a leaf a layer: models/minicpm_sala.py says why)
-            "ck": tuple(jnp.zeros(ck, dt) for _ in range(Ls)),
-            "lin": tuple(jnp.zeros((n_slots,) + state, jnp.float32)
-                         for _ in range(Ll)),
-            "snap": tuple(jnp.zeros((max(1, n_snapshots),) + state,
-                                    jnp.float32) for _ in range(Ll)),
-        }
+            return tuple(jnp.zeros((first,) + shape, dtype) for _ in layers)
+
+        kv = (len(cfg.attn_layers), n_blocks, cfg.n_kv_heads // cfg.kv_pack,
+              block_size, Dh * cfg.kv_pack)
+        pool = {"k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt)}
+        if cfg.sparse_layers:
+            if block_size != cfg.sparse_block:
+                raise ValueError(
+                    f"{cfg.name}: a pool block is one block of the "
+                    f"selection (pass a block size of {cfg.sparse_block}, "
+                    f"not {block_size})")
+            ck = (leaf_rows(cfg.n_kv_heads, block_size // cfg.sparse_stride,
+                            dt), Dh)
+            pool["ck"] = a_layer(cfg.sparse_layers, n_blocks, ck, dt)
+        if cfg.conv_layers:
+            hist = (cfg.conv_kernel - 1, cfg.conv_channels)
+            pool["conv"] = a_layer(cfg.conv_layers, n_slots, hist, dt)
+            pool["csnap"] = a_layer(cfg.conv_layers, snaps, hist, dt)
+        state = cfg.matrix_state_shape
+        pool["lin"] = a_layer(cfg.linear_layers, n_slots, state, jnp.float32)
+        pool["snap"] = a_layer(cfg.linear_layers, snaps, state, jnp.float32)
+        return pool
     if cfg.arch == "afmoe":
         # K/V in groups by layer kind, each with its own blocks and block
         # table (the module docstring's "Groups"); n_blocks: one count a
@@ -179,14 +199,14 @@ def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
             (2, cfg.n_layers - cfg.first_k_dense, cfg.experts_held + share),
             jnp.int32)
         return pool
-    if cfg.conv_layers:
+    if cfg.state_tails:
         if n_slots is None:
             raise ValueError(f"{cfg.name}: the pool holds a state a slot "
                              f"(pass n_slots)")
         La, Lc = len(cfg.attn_layers), len(cfg.conv_layers)
         kv = (La, n_blocks, cfg.n_kv_heads // cfg.kv_pack, block_size,
               cfg.head_dim * cfg.kv_pack)
-        hist, dt = (cfg.conv_kernel - 1, cfg.dim), cfg.jnp_dtype
+        hist, dt = (cfg.conv_kernel - 1, cfg.conv_channels), cfg.jnp_dtype
         return {
             "k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt),
             "conv": jnp.zeros((Lc, n_slots) + hist, dt),
@@ -575,7 +595,7 @@ def refuse_unsupported_latent(cfg: ModelConfig, **asked):
             "unchunked": "the unchunked ragged admission: served by "
                          "chunked prefill only (keep chunked_prefill on)",
         })
-    if cfg.conv_layers:
+    if cfg.state_tails:
         lead = "a model with recurrent layers is served on one device " \
                "from the paged pool by chunked ragged prefill"
         why.update({
@@ -597,7 +617,7 @@ def refuse_unsupported_latent(cfg: ModelConfig, **asked):
             "no_pool": "a dense slot fleet: there is no dense recurrent "
                        "fleet (pass --kv-pool-blocks)",
         })
-    if cfg.linear_layers:
+    if cfg.sparse_layers:
         lead = "a model of sparse and linear attention layers is served " \
                "on one device from the paged pool by chunked ragged prefill"
         why.update({
@@ -619,6 +639,30 @@ def refuse_unsupported_latent(cfg: ModelConfig, **asked):
                     "already be in a linear layer's state",
             "no_pool": "a dense slot fleet: there is no dense fleet of "
                        "compressed keys and matrix states (pass "
+                       "--kv-pool-blocks)",
+        })
+    elif cfg.linear_layers:
+        lead = "a model of state-space and attention layers is served on " \
+               "one device from the paged pool by chunked ragged prefill"
+        why.update({
+            "quant": "weight quantization: ops/quant knows no leaf of the "
+                     "state-space mixer",
+            "kv_quant": "the int8 pool: heads are stored in pairs and the "
+                        "recurrent states have no scale",
+            "mesh": "pp / tp / ep / sp / dp meshes: layers of two kinds, "
+                    "a convolution state and a matrix state a slot and "
+                    "the snapshot pool are not partitioned "
+                    "(parallel/partition.py)",
+            "kv_shadow": "the host shadow store (and swap preemption, /kv "
+                         "export, the KV fabric): it copies K/V block "
+                         "pairs and would leave the state snapshots "
+                         "behind; pass --no-kv-shadow",
+            "unchunked": "the unchunked ragged admission: a row's states "
+                         "ride the mixed launch's slot rows only",
+            "spec": "speculative decoding: a rejected draft token would "
+                    "already be in a state-space layer's states",
+            "no_pool": "a dense slot fleet: there is no dense fleet of "
+                       "convolution and matrix states (pass "
                        "--kv-pool-blocks)",
         })
     if len(cfg.kv_groups) > 1:

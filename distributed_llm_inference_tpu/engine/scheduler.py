@@ -15,9 +15,11 @@ that planning:
     to the launch width `step_width` derives from what the model
     streams a step (128 flat tokens for a dense model, 512 for one
     whose routed layers stream four or more experts for each one a
-    token computes; an explicit `engine_cfg.step_token_budget` is
-    obeyed). Decode rows are reserved FIRST (prefill can never starve
-    decode — the TPOT guarantee); the remaining query tiles are the
+    token computes, and the fleet's decode tiles plus 128 where a full
+    fleet's matrix states outweigh the weights; an explicit
+    `engine_cfg.step_token_budget` is obeyed). Decode rows are reserved
+    FIRST (prefill can never starve decode — the TPOT guarantee); the
+    remaining query tiles are the
     per-step prefill budget. A prompt of any length therefore costs
     each decode step at most `width - n_slots x tile` extra flat tokens
     instead of a whole-prompt stall, and TTFT degrades gracefully (the
@@ -303,6 +305,27 @@ def _clamp_width(width: int, n_slots: int, tile: int) -> int:
     return clamped
 
 
+def _states_outweigh_weights(model_cfg, n_slots: int) -> bool:
+    """A full fleet's float32 matrix states (`ModelConfig.linear_layers`),
+    read and written once a step, are more bytes than the weights, counted
+    from the shapes `init_params` makes at the served dtype: the step's
+    largest stream is then the rows' own. granite-4.0-h-micro in bfloat16:
+    151 MB a row beside 6.38 GB of weights, so from 43 slots on;
+    minicpm-sala's 16 rows are 8% of its weights."""
+    if not model_cfg.linear_layers:
+        return False
+    import jax
+
+    from ..models import api as M
+
+    shapes = jax.eval_shape(
+        lambda: M.init_params(model_cfg, jax.random.PRNGKey(0)))
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(shapes))
+    row = 2 * 4 * len(model_cfg.linear_layers) * math.prod(
+        model_cfg.matrix_state_shape)
+    return n_slots * row > weights
+
+
 def step_width(model_cfg, n_slots: int, tile: int = 8,
                budget: Optional[int] = None) -> int:
     """Flat-token width of the mixed launch: the ONE place it is decided
@@ -315,13 +338,23 @@ def step_width(model_cfg, n_slots: int, tile: int = 8,
     and the bank is ROUTED_STREAM_RATIO times what a token computes,
     DENSE_STEP_TOKENS otherwise. That covers the all-experts einsum of
     `models/llama.moe_ffn` (`n_experts` without `moe_ffn_dim`), where a
-    token computes every expert it streams. The slot clamp stays on top."""
+    token computes every expert it streams. The slot clamp stays on top.
+
+    Where a full fleet's states outweigh the weights
+    (`_states_outweigh_weights`) the budget is PREFILL's, on top of the
+    fleet's decode tiles: a narrower step saves no stream there, and every
+    step a starved prefill adds costs a pass over all the rows' states and
+    the fleet's padded tiles again (granite-4.0-h-micro at 64 slots:
+    64 x 8 + 128 = 640 where the clamp alone gave 520, 8-32 prompt tokens
+    a step, ~9 mixed steps of ~45 ms to admit what 2 now do)."""
     if budget is None:
         wide = model_cfg.moe_ffn_dim and (
             model_cfg.n_experts // model_cfg.n_experts_per_tok
             >= ROUTED_STREAM_RATIO
         )
         budget = ROUTED_STEP_TOKENS if wide else DENSE_STEP_TOKENS
+        if _states_outweigh_weights(model_cfg, n_slots):
+            budget += int(n_slots) * tile
     return _clamp_width(budget, n_slots, tile)
 
 

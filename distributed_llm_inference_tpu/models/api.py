@@ -18,7 +18,11 @@ so, and serves one device from a pool grouped by layer kind; minicpm_sala
 (models/minicpm_sala.py) alternates sparse attention layers, which read the
 blocks a score over mean-pooled keys selects, with decayed linear-attention
 layers, keeps K/V and compressed keys for the first and a float32 matrix
-state a row for the second, and serves one device from the paged pool only.
+state a row for the second, and serves one device from the paged pool only;
+granite_hybrid (models/granite_hybrid.py) alternates Mamba-2 state-space
+layers, which keep a convolution state AND a float32 matrix state a row,
+with attention layers that take no position encoding, and serves one device
+from the paged pool only.
 Routed experts
 are one module for the families that have them (models/experts.py: `route`, `routed_ffn`, the
 grouped product): a configuration that routes serves one device, from the
@@ -28,10 +32,11 @@ paged pool (engine/paged.refuse_unsupported_latent).
 from __future__ import annotations
 
 from ..config import ModelConfig
-from . import afmoe, gpt2, lfm2, llama, minicpm_sala, mla_moe
+from . import afmoe, gpt2, granite_hybrid, lfm2, llama, minicpm_sala, mla_moe
 
 _FAMILIES = {"llama": llama, "gpt2": gpt2, "mla_moe": mla_moe, "lfm2": lfm2,
-             "afmoe": afmoe, "minicpm_sala": minicpm_sala}
+             "afmoe": afmoe, "minicpm_sala": minicpm_sala,
+             "granite_hybrid": granite_hybrid}
 
 
 def family(cfg: ModelConfig):

@@ -240,6 +240,26 @@ register(ModelConfig(
     residual_multiplier=1.4 / 32 ** 0.5, logits_divider=16.0,
     eos_token_id=2, bos_token_id=1, pad_token_id=0,
 ))
+# --- granite-4.0-h-micro (Mamba-2 state-space layers beside attention
+# without a position encoding; ibm-granite/granite-4.0-h-micro config.json,
+# model_type granitemoehybrid, 3B dense: num_local_experts 0, every layer's
+# FFN the "shared" SwiGLU of 8,192: models/granite_hybrid.py). layer_types as
+# published: attention at layers 5, 15, 25 and 35 of 40, Mamba-2 elsewhere
+# (64 heads of 64, state 128, one group, 4 taps with a bias, expand 2).
+# position_embedding_type "nope"; Granite's four scalars. Not in config.json
+# and so assumed: the special tokens.
+GRANITE_H_MICRO_LAYERS = tuple(
+    "attention" if i % 10 == 5 else "mamba" for i in range(40))
+register(ModelConfig(
+    name="granite-4.0-h-micro", arch="granite_hybrid", vocab_size=100352,
+    dim=2048, n_layers=40, n_heads=32, n_kv_heads=8, ffn_dim=8192,
+    max_seq_len=131072, norm_eps=1e-5, head_dim_override=64,
+    layer_types=GRANITE_H_MICRO_LAYERS, conv_kernel=4, conv_bias=True,
+    ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=1,
+    embed_multiplier=12.0, residual_multiplier=0.22,
+    attn_scale_override=0.015625, logits_divider=8.0, tie_embeddings=True,
+    eos_token_id=2, bos_token_id=1, pad_token_id=0,
+))
 register(ModelConfig(
     name="qwen3-8b", arch="llama", vocab_size=151936, dim=4096,
     n_layers=36, n_heads=32, n_kv_heads=8, ffn_dim=12288, max_seq_len=40960,
@@ -419,6 +439,19 @@ register(ModelConfig(
     sparse_window=8, sparse_init_blocks=1, sparse_dense_len=24,
     embed_multiplier=12.0, residual_multiplier=1.4 / 32 ** 0.5,
     logits_divider=4.0, eos_token_id=2, bos_token_id=1,
+))
+# (state-space layers at toy sizes: 4 heads of 16 over a state of 8, so a
+# row's matrix state is two packed rows of [8, 128]; attention 4/2 at head
+# dim 64, packed two a pool row as the published model's)
+register(ModelConfig(
+    name="test-granite-tiny", arch="granite_hybrid", vocab_size=256, dim=64,
+    n_layers=4, n_heads=4, n_kv_heads=2, ffn_dim=96, max_seq_len=256,
+    norm_eps=1e-5, head_dim_override=64,
+    layer_types=("mamba", "attention", "mamba", "mamba"),
+    conv_kernel=4, conv_bias=True, ssm_heads=16, ssm_head_dim=16,
+    ssm_state=8, embed_multiplier=12.0, residual_multiplier=0.22,
+    attn_scale_override=0.015625, logits_divider=8.0, tie_embeddings=True,
+    eos_token_id=2, bos_token_id=1,
 ))
 register(ModelConfig(
     name="test-trinity-tiny", arch="afmoe", vocab_size=256, dim=64,

@@ -111,10 +111,17 @@ LINEAR_STATE_RESETS_HELP = (
     "tenant left in the slot"
 )
 LINEAR_STATE_ROWS_HELP = (
-    "slot-steps of the linear-attention layers' state leaf by state: "
+    "slot-steps of the matrix-state layers' state leaf by state: "
     "touched = a row that carried a token, whose state the scan read and "
     "wrote (the launch records' state_rows), held = slots x the launch's "
     "steps; touched / held is the share of the leaf a launch has to move"
+)
+# fleets of a model of state-space layers (models/granite_hybrid.py: a
+# convolution state AND a matrix state a slot)
+SSM_STATE_RESETS_HELP = (
+    "slots let to a tenant with zeroed state-space states (a cold start: "
+    "no prefix hit restored a snapshot), whatever the previous tenant left "
+    "in the slot"
 )
 SPARSE_SCORED_KEYS_HELP = (
     "compressed keys the sparse layers' selection scored, a layer and KV "

@@ -38,7 +38,7 @@ def pytest_generate_tests(metafunc):
 # Asserted on the builder's result, which read the sizes from
 # cellbench/configs/<name>.json: the mixed launch's width
 # (engine/scheduler.step_width: 128 / 136 for the dense two, 512 where the
-# experts route), the pool's blocks (a grouped pool: the window group a
+# experts route, the fleet's tiles and 128 where its states outweigh the weights), the pool's blocks (a grouped pool: the window group a
 # quarter of the global one's, its row budget 37), one pool leaf's shape, the
 # labels of the family (utils/tracing.STEP_SCOPES) and the least the pool
 # holds (2 GB and more; sdar-batch's 32 rows of 2,048 tokens: 0.94 GB).
@@ -57,6 +57,13 @@ CELLS = {
     "minicpm-sala-9b-16l": dict(
         width=136, blocks=9216, leaf=("k", (4, 9216, 2, 64, 128)),
         scopes=DENSE_SCOPES + ("linear_attn", "linear_scan", "sparse_select")),
+    # (K/V belongs to 4 of the 40 layers, heads packed two a row; the 36
+    # mamba layers' convolution and matrix states and their snapshots are a
+    # leaf a layer; 64 slots' tiles of 8 and the dense budget on top for prefill,
+    # since the fleet's states outweigh the weights: 640)
+    "granite-4.0-h-micro": dict(
+        width=640, blocks=2048, leaf=("k", (4, 2048, 4, 64, 128)),
+        scopes=DENSE_SCOPES + ("ssm_mix", "ssm_scan")),
     "mistral-7b-16l": dict(
         width=136, blocks=271, leaf=("k", (16, 271, 8, 128, 128)),
         scopes=DENSE_SCOPES),
@@ -95,6 +102,16 @@ def test_the_programs_are_the_cells(one_chip, no_persistent_cache, config):
         assert built.pool["ck"][0].shape == (9216, 16, 128)
         assert built.pool["lin"][0].shape == (16, 32, 128, 128)
         assert built.pool["snap"][0].shape[1:] == (32, 128, 128)
+    if config.startswith("granite"):
+        assert (len(cfg.attn_layers), len(cfg.linear_layers), cfg.kv_pack) == (4, 36, 2)
+        assert cfg.conv_layers == cfg.linear_layers and not cfg.state_tails
+        # (float32 is the configuration's STATED state, and `correct` cannot
+        # tell it from bfloat16: this and tests/test_granite_ops.py hold it)
+        assert built.pool["lin"][0].shape == (64, 32, 128, 128)
+        assert built.pool["snap"][0].shape == (16, 32, 128, 128)
+        assert all(a.dtype == "float32" for a in built.pool["lin"] + built.pool["snap"])
+        assert built.pool["conv"][0].shape == (64, 3, 4352)
+        assert built.pool["csnap"][0].shape == (16, 3, 4352)
     if cfg.diffusion_block:
         # the decode chunk's flat axis: 32 slots x 2 blocks of 4 = 256 tokens
         assert "bf16[256,2048]" in built.texts["decode_slots_paged"]
@@ -146,6 +163,11 @@ def _temporaries_bound(config, built):
         # decode chunk's relayout of [4096, 4096] projections, once a launch
         # (34 MB each; PERF.md section 6, PR 48)
         return 0.9 * built.pool["k"].size * 2
+    if config.startswith("granite"):
+        # nothing of the live states' (4.8 GB), the snapshot pool's (1.2 GB)
+        # or a K/V leaf's size (0.27 GB): the mixed step's [64, 640, 640]
+        # within-launch decays (105 MB each) and its flat tokens' activations
+        return 0.8e9
     bound = 0.45 * _pool_bytes(built.pool)
     if config.startswith("sdar"):
         bound += built.cfg.dim * built.cfg.vocab_size * 2
@@ -179,29 +201,40 @@ def test_step_programs_hold_no_copy_of_the_pool_at_cell_sizes(
 
 def test_the_linear_scan_takes_its_leaf_in_place(one_chip, no_persistent_cache,
                                                  config):
-    """Both step programs of a configuration with linear-attention layers
-    run the scan's kernel once a linear layer, under its scope's name (what
-    `linear_attn_roofline` finds it by), with the layer's `lin` leaf as the
-    call's aliased output; no `copy` makes a buffer of a state leaf's or the
-    snapshot pool's shape (the test above holds the leaves among the
-    program's aliased arguments)."""
+    """Both step programs of a configuration whose layers keep a matrix
+    state run the scan's kernel once such a layer, under its scope's name
+    (what `linear_attn_roofline` / `ssm_scan_roofline` find it by), with the
+    layer's `lin` leaf as the call's aliased output; no `copy` makes a
+    buffer of a state leaf's or the snapshot pool's shape (the test above
+    holds the leaves among the program's aliased arguments), and no
+    instruction but a row's own `dynamic-update-slice` writes one where a
+    snapshot is restored or kept a row at a time
+    (models/granite_hybrid._move_rows)."""
     built = cell_programs(config)
     if not built.cfg.linear_layers:
         assert "lin" not in built.pool
         return
     lin, snap = built.pool["lin"][0], built.pool["snap"][0]
     shapes = {",".join(map(str, leaf.shape)) for leaf in (lin, snap)}
+    # (operands: the prefetched scalars, the tokens' blocks, the decay's
+    # power, the state: ops/linear_attention.linear_scan, ops/ssm_scan.ssm_scan)
+    kernel, scope, alias = ("linear_scan", "linear_attn/linear_scan", 8) \
+        if built.cfg.sparse_layers else ("ssm_scan", "ssm_mix/ssm_scan", 9)
     for name, text in built.texts.items():
         calls = [line for line in text.splitlines()
-                 if re.search(r"%linear_scan[\w.\-]* = .*custom-call\(", line)]
+                 if re.search(rf"%{kernel}[\w.\-]* = .*custom-call\(", line)]
         assert len(calls) == len(built.cfg.linear_layers), (name, len(calls))
         for line in calls:
-            # (operands: 4 prefetched scalars, q, k, v under their decays,
-            # the decay's power, the state: ops/linear_attention.linear_scan)
-            assert "output_to_operand_aliasing={{1}: (8, {})}" in line, line
-            assert "linear_attn/linear_scan" in line, line
+            assert f"output_to_operand_aliasing={{{{1}}: ({alias}, {{}})}}" in line, line
+            assert scope in line, line
         copies = re.findall(r"= f32\[([\d,]+)\]\{[^}]*\} copy\(", text)
         assert not shapes & set(copies), (name, shapes & set(copies))
+        if kernel == "ssm_scan":
+            made = set(re.findall(
+                rf"= f32\[(?:{'|'.join(shapes)})\]\{{[^}}]*\}} ([\w\-]+)\(", text))
+            assert made <= {"parameter", "get-tuple-element", "bitcast", "tuple",
+                            "custom-call", "dynamic-update-slice", "fusion",
+                            "while", "conditional"}, (name, made)
 
 
 # -- the selection scores the compressed keys where they lie (ISSUE 50) ---------
@@ -221,7 +254,7 @@ def test_the_selection_gathers_no_table_of_compressed_keys(
     from dense_equal import canon
 
     built = cell_programs(config)
-    if not built.cfg.linear_layers:
+    if not built.cfg.sparse_layers:
         assert "ck" not in built.pool
         return
     cfg, MB = built.cfg, 66048 // 64
@@ -263,8 +296,8 @@ def test_step_programs_copy_no_expert_bank(one_chip, no_persistent_cache, config
     """No operation of a bank's size beside the kernel that reads it."""
     built = cell_programs(config)
     shapes = _bank_shapes(built.params)
-    if config in ("mistral-7b-16l", "olmo2-7b-16l",
-                  "minicpm-sala-9b-16l"):  # dense: no bank
+    if config in ("mistral-7b-16l", "olmo2-7b-16l", "minicpm-sala-9b-16l",
+                  "granite-4.0-h-micro"):  # dense: no bank
         assert not built.cfg.n_experts and not shapes
         return
     assert shapes
@@ -332,12 +365,13 @@ def test_step_programs_read_the_attention_projections_in_place(
     layer-step there either, but the decode chunk relays the stacks of q,
     k and v out once a launch (25 MB in all)."""
     built = cell_programs(config)
-    if config == "minicpm-sala-9b-16l":
+    kinds = {"minicpm-sala-9b-16l": ("sparse", "linear"),
+             "granite-4.0-h-micro": ("mamba", "attn")}.get(config)
+    if kinds:
         # the family's leaves are a layer's own (models/minicpm_sala.py: no
         # stack to slice or relay out): the rule's shapes do not exist
         # (and a mixer's input projections are one matrix, `w_in`)
-        assert all(leaf.ndim == 2 for name in ("w_in", "wo")
-                   for kind in ("sparse", "linear")
+        assert all(leaf.ndim == 2 for name in ("w_in", "wo") for kind in kinds
                    for leaf in built.params["layers"][kind][name])
         return
     found = {name: _projection_sized_instructions(text, built.params["layers"])

@@ -288,6 +288,7 @@ def programs(model, slots, blocks, context, block_size=128, tile=8,
 CELL_FILES = {
     "test_cell_programs_kanana_lfm2": ("kanana-2-30b-a3b-7l", "lfm2-24b-a2b-9l"),
     "test_cell_programs_mistral_olmo2": ("mistral-7b-16l", "olmo2-7b-16l"),
+    "test_cell_programs_granite": ("granite-4.0-h-micro",),
     "test_cell_programs_sala": ("minicpm-sala-9b-16l",),
     "test_cell_programs_sdar_trinity": ("sdar-30b-a3b-7l", "trinity-large-ep8-5l"),
 }

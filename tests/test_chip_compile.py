@@ -379,6 +379,46 @@ def test_the_linear_scan_compiles_at_the_sala_cells_shapes(
     assert set(made) <= {"parameter", "get-tuple-element", "bitcast"}, made
 
 
+# granite-4.0-h-micro's mamba layers: the state-space scan's program at the
+# cell's shapes (64 slots, 64 heads of 64 over a state of 128, packed two a
+# 128-lane row: a float32 state [32, 128, 128] a row): a mixed launch's 64
+# tiles of 8 and 128 prompt tokens on top (`scheduler.step_width`'s 640 at 64
+# slots) and the decode chunk's one token a row. The state leaf goes in and comes out as one buffer, and the
+# call is named `ssm_scan` under its scope: what `ssm_scan_roofline` finds it
+# by.
+@pytest.mark.parametrize("flat,tq", [(640, 8), (64, 1)], ids=["mixed", "decode"])
+def test_the_ssm_scan_compiles_at_the_granite_cells_shapes(
+    one_chip, no_persistent_cache, flat, tq
+):
+    from distributed_llm_inference_tpu.ops.ssm_scan import ssm_scan_rows
+
+    cfg, slots, _, pool = cell_pool("granite-4.0-h-micro")
+    S = _spec(one_chip)
+    lin = pool["lin"][0]
+    assert (slots, step_width(cfg, slots, 8)) == (64, 640)
+    assert (lin.shape, lin.dtype) == ((64, 32, 128, 128), jnp.float32)
+    H, P, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    compiled = jax.jit(
+        lambda x, dt, A, B, C, state, tok_row, zero: ssm_scan_rows(
+            x, dt, A, B, C, state, tok_row, tq, zero=zero, interpret=False),
+        donate_argnums=(5,),
+    ).lower(S((flat, H, P), jnp.float32), S((flat, H), jnp.float32),
+            S((H,), jnp.float32), S((flat, N), jnp.float32),
+            S((flat, N), jnp.float32), S(lin.shape, lin.dtype),
+            S((flat,), jnp.int32), S((slots,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert any("ssm_scan" in c for c in _custom_call_names(text))
+    assert "ssm_scan/jit(ssm_scan)" in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == lin.size * 4, memory
+    # nothing of the leaf's size beside the leaf: the tokens, and in the
+    # mixed launch the [64, 640, 640] decays of the within-launch part
+    assert memory.temp_size_in_bytes < lin.size * 4 // 2, memory
+    made = re.findall(r"= f32\[64,32,128,128\]\{[^}]*\} ([\w\-]+)\(", text)
+    assert set(made) <= {"parameter", "get-tuple-element", "bitcast",
+                         "custom-call"}, made
+
+
 # minicpm-sala-9b-16l's sparse layers: the selection's program at the cell's
 # shapes (a table of 1,032 blocks, a leaf of 9,216 whole (16, 128) tiles of
 # bfloat16, 2 KV heads of 16 query heads): a mixed launch's 17 tiles of 8 and

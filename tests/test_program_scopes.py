@@ -39,10 +39,11 @@ FAMILIES = {
     "test-lfm2-tiny": BLOCKS | ROUTED | {"ffn", "conv_mix"},
     "test-sala-tiny": BLOCKS | {"ffn", "linear_attn", "linear_scan",
                                 "sparse_select"},
+    "test-granite-tiny": BLOCKS | {"ffn", "ssm_mix", "ssm_scan"},
 }
 # a label that only ever nests in another
 NESTED = {"mla_absorb": "attn", "sparse_select": "attn",
-          "linear_scan": "linear_attn"}
+          "linear_scan": "linear_attn", "ssm_scan": "ssm_mix"}
 
 
 def test_the_vocabulary_names_every_scope_once():

@@ -115,6 +115,31 @@ def test_an_explicit_step_token_budget_is_obeyed(model, budget, slots, width):
     assert step_width(get_model_config(model), slots, TILE, budget) == width
 
 
+@pytest.mark.parametrize("model,dtype,slots,width", [
+    # 36 layers' float32 states, read and written: 151 MB a row beside
+    # 6.38 GB of bfloat16 weights, so the states are the larger stream from
+    # 43 rows on, and the dense budget is then prefill's on top of the fleet
+    ("granite-4.0-h-micro", "bfloat16", 64, 64 * TILE + 128),
+    ("granite-4.0-h-micro", "bfloat16", 43, 43 * TILE + 128),
+    ("granite-4.0-h-micro", "bfloat16", 42, 43 * TILE),  # the slot clamp alone
+    ("granite-4.0-h-micro", "bfloat16", 16, 136),
+    # float32 weights are twice the stream: 64 rows' states do not outweigh them
+    ("granite-4.0-h-micro", "float32", 64, 65 * TILE),
+    # a fleet whose states are a few percent of the weights keeps its width
+    ("minicpm-sala", "bfloat16", 16, 136),
+    ("minicpm-sala", "bfloat16", 64, 65 * TILE),
+    # no matrix state, however many rows: a convolution state is a few KB
+    ("lfm2-24b-a2b", "bfloat16", 80, 648),
+    ("mistral-7b", "bfloat16", 64, 65 * TILE),
+])
+def test_prefill_keeps_the_budget_where_the_states_outweigh_the_weights(
+        model, dtype, slots, width):
+    cfg = get_model_config(model).replace(dtype=dtype)
+    assert step_width(cfg, slots, TILE) == width
+    # an explicit budget is obeyed here too
+    assert step_width(cfg, slots, TILE, 1024) == 1024
+
+
 def test_budget_slicing_reserves_decode_rows():
     s = _sched(width=64, n_slots=4)  # 8 tiles
     cls = s.classes["standard"]
@@ -365,6 +390,20 @@ def test_streaming_through_chunked_path(setup):
     assert final.get("done") and final["status"] == "success"
     joined = "".join(e.get("delta", "") for e in events[:-1])
     assert joined == final["response"]
+
+
+@pytest.mark.parametrize("slots,asked,held", [(2, None, 64), (40, None, 80), (2, 3, 3)],
+                         ids=["small-fleet", "large-fleet", "asked"])
+def test_the_waiting_room_holds_a_fleets_worth_where_none_is_asked(
+        setup, slots, asked, held):
+    """A closed loop of twice as many callers as slots is never refused: the
+    default queue is the larger of 64 and twice the slots."""
+    cfg, params = setup
+    cont = _cont(cfg, params, True, n_slots=slots, max_queue=asked)
+    try:
+        assert cont.max_queue == held
+    finally:
+        cont.close()
 
 
 def test_slo_class_envelope_and_shed(setup):
