@@ -33,7 +33,11 @@ walks its row's block table itself:
     (`_walk_shape`), P = 1 being the same body;
   * a row that holds nothing (a launch-padding tile, a decode slot whose
     `active` flag is false) copies nothing and loops zero times; its
-    output is zeros, which the caller discards.
+    output is zeros, which the caller discards;
+  * a SELECTED read (`pages`: models/minicpm_sala.py) walks a LIST of the
+    row's pages a KV head in place of the range. Its pages are 32 KB, so
+    its step is its own (`_walk_kernel`, `fold_listed`): what such a page
+    costs is a descriptor and the fold's vector work, not bytes.
 
 The step programs hand the kernels the pool WHOLE, stacked over its layers
 ([L, N, KV, bs, Dh], a donated loop carry) with the layer to read and the
@@ -132,10 +136,23 @@ def _ragged_live_range(q_start, q_len, *, bs: int, MB: int, win):
 _WALK_STEP_PAGES = 8
 _WALK_STEP_TOKENS = 1024
 _WALK_STEP_BYTES = 3 * 2**19
+# A LISTED step (the selected read, `pages > 0` below) folds up to 32 pages
+# and 2,048 positions: its pages are one KV head's (32 KB at 64 tokens of 128
+# numbers, where the range walk's are 0.5-2 MB), its copies are straight-line
+# code with one wait a leaf whatever P is, and its masks do not grow with P,
+# so what a step pays beside its pages (0.6 us: the waits, the running max /
+# sum / accumulator's rescale on 128 score rows, the branches of the in-place
+# write) is halved with every doubling (PERF.md section 6, PR 52, the same
+# kernel at sala-docs-xlong's shapes: 0.150 us a page of a prompt tile's union
+# at 8 pages a step, 0.103 at 16, 0.093 at 32; a decode row's 0.110 / 0.088 /
+# 0.075). At 32 pages of 64 tokens the working set is 9.4 of the 12 MiB.
+_LIST_STEP_PAGES = 32
+_LIST_STEP_TOKENS = 2048
 
 
 def _walk_shape(KV: int, bs: int, Dh: int, itemsize: int, quant: bool,
-                rows: int, MB: int, latent: bool = False) -> tuple[int, int]:
+                rows: int, MB: int, latent: bool = False,
+                listed: bool = False) -> tuple[int, int]:
     """(KVg, P) of one program's walk, from the shapes and the stated
     count alone. KVg, the KV heads a program folds at a time: the largest
     divisor of KV whose working set at one page a step stays inside
@@ -149,7 +166,14 @@ def _walk_shape(KV: int, bs: int, Dh: int, itemsize: int, quant: bool,
     float32 query, accumulator, running max and sum, and the compute
     block's scores and probabilities. An int8 pool walks a page a step:
     its scale slabs hold the tokens on lanes, and blocks under 128 tokens
-    would not lie side by side there."""
+    would not lie side by side there. listed: the selected read's walk of a
+    page LIST: a program is ONE KV head (each chose its own pages), a step
+    folds up to _LIST_STEP_PAGES of them and _LIST_STEP_TOKENS positions,
+    and the working set holds the table that spreads a query's choices over
+    a step's columns (`_walk_kernel`: 128 entries x 128 / P steps x P x bs
+    columns)."""
+    if listed:
+        KV = 1
 
     def up(n, m):
         return -(-n // m) * m
@@ -161,6 +185,8 @@ def _walk_shape(KV: int, bs: int, Dh: int, itemsize: int, quant: bool,
         n += up(rows, 8) * 4 * (2 * lanes + 2 * 128 + 3 * toks)
         if quant:  # a [heads, bs] float32 scale slab beside each int8 slab
             n += 4 * toks * 4
+        if listed:
+            n += 128 * (128 // P) * toks * 2
         return n
 
     fit = max(1, _WALK_VMEM_BYTES // head(1))
@@ -173,8 +199,10 @@ def _walk_shape(KV: int, bs: int, Dh: int, itemsize: int, quant: bool,
     )
     page = KVg * bs * up(Dh, 128) * itemsize * (1 if latent else 2)
     P = 1
-    while (not quant and 2 * P <= min(MB, _WALK_STEP_PAGES)
-           and 2 * P * bs <= _WALK_STEP_TOKENS
+    most, tokens = ((_LIST_STEP_PAGES, _LIST_STEP_TOKENS) if listed else
+                    (_WALK_STEP_PAGES, _WALK_STEP_TOKENS))
+    while (not quant and 2 * P <= min(MB, most)
+           and 2 * P * bs <= tokens
            and 2 * P * page <= _WALK_STEP_BYTES
            and KVg * head(2 * P) <= _WALK_VMEM_BYTES):
         P *= 2
@@ -194,9 +222,8 @@ def walk_pages_per_step(leaf, n_heads: int, tq: int, MB: int,
     quant = isinstance(leaf, KVQuant)
     a = leaf.q if quant else leaf
     KV, bs, Dh = a.shape[-3:]
-    return _walk_shape(1 if listed else KV, bs, Dh + -Dh % 128,
-                       a.dtype.itemsize, quant, tq * (n_heads // KV), MB,
-                       latent)[1]
+    return _walk_shape(KV, bs, Dh + -Dh % 128, a.dtype.itemsize, quant,
+                       tq * (n_heads // KV), MB, latent, listed)[1]
 
 
 def _walk_kernel(
@@ -276,7 +303,27 @@ def _walk_kernel(
     list entry 128 * a + b) masks per query what it did not choose; a tile
     of one query chose its whole list. The tile's own pages (where its new
     rows fall) are the list's last entries: every query's forced window
-    holds them."""
+    holds them. A list has no window beside it (the list is the window).
+
+    A listed page is one KV head's K and V, 2 x 16 KB at 64 tokens of 128
+    numbers, and what it costs is not its bytes (PERF.md section 6, PR 52;
+    the kernels alone at sala-docs-xlong's shapes): a copy's descriptor
+    holds the core some 20 ns whatever it moves (half a page a descriptor
+    took the time of a whole one, K alone two thirds of K and V), and the
+    fold of 128 score rows is vector work a step (0.55 us of a step of 8
+    pages with no product in it) that no copy hides. So the listed step
+    (`fold_listed`) is built to pay each once: its copies are straight-line
+    code, all P of them whatever is live (a dead entry copies the last live
+    page again, masked) with ONE wait a leaf for a half's bytes, the next
+    step's started in quarters between this step's phases and the last
+    step the same code without them (no test of "is there a next step" in
+    the loop); the positions of a step's columns are ONE row built from P
+    scalars, compared against a column of frontiers; the 0 / 1 matrix that
+    carries a score row's choices from list entries to columns is read from
+    a table written once a call; a masked score is -inf, so no second
+    select; and a step folds 32 pages (`_walk_shape`, listed). Read there: a
+    page of a prompt tile's union of 192 cost 0.170 us and costs 0.093, a
+    decode row's 0.129 and 0.075 with 16 rows live (0.21 / 0.16 with 2)."""
     n = 1 if latent else (4 if quant else 2)  # pool leaves
     plist_ref = count_ref = sel_ref = None
     if pages:
@@ -289,6 +336,8 @@ def _walk_kernel(
     if write:
         srcs, rest = rest[:n], rest[n:]
     m_ref, l_ref, acc_ref, sem, *bufs = rest
+    spread_ref = bufs.pop() if sel_ref is not None else None
+    pos_ref = bufs.pop() if pages else None
     wsem = bufs.pop() if write else None
     kbuf = bufs[0]
     vbuf = None if latent else bufs[1]
@@ -403,7 +452,34 @@ def _walk_kernel(
             buf[rows_] = cur.astype(buf.dtype)
         return start
 
-    start_block(first, 0)
+    def start_listed(j0, slot, part=range(P)):
+        """Starts the copies of list entries j0 + p, p in `part`, into
+        buffer half `slot`, side by side (no loop, no branch, and no dead-
+        page test: an entry at or past `needed` copies the last live page
+        again, real rows of the row that are masked like any dead entry's).
+        Over a step every page of a half is started, so the half is always
+        whole and `wait_listed` is one wait a leaf. The copies are unrolled
+        where the kernel is lowered, not by Python: a body traced P times a
+        call site cost the server's start 10 s (PERF.md section 6, PR 52)."""
+        def start(p, carry):
+            for c in copies(jnp.minimum(j0 + p, needed - 1), slot, p):
+                c.start()
+            return carry
+
+        if part:
+            jax.lax.fori_loop(part.start, part.stop, start, 0, unroll=True)
+
+    def wait_listed(slot):
+        # a half's 2 P copies signal one semaphore a leaf, by their bytes:
+        # one wait for the half's bytes
+        for i, buf in enumerate(bufs):
+            pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                  sem.at[i, slot]).wait()
+
+    if pages:
+        pl.when(needed > 0)(lambda: start_listed(0, 0))
+    else:
+        start_block(first, 0)
 
     # the tile's queries, KV heads first: [KVg, rows, Dh]
     q = q_ref[0]
@@ -443,8 +519,6 @@ def _walk_kernel(
             for k in range(touched):
                 at = q_start // bs + k  # the logical page
                 j = at
-                if pages:  # the tile's own pages end its list
-                    j = needed - 1 - ((q_start + q_len - 1) // bs - at)
                 news.append((j, (j >= j0) & (j < jnp.minimum(j0 + P, needed))
                              & (at * bs < q_start + q_len)))
             for j, has_new in news:
@@ -453,29 +527,9 @@ def _walk_kernel(
                     for c in put_back(j, j0, slot, patch(j, j0, slot)):
                         c.start()
 
-        if pages:
-            # a list entry's positions are its logical page's; entries at or
-            # past `needed` hold nothing
-            kv_pos = jnp.full(col.shape, MB * bs, jnp.int32)
-            for p in range(P):
-                here = (col // bs == p) & (j0 + p < needed)
-                kv_pos = jnp.where(here, logical(j0 + p) * bs + col % bs,
-                                   kv_pos)
-        else:
-            kv_pos = j0 * bs + col
+        kv_pos = j0 * bs + col
         mask = (t_local < q_len) & (kv_pos <= q_end)
         mask &= (win <= 0) | (kv_pos > q_pos - win)
-        if sel_ref is not None:
-            # score row r's query chose list entry j0 + p: the entries of
-            # this step lie in one 128-entry part of the list
-            part = sel_ref[0, 0, j0 // 128]  # [rows, 128]
-            entry = jax.lax.broadcasted_iota(jnp.int32, (128, P * bs), 0)
-            spread = (entry == j0 % 128 + jax.lax.broadcasted_iota(
-                jnp.int32, (128, P * bs), 1) // bs).astype(part.dtype)
-            # (0 / 1 in bfloat16: exact at one pass, whatever precision the
-            # process asks of its float32 products)
-            mask &= jnp.dot(part, spread, precision=jax.lax.Precision.DEFAULT,
-                            preferred_element_type=jnp.float32) > 0.5
         ks = kbuf[slot].astype(jnp.float32)  # [KVg, P x bs, Dh]
         vs = ks[:, :, :latent] if latent else vbuf[slot].astype(jnp.float32)
         s = jax.lax.dot_general(
@@ -512,7 +566,137 @@ def _walk_kernel(
 
         return carry
 
-    jax.lax.fori_loop(0, pl.cdiv(needed - first, P), fold_block, 0)
+    # -- the selected read's step (pages > 0) --------------------------------
+    if pages:
+        assert KVg == 1 and not (quant or latent)
+        # the positions of a step's columns are built 128 lanes at a time (a
+        # page of 128 tokens or more: a page at a time) from the pages' first
+        # positions, which are scalars: lane c of a piece is column c of its
+        # `per` pages
+        w = P * bs
+        if 128 % bs == 0 or bs % 128 == 0:
+            w = min(w, max(bs, 128))
+        per = w // bs
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
+        lane_page, lane_off = lane // bs, lane % bs
+        # a score row's frontier, [rows, 1]; a row past q_len attends nothing
+        t_row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // group
+        frontier = q_start + t_row
+        if block:
+            frontier = (frontier // block + 1) * block - 1
+        frontier = jnp.where(t_row < q_len, frontier, -1)
+        if sel_ref is not None:
+            # spread_ref[a][e, c] = 1 where column c of a step belongs to
+            # entry e of the 128-entry part the step lies in, the step the
+            # part's a-th: the 0 / 1 matrix that carries a score row's
+            # choices from entries to columns through one product. It does
+            # not depend on the program: written once a call (the grid runs
+            # in order on the one core, and scratch outlives a program)
+            @pl.when((g == 0) & (hg == 0))
+            def _():
+                e = jax.lax.broadcasted_iota(jnp.int32, (128, P * bs), 0)
+                c = jax.lax.broadcasted_iota(jnp.int32, (128, P * bs), 1)
+
+                def spread(a, carry):
+                    spread_ref[a] = (e == a * P + c // bs).astype(
+                        jnp.float32).astype(spread_ref.dtype)
+                    return carry
+
+                jax.lax.fori_loop(0, 128 // P, spread, 0)
+
+    def fold_listed(i, prefetch: bool):
+        """Step i of a listed walk: list entries i * P .. + P - 1. With
+        `prefetch` the next step's copies start here, unconditionally (the
+        caller knows there is a next step): a quarter of them before this
+        step's wait, so that the next step's first pages are under way
+        early, and a quarter each between the fold's phases. What a copy of
+        16 KB costs this program is its descriptor (some 20 ns in which the
+        core issues nothing else), not its bytes: the quarters let the
+        copies themselves run while the fold computes (PERF.md section 6,
+        PR 52, calls 5-7)."""
+        slot = i % 2
+        j0 = i * P
+        quarter = [range(k * P // 4, (k + 1) * P // 4) for k in range(4)]
+
+        def start_next(k):
+            if prefetch:
+                start_listed(j0 + P, 1 - slot, quarter[k])
+
+        start_next(0)
+        wait_listed(slot)
+        if write:
+            # the tile's own pages end its list
+            news = []
+            for k in range(touched):
+                at = q_start // bs + k  # the logical page
+                j = needed - 1 - ((q_start + q_len - 1) // bs - at)
+                news.append((j, (j >= j0) & (j < jnp.minimum(j0 + P, needed))
+                             & (at * bs < q_start + q_len)))
+            for j, has_new in news:
+                @pl.when(has_new)
+                def _():
+                    for c in put_back(j, j0, slot, patch(j, j0, slot)):
+                        c.start()
+
+        def base(p):
+            # the first position of list entry j0 + p; an entry at or past
+            # `needed` lies past every frontier
+            return jnp.where(j0 + p < needed, logical(j0 + p) * bs, MB * bs)
+
+        def piece(a, carry):
+            b = jnp.full((1, w), base(a * per), jnp.int32)
+            for k in range(1, per):
+                b = jnp.where(lane_page >= k, base(a * per + k), b)
+            pos_ref[:, pl.ds(pl.multiple_of(a * w, w), w)] = b + lane_off
+            return carry
+
+        jax.lax.fori_loop(0, P * bs // w, piece, 0, unroll=True)
+        mask = pos_ref[:] <= frontier  # [rows, P x bs]
+        if sel_ref is not None:
+            # (0 / 1 in bfloat16: exact at one pass, whatever precision the
+            # process asks of its float32 products)
+            mask &= jnp.dot(
+                sel_ref[0, 0, j0 // 128], spread_ref[j0 % 128 // P],
+                precision=jax.lax.Precision.DEFAULT,
+                preferred_element_type=jnp.float32) > 0.5
+        start_next(1)
+        ks = kbuf[slot].astype(jnp.float32)  # [1, P x bs, Dh]
+        s = jax.lax.dot_general(
+            q, ks, (((2,), (2,)), heads), preferred_element_type=jnp.float32
+        )  # [1, rows, P x bs]
+        start_next(2)
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
+        # the running max starts at _NEG and never falls, so a masked score
+        # of -inf leaves exp(-inf - m) == 0 with no second select
+        s = jnp.where(mask, s, -jnp.inf)
+        m_prev, l_prev = m_ref[:], l_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        m_ref[:] = m_new
+        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
+        start_next(3)
+        vs = vbuf[slot].astype(jnp.float32)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            p, vs, (((2,), (1,)), heads), preferred_element_type=jnp.float32
+        )
+        if write:
+            for j, has_new in news:
+                @pl.when(has_new)
+                def _():
+                    for c in put_back(j, j0, slot, 0):
+                        c.wait()
+
+    steps = pl.cdiv(needed - first, P)
+    if pages:
+        # every step but the last starts its successor's copies; the last is
+        # the same code without them
+        jax.lax.fori_loop(
+            0, steps - 1, lambda i, c: (fold_listed(i, True), c)[1], 0)
+        pl.when(steps > 0)(lambda: fold_listed(steps - 1, False))
+    else:
+        jax.lax.fori_loop(0, steps, fold_block, 0)
 
     l = l_ref[:]
     l = jnp.where(l == 0.0, 1.0, l)  # padding queries, rows not walked
@@ -592,11 +776,13 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
     Dp = q5.shape[-1]
     Dv = value_dim if latent else Dp
     rows = tq * group
-    KVg, P = _walk_shape(1 if pages else KV, bs, Dp,
-                         leaves[0].dtype.itemsize, quant, rows, MB, latent)
+    KVg, P = _walk_shape(KV, bs, Dp, leaves[0].dtype.itemsize, quant, rows,
+                         MB, latent, listed=pages is not None)
     lists, sel, L = [], [], 0
     if pages is not None:
         assert not (latent or quant), "a selected read is of raw K/V pages"
+        assert window is None and window_dyn is None, (
+            "a selected read's list is its window")
         plist, count, chosen = pages
         L = plist.shape[-1]
         assert L % 128 == 0, L
@@ -646,6 +832,10 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
         # the list's choices, the new rows, the pool leaves
         at = 3 + len(lists) + 1 + len(sel) + n
         aliases = {at + i: 1 + i for i in range(n)}
+    if pages is not None:  # the positions of a step's columns
+        scratch.append(pltpu.VMEM((1, P * bs), jnp.int32))
+    if sel:  # the table that spreads a score row's choices over a step
+        scratch.append(pltpu.VMEM((128 // P, 128, P * bs), jnp.bfloat16))
     sel_spec = [pl.BlockSpec(
         (1, 1, L // 128, rows, 128), lambda g, hg, *refs: (g, hg, 0, 0, 0),
     )] * len(sel)

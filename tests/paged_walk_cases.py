@@ -273,3 +273,203 @@ def check_ragged_block_case(name):
                               row, np.arange(start, start + n), window, block)
             np.testing.assert_allclose(got[:n], np.asarray(want), rtol=2e-5,
                                        atol=2e-5, err_msg=f"{name} {g}")
+
+
+# -- the walk of a page LIST (the selected read: `_walk_kernel`, pages > 0) ----
+# name: (heads H, bs, MB, tiles [(row, q_start, q_len)], older, want, poison).
+# older(g, t, kv) -> the logical pages below the tile's own that query t of
+# tile g chose for KV head kv; every query also reads the tile's pages up to
+# its own position's (its forced window), so they end its list. want: what
+# the case is there for, asserted on the lists it makes ("long": more pages
+# than a step folds and not a multiple of P; "edge": the tile's two pages lie
+# on either side of a compute block's edge; "disjoint": no older page is two
+# queries'). P is the listed walk's own (`_walk_shape(..., listed=True)`).
+def _mod(g, t, kv):
+    return {0} | {1 + (5 * t + 3 * i + kv + 7 * g) % 34 for i in range(12)}
+
+
+LISTED_CASES = {
+    # 64-token pages, 32 a step: lists of 25-37 pages under a table of 40; a
+    # tile that straddles pages 36 | 37, one of 5 queries, a decode tile, a
+    # tile that holds nothing, a later tile of the first row
+    "listed-64tok": (4, 64, 40, [(0, 37 * 64 - 4, 8), (1, 29 * 64 + 7, 5),
+                                 (2, 33 * 64 + 1, 1), (1, 29 * 64 + 12, 0),
+                                 (0, 37 * 64 + 4, 8)], _mod, "long", False),
+    # 31 older pages, so the tile's pages 35 | 36 are entries 31 | 32 of 33
+    "listed-edge": (4, 64, 40, [(0, 36 * 64 - 3, 8)],
+                    lambda g, t, kv: {p for p in range(31) if p % 8 == t},
+                    "edge", False),
+    "listed-disjoint": (4, 64, 40, [(0, 31 * 64 + 9, 8), (2, 35 * 64, 8)],
+                        lambda g, t, kv: {1 + 3 * t + i + kv for i in range(3)},
+                        "disjoint", False),
+    # the cell's tile: 16 query heads a KV head, 128 score rows
+    "listed-rows128": (32, 64, 40, [(0, 37 * 64 - 4, 8), (1, 20 * 64 + 3, 1)],
+                       _mod, "long", False),
+    # 16-token pages: eight pages of a step lie side by side on 128 lanes
+    "listed-16tok": (4, 16, 40, [(0, 37 * 16 - 4, 8), (1, 29 * 16 + 7, 5),
+                                 (2, 33 * 16 + 1, 1)], _mod, "long", False),
+    # a step's dead entries (the count is not a multiple of P) over
+    # uninitialised memory that reads NaN
+    "listed-poison": (4, 64, 40, [(0, 37 * 64 - 4, 8), (1, 29 * 64 + 7, 5)],
+                      _mod, "long", True),
+}
+
+
+def _listed_pool(bs, MB, seed=0):
+    rng = np.random.default_rng(seed)
+    N = 3 * MB + 1
+    shape = (2, N, KV, bs, DH)
+    k, v = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16) for _ in range(2))
+    table = 1 + rng.permutation(N - 1)[:3 * MB].reshape(3, MB)
+    return k, v, jnp.asarray(table, jnp.int32), rng
+
+
+def _chosen(tiles, tq, bs, MB, older):
+    """[G, tq, KV, MB] bool: each live query's older pages and the tile's
+    pages up to its own."""
+    chosen = np.zeros((len(tiles), tq, KV, MB), bool)
+    for g, (_, start, n) in enumerate(tiles):
+        for t in range(n):
+            for kv in range(KV):
+                mine = sorted(p for p in older(g, t, kv) if p < start // bs)
+                chosen[g, t, kv, mine] = True
+                chosen[g, t, kv, start // bs:(start + t) // bs + 1] = True
+    return chosen
+
+
+def _assert_listed(want, chosen, count, tiles, bs, P):
+    count = np.asarray(count)
+    if want == "long":
+        assert np.any((count > P) & (count % P != 0)), count
+    if want == "edge":
+        _, start, n = tiles[0]
+        assert start // bs != (start + n - 1) // bs
+        assert np.all(count % P == 1) and np.all(count > P), count
+    if want == "disjoint":
+        older = chosen.copy()
+        for g, (_, start, _) in enumerate(tiles):
+            older[g, :, :, start // bs:] = False
+        assert older.sum(axis=1).max() == 1
+
+
+def _listed_reference(q, keys, vals, reads):
+    """Plain attention of one query head over the positions `reads`."""
+    s = keys[reads].astype(np.float64) @ np.asarray(q, np.float64) * DH ** -0.5
+    p = np.exp(s - s.max())
+    return (p / p.sum()) @ vals[reads].astype(np.float64)
+
+
+def check_listed_case(name):
+    """A mixed launch over page lists, the kernel writing the tiles' new rows
+    in place (layer 1 of 2): every query against plain attention over the
+    pages it chose up to its own position, the pool's written rows equal and
+    every other row as it was."""
+    from distributed_llm_inference_tpu.models.minicpm_sala import page_lists
+
+    heads, bs, MB, tiles, older, want, poison = LISTED_CASES[name]
+    group = heads // KV
+    P = _walk_shape(KV, bs, DH, 2, False, TQ * group, MB, listed=True)[1]
+    assert P == min(32, 2048 // bs), P
+    pool_k, pool_v, table, rng = _listed_pool(bs, MB)
+    chosen = _chosen(tiles, TQ, bs, MB, older)
+    plist, count, at = page_lists(jnp.asarray(chosen), 128)
+    _assert_listed(want, chosen, count, tiles, bs, P)
+    W = len(tiles) * TQ
+    q = jnp.asarray(rng.normal(size=(W, heads, DH)), jnp.float32)
+    new_k, new_v = (jnp.asarray(rng.normal(size=(W, KV, DH)), jnp.bfloat16)
+                    for _ in range(2))
+    meta = jnp.asarray([(r, s, n, int(n == 1)) for r, s, n in tiles], jnp.int32)
+    out, got_k, got_v = ragged_paged_attend(
+        q, pool_k, pool_v, table, meta, None, (jnp.int32(1), new_k, new_v),
+        (plist, count, at), interpret=_interpret(poison))
+    want_k, want_v = np.array(pool_k), np.array(pool_v)
+    for g, (row, start, n) in enumerate(tiles):
+        for t in range(n):
+            where = (1, int(table[row, (start + t) // bs]), slice(None),
+                     (start + t) % bs)
+            want_k[where] = np.asarray(new_k)[g * TQ + t]
+            want_v[where] = np.asarray(new_v)[g * TQ + t]
+    np.testing.assert_array_equal(np.asarray(got_k), want_k)
+    np.testing.assert_array_equal(np.asarray(got_v), want_v)
+    out = np.asarray(out)
+    assert np.all(np.isfinite(out)), name
+    for g, (row, start, n) in enumerate(tiles):
+        assert np.all(out[g * TQ + n:(g + 1) * TQ] == 0.0), (name, g)
+        view = [np.asarray(a[1], np.float32)[np.asarray(table[row])]
+                .transpose(1, 0, 2, 3).reshape(KV, MB * bs, DH)
+                for a in (want_k, want_v)]
+        for t in range(n):
+            for h in range(heads):
+                kv = h // group
+                reads = np.repeat(chosen[g, t, kv], bs) & (
+                    np.arange(MB * bs) <= start + t)
+                np.testing.assert_allclose(
+                    out[g * TQ + t, h],
+                    _listed_reference(q[g * TQ + t, h], view[0][kv],
+                                      view[1][kv], reads),
+                    rtol=2e-5, atol=2e-5, err_msg=f"{name} {g} {t} {h}")
+
+
+# decode rows under a list (paged_flash_attend, pages=...): name: (bs, MB,
+# positions, live, pages a list holds besides the row's own, poison); the
+# fourth row is a freed slot whose stale table row is the first row's
+LISTED_DECODE_CASES = {
+    "listed-decode-64tok": (64, 40, [37 * 64 + 5, 20 * 64, 39 * 64 + 63, 7],
+                            [True, True, True, False], 35, False),
+    "listed-decode-one-step": (64, 40, [31 * 64 + 5, 20 * 64, 64 + 3, 7],
+                               [True, True, True, False], 15, False),
+    "listed-decode-poison": (64, 40, [37 * 64 + 5, 20 * 64, 39 * 64 + 63, 7],
+                             [True, False, True, False], 36, True),
+}
+
+
+def check_listed_decode_case(name):
+    """Decode rows, a list a row and KV head (its own page the last), the new
+    token written in place; a row that is not live is not walked."""
+    from distributed_llm_inference_tpu.models.minicpm_sala import page_lists
+
+    bs, MB, pos, live, extra, poison = LISTED_DECODE_CASES[name]
+    P = _walk_shape(KV, bs, DH, 2, False, H // KV, MB, listed=True)[1]
+    assert P == 32
+    pool_k, pool_v, table, rng = _listed_pool(bs, MB, seed=4)
+    table = jnp.concatenate([table, table[:1]])
+    B = len(pos)
+    chosen = np.zeros((B, 1, KV, MB), bool)
+    for b in range(B):
+        own = pos[b] // bs
+        for kv in range(KV):
+            chosen[b, 0, kv, rng.permutation(own)[:extra]] = True
+            chosen[b, 0, kv, own] = True
+    plist, count, _ = page_lists(jnp.asarray(chosen), 128)
+    q = jnp.asarray(rng.normal(size=(B, 1, H, DH)), jnp.float32)
+    new_k, new_v = (jnp.asarray(rng.normal(size=(B, 1, KV, DH)), jnp.bfloat16)
+                    for _ in range(2))
+    out, got_k, got_v = paged_flash_attend(
+        q, pool_k, pool_v, table, jnp.asarray(pos, jnp.int32), None,
+        jnp.asarray(live), (jnp.int32(0), new_k, new_v), (plist, count),
+        interpret=_interpret(poison))
+    want_k, want_v = np.array(pool_k), np.array(pool_v)
+    for b in range(B):
+        if live[b]:
+            where = (0, int(table[b, pos[b] // bs]), slice(None), pos[b] % bs)
+            want_k[where] = np.asarray(new_k)[b, 0]
+            want_v[where] = np.asarray(new_v)[b, 0]
+    np.testing.assert_array_equal(np.asarray(got_k), want_k)
+    np.testing.assert_array_equal(np.asarray(got_v), want_v)
+    out = np.asarray(out)[:, 0]
+    assert np.all(np.isfinite(out)), name
+    for b in range(B):
+        if not live[b]:
+            assert np.all(out[b] == 0.0)
+            continue
+        view = [np.asarray(a[0], np.float32)[np.asarray(table[b])]
+                .transpose(1, 0, 2, 3).reshape(KV, MB * bs, DH)
+                for a in (want_k, want_v)]
+        for h in range(H):
+            kv = h // (H // KV)
+            reads = np.repeat(chosen[b, 0, kv], bs) & (
+                np.arange(MB * bs) <= pos[b])
+            np.testing.assert_allclose(
+                out[b, h], _listed_reference(q[b, 0, h], view[0][kv],
+                                             view[1][kv], reads),
+                rtol=2e-5, atol=2e-5, err_msg=f"{name} {b} {h}")

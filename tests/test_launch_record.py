@@ -371,8 +371,10 @@ CELL_WALK_SHAPES = {
     "sdar-30b-a3b-7l": ((4, 4), (4, 4)),  # a row's forward is a tile of 8
     "lfm2-24b-a2b-9l": ((4, 4), (4, 4)),  # pairs of 64-number heads a row
     "trinity-large-ep8-5l": ((8, 2), (8, 2)),
-    # a page LIST a KV head: a program is one head, 8 pages of 64 tokens a step
-    "minicpm-sala-9b-16l": ((1, 8), (1, 8)),
+    # a page LIST a KV head: a program is one head, 32 pages of 64 tokens a
+    # step (32 KB a page: `_LIST_STEP_PAGES`, ISSUE 52; the seven rows above
+    # are the range walk's and did not move)
+    "minicpm-sala-9b-16l": ((1, 32), (1, 32)),
 }
 
 
@@ -395,9 +397,8 @@ def test_walk_shape_at_the_cells_shapes(name):
     tiles = (2 * cfg.diffusion_block or 1, 8)  # the decode chunk's, a mixed launch's
     for tq, want in zip(tiles, CELL_WALK_SHAPES[name]):
         assert ContinuousEngine._walk_pages_of(host, tq) == want[1]
-        assert _walk_shape(1 if listed else kv, bs, dh, 2, False,
-                           tq * (cfg.n_heads // kv), mb,
-                           cfg.latent_dim > 0) == want
+        assert _walk_shape(kv, bs, dh, 2, False, tq * (cfg.n_heads // kv), mb,
+                           cfg.latent_dim > 0, listed) == want
 
 
 # -- (a4) the compressed keys a selection's scoring reads (ISSUE 50) -------------

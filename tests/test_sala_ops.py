@@ -26,6 +26,11 @@ from distributed_llm_inference_tpu.ops.paged_attention import (
 )
 from distributed_llm_inference_tpu.ops.sparse_select import select_blocks
 
+from paged_walk_cases import (
+    LISTED_CASES, LISTED_DECODE_CASES, check_listed_case,
+    check_listed_decode_case,
+)
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "cellbench"))
 
@@ -521,7 +526,15 @@ def _full_lists(G, counts):
 def test_the_walk_of_a_full_list_is_the_range_walk_bit_for_bit():
     """Decode rows and mixed tiles, the kernels writing the new rows in
     place: a list that names every page of the range gives the range walk's
-    output and pool, to the bit."""
+    output and pool, to the bit. Bit for bit needs the two walks to fold the
+    same pages a step (another P is another order of float32 sums): at these
+    shapes the listed rule (up to 32 pages a step, ISSUE 52) and the range
+    rule (up to 8) both stop at the table's width, asserted here."""
+    from distributed_llm_inference_tpu.ops.paged_attention import _walk_shape
+
+    for rows in (H // KV, 8 * (H // KV)):
+        assert (_walk_shape(KV, BS, DH, 4, False, rows, MB, listed=True)[1]
+                == _walk_shape(KV, BS, DH, 4, False, rows, MB)[1] == 4)
     k, v, table, rng = _pool()
     pos = jnp.asarray([37, 5, 80], jnp.int32)
     q = jnp.asarray(rng.normal(size=(3, 1, H, DH)), jnp.float32)
@@ -553,6 +566,20 @@ def test_the_walk_of_a_full_list_is_the_range_walk_bit_for_bit():
                               (plist, count, chosen))
     for a, b in zip(want, got):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize(
+    "case", sorted(LISTED_CASES) + sorted(LISTED_DECODE_CASES))
+def test_the_walk_of_a_list_is_plain_attention_over_the_chosen_pages(case):
+    """The listed walk at the shapes ISSUE 52 touches
+    (tests/paged_walk_cases.py): 64-token pages, 32 a step; a list longer
+    than a step whose count is not a multiple of P; a tile's own pages on
+    either side of a compute block's edge; a tile of fewer than tq queries; a
+    decode tile and decode rows; queries of one tile that chose disjoint
+    pages; dead entries over memory that reads NaN."""
+    if case in LISTED_DECODE_CASES:
+        return check_listed_decode_case(case)
+    check_listed_case(case)
 
 
 def test_the_walk_of_a_selection_masks_per_query_what_the_tile_walks():
