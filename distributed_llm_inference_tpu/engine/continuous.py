@@ -121,16 +121,20 @@ from ..utils.logging import get_logger
 from ..utils.metrics import (
     ADMISSION_WAIT_HELP, ATTN_WALK_STEPS_HELP, CHUNK_STEPS_HELP,
     CONV_STATE_RESETS_HELP,
-    CONV_TAIL_WRITES_HELP, DEFAULT_SIZE_BUCKETS, DIFFUSION_FORWARDS_HELP,
+    CONV_TAIL_WRITES_HELP, DECODE_ROW_SECONDS_HELP, DECODE_STEP_HELP,
+    DEFAULT_SIZE_BUCKETS, DEVICE_EMPTY_HELP, DIFFUSION_FORWARDS_HELP,
     DIFFUSION_FUSED_HELP, DIFFUSION_TOKENS_HELP, KV_GROUP_BLOCKS_HELP,
-    KV_WINDOW_RELEASED_HELP, LINEAR_STATE_RESETS_HELP, LINEAR_STATE_ROWS_HELP,
-    MOE_PAIRS_HELP,
+    KV_WINDOW_RELEASED_HELP, LAUNCH_DEVICE_SECONDS_HELP,
+    LAUNCH_DEVICE_STEPS_HELP, LAUNCH_TIMING_HELP, LINEAR_STATE_RESETS_HELP,
+    LINEAR_STATE_ROWS_HELP, MOE_PAIRS_HELP,
     PREFIX_STATE_TOKENS_HELP, SLOT_RELEASE_HELP, SLOT_TURNOVER_HELP,
     SPARSE_ROWS_HELP, SPARSE_SCORED_KEYS_HELP, SSM_STATE_RESETS_HELP,
     STEPS_AHEAD_BUCKETS,
 )
 from ..utils.retry import overload_retry_after
-from ..utils.tracing import PhaseClock, Trace, abstract_call, sample_decision
+from ..utils.tracing import (
+    LaunchTimer, PhaseClock, Trace, abstract_call, sample_decision,
+)
 from . import generate as G
 from .block_prefix import chunk_digests
 
@@ -972,9 +976,7 @@ class ContinuousEngine:
             buckets=STEPS_AHEAD_BUCKETS,
         ).labels()
         self._m_step = m.histogram(
-            "dli_decode_step_seconds",
-            "per-token decode step time, chunk launch-to-fetch / the "
-            "steps the chunk ran (includes pipelining lag)", ("engine",),
+            "dli_decode_step_seconds", DECODE_STEP_HELP, ("engine",),
         ).labels(engine="continuous")
         self._m_preempt = m.counter(
             "dli_preemptions_total",
@@ -1085,16 +1087,8 @@ class ContinuousEngine:
             "decode rows carried by scheduler launches (a pure-decode "
             "chunk counts its row-steps)",
         ).labels()
-        # how far the derived launch width (engine/scheduler.step_width)
-        # engages: the width itself, set once, and per mixed launch the
-        # query tiles it was compiled for against those that carried
-        # tokens (live / launched = the share of the launch that worked)
-        self._m_sched_tiles = m.counter(
-            "dli_sched_step_tiles_total",
-            "query tiles of mixed scheduler launches: launched = the "
-            "compiled width's, live = those that carried tokens",
-            ("state",),
-        )
+        # the derived launch width (engine/scheduler.step_width), set once;
+        # how far it engages is on the launch record (tiles / tiles_live)
         if self._chunked:
             m.gauge(
                 "dli_sched_step_width_tokens",
@@ -1210,12 +1204,30 @@ class ContinuousEngine:
             "scheduler steps dispatched and unfetched when a launch was "
             "dispatched", ("phase",), buckets=STEPS_AHEAD_BUCKETS,
         )
-        self._clock = PhaseClock(m.counter(
-            "dli_worker_phase_seconds_total",
-            "wall time of the scheduler's worker thread by phase "
-            "(contiguous: the phases sum to the thread's life)",
-            ("phase",),
-        ))
+        self._clock = PhaseClock(
+            m.counter(
+                "dli_worker_phase_seconds_total",
+                "wall time of the scheduler's worker thread by phase "
+                "(contiguous: the phases sum to the thread's life)",
+                ("phase",),
+            ),
+            m.counter(
+                "dli_device_empty_seconds_total", DEVICE_EMPTY_HELP,
+                ("phase",),
+            ),
+        )
+        # the device's time a launch as the worker can know it (ISSUE 53):
+        # told at every fetch, always on, no device read of its own
+        self._timer = LaunchTimer(
+            m.counter("dli_launch_device_seconds_total",
+                      LAUNCH_DEVICE_SECONDS_HELP, ("phase",)),
+            m.counter("dli_launch_device_steps_total",
+                      LAUNCH_DEVICE_STEPS_HELP, ("phase",)),
+            m.counter("dli_launch_timing_total", LAUNCH_TIMING_HELP,
+                      ("phase", "state")),
+            m.counter("dli_decode_row_seconds_total",
+                      DECODE_ROW_SECONDS_HELP, ("phase",)),
+        )
         # fleet speculative-decoding families (pre-registered in
         # engine/engine.py): draft/accept/reject token flow, verify-row
         # launches by draft source, tokens-per-launch distribution
@@ -3223,7 +3235,10 @@ class ContinuousEngine:
         a loop step = kv_grid_tokens / block size / kv_walk_steps, 1.0
         where a shape's compute block is one page);
         `steps_ahead` the scheduler steps dispatched
-        and not yet fetched; `row_steps` the decode row-steps it carries
+        and not yet fetched, `queue_empty` whether that is none (the device
+        has nothing queued: its idle time until this launch is the host's
+        or the traffic's, `PhaseClock.device_empty`); `row_steps` the
+        decode row-steps it carries
         (a chunk's rows run up to `steps` each). Counted here; the
         caller hands it to the `launch.<phase>` annotation, the flight
         `plan` event and the sampled per-tenant span, and the fetch
@@ -3233,6 +3248,7 @@ class ContinuousEngine:
             "phase": phase, "seq": self._launch_seq, "steps": steps,
             "decode_rows": 0, "prefill_chunks": 0, "prefill_tokens": 0,
             "spec_drafted": 0, "steps_ahead": self._steps_inflight,
+            "queue_empty": int(self._steps_inflight == 0),
             "kv_tokens": int(kv_tokens),
             "kv_grid_tokens": int(kv_grid_tokens),
             "kv_walk_steps": int(kv_walk_steps),
@@ -3251,9 +3267,6 @@ class ContinuousEngine:
         )
         self._m_walk_steps.labels(phase=phase).inc(rec["kv_walk_steps"])
         self._m_steps_ahead.labels(phase=phase).observe(rec["steps_ahead"])
-        if phase == "mixed":
-            self._m_sched_tiles.labels(state="launched").inc(rec["tiles"])
-            self._m_sched_tiles.labels(state="live").inc(rec["tiles_live"])
         return rec
 
     def _kv_fields(self, phase: str, attended, walked) -> dict:
@@ -3582,6 +3595,7 @@ class ContinuousEngine:
             packed = self._P.pack_routed(packed, self.cache["routed"])
         self._window_release(wrows)
         t_launch = self._clock.mark("plan")
+        self._clock.device_empty = False  # the device has this launch queued
         if self._trace_rate > 0.0:
             self._prof_note_launch(t_launch, snapshot, rec)
         if self._chunked:
@@ -3652,6 +3666,11 @@ class ContinuousEngine:
         self._launch_log.clear()
         self._steps_inflight = 0
         self._clock.mark("admit")  # restore + recovery are re-admission
+        # nothing is dispatched that will be fetched: the device's queue is
+        # empty as far as this loop knows, and the next launch has no
+        # predecessor to be timed from
+        self._clock.device_empty = True
+        self._timer.reset()
         # warm restore FIRST (supervisor restart or --restore-dir start):
         # the rebuilt pool takes the shadowed blocks back in one scatter
         # and the block-prefix index re-learns the chains, so the
@@ -4590,6 +4609,7 @@ class ContinuousEngine:
             *out, self._diff = out
         packed, self.state, self.sparams, self.cache = out
         t_launch = self._clock.mark("plan")
+        self._clock.device_empty = False  # the device has this launch queued
         # host position model + completion bookkeeping AFTER the launch
         # is enqueued (the arming rode the program itself). Verify rows
         # do NOT advance here: their advance is data-dependent (the
@@ -5368,17 +5388,30 @@ class ContinuousEngine:
         steps the device ran (it stops at its last live row's end): the
         record closes with `steps_run`, which rides the span that follows
         the fetch, and dli_decode_chunk_steps_total counts the dispatched
-        steps as run | cut. dli_decode_step_seconds is launch-to-fetch
-        over the steps that RAN (a mixed launch: 1; a dead fleet's empty
-        chunk counts as 1): under lag-N pipelining this includes queue
-        wait behind earlier launches, so it is the EFFECTIVE per-token
-        step time the fleet delivers, not raw compute."""
+        steps as run | cut.
+
+        The fetch is also where the worker learns the device's time
+        (`utils/tracing.LaunchTimer`): whether the result was ready when it
+        arrived (`ready`, on the `fetch.<phase>` span) and when the
+        blocking read returned, taken right behind it so that a routed
+        fleet's unpacking is in no launch's time. The outcome rides the
+        span that follows (`timed`, `device_us`), and the pair
+        dli_launch_device_seconds_total / dli_launch_device_steps_total is
+        the device's step time by launch kind. dli_decode_step_seconds is
+        NOT that: it is launch-to-fetch over the steps that RAN (a mixed
+        launch: 1; a dead fleet's empty chunk counts as 1), so under lag-N
+        pipelining it holds the wait behind the launches dispatched ahead
+        (`steps_per_s.batch` reads its count). When the last unfetched
+        launch is fetched the device's queue is empty, and the clock gives
+        the seconds until the next dispatch to the phases they pass in
+        (`PhaseClock.device_empty`)."""
+        phase, steps_run = rec["phase"], rec["steps"]
+        ready = packed_dev.is_ready()
         self._clock.mark(
-            "fetch_wait", f"fetch.{rec['phase']}", seq=rec["seq"]
+            "fetch_wait", f"fetch.{phase}", seq=rec["seq"], ready=int(ready)
         )
         packed = np.asarray(packed_dev)
-        phase, steps_run = rec["phase"], rec["steps"]
-        after = {}  # what the fetch learned: on the span that follows it
+        t_fetched = time.perf_counter()
         if phase == "chunk":
             # the last of the chunk's own rows (G.pack_chunk: [2K+2, B]),
             # in front of whatever pack_routed appended
@@ -5387,7 +5420,14 @@ class ContinuousEngine:
             self._m_chunk_steps.labels(state="run").inc(steps_run)
             self._m_chunk_steps.labels(state="cut").inc(
                 rec["steps"] - steps_run)
-            after = {"seq": rec["seq"], "steps_run": steps_run}
+        timing, device_s = self._timer.returned(
+            phase, t_launch, ready, t_fetched, steps_run, rec["decode_rows"]
+        )
+        # what the fetch learned: on the span that follows it
+        after = {"seq": rec["seq"], "timed": int(timing == "timed"),
+                 "device_us": int(device_s * 1e6)}
+        if phase == "chunk":
+            after["steps_run"] = steps_run
         if self._routed_shape is not None:
             # what the launch's expert layers routed came in the same
             # array: it closes the record and rides the span that follows
@@ -5408,12 +5448,14 @@ class ContinuousEngine:
             )
             self._m_moe_slots.labels(phase=phase).inc(slots)
             after.update(
-                seq=rec["seq"], moe_pairs=rec["moe_pairs"],
+                moe_pairs=rec["moe_pairs"],
                 moe_experts_touched=rec["moe_experts_touched"],
                 moe_expert_slots=slots,
             )
         now = self._clock.mark("distribute", **after)
         self._steps_inflight -= rec["steps"]
+        if self._steps_inflight == 0:
+            self._clock.device_empty = True  # until the next dispatch ends
         self._m_step.observe(max(0.0, now - t_launch) / max(1, steps_run))
         return packed
 

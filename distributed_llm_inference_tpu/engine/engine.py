@@ -34,8 +34,10 @@ from ..utils import faults
 from ..utils.logging import get_logger, request_id_context
 from ..utils.metrics import (
     ADMISSION_WAIT_HELP, ATTN_WALK_STEPS_HELP, CHUNK_STEPS_HELP,
-    DEFAULT_SIZE_BUCKETS,
+    DECODE_ROW_SECONDS_HELP, DECODE_STEP_HELP, DEFAULT_SIZE_BUCKETS,
+    DEVICE_EMPTY_HELP,
     DIFFUSION_FORWARDS_HELP, DIFFUSION_FUSED_HELP, DIFFUSION_TOKENS_HELP,
+    LAUNCH_DEVICE_SECONDS_HELP, LAUNCH_DEVICE_STEPS_HELP, LAUNCH_TIMING_HELP,
     SLOT_RELEASE_HELP, SLOT_TURNOVER_HELP,
     STEPS_AHEAD_BUCKETS, MetricsRegistry,
 )
@@ -439,9 +441,7 @@ class InferenceEngine:
             "dli_slots_occupied", "continuous-fleet slots serving a request"
         )
         self.metrics.histogram(
-            "dli_decode_step_seconds",
-            "per-token decode step time, chunk launch-to-fetch / "
-            "chunk_steps (includes pipelining lag)", ("engine",),
+            "dli_decode_step_seconds", DECODE_STEP_HELP, ("engine",),
         )
         self.metrics.counter(
             "dli_preemptions_total",
@@ -642,12 +642,6 @@ class InferenceEngine:
             "decode rows carried by scheduler launches (a pure-decode "
             "chunk counts its row-steps)",
         )
-        self.metrics.counter(
-            "dli_sched_step_tiles_total",
-            "query tiles of mixed scheduler launches: launched = the "
-            "compiled width's, live = those that carried tokens",
-            ("state",),
-        )
         self.metrics.gauge(
             "dli_sched_step_width_tokens",
             "flat-token width of the mixed scheduler launch "
@@ -696,6 +690,26 @@ class InferenceEngine:
             "dli_worker_phase_seconds_total",
             "wall time of the scheduler's worker thread by phase "
             "(contiguous: the phases sum to the thread's life)",
+            ("phase",),
+        )
+        # the worker's reading of the device it feeds (utils/tracing.
+        # LaunchTimer, PhaseClock.device_empty): always on, no profiler
+        self.metrics.counter(
+            "dli_device_empty_seconds_total", DEVICE_EMPTY_HELP, ("phase",),
+        )
+        self.metrics.counter(
+            "dli_launch_device_seconds_total", LAUNCH_DEVICE_SECONDS_HELP,
+            ("phase",),
+        )
+        self.metrics.counter(
+            "dli_launch_device_steps_total", LAUNCH_DEVICE_STEPS_HELP,
+            ("phase",),
+        )
+        self.metrics.counter(
+            "dli_launch_timing_total", LAUNCH_TIMING_HELP, ("phase", "state"),
+        )
+        self.metrics.counter(
+            "dli_decode_row_seconds_total", DECODE_ROW_SECONDS_HELP,
             ("phase",),
         )
         # fleet speculative-decoding families (engine/continuous.py

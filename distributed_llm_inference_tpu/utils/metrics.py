@@ -80,6 +80,43 @@ ATTN_WALK_STEPS_HELP = (
     "(ops/paged_attention._walk_shape), so walked KV positions / block "
     "size / this = pages a loop step"
 )
+# the continuous engine's step-time series: the old histogram, which is a
+# host time, and the worker's own reading of the device it feeds
+# (utils/tracing.LaunchTimer, PhaseClock.device_empty); registered by the
+# engine for a stable scrape schema and by the fleet that counts them
+DECODE_STEP_HELP = (
+    "launch to fetch over the steps the launch ran, per fetch: the wait "
+    "behind the launches dispatched ahead of it is inside (under lag 2, two "
+    "of them), so NOT a device step time: that is "
+    "dli_launch_device_seconds_total / dli_launch_device_steps_total"
+)
+LAUNCH_DEVICE_SECONDS_HELP = (
+    "device seconds of TIMED launches by kind (mixed step / decode chunk): "
+    "the time between two consecutive fetches that both found their result "
+    "not ready, the later launch dispatched before the earlier ended"
+)
+LAUNCH_DEVICE_STEPS_HELP = (
+    "steps the device ran in timed launches (a mixed launch 1, a chunk its "
+    "steps_run): dli_launch_device_seconds_total over this is the device's "
+    "step time by kind, with no profiler"
+)
+LAUNCH_TIMING_HELP = (
+    "fetched launches by whether their device time is known: timed, "
+    "ready_early = the worker came late to this result or the one before "
+    "(the device may have stood still behind it), queue_empty = nothing "
+    "was running when it was enqueued"
+)
+DECODE_ROW_SECONDS_HELP = (
+    "device seconds of timed launches x their decoding rows, by kind: the "
+    "seconds decoding rows lived through in mixed steps (another request's "
+    "prefill beside them) against pure-decode chunks"
+)
+DEVICE_EMPTY_HELP = (
+    "worker-thread seconds during which no launch was dispatched and "
+    "unfetched (the device's queue stood empty), by the worker's phase: "
+    "wait_work = no request to serve, every other phase = the chip waits "
+    "for Python; a lower bound on the device's idle time"
+)
 SLOT_TURNOVER_HELP = (
     "scheduler steps dispatched between a row's last live step by the "
     "host position model and the first prefill chunk of the slot's next "

@@ -44,6 +44,13 @@ to the next mark goes to `dli_worker_phase_seconds_total{phase}`, and
 each interval is one `jax.profiler.TraceAnnotation`, so a `/profiler`
 trace shows what the host did beside the device's own events.
 
+`LaunchTimer` (ISSUE 53) is the worker's reading of the device it feeds,
+with no profiler: the fetch is the one place the worker blocks, the device
+runs launches in dispatch order, so two consecutive fetches that both had to
+wait bound one launch's device time; and `PhaseClock.device_empty` gives the
+seconds in which no launch was unfetched to the phase the worker was in
+(`dli_device_empty_seconds_total{phase}`).
+
 The device's half of a launch (ISSUE 38): a trace's `XLA Ops` events carry
 an instruction's name (`%fusion.12`) and nothing of the `jax.named_scope`
 labels the step programs are written under (`STEP_SCOPES`). So while a
@@ -416,15 +423,27 @@ class PhaseClock:
     the first recorded) and a wait is preceded by an instant
     `begin.<name>` marker (the one open when the session ended runs from
     its marker to the end). Outside a profiler session an annotation is
-    a constructor call and two no-op methods."""
+    a constructor call and two no-op methods.
 
-    __slots__ = ("_children", "_phase", "_last", "_open", "_annotation")
+    `device_empty` is the owner's to set (the engine: True when a fetch
+    returns and no launch is left unfetched, False when a dispatch's jitted
+    call has returned): while it is set an interval that closes is added to
+    `empty`'s child of its phase as well, so the seconds the device's queue
+    stood empty split by what the thread was doing, `wait_work` (no
+    request) against the rest (the chip waits for Python). A lower bound on
+    the device's idle time: it may also stand still behind a launch whose
+    result nobody has fetched yet (`LaunchTimer`'s `ready_early`)."""
 
-    def __init__(self, family, phases=WORKER_PHASES):
+    __slots__ = ("_children", "_empty", "device_empty", "_phase", "_last",
+                 "_open", "_annotation")
+
+    def __init__(self, family, empty, phases=WORKER_PHASES):
         from jax.profiler import TraceAnnotation
 
         self._annotation = TraceAnnotation
         self._children = {p: family.labels(phase=p) for p in phases}
+        self._empty = {p: empty.labels(phase=p) for p in phases}
+        self.device_empty = False
         self._phase: Optional[str] = None
         self._last = time.perf_counter()
         self._open = None
@@ -439,7 +458,10 @@ class PhaseClock:
             self._open.__exit__(None, None, None)
             self._open = None
         if self._phase is not None:
-            self._children[self._phase].inc(now - self._last)
+            took = now - self._last
+            self._children[self._phase].inc(took)
+            if self.device_empty:
+                self._empty[self._phase].inc(took)
         prev, self._phase, self._last = self._phase, phase, now
         if phase is not None:
             name = span or f"phase.{phase}"
@@ -449,6 +471,82 @@ class PhaseClock:
             self._open = self._annotation(name, prev=prev or "", **attrs)
             self._open.__enter__()
         return now
+
+
+LAUNCH_PHASES = ("mixed", "chunk")
+# how a fetched launch's device time came out: known, or why not
+LAUNCH_TIMINGS = ("timed", "ready_early", "queue_empty")
+
+
+class LaunchTimer:
+    """A launch's device time as the thread that feeds the device can
+    know it, with no profiler and no device sync of its own. The device
+    runs launches in dispatch order and the worker blocks in one place,
+    the fetch of a launch's result. If a fetch found its result NOT ready
+    (`jax.Array.is_ready()` read before the blocking read), its return is
+    when the launch ended, up to the fetch's own latency. So launch n is
+    TIMED where its own fetch and the fetch before it both had to wait and
+    n was dispatched before n - 1 ended: the device went from the one
+    straight to the other, and `device_s` = return(n) - return(n - 1); the
+    fetches' latencies telescope out of a sum over consecutive launches.
+    What else was dispatched between the two launches (a key split, a
+    restored prefix block's scatter) is in the later one's time. Otherwise
+    the launch is counted and left out of the seconds: `queue_empty` (the
+    fetch before it had returned when its dispatch ended, or there was
+    none: nothing ran when it was enqueued, and its start is somewhere in
+    its dispatch) or `ready_early` (the worker came late to this result or
+    to the one before, so one end is unknown, and the device may have stood
+    still behind it). State: the last fetch's return and whether it had
+    waited; nothing grows with the run.
+
+    Counted per launch kind (`LAUNCH_PHASES`), at the fetch: `seconds` +=
+    device_s and `steps` += the steps the device RAN in timed launches
+    (their quotient is a device step time over every timed launch since
+    start-up), `timing` += 1 by outcome, `row_seconds` += device_s x the
+    launch's decoding rows (the seconds decoding rows lived through, by the
+    kind of launch they rode)."""
+
+    __slots__ = ("_seconds", "_steps", "_timing", "_row_seconds",
+                 "_last_return", "_last_waited")
+
+    def __init__(self, seconds, steps, timing, row_seconds):
+        self._seconds = {p: seconds.labels(phase=p) for p in LAUNCH_PHASES}
+        self._steps = {p: steps.labels(phase=p) for p in LAUNCH_PHASES}
+        self._row_seconds = {
+            p: row_seconds.labels(phase=p) for p in LAUNCH_PHASES
+        }
+        self._timing = {
+            (p, s): timing.labels(phase=p, state=s)
+            for p in LAUNCH_PHASES for s in LAUNCH_TIMINGS
+        }
+        self.reset()
+
+    def reset(self):
+        """The loop starts (anew): nothing is in flight that will be
+        fetched, the next launch meets an empty queue."""
+        self._last_return: Optional[float] = None
+        self._last_waited = False
+
+    def returned(self, phase: str, t_dispatched: float, ready: bool,
+                 t: float, steps_run: int, decode_rows: int) -> tuple:
+        """The fetch of a launch of kind `phase` returned at `t`; `ready`:
+        its result was there when the worker arrived. `t_dispatched`: the
+        end of its dispatch (all three are `time.perf_counter()` readings).
+        Returns (outcome, device_s); device_s is 0.0 unless timed."""
+        last, waited = self._last_return, self._last_waited
+        self._last_return, self._last_waited = t, not ready
+        if last is None or last <= t_dispatched:
+            state, device_s = "queue_empty", 0.0
+        elif ready or not waited:
+            state, device_s = "ready_early", 0.0
+        else:
+            state, device_s = "timed", t - last
+            self._seconds[phase].inc(device_s)
+            self._steps[phase].inc(steps_run)
+            if decode_rows:
+                self._row_seconds[phase].inc(device_s * decode_rows)
+        self._timing[phase, state].inc()
+        return state, device_s
 
 
 class FlightRecorder:

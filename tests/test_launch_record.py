@@ -23,7 +23,7 @@ from distributed_llm_inference_tpu.engine.engine import InferenceEngine
 from distributed_llm_inference_tpu.models import api as M
 from distributed_llm_inference_tpu.utils.metrics import MetricsRegistry
 from distributed_llm_inference_tpu.utils.tracing import (
-    WORKER_PHASES, PhaseClock,
+    LAUNCH_PHASES, LAUNCH_TIMINGS, WORKER_PHASES, PhaseClock,
 )
 
 WINDOW = 48
@@ -527,8 +527,9 @@ def test_worker_phases_sum_to_wall_time(runs):
 
 
 def test_phase_clock_is_contiguous():
-    fam = MetricsRegistry().counter("dli_worker_phase_seconds_total", "", ("phase",))
-    clock = PhaseClock(fam)
+    m = MetricsRegistry()
+    fam = m.counter("dli_worker_phase_seconds_total", "", ("phase",))
+    clock = PhaseClock(fam, m.counter("dli_device_empty_seconds_total", "", ("phase",)))
     t0 = clock.mark("plan")
     time.sleep(0.01)
     clock.mark("dispatch", "launch.mixed", seq=1, kv_tokens=5)
@@ -593,6 +594,95 @@ def test_steps_ahead_is_zero_first_and_bounded_by_the_lag(runs):
         assert s["p99"] <= bound
 
 
+# -- (g) the worker times the device it feeds (ISSUE 53) ------------------------
+
+@pytest.fixture(scope="module")
+def drained(setup):
+    """The request list through a fresh fleet whose worker is left to fetch
+    every launch it dispatched before the scrape (close() would find the
+    lag's launches unfetched), then a second of an idle engine that a last
+    request ends."""
+    cont = _cont(*setup)
+    try:
+        for p, n in REQUESTS:
+            cont.submit(p, max_tokens=n, greedy=True, chat=False)
+        deadline = time.time() + 30
+        while cont._steps_inflight and time.time() < deadline:
+            time.sleep(0.005)
+        assert cont._steps_inflight == 0
+        time.sleep(0.05)  # the worker is back in wait_work
+        served = cont.engine.metrics.snapshot()
+        # an interval is counted when it closes: a request ends the wait
+        time.sleep(1.0)
+        cont.submit("7 seven", max_tokens=1, greedy=True, chat=False)
+        idle = cont.engine.metrics.snapshot()
+    finally:
+        cont.close()
+    return {"served": served, "idle": idle}
+
+
+def test_every_fetched_launch_is_timed_or_says_why_not(drained):
+    snap = drained["served"]
+    for phase in LAUNCH_PHASES:
+        by_state = {s: _value(snap, "dli_launch_timing_total", phase=phase, state=s)
+                    for s in LAUNCH_TIMINGS}
+        assert sum(by_state.values()) == _value(
+            snap, "dli_ragged_launches_total", phase=phase) > 0, by_state
+        # the loop's first launch met an empty queue (a later request's
+        # first may find the one before's last chunks still unfetched)
+        if phase == "mixed":
+            assert by_state["queue_empty"] >= 1
+    # timed chunks ran no more steps than all fetched chunks did, and seconds
+    # are counted for timed launches alone
+    steps = _value(snap, "dli_launch_device_steps_total", phase="chunk")
+    assert steps <= _value(snap, "dli_decode_chunk_steps_total", state="run")
+    for phase in LAUNCH_PHASES:
+        timed = _value(snap, "dli_launch_timing_total", phase=phase, state="timed")
+        seconds = _value(snap, "dli_launch_device_seconds_total", phase=phase)
+        rows = _value(snap, "dli_decode_row_seconds_total", phase=phase)
+        assert (seconds > 0) == (timed > 0) and rows <= seconds * SLOTS
+        if phase == "mixed":  # a mixed launch is one step
+            assert _value(snap, "dli_launch_device_steps_total", phase=phase) == timed
+
+
+def test_the_families_are_on_an_idle_engines_metrics_from_zero(setup):
+    cont = _cont(*setup)
+    try:
+        text = cont.engine.metrics.render()
+    finally:
+        cont.close()
+    for phase in LAUNCH_PHASES:
+        for name in ("dli_launch_device_seconds_total", "dli_launch_device_steps_total",
+                     "dli_decode_row_seconds_total"):
+            assert f'{name}{{phase="{phase}"}} 0' in text
+        for state in LAUNCH_TIMINGS:
+            assert f'dli_launch_timing_total{{phase="{phase}",state="{state}"}} 0' in text
+    for phase in WORKER_PHASES:  # the loop's first instants are already counted
+        assert f'dli_device_empty_seconds_total{{phase="{phase}"}} ' in text
+
+
+def test_an_idle_engines_empty_seconds_are_wait_work(drained):
+    def empty(snap):
+        return {dict(k)["phase"]: s["value"] for k, s in
+                _series(snap, "dli_device_empty_seconds_total").items()}
+
+    served, idle = empty(drained["served"]), empty(drained["idle"])
+    assert set(idle) == set(WORKER_PHASES) and idle["fetch_wait"] == 0
+    grew = {p: idle[p] - served[p] for p in idle}
+    # the idle second is the traffic's; the last request's own launch adds
+    # a little of the host's phases
+    assert 0.9 <= grew["wait_work"] <= 1.3
+    assert grew["wait_work"] >= 0.8 * sum(grew.values())
+    # empty seconds are worker seconds: never more than the phase's own
+    for p in WORKER_PHASES:
+        assert idle[p] <= _value(drained["idle"], "dli_worker_phase_seconds_total",
+                                 phase=p) + 1e-9
+    # while it served, the queue also stood empty in the host's own phases
+    # (the first launch, at the least, is planned and dispatched with
+    # nothing queued)
+    assert served["dispatch"] > 0 and served["plan"] > 0
+
+
 def test_flight_plan_event_is_the_launch_record(runs):
     ev = [e for e in runs[0]["flight"] if e["kind"] == "plan"]
     assert len(ev) == 7  # one per mixed step that carried a prefill chunk
@@ -600,6 +690,7 @@ def test_flight_plan_event_is_the_launch_record(runs):
         assert e["phase"] == "mixed" and e["steps"] == 1 and e["prefill_chunks"] == 1
         assert 0 < e["kv_tokens"] <= e["kv_grid_tokens"]
         assert e["tiles_live"] <= e["tiles"] and "budget" in e
+        assert e["queue_empty"] == int(e["steps_ahead"] == 0)
     assert sum(e["prefill_tokens"] for e in ev) == 233
     assert [e["seq"] for e in ev] == sorted(e["seq"] for e in ev)
 
@@ -673,6 +764,18 @@ def test_profiler_trace_holds_launch_fetch_and_phase_events(setup, tmp_path):
         if seq in ran:
             assert ran[seq] == int(st["steps_live"])
     assert set(ran) & set(chunks) and set(ran) <= {seq for seq, *_ in fetches.items()}
+    # ISSUE 53: a launch says whether it met an empty queue, a fetch whether
+    # its result was ready, and the span after a fetch what the worker made
+    # of the launch's device time (microseconds, 0 unless timed)
+    assert all(int(st["queue_empty"]) == int(int(st["steps_ahead"]) == 0)
+               for _, _, _, st in launches.values())
+    assert {int(st["ready"]) for n, _, _, st in events if n.startswith("fetch.")} <= {0, 1}
+    closed = {int(st["seq"]): st for n, _, _, st in events
+              if n == "phase.distribute" and "seq" in st}
+    assert set(closed) == set(fetches)
+    for st in closed.values():
+        assert int(st["timed"]) in (0, 1)
+        assert (int(st["device_us"]) > 0) == bool(int(st["timed"]))
     # the worker's intervals are contiguous: each begins where one ended.
     # A hole the clock leaves shows at every iteration (one gap in seven),
     # so nine gaps in ten are held and not the longest: a loaded machine

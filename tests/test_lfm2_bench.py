@@ -35,6 +35,9 @@ JOINED = ["moe_ms_per_step", "moe_expert_roofline", "moe_experts_touched_pct", "
 JOINED_COUNTERS = ["mixed_step_pct", "host_ms_per_step", "fetch_wait_pct", "gen_late_ms_max",
                    "queue_wait_ms_mean", "slot_wait_ms_mean", "steps_ahead_of_prefill_mean",
                    "attn_grid_live_pct"]
+# PR 53's six read the worker's own counters in every cell they list
+WORKER_TIMED = ["decode_step_ms_mean", "mixed_step_ms_mean", "launch_timed_pct",
+                "decode_time_in_mixed_pct", "device_empty_wait_pct", "device_empty_host_pct"]
 LIST_LESS = ["batch_rows_mean", "prefill_tok_pct", "step_device_ms_p50",
              "attn_kernel_ms_per_step", "device_idle_pct"]
 # ... and the program's scopes (PR 38): every one of the six lists this cell
@@ -278,7 +281,7 @@ def test_the_manifest_gained_one_configuration_one_cell_and_one_metric():
     assert {m["name"] for m in cell.end_to_end} == {"tpot_ms_p50", "setup_s"}
     reported = {m["name"] for m in cell.per_layer}
     assert reported == set(LIST_LESS) | set(NEW_METRICS) | set(JOINED) | set(JOINED_COUNTERS) \
-        | set(SCOPES)
+        | set(SCOPES) | {n for n in WORKER_TIMED if CELL in by_name[n]["workloads"]}
     for other in ACCEPTED:
         assert "step_weight_roofline" in {m["name"] for m in manifest.Cell(man, other).per_layer}
     # the same trace as kanana-docs-long, at a rate of its own

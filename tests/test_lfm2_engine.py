@@ -207,7 +207,7 @@ def test_the_derived_width_serves_the_tokens_a_16_token_budget_serves(impl):
             hit = f.ask_all([(doc + [21, 22, 23, 24], 10)])[0]
             t.join(300)
             width = f.ce.stats()["scheduler"]["step_width"]
-            tiles = f.series("dli_sched_step_tiles_total")
+            tiles = f.series("dli_ragged_tiles_total")
             gauge = f.series("dli_sched_step_width_tokens")
         finally:
             f.ce.close()
@@ -223,7 +223,9 @@ def test_the_derived_width_serves_the_tokens_a_16_token_budget_serves(impl):
         restored = [r for r in mixed if r["state_restored_tokens"]]
         assert [r["state_restored_tokens"] for r in restored] == [64]
         assert restored[0]["decode_rows"] == 1  # beside the decoding row
-        assert tiles[(("state", "launched"),)] == sum(r["tiles"] for r in mixed)
+        # the record's tiles are the ones dli_ragged_tiles_total counts
+        assert tiles[(("state", "live"),)] + tiles[(("state", "pad"),)] == sum(
+            r["tiles"] for r in mixed)
         assert tiles[(("state", "live"),)] == sum(r["tiles_live"] for r in mixed)
         if budget is None:  # each prompt landed in one launch, four tails in one chunk
             assert [r["prefill_tokens"] for r in mixed if r["prefill_chunks"]] == [73, 20, 10]

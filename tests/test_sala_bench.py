@@ -36,6 +36,9 @@ JOINED = ["gen_late_ms_max", "queue_wait_ms_mean", "ttft_ms_p50", "ttft_ms_p90",
           "head_sample_ms_per_step"]
 # their bytes are a range's, or a formula that miscounts this stack; attended /
 # walked is a bound here, not the kernels' grid (a tile walks its queries' union)
+# PR 53's six read the worker's own counters in every cell they list
+WORKER_TIMED = ["decode_step_ms_mean", "mixed_step_ms_mean", "launch_timed_pct",
+                "decode_time_in_mixed_pct", "device_empty_wait_pct", "device_empty_host_pct"]
 NOT_JOINED = ["attn_kv_roofline", "hybrid_attn_kv_roofline", "step_weight_roofline",
               "attn_grid_live_pct", "window_attn_kv_roofline", "conv_mix_ms_per_step"]
 LIST_LESS = ["batch_rows_mean", "prefill_tok_pct", "step_device_ms_p50",
@@ -316,7 +319,7 @@ def test_the_manifest_gained_one_configuration_one_cell_and_five_metrics():
     cell = manifest.Cell(man, CELL)
     assert {m["name"] for m in cell.end_to_end} == {"tpot_ms_p50", "setup_s"}
     assert {m["name"] for m in cell.per_layer} == \
-        set(LIST_LESS) | set(NEW_METRICS) | set(JOINED)
+        set(LIST_LESS) | set(NEW_METRICS) | set(JOINED) | {n for n in WORKER_TIMED if CELL in by_name[n]["workloads"]}
     for other in ACCEPTED:  # nothing an accepted cell reports has changed
         assert not set(NEW_METRICS) & {m["name"] for m in manifest.Cell(man, other).per_layer}
     # the traffic file trinity-docs-xlong runs, unedited: two models, one pinned trace

@@ -1137,8 +1137,11 @@ def test_the_derived_width_serves_the_tokens_a_16_token_budget_serves():
                     for s in snap[name]["series"]}
 
         assert series("dli_sched_step_width_tokens") == {(): width}
-        tiles = series("dli_sched_step_tiles_total")
-        assert tiles[(("state", "launched"),)] == sum(r["tiles"] for r in mixed)
+        # the record's tiles are the ones dli_ragged_tiles_total counts (a
+        # chunked fleet launches no ingest program beside its mixed steps)
+        tiles = series("dli_ragged_tiles_total")
+        assert tiles[(("state", "live"),)] + tiles[(("state", "pad"),)] == sum(
+            r["tiles"] for r in mixed)
         assert tiles[(("state", "live"),)] == sum(
             r["tiles_live"] for r in mixed) > 0
         if budget is None:  # a prompt's prefill is ONE launch, not four

@@ -36,6 +36,9 @@ SCOPES = ["moe_layer_ms_per_step", "scoped_device_pct", "attn_layer_ms_per_step"
           "ffn_ms_per_step", "head_sample_ms_per_step"]
 LIST_LESS = ["batch_rows_mean", "prefill_tok_pct", "step_device_ms_p50",
              "attn_kernel_ms_per_step", "device_idle_pct"]
+# PR 53's six read the worker's own counters in every cell they list
+WORKER_TIMED = ["decode_step_ms_mean", "mixed_step_ms_mean", "launch_timed_pct",
+                "decode_time_in_mixed_pct", "device_empty_wait_pct", "device_empty_host_pct"]
 NOT_JOINED = ["step_weight_roofline", "attn_kv_roofline", "hybrid_attn_kv_roofline",
               "mla_attn_roofline", "conv_mix_ms_per_step"]
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -281,7 +284,7 @@ def test_the_manifest_gained_one_configuration_one_cell_and_three_metrics():
     cell = manifest.Cell(man, CELL)
     assert {m["name"] for m in cell.end_to_end} == {"tpot_ms_p50", "setup_s"}
     assert {m["name"] for m in cell.per_layer} == \
-        set(LIST_LESS) | set(NEW_METRICS) | set(JOINED) | set(SCOPES)
+        set(LIST_LESS) | set(NEW_METRICS) | set(JOINED) | set(SCOPES) | {n for n in WORKER_TIMED if CELL in by_name[n]["workloads"]}
     for other in ACCEPTED:  # nothing an accepted cell reports has changed
         assert not set(NEW_METRICS) & {m["name"] for m in manifest.Cell(man, other).per_layer}
     # docs-repeat-long's trace at three times the length
