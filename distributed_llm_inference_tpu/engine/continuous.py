@@ -509,16 +509,18 @@ class ContinuousEngine:
         # admission regardless of ingest strategy); only the step
         # planning needs the mixed ragged program.
         from .scheduler import (
-            TokenBudgetScheduler, parse_slo_classes, step_width,
+            TokenBudgetScheduler, live_width, parse_slo_classes, step_width,
         )
 
         self._slo = parse_slo_classes(engine.engine_cfg)
+        launch = (cfg, self.n_slots, self._ragged_tile,
+                  engine.engine_cfg.step_token_budget)
         self._sched = TokenBudgetScheduler(
             self._slo, engine.engine_cfg.slo_default_class,
-            step_width(cfg, self.n_slots, self._ragged_tile,
-                       engine.engine_cfg.step_token_budget),
+            step_width(*launch),
             self._ragged_tile, self.n_slots, registry=engine.metrics,
             tenant_weights=engine.engine_cfg.tenant_weights,
+            live_width=live_width(*launch),
         )
         self._chunked = bool(
             self.paged and engine.engine_cfg.chunked_prefill
@@ -546,6 +548,10 @@ class ContinuousEngine:
             from . import paged as _P_arm
 
             self._sched_width = self._sched.width
+            # the axis the mixed step's token-wise layers run on
+            # (engine/scheduler.live_width; the width itself everywhere but
+            # where the launch is fleet tiles + budget)
+            self._live_width = self._sched.live_width
             self._idle_arm = _P_arm.idle_mixed_arm(
                 self.n_slots, cfg.vocab_size
             )
@@ -1055,6 +1061,14 @@ class ContinuousEngine:
             "ragged-launch query tiles by liveness (live / pad — a pad "
             "tile is one program that walks no KV block)", ("state",),
         )
+        mixed_tokens = m.counter(
+            "dli_mixed_tokens_total",
+            "flat tokens of mixed scheduler launches: live (a decode, verify "
+            "or prompt token) and computed (the axis the token-wise layers "
+            "ran on: engine/scheduler.live_width)", ("state",),
+        )
+        self._m_mixed_tokens = {
+            s: mixed_tokens.labels(state=s) for s in ("live", "computed")}
         self._m_ragged_launches = m.counter(
             "dli_ragged_launches_total",
             "launches by program: ragged ingest (extend / prefill) and "
@@ -1879,6 +1893,7 @@ class ContinuousEngine:
             out["scheduler"] = {
                 "chunked_prefill": True,
                 "step_width": self._sched_width,
+                "live_width": self._live_width,
                 "tile": self._ragged_tile,
                 "prefilling": len(self._jobs),
             }
@@ -4311,6 +4326,8 @@ class ContinuousEngine:
             active_classes={
                 self._assignment[b].slo for b in active
             },
+            n_decode_tokens=len(active) + sum(
+                spec_rows[b][0] for b in active if b in spec_rows),
         )
         # a block-diffusion prompt whose whole blocks are all mapped from
         # the prefix index (or shorter than a block) has no chunk to land:
@@ -4383,6 +4400,11 @@ class ContinuousEngine:
         meta, tok_row, tok_pos, offsets, stats = P.build_ragged_meta(
             entries, width=W, tile=tile,
         )
+        tokens_live = sum(n for _, _, n, _ in entries)
+        if tokens_live > self._live_width:
+            raise ValueError(
+                f"launch overflow: {tokens_live} live tokens, the mixed "
+                f"step computes {self._live_width}")
         # (a decode row whose budget ran out is dead on the device)
         wrows = [] if self._wgrp is None else [
             (b, start, n) for i, (b, start, n, _) in enumerate(entries)
@@ -4590,7 +4612,9 @@ class ContinuousEngine:
             decode_rows=n_dec, prefill_chunks=len(chunk_list),
             prefill_tokens=sum(n for _, n, _ in chunk_list),
             spec_drafted=sum(nd for nd, _, _ in spec_rows.values()),
-            tiles=stats["tiles"], tiles_live=live_tiles, **diff_fields,
+            tiles=stats["tiles"], tiles_live=live_tiles,
+            tokens_live=tokens_live, tokens_computed=self._live_width,
+            **diff_fields,
         )
         self._clock.mark("dispatch", "launch.mixed", **rec)
         out = self._step_program(
@@ -4604,6 +4628,9 @@ class ContinuousEngine:
             dev=dev_dev, pages=pages_dev, **diffusion,
             **({"snaps": (jnp.asarray(snap_restore), jnp.asarray(snap_take))}
                if self._snap_pool else {}),
+            # (the program every other fleet dispatched: no operand more)
+            **({"live_width": self._live_width}
+               if self._live_width < W else {}),
         )
         if Bd:
             *out, self._diff = out
@@ -4709,6 +4736,8 @@ class ContinuousEngine:
             )
         self._m_ragged_tiles.labels(state="pad").inc(stats["pad_tiles"])
         self._m_ragged_tiles.labels(state="live").inc(live_tiles)
+        self._m_mixed_tokens["live"].inc(tokens_live)
+        self._m_mixed_tokens["computed"].inc(self._live_width)
         # decode snapshot: only rows DECODING at launch (mid-prefill rows
         # emit nothing; the completing slot's first decode token arrives
         # with the NEXT launch) — attribution discipline as ever

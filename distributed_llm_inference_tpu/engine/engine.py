@@ -610,6 +610,12 @@ class InferenceEngine:
             "tile is one program that walks no KV block)", ("state",),
         )
         self.metrics.counter(
+            "dli_mixed_tokens_total",
+            "flat tokens of mixed scheduler launches: live (a decode, verify "
+            "or prompt token) and computed (the axis the token-wise layers "
+            "ran on: engine/scheduler.live_width)", ("state",),
+        )
+        self.metrics.counter(
             "dli_ragged_launches_total",
             "launches by program: ragged ingest (extend / prefill) and "
             "scheduler steps (mixed / chunk)", ("phase",),

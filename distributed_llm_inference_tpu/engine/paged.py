@@ -1571,7 +1571,21 @@ def _ragged_latent_xla(cfg, q, pool_c, layer, table, tok_row, tok_pos):
     return latent_attend(cfg, q, rows, mask[:, None, :])
 
 
-def make_ragged_fill_hook(table, meta, tok_row, snaps=None):
+def live_tokens(tok_row, width: int):
+    """A launch's live flat tokens (tok_row >= 0) side by side on an axis of
+    `width`, in flat order, so that a row's tokens stay contiguous and in
+    order: (at [width], the flat index each place of the axis reads: past
+    the live tokens, launch padding; back [W], the place each live flat
+    token went to: for padding some place, whose values nothing reads). The
+    host plans no launch of more live tokens than `width`
+    (engine/scheduler.live_width)."""
+    live = tok_row >= 0
+    at = jnp.argsort(~live, stable=True)[:width].astype(jnp.int32)
+    back = jnp.clip(jnp.cumsum(live, dtype=jnp.int32) - 1, 0, width - 1)
+    return at, back
+
+
+def make_ragged_fill_hook(table, meta, tok_row, snaps=None, compact=None):
     """attn_hook for the ragged ingest programs: flat-token layout
     ([W, 1] chunks — each token is a batch row at its own position, the
     slots-mode contract), per-token K/V scatter into the owning row's
@@ -1585,10 +1599,17 @@ def make_ragged_fill_hook(table, meta, tok_row, snaps=None):
     the write-only TRASH block, exactly like ungated pp microsteps; snaps
     (restore [R], take [R]): StateRows' snapshot indices, for a fleet
     whose state has a snapshot pool.
+
+    compact (`live_tokens` of tok_row; None: the model runs on the tile
+    layout itself): the model runs on the launch's live tokens alone, side
+    by side on a shorter axis that has no tiles (`hook.tile` 1). q / k / v
+    and pos come in on that axis and go to the tile layout here, which the
+    kernel and the pool's write want, and the attended rows come back: the
+    one place that knows both layouts.
     """
 
-    def hook(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
-             valid_start, window_flag=None, layer=None, pages=None):
+    def tiles(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
+              valid_start, window_flag=None, layer=None, pages=None):
         del mask, valid_start  # mask derived from pos/tok_row in-kernel
         W, T = q.shape[0], q.shape[1]
         assert T == 1, "ragged fill runs the flat token layout (T=1 rows)"
@@ -1655,14 +1676,26 @@ def make_ragged_fill_hook(table, meta, tok_row, snaps=None):
             )
         return attn, new_k, new_v
 
+    hook, own = tiles, tok_row  # own: a token's row on the model's axis
+    if compact is not None:
+        at, back = compact
+        own = tok_row[at]
+
+        def hook(cfg, q, k, v, cache_k, cache_v, pos, *rest, **kw):
+            attn, new_k, new_v = tiles(
+                cfg, q[back], k[back], None if v is None else v[back],
+                cache_k, cache_v, pos[back], *rest, **kw)
+            return attn[at], new_k, new_v
+
     hook.paged = True  # forward_layers carries the stacked pool
-    hook.tile = tok_row.shape[0] // meta.shape[0]
-    hook.live = tok_row >= 0  # launch padding reaches no routed expert
-    rows = functools.partial(_ragged_rows, table, meta, tok_row)
+    hook.tile = 1 if compact is not None else (
+        tok_row.shape[0] // meta.shape[0])
+    hook.live = own >= 0  # launch padding reaches no routed expert
+    rows = functools.partial(_ragged_rows, table, meta, own)
     hook.rows = rows if snaps is None else (
         lambda: rows()._replace(restore=snaps[0], take=snaps[1]))
     hook.group = lambda g, n: make_ragged_fill_hook(
-        _group_table(table, g, n), meta, tok_row)
+        _group_table(table, g, n), meta, tok_row, compact=compact)
     return hook
 
 
@@ -1995,14 +2028,16 @@ def spec_verify(cfg: ModelConfig, state: G.SlotState, window, draft,
     return state, spec_emit, emit_ok, adv
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",), donate_argnames=("pool",))
+@functools.partial(jax.jit, static_argnames=("cfg", "live_width"),
+                   donate_argnames=("pool",))
 def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
                       dec_flag, meta, pool, table, state: G.SlotState,
                       sparams: G.SlotParams, key, dec_idx, arm: MixedArm,
                       spec: Optional[SpecPlan] = None, spec_toks=None,
                       dev: Optional[DeviceMeta] = None, pages=None,
                       diff: Optional[DiffState] = None,
-                      darm: Optional[DiffState] = None, snaps=None):
+                      darm: Optional[DiffState] = None, snaps=None,
+                      live_width: Optional[int] = None):
     """One scheduler step: advance every active slot one decode token AND
     write the launch's prefill chunks into the pool, in one program.
 
@@ -2056,6 +2091,13 @@ def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
     starts from (-1: zeros) and the one its state after the launch is kept
     in (-1: none): `StateRows.restore` / `.take`.
 
+    live_width (static; engine/scheduler.live_width, given only where it is
+    under the launch's width W): the model runs on the launch's live tokens,
+    packed side by side on an axis that wide (`live_tokens`), and only the
+    hook's kernel and pool write see the tile layout. The host plans no
+    launch of more live tokens. None: the tile layout throughout, and the
+    program is what it was before the axis existed.
+
     Returns (packed int32 — [5, B] plain, [5 + 2*(K+1) + 1, B] with
     spec: emitted / emit_mask / active / firsts / armed [/ spec_emit /
     spec_mask / position advance], ONE fetch per step — state, sparams,
@@ -2092,14 +2134,23 @@ def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
         tok_row = jnp.where(dev.tok_on & ~state.active[rows_ix], -1, tok_row)
         ended = dev.tile_on & ~state.active[jnp.maximum(meta[:, 0], 0)]
         meta = meta.at[:, 2].set(jnp.where(ended, 0, meta[:, 2]))
-    x = M.embed(cfg, params, toks[:, None], pos)
+    # the model's axis: the tile layout, or the live tokens alone (`toks`
+    # keeps the layout spec.idx reads the drafts from)
+    compact, own, own_toks = None, tok_row, toks
+    if live_width is not None and live_width < tok_row.shape[0]:
+        compact = at, back = live_tokens(tok_row, live_width)
+        own, own_toks, pos = tok_row[at], toks[at], pos[at]
+    x = M.embed(cfg, params, own_toks[:, None], pos)
     if cfg.linear_layers and snaps is None:  # restores none, keeps none
         snaps = (jnp.full((table.shape[0],), -1, jnp.int32),) * 2
     x, pool = M.forward_layers(
         cfg, params["layers"], x, pool, pos,
-        attn_hook=make_ragged_fill_hook(table, meta, tok_row, snaps),
-        attn_seq_len=1, lora_pages=_token_pages(pages, tok_row),
+        attn_hook=make_ragged_fill_hook(table, meta, tok_row, snaps, compact),
+        attn_seq_len=1, lora_pages=_token_pages(pages, own),
     )
+    if compact is not None:
+        # dec_idx, arm.idx and spec.idx name flat tokens of the tile layout
+        x = x[back]
     if cfg.diffusion_block:
         Bd = cfg.diffusion_block
         logits = M.unembed(cfg, params, open_block_rows(x, dec_idx, Bd))

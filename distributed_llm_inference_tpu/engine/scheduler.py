@@ -358,6 +358,35 @@ def step_width(model_cfg, n_slots: int, tile: int = 8,
     return _clamp_width(budget, n_slots, tile)
 
 
+def live_width(model_cfg, n_slots: int, tile: int = 8,
+               budget: Optional[int] = None) -> int:
+    """The most LIVE flat tokens a mixed launch carries, and so the width of
+    the axis its token-wise layers run on (engine/paged.mixed_step_ragged
+    packs the live tokens side by side; only the paged attention kernel sees
+    the tiles): decided here, beside `step_width`, from the same shapes.
+
+    Everywhere but one branch it IS the launch's width, and no token is
+    packed. Where `step_width` builds the launch of the fleet's decode tiles
+    plus prefill's budget (`_states_outweigh_weights`), a decode row's tile
+    of `tile` tokens holds one live token, so a full fleet's launch is
+    mostly padding (granite-4.0-h-micro at 64 slots: 640 wide, 448 of them
+    dead, and at 640 rows the products are arithmetic-bound where 240 rows
+    still hide under the weights' stream): there the live tokens are held to
+    n_slots + 2 x DENSE_STEP_TOKENS, a full fleet's decode tokens and twice
+    the dense budget of prompt (320 at 64 slots; the free tiles alone hold
+    `tile` x (width / tile - decoding rows), which is the tighter limit from
+    46 decoding rows up, so a warm fleet's burst loses no step). A model
+    whose sparse layers select by the launch's tiles
+    (`ModelConfig.sparse_layers`: models/minicpm_sala.tile_meta) keeps the
+    tile layout, and an explicit `budget` is obeyed as it is."""
+    width = step_width(model_cfg, n_slots, tile, budget)
+    if budget is not None or model_cfg.sparse_layers \
+            or not _states_outweigh_weights(model_cfg, n_slots):
+        return width
+    live = int(n_slots) + 2 * DENSE_STEP_TOKENS
+    return min(width, -(-live // tile) * tile)
+
+
 class TokenBudgetScheduler:
     """Pure host-side planner: slices the per-step flat-token budget into
     decode rows + class-apportioned prefill chunks, and answers the
@@ -367,11 +396,14 @@ class TokenBudgetScheduler:
 
     width: flat-token launch width (the compiled mixed program's shape);
     tile: the ragged kernel's query tile — every launch entry occupies
-    whole tiles, so budget accounting is in tiles.
+    whole tiles, so budget accounting is in tiles; live_width: the live
+    tokens the launch's compact axis holds (`live_width`), which `plan`
+    holds a step's prompt tokens to beside the tiles.
     """
 
     def __init__(self, classes, default_name: str, width: int, tile: int,
-                 n_slots: int, registry=None, tenant_weights=()):
+                 n_slots: int, registry=None, tenant_weights=(),
+                 live_width: Optional[int] = None):
         self.classes = classes
         self.default_name = default_name
         # tenant -> prefill-budget weight (engine_cfg.tenant_weights);
@@ -384,6 +416,9 @@ class TokenBudgetScheduler:
         self.tenant_feedback: dict = {}
         self.tile = int(tile)
         self.width = _clamp_width(width, n_slots, self.tile)
+        # the most live tokens a launch carries (`live_width`; None: the
+        # width, every tile may be full)
+        self.live_width = min(self.width, int(live_width or self.width))
         self.n_slots = int(n_slots)
         self.feedback = {name: _ClassFeedback() for name in classes}
         # summary of the most recent non-empty plan() — the flight
@@ -620,7 +655,8 @@ class TokenBudgetScheduler:
         accelerates idle fleets and self-disables under load — and
         otherwise K shrinks until every verify row (ceil((1+K)/tile)
         tiles each), every plain decode row, and one prefill-progress
-        tile (when prefill is pending) fit the step budget together."""
+        tile (when prefill is pending) fit the step budget together, and
+        their live tokens `live_width`."""
         if k_max <= 0 or n_spec_rows <= 0:
             return 0
         if self.decode_pressure(active_classes):
@@ -629,7 +665,10 @@ class TokenBudgetScheduler:
         reserve = n_plain_rows + (1 if jobs_pending else 0)
         for k in range(k_max, 0, -1):
             spec_tiles = -(-(1 + k) // self.tile) * n_spec_rows
-            if spec_tiles + reserve <= tiles_total:
+            live = (1 + k) * n_spec_rows + n_plain_rows + (
+                self.tile if jobs_pending else 0)
+            if spec_tiles + reserve <= tiles_total \
+                    and live <= self.live_width:
                 return k
         return 0
 
@@ -680,15 +719,19 @@ class TokenBudgetScheduler:
         return max(0, leftover)
 
     def plan(self, n_decode_tiles: int, jobs: list,
-             active_classes=(), now: Optional[float] = None) -> list:
+             active_classes=(), now: Optional[float] = None,
+             n_decode_tokens: Optional[int] = None) -> list:
         """Slice one step's budget: returns [(job, chunk_tokens)] with
         chunk_tokens >= 1, tile-granular except a job's FINAL chunk.
 
         Decode rows were reserved upstream — `n_decode_tiles` query
         tiles, one per plain decode row plus ceil((1+K)/tile) per
         speculative verify row, so speculated tokens debit the budget
-        exactly like prefill tokens; `jobs` are the pending prefills in
-        arrival order. Tiles left after decode are apportioned across
+        exactly like prefill tokens — and hold `n_decode_tokens` live
+        tokens (1 a decode row, 1 + K a verify row; None: every tile
+        full): the prompt's tiles fit what `live_width` leaves beside
+        them; `jobs` are the pending prefills in arrival order. Tiles
+        left after decode are apportioned across
         classes by weight x urgency, then WITHIN each class across
         tenants by configured tenant weight (`_grant_class`), FIFO
         within a tenant; leftovers spill FIFO across classes; the
@@ -699,10 +742,14 @@ class TokenBudgetScheduler:
             return []
         t = time.time() if now is None else now
         tiles_total = self.width // self.tile
-        tiles_left = tiles_total - n_decode_tiles
+        if n_decode_tokens is None:
+            n_decode_tokens = n_decode_tiles * self.tile
+        tiles_left = min(tiles_total - n_decode_tiles,
+                         (self.live_width - n_decode_tokens) // self.tile)
         if tiles_left < 1:
-            # structurally unreachable (width clamps to n_slots + 1 tiles
-            # and a prefilling admission occupies a slot), but never plan
+            # structurally unreachable (width clamps to n_slots + 1 tiles,
+            # a prefilling admission occupies a slot, and `live_width`
+            # leaves the dense budget beside a full fleet), but never plan
             # a launch that cannot hold its entries
             return []
         if self.decode_pressure(active_classes):

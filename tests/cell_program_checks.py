@@ -61,8 +61,10 @@ CELLS = {
     # mamba layers' convolution and matrix states and their snapshots are a
     # leaf a layer; 64 slots' tiles of 8 and the dense budget on top for prefill,
     # since the fleet's states outweigh the weights: 640)
+    # (... of which at most 64 + 2 x 128 = 320 are live, the axis the
+    # token-wise layers run on: engine/scheduler.live_width)
     "granite-4.0-h-micro": dict(
-        width=640, blocks=2048, leaf=("k", (4, 2048, 4, 64, 128)),
+        width=640, live=320, blocks=2048, leaf=("k", (4, 2048, 4, 64, 128)),
         scopes=DENSE_SCOPES + ("ssm_mix", "ssm_scan")),
     "mistral-7b-16l": dict(
         width=136, blocks=271, leaf=("k", (16, 271, 8, 128, 128)),
@@ -78,6 +80,25 @@ CELLS = {
         width=512, blocks=(4608, 1152), leaf=("kw", (4, 1152, 8, 128, 128)),
         scopes=DENSE_SCOPES + ROUTED_SCOPES + ("moe_shared",)),
 }
+
+
+def test_the_model_runs_on_the_launch_width_but_where_tiles_pad_it(
+        one_chip, no_persistent_cache, config):
+    """`live_width` is the launch's width in every configuration but the one
+    whose launch is fleet tiles + budget: there the token-wise layers run on
+    the live tokens alone (ISSUE 54) and nowhere else does a product, a norm
+    or a scan of the mixed program see the tile layout's width."""
+    built, cell = cell_programs(config), CELLS[config]
+    live = cell.get("live", cell["width"])
+    assert built.live == live
+    text = built.texts["mixed_step_ragged"]
+    D = built.cfg.dim
+    assert f"[{live},{D}]" in text
+    if live < built.width:
+        # (the tile layout is the kernel's and the head's index space alone)
+        assert not re.search(rf"\[{built.width},{built.cfg.ffn_dim}\]", text)
+        assert re.search(rf"f32\[\d+,{live},{live}\]", text)  # the decays
+        assert not re.search(rf"f32\[\d+,{built.width},{built.width}\]", text)
 
 
 def _pool_bytes(pool):

@@ -173,6 +173,7 @@ class StepPrograms:
     pool: dict
     blocks: object  # the pool's blocks (a grouped pool: one number a group)
     width: int  # the mixed launch's flat tokens
+    live: int  # the axis its token-wise layers run on (scheduler.live_width)
     compiled: dict  # {program name: jax.stages.Compiled}
 
     @functools.cached_property
@@ -202,7 +203,7 @@ def programs(model, slots, blocks, context, block_size=128, tile=8,
     from distributed_llm_inference_tpu.config import resolve_attn_impl
     from distributed_llm_inference_tpu.engine import generate as G
     from distributed_llm_inference_tpu.engine import paged as P
-    from distributed_llm_inference_tpu.engine.scheduler import step_width
+    from distributed_llm_inference_tpu.engine.scheduler import live_width, step_width
     from distributed_llm_inference_tpu.models import api as M
     from distributed_llm_inference_tpu.models.registry import get_model_config
 
@@ -257,6 +258,9 @@ def programs(model, slots, blocks, context, block_size=128, tile=8,
                 entries, offsets, slots, width=width, tile=tile)}
         if cfg.linear_layers:  # by slot: the snapshot restored, the one kept
             mixed_kw = {"snaps": (S((slots,), jnp.int32),) * 2}
+    live = live_width(cfg, slots, tile)
+    if live < width:  # as the engine dispatches it: no operand more elsewhere
+        mixed_kw["live_width"] = live
     assert len(offsets) == slots  # one tile a row
     if "dev" in mixed_kw:
         mixed_kw["dev"] = P.DeviceMeta(
@@ -278,7 +282,7 @@ def programs(model, slots, blocks, context, block_size=128, tile=8,
             key, S((slots,), jnp.int32),
             place(lambda: P.idle_mixed_arm(slots, cfg.vocab_size)),
             **mixed_kw).compile()
-    return StepPrograms(cfg, chip, params, pool, blocks, width, {
+    return StepPrograms(cfg, chip, params, pool, blocks, width, live, {
         "decode_slots_paged": chunk, "mixed_step_ragged": mixed})
 
 

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import pytest
 
 from distributed_llm_inference_tpu.engine import paged as EP
-from distributed_llm_inference_tpu.engine.scheduler import step_width
+from distributed_llm_inference_tpu.engine.scheduler import live_width, step_width
 from distributed_llm_inference_tpu.ops import quant as Q
 from distributed_llm_inference_tpu.ops.flash_attention import flash_attend
 from distributed_llm_inference_tpu.ops.kv_quant import KVQuant
@@ -381,12 +381,15 @@ def test_the_linear_scan_compiles_at_the_sala_cells_shapes(
 
 # granite-4.0-h-micro's mamba layers: the state-space scan's program at the
 # cell's shapes (64 slots, 64 heads of 64 over a state of 128, packed two a
-# 128-lane row: a float32 state [32, 128, 128] a row): a mixed launch's 64
-# tiles of 8 and 128 prompt tokens on top (`scheduler.step_width`'s 640 at 64
-# slots) and the decode chunk's one token a row. The state leaf goes in and comes out as one buffer, and the
-# call is named `ssm_scan` under its scope: what `ssm_scan_roofline` finds it
-# by.
-@pytest.mark.parametrize("flat,tq", [(640, 8), (64, 1)], ids=["mixed", "decode"])
+# 128-lane row: a float32 state [32, 128, 128] a row): a mixed launch's live
+# tokens side by side (`scheduler.live_width`'s 320 at 64 slots: no tiles, so a
+# chunk's blocks start anywhere), the tile layout it replaced there (64 tiles
+# of 8 and 128 prompt tokens on top, `scheduler.step_width`'s 640: what an
+# explicit budget still runs) and the decode chunk's one token a row. The state
+# leaf goes in and comes out as one buffer, and the call is named `ssm_scan`
+# under its scope: what `ssm_scan_roofline` finds it by.
+@pytest.mark.parametrize("flat,tq", [(320, 1), (640, 8), (64, 1)],
+                         ids=["mixed", "tiles", "decode"])
 def test_the_ssm_scan_compiles_at_the_granite_cells_shapes(
     one_chip, no_persistent_cache, flat, tq
 ):
@@ -395,7 +398,8 @@ def test_the_ssm_scan_compiles_at_the_granite_cells_shapes(
     cfg, slots, _, pool = cell_pool("granite-4.0-h-micro")
     S = _spec(one_chip)
     lin = pool["lin"][0]
-    assert (slots, step_width(cfg, slots, 8)) == (64, 640)
+    assert (slots, step_width(cfg, slots, 8), live_width(cfg, slots, 8)) == (
+        64, 640, 320)
     assert (lin.shape, lin.dtype) == ((64, 32, 128, 128), jnp.float32)
     H, P, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
     compiled = jax.jit(
@@ -411,8 +415,8 @@ def test_the_ssm_scan_compiles_at_the_granite_cells_shapes(
     assert "ssm_scan/jit(ssm_scan)" in text
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == lin.size * 4, memory
-    # nothing of the leaf's size beside the leaf: the tokens, and in the
-    # mixed launch the [64, 640, 640] decays of the within-launch part
+    # nothing of the leaf's size beside the leaf: the tokens, and in a mixed
+    # launch the [64, W, W] decays of the within-launch part (W 640 or 320)
     assert memory.temp_size_in_bytes < lin.size * 4 // 2, memory
     made = re.findall(r"= f32\[64,32,128,128\]\{[^}]*\} ([\w\-]+)\(", text)
     assert set(made) <= {"parameter", "get-tuple-element", "bitcast",

@@ -36,7 +36,9 @@ JOINED = ["mixed_step_pct", "host_ms_per_step", "fetch_wait_pct", "attn_grid_liv
 # metrics move out_tok_s, which keeps its one cell
 # PR 53's six read the worker's own counters in every cell they list
 WORKER_TIMED = ["decode_step_ms_mean", "mixed_step_ms_mean", "launch_timed_pct",
-                "decode_time_in_mixed_pct", "device_empty_wait_pct", "device_empty_host_pct"]
+                "decode_time_in_mixed_pct", "device_empty_wait_pct", "device_empty_host_pct",
+                # PR 54: the live share of the tokens a mixed step computes
+                "mixed_tokens_live_pct"]
 NOT_JOINED = ["step_weight_roofline", "attn_kv_roofline", "hybrid_attn_kv_roofline",
               "linear_attn_ms_per_step", "linear_attn_roofline", "conv_mix_ms_per_step",
               "gen_late_ms_max", "queue_wait_ms_mean", "ttft_ms_p50", "ttft_ms_p90",
@@ -218,6 +220,30 @@ def test_the_rooflines_count_useful_work_of_the_matched_launches(tmp_path):
     got = read("ssm_step_roofline", _traced(tmp_path, config))
     assert got == pytest.approx(
         100 * (5 * weights + 11 * 2 * 36 * 2 ** 21) / 819e9 / 5000e-6)
+
+
+@pytest.mark.parametrize("live,computed,want", [
+    (25000, 32000, 78.125),  # 100 launches of 640 flat tokens computed on 320
+    (25000, 64000, 39.0625),  # the same launches on the tile layout
+    (0, 0, None),  # no mixed launch in the window
+    (None, None, None),  # a program before PR 54: no counter, and no error
+], ids=["packed", "tiles", "no-launch", "parent"])
+def test_the_live_share_of_the_computed_tokens_is_the_counters_delta(live, computed, want):
+    from harness import scrape
+
+    def text(a, b):
+        held = 'dli_ragged_launches_total{phase="mixed"} 3\n'
+        if a is None:
+            return held
+        return held + (f'dli_mixed_tokens_total{{state="live"}} {a}\n'
+                       f'dli_mixed_tokens_total{{state="computed"}} {b}\n')
+
+    class Ctx:
+        before = scrape.parse(text(None if live is None else 1000, 6400))
+        after = scrape.parse(text(None if live is None else 1000 + live, 6400 + (computed or 0)))
+
+    got = read("mixed_tokens_live_pct", Ctx)
+    assert got is None if want is None else got == pytest.approx(want)
 
 
 def test_the_new_readers_give_nothing_for_a_program_without_what_they_read(tmp_path):

@@ -289,6 +289,58 @@ def test_the_launch_records_counts_are_the_hand_count():
     assert held >= f.ce._m_lin_rows.labels(state="touched").value > touched
 
 
+@pytest.fixture(scope="module")
+def wide_fleets():
+    """32 slots of the tiny model: their float32 states outweigh its weights,
+    so the launch is the fleet's 32 tiles of 8 and the dense 128 on top, 384
+    wide, and its token-wise layers run on 32 + 2 x 128 = 288 live tokens
+    (engine/scheduler.live_width); beside it the same fleet held to the tile
+    layout throughout (`live_width` = the width). Both serve the same
+    requests at once: prompts that land in chunks beside decoding rows, a
+    prefix hit restored from a snapshot, slots let again."""
+    from distributed_llm_inference_tpu.engine import scheduler
+
+    asks = [(prompt_ids(9 + 11 * i, 20 + i), 6 + i % 5) for i in range(8)]
+    again = (asks[6][0][:66] + prompt_ids(7, 40), 5)
+
+    def serve():
+        f = Fleet(impl="pallas", budget=None, slots=32, pool=700, snapshots=24)
+        first = f.ask_all(asks)
+        return f, first + [f.ask(*again)] + f.ask_all(asks[:3])
+
+    compact = serve()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(scheduler, "live_width", scheduler.step_width)
+        tiles = serve()
+    return asks + [again] + asks[:3], compact, tiles
+
+
+def test_the_live_tokens_alone_deliver_what_the_tile_layout_delivers(wide_fleets):
+    asks, (compact, got), (tiles, want) = wide_fleets
+    assert compact.ce.stats()["scheduler"]["step_width"] == 384
+    assert compact.ce.stats()["scheduler"]["live_width"] == 288
+    assert tiles.ce.stats()["scheduler"]["live_width"] == 384
+    assert got[8].get("prefix_cached_tokens") == want[8].get("prefix_cached_tokens") == 64
+    for (prompt, _), a, b in zip(asks, got, want):
+        assert a["ids"] == b["ids"] and len(a["ids"]) >= 3
+        np.testing.assert_allclose(margins(prompt, a["ids"]), 0.0, atol=1e-4)
+
+
+def test_the_record_and_the_counter_hold_the_live_and_the_computed_tokens(wide_fleets):
+    _, (compact, _), (tiles, _) = wide_fleets
+    for f, computed in ((compact, 288), (tiles, 384)):
+        mixed = [r for r in f.records if r["phase"] == "mixed"]
+        assert any(r["prefill_chunks"] and r["decode_rows"] for r in mixed)
+        assert all(r["tokens_live"] == r["decode_rows"] + r["prefill_tokens"] <= computed
+                   for r in mixed)
+        assert {r["tokens_computed"] for r in mixed} == {computed}
+        assert all(r["tiles"] == 48 for r in mixed)
+        count = {s: f.ce._m_mixed_tokens[s].value for s in ("live", "computed")}
+        assert count == {"live": sum(r["tokens_live"] for r in mixed),
+                         "computed": computed * len(mixed)}
+        assert "tokens_live" not in next(r for r in f.records if r["phase"] == "chunk")
+
+
 def test_start_up_refuses_what_the_family_does_not_carry():
     eng = create_engine("test-granite-tiny", seed=SEED)
     with pytest.raises(ValueError, match="no dense fleet of convolution and matrix"):

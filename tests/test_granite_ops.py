@@ -51,6 +51,10 @@ LAUNCHES = {
     "tiles-of-8": (8, [(2, 1), (0, 200), (-1, 0), (3, 1)]),
     # a decode step: one token a row, a tile each
     "decode": (1, [(0, 1), (1, 1), (2, 1), (3, 1)]),
+    # a mixed launch's live tokens packed side by side (ISSUE 54: no tiles, so
+    # tq 1): decode rows' single tokens, then a chunk that starts at flat
+    # token 2 and whose second block of 128 ends with the axis, padding last
+    "compact": (1, [(2, 1), (3, 1), (0, 203)] + [(-1, 0)] * 11),
 }
 
 

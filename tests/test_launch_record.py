@@ -659,6 +659,8 @@ def test_the_families_are_on_an_idle_engines_metrics_from_zero(setup):
             assert f'dli_launch_timing_total{{phase="{phase}",state="{state}"}} 0' in text
     for phase in WORKER_PHASES:  # the loop's first instants are already counted
         assert f'dli_device_empty_seconds_total{{phase="{phase}"}} ' in text
+    for state in ("live", "computed"):
+        assert f'dli_mixed_tokens_total{{state="{state}"}} 0' in text
 
 
 def test_an_idle_engines_empty_seconds_are_wait_work(drained):
@@ -681,6 +683,24 @@ def test_an_idle_engines_empty_seconds_are_wait_work(drained):
     # (the first launch, at the least, is planned and dispatched with
     # nothing queued)
     assert served["dispatch"] > 0 and served["plan"] > 0
+
+
+def test_mixed_tokens_are_counted_live_and_computed(runs):
+    """The record's `tokens_live` is the launch's decode, verify and prompt
+    tokens and `tokens_computed` the axis the token-wise layers ran on
+    (engine/scheduler.live_width: this dense fleet's whole width); the
+    counter sums both over the mixed launches."""
+    run = runs[0]
+    ev = [e for e in run["flight"] if e["kind"] == "plan"]
+    assert all(e["tokens_live"] == e["decode_rows"] + e["prefill_tokens"] for e in ev)
+    assert all(e["tokens_computed"] == run["width"] for e in ev)
+    assert run["cont"].stats()["scheduler"]["live_width"] == run["width"]
+    want = _expected(run)
+    snap = run["snap"]
+    assert _value(snap, "dli_mixed_tokens_total", state="live") \
+        == sum(e["tokens_live"] for e in ev) == want["prefill_tokens"]
+    assert _value(snap, "dli_mixed_tokens_total", state="computed") \
+        == run["width"] * want["mixed"]
 
 
 def test_flight_plan_event_is_the_launch_record(runs):

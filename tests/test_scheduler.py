@@ -30,6 +30,7 @@ from distributed_llm_inference_tpu.engine.scheduler import (
     PrefillJob,
     SLOClass,
     TokenBudgetScheduler,
+    live_width,
     parse_slo_classes,
     step_width,
 )
@@ -138,6 +139,81 @@ def test_prefill_keeps_the_budget_where_the_states_outweigh_the_weights(
     assert step_width(cfg, slots, TILE) == width
     # an explicit budget is obeyed here too
     assert step_width(cfg, slots, TILE, 1024) == 1024
+
+
+# -- the live tokens of a launch (engine/scheduler.live_width, ISSUE 54) --------
+
+@pytest.mark.parametrize("model,slots,live", [
+    # a launch of fleet tiles + budget holds one live token a decode tile:
+    # the token-wise layers run on slots + 2 x 128 live tokens
+    ("granite-4.0-h-micro", 64, 320),
+    ("granite-4.0-h-micro", 128, 384),  # of 1,152
+    ("granite-4.0-h-micro", 43, 304),  # of 472
+    # everywhere else it IS the width: the benchmark's seven other
+    # configurations at their cells' slots ...
+    ("olmo2-7b", 12, 128), ("mistral-7b", 16, 136),
+    ("kanana-2-30b-a3b", 8, 512), ("sdar-30b-a3b-chat", 32, 512),
+    ("lfm2-24b-a2b", 16, 512), ("trinity-large-preview", 16, 512),
+    ("minicpm-sala", 16, 136),
+    # ... granite under the slot clamp alone, and a sparse model whose
+    # selection reads the launch's tiles, however many rows it keeps
+    ("granite-4.0-h-micro", 42, 43 * TILE), ("granite-4.0-h-micro", 16, 136),
+    ("minicpm-sala", 512, 512 * TILE + 128),
+])
+def test_live_width_is_the_width_but_where_fleet_tiles_pad_the_launch(
+        model, slots, live):
+    cfg = get_model_config(model).replace(dtype="bfloat16")
+    width = step_width(cfg, slots, TILE)
+    assert live_width(cfg, slots, TILE) == live <= width
+    assert (live < width) == (model.startswith("granite") and slots >= 43)
+    # whole tiles, room for a full fleet's decode tokens and a tile of prompt
+    assert live % TILE == 0 and live >= slots + TILE
+    # an explicit budget is a width of full tiles: obeyed as it is
+    assert live_width(cfg, slots, TILE, 1024) == step_width(cfg, slots, TILE, 1024)
+
+
+def _live_sched(slots=64, live=320):
+    s = _sched(width=slots * TILE + 128, n_slots=slots)
+    return TokenBudgetScheduler(s.classes, "standard", s.width, TILE, slots,
+                                live_width=live)
+
+
+@pytest.mark.parametrize("decoding,spec_k,callers", [
+    (0, 0, 128),  # a cold start: every tile is free, 640 tokens would fit
+    (20, 0, 44), (45, 0, 19), (46, 0, 18),  # the tiles bind from 46 rows up
+    (63, 0, 1),  # a full fleet
+    (24, 3, 40),  # verify rows: 1 + K live tokens a row
+    (40, 6, 24),  # ... whose live tokens alone nearly fill the axis
+])
+def test_plan_never_plans_more_live_tokens_than_the_axis_holds(
+        decoding, spec_k, callers):
+    s = _live_sched()
+    cls = s.classes["standard"]
+    assert (s.width, s.live_width) == (640, 320)
+    k = s.spec_draft_len(spec_k, decoding, 0, jobs_pending=True) if spec_k else 0
+    assert k <= spec_k and (k > 0 or not spec_k or decoding * 2 > 312)
+    tiles = decoding * -(-(1 + k) // TILE)
+    tokens = decoding * (1 + k)
+    jobs = [_job(cls, tail=64 + (7 * i) % 65, enqueued=1.0 + i, slot=decoding + i)
+            for i in range(min(callers, 64 - decoding))]
+    plan = s.plan(tiles, jobs, now=200.0, n_decode_tokens=tokens)
+    prompt = sum(n for _, n in plan)
+    assert tokens + prompt <= s.live_width
+    assert tiles + sum(-(-n // TILE) for _, n in plan) <= s.width // TILE
+    # no token of room is left idle beyond a tile, and the oldest job moves
+    room = min(s.live_width - tokens, (s.width // TILE - tiles) * TILE)
+    assert prompt > room - 2 * TILE or prompt == sum(len(j.ids) for j in jobs)
+    assert plan[0][0] is jobs[0]
+
+
+def test_plan_without_a_live_width_fills_every_tile_as_before():
+    s = _sched(width=640, n_slots=64)
+    assert s.live_width == s.width
+    cls = s.classes["standard"]
+    jobs = [_job(cls, tail=1000, enqueued=1.0 + i, slot=i) for i in range(10)]
+    assert sum(n for _, n in s.plan(0, jobs, now=2.0)) == 640
+    assert sum(n for _, n in s.plan(0, jobs, now=2.0, n_decode_tokens=0)) == 640
+    assert s.spec_draft_len(7, 60, 3, jobs_pending=True) == 7
 
 
 def test_budget_slicing_reserves_decode_rows():
