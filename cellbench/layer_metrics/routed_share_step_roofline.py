@@ -1,0 +1,36 @@
+"""Model step: the whole step's share of the chip's peak for a model that
+holds one chip's share of its routed experts. For each traced launch the
+least time for what its steps must stream and compute
+(roofline/routed_share_step.py: the attention and dense matrices, the
+routers and the head's slice once a step, THE HELD EXPERTS THE LAUNCH
+TOUCHED, the useful K/V bytes; or the launch's matrix operations at the bf16
+peak, the larger) over the device time of THAT launch's execution of its
+step program (harness/host_spans.join_launches pairs them). Useful bytes and
+operations only, so it cannot pass 100; it is the bound a later claim in
+such a cell is read against. From a configuration of another family, or a
+program or a trace without the launch spans, the record's counts per kind or
+the routed counts on the span that follows the fetch, None."""
+from harness import host_spans, manifest, trace_reduce
+
+
+def read(ctx):
+    trace = ctx.config.get("serving", {}).get("trace", {})
+    path = host_spans.find(ctx.trace_dir)
+    if "hybrid_layer_pattern" not in ctx.config or path is None \
+            or "step_modules" not in trace:
+        return None
+    step = manifest.load_module("roofline", "routed_share_step")
+    spans = host_spans.read(path)
+    planes = trace_reduce.read_planes(path)
+    if not spans or not planes:
+        return None
+    chip = planes[min(planes)]
+    after = {int(st["seq"]): st for name, _, _, st in spans
+             if name == "phase.distribute" and "seq" in st}
+    least = seconds = 0.0
+    for st, start, end in host_spans.join_launches(
+            spans, chip.get(trace_reduce.MODULES_LINE, []), trace["step_modules"]):
+        t = step.least_seconds(ctx.config, st, after.get(int(st["seq"]), {}), ctx.peaks)
+        if t is not None:
+            least, seconds = least + t, seconds + (end - start)
+    return 100.0 * least / seconds if seconds > 0 else None

@@ -21,7 +21,15 @@ import jax.numpy as jnp
 KEEPS_CONV_STATE = frozenset({"conv", "mamba"})
 KEEPS_MATRIX_STATE = frozenset({"lightning-attn", "mamba"})
 KEEPS_KV = frozenset({"full_attention", "minicpm4", "attention"})
+# K/V of the last attn_window positions alone: in a stack that also has a
+# kind that keeps its whole context, the pool holds such layers in a group
+# of their own whose rows give blocks back (`kv_groups`)
+KEEPS_WINDOW_KV = frozenset({"sliding_attention"})
 SELECTS_BLOCKS = frozenset({"minicpm4"})
+# the families whose routed layer is told which experts it holds
+# (cfg.expert_lo) and whose seeded draw follows an expert's PUBLISHED index,
+# so that a configuration can be one chip's share of an expert-parallel layer
+HOLDS_EXPERT_SHARE = frozenset({"afmoe", "mimo_v2"})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -35,7 +43,7 @@ class ModelConfig:
 
     name: str = "tinyllama-1.1b"
     # "llama" | "gpt2" | "mla_moe" | "lfm2" | "afmoe" | "minicpm_sala"
-    # | "granite_hybrid"
+    # | "granite_hybrid" | "mimo_v2"
     arch: str = "llama"
     vocab_size: int = 32000
     dim: int = 2048
@@ -150,7 +158,8 @@ class ModelConfig:
     # added to the sum of the chosen scores before they are renormalized
     # (lfm2's modelling code: 1e-6; the other routed families add nothing)
     router_norm_eps: float = 0.0
-    # One chip's share of an expert-parallel deployment (arch "afmoe"): the
+    # One chip's share of an expert-parallel deployment (HOLDS_EXPERT_SHARE:
+    # arch "afmoe", "mimo_v2"): the
     # router stays n_experts wide and this chip holds published experts
     # expert_lo .. expert_lo + n_experts_held - 1 (0: all of them). A pair
     # routed to an expert held elsewhere is left out, in the program and in
@@ -184,7 +193,20 @@ class ModelConfig:
     # conv_kernel - 1 inputs AND a float32 matrix state of ssm_heads x
     # ssm_head_dim x ssm_state numbers a row, no K/V) or "attention" (GQA
     # with no position encoding at attn_scale_override; owns K/V).
+    # Arch "mimo_v2" (models/mimo_v2.py: MiMo-V2.5) names its layers as
+    # "afmoe" does, and the two kinds differ in more than the window: a
+    # sliding layer has window_kv_heads K/V heads (0: n_kv_heads, as a global
+    # one), rotates by rope_local_theta (a global one by rope_theta) and,
+    # under window_sink, adds one learned logit a query head to its softmax's
+    # denominator (it takes probability and gives no value). Both kinds: keys
+    # and queries head_dim wide and values v_head_dim (0: head_dim), the
+    # rotation on the first rotary_dim lanes of a head (0: all of them), the
+    # values times attn_value_scale.
     layer_types: Optional[tuple] = None
+    window_kv_heads: int = 0
+    window_sink: bool = False
+    rotary_dim: int = 0
+    attn_value_scale: float = 1.0
     conv_kernel: int = 0
     linear_heads: int = 0
     ssm_heads: int = 0
@@ -259,7 +281,8 @@ class ModelConfig:
         if self.router_score is None:
             object.__setattr__(
                 self, "router_score",
-                "sigmoid" if self.arch in ("mla_moe", "lfm2", "afmoe")
+                "sigmoid" if self.arch in ("mla_moe", "lfm2", "afmoe",
+                                           "mimo_v2")
                 else "softmax",
             )
         if self.router_score not in ("sigmoid", "softmax"):
@@ -332,7 +355,8 @@ class ModelConfig:
         if self.rope_local_theta is not None and (
             self.attn_window is None
             or (self.attn_window_pattern == "all"
-                and self.attn_window_layer_types is None)
+                and self.attn_window_layer_types is None
+                and not set(self.layer_types or ()) & KEEPS_WINDOW_KV)
         ):
             raise ValueError(
                 "rope_local_theta needs a per-layer window pattern "
@@ -380,23 +404,28 @@ class ModelConfig:
                     "arch 'lfm2' needs n_experts, moe_ffn_dim and "
                     "first_k_dense < n_layers (an expert stack)"
                 )
-        elif self.arch == "afmoe":
+        elif self.arch in ("afmoe", "mimo_v2"):
             kinds = self.layer_types or ()
             if (len(kinds) != self.n_layers
                     or set(kinds) - {"sliding_attention", "full_attention"}):
                 raise ValueError(
-                    f"arch 'afmoe' needs layer_types: n_layers "
+                    f"arch {self.arch!r} needs layer_types: n_layers "
                     f"({self.n_layers}) entries of 'sliding_attention' / "
                     f"'full_attention'; got {kinds!r}"
                 )
             if not (self.n_experts and self.moe_ffn_dim
                     and 0 <= self.first_k_dense < self.n_layers):
                 raise ValueError(
-                    "arch 'afmoe' needs n_experts, moe_ffn_dim and "
-                    "first_k_dense < n_layers (an expert stack)"
+                    f"arch {self.arch!r} needs n_experts, moe_ffn_dim and "
+                    f"first_k_dense < n_layers (an expert stack)"
                 )
             if self.conv_kernel:
                 raise ValueError("conv_kernel is arch 'lfm2' only")
+            if self.arch == "mimo_v2" and "full_attention" not in kinds:
+                # (a pool of one group takes the global kind's K/V heads)
+                raise ValueError(
+                    "arch 'mimo_v2' needs at least one 'full_attention' "
+                    "layer")
         elif self.arch == "minicpm_sala":
             kinds = self.layer_types or ()
             # (at least one of EACH: the family's pool, host position model
@@ -445,9 +474,23 @@ class ModelConfig:
                     f"ssm_groups {self.ssm_groups}")
         elif self.layer_types is not None or self.conv_kernel:
             raise ValueError(
-                "layer_types is arch 'lfm2' / 'afmoe' / 'minicpm_sala' / "
-                "'granite_hybrid' only, conv_kernel arch 'lfm2' / "
-                "'granite_hybrid' only")
+                "layer_types is arch 'lfm2' / 'afmoe' / 'mimo_v2' / "
+                "'minicpm_sala' / 'granite_hybrid' only, conv_kernel arch "
+                "'lfm2' / 'granite_hybrid' only")
+        if self.arch != "mimo_v2" and (
+                self.window_kv_heads or self.window_sink or self.rotary_dim
+                or self.attn_value_scale != 1.0):
+            raise ValueError(
+                "window_kv_heads, window_sink, rotary_dim and "
+                "attn_value_scale are arch 'mimo_v2' only")
+        if self.window_kv_heads and self.n_heads % self.window_kv_heads:
+            raise ValueError(
+                f"n_heads ({self.n_heads}) must be divisible by "
+                f"window_kv_heads ({self.window_kv_heads})")
+        if self.rotary_dim % 2 or self.rotary_dim > self.head_dim:
+            raise ValueError(
+                f"rotary_dim ({self.rotary_dim}) must be even and at most "
+                f"head_dim ({self.head_dim})")
         if self.linear_heads and self.arch != "minicpm_sala":
             raise ValueError("linear_heads is arch 'minicpm_sala' only")
         if self.arch != "granite_hybrid" and (
@@ -456,9 +499,11 @@ class ModelConfig:
             raise ValueError("ssm_heads, ssm_head_dim, ssm_state and "
                              "conv_bias are arch 'granite_hybrid' only")
         if self.expert_lo or self.n_experts_held:
-            if self.arch != "afmoe":
-                raise ValueError("an expert share (expert_lo, "
-                                 "n_experts_held) is arch 'afmoe' only")
+            if self.arch not in HOLDS_EXPERT_SHARE:
+                raise ValueError(
+                    "an expert share (expert_lo, n_experts_held) is for the "
+                    "families whose routed layer is told what it holds: "
+                    + " / ".join(sorted(HOLDS_EXPERT_SHARE)))
             if not (0 <= self.expert_lo
                     and self.expert_lo + self.experts_held <= self.n_experts):
                 raise ValueError(
@@ -476,7 +521,8 @@ class ModelConfig:
         if self.moe_ffn_dim and not self.n_experts:
             raise ValueError("moe_ffn_dim > 0 needs n_experts > 0")
         if self.n_experts:
-            if self.arch not in ("llama", "mla_moe", "lfm2", "afmoe"):
+            if self.arch not in ("llama", "mla_moe", "lfm2", "afmoe",
+                                 "mimo_v2"):
                 raise ValueError("MoE (n_experts > 0) is llama-family only")
             if not 1 <= self.n_experts_per_tok <= self.n_experts:
                 raise ValueError(
@@ -552,9 +598,9 @@ class ModelConfig:
         """The layers that own K/V: every layer, or those of a kind that
         keeps K/V where layer_types tells kinds that do from kinds that do
         not."""
-        if self.layer_types is None or self.arch == "afmoe":
+        if self.layer_types is None:
             return tuple(range(self.n_layers))
-        return self._layers_of(KEEPS_KV)
+        return self._layers_of(KEEPS_KV | KEEPS_WINDOW_KV)
 
     @property
     def experts_held(self) -> int:
@@ -566,12 +612,12 @@ class ModelConfig:
         """The paged pool's groups of K/V layers, each with its own blocks
         and block table (engine/paged.py): ("global",) for a model whose
         layers all keep their whole context (a uniform window is masked,
-        never given back), ("global", "window") for arch 'afmoe' with both
-        kinds of layer, where a window layer gives back the blocks it can
-        no longer read."""
-        kinds = set(self.layer_types or ()) if self.arch == "afmoe" else ()
-        if kinds == {"sliding_attention", "full_attention"} \
-                and self.attn_window:
+        never given back), ("global", "window") for a stack that has layers
+        of a kind that keeps its whole context (KEEPS_KV) AND of a kind
+        that keeps its last attn_window positions (KEEPS_WINDOW_KV), where
+        a window layer gives back the blocks it can no longer read."""
+        if (self.attn_window and self._layers_of(KEEPS_KV)
+                and self._layers_of(KEEPS_WINDOW_KV)):
             return ("global", "window")
         return ("global",)
 
@@ -581,8 +627,47 @@ class ModelConfig:
         ones, else every layer that owns K/V."""
         if len(self.kv_groups) == 1:
             return self.attn_layers
-        kind = "full_attention" if group == "global" else "sliding_attention"
-        return tuple(i for i, k in enumerate(self.layer_types) if k == kind)
+        return self._layers_of(
+            KEEPS_KV if group == "global" else KEEPS_WINDOW_KV)
+
+    @property
+    def kinds_of_attention(self) -> bool:
+        """layer_types tells window layers from global ones and nothing
+        else: every layer owns K/V and none keeps a state (the pool's
+        leaves by group: engine/paged.init_pool)."""
+        return (self.layer_types is not None and not self.conv_kernel
+                and not set(self.layer_types)
+                - {"sliding_attention", "full_attention"})
+
+    def group_kv_heads(self, group: str) -> int:
+        """K/V heads of the layers in the pool's `group`."""
+        if group == "window" and self.window_kv_heads:
+            return self.window_kv_heads
+        return self.n_kv_heads
+
+    def group_rope_theta(self, group: str) -> float:
+        """The rotation's base on the layers of `group`."""
+        if group == "window" and self.rope_local_theta is not None:
+            return self.rope_local_theta
+        return self.rope_theta
+
+    @property
+    def value_dim(self) -> int:
+        """Numbers of a value head (a per-head K/V cache; keys and queries
+        are head_dim wide)."""
+        return self.head_dim if self.latent_dim else (
+            self.v_head_dim or self.head_dim)
+
+    @property
+    def key_row(self) -> int:
+        """A key head as the paged pool stores it: a head wider than one
+        128-lane tile on whole tiles, zero pad (192 -> 256: the device
+        tiles the minor dimension by 128 lanes whatever the array says, so
+        the pad costs no byte the unpadded leaf would not, and the kernels
+        then copy and write whole tiles, ops/paged_attention.
+        writes_in_place); a narrower one as it is."""
+        Dh = self.head_dim
+        return -(-Dh // 128) * 128 if Dh > 128 else Dh
 
     @property
     def kv_pack(self) -> int:

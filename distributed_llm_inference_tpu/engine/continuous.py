@@ -123,8 +123,9 @@ from ..utils.metrics import (
     CONV_STATE_RESETS_HELP,
     CONV_TAIL_WRITES_HELP, DECODE_ROW_SECONDS_HELP, DECODE_STEP_HELP,
     DEFAULT_SIZE_BUCKETS, DEVICE_EMPTY_HELP, DIFFUSION_FORWARDS_HELP,
-    DIFFUSION_FUSED_HELP, DIFFUSION_TOKENS_HELP, KV_GROUP_BLOCKS_HELP,
-    KV_WINDOW_RELEASED_HELP, LAUNCH_DEVICE_SECONDS_HELP,
+    DIFFUSION_FUSED_HELP, DIFFUSION_TOKENS_HELP, KV_GROUP_BLOCK_BYTES_HELP,
+    KV_GROUP_BLOCKS_HELP, KV_WINDOW_GIVEN_HELP, KV_WINDOW_RELEASED_HELP,
+    LAUNCH_DEVICE_SECONDS_HELP,
     LAUNCH_DEVICE_STEPS_HELP, LAUNCH_TIMING_HELP, LINEAR_STATE_RESETS_HELP,
     LINEAR_STATE_ROWS_HELP, MOE_PAIRS_HELP,
     PREFIX_STATE_TOKENS_HELP, SLOT_RELEASE_HELP, SLOT_TURNOVER_HELP,
@@ -311,11 +312,11 @@ class ContinuousEngine:
     ):
         cfg = engine.cfg
         if cfg.arch not in ("llama", "gpt2", "mla_moe", "lfm2", "afmoe",
-                            "minicpm_sala", "granite_hybrid"):
+                            "minicpm_sala", "granite_hybrid", "mimo_v2"):
             raise ValueError(
                 f"continuous batching supports the llama, gpt2, mla_moe, afmoe, "
-                f"lfm2, minicpm_sala and granite_hybrid families; model arch "
-                f"is {cfg.arch!r}"
+                f"lfm2, minicpm_sala, granite_hybrid and mimo_v2 families; "
+                f"model arch is {cfg.arch!r}"
             )
         if cfg.recurrent:
             # (before the dense fleet would be built for it)
@@ -440,7 +441,7 @@ class ContinuousEngine:
                 )
             self._pool_blocks = int(kv_pool_blocks)
             # A pool grouped by layer kind (cfg.kv_groups: window and
-            # global layers in one stack, models/afmoe.py): this
+            # global layers in one stack, models/afmoe.py, mimo_v2.py): this
             # allocator, `_table` and `req.block_ids` are the GLOBAL
             # group's, as for every model; the window group has its own
             # free list and tables (engine/paged.WindowBlocks), sized from
@@ -456,7 +457,7 @@ class ContinuousEngine:
                 self._group_blocks = P.group_blocks(
                     cfg, self._pool_blocks, P.window_row_budget(
                         cfg.attn_window, launch, self.kv_block_size),
-                    self.n_slots)
+                    self.n_slots, self.kv_block_size)
                 self._wgrp = P.WindowBlocks(
                     self._group_blocks[1], self.n_slots, self._max_blocks,
                     self.kv_block_size, cfg.attn_window, launch,
@@ -909,7 +910,7 @@ class ContinuousEngine:
         # a stack of window and global layers (cfg.layer_types) is counted
         # per kind: (layers, window) of each, the global kind first
         self._kv_kinds = None
-        if self.cfg.arch == "afmoe":
+        if self.cfg.kinds_of_attention:
             self._kv_window = None
             slide = sum(k == "sliding_attention"
                         for k in self.cfg.layer_types)
@@ -1148,6 +1149,20 @@ class ContinuousEngine:
         self._m_win_released = m.counter(
             "dli_kv_window_blocks_released_total", KV_WINDOW_RELEASED_HELP,
         ).labels()
+        self._m_win_given = m.counter(
+            "dli_kv_window_blocks_given_total", KV_WINDOW_GIVEN_HELP,
+        ).labels()
+        if self.paged:
+            block_bytes = m.gauge(
+                "dli_kv_group_block_bytes", KV_GROUP_BLOCK_BYTES_HELP,
+                ("group",))
+            for group, leaves in zip(self.cfg.kv_groups,
+                                     self._P.GROUP_LEAVES):
+                held = [self.cache[n] for n in leaves if n in self.cache]
+                if held:  # (a latent pool has other leaves and no groups)
+                    block_bytes.labels(group=group).set(sum(
+                        a.nbytes // a.shape[1]
+                        for a in jax.tree.leaves(held)))
         self._groups_noted = 0.0
         self._note_groups()
         self._m_moe_tokens = m.counter(
@@ -1860,6 +1875,7 @@ class ContinuousEngine:
                     "free_blocks": self._wgrp.alloc.free_blocks,
                     "row_budget": self._wgrp.row_budget,
                     "released_blocks": self._wgrp.released,
+                    "given_blocks": self._wgrp.given,
                     **(self._bpx.side_stats() if self._bpx is not None
                        else {}),
                 }
@@ -2099,17 +2115,28 @@ class ContinuousEngine:
             g.labels(group=group, state="live").set(
                 alloc.outstanding - cached)
 
-    def _window_ensure(self, rows):
+    def _window_ensure(self, rows) -> dict:
         """Before a launch is built: a window-group block for every
         position the launch's rows write ((slot, first position, tokens)
-        each; a pool of one group has nothing to do)."""
+        each; a pool of one group has nothing to do). Returns the launch
+        record's fields of the group's turnover: the blocks given now, and
+        those the rows give back once the launch is dispatched
+        (`_window_release`, which the record comes before)."""
         if self._wgrp is None:
-            return
-        changed = False
+            return {}
+        before = self._wgrp.given
         for b, start, n in rows:
-            changed |= self._wgrp.ensure(b, start, n)
-        if changed:
+            self._wgrp.ensure(b, start, n)
+        given = self._wgrp.given - before
+        if given:
+            self._m_win_given.inc(given)
             self._table_dev = None
+        return {
+            "window_blocks_given": given,
+            "window_blocks_released": sum(
+                self._wgrp.releasable(b, start + n - 1)
+                for b, start, n in rows),
+        }
 
     def _window_release(self, rows):
         """After the launch's dispatch: each row gives back the window
@@ -3295,18 +3322,18 @@ class ContinuousEngine:
         the three are then the kinds' sums by their layer counts, so that
         attended / walked and walked pages / steps stay shares of one
         thing."""
-        def counts(window):
+        def counts(window, group=0):
             walk = walked(window)
             return (int(attended(window)), int(np.sum(walk)),
-                    int(np.sum(self._kv_walk_steps(phase, walk))))
+                    int(np.sum(self._kv_walk_steps(phase, walk, group))))
 
         names = ("kv_tokens", "kv_grid_tokens", "kv_walk_steps")
         if self._kv_kinds is None:
             return dict(zip(names, counts(self._kv_window)))
         out = dict.fromkeys(names, 0)
-        for name, (layers, window) in zip(("global", "window"),
-                                          self._kv_kinds):
-            a, w, n = counts(window)
+        for group, (name, (layers, window)) in enumerate(
+                zip(("global", "window"), self._kv_kinds)):
+            a, w, n = counts(window, group)
             out[f"kv_tokens_{name}"], out[f"kv_grid_tokens_{name}"] = a, w
             for key, count in zip(names, (a, w, n)):
                 out[key] += layers * count
@@ -3380,33 +3407,41 @@ class ContinuousEngine:
         )
         return np.where(length > 0, (needed - first) * bs, 0)
 
-    def _walk_pages_of(self, tq: int) -> int:
+    def _walk_pages_of(self, tq: int):
         """P of the paged kernels' walk for query tiles of tq tokens over
-        this fleet's pool (every K/V or latent leaf has one row shape),
-        from the function the kernels take it from."""
+        this fleet's pool, from the function the kernels take it from: one
+        number a group of the pool (cfg.kv_groups' order; a pool of one
+        kind has one), since a grouped pool's rows, their own kinds', may
+        walk differently."""
         from ..ops.kv_quant import KVQuant
         from ..ops.paged_attention import walk_pages_per_step
 
+        if "kw" in self.cache:
+            return tuple(
+                walk_pages_per_step(self.cache[kn], self.cfg.n_heads, tq,
+                                    self._max_blocks) for kn in ("k", "kw"))
         if self._sparse is not None:  # a page list a KV head
-            return walk_pages_per_step(self.cache["k"], self.cfg.n_heads, tq,
-                                       self._max_blocks, listed=True)
+            return (walk_pages_per_step(self.cache["k"], self.cfg.n_heads, tq,
+                                        self._max_blocks, listed=True),)
         leaf = next(
             a for a in jax.tree.leaves(
                 self.cache, is_leaf=lambda a: isinstance(a, KVQuant))
             if isinstance(a, KVQuant) or a.ndim == 5
         )
-        return walk_pages_per_step(leaf, self.cfg.n_heads, tq,
-                                   self._max_blocks,
-                                   latent=self.cfg.latent_dim > 0)
+        return (walk_pages_per_step(leaf, self.cfg.n_heads, tq,
+                                    self._max_blocks,
+                                    latent=self.cfg.latent_dim > 0),)
 
-    def _kv_walk_steps(self, phase: str, walk):
+    def _kv_walk_steps(self, phase: str, walk, group: int = 0):
         """Loop steps the paged kernels run over `walk`, the positions
         `_kv_walk` counts a tile: its pages over the pages a step of
         `phase`'s program folds, rounded up (ops/paged_attention.
-        _walk_shape's P). The gather path loops over nothing."""
+        _walk_shape's P; `group`'s own under a grouped pool). The gather
+        path loops over nothing."""
         if not self._kv_walks:
             return np.zeros_like(walk)
-        return -(-(walk // self.kv_block_size) // self._walk_pages[phase])
+        pages = self._walk_pages[phase][group]
+        return -(-(walk // self.kv_block_size) // pages)
 
     # -- launch-level device-time attribution (ISSUE 17) ---------------------
     def _prof_note_launch(self, t_launch: float, snapshot, rec: dict):
@@ -3517,7 +3552,7 @@ class ContinuousEngine:
                 for b, r in enumerate(self._assignment)
                 if r is not None and self._host_pos[b] < self._host_end[b]
             ]
-            self._window_ensure(wrows)
+        wfields = self._window_ensure(wrows)
         if self.paged:
             if self._table_dev is None:
                 self._table_dev = self._launch_table()
@@ -3559,7 +3594,7 @@ class ContinuousEngine:
                 lambda w: self._kv_walk(at, alive * span, w)),
             row_steps=int(live.sum()),
             decode_rows=int(np.count_nonzero(live)),
-            steps_live=int(live.max()), **diff_fields,
+            steps_live=int(live.max()), **diff_fields, **wfields,
         )
         # every believed-active slot advances K: a row that outlives the
         # chunk forces all K steps, one that dies mid-chunk is frozen
@@ -4410,7 +4445,7 @@ class ContinuousEngine:
             (b, start, n) for i, (b, start, n, _) in enumerate(entries)
             if i >= len(active) or self._host_pos[b] < self._host_end[b]
         ]
-        self._window_ensure(wrows)
+        wfields = self._window_ensure(wrows)
         dev_dev = None
         toks = np.zeros((W,), np.int32)
         dec_flag = np.zeros((W,), bool)
@@ -4614,7 +4649,7 @@ class ContinuousEngine:
             spec_drafted=sum(nd for nd, _, _ in spec_rows.values()),
             tiles=stats["tiles"], tiles_live=live_tiles,
             tokens_live=tokens_live, tokens_computed=self._live_width,
-            **diff_fields,
+            **diff_fields, **wfields,
         )
         self._clock.mark("dispatch", "launch.mixed", **rec)
         out = self._step_program(

@@ -186,7 +186,7 @@ class SingleDeviceBackend:
     @property
     def supports_paged(self):
         return self.cfg.arch in ("llama", "gpt2", "mla_moe", "lfm2", "afmoe",
-                                 "minicpm_sala", "granite_hybrid")
+                                 "minicpm_sala", "granite_hybrid", "mimo_v2")
 
     def init_paged_pool(self, n_blocks, block_size, n_slots=None,
                         **snapshots):

@@ -182,18 +182,21 @@ def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
         pool["lin"] = a_layer(cfg.linear_layers, n_slots, state, jnp.float32)
         pool["snap"] = a_layer(cfg.linear_layers, snaps, state, jnp.float32)
         return pool
-    if cfg.arch == "afmoe":
+    if cfg.kinds_of_attention:
         # K/V in groups by layer kind, each with its own blocks and block
         # table (the module docstring's "Groups"); n_blocks: one count a
-        # group (`group_blocks`). The routed counts are of the experts held
-        # here, with one more column under a share: pairs routed elsewhere.
+        # group (`group_blocks`). A group's rows are its own kind's: its
+        # K/V heads, keys cfg.key_row wide and values cfg.value_dim, so a
+        # block of either group holds the same positions and not the same
+        # bytes. The routed counts are of the experts held here, with one
+        # more column under a share: pairs routed elsewhere.
         sizes = (n_blocks,) if isinstance(n_blocks, int) else tuple(n_blocks)
-        row = (cfg.n_kv_heads, block_size, cfg.head_dim)
         pool = {}
         for group, (kn, vn), n in zip(cfg.kv_groups, GROUP_LEAVES, sizes):
-            shape = (len(cfg.group_layers(group)), n) + row
-            pool[kn] = jnp.zeros(shape, cfg.jnp_dtype)
-            pool[vn] = jnp.zeros(shape, cfg.jnp_dtype)
+            rows = (len(cfg.group_layers(group)), n,
+                    cfg.group_kv_heads(group), block_size)
+            pool[kn] = jnp.zeros(rows + (cfg.key_row,), cfg.jnp_dtype)
+            pool[vn] = jnp.zeros(rows + (cfg.value_dim,), cfg.jnp_dtype)
         share = cfg.experts_held < cfg.n_experts
         pool["routed"] = jnp.zeros(
             (2, cfg.n_layers - cfg.first_k_dense, cfg.experts_held + share),
@@ -404,17 +407,33 @@ def window_row_budget(window: int, launch_tokens: int, block_size: int) -> int:
     return -(-(window + launch_tokens) // block_size) + 1
 
 
+# the context a window group is sized for (`group_blocks`). The number only
+# re-encodes "a quarter of the global group's blocks at a window of 4,096"
+# (4,096 / 16,384), the one sizing a cell has run; no traffic backs it
+WINDOW_GROUP_CONTEXT = 16384
+
+
 def group_blocks(cfg: ModelConfig, n_blocks: int, row_budget: int,
-                 n_slots: int) -> tuple:
+                 n_slots: int, block_size: int) -> tuple:
     """The one rule that sizes a pool's groups from `kv_pool_blocks`: the
     global group gets n_blocks (the number's meaning for every model: the
-    context tokens the pool holds, over the block size); a window group
-    gets a quarter as many, so that every cached context of four windows
-    or more keeps its last window, and never fewer than the slots' budgets
-    (+ its own null block)."""
+    context tokens the pool holds, over the block size). The window group
+    follows the window: of every WINDOW_GROUP_CONTEXT tokens the global
+    group holds it holds one window (in whole blocks), so that every
+    cached context that long or longer keeps its last window; never fewer
+    than the slots' budgets (+ its own null block). At a window of 4,096
+    that is a quarter of n_blocks (four windows to such a context); at a
+    window of one block of 128 a 128th, where the slots' budgets decide.
+    WINDOW_GROUP_CONTEXT is that quarter written as a context and nothing
+    more: it was chosen so that the one accepted grouped configuration's
+    counts stay what they were, and at a small window the rule's own term
+    never decides (PERF.md section 7, question 27a)."""
     if len(cfg.kv_groups) == 1:
         return (n_blocks,)
-    return (n_blocks, max(-(-n_blocks // 4), n_slots * row_budget + 1))
+    window = -(-cfg.attn_window // block_size)
+    context = max(-(-WINDOW_GROUP_CONTEXT // block_size), window)
+    return (n_blocks, max(-(-n_blocks * window // context),
+                          n_slots * row_budget + 1))
 
 
 class WindowBlocks:
@@ -463,7 +482,7 @@ class WindowBlocks:
         self._budget = np.zeros((n_slots,), np.int64)
         self._held = np.zeros((n_slots,), np.int64)
         self.index = None  # the BlockPrefixIndex that caches this group too
-        self.released = 0
+        self.given = self.released = 0  # blocks, while rows went on
 
     def _evictable(self) -> int:
         return 0 if self.index is None else self.index.side_evictable()
@@ -511,15 +530,26 @@ class WindowBlocks:
                     "window group exhausted under its own reservations")
             row[b] = got[0]
             self._held[slot] += 1
+            self.given += 1
             changed = True
         return changed
+
+    def _below(self, slot: int, last: int) -> tuple:
+        # (lo, new_lo): the row's logical blocks [lo, new_lo) lie wholly
+        # below the window of a query at `last`
+        lo = int(self._lo[slot])
+        return lo, max(lo, min((last - self.window + 1) // self.block_size,
+                               int(self._end[slot])))
+
+    def releasable(self, slot: int, last: int) -> int:
+        """Blocks `release_below(slot, last)` would give back."""
+        lo, new_lo = self._below(slot, last)
+        return int((self.table[slot, lo:new_lo] != 0).sum())
 
     def release_below(self, slot: int, last: int) -> bool:
         """After a launch whose last query of the row stands at `last`:
         give back every block wholly below last - (window - 1)."""
-        lo = int(self._lo[slot])
-        new_lo = min((last - self.window + 1) // self.block_size,
-                     int(self._end[slot]))
+        lo, new_lo = self._below(slot, last)
         if new_lo <= lo:
             return False
         row = self.table[slot]
@@ -843,7 +873,8 @@ def make_paged_hook(table: jnp.ndarray, active=None):
     """
 
     def hook(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
-             valid_start, window_flag=None, layer=None, pages=None):
+             valid_start, window_flag=None, layer=None, pages=None,
+             sink=None):
         del valid_start  # slots never left-pad
         # window_flag (mixed per-layer patterns): the XLA gather path
         # ignores it — decoder_layer resolved `mask` per layer already —
@@ -889,7 +920,7 @@ def make_paged_hook(table: jnp.ndarray, active=None):
             return _kernel_step(
                 lambda pool_k, pool_v, write: paged_flash_attend(
                     q, pool_k, pool_v, table, pos, wd, active, write,
-                    None if pages is None else pages[:2],
+                    None if pages is None else pages[:2], sink,
                     window=w, scale=cfg.query_scale,
                     softcap=None if v is None else cfg.attn_softcap,
                     value_dim=cfg.kv_lora_rank if v is None else None,
@@ -915,7 +946,7 @@ def make_paged_hook(table: jnp.ndarray, active=None):
 
         def gathered(leaf):
             g = _gather_blocks_of(leaf, layer, table)  # [B, MB, KV, bs, Dh]
-            return g.transpose(0, 2, 1, 3, 4).reshape(B, KV_, MB * bs, Dh)
+            return g.transpose(0, 2, 1, 3, 4).reshape(B, KV_, MB * bs, -1)
 
         if pages is not None:  # a selected read: the mask is the list's
             kv_pos = jnp.arange(MB * bs, dtype=jnp.int32)
@@ -926,7 +957,7 @@ def make_paged_hook(table: jnp.ndarray, active=None):
             return attn, new_k, new_v
         attn = attend(
             q, gathered(new_k), gathered(new_v), mask,
-            scale=cfg.query_scale, softcap=cfg.attn_softcap,
+            scale=cfg.query_scale, softcap=cfg.attn_softcap, sink=sink,
         )
         return attn, new_k, new_v
 
@@ -1491,7 +1522,7 @@ def build_ragged_meta(entries, *, width: int, tile: int):
 
 
 def _ragged_attend_xla(cfg, q, cache_k, cache_v, layer, table, tok_row,
-                       tok_pos, window_flag):
+                       tok_pos, window_flag, sink=None):
     """XLA twin of the ragged kernel: per-token gather of the owning
     row's blocks of `layer` out of the stacked pool into a contiguous
     logical view, then the stock masked attention. This is the CPU /
@@ -1503,7 +1534,6 @@ def _ragged_attend_xla(cfg, q, cache_k, cache_v, layer, table, tok_row,
     W = q.shape[0]
     KV, bs = cache_k.shape[2], cache_k.shape[3]
     MB = table.shape[1]
-    Dh = cache_k.shape[-1]
     S = MB * bs
     w, wd = kernel_window(cfg, window_flag)
 
@@ -1521,7 +1551,7 @@ def _ragged_attend_xla(cfg, q, cache_k, cache_v, layer, table, tok_row,
         # prefill runs, with none of its gather/scatter bookends.
         def gathered1(leaf):
             g = _gather_blocks_of(leaf, layer, table[0])  # [MB, KV, bs, Dh]
-            return g.transpose(1, 0, 2, 3).reshape(1, KV, S, Dh)
+            return g.transpose(1, 0, 2, 3).reshape(1, KV, S, -1)
 
         kv_pos = jnp.arange(S, dtype=jnp.int32)[None, :]
         q_pos = tok_pos[:, None]
@@ -1531,6 +1561,7 @@ def _ragged_attend_xla(cfg, q, cache_k, cache_v, layer, table, tok_row,
         out = attend(
             q[:, 0][None], gathered1(cache_k), gathered1(cache_v),
             mask[None], scale=cfg.query_scale, softcap=cfg.attn_softcap,
+            sink=sink,
         )  # [1, W, H, Dh]
         return out[0][:, None]
 
@@ -1539,7 +1570,7 @@ def _ragged_attend_xla(cfg, q, cache_k, cache_v, layer, table, tok_row,
 
     def gathered(leaf):
         g = _gather_blocks_of(leaf, layer, row_table)  # [W, MB, KV, bs, Dh]
-        return g.transpose(0, 2, 1, 3, 4).reshape(W, KV, S, Dh)
+        return g.transpose(0, 2, 1, 3, 4).reshape(W, KV, S, -1)
 
     kv_pos = jnp.arange(S, dtype=jnp.int32)[None, None, :]
     q_pos = tok_pos[:, None, None]
@@ -1548,7 +1579,7 @@ def _ragged_attend_xla(cfg, q, cache_k, cache_v, layer, table, tok_row,
     mask = win_mask(mask, kv_pos, q_pos)
     return attend(
         q, gathered(cache_k), gathered(cache_v), mask,
-        scale=cfg.query_scale, softcap=cfg.attn_softcap,
+        scale=cfg.query_scale, softcap=cfg.attn_softcap, sink=sink,
     )
 
 
@@ -1609,7 +1640,8 @@ def make_ragged_fill_hook(table, meta, tok_row, snaps=None, compact=None):
     """
 
     def tiles(cfg, q, k, v, cache_k, cache_v, pos, mask, update_gate,
-              valid_start, window_flag=None, layer=None, pages=None):
+              valid_start, window_flag=None, layer=None, pages=None,
+              sink=None):
         del mask, valid_start  # mask derived from pos/tok_row in-kernel
         W, T = q.shape[0], q.shape[1]
         assert T == 1, "ragged fill runs the flat token layout (T=1 rows)"
@@ -1638,7 +1670,7 @@ def make_ragged_fill_hook(table, meta, tok_row, snaps=None, compact=None):
             def kernel(pool_k, pool_v, write):
                 out = ragged_paged_attend(
                     q[:, 0], pool_k, pool_v, table, meta, wd, write, pages,
-                    window=w, scale=cfg.query_scale,
+                    sink, window=w, scale=cfg.query_scale,
                     softcap=None if v is None else cfg.attn_softcap,
                     value_dim=cfg.kv_lora_rank if v is None else None,
                     block=cfg.diffusion_block,
@@ -1672,7 +1704,8 @@ def make_ragged_fill_hook(table, meta, tok_row, snaps=None, compact=None):
                      None, pages[2]), MB, bs)[:, :, None])
         else:
             attn = _ragged_attend_xla(
-                cfg, q, new_k, new_v, layer, table, tok_row, pos, window_flag
+                cfg, q, new_k, new_v, layer, table, tok_row, pos,
+                window_flag, sink,
             )
         return attn, new_k, new_v
 

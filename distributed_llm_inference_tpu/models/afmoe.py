@@ -86,7 +86,6 @@ LEAF_KEYS = {
     "moe.w_gate": 12, "moe.w_up": 13, "moe.w_down": 14,
     "moe.ws_gate": 15, "moe.ws_up": 16, "moe.ws_down": 17,
 }
-KEYED = ("embed", "head", "moe.w_gate", "moe.w_up", "moe.w_down")
 
 
 def stack_depths(cfg: ModelConfig) -> dict:
@@ -136,48 +135,60 @@ def _normal_keyed(key, ids, *, shape, scale, dtype):
                        if len(shape) == 1 else None)
 
 
-def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
-    """Seeded random parameters (tests and benchmarks): scaled normals, norm
-    weights 1, the selection bias normal * ROUTER_BIAS_SCALE in float32.
-    The expert banks and the two vocabulary tables are drawn by published
-    index (`_normal_keyed`, the module docstring), the held ones alone; the
-    other leaves slice by slice (models/experts._normal_slices)."""
-    if cfg.tie_embeddings:
-        raise ValueError(f"{cfg.name}: the afmoe family's head is untied")
+def draw_params(cfg: ModelConfig, key: jax.Array, shapes: dict,
+                leaf_keys: dict, float32: tuple = ()) -> Params:
+    """The seeded tree of `shapes` ({leaf path: (shape, scale or None for
+    ones)}; "kind.name" a leaf of layers[kind], a bare name the tree's own
+    where it is embed / head / final_norm, else layers'): the expert banks
+    ("moe." + BANKS) and the two vocabulary tables by published index
+    (`_normal_keyed`, the module docstring), the held ones alone; the other
+    leaves slice by slice (models/experts._normal_slices), key
+    split(key, 24)[leaf_keys[path]] each, the `float32` paths in float32.
+    One draw for the families that hold a share (config.HOLDS_EXPERT_SHARE)."""
     dt = cfg.jnp_dtype
     ks = jax.random.split(key, 24)
-    layers: Params = {"attn": {}, "dense": {}, "moe": {}}
+    layers: Params = {}
     params: Params = {"layers": layers}
     E, Eh = cfg.n_experts, cfg.experts_held
-    Lm = stack_depths(cfg)["moe"]
+    Lm = cfg.n_layers - cfg.first_k_dense
     held = (jnp.arange(Lm, dtype=jnp.int32)[:, None] * E + cfg.expert_lo
             + jnp.arange(Eh, dtype=jnp.int32)[None, :]).reshape(-1)
-    for path, (shape, scale) in leaf_shapes(cfg).items():
+    for path, (shape, scale) in shapes.items():
+        kind, _, name = path.rpartition(".")
+        bank = kind == "moe" and name in BANKS
         if scale is None:
             leaf = jnp.ones(shape, dt)
         elif 0 in shape:
             leaf = jnp.zeros(shape, dt)
-        elif path in KEYED:
-            bank = path.startswith("moe.")
+        elif bank or path in ("embed", "head"):
             leaf = _normal_keyed(
-                ks[LEAF_KEYS[path]],
+                ks[leaf_keys[path]],
                 held if bank else jnp.arange(shape[0], dtype=jnp.int32),
                 shape=shape[2:] if bank else shape[1:], scale=float(scale),
                 dtype=dt,
             ).reshape(shape)
         else:
             leaf = _normal_slices(
-                ks[LEAF_KEYS[path]], scale=float(scale), shape=shape,
-                dtype=F32 if path == "moe.router_bias" else dt,
+                ks[leaf_keys[path]], scale=float(scale), shape=shape,
+                dtype=F32 if path in float32 else dt,
             )
-        kind, _, name = path.rpartition(".")
         if kind:
-            layers[kind][name] = leaf
+            layers.setdefault(kind, {})[name] = leaf
         elif name in ("embed", "head", "final_norm"):
             params[name] = leaf
         else:
             layers[name] = leaf
     return params
+
+
+def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
+    """Seeded random parameters (tests and benchmarks): scaled normals, norm
+    weights 1, the selection bias normal * ROUTER_BIAS_SCALE in float32
+    (`draw_params`)."""
+    if cfg.tie_embeddings:
+        raise ValueError(f"{cfg.name}: the afmoe family's head is untied")
+    return draw_params(cfg, key, leaf_shapes(cfg), LEAF_KEYS,
+                       float32=("moe.router_bias",))
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_seq: Optional[int] = None,
@@ -242,9 +253,10 @@ def attention(cfg: ModelConfig, lp: Params, h, cache_k, cache_v, pos, rope,
 
 def moe_ffn(cfg: ModelConfig, lp: Params, banks: Params, layer: int, h,
             live=None):
-    """The held experts' part and the shared expert on normed h [B, T, D]:
-    (float32 [B, T, D], tokens each held expert got [Eh], the live pairs
-    that went to experts held elsewhere)."""
+    """The held experts' part and the shared expert (where the layer has
+    one: its leaves ws_*) on normed h [B, T, D]: (float32 [B, T, D], tokens
+    each held expert got [Eh], the live pairs that went to experts held
+    elsewhere)."""
     B, T, D = h.shape
     flat = h.reshape(B * T, D)
     with jax.named_scope("moe_route"):
@@ -255,9 +267,37 @@ def moe_ffn(cfg: ModelConfig, lp: Params, banks: Params, layer: int, h,
         pairs = chosen.shape[0] * chosen.shape[1] if live is None else \
             jnp.sum(live.astype(jnp.int32)) * chosen.shape[1]
         elsewhere = pairs - jnp.sum(sizes)
-    with jax.named_scope("moe_shared"):
-        out = out + swiglu(flat, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+    if "ws_gate" in lp:
+        with jax.named_scope("moe_shared"):
+            out = out + swiglu(flat, lp["ws_gate"], lp["ws_up"],
+                               lp["ws_down"])
     return out.reshape(B, T, D), sizes, elsewhere
+
+
+def group_hooks(hook, two: bool) -> dict:
+    """{group: its attention hook}: under a pool of two groups each group's
+    half of the launch's block table (`hook.group`); else the one hook for
+    both kinds."""
+    if two:
+        return {"global": hook.group(0, 2), "window": hook.group(1, 2)}
+    return {"global": hook, "window": hook}
+
+
+def add_routed(cache: dict, new: dict, sizes: list, away: list) -> dict:
+    """`new` with the launch's routed counts added to the pool's "routed"
+    leaf, where `cache` has one ([2, Lm, Eh (+ 1 under a share)]: pairs each
+    held expert got and whether it got any, a layer; a share's last column
+    the pairs that went elsewhere)."""
+    if "routed" not in cache:
+        return new
+    sizes = jnp.stack(sizes)
+    counts = jnp.stack([sizes, (sizes > 0).astype(jnp.int32)])
+    if cache["routed"].shape[2] > sizes.shape[1]:  # a share
+        away = jnp.stack(away).astype(jnp.int32)[:, None]
+        counts = jnp.concatenate(
+            [counts, jnp.stack([away, jnp.zeros_like(away)])], axis=2)
+    new["routed"] = cache["routed"] + counts
+    return new
 
 
 def forward_layers(cfg: ModelConfig, layers: Params, x, cache, pos,
@@ -293,10 +333,8 @@ def forward_layers(cfg: ModelConfig, layers: Params, x, cache, pos,
                  W: causal_mask(pos, T, S, window=W)}
     with jax.named_scope("attn"):  # the rotary tables, once a forward
         rope = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
-    hook = attn_hook or default_attn_hook
     # each group's half of the launch's block table, and its pool leaves
-    hooks = {"global": hook.group(0, 2), "window": hook.group(1, 2)} if two \
-        else {"global": hook, "window": hook}
+    hooks = group_hooks(attn_hook or default_attn_hook, two)
     leaves = {"global": ("k", "v"), "window": ("kw", "vw") if two else ("k", "v")}
     live = getattr(attn_hook, "live", None)
     if live is not None and T > 1:
@@ -346,15 +384,7 @@ def forward_layers(cfg: ModelConfig, layers: Params, x, cache, pos,
             out = rms_norm(out, layers["norm4"][li], cfg.norm_eps)
         with jax.named_scope("attn" if li + 1 < cfg.n_layers else "head"):
             x = x + out
-    if "routed" in cache:
-        sizes = jnp.stack(sizes)
-        counts = jnp.stack([sizes, (sizes > 0).astype(jnp.int32)])
-        if cache["routed"].shape[2] > sizes.shape[1]:  # a share
-            away = jnp.stack(away).astype(jnp.int32)[:, None]
-            counts = jnp.concatenate(
-                [counts, jnp.stack([away, jnp.zeros_like(away)])], axis=2)
-        new["routed"] = cache["routed"] + counts
-    return x, new
+    return x, add_routed(cache, new, sizes, away)
 
 
 def forward(cfg: ModelConfig, params: Params, tokens, cache, pos):

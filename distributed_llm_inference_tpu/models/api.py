@@ -22,7 +22,12 @@ state a row for the second, and serves one device from the paged pool only;
 granite_hybrid (models/granite_hybrid.py) alternates Mamba-2 state-space
 layers, which keep a convolution state AND a float32 matrix state a row,
 with attention layers that take no position encoding, and serves one device
-from the paged pool only.
+from the paged pool only; mimo_v2 (models/mimo_v2.py) mixes sliding-window
+layers that have a learned sink in their softmax with global layers, the two
+kinds with their own K/V head counts and rotation bases and keys wider than
+values, over routed experts with no shared one, holds one chip's share of
+them where the configuration says so, and serves one device from a pool
+grouped by layer kind whose groups' rows are their own kinds'.
 Routed experts
 are one module for the families that have them (models/experts.py: `route`, `routed_ffn`, the
 grouped product): a configuration that routes serves one device, from the
@@ -32,11 +37,12 @@ paged pool (engine/paged.refuse_unsupported_latent).
 from __future__ import annotations
 
 from ..config import ModelConfig
-from . import afmoe, gpt2, granite_hybrid, lfm2, llama, minicpm_sala, mla_moe
+from . import (afmoe, gpt2, granite_hybrid, lfm2, llama, mimo_v2,
+               minicpm_sala, mla_moe)
 
 _FAMILIES = {"llama": llama, "gpt2": gpt2, "mla_moe": mla_moe, "lfm2": lfm2,
              "afmoe": afmoe, "minicpm_sala": minicpm_sala,
-             "granite_hybrid": granite_hybrid}
+             "granite_hybrid": granite_hybrid, "mimo_v2": mimo_v2}
 
 
 def family(cfg: ModelConfig):

@@ -216,6 +216,32 @@ register(ModelConfig(
     first_k_dense=6, moe_renormalize=True, routed_scaling=2.448,
     router_norm_eps=1e-20, eos_token_id=2, bos_token_id=1, pad_token_id=0,
 ))
+# --- MiMo-V2.5 (five 128-token sliding-window layers with a learned sink to
+# each global layer, 256 sigmoid-routed experts and no shared one;
+# XiaomiMiMo/MiMo-V2.5 config.json, model_type mimo_v2: models/mimo_v2.py).
+# hybrid_layer_pattern: layer 0 global, 1-4 window, 5 global, then (window
+# x 5, global) seven times; layer 0 dense, the others routed. Global layers:
+# 4 K/V heads, rope_theta 1e7; window layers: 8 K/V heads, swa_rope_theta
+# 1e4, a sink logit a query head; both: keys 192 wide and values 128, the
+# rotation on 0.334 x 192 -> 64 lanes, values x 0.707. Not in config.json and
+# so assumed: the value scale's place (on v, before the sum), the window's
+# convention (i - j < 128), which 64 lanes rotate and in which form (the
+# first, half-rotation), no per-head q/k norm, the 1e-20 in the router's
+# normalisation; the vision and audio towers and the MTP layers are not here.
+MIMO_V25_LAYER_TYPES = tuple(
+    "full_attention" if i == 0 or i % 6 == 5 else "sliding_attention"
+    for i in range(48))
+register(ModelConfig(
+    name="mimo-v2.5", arch="mimo_v2", vocab_size=152576, dim=4096,
+    n_layers=48, n_heads=64, n_kv_heads=4, window_kv_heads=8, ffn_dim=16384,
+    max_seq_len=1048576, norm_eps=1e-5, rope_theta=1e7,
+    rope_local_theta=1e4, head_dim_override=192, v_head_dim=128,
+    rotary_dim=64, attn_value_scale=0.707, attn_window=128, window_sink=True,
+    layer_types=MIMO_V25_LAYER_TYPES,
+    n_experts=256, n_experts_per_tok=8, moe_ffn_dim=2048, first_k_dense=1,
+    moe_renormalize=True, routed_scaling=1.0, router_norm_eps=1e-20,
+    eos_token_id=2, bos_token_id=1, pad_token_id=0,
+))
 # --- MiniCPM-SALA (sparse attention beside decayed linear attention;
 # openbmb/MiniCPM-SALA config.json, model_type minicpm_sala:
 # models/minicpm_sala.py). mixer_types as published: 8 "minicpm4" layers
@@ -463,6 +489,22 @@ register(ModelConfig(
     n_experts=8, n_experts_per_tok=2, moe_ffn_dim=32, n_shared_experts=1,
     first_k_dense=1, moe_renormalize=True, routed_scaling=2.448,
     router_norm_eps=1e-20, eos_token_id=2, bos_token_id=1,
+))
+# (the published head widths, keys 192 on a 256-lane pool row and values
+# 128, so that the paged kernels' in-place write runs at float32 blocks of 8;
+# two window layers to a global one, K/V heads 2 and 1, a window of two
+# blocks of 8)
+register(ModelConfig(
+    name="test-mimo-tiny", arch="mimo_v2", vocab_size=256, dim=64,
+    n_layers=4, n_heads=4, n_kv_heads=1, window_kv_heads=2, ffn_dim=96,
+    max_seq_len=256, norm_eps=1e-5, rope_theta=1e7, rope_local_theta=1e4,
+    head_dim_override=192, v_head_dim=128, rotary_dim=64,
+    attn_value_scale=0.707, attn_window=16, window_sink=True,
+    layer_types=("full_attention", "sliding_attention", "sliding_attention",
+                 "full_attention"),
+    n_experts=8, n_experts_per_tok=2, moe_ffn_dim=32, first_k_dense=1,
+    moe_renormalize=True, routed_scaling=1.0, router_norm_eps=1e-20,
+    eos_token_id=2, bos_token_id=1,
 ))
 register(ModelConfig(
     name="test-gemma2-tiny", arch="llama", vocab_size=256, dim=64,

@@ -165,11 +165,16 @@ def attend(
     mask: jnp.ndarray,
     scale=None,
     softcap=None,
+    sink=None,
 ) -> jnp.ndarray:
     """Grouped-query attention over the (already updated) cache.
 
     mask: [T, S] (shared) or [B, T, S] (per-row, ragged left-padded batch).
-    Softmax in fp32; output cast back to q.dtype. Returns [B, T, H, Dh].
+    Softmax in fp32; output cast back to q.dtype. Returns [B, T, H, Dv]:
+    the values may be narrower than the keys (cache_v [B, KV, S, Dv]).
+    sink: [H] float32, a learned logit a query head that joins the
+    softmax's denominator and brings no value: p_ij = exp(s_ij) / (sum_j'
+    exp(s_ij') + exp(sink_h)) (None: the plain softmax).
     scale: score scale (None = head_dim**-0.5; Gemma-2 overrides).
     softcap: Gemma-2 attention logit softcapping, cap*tanh(scores/cap),
     applied BEFORE masking (HF Gemma2Attention order).
@@ -190,6 +195,13 @@ def attend(
     neg = jnp.finfo(jnp.float32).min
     bmask = mask[:, None, None, :, :] if mask.ndim == 3 else mask[None, None, None, :, :]
     scores = jnp.where(bmask, scores, neg)
-    probs = jax.nn.softmax(scores, axis=-1)
+    if sink is not None:  # one more column, dropped after the softmax
+        col = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, KV, group, 1, 1),
+            scores.shape[:-1] + (1,))
+        probs = jax.nn.softmax(
+            jnp.concatenate([scores, col], axis=-1), axis=-1)[..., :-1]
+    else:
+        probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgts,bksd->btkgd", probs, cache_v.astype(jnp.float32))
-    return out.reshape(B, T, H, Dh).astype(q.dtype)
+    return out.reshape(B, T, H, -1).astype(q.dtype)

@@ -66,6 +66,16 @@ scale and Gemma-2 softcapping are static kernel parameters. GQA is folded
 into the query-row dimension exactly like ops/flash_attention.py: the
 score matmul of one KV head is [queries x group, Dh] x [Dh, bs].
 
+The values may be narrower than the keys (pool_v [.., Dv], pool_k
+[.., Dk]: models/mimo_v2.py keeps keys of 192 numbers on 256 lanes and
+values of 128): each leaf's slabs, new rows and buffers take the leaf's own
+width, and the output is Dv wide. `sink` [H] float32 is a learned logit a
+query head that joins the softmax's denominator and brings no value: the
+online softmax's running maximum starts at the head's sink logit, its
+denominator at 1 and its accumulator at 0, which IS the softmax over the
+scores and the sink with the sink's value left out; it costs no column.
+Absent, the call is what it was before the sink existed.
+
 On non-TPU backends the kernels run in interpret mode (the CPU test
 suite); numerics match the gather path to fp32 tolerance.
 """
@@ -248,6 +258,7 @@ def _walk_kernel(
     write: bool = False,
     block: int = 0,
     pages: int = 0,
+    sink: bool = False,
 ):
     """One program: query tile g (tq queries of one row; a decode slot is
     a tile of one) against head group hg's KVg KV heads. The walk over
@@ -329,6 +340,9 @@ def _walk_kernel(
     if pages:
         plist_ref, count_ref, *rest = rest
     q_ref, *rest = rest
+    sink_ref = None
+    if sink:  # [KVg, rows, 1] float32: a score row's sink logit
+        sink_ref, *rest = rest
     if pages and tq > 1:
         sel_ref, *rest = rest
     new_refs, rest = (rest[:n], rest[n:]) if write else ((), rest)
@@ -365,8 +379,12 @@ def _walk_kernel(
             return plist_ref[g, hg, jnp.minimum(j, pages - 1)]
         return j
 
-    m_ref[:] = jnp.full(m_ref.shape, _NEG, jnp.float32)
-    l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+    if sink:  # the softmax already holds one term, exp(sink - sink) == 1
+        m_ref[:] = sink_ref[:]
+        l_ref[:] = jnp.ones(l_ref.shape, jnp.float32)
+    else:
+        m_ref[:] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
     acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     def page(buf, slot, p):
@@ -441,9 +459,9 @@ def _walk_kernel(
             start = pl.multiple_of(
                 jnp.clip(r0, 0, bs - span) // sub * sub, sub
             )
-        ix = jax.lax.broadcasted_iota(
-            jnp.int32, (span, kbuf.shape[3]), 0) + (start - r0)
         for new_ref, buf in zip(new_refs, bufs):
+            ix = jax.lax.broadcasted_iota(
+                jnp.int32, (span, buf.shape[3]), 0) + (start - r0)
             rows_ = (slot, slice(None), rows_of(j, j0, start))
             cur = buf[rows_].astype(jnp.float32)  # [KVg, span, Dh]
             for t in range(tq):
@@ -700,7 +718,7 @@ def _walk_kernel(
 
     l = l_ref[:]
     l = jnp.where(l == 0.0, 1.0, l)  # padding queries, rows not walked
-    Dv = latent or Dh
+    Dv = acc_ref.shape[-1]
     o = (acc_ref[:] / l).astype(o_ref.dtype).reshape(KVg, tq, group, Dv)
     o_ref[0] = o.reshape(1, KVg, group, Dv) if tq == 1 else o.swapaxes(0, 1)
 
@@ -735,7 +753,7 @@ def writes_in_place(leaf) -> bool:
 
 def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
                 scale, softcap, interpret, value_dim=None, write=None,
-                block=0, pages=None):
+                block=0, pages=None, sink=None):
     """The pallas_call both wrappers share. q [G, tq, H, Dh]: G query
     tiles of tq queries; meta [G, 4]. pool_v None is the latent form.
     write None: the pool leaves are one layer's slices and hold the
@@ -745,11 +763,16 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
     `_walk_kernel`); returns (output, pool_k, pool_v), the pool updated in
     place (donate it). pages (plist [G, KV, L], count [G, KV], chosen
     [G, tq, KV, L] bool or None): the selected read; L a multiple of 128.
-    Absent, the call is what it was before the list existed."""
+    Absent, the call is what it was before the list existed. sink [H]: the
+    module docstring's; the K leaf may be wider than the V leaf, and q is
+    as wide as the K leaf."""
     from .kv_quant import KVQuant
 
     latent = pool_v is None
     quant = isinstance(pool_k, KVQuant)
+    # the values' width before any pad to whole lane tiles
+    pool_v_width = None if latent else (
+        pool_v.q if quant else pool_v).shape[-1]
     leaves = [pool_k] if latent else [pool_k, pool_v]
     if quant:
         leaves = [pool_k.q, pool_v.q, _lanes(pool_k.s), _lanes(pool_v.s)]
@@ -769,20 +792,20 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
         scalars = jnp.concatenate(
             [scalars, jnp.reshape(layer, (1,)).astype(jnp.int32)]
         )
-        news = [a.reshape(G, tq, KV, 1, Dh) for a in news[:n]]
+        news = [a.reshape(G, tq, KV, 1, a.shape[-1]) for a in news[:n]]
     else:
         leaves[:2] = [_lanes(a) for a in leaves[:2]]
     q5 = _lanes(q.reshape(G, tq, KV, group, Dh))
     Dp = q5.shape[-1]
-    Dv = value_dim if latent else Dp
+    Dv = value_dim if latent else leaves[1].shape[-1]
     rows = tq * group
     KVg, P = _walk_shape(KV, bs, Dp, leaves[0].dtype.itemsize, quant, rows,
                          MB, latent, listed=pages is not None)
     lists, sel, L = [], [], 0
     if pages is not None:
         assert not (latent or quant), "a selected read is of raw K/V pages"
-        assert window is None and window_dyn is None, (
-            "a selected read's list is its window")
+        assert window is None and window_dyn is None and sink is None, (
+            "a selected read's list is its window, and has no sink")
         plist, count, chosen = pages
         L = plist.shape[-1]
         assert L % 128 == 0, L
@@ -798,7 +821,15 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
         scale=scale if scale is not None else Dh**-0.5, softcap=softcap,
         quant=quant, latent=value_dim if latent else 0,
         write=write is not None, block=block, pages=L,
+        sink=sink is not None,
     )
+    sinks, sink_spec = [], []
+    if sink is not None:
+        # score row r of a KV head is (query r // group, head r % group)
+        sinks = [jnp.tile(sink.astype(jnp.float32).reshape(KV, 1, group),
+                          (1, tq, 1)).reshape(KV, rows, 1)]
+        sink_spec = [pl.BlockSpec((KVg, rows, 1),
+                                  lambda g, hg, *refs: (hg, 0, 0))]
 
     def tile(per_head, width):
         return pl.BlockSpec(
@@ -813,7 +844,8 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
         pltpu.SemaphoreType.DMA((n, 2)),
     ]
     scratch += [
-        pltpu.VMEM((2, KVg, P * bs, Dp), a.dtype) for a in leaves[:2]
+        pltpu.VMEM((2, KVg, P * bs, a.shape[-1]), a.dtype)
+        for a in leaves[:2]
     ]
     scratch += [
         pltpu.VMEM((2, KVg) + a.shape[2:], jnp.float32) for a in leaves[2:]
@@ -830,7 +862,7 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
         ]
         # operands: the prefetched scalars (3, and a page list's 2), q,
         # the list's choices, the new rows, the pool leaves
-        at = 3 + len(lists) + 1 + len(sel) + n
+        at = 3 + len(lists) + 1 + len(sinks) + len(sel) + n
         aliases = {at + i: 1 + i for i in range(n)}
     if pages is not None:  # the positions of a step's columns
         scratch.append(pltpu.VMEM((1, P * bs), jnp.int32))
@@ -842,8 +874,8 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3 + len(lists),
         grid=(G, KV // KVg),
-        in_specs=[tile(group, Dp)] + sel_spec + [tile(1, Dp)] * len(news)
-        + in_hbm,
+        in_specs=[tile(group, Dp)] + sink_spec + sel_spec
+        + [tile(1, a.shape[-1]) for a in news] + in_hbm,
         out_specs=out_specs,
         scratch_shapes=scratch,
     )
@@ -854,8 +886,8 @@ def _paged_walk(q, pool_k, pool_v, table, meta, window, window_dyn, *,
         input_output_aliases=aliases,
         interpret=interpret,
     )(meta.astype(jnp.int32), table.astype(jnp.int32), scalars, *lists, q5,
-      *sel, *news, *leaves)
-    width = Dv if latent else Dh
+      *sinks, *sel, *news, *leaves)
+    width = Dv if latent else pool_v_width
     if write is None:
         return out[..., :width].reshape(G, tq, H, width)
     out, *pool = out
@@ -877,6 +909,7 @@ def paged_flash_attend(
     active: jnp.ndarray | None = None,
     write: tuple | None = None,
     pages: tuple | None = None,
+    sink: jnp.ndarray | None = None,
     *,
     window: int | None = None,
     scale: float | None = None,
@@ -915,6 +948,9 @@ def paged_flash_attend(
     (`_walk_kernel`): row b's KV head h reads the first count[b, h] logical
     pages of plist[b, h] (ascending; the page of pos[b] the last of them)
     in place of the range.
+    sink [H] float32: a learned logit a query head in the softmax's
+    denominator (the module docstring); pool_v may be narrower than pool_k,
+    q is as wide as pool_k and the output as wide as pool_v.
     """
     B, T, H, Dh = q.shape
     assert T == 1, "paged kernel serves decode steps (T=1) only"
@@ -928,7 +964,7 @@ def paged_flash_attend(
         q, pool_k, pool_v, table, meta, window, window_dyn, scale=scale,
         softcap=softcap, interpret=resolve_interpret(interpret),
         value_dim=value_dim, write=write, block=block,
-        pages=None if pages is None else (*pages, None),
+        pages=None if pages is None else (*pages, None), sink=sink,
     )
 
 
@@ -946,6 +982,7 @@ def ragged_paged_attend(
     window_dyn: jnp.ndarray | None = None,
     write: tuple | None = None,
     pages: tuple | None = None,
+    sink: jnp.ndarray | None = None,
     *,
     window: int | None = None,
     scale: float | None = None,
@@ -1000,7 +1037,7 @@ def ragged_paged_attend(
         q.reshape(G, tq, H, Dh), pool_k, pool_v, table, meta, window,
         window_dyn, scale=scale, softcap=softcap,
         interpret=resolve_interpret(interpret), value_dim=value_dim,
-        write=write, block=block, pages=pages,
+        write=write, block=block, pages=pages, sink=sink,
     )
     if write is None:
         return out.reshape(W, H, -1)

@@ -90,8 +90,16 @@ def apply_rope(
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Apply rotary embedding to q [B,T,H,Dh] and k [B,T,KV,Dh].
 
-    cos/sin: [T, Dh] or [B, T, Dh]; broadcast over the head axis.
+    cos/sin: [T, R] or [B, T, R]; broadcast over the head axis. R == Dh
+    rotates the whole head; R < Dh (a partial rotary factor: tables made
+    for R) rotates lanes 0 .. R-1 of every head among themselves, in the
+    same half-rotation form, and passes lanes R .. Dh-1 through.
     """
+    R = cos.shape[-1]
+    if R < q.shape[-1]:
+        q_rot, k_rot = apply_rope(q[..., :R], k[..., :R], cos, sin)
+        return (jnp.concatenate([q_rot, q[..., R:]], axis=-1),
+                jnp.concatenate([k_rot, k[..., R:]], axis=-1))
     if cos.ndim == 2:  # [T, Dh] -> [1, T, 1, Dh]
         cos_b = cos[None, :, None, :]
         sin_b = sin[None, :, None, :]

@@ -182,6 +182,16 @@ KV_WINDOW_RELEASED_HELP = (
     "position of the block below the row's last query's window), by the "
     "host position model"
 )
+KV_WINDOW_GIVEN_HELP = (
+    "window-group blocks rows were given while they went on (a block for "
+    "every logical block a launch's queries write), by the host position "
+    "model; a row that ends gives the rest back uncounted"
+)
+KV_GROUP_BLOCK_BYTES_HELP = (
+    "bytes of one pool block of a group, all its layers' K and V rows as "
+    "the pool's leaves hold them: a block of either group holds the same "
+    "positions and not the same bytes"
+)
 MOE_PAIRS_HELP = (
     "live token-expert pairs the routers chose: held = those whose expert "
     "lives on this chip (computed here), routed = all of them; equal where "

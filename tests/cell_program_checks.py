@@ -79,6 +79,13 @@ CELLS = {
     "trinity-large-ep8-5l": dict(
         width=512, blocks=(4608, 1152), leaf=("kw", (4, 1152, 8, 128, 128)),
         scopes=DENSE_SCOPES + ROUTED_SCOPES + ("moe_shared",)),
+    # (a group's rows are its own kind's: 4 K/V heads in the global group's
+    # two layers, 8 in the window group's five, keys of 192 numbers on 256
+    # lanes and values of 128; the window is one block, so the window group
+    # is the 32 slots' budgets of 6 and its null block)
+    "mimo-v2.5-7l": dict(
+        width=512, blocks=(2304, 193), leaf=("kw", (5, 193, 8, 128, 256)),
+        scopes=DENSE_SCOPES + ROUTED_SCOPES),
 }
 
 
@@ -118,6 +125,12 @@ def test_the_programs_are_the_cells(one_chip, no_persistent_cache, config):
         assert (len(cfg.conv_layers), len(cfg.attn_layers), cfg.kv_pack) == (7, 2, 2)
     if config.startswith("trinity"):
         assert EP.window_row_budget(cfg.attn_window, built.width, 128) == 37
+    if config.startswith("mimo"):
+        assert EP.window_row_budget(cfg.attn_window, built.width, 128) == 6
+        assert built.pool["k"].shape == (2, 2304, 4, 128, 256)
+        assert built.pool["v"].shape == (2, 2304, 4, 128, 128)
+        assert built.pool["vw"].shape == (5, 193, 8, 128, 128)
+        assert built.pool["routed"].shape == (2, 6, 33)
     if config.startswith("minicpm-sala"):
         assert (len(cfg.attn_layers), len(cfg.linear_layers)) == (4, 12)
         assert built.pool["ck"][0].shape == (9216, 16, 128)

@@ -227,7 +227,7 @@ def programs(model, slots, blocks, context, block_size=128, tile=8,
     grouped = len(cfg.kv_groups) > 1
     if grouped:
         blocks = P.group_blocks(cfg, blocks, P.window_row_budget(
-            cfg.attn_window, width, block_size), slots)
+            cfg.attn_window, width, block_size), slots, block_size)
     pool = place(lambda: P.init_pool(
         cfg, blocks, block_size, n_slots=slots,
         **({"n_snapshots": snapshots} if cfg.linear_layers else {})))
@@ -295,6 +295,7 @@ CELL_FILES = {
     "test_cell_programs_granite": ("granite-4.0-h-micro",),
     "test_cell_programs_sala": ("minicpm-sala-9b-16l",),
     "test_cell_programs_sdar_trinity": ("sdar-30b-a3b-7l", "trinity-large-ep8-5l"),
+    "test_cell_programs_mimo": ("mimo-v2.5-7l",),
 }
 CELL_CONFIGS = tuple(sorted(sum(CELL_FILES.values(), ())))
 

@@ -35,8 +35,8 @@ def cell_pool(name):
     """(cfg, slots, table width in pages, the pool's shapes) of a benchmark
     configuration as its cells serve it: the registry entry with the file's
     overrides under `--attn-impl pallas`, the pool `init_pool` makes from
-    its flags (a grouped pool's window group a quarter of the global one's
-    blocks), nothing allocated."""
+    its flags (a grouped pool's groups as engine/paged.group_blocks sizes
+    them under the served launch), nothing allocated."""
     serving = cell_serving(name)
     flags = serving["flags"]
     slots, context, blocks, bs = (
@@ -46,7 +46,10 @@ def cell_pool(name):
     cfg = resolve_attn_impl(get_model_config(serving["base"]).replace(
         dtype="bfloat16", **serving["overrides"]), "pallas")
     if len(cfg.kv_groups) > 1:
-        blocks = (blocks, blocks // 4)
+        from distributed_llm_inference_tpu.engine.scheduler import step_width
+
+        blocks = EP.group_blocks(cfg, blocks, EP.window_row_budget(
+            cfg.attn_window, step_width(cfg, slots, 8), bs), slots, bs)
     pool = jax.eval_shape(lambda: EP.init_pool(cfg, blocks, bs, n_slots=slots))
     return cfg, slots, -(-context // bs), pool
 

@@ -193,11 +193,15 @@ def test_a_uniform_configuration_is_one_group():
                  "test-sdar-tiny"):
         cfg = get_model_config(name)
         assert cfg.kv_groups == ("global",)
-        assert P.group_blocks(cfg, 100, 9, 4) == (100,)
+        assert P.group_blocks(cfg, 100, 9, 4, 4) == (100,)
     one_kind = CFG.replace(name="all-sliding", layer_types=("sliding_attention",) * 5)
     assert one_kind.kv_groups == ("global",)
     assert set(P.init_pool(one_kind, 8, 4)) == {"k", "v", "routed"}
     assert set(P.init_pool(CFG, (8, 6), 4)) == {"k", "v", "kw", "vw", "routed"}
     assert P.init_pool(CFG, (8, 6), 4)["kw"].shape[:2] == (4, 6)
-    assert P.group_blocks(CFG, 100, 9, 4) == (100, 37)
-    assert P.group_blocks(CFG, 100, 7, 2) == (100, 25)
+    # the slots' budgets where the window's share of the contexts is less;
+    # a quarter at a window of 32 blocks (tests/test_mimo.py holds the
+    # served counts of both grouped configurations)
+    assert P.group_blocks(CFG, 100, 9, 4, 4) == (100, 37)
+    assert P.group_blocks(CFG, 100, 7, 2, 4) == (100, 15)
+    assert P.group_blocks(CFG.replace(attn_window=4096), 100, 3, 2, 128) == (100, 25)

@@ -49,14 +49,18 @@ def prompt_ids(n, salt=0):
 
 
 class Fleet:
+    """A served fleet of a grouped-pool family (tests/test_mimo_engine.py
+    serves its own model and reference through it)."""
+    model, block, ref = MODEL.name, BS, staticmethod(ref_logits)
+
     def __init__(self, impl="xla", budget=16, slots=2, pool=48, chunk=4, **kw):
         self.eng = create_engine(
-            MODEL.name, seed=SEED, attn_impl=impl, dtype="float32",
+            self.model, seed=SEED, attn_impl=impl, dtype="float32",
             engine_cfg=EngineConfig(prefix_cache_entries=8, step_token_budget=budget))
         self.eng.tokenizer = WordTok()
         self.ce = ContinuousEngine(
             self.eng, n_slots=slots, chunk_steps=chunk, kv_pool_blocks=pool,
-            kv_block_size=BS, kv_shadow=False, slot_max_seq=160, **kw)
+            kv_block_size=self.block, kv_shadow=False, slot_max_seq=160, **kw)
         self.cfg = self.eng.cfg
         self.records = []
         record = self.ce._launch_record
@@ -73,7 +77,7 @@ class Fleet:
         for t in ts:
             t.start()
         for t in ts:
-            t.join(300)
+            t.join(600)
         for r in out:
             assert r is not None and r.get("status") == "success", r
             r["ids"] = WordTok().encode(r["response"]) if r["response"] else []
@@ -81,7 +85,7 @@ class Fleet:
 
     def margins(self, prompt, res):
         seq = prompt + res["ids"]
-        lg = ref_logits(self.cfg, SEED, seq)[len(prompt) - 1:len(seq) - 1]
+        lg = self.ref(self.cfg, SEED, seq)[len(prompt) - 1:len(seq) - 1]
         chosen = np.asarray(res["ids"])
         return (lg.max(axis=-1) - lg[np.arange(len(chosen)), chosen]) / lg.std()
 

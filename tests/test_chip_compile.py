@@ -170,12 +170,15 @@ def test_q4_matmul_rows_compiles(one_chip, no_persistent_cache):
     assert "tpu_custom_call" in text
 
 
-# The six configurations as their cells serve them: both kernels as the
+# The configurations as their cells serve them: both kernels as the
 # step programs call them (the stacked pool, written in place), at the
 # (KV heads, pages a loop step) their shapes get (ops/paged_attention.
 # _walk_shape; tests/test_launch_record.py pins the numbers), by the step
-# program that calls them. A grouped pool (trinity) compiles its window
-# group's leaf under the window.
+# program that calls them. A grouped pool (trinity, mimo) compiles its window
+# group's leaves under the window, with a sink a query head where the window
+# layers have one, and its global group's too where that group's rows are
+# another shape (mimo: 4 K/V heads beside 8; keys 256 lanes wide and values
+# 128 in both).
 @pytest.mark.parametrize("program", ["decode_slots_paged", "mixed_step_ragged"])
 @pytest.mark.parametrize("config", CELL_CONFIGS)
 def test_paged_kernels_compile_writing_in_place_at_every_cells_shapes(
@@ -183,36 +186,62 @@ def test_paged_kernels_compile_writing_in_place_at_every_cells_shapes(
 ):
     monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
     cfg, slots, mb, pool = cell_pool(config)
-    names = EP.GROUP_LEAVES[1] if len(cfg.kv_groups) > 1 else (
-        ("k", "v") if "k" in pool else ("moe", None))
     S = _spec(one_chip)
-    pool_k, pool_v = (n and S(pool[n].shape, pool[n].dtype) for n in names)
-    kv, _, width = pool_k.shape[-3:]
-    kw = dict(window=cfg.attn_window or None, scale=cfg.query_scale,
-              value_dim=cfg.kv_lora_rank if pool_v is None else None)
-    table = S((slots, mb), jnp.int32)
-    kernel = "ragged_paged_attend"
-    if program == "decode_slots_paged" and not cfg.diffusion_block:
-        kernel = "paged_flash_attend"
-        new = S((slots, 1, kv, width), jnp.bfloat16)
-        text = _compile(
-            lambda q, pk, pv, t, pos, live, layer, k, v: paged_flash_attend(
-                q, pk, pv, t, pos, None, live,
-                (layer, k, None if pv is None else v), **kw),
-            S((slots, 1, cfg.n_heads, width), jnp.bfloat16), pool_k, pool_v,
-            table, S((slots,), jnp.int32), S((slots,), jnp.bool_),
-            S((), jnp.int32), new, new)
-    else:  # a block-diffusion row's forward is a query tile in both programs
-        flat = slots * 8 if program == "decode_slots_paged" else step_width(
-            cfg, slots, 8)
-        new = S((flat, kv, width), jnp.bfloat16)
-        text = _compile(
-            lambda q, pk, pv, t, m, layer, k, v: ragged_paged_attend(
-                q, pk, pv, t, m, None, (layer, k, None if pv is None else v),
-                block=cfg.diffusion_block, **kw),
-            S((flat, cfg.n_heads, width), jnp.bfloat16), pool_k, pool_v,
-            table, S((flat // 8, 4), jnp.int32), S((), jnp.int32), new, new)
-    assert any(kernel in c for c in _custom_call_names(text))
+    grouped = len(cfg.kv_groups) > 1
+    # (leaves, the layers' window, whether they have a sink)
+    cases = [(EP.GROUP_LEAVES[1] if grouped else
+              ("k", "v") if "k" in pool else ("moe", None),
+              cfg.attn_window or None, cfg.window_sink)]
+    if grouped and pool["k"].shape[2:] != pool["kw"].shape[2:]:
+        cases.append((EP.GROUP_LEAVES[0], None, False))
+    for names, window, has_sink in cases:
+        pool_k, pool_v = (n and S(pool[n].shape, pool[n].dtype) for n in names)
+        kv, _, width = pool_k.shape[-3:]
+        v_width = width if pool_v is None else pool_v.shape[-1]
+        kw = dict(window=window, scale=cfg.query_scale,
+                  value_dim=cfg.kv_lora_rank if pool_v is None else None)
+        sink = S((cfg.n_heads,), jnp.float32) if has_sink else None
+        table = S((slots, mb), jnp.int32)
+        kernel = "ragged_paged_attend"
+        if program == "decode_slots_paged" and not cfg.diffusion_block:
+            kernel = "paged_flash_attend"
+            text = _compile(
+                lambda q, pk, pv, t, pos, live, layer, k, v, s: paged_flash_attend(
+                    q, pk, pv, t, pos, None, live,
+                    (layer, k, None if pv is None else v), None, s, **kw),
+                S((slots, 1, cfg.n_heads, width), jnp.bfloat16), pool_k, pool_v,
+                table, S((slots,), jnp.int32), S((slots,), jnp.bool_),
+                S((), jnp.int32), S((slots, 1, kv, width), jnp.bfloat16),
+                S((slots, 1, kv, v_width), jnp.bfloat16), sink)
+        else:  # a block-diffusion row's forward is a query tile in both programs
+            flat = slots * 8 if program == "decode_slots_paged" else step_width(
+                cfg, slots, 8)
+            text = _compile(
+                lambda q, pk, pv, t, m, layer, k, v, s: ragged_paged_attend(
+                    q, pk, pv, t, m, None, (layer, k, None if pv is None else v),
+                    None, s, block=cfg.diffusion_block, **kw),
+                S((flat, cfg.n_heads, width), jnp.bfloat16), pool_k, pool_v,
+                table, S((flat // 8, 4), jnp.int32), S((), jnp.int32),
+                S((flat, kv, width), jnp.bfloat16),
+                S((flat, kv, v_width), jnp.bfloat16), sink)
+        assert any(kernel in c for c in _custom_call_names(text))
+
+
+def test_the_mimo_cells_four_kv_leaves_are_their_kinds_bytes():
+    """mimo-v2.5-7l's pool as served: keys of 192 numbers on 256 lanes and
+    values of 128, 4 K/V heads in the global group's 2 layers and 8 in the
+    window group's 5: a block of the global group is 0.79 MB (0.66 MB of it
+    useful), one of the window group 3.93 MB, 1.81 + 0.76 GB in all."""
+    cfg, slots, mb, pool = cell_pool("mimo-v2.5-7l")
+    assert (slots, mb, cfg.key_row, cfg.value_dim) == (32, 144, 256, 128)
+    shapes = {n: pool[n].shape for n in ("k", "v", "kw", "vw")}
+    assert shapes == {"k": (2, 2304, 4, 128, 256), "v": (2, 2304, 4, 128, 128),
+                      "kw": (5, 193, 8, 128, 256), "vw": (5, 193, 8, 128, 128)}
+    nbytes = {n: pool[n].size * 2 for n in shapes}
+    assert nbytes["k"] + nbytes["v"] == 2304 * 2 * 4 * 128 * 384 * 2 == 1_811_939_328
+    assert nbytes["kw"] + nbytes["vw"] == 193 * 5 * 8 * 128 * 384 * 2 == 758_906_880
+    useful = 2 * 4 * 128 * (192 + 128) * 2
+    assert (useful, (nbytes["k"] + nbytes["v"]) // 2304) == (655_360, 786_432)
 
 
 # -- the latent-attention, routed-expert family (ISSUE 28) ---------------------

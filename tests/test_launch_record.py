@@ -342,7 +342,7 @@ def test_host_walk_steps_round_the_pages_up(P):
     bs, mb, window = 16, 24, 100
     host = types.SimpleNamespace(
         _kv_walks=True, kv_block_size=bs, _max_blocks=mb, _kv_window=window,
-        _scratch_seq=bs * mb, _walk_pages={"chunk": P, "mixed": 1},
+        _scratch_seq=bs * mb, _walk_pages={"chunk": (P,), "mixed": (1,)},
         _sparse=None,
     )
     host._kv_walk = types.MethodType(ContinuousEngine._kv_walk, host)
@@ -396,7 +396,9 @@ def test_walk_shape_at_the_cells_shapes(name):
     kv, bs, dh = next(a for a in jax.tree.leaves(pool) if a.ndim == 5).shape[-3:]
     tiles = (2 * cfg.diffusion_block or 1, 8)  # the decode chunk's, a mixed launch's
     for tq, want in zip(tiles, CELL_WALK_SHAPES[name]):
-        assert ContinuousEngine._walk_pages_of(host, tq) == want[1]
+        # (one number a group of the pool)
+        assert ContinuousEngine._walk_pages_of(host, tq) == (
+            want[1],) * len(cfg.kv_groups)
         assert _walk_shape(kv, bs, dh, 2, False, tq * (cfg.n_heads // kv), mb,
                            cfg.latent_dim > 0, listed) == want
 

@@ -272,7 +272,10 @@ def test_the_manifest_gained_one_configuration_one_cell_and_three_metrics():
     assert len(cells[CELL]["why"]) <= 200 and len(by_config[CONFIG]["why"]) <= 200
     by_name = {m["name"]: m for m in man["per_layer"]}
     for name in NEW_METRICS:
-        assert by_name[name]["workloads"] == [CELL] and by_name[name]["moves"] == "tpot_ms_p50"
+        # (a later cell that runs a grouped pool and a share joined two of the
+        # lists behind this one: ISSUE 55)
+        assert by_name[name]["workloads"][0] == CELL and by_name[name]["moves"] == "tpot_ms_p50"
+    assert by_name["window_attn_kv_roofline"]["workloads"] == [CELL]
     assert by_name["window_attn_kv_roofline"]["source"] == "device_trace"
     assert by_name["window_attn_kv_roofline"]["layer"] == "kernels"
     assert by_name["window_kv_held_pct"]["layer"] == "paged KV + prefix"
@@ -395,7 +398,7 @@ def test_reduced_whys_arithmetic_and_the_registrys_sizes():
     # the pool the flags ask for, as `served` states it: one number, two groups
     flags = config["serving"]["flags"]
     slots, blocks = (int(flags[flags.index(f) + 1]) for f in ("--continuous", "--kv-pool-blocks"))
-    groups = P.group_blocks(cfg, blocks, 37, slots)
+    groups = P.group_blocks(cfg, blocks, 37, slots, 128)
     assert groups == (blocks, blocks // 4) == (4608, 1152)
     pool = jax.eval_shape(lambda: P.init_pool(cfg.replace(dtype="bfloat16"), groups, 128))
     assert pool["k"].shape == (1, 4608, 8, 128, 128) and pool["kw"].shape == (4, 1152, 8, 128, 128)
