@@ -834,9 +834,10 @@ class EngineConfig:
     # stream four or more experts for each one a token computes, where a
     # step costs the bytes of the whole bank whatever it carries and a
     # document's prefill is one pass over the bank a chunk; where a full
-    # fleet's float32 matrix states are more bytes than the weights, that
-    # budget on top of the fleet's decode tiles. An explicit value is
-    # obeyed. Either is rounded up to a whole number of query
+    # fleet's decode tiles would take a third or more of that launch, the
+    # fleet's tiles go on top of it and the model computes the live tokens
+    # packed on the budget's axis (engine/scheduler.live_width). An explicit
+    # value is obeyed as it is. Either is rounded up to a whole number of query
     # tiles, and to at least one prefill tile above the decode fleet —
     # every active slot's decode row is reserved ahead of any prefill
     # chunk, so decode can never be starved by prefill and at least one

@@ -449,9 +449,11 @@ class ContinuousEngine:
             # tables side by side (`_launch_table`).
             self._wgrp = None
             if len(cfg.kv_groups) > 1:
-                from .scheduler import step_width
+                from .scheduler import live_width
 
-                launch = max(self.chunk_steps, step_width(
+                # the most tokens ONE row carries in a launch: the axis the
+                # model computes, not the tile layout's width
+                launch = max(self.chunk_steps, live_width(
                     cfg, self.n_slots, 8,
                     engine.engine_cfg.step_token_budget))
                 self._group_blocks = P.group_blocks(
@@ -550,8 +552,8 @@ class ContinuousEngine:
 
             self._sched_width = self._sched.width
             # the axis the mixed step's token-wise layers run on
-            # (engine/scheduler.live_width; the width itself everywhere but
-            # where the launch is fleet tiles + budget)
+            # (engine/scheduler.live_width; the width itself but where the
+            # launch is fleet tiles + budget)
             self._live_width = self._sched.live_width
             self._idle_arm = _P_arm.idle_mixed_arm(
                 self.n_slots, cfg.vocab_size
@@ -1107,8 +1109,9 @@ class ContinuousEngine:
         if self._chunked:
             m.gauge(
                 "dli_sched_step_width_tokens",
-                "flat-token width of the mixed scheduler launch "
-                "(derived from the model unless step_token_budget is set)",
+                "flat-token width of the mixed scheduler launch in the kernel's "
+                "tile layout (derived from the model unless step_token_budget is "
+                "set; the axis the model computes is /stats scheduler.live_width)",
             ).labels().set(self._sched_width)
         # launch-record families (ISSUE 24, pre-registered in
         # engine/engine.py): KV positions attention had to read against

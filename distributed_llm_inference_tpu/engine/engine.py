@@ -650,8 +650,9 @@ class InferenceEngine:
         )
         self.metrics.gauge(
             "dli_sched_step_width_tokens",
-            "flat-token width of the mixed scheduler launch "
-            "(derived from the model unless step_token_budget is set)",
+            "flat-token width of the mixed scheduler launch in the kernel's "
+            "tile layout (derived from the model unless step_token_budget is "
+            "set; the axis the model computes is /stats scheduler.live_width)",
         )
         # launch-record families (engine/continuous.py counts them at
         # every dispatch, mixed step or pure-decode chunk): KV positions

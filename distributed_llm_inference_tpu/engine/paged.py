@@ -1616,6 +1616,26 @@ def live_tokens(tok_row, width: int):
     return at, back
 
 
+def model_axis(live_width, tok_row, toks, pos):
+    """(compact, own, own_toks, pos) of a mixed step: the axis the model runs
+    on. The tile layout itself (compact None) unless a `live_width` under the
+    launch's width is given: then the live tokens alone, side by side
+    (`live_tokens`), with their rows, tokens and positions on that axis.
+    `toks` itself keeps the layout spec.idx reads the drafts from."""
+    if live_width is None or live_width >= tok_row.shape[0]:
+        return None, tok_row, toks, pos
+    at, back = live_tokens(tok_row, live_width)
+    return (at, back), tok_row[at], toks[at], pos[at]
+
+
+def _take_rows(x, index):
+    """x[index] along the first axis, gathered as whole 2-D rows: the form
+    the products on either side of the ragged hook write and read (gathered
+    as [n, 1, H, Dh] the output projection read a relaid copy: 0.36 ms a
+    layer more at mimo-v2.5's widths, PERF.md section 6, PR 56)."""
+    return x.reshape(x.shape[0], -1)[index].reshape(index.shape + x.shape[1:])
+
+
 def make_ragged_fill_hook(table, meta, tok_row, snaps=None, compact=None):
     """attn_hook for the ragged ingest programs: flat-token layout
     ([W, 1] chunks — each token is a batch row at its own position, the
@@ -1716,9 +1736,10 @@ def make_ragged_fill_hook(table, meta, tok_row, snaps=None, compact=None):
 
         def hook(cfg, q, k, v, cache_k, cache_v, pos, *rest, **kw):
             attn, new_k, new_v = tiles(
-                cfg, q[back], k[back], None if v is None else v[back],
+                cfg, _take_rows(q, back), _take_rows(k, back),
+                None if v is None else _take_rows(v, back),
                 cache_k, cache_v, pos[back], *rest, **kw)
-            return attn[at], new_k, new_v
+            return _take_rows(attn, at), new_k, new_v
 
     hook.paged = True  # forward_layers carries the stacked pool
     hook.tile = 1 if compact is not None else (
@@ -2167,12 +2188,7 @@ def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
         tok_row = jnp.where(dev.tok_on & ~state.active[rows_ix], -1, tok_row)
         ended = dev.tile_on & ~state.active[jnp.maximum(meta[:, 0], 0)]
         meta = meta.at[:, 2].set(jnp.where(ended, 0, meta[:, 2]))
-    # the model's axis: the tile layout, or the live tokens alone (`toks`
-    # keeps the layout spec.idx reads the drafts from)
-    compact, own, own_toks = None, tok_row, toks
-    if live_width is not None and live_width < tok_row.shape[0]:
-        compact = at, back = live_tokens(tok_row, live_width)
-        own, own_toks, pos = tok_row[at], toks[at], pos[at]
+    compact, own, own_toks, pos = model_axis(live_width, tok_row, toks, pos)
     x = M.embed(cfg, params, own_toks[:, None], pos)
     if cfg.linear_layers and snaps is None:  # restores none, keeps none
         snaps = (jnp.full((table.shape[0],), -1, jnp.int32),) * 2
@@ -2183,7 +2199,7 @@ def mixed_step_ragged(cfg: ModelConfig, params, tokens, tok_row, tok_pos,
     )
     if compact is not None:
         # dec_idx, arm.idx and spec.idx name flat tokens of the tile layout
-        x = x[back]
+        x = x[compact[1]]
     if cfg.diffusion_block:
         Bd = cfg.diffusion_block
         logits = M.unembed(cfg, params, open_block_rows(x, dec_idx, Bd))

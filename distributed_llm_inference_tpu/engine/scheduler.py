@@ -326,65 +326,90 @@ def _states_outweigh_weights(model_cfg, n_slots: int) -> bool:
     return n_slots * row > weights
 
 
+def _launch_widths(model_cfg, n_slots: int, tile: int,
+                   budget: Optional[int]) -> tuple:
+    """(width, live) of the mixed launch: its flat tokens in the kernel's
+    TILE layout, and the most LIVE tokens it carries, which is the axis the
+    token-wise layers run on (engine/paged.mixed_step_ragged packs the live
+    tokens side by side; only the paged attention kernel and the pool's
+    write see the tiles). The ONE place both are decided, from the
+    ModelConfig and the slot count alone; `step_width` and `live_width` hand
+    them out (the engine, `/stats`, the described-chip compiles and
+    tests/dense_equal.py all ask there).
+
+    An explicit `budget` (`EngineConfig.step_token_budget`) is obeyed as it
+    is: a launch of full tiles, no token packed. Without one:
+
+    The budget is what a step of the model wants: ROUTED_STEP_TOKENS where
+    the FFN layers route through the grouped kernels (`moe_ffn_dim`: only the
+    chosen experts are computed) and the bank is ROUTED_STREAM_RATIO times
+    what a token computes, DENSE_STEP_TOKENS otherwise. That covers the
+    all-experts einsum of `models/llama.moe_ffn` (`n_experts` without
+    `moe_ffn_dim`), where a token computes every expert it streams. The slot
+    clamp stays on top.
+
+    A decode row takes a whole tile of the launch for its one token, so
+    where a full fleet's tiles would take a third or more of the launch the
+    budget alone gives (3 x n_slots x tile >= the clamped budget), most of a
+    busy step's places are padding and prefill rides what is left (mimo-v2.5
+    at 32 slots: 256 of 512, 264-272 prompt tokens a step; olmo2-7b at 12:
+    96 of 128; granite-4.0-h-micro at 64: 512 of 520, 8-32 prompt tokens a
+    step and ~9 mixed steps of ~45 ms to admit what 2 now do). There the
+    fleet's tiles go ON TOP of the budget and the model computes the
+    (clamped) budget's axis, packed: exactly what it computed before the
+    tiles went on top, so no token-wise layer gets wider or narrower, a
+    decode row costs the step one token, and only the prompt tokens a step
+    holds change (mimo-v2.5: 512 of 768, 480 prompt tokens beside 31
+    decoding rows where the tile layout left 264). Under a third (kanana at
+    8 slots, lfm2 and trinity at 16: 64-128 of 512) packing would buy 7
+    tokens a decoding row at one or two rows a step, and the launch stays
+    the budget. Two kinds of model keep the tile layout whatever the share:
+    one whose sparse layers select by the launch's tiles
+    (`ModelConfig.sparse_layers`: models/minicpm_sala.tile_meta), and a
+    block-diffusion model, whose decode row's tile IS its open and owed
+    blocks (`diffusion_block`).
+
+    One axis is wider than its budget: where a full fleet's float32 states
+    outweigh the weights (`_states_outweigh_weights`) a narrower step saves
+    no stream, and every step a starved prefill adds costs a pass over all
+    the rows' states, so the live tokens are held to n_slots + 2 x
+    DENSE_STEP_TOKENS, a full fleet's decode tokens and twice the dense
+    budget of prompt (granite-4.0-h-micro at 64 slots: 320 of 640; 384 and
+    448 read no better on the chip; the free tiles alone hold `tile` x
+    (width / tile - decoding rows), which is the tighter limit from 46
+    decoding rows up, so a warm fleet's burst loses no step)."""
+    if budget is not None:
+        return (_clamp_width(budget, n_slots, tile),) * 2
+    wide = model_cfg.moe_ffn_dim and (
+        model_cfg.n_experts // model_cfg.n_experts_per_tok
+        >= ROUTED_STREAM_RATIO
+    )
+    budget = ROUTED_STEP_TOKENS if wide else DENSE_STEP_TOKENS
+    live = _clamp_width(budget, n_slots, tile)
+    fleet = int(n_slots) * tile
+    if model_cfg.sparse_layers or model_cfg.diffusion_block \
+            or 3 * fleet < live:
+        return live, live
+    width = _clamp_width(fleet + budget, n_slots, tile)
+    if _states_outweigh_weights(model_cfg, n_slots):
+        rows = int(n_slots) + 2 * DENSE_STEP_TOKENS
+        live = min(width, -(-rows // tile) * tile)
+    return width, live
+
+
 def step_width(model_cfg, n_slots: int, tile: int = 8,
                budget: Optional[int] = None) -> int:
-    """Flat-token width of the mixed launch: the ONE place it is decided
-    (the engine, `/stats`, the described-chip compiles and
-    tests/dense_equal.py all ask here). An explicit `budget`
-    (`EngineConfig.step_token_budget`) is obeyed; without one the width
-    follows what a step of the model streams, read from the ModelConfig
-    alone: ROUTED_STEP_TOKENS where the FFN layers route through the
-    grouped kernels (`moe_ffn_dim`: only the chosen experts are computed)
-    and the bank is ROUTED_STREAM_RATIO times what a token computes,
-    DENSE_STEP_TOKENS otherwise. That covers the all-experts einsum of
-    `models/llama.moe_ffn` (`n_experts` without `moe_ffn_dim`), where a
-    token computes every expert it streams. The slot clamp stays on top.
-
-    Where a full fleet's states outweigh the weights
-    (`_states_outweigh_weights`) the budget is PREFILL's, on top of the
-    fleet's decode tiles: a narrower step saves no stream there, and every
-    step a starved prefill adds costs a pass over all the rows' states and
-    the fleet's padded tiles again (granite-4.0-h-micro at 64 slots:
-    64 x 8 + 128 = 640 where the clamp alone gave 520, 8-32 prompt tokens
-    a step, ~9 mixed steps of ~45 ms to admit what 2 now do)."""
-    if budget is None:
-        wide = model_cfg.moe_ffn_dim and (
-            model_cfg.n_experts // model_cfg.n_experts_per_tok
-            >= ROUTED_STREAM_RATIO
-        )
-        budget = ROUTED_STEP_TOKENS if wide else DENSE_STEP_TOKENS
-        if _states_outweigh_weights(model_cfg, n_slots):
-            budget += int(n_slots) * tile
-    return _clamp_width(budget, n_slots, tile)
+    """Flat-token width of the mixed launch, in the kernel's tile layout
+    (`_launch_widths`)."""
+    return _launch_widths(model_cfg, n_slots, tile, budget)[0]
 
 
 def live_width(model_cfg, n_slots: int, tile: int = 8,
                budget: Optional[int] = None) -> int:
-    """The most LIVE flat tokens a mixed launch carries, and so the width of
-    the axis its token-wise layers run on (engine/paged.mixed_step_ragged
-    packs the live tokens side by side; only the paged attention kernel sees
-    the tiles): decided here, beside `step_width`, from the same shapes.
-
-    Everywhere but one branch it IS the launch's width, and no token is
-    packed. Where `step_width` builds the launch of the fleet's decode tiles
-    plus prefill's budget (`_states_outweigh_weights`), a decode row's tile
-    of `tile` tokens holds one live token, so a full fleet's launch is
-    mostly padding (granite-4.0-h-micro at 64 slots: 640 wide, 448 of them
-    dead, and at 640 rows the products are arithmetic-bound where 240 rows
-    still hide under the weights' stream): there the live tokens are held to
-    n_slots + 2 x DENSE_STEP_TOKENS, a full fleet's decode tokens and twice
-    the dense budget of prompt (320 at 64 slots; the free tiles alone hold
-    `tile` x (width / tile - decoding rows), which is the tighter limit from
-    46 decoding rows up, so a warm fleet's burst loses no step). A model
-    whose sparse layers select by the launch's tiles
-    (`ModelConfig.sparse_layers`: models/minicpm_sala.tile_meta) keeps the
-    tile layout, and an explicit `budget` is obeyed as it is."""
-    width = step_width(model_cfg, n_slots, tile, budget)
-    if budget is not None or model_cfg.sparse_layers \
-            or not _states_outweigh_weights(model_cfg, n_slots):
-        return width
-    live = int(n_slots) + 2 * DENSE_STEP_TOKENS
-    return min(width, -(-live // tile) * tile)
+    """The most live flat tokens a mixed launch carries, and so the width of
+    the axis its token-wise layers run on (`_launch_widths`): the launch's
+    own width where no token is packed."""
+    return _launch_widths(model_cfg, n_slots, tile, budget)[1]
 
 
 class TokenBudgetScheduler:

@@ -972,14 +972,16 @@ class PipelineBackend(SPMDBackendBase):
     # chunks in one program.
     def mixed_step_ragged(self, tokens, tok_row, tok_pos, dec_flag, meta,
                           pool, table, state, sparams, key, dec_idx, arm,
-                          spec=None, spec_toks=None, dev=None, pages=None):
+                          spec=None, spec_toks=None, dev=None, pages=None,
+                          live_width=None):
         mkey = ("mixed_step_ragged", spec is not None,
-                spec_toks is not None, dev is not None, pages is not None)
+                spec_toks is not None, dev is not None, pages is not None,
+                live_width)
         fn = self._programs.get(mkey)
         if fn is None:
             fn = self._build_mixed_step_ragged(
                 spec is not None, spec_toks is not None, dev is not None,
-                pages is not None,
+                pages is not None, live_width,
             )
             self._programs[mkey] = fn
         args = [self.shared, self.layers, tokens, tok_row, tok_pos,
@@ -1007,7 +1009,8 @@ class PipelineBackend(SPMDBackendBase):
     def _build_mixed_step_ragged(self, with_spec: bool = False,
                                  with_spec_toks: bool = False,
                                  with_dev: bool = False,
-                                 with_pages: bool = False):
+                                 with_pages: bool = False,
+                                 live_width=None):
         """shard_map twin of engine/paged.mixed_step_ragged: the flat
         token fleet (decode rows gathered from the replicated slot state,
         prefill chunks from the host plan) runs the S ring microsteps
@@ -1025,7 +1028,11 @@ class PipelineBackend(SPMDBackendBase):
         with_dev variant applies the SHARED engine/paged.
         apply_device_meta substitution (decode/verify positions derived
         from the replicated slot state) before the hook sees the plan —
-        device-derived metadata cannot drift across backends either."""
+        device-derived metadata cannot drift across backends either.
+        live_width (engine/scheduler.live_width, where it is under the
+        launch's width): the ring runs the live tokens packed side by
+        side (the SHARED engine/paged.model_axis), as the single device
+        does."""
         cfg, S = self.cfg, self.pp
         from ..engine import paged as EP
         from ..engine.generate import SlotParams, SlotState
@@ -1050,7 +1057,6 @@ class PipelineBackend(SPMDBackendBase):
                 meta, tok_pos = EP.apply_device_meta(
                     meta, tok_row, tok_pos, dev, state.pos
                 )
-            hook = EP.make_ragged_fill_hook(table, meta, tok_row)
             s = jax.lax.axis_index(AXIS_PP)
             rows_ix = jnp.maximum(tok_row, 0)
             toks = jnp.where(dec_flag, state.token[rows_ix], tokens)
@@ -1067,11 +1073,17 @@ class PipelineBackend(SPMDBackendBase):
                     spec_toks.reshape(-1), mode="drop"
                 )
             pos = jnp.where(dec_flag, state.pos[rows_ix], tok_pos)
-            x = embed_sharded(cfg, shared, toks[:, None], pos, S)
+            compact, own, own_toks, pos = EP.model_axis(
+                live_width, tok_row, toks, pos)
+            x = embed_sharded(cfg, shared, own_toks[:, None], pos, S)
             buf, pool = self._microstep_loop(
-                layers, x, pool, pos, attn_hook=hook, attn_seq_len=1,
-                lora_pages=EP._token_pages(pages, tok_row),
+                layers, x, pool, pos, attn_seq_len=1,
+                attn_hook=EP.make_ragged_fill_hook(
+                    table, meta, tok_row, compact=compact),
+                lora_pages=EP._token_pages(pages, own),
             )
+            if compact is not None:
+                buf = buf[compact[1]]  # the idx operands name the tile layout
 
             def replicated_logits(idx):
                 sel = buf[idx]  # [N, 1, D]

@@ -36,9 +36,12 @@ def pytest_generate_tests(metafunc):
 # -- what a cell is ------------------------------------------------------------
 #
 # Asserted on the builder's result, which read the sizes from
-# cellbench/configs/<name>.json: the mixed launch's width
-# (engine/scheduler.step_width: 128 / 136 for the dense two, 512 where the
-# experts route, the fleet's tiles and 128 where its states outweigh the weights), the pool's blocks (a grouped pool: the window group a
+# cellbench/configs/<name>.json: the mixed launch's width in the kernel's
+# tile layout (engine/scheduler.step_width: 128 for a dense model, 512 where
+# the experts route, with the fleet's decode tiles on top where they would
+# take a third of that) and `live`, the axis the token-wise layers run on
+# where that is narrower (engine/scheduler.live_width), the pool's blocks (a
+# grouped pool: the window group a
 # quarter of the global one's, its row budget 37), one pool leaf's shape, the
 # labels of the family (utils/tracing.STEP_SCOPES) and the least the pool
 # holds (2 GB and more; sdar-batch's 32 rows of 2,048 tokens: 0.94 GB).
@@ -66,11 +69,13 @@ CELLS = {
     "granite-4.0-h-micro": dict(
         width=640, live=320, blocks=2048, leaf=("k", (4, 2048, 4, 64, 128)),
         scopes=DENSE_SCOPES + ("ssm_mix", "ssm_scan")),
+    # (16 slots' tiles and the dense 128 on top; the model computes the 136
+    # it launched before: the budget clamped to the fleet and a prefill tile)
     "mistral-7b-16l": dict(
-        width=136, blocks=271, leaf=("k", (16, 271, 8, 128, 128)),
+        width=256, live=136, blocks=271, leaf=("k", (16, 271, 8, 128, 128)),
         scopes=DENSE_SCOPES),
     "olmo2-7b-16l": dict(
-        width=128, blocks=61, leaf=("k", (16, 61, 32, 128, 128)),
+        width=224, live=128, blocks=61, leaf=("k", (16, 61, 32, 128, 128)),
         scopes=DENSE_SCOPES),
     # (every layer routes: the family's programs hold no dense `ffn`)
     "sdar-30b-a3b-7l": dict(
@@ -82,28 +87,37 @@ CELLS = {
     # (a group's rows are its own kind's: 4 K/V heads in the global group's
     # two layers, 8 in the window group's five, keys of 192 numbers on 256
     # lanes and values of 128; the window is one block, so the window group
-    # is the 32 slots' budgets of 6 and its null block)
+    # is the 32 slots' budgets of 6 and its null block: a row carries at most
+    # the 512 of the compact axis in a launch, whatever the 768 tile places)
     "mimo-v2.5-7l": dict(
-        width=512, blocks=(2304, 193), leaf=("kw", (5, 193, 8, 128, 256)),
+        width=768, live=512, blocks=(2304, 193), leaf=("kw", (5, 193, 8, 128, 256)),
         scopes=DENSE_SCOPES + ROUTED_SCOPES),
 }
 
 
 def test_the_model_runs_on_the_launch_width_but_where_tiles_pad_it(
         one_chip, no_persistent_cache, config):
-    """`live_width` is the launch's width in every configuration but the one
-    whose launch is fleet tiles + budget: there the token-wise layers run on
-    the live tokens alone (ISSUE 54) and nowhere else does a product, a norm
-    or a scan of the mixed program see the tile layout's width."""
+    """`live_width` is the launch's width but where the launch is fleet
+    tiles + budget (ISSUE 54, ISSUE 56: every configuration whose full
+    fleet's tiles would take a third of the budget's launch): there the
+    token-wise layers run on the live tokens alone, the mixed program's
+    products see `[live, D]` and none of them the tile layout's width."""
     built, cell = cell_programs(config), CELLS[config]
     live = cell.get("live", cell["width"])
-    assert built.live == live
+    assert (built.width, built.live) == (cell["width"], live)
     text = built.texts["mixed_step_ragged"]
-    D = built.cfg.dim
-    assert f"[{live},{D}]" in text
-    if live < built.width:
-        # (the tile layout is the kernel's and the head's index space alone)
-        assert not re.search(rf"\[{built.width},{built.cfg.ffn_dim}\]", text)
+    cfg = built.cfg
+    assert f"[{live},{cfg.dim}]" in text
+    if live == built.width:
+        return
+    # (the tile layout is the kernel's and the head's index space alone: no
+    # product's result has its width; what does are the hook's gathers, whole
+    # rows of q / k / v on their way to the kernel)
+    products = re.findall(
+        r"= \w+\[(\d+),[^\]]*\]\S* (?:dot|convolution)\(", text)
+    assert products and str(live) in products
+    assert str(built.width) not in products
+    if config.startswith("granite"):
         assert re.search(rf"f32\[\d+,{live},{live}\]", text)  # the decays
         assert not re.search(rf"f32\[\d+,{built.width},{built.width}\]", text)
 
@@ -124,9 +138,9 @@ def test_the_programs_are_the_cells(one_chip, no_persistent_cache, config):
     if config.startswith("lfm2"):
         assert (len(cfg.conv_layers), len(cfg.attn_layers), cfg.kv_pack) == (7, 2, 2)
     if config.startswith("trinity"):
-        assert EP.window_row_budget(cfg.attn_window, built.width, 128) == 37
+        assert EP.window_row_budget(cfg.attn_window, built.live, 128) == 37
     if config.startswith("mimo"):
-        assert EP.window_row_budget(cfg.attn_window, built.width, 128) == 6
+        assert EP.window_row_budget(cfg.attn_window, built.live, 128) == 6
         assert built.pool["k"].shape == (2, 2304, 4, 128, 256)
         assert built.pool["v"].shape == (2, 2304, 4, 128, 128)
         assert built.pool["vw"].shape == (5, 193, 8, 128, 128)

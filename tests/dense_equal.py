@@ -224,10 +224,11 @@ def programs(model, slots, blocks, context, block_size=128, tile=8,
     params = place(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
     state, sparams = place(lambda: G.init_slots(slots, cfg.vocab_size))
     width = step_width(cfg, slots, tile)  # what the server launches
+    live = live_width(cfg, slots, tile)  # ... and the axis the model computes
     grouped = len(cfg.kv_groups) > 1
-    if grouped:
+    if grouped:  # (the most tokens one row carries in a launch: the axis's)
         blocks = P.group_blocks(cfg, blocks, P.window_row_budget(
-            cfg.attn_window, width, block_size), slots, block_size)
+            cfg.attn_window, live, block_size), slots, block_size)
     pool = place(lambda: P.init_pool(
         cfg, blocks, block_size, n_slots=slots,
         **({"n_snapshots": snapshots} if cfg.linear_layers else {})))
@@ -258,7 +259,6 @@ def programs(model, slots, blocks, context, block_size=128, tile=8,
                 entries, offsets, slots, width=width, tile=tile)}
         if cfg.linear_layers:  # by slot: the snapshot restored, the one kept
             mixed_kw = {"snaps": (S((slots,), jnp.int32),) * 2}
-    live = live_width(cfg, slots, tile)
     if live < width:  # as the engine dispatches it: no operand more elsewhere
         mixed_kw["live_width"] = live
     assert len(offsets) == slots  # one tile a row
@@ -308,6 +308,18 @@ def cell_serving(config: str) -> dict:
             os.path.abspath(__file__))), "cellbench", "configs", config + ".json")
     with open(config) as f:
         return json.load(f)["serving"]
+
+
+def cell_config(config: str) -> tuple:
+    """(the ModelConfig a benchmark configuration serves: its registry entry
+    under the file's overrides, in bfloat16; its `--continuous` slots)."""
+    from distributed_llm_inference_tpu.models.registry import get_model_config
+
+    serving = cell_serving(config)
+    flags = serving["flags"]
+    cfg = get_model_config(serving["base"]).replace(
+        dtype="bfloat16", **serving.get("overrides", {}))
+    return cfg, int(flags[flags.index("--continuous") + 1])
 
 
 @functools.cache  # (one compile a configuration in a process: minutes each)

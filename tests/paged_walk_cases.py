@@ -46,10 +46,10 @@ def cell_pool(name):
     cfg = resolve_attn_impl(get_model_config(serving["base"]).replace(
         dtype="bfloat16", **serving["overrides"]), "pallas")
     if len(cfg.kv_groups) > 1:
-        from distributed_llm_inference_tpu.engine.scheduler import step_width
+        from distributed_llm_inference_tpu.engine.scheduler import live_width
 
         blocks = EP.group_blocks(cfg, blocks, EP.window_row_budget(
-            cfg.attn_window, step_width(cfg, slots, 8), bs), slots, bs)
+            cfg.attn_window, live_width(cfg, slots, 8), bs), slots, bs)
     pool = jax.eval_shape(lambda: EP.init_pool(cfg, blocks, bs, n_slots=slots))
     return cfg, slots, -(-context // bs), pool
 

@@ -138,15 +138,15 @@ def test_paged_flash_attend_compiles_at_cell_shapes(
 def test_ragged_paged_attend_compiles_at_cell_shapes(
     one_chip, no_persistent_cache, cell, quant
 ):
-    """The mixed step's kernel: `step_width` flat tokens (a dense model's
-    128, or one tile above the fleet) in query tiles of 8 (16 tiles for
-    olmo2's 12 slots, 17 for mistral's 16)."""
+    """The mixed step's kernel: `step_width` flat tokens (the fleet's decode
+    tiles and a dense model's 128 on top) in query tiles of 8 (28 tiles for
+    olmo2's 12 slots, 32 for mistral's 16)."""
     cfg, slots, h, kv, dh, bs, mb, blocks, window = _cell_shape(cell)
     S = _spec(one_chip)
     pool = _kv(S, (blocks, kv, bs, dh), quant)
     tq = 8
     tiles = step_width(cfg, slots, tq) // tq
-    assert tiles == {"olmo2-7b-16l": 16, "mistral-7b-16l": 17}[cell]
+    assert tiles == {"olmo2-7b-16l": 28, "mistral-7b-16l": 32}[cell]
     text = _compile(
         functools.partial(ragged_paged_attend, interpret=False, window=window),
         S((tiles * tq, h, dh), jnp.bfloat16), pool, pool,

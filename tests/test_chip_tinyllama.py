@@ -74,7 +74,9 @@ def test_tinyllama_paged_decode_chunk_compiles_with_kernel(
     `--continuous-chunk`'s default 16 steps over the block pool, whose
     attention is the paged kernel walking the table."""
     fleet = _fleet()
-    assert fleet.width == 128  # engine/scheduler.step_width: a dense model's
+    # engine/scheduler.step_width / live_width: 8 slots' tiles on top of a dense
+    # model's 128, which stays the axis the model computes
+    assert (fleet.width, fleet.live) == (192, 128)
     compiled = fleet.compiled["decode_slots_paged"]
     text = fleet.texts["decode_slots_paged"]
     assert "tpu_custom_call" in text and "jit_decode_slots_paged" in text

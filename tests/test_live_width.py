@@ -1,12 +1,15 @@
-"""A mixed step computes the tokens it carries (ISSUE 54): the launch's live
-flat tokens packed side by side on an axis of `live_width`
-(engine/paged.live_tokens; only the paged hook's kernel and pool write see
-the tile layout) against the SAME launches at `live_width == width`, the
-tile layout throughout, at `test-granite-tiny`: the emitted tokens, the slot
-state and every leaf of the pool (float32 matrix and convolution states,
-both snapshot pools, K/V outside the trash block) after a run of launches
-that starts rows cold, takes a snapshot and restores it, decodes beside
-prefill, carries no prefill at all, and fills the compact axis exactly."""
+"""A mixed step computes the tokens it carries (ISSUE 54, and every padded
+fleet since ISSUE 56): the launch's live flat tokens packed side by side on
+an axis of `live_width` (engine/paged.live_tokens; only the paged hook's
+kernel and pool write see the tile layout) against the SAME launches at
+`live_width == width`, the tile layout throughout: the emitted tokens, the
+slot state and every leaf of the pool after a run of launches that starts
+rows cold, decodes beside prefill, carries no prefill at all, and fills the
+compact axis exactly. At `test-granite-tiny` (float32 matrix and convolution
+states, both snapshot pools: a snapshot taken and restored), `test-mimo-tiny`
+under a held expert share (a pool grouped by layer kind, a learned sink: both
+groups' leaves and the routed counts) and `test-olmo2-tiny` (K/V alone); K/V
+outside the trash block."""
 
 import jax
 import jax.numpy as jnp
@@ -51,13 +54,32 @@ LAUNCHES = (
 )
 
 
-def run(impl, live_width):
+# model -> (its configuration, the pool's leaves that are compared)
+MODELS = {
+    "test-granite-tiny": (lambda: get_model_config("test-granite-tiny"),
+                          ("lin", "snap", "conv", "csnap", "k", "v")),
+    "test-mimo-tiny": (lambda: get_model_config("test-mimo-tiny").replace(
+        name="test-mimo-share", expert_lo=2, n_experts_held=4),
+        ("k", "v", "kw", "vw", "routed")),
+    "test-olmo2-tiny": (lambda: get_model_config("test-olmo2-tiny"), ("k", "v")),
+}
+CASES = [(model, impl) for model in MODELS for impl in ("xla", "pallas")]
+
+
+def run(model, impl, live_width):
     """The launches in order through `mixed_step_ragged`; returns ([packed],
     the slot state, the pool)."""
-    cfg = resolve_attn_impl(get_model_config("test-granite-tiny"), impl)
+    cfg = resolve_attn_impl(MODELS[model][0](), impl)
     params = M.init_params(cfg, jax.random.PRNGKey(3))
-    pool = P.init_pool(cfg, SLOTS * MB + 1, BS, n_slots=SLOTS, n_snapshots=4)
-    table = jnp.asarray(1 + np.arange(SLOTS * MB, dtype=np.int32).reshape(SLOTS, MB))
+    groups = len(cfg.kv_groups)
+    blocks = SLOTS * MB + 1
+    pool = P.init_pool(cfg, blocks if groups == 1 else (blocks,) * groups, BS,
+                       n_slots=SLOTS, n_snapshots=4)
+    # (a grouped pool's launch table: its groups' side by side, every row
+    # its own blocks of each)
+    table = jnp.asarray(np.tile(
+        1 + np.arange(SLOTS * MB, dtype=np.int32).reshape(SLOTS, MB), (1, groups)))
+    stateful = bool(cfg.linear_layers)
     state, sparams = G.init_slots(SLOTS, cfg.vocab_size)
     on = np.zeros((SLOTS,), bool)
     on[[6, 7]] = True
@@ -68,7 +90,8 @@ def run(impl, live_width):
     packed = []
     for decode, chunks, restore, take in LAUNCHES:
         entries = [(b, 0, 1, P.RAGGED_DECODE) for b in decode] + [
-            (row, start, len(toks), kind) for row, start, toks, kind, _ in chunks]
+            (row, start, len(toks), kind if stateful else P.RAGGED_PREFILL)
+            for row, start, toks, kind, _ in chunks]
         meta, tok_row, tok_pos, offsets, _ = P.build_ragged_meta(
             entries, width=WIDTH, tile=TILE)
         assert (tok_row >= 0).sum() <= LIVE
@@ -97,15 +120,21 @@ def run(impl, live_width):
         out, state, sparams, pool = P.mixed_step_ragged(
             cfg, params, jnp.asarray(toks), jnp.asarray(tok_row), jnp.asarray(tok_pos),
             jnp.asarray(dec_flag), jnp.asarray(meta), pool, table, state, sparams, key,
-            jnp.asarray(dec_idx), arm, dev=dev, snaps=(jnp.asarray(snaps[0]), jnp.asarray(snaps[1])),
+            jnp.asarray(dec_idx), arm, dev=dev,
+            **({"snaps": (jnp.asarray(snaps[0]), jnp.asarray(snaps[1]))} if stateful else {}),
             **({} if live_width is None else {"live_width": live_width}))
         packed.append(np.asarray(out))
     return packed, state, pool
 
 
-@pytest.fixture(scope="module", params=["xla", "pallas"])
-def both(request):
-    return run(request.param, None), run(request.param, LIVE)
+_RUNS = {}
+
+
+def both(model, impl):
+    """(the tile layout's run, the compact axis's), made once a case."""
+    if (model, impl) not in _RUNS:
+        _RUNS[model, impl] = run(model, impl, None), run(model, impl, LIVE)
+    return _RUNS[model, impl]
 
 
 def test_launches_fill_the_compact_axis_exactly_and_not_at_all():
@@ -114,8 +143,10 @@ def test_launches_fill_the_compact_axis_exactly_and_not_at_all():
     assert any(not chunks for _, chunks, _, _ in LAUNCHES)
 
 
-def test_emitted_tokens_and_slot_state_equal(both):
-    (packed_t, state_t, _), (packed_c, state_c, _) = both
+@pytest.mark.parametrize("model,impl", CASES)
+def test_emitted_tokens_and_slot_state_equal(model, impl):
+    """(a routed model's packed rows end in its experts' counts: equal too)"""
+    (packed_t, state_t, _), (packed_c, state_c, _) = both(model, impl)
     for n, (a, b) in enumerate(zip(packed_t, packed_c)):
         np.testing.assert_array_equal(a, b, err_msg=f"launch {n}")
     assert int(np.asarray(packed_t[-1][2]).sum()) == 8  # every row decodes by the end
@@ -123,18 +154,24 @@ def test_emitted_tokens_and_slot_state_equal(both):
         np.testing.assert_array_equal(getattr(state_t, name), getattr(state_c, name), err_msg=name)
 
 
-@pytest.mark.parametrize("leaf", ["lin", "snap", "conv", "csnap", "k", "v"])
-def test_pool_leaves_equal(both, leaf):
+@pytest.mark.parametrize("model,impl,leaf", [
+    (model, impl, leaf) for model, impl in CASES for leaf in MODELS[model][1]])
+def test_pool_leaves_equal(model, impl, leaf):
     """Every leaf to 1e-5 + 1e-5 of the value after five launches, in a
     float32 model (a row's tokens lie elsewhere on a shorter axis, so the
     segmented running sum's tree and the within-launch sums add the same
     numbers in another order: 1.5e-6 on a matrix state's 0.54 and 1.2e-6 on
     a value row's 0.013 were the most read), snapshots taken and restored
-    among them; K/V outside the trash block (dead tokens write there, and
-    which of them wrote last is the layout's)."""
-    (_, _, pool_t), (_, _, pool_c) = both
+    among them; K/V of every group outside the trash block (dead tokens
+    write there, and which of them wrote last is the layout's); the routed
+    counts of the last launch to the pair."""
+    (_, _, pool_t), (_, _, pool_c) = both(model, impl)
     a, b = pool_t[leaf], pool_c[leaf]
-    if leaf in ("k", "v"):
+    if leaf == "routed":
+        assert int(np.asarray(a).sum()) > 0
+        np.testing.assert_array_equal(a, b)
+        return
+    if leaf in ("k", "v", "kw", "vw"):
         a, b = (a[:, 1:],), (b[:, 1:],)
     assert len(a) == len(b) > 0
     for layer, (x, y) in enumerate(zip(a, b)):

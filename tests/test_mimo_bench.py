@@ -520,7 +520,7 @@ def test_reduced_whys_arithmetic_and_the_registrys_sizes():
     import jax
 
     from distributed_llm_inference_tpu.engine import paged as P
-    from distributed_llm_inference_tpu.engine.scheduler import step_width
+    from distributed_llm_inference_tpu.engine.scheduler import live_width, step_width
     from distributed_llm_inference_tpu.models import api as M
     from distributed_llm_inference_tpu.models.registry import get_model_config
     from harness import serve
@@ -575,7 +575,9 @@ def test_reduced_whys_arithmetic_and_the_registrys_sizes():
     # the pool the flags ask for, as `served` states it: one number, two groups
     flags = config["serving"]["flags"]
     slots, blocks = (int(flags[flags.index(f) + 1]) for f in ("--continuous", "--kv-pool-blocks"))
-    assert step_width(cfg, slots, 8) == 512
+    # (the fleet's 256 tile places on top of the 512 the model computes, which
+    # is the most one row carries in a launch: engine/scheduler.live_width)
+    assert (step_width(cfg, slots, 8), live_width(cfg, slots, 8)) == (768, 512)
     budget = P.window_row_budget(cfg.attn_window, 512, 128)
     groups = P.group_blocks(cfg, blocks, budget, slots, 128)
     assert (budget, groups) == (6, (2304, 193))

@@ -174,3 +174,48 @@ def test_a_mesh_quantization_and_lora_stay_refused_by_name(what, kw):
         return
     with pytest.raises(ValueError, match=what):
         P.refuse_unsupported_latent(get_model_config(MODEL.name), **kw)
+
+
+# -- the window group under a launch of fleet tiles + budget (ISSUE 56) --------
+
+@pytest.mark.parametrize("config,slots,blocks,widths,budget,groups", [
+    # the launch's 768 tile places would make a row's budget 8 blocks and the
+    # group 257 for nothing: one row carries at most the axis's 512 a launch
+    ("mimo-v2.5-7l", 32, 2304, (768, 512), 6, (2304, 193)),
+    ("trinity-large-ep8-5l", 16, 4608, (512, 512), 37, (4608, 1152)),
+])
+def test_a_window_group_is_sized_from_the_axis_the_model_computes(
+        config, slots, blocks, widths, budget, groups):
+    from dense_equal import cell_config
+    from distributed_llm_inference_tpu.engine import paged as P
+    from distributed_llm_inference_tpu.engine.scheduler import live_width, step_width
+
+    cfg, served = cell_config(config)
+    assert served == slots
+    assert (step_width(cfg, slots, 8), live_width(cfg, slots, 8)) == widths
+    row = P.window_row_budget(cfg.attn_window, widths[1], 128)
+    assert row == budget
+    assert P.group_blocks(cfg, blocks, row, slots, 128) == groups
+
+
+def test_the_engine_sizes_the_window_group_from_the_compact_axis_and_serves_it():
+    """test-mimo-tiny at 24 slots and no explicit budget: the fleet's 192 tile
+    places on top of the routed 512, of which the model computes 512. The
+    window group is the slots' budgets at a launch of 512 tokens a row (not
+    704), and the rows it serves on the compact axis agree with the reference
+    as the tile layout's do."""
+    f = fleet(impl="pallas", slots=24, budget=None, pool=120)
+    sched = f.ce.stats()["scheduler"]
+    assert (sched["step_width"], sched["live_width"]) == (24 * 8 + 512, 512)
+    wg = f.ce._wgrp
+    assert wg.row_budget == -(-(16 + 512) // BS) + 1 == 67
+    assert f.ce._group_blocks == (120, 24 * 67 + 1)
+    asks = [(prompt_ids(n), mt) for n, mt in ASKS[:5]]
+    for (ids, mt), r in zip(asks, f.ask_all(asks)):
+        assert 0 < len(r["ids"]) <= mt
+        assert f.margins(ids, r).max() < TOL, (len(ids), f.margins(ids, r))
+    mixed = [r for r in f.records if r["phase"] == "mixed"]
+    assert mixed and all(r["tokens_computed"] == 512 for r in mixed)
+    assert all(r["tiles"] == (24 * 8 + 512) // 8 for r in mixed)
+    assert any(r["decode_rows"] and r["prefill_chunks"] for r in mixed)
+    assert f.books_balance()

@@ -37,6 +37,8 @@ from distributed_llm_inference_tpu.engine.scheduler import (
 from distributed_llm_inference_tpu.models import api as M
 from distributed_llm_inference_tpu.utils import faults
 
+from dense_equal import CELL_CONFIGS, cell_config
+
 TILE = 8
 
 
@@ -76,19 +78,21 @@ def test_width_clamps_to_fleet_plus_one_tile():
 
 
 @pytest.mark.parametrize("model,slots,width", [
-    # dense: the launch stops being weight-bound at 240 flat tokens
-    ("olmo2-7b", 12, 128),
-    ("mistral-7b", 16, 136),  # the slot clamp on top: 17 tiles
+    # dense: the launch stops being weight-bound at 240 flat tokens; the
+    # fleet's decode tiles on top where they would take a third of that
+    ("olmo2-7b", 12, 96 + 128),
+    ("mistral-7b", 16, 128 + 128),
+    ("olmo2-7b", 5, 128),  # 40 of 128: under a third
     # routed through the grouped kernels, 16 / 21 / 16 experts streamed for
     # each one a token computes (llama family, mla_moe, lfm2)
-    ("sdar-30b-a3b-chat", 32, 512),
+    ("sdar-30b-a3b-chat", 32, 512),  # (a block-diffusion row fills its tile)
     ("kanana-2-30b-a3b", 8, 512),
     ("lfm2-24b-a2b", 16, 512),
-    ("lfm2-24b-a2b", 80, 648),  # the slot clamp on top of a routed width
+    ("lfm2-24b-a2b", 80, 640 + 512),  # a fleet that would pad a routed width
     # the all-experts einsum computes every expert it streams for every
     # token: dense by its arithmetic whatever n_experts / n_experts_per_tok
-    ("mixtral-8x7b", 8, 128),
-    ("qwen3-30b-a3b", 8, 128),
+    ("mixtral-8x7b", 8, 64 + 128),
+    ("qwen3-30b-a3b", 8, 64 + 128),
     ("test-moe-tiny", 4, 128),
     # the CI presets of the three routed families: 5, 5 and 4 to one
     ("test-sdar-tiny", 4, 512),
@@ -116,60 +120,107 @@ def test_an_explicit_step_token_budget_is_obeyed(model, budget, slots, width):
     assert step_width(get_model_config(model), slots, TILE, budget) == width
 
 
-@pytest.mark.parametrize("model,dtype,slots,width", [
+@pytest.mark.parametrize("model,dtype,slots,width,live", [
     # 36 layers' float32 states, read and written: 151 MB a row beside
     # 6.38 GB of bfloat16 weights, so the states are the larger stream from
-    # 43 rows on, and the dense budget is then prefill's on top of the fleet
-    ("granite-4.0-h-micro", "bfloat16", 64, 64 * TILE + 128),
-    ("granite-4.0-h-micro", "bfloat16", 43, 43 * TILE + 128),
-    ("granite-4.0-h-micro", "bfloat16", 42, 43 * TILE),  # the slot clamp alone
-    ("granite-4.0-h-micro", "bfloat16", 16, 136),
+    # 43 rows on: there the live tokens are the fleet's and twice the dense
+    # budget of prompt, under it the (clamped) budget as everywhere
+    ("granite-4.0-h-micro", "bfloat16", 64, 64 * TILE + 128, 320),
+    ("granite-4.0-h-micro", "bfloat16", 128, 128 * TILE + 128, 384),
+    ("granite-4.0-h-micro", "bfloat16", 43, 43 * TILE + 128, 304),
+    ("granite-4.0-h-micro", "bfloat16", 42, 42 * TILE + 128, 43 * TILE),
+    ("granite-4.0-h-micro", "bfloat16", 16, 16 * TILE + 128, 136),
     # float32 weights are twice the stream: 64 rows' states do not outweigh them
-    ("granite-4.0-h-micro", "float32", 64, 65 * TILE),
-    # a fleet whose states are a few percent of the weights keeps its width
-    ("minicpm-sala", "bfloat16", 16, 136),
-    ("minicpm-sala", "bfloat16", 64, 65 * TILE),
+    ("granite-4.0-h-micro", "float32", 64, 64 * TILE + 128, 65 * TILE),
+    # a sparse model's selection reads the launch's tiles: the tile layout,
+    # however many rows it keeps
+    ("minicpm-sala", "bfloat16", 16, 136, 136),
+    ("minicpm-sala", "bfloat16", 64, 65 * TILE, 65 * TILE),
+    ("minicpm-sala", "bfloat16", 512, 513 * TILE, 513 * TILE),
     # no matrix state, however many rows: a convolution state is a few KB
-    ("lfm2-24b-a2b", "bfloat16", 80, 648),
-    ("mistral-7b", "bfloat16", 64, 65 * TILE),
+    ("lfm2-24b-a2b", "bfloat16", 80, 640 + 512, 648),
+    ("mistral-7b", "bfloat16", 64, 512 + 128, 65 * TILE),
 ])
-def test_prefill_keeps_the_budget_where_the_states_outweigh_the_weights(
-        model, dtype, slots, width):
+def test_widths_where_a_fleet_pads_the_launch(model, dtype, slots, width, live):
     cfg = get_model_config(model).replace(dtype=dtype)
-    assert step_width(cfg, slots, TILE) == width
-    # an explicit budget is obeyed here too
-    assert step_width(cfg, slots, TILE, 1024) == 1024
-
-
-# -- the live tokens of a launch (engine/scheduler.live_width, ISSUE 54) --------
-
-@pytest.mark.parametrize("model,slots,live", [
-    # a launch of fleet tiles + budget holds one live token a decode tile:
-    # the token-wise layers run on slots + 2 x 128 live tokens
-    ("granite-4.0-h-micro", 64, 320),
-    ("granite-4.0-h-micro", 128, 384),  # of 1,152
-    ("granite-4.0-h-micro", 43, 304),  # of 472
-    # everywhere else it IS the width: the benchmark's seven other
-    # configurations at their cells' slots ...
-    ("olmo2-7b", 12, 128), ("mistral-7b", 16, 136),
-    ("kanana-2-30b-a3b", 8, 512), ("sdar-30b-a3b-chat", 32, 512),
-    ("lfm2-24b-a2b", 16, 512), ("trinity-large-preview", 16, 512),
-    ("minicpm-sala", 16, 136),
-    # ... granite under the slot clamp alone, and a sparse model whose
-    # selection reads the launch's tiles, however many rows it keeps
-    ("granite-4.0-h-micro", 42, 43 * TILE), ("granite-4.0-h-micro", 16, 136),
-    ("minicpm-sala", 512, 512 * TILE + 128),
-])
-def test_live_width_is_the_width_but_where_fleet_tiles_pad_the_launch(
-        model, slots, live):
-    cfg = get_model_config(model).replace(dtype="bfloat16")
-    width = step_width(cfg, slots, TILE)
-    assert live_width(cfg, slots, TILE) == live <= width
-    assert (live < width) == (model.startswith("granite") and slots >= 43)
+    assert (step_width(cfg, slots, TILE), live_width(cfg, slots, TILE)) == (
+        width, live)
     # whole tiles, room for a full fleet's decode tokens and a tile of prompt
     assert live % TILE == 0 and live >= slots + TILE
     # an explicit budget is a width of full tiles: obeyed as it is
+    assert step_width(cfg, slots, TILE, 1024) == max(1024, (slots + 1) * TILE)
     assert live_width(cfg, slots, TILE, 1024) == step_width(cfg, slots, TILE, 1024)
+
+
+# -- the two widths of the benchmark's configurations (ISSUE 56) ---------------
+
+# configuration -> (its slots, step_width, live_width): the launch in the
+# kernel's tile layout, and the axis the model computes
+BENCHMARK_WIDTHS = {
+    # a full fleet's decode tiles would take a third or more of the launch
+    # the budget alone gives: the tiles go on top, the budget stays the axis
+    "mimo-v2.5-7l": (32, 768, 512),  # 256 of 512
+    "olmo2-7b-16l": (12, 224, 128),  # 96 of 128
+    "mistral-7b-16l": (16, 256, 136),  # 128 of 136
+    # ... and where the states outweigh the weights, slots + 2 x 128 of it
+    "granite-4.0-h-micro": (64, 640, 320),  # 512 of 520
+    # under a third (64-128 of 512): the budget is the launch
+    "kanana-2-30b-a3b-7l": (8, 512, 512),
+    "lfm2-24b-a2b-9l": (16, 512, 512),
+    "trinity-large-ep8-5l": (16, 512, 512),
+    # the selection reads the launch's tiles; a decode row's tile is its blocks
+    "minicpm-sala-9b-16l": (16, 136, 136),
+    "sdar-30b-a3b-7l": (32, 512, 512),
+}
+
+
+@pytest.mark.parametrize("config", sorted(BENCHMARK_WIDTHS))
+def test_the_benchmarks_configurations_launch_and_compute_these_widths(config):
+    slots, width, live = BENCHMARK_WIDTHS[config]
+    cfg, served = cell_config(config)
+    assert served == slots
+    assert (step_width(cfg, slots, TILE), live_width(cfg, slots, TILE)) == (
+        width, live)
+    # the rule reads shapes: no name
+    assert live_width(cfg.replace(name="x"), slots, TILE) == live
+    # a newly covered configuration computes exactly the width it launched
+    # before the tiles went on top (its clamped budget)
+    budget = 512 if cfg.moe_ffn_dim else 128
+    if live < width and not cfg.linear_layers:
+        assert (width, live) == (slots * TILE + budget,
+                                 max(budget, (slots + 1) * TILE))
+    # an explicit budget is obeyed as it is, by both
+    assert step_width(cfg, slots, TILE, 1024) == live_width(
+        cfg, slots, TILE, 1024) == 1024
+
+
+def test_every_benchmark_configuration_is_in_the_table():
+    assert sorted(CELL_CONFIGS) == sorted(BENCHMARK_WIDTHS)
+
+
+@pytest.mark.parametrize("decoding,prompt", [
+    (31, 480),  # a full fleet: 512 - 31 = 481 live tokens' room, 60 whole tiles
+    (16, 496), (1, 504),
+    (0, 512),  # a cold start: every place of the axis, not the 768 tiles' worth
+])
+def test_plan_at_mimos_shapes_gives_a_decode_row_one_token(decoding, prompt):
+    cfg, slots = cell_config("mimo-v2.5-7l")
+    s0 = _sched()
+    s = TokenBudgetScheduler(
+        s0.classes, "standard", step_width(cfg, slots, TILE), TILE, slots,
+        live_width=live_width(cfg, slots, TILE))
+    assert (s.width, s.live_width) == (768, 512)
+    cls = s.classes["standard"]
+    jobs = [_job(cls, tail=2800, enqueued=1.0 + i, slot=decoding + i)
+            for i in range(slots - decoding)]
+    plan = s.plan(decoding, jobs, now=2.0, n_decode_tokens=decoding)
+    assert sum(n for _, n in plan) == prompt == (512 - decoding) // TILE * TILE
+    assert decoding + prompt <= s.live_width
+    assert decoding + sum(-(-n // TILE) for _, n in plan) <= s.width // TILE
+    # the tile layout alone (the parent's plan) left 264 beside 31 rows
+    tiles = TokenBudgetScheduler(s0.classes, "standard", 512, TILE, slots)
+    assert sum(n for _, n in tiles.plan(decoding, jobs, now=2.0)) == \
+        512 - decoding * TILE
 
 
 def _live_sched(slots=64, live=320):

@@ -851,10 +851,13 @@ def test_draft_model_fleet_accepts_everything_with_identical_draft(setup):
 
 # -- pp shard_map twin --------------------------------------------------------
 
-def test_pp_spec_mixed_step_token_identical(setup, eight_devices):
+@pytest.mark.parametrize("live_width", [None, 24], ids=["tiles", "compact"])
+def test_pp_spec_mixed_step_token_identical(setup, eight_devices, live_width):
     """The pipeline's spec-mixed program produces the identical packed
     fetch / slot state as the single-device program on the same
-    operands — pp verify rows cannot drift."""
+    operands — pp verify rows cannot drift. With a `live_width` under the
+    launch's width both run the live tokens packed on that axis
+    (engine/scheduler.live_width): the same tokens again."""
     from distributed_llm_inference_tpu import MeshConfig
     from distributed_llm_inference_tpu.analysis.hlo import _spec_mixed_args
     from distributed_llm_inference_tpu.parallel.mesh import build_mesh
@@ -882,17 +885,24 @@ def test_pp_spec_mixed_step_token_identical(setup, eight_devices):
         _spec_mixed_args(eng, n_spec=1, n_draft=3, chunk=9)
     )
     cpu_cfg = acfg.replace(attn_impl="xla")
-    packed_s, state_s, _, _ = EP.mixed_step_ragged(
-        cpu_cfg, params, toks, tok_row, tok_pos, dec_flag, meta,
-        EP.init_pool(cpu_cfg, 10, 16), table, state, sparams, key,
-        dec_idx, arm, spec=spec, spec_toks=spec_toks, dev=dev,
-    )
+    packed = {}
+    for width in (None, live_width):  # (the tile layout is the reference)
+        kw = {} if width is None else {"live_width": width}
+        assert width is None or int(np.sum(np.asarray(tok_row) >= 0)) \
+            <= width < tok_row.shape[0]
+        packed[width], state_s, _, _ = EP.mixed_step_ragged(
+            cpu_cfg, params, toks, tok_row, tok_pos, dec_flag, meta,
+            EP.init_pool(cpu_cfg, 10, 16), table, state, sparams, key,
+            dec_idx, arm, spec=spec, spec_toks=spec_toks, dev=dev, **kw,
+        )
+    packed_s = packed[live_width]
+    assert np.asarray(packed_s).tolist() == np.asarray(packed[None]).tolist()
     pb = PipelineBackend(cpu_cfg, params, mesh)
     pool_pp = pb.init_paged_pool(10, 16)
     packed_p, state_p, _, _ = pb.mixed_step_ragged(
         toks, tok_row, tok_pos, dec_flag, meta, pool_pp, table,
         state, sparams, key, dec_idx, arm, spec=spec,
-        spec_toks=spec_toks, dev=dev,
+        spec_toks=spec_toks, dev=dev, **kw,
     )
     assert np.asarray(packed_s).tolist() == np.asarray(packed_p).tolist()
     assert (
