@@ -15,11 +15,39 @@ from typing import Optional
 import jax.numpy as jnp
 
 
+def _mamba_state(cfg) -> tuple:
+    from .ops.ssm_scan import state_shape  # (owns the packed layout)
+
+    return state_shape(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
+
+
+# what a layer of each recurrent kind keeps for a row, as the pool's leaves
+# hold it (engine/paged.init_pool): (channels of its convolution state, the
+# last conv_kernel - 1 inputs; its float32 matrix state's shape), each a
+# function of the ModelConfig, None where the kind keeps none. A new kind is
+# a row here: the KEEPS_* sets below are read off it.
+STATE_OF_KIND = {
+    # gated short convolutions (models/lfm2.py): the model's width
+    "conv": (lambda c: c.dim, None),
+    # a state-space mixer (models/granite_hybrid.py): [x | B | C], and
+    # [H / pack, N, pack x P]
+    "mamba": (lambda c: (c.ssm_heads * c.ssm_head_dim
+                         + 2 * c.ssm_groups * c.ssm_state), _mamba_state),
+    # decayed linear attention (models/minicpm_sala.py): [heads, Dh, Dh]
+    "lightning-attn": (
+        None, lambda c: (c.linear_heads, c.head_dim, c.head_dim)),
+    # the gated delta rule (models/solar_open2.py): the convolutions of q, k
+    # and v side by side, and [heads, Dv, Dk] (ops/delta_rule.py: transposed)
+    "kda": (lambda c: 3 * c.linear_heads * c.head_dim,
+            lambda c: (c.linear_heads, c.head_dim, c.head_dim)),
+}
 # the layer kinds (ModelConfig.layer_types) by what a layer of the kind KEEPS
 # for a row, whatever the arch: the pool's leaves, the prefix index's
 # snapshots and the start-up refusals are keyed on these, not on arch names
-KEEPS_CONV_STATE = frozenset({"conv", "mamba"})
-KEEPS_MATRIX_STATE = frozenset({"lightning-attn", "mamba"})
+KEEPS_CONV_STATE = frozenset(
+    kind for kind, (conv, _) in STATE_OF_KIND.items() if conv)
+KEEPS_MATRIX_STATE = frozenset(
+    kind for kind, (_, state) in STATE_OF_KIND.items() if state)
 KEEPS_KV = frozenset({"full_attention", "minicpm4", "attention"})
 # K/V of the last attn_window positions alone: in a stack that also has a
 # kind that keeps its whole context, the pool holds such layers in a group
@@ -29,7 +57,10 @@ SELECTS_BLOCKS = frozenset({"minicpm4"})
 # the families whose routed layer is told which experts it holds
 # (cfg.expert_lo) and whose seeded draw follows an expert's PUBLISHED index,
 # so that a configuration can be one chip's share of an expert-parallel layer
-HOLDS_EXPERT_SHARE = frozenset({"afmoe", "mimo_v2"})
+HOLDS_EXPERT_SHARE = frozenset({"afmoe", "mimo_v2", "solar_open2"})
+# the layers whose matrix state is folded by the delta rule, a chunk at a
+# time (ops/delta_rule.py: the launch records count their chunks)
+FOLDS_BY_DELTA_RULE = frozenset({"kda"})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,7 +74,7 @@ class ModelConfig:
 
     name: str = "tinyllama-1.1b"
     # "llama" | "gpt2" | "mla_moe" | "lfm2" | "afmoe" | "minicpm_sala"
-    # | "granite_hybrid" | "mimo_v2"
+    # | "granite_hybrid" | "mimo_v2" | "solar_open2"
     arch: str = "llama"
     vocab_size: int = 32000
     dim: int = 2048
@@ -202,6 +233,14 @@ class ModelConfig:
     # and queries head_dim wide and values v_head_dim (0: head_dim), the
     # rotation on the first rotary_dim lanes of a head (0: all of them), the
     # values times attn_value_scale.
+    # Arch "solar_open2" (models/solar_open2.py: Solar-Open2) names each layer
+    # "kda" (Kimi Delta Attention: linear_heads heads of head_dim whose q, k
+    # and v each pass a causal depthwise convolution of conv_kernel taps, a
+    # float32 matrix state folded by the gated delta rule with a decay a key
+    # channel and, under delta_neg_eigval, beta in (0, 2); keeps the three
+    # convolutions' last inputs AND the matrix state a row, no K/V) or
+    # "full_attention" (gated GQA with no position encoding; owns K/V), every
+    # layer over routed experts beside a shared one.
     layer_types: Optional[tuple] = None
     window_kv_heads: int = 0
     window_sink: bool = False
@@ -214,6 +253,7 @@ class ModelConfig:
     ssm_state: int = 0
     ssm_groups: int = 1
     conv_bias: bool = False
+    delta_neg_eigval: bool = False
     # the selection's constants (the family's published sparse_config): a
     # compressed key is the mean of sparse_kernel keys, one every
     # sparse_stride tokens; block 0.. sparse_init_blocks - 1 and the blocks
@@ -282,7 +322,7 @@ class ModelConfig:
             object.__setattr__(
                 self, "router_score",
                 "sigmoid" if self.arch in ("mla_moe", "lfm2", "afmoe",
-                                           "mimo_v2")
+                                           "mimo_v2", "solar_open2")
                 else "softmax",
             )
         if self.router_score not in ("sigmoid", "softmax"):
@@ -472,11 +512,29 @@ class ModelConfig:
                     f"arch 'granite_hybrid': the scan carries ONE group of "
                     f"B and C for all heads (ops/ssm_scan.py); got "
                     f"ssm_groups {self.ssm_groups}")
+        elif self.arch == "solar_open2":
+            kinds = self.layer_types or ()
+            if (len(kinds) != self.n_layers
+                    or set(kinds) != {"kda", "full_attention"}):
+                raise ValueError(
+                    f"arch 'solar_open2' needs layer_types: n_layers "
+                    f"({self.n_layers}) entries of 'kda' / 'full_attention', "
+                    f"at least one of each; got {kinds!r}")
+            if self.linear_heads < 1 or self.conv_kernel < 2:
+                raise ValueError(
+                    "arch 'solar_open2' needs linear_heads and conv_kernel "
+                    ">= 2")
+            if not (self.n_experts and self.moe_ffn_dim
+                    and self.first_k_dense == 0):
+                raise ValueError(
+                    "arch 'solar_open2' needs n_experts, moe_ffn_dim and "
+                    "first_k_dense 0 (every layer routes)")
         elif self.layer_types is not None or self.conv_kernel:
             raise ValueError(
                 "layer_types is arch 'lfm2' / 'afmoe' / 'mimo_v2' / "
-                "'minicpm_sala' / 'granite_hybrid' only, conv_kernel arch "
-                "'lfm2' / 'granite_hybrid' only")
+                "'minicpm_sala' / 'granite_hybrid' / 'solar_open2' only, "
+                "conv_kernel arch 'lfm2' / 'granite_hybrid' / 'solar_open2' "
+                "only")
         if self.arch != "mimo_v2" and (
                 self.window_kv_heads or self.window_sink or self.rotary_dim
                 or self.attn_value_scale != 1.0):
@@ -491,8 +549,12 @@ class ModelConfig:
             raise ValueError(
                 f"rotary_dim ({self.rotary_dim}) must be even and at most "
                 f"head_dim ({self.head_dim})")
-        if self.linear_heads and self.arch != "minicpm_sala":
-            raise ValueError("linear_heads is arch 'minicpm_sala' only")
+        if self.linear_heads and self.arch not in ("minicpm_sala",
+                                                   "solar_open2"):
+            raise ValueError(
+                "linear_heads is arch 'minicpm_sala' / 'solar_open2' only")
+        if self.delta_neg_eigval and self.arch != "solar_open2":
+            raise ValueError("delta_neg_eigval is arch 'solar_open2' only")
         if self.arch != "granite_hybrid" and (
                 self.ssm_heads or self.ssm_head_dim or self.ssm_state
                 or self.conv_bias):
@@ -522,7 +584,7 @@ class ModelConfig:
             raise ValueError("moe_ffn_dim > 0 needs n_experts > 0")
         if self.n_experts:
             if self.arch not in ("llama", "mla_moe", "lfm2", "afmoe",
-                                 "mimo_v2"):
+                                 "mimo_v2", "solar_open2"):
                 raise ValueError("MoE (n_experts > 0) is llama-family only")
             if not 1 <= self.n_experts_per_tok <= self.n_experts:
                 raise ValueError(
@@ -571,27 +633,33 @@ class ModelConfig:
         ALL its states instead (engine/block_prefix.py)."""
         return bool(self.conv_layers) and not self.linear_layers
 
+    def _kept(self, which: int):
+        """What the stack's recurrent kind keeps (STATE_OF_KIND's column
+        `which`), or None where no kind of the stack keeps one."""
+        for kind in dict.fromkeys(self.layer_types or ()):
+            kept = STATE_OF_KIND.get(kind, (None, None))[which]
+            if kept is not None:
+                return kept(self)
+        return None
+
     @property
     def conv_channels(self) -> int:
         """Channels of a kept convolution state (its last conv_kernel - 1
-        inputs): a state-space mixer's [x | B | C], else the model's width."""
-        if self.ssm_heads:
-            return (self.ssm_heads * self.ssm_head_dim
-                    + 2 * self.ssm_groups * self.ssm_state)
-        return self.dim
+        inputs), by the kind that keeps it (STATE_OF_KIND)."""
+        return self._kept(0) or self.dim
 
     @property
     def matrix_state_shape(self) -> tuple:
         """A row's float32 matrix state in one layer, as the pool's leaf
-        holds it: a state-space mixer's [H / pack, N, pack x P]
-        (ops/ssm_scan.state_shape owns that layout), else linear attention's
-        [heads, Dh, Dh]."""
-        if self.ssm_heads:
-            from .ops.ssm_scan import state_shape
+        holds it, by the kind that keeps it (STATE_OF_KIND)."""
+        return self._kept(1) or (
+            self.linear_heads, self.head_dim, self.head_dim)
 
-            return state_shape(self.ssm_heads, self.ssm_head_dim,
-                               self.ssm_state)
-        return (self.linear_heads, self.head_dim, self.head_dim)
+    @property
+    def delta_layers(self) -> tuple:
+        """The layers whose matrix state the delta rule folds chunk by chunk
+        (their index in the stack)."""
+        return self._layers_of(FOLDS_BY_DELTA_RULE)
 
     @property
     def attn_layers(self) -> tuple:

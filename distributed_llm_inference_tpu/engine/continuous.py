@@ -116,13 +116,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.delta_rule import chunks_of
 from ..utils import faults
 from ..utils.logging import get_logger
 from ..utils.metrics import (
     ADMISSION_WAIT_HELP, ATTN_WALK_STEPS_HELP, CHUNK_STEPS_HELP,
     CONV_STATE_RESETS_HELP,
     CONV_TAIL_WRITES_HELP, DECODE_ROW_SECONDS_HELP, DECODE_STEP_HELP,
-    DEFAULT_SIZE_BUCKETS, DEVICE_EMPTY_HELP, DIFFUSION_FORWARDS_HELP,
+    DEFAULT_SIZE_BUCKETS, DELTA_CHUNKS_HELP, DELTA_STATE_ROWS_HELP,
+    DEVICE_EMPTY_HELP, DIFFUSION_FORWARDS_HELP,
     DIFFUSION_FUSED_HELP, DIFFUSION_TOKENS_HELP, KV_GROUP_BLOCK_BYTES_HELP,
     KV_GROUP_BLOCKS_HELP, KV_WINDOW_GIVEN_HELP, KV_WINDOW_RELEASED_HELP,
     LAUNCH_DEVICE_SECONDS_HELP,
@@ -311,12 +313,12 @@ class ContinuousEngine:
         restore_dir: Optional[str] = None,
     ):
         cfg = engine.cfg
-        if cfg.arch not in ("llama", "gpt2", "mla_moe", "lfm2", "afmoe",
-                            "minicpm_sala", "granite_hybrid", "mimo_v2"):
+        from ..models.api import FAMILIES
+
+        if cfg.arch not in FAMILIES:
             raise ValueError(
-                f"continuous batching supports the llama, gpt2, mla_moe, afmoe, "
-                f"lfm2, minicpm_sala, granite_hybrid and mimo_v2 families; "
-                f"model arch is {cfg.arch!r}"
+                f"continuous batching supports the families of models/api.py "
+                f"({', '.join(FAMILIES)}); model arch is {cfg.arch!r}"
             )
         if cfg.recurrent:
             # (before the dense fleet would be built for it)
@@ -1231,6 +1233,14 @@ class ContinuousEngine:
         if cfg.linear_layers:
             for state in ("touched", "held"):
                 self._m_lin_rows.labels(state=state)
+        # delta-rule layers (cfg.delta_layers): the row-steps whose state
+        # the rule's program moved and the chunks their tokens were cut into
+        self._m_delta_rows = m.counter(
+            "dli_delta_state_rows_total", DELTA_STATE_ROWS_HELP, ("phase",),
+        )
+        self._m_delta_chunks = m.counter(
+            "dli_delta_chunks_total", DELTA_CHUNKS_HELP, ("phase",),
+        )
         self._m_steps_ahead = m.histogram(
             "dli_launch_steps_ahead",
             "scheduler steps dispatched and unfetched when a launch was "
@@ -3235,7 +3245,8 @@ class ContinuousEngine:
         return tuple(b * bs for b in (last - 1, last) if b >= low)
 
     def _state_fields(self, spans, state_rows: int, steps: int = 1,
-                      restored: int = 0, resets: int = 0, fresh: int = 0):
+                      restored: int = 0, resets: int = 0, fresh: int = 0,
+                      placed=None):
         """The launch record's fields of a fleet with recurrent layers, by
         the host position model. spans: (first position, tokens) of every
         live row of the launch; restored / resets: what the tenants whose
@@ -3247,7 +3258,11 @@ class ContinuousEngine:
         `state_fresh_rows`, the `fresh` rows that start from zeros or a
         snapshot in this launch, and, unless `_sparse_fields` counts them,
         `state_rows`, the row-steps that read and write a state (a decode
-        row a step, a prefill chunk once) of the slots x `steps` held."""
+        row a step, a prefill chunk once) of the slots x `steps` held. Where
+        layers fold their state by the delta rule (cfg.delta_layers):
+        `delta_chunks`, the chunks of the flat axis those rows' tokens were
+        cut into, from `placed`, (flat place on the model's axis, tokens) of
+        each (None: a decode chunk, a token and so a chunk a row-step)."""
         fields = {}
         if self.cfg.state_tails:
             bs = self.kv_block_size
@@ -3263,6 +3278,13 @@ class ContinuousEngine:
                 self._m_lin_rows.labels(state="held").inc(
                     self.n_slots * steps)
                 fields["state_rows"] = int(state_rows)
+        if self.cfg.delta_layers:
+            phase = "chunk" if placed is None else "mixed"
+            chunks = int(state_rows) if placed is None else sum(
+                chunks_of(at, n) for at, n in placed)
+            self._m_delta_rows.labels(phase=phase).inc(state_rows)
+            self._m_delta_chunks.labels(phase=phase).inc(chunks)
+            fields["delta_chunks"] = chunks
         return fields
 
     def _launch_record(self, phase: str, steps: int, kv_tokens: int,
@@ -4409,7 +4431,7 @@ class ContinuousEngine:
                     (b, int(self._host_pos[b]), 1, P.RAGGED_DECODE)
                 )
         chunk_list = []
-        snaps_taken = 0
+        snaps_taken = snaps_restored = 0
         if self._snap_pool:
             # (by slot: the snapshot a row starts from, and the one it leaves)
             snap_restore = np.full((B,), -1, np.int32)
@@ -4433,6 +4455,7 @@ class ContinuousEngine:
                 if first and job.snap_from >= 0:
                     snap_restore[job.slot] = job.snap_from
                     self._bpx.snap_unpin(job.snap_from)
+                    snaps_restored += 1
             entries.append((job.slot, start, n, kind))
             chunk_list.append((job, n, start))
         meta, tok_row, tok_pos, offsets, stats = P.build_ragged_meta(
@@ -4622,14 +4645,22 @@ class ContinuousEngine:
             touched = [(int(self._host_pos[b]), 1) for b in active
                        if self._host_pos[b] < self._host_end[b]] \
                 + [(st, n) for _, n, st in chunk_list]
+            # where each of them lies on the axis the model runs on: its
+            # tile's place, or under a packed axis the live tokens before it
+            at = offsets if self._live_width >= W else np.cumsum(
+                [0] + [n for _, _, n, _ in entries[:-1]])
+            placed = [(int(a), n)
+                      for i, ((b, _, n, _), a) in enumerate(zip(entries, at))
+                      if i >= n_dec or self._host_pos[b] < self._host_end[b]]
             diff_fields = self._state_fields(
                 touched, len(touched),
                 restored=sum(st for _, st in firsts),
                 resets=sum(1 for _, st in firsts if st == 0),
-                fresh=len(firsts),
+                fresh=len(firsts), placed=placed,
             )
             if self._snap_pool:
                 diff_fields["state_snapshots_taken"] = snaps_taken
+                diff_fields["state_snapshots_restored"] = snaps_restored
         if self._sparse is not None:
             diff_fields.update(self._sparse_fields(
                 "mixed", [int(self._host_pos[b]) + 1 for b in active

@@ -185,8 +185,7 @@ class SingleDeviceBackend:
     # routes through llama.default_attn_hook since round 5).
     @property
     def supports_paged(self):
-        return self.cfg.arch in ("llama", "gpt2", "mla_moe", "lfm2", "afmoe",
-                                 "minicpm_sala", "granite_hybrid", "mimo_v2")
+        return self.cfg.arch in M.FAMILIES  # every family's hook is paged
 
     def init_paged_pool(self, n_blocks, block_size, n_slots=None,
                         **snapshots):

@@ -86,6 +86,16 @@ TRASH_BLOCK = 0  # reserved pool block: write-only spill for table tails
 GROUP_LEAVES = (("k", "v"), ("kw", "vw"))
 
 
+def _routed_counts(cfg: ModelConfig):
+    """The zeroed "routed" leaf of a family that may hold a share
+    (models/afmoe.add_routed): the experts held here, with one more column
+    under a share, the pairs routed elsewhere."""
+    share = cfg.experts_held < cfg.n_experts
+    return jnp.zeros(
+        (2, cfg.n_layers - cfg.first_k_dense, cfg.experts_held + share),
+        jnp.int32)
+
+
 def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
               n_layers: Optional[int] = None, n_slots: Optional[int] = None,
               n_snapshots: int = 0):
@@ -150,7 +160,11 @@ def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
     "snap", the same two by snapshot: ONE snapshot index names both states
     of every layer at one block boundary, so a prefix hit restores the
     convolution state with the matrix state (no tail a block: at 36 layers
-    a snapshot is 76 MB)."""
+    a snapshot is 76 MB). A model of delta-rule and attention layers over
+    routed experts (models/solar_open2.py) keeps the same leaves by what its
+    "kda" kind keeps (config.STATE_OF_KIND: the three convolutions' inputs
+    side by side, a [heads, Dv, Dk] state) and the "routed" counts beside
+    them."""
     if cfg.linear_layers:
         if n_slots is None:
             raise ValueError(f"{cfg.name}: the pool holds a state a slot "
@@ -181,6 +195,8 @@ def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
         state = cfg.matrix_state_shape
         pool["lin"] = a_layer(cfg.linear_layers, n_slots, state, jnp.float32)
         pool["snap"] = a_layer(cfg.linear_layers, snaps, state, jnp.float32)
+        if cfg.moe_ffn_dim:  # routed layers over the mixers: the same counts
+            pool["routed"] = _routed_counts(cfg)
         return pool
     if cfg.kinds_of_attention:
         # K/V in groups by layer kind, each with its own blocks and block
@@ -197,10 +213,7 @@ def init_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
                     cfg.group_kv_heads(group), block_size)
             pool[kn] = jnp.zeros(rows + (cfg.key_row,), cfg.jnp_dtype)
             pool[vn] = jnp.zeros(rows + (cfg.value_dim,), cfg.jnp_dtype)
-        share = cfg.experts_held < cfg.n_experts
-        pool["routed"] = jnp.zeros(
-            (2, cfg.n_layers - cfg.first_k_dense, cfg.experts_held + share),
-            jnp.int32)
+        pool["routed"] = _routed_counts(cfg)
         return pool
     if cfg.state_tails:
         if n_slots is None:

@@ -136,17 +136,18 @@ def _normal_keyed(key, ids, *, shape, scale, dtype):
 
 
 def draw_params(cfg: ModelConfig, key: jax.Array, shapes: dict,
-                leaf_keys: dict, float32: tuple = ()) -> Params:
+                leaf_keys: dict, float32: tuple = (),
+                n_keys: int = 24) -> Params:
     """The seeded tree of `shapes` ({leaf path: (shape, scale or None for
     ones)}; "kind.name" a leaf of layers[kind], a bare name the tree's own
     where it is embed / head / final_norm, else layers'): the expert banks
     ("moe." + BANKS) and the two vocabulary tables by published index
     (`_normal_keyed`, the module docstring), the held ones alone; the other
     leaves slice by slice (models/experts._normal_slices), key
-    split(key, 24)[leaf_keys[path]] each, the `float32` paths in float32.
+    split(key, n_keys)[leaf_keys[path]] each, the `float32` paths in float32.
     One draw for the families that hold a share (config.HOLDS_EXPERT_SHARE)."""
     dt = cfg.jnp_dtype
-    ks = jax.random.split(key, 24)
+    ks = jax.random.split(key, n_keys)
     layers: Params = {}
     params: Params = {"layers": layers}
     E, Eh = cfg.n_experts, cfg.experts_held
@@ -239,8 +240,9 @@ def attention(cfg: ModelConfig, lp: Params, h, cache_k, cache_v, pos, rope,
     k = k.reshape(B, T, KV, Dh)
     v = v.reshape(B, T, KV, Dh)
     gate = jax.nn.sigmoid(jnp.dot(h, lp["wg"], preferred_element_type=F32))
-    q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
-    k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+    if "q_norm" in lp:  # (models/solar_open2.py's gated layer has none)
+        q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
     if rope is not None:
         q, k = apply_rope(q, k, *rope)
     attn, new_k, new_v = hook(

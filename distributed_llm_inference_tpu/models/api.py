@@ -27,7 +27,15 @@ layers that have a learned sink in their softmax with global layers, the two
 kinds with their own K/V head counts and rotation bases and keys wider than
 values, over routed experts with no shared one, holds one chip's share of
 them where the configuration says so, and serves one device from a pool
-grouped by layer kind whose groups' rows are their own kinds'.
+grouped by layer kind whose groups' rows are their own kinds'; solar_open2
+(models/solar_open2.py) alternates gated delta-rule layers (Kimi Delta
+Attention: a convolution on each of q, k and v, a float32 matrix state with a
+decay a key channel and beta up to 2, ops/delta_rule.py), which keep the
+convolutions' state AND the matrix state a row, with gated attention layers
+that take no position encoding, every layer over routed experts beside a
+shared one, holds one chip's share of them where the configuration says so,
+and serves one device from the paged pool only, with the state leaves and
+the snapshot pool granite_hybrid's by what the layer kind keeps.
 Routed experts
 are one module for the families that have them (models/experts.py: `route`, `routed_ffn`, the
 grouped product): a configuration that routes serves one device, from the
@@ -38,11 +46,13 @@ from __future__ import annotations
 
 from ..config import ModelConfig
 from . import (afmoe, gpt2, granite_hybrid, lfm2, llama, mimo_v2,
-               minicpm_sala, mla_moe)
+               minicpm_sala, mla_moe, solar_open2)
 
 _FAMILIES = {"llama": llama, "gpt2": gpt2, "mla_moe": mla_moe, "lfm2": lfm2,
              "afmoe": afmoe, "minicpm_sala": minicpm_sala,
-             "granite_hybrid": granite_hybrid, "mimo_v2": mimo_v2}
+             "granite_hybrid": granite_hybrid, "mimo_v2": mimo_v2,
+             "solar_open2": solar_open2}
+FAMILIES = tuple(_FAMILIES)  # the arch names, for the engines' start-up checks
 
 
 def family(cfg: ModelConfig):

@@ -80,6 +80,11 @@ def _group_tiling(m: int, k: int, n: int, itemsize: int) -> tuple:
     tk, tn = k, n
     while tk * tn * itemsize > 13 * 2**18 and tk % 256 == 0:
         tk //= 2
+    # (an inner width that stops halving above the limit, 1,280 -> 640: the
+    # columns take the rest, or two buffers of the tile beside the float32
+    # result's pass the scoped VMEM)
+    while tk * tn * itemsize > 13 * 2**18 and tn % 256 == 0:
+        tn //= 2
     return tm, tk, tn
 
 

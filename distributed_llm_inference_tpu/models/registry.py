@@ -286,6 +286,29 @@ register(ModelConfig(
     attn_scale_override=0.015625, logits_divider=8.0, tie_embeddings=True,
     eos_token_id=2, bos_token_id=1, pad_token_id=0,
 ))
+# --- Solar-Open2-250B (gated delta-rule layers 3:1 beside gated attention
+# without a position encoding, every layer over 320 routed experts and a
+# shared one; upstage/Solar-Open2-250B config.json, model_type solar_open2,
+# 250B-A15B: models/solar_open2.py). gqa_layers 0, 4, ..., 44 as published;
+# linear_attn_config: 64 heads of 128, 4 taps, kda_use_full_proj false,
+# kda_allow_neg_eigval true. use_rope false. Not in config.json and so
+# assumed (cellbench/configs/solar-open2-ep8-4l.json lists each): the
+# low-rank width of the decay and gate projections (head_dim), A_log and
+# dt_bias drawn as Mamba-2's, the values' head width, the float32 state, the
+# gate's granularity, no q/k norm, the sigmoid router with a selection bias,
+# the pre-norm order, the special tokens.
+SOLAR_OPEN2_LAYERS = tuple(
+    "full_attention" if i % 4 == 0 else "kda" for i in range(48))
+register(ModelConfig(
+    name="solar-open2-250b", arch="solar_open2", vocab_size=196608, dim=4096,
+    n_layers=48, n_heads=64, n_kv_heads=8, ffn_dim=10240,
+    max_seq_len=1048576, norm_eps=1e-5, head_dim_override=128,
+    layer_types=SOLAR_OPEN2_LAYERS, linear_heads=64, conv_kernel=4,
+    delta_neg_eigval=True,
+    n_experts=320, n_experts_per_tok=8, moe_ffn_dim=1280, n_shared_experts=1,
+    first_k_dense=0, moe_renormalize=True, routed_scaling=1.0,
+    eos_token_id=2, bos_token_id=1, pad_token_id=0,
+))
 register(ModelConfig(
     name="qwen3-8b", arch="llama", vocab_size=151936, dim=4096,
     n_layers=36, n_heads=32, n_kv_heads=8, ffn_dim=12288, max_seq_len=40960,
@@ -504,6 +527,19 @@ register(ModelConfig(
                  "full_attention"),
     n_experts=8, n_experts_per_tok=2, moe_ffn_dim=32, first_k_dense=1,
     moe_renormalize=True, routed_scaling=1.0, router_norm_eps=1e-20,
+    eos_token_id=2, bos_token_id=1,
+))
+# (the published head width, 128 for keys and values of both kinds of layer,
+# so that float32 blocks of 8 take the paged kernels' in-place write; one
+# whole period, gated attention first)
+register(ModelConfig(
+    name="test-solar-tiny", arch="solar_open2", vocab_size=256, dim=64,
+    n_layers=4, n_heads=2, n_kv_heads=1, ffn_dim=96, max_seq_len=256,
+    norm_eps=1e-5, head_dim_override=128,
+    layer_types=("full_attention", "kda", "kda", "kda"), linear_heads=2,
+    conv_kernel=4, delta_neg_eigval=True,
+    n_experts=8, n_experts_per_tok=2, moe_ffn_dim=32, n_shared_experts=1,
+    first_k_dense=0, moe_renormalize=True, routed_scaling=1.0,
     eos_token_id=2, bos_token_id=1,
 ))
 register(ModelConfig(

@@ -160,6 +160,18 @@ SSM_STATE_RESETS_HELP = (
     "no prefix hit restored a snapshot), whatever the previous tenant left "
     "in the slot"
 )
+# fleets of a model with delta-rule layers (ModelConfig.delta_layers,
+# models/solar_open2.py)
+DELTA_STATE_ROWS_HELP = (
+    "row-steps whose float32 matrix state the delta rule's program read and "
+    "wrote (the launch records' state_rows), by launch phase, a layer"
+)
+DELTA_CHUNKS_HELP = (
+    "chunks of the flat token axis those row-steps' tokens were cut into "
+    "(ops/delta_rule.CHUNK places each; the launch records' delta_chunks), "
+    "by launch phase, a layer: chunks / rows is how many chunks a state's "
+    "trip through the program serves (1 in decode)"
+)
 SPARSE_SCORED_KEYS_HELP = (
     "compressed keys the sparse layers' selection scored, a layer and KV "
     "head: each row of a launch's once a step, up to the row's length (the "

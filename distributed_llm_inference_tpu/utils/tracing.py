@@ -256,12 +256,14 @@ WAIT_PHASES = ("wait_work", "fetch_wait")
 # from its input norm to its output projection, and a residual add or a
 # norm between two blocks belongs to the block it feeds. `mla_absorb` and
 # `sparse_select` nest under `attn`, `linear_scan` under `linear_attn`,
-# `ssm_scan` under `ssm_mix`. A device trace is read by these names
+# `ssm_scan` under `ssm_mix`, `delta_conv` and `delta_scan` under `delta_mix`.
+# A device trace is read by these names
 # (`scope_map`).
 STEP_SCOPES = (
     "embed", "attn", "mla_absorb", "ffn", "moe_route", "moe_dispatch",
     "moe_experts", "moe_combine", "moe_shared", "conv_mix", "linear_attn",
     "linear_scan", "sparse_select", "ssm_mix", "ssm_scan", "head", "sample",
+    "delta_mix", "delta_conv", "delta_scan",
 )
 PROGRAM_SCOPES_FILE = "program_scopes.json"
 

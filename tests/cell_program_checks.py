@@ -92,6 +92,14 @@ CELLS = {
     "mimo-v2.5-7l": dict(
         width=768, live=512, blocks=(2304, 193), leaf=("kw", (5, 193, 8, 128, 256)),
         scopes=DENSE_SCOPES + ROUTED_SCOPES),
+    # (K/V belongs to 1 of the 4 layers; the 3 KDA layers' convolution and
+    # matrix states and their snapshots are a leaf a layer; every layer
+    # routes: no dense `ffn`; 16 decode tiles are under a third of the routed
+    # 512, so no token is packed)
+    "solar-open2-ep8-4l": dict(
+        width=512, blocks=8192, leaf=("k", (1, 8192, 8, 128, 128)),
+        scopes=("embed", "attn", "head", "sample") + ROUTED_SCOPES
+        + ("moe_shared", "delta_mix", "delta_conv", "delta_scan")),
 }
 
 
@@ -160,6 +168,19 @@ def test_the_programs_are_the_cells(one_chip, no_persistent_cache, config):
         assert all(a.dtype == "float32" for a in built.pool["lin"] + built.pool["snap"])
         assert built.pool["conv"][0].shape == (64, 3, 4352)
         assert built.pool["csnap"][0].shape == (16, 3, 4352)
+    if config.startswith("solar"):
+        assert (len(cfg.attn_layers), len(cfg.linear_layers), cfg.kv_pack) == (1, 3, 1)
+        assert cfg.conv_layers == cfg.linear_layers == cfg.delta_layers
+        # (float32 is the configuration's STATED state: this, the dtype in
+        # tests/test_chip_compile.py and tests/test_solar_ops.py's 2e-4 bound
+        # hold it, whatever `correct` can tell)
+        assert built.pool["lin"][0].shape == (16, 64, 128, 128)
+        assert built.pool["snap"][0].shape == (96, 64, 128, 128)
+        assert all(a.dtype == "float32" for a in built.pool["lin"] + built.pool["snap"])
+        assert built.pool["conv"][0].shape == (16, 3, 24576)
+        assert built.pool["csnap"][0].shape == (96, 3, 24576)
+        assert all(a.dtype == "bfloat16" for a in built.pool["conv"] + built.pool["csnap"])
+        assert built.pool["routed"].shape == (2, 4, 41)
     if cfg.diffusion_block:
         # the decode chunk's flat axis: 32 slots x 2 blocks of 4 = 256 tokens
         assert "bf16[256,2048]" in built.texts["decode_slots_paged"]
@@ -251,7 +272,8 @@ def test_the_linear_scan_takes_its_leaf_in_place(one_chip, no_persistent_cache,
                                                  config):
     """Both step programs of a configuration whose layers keep a matrix
     state run the scan's kernel once such a layer, under its scope's name
-    (what `linear_attn_roofline` / `ssm_scan_roofline` find it by), with the
+    (what `linear_attn_roofline` / `ssm_scan_roofline` / `delta_scan_roofline`
+    find it by), with the
     layer's `lin` leaf as the call's aliased output; no `copy` makes a
     buffer of a state leaf's or the snapshot pool's shape (the test above
     holds the leaves among the program's aliased arguments), and no
@@ -265,9 +287,12 @@ def test_the_linear_scan_takes_its_leaf_in_place(one_chip, no_persistent_cache,
     lin, snap = built.pool["lin"][0], built.pool["snap"][0]
     shapes = {",".join(map(str, leaf.shape)) for leaf in (lin, snap)}
     # (operands: the prefetched scalars, the tokens' blocks, the decay's
-    # power, the state: ops/linear_attention.linear_scan, ops/ssm_scan.ssm_scan)
+    # power, the state: ops/linear_attention.linear_scan, ops/ssm_scan.ssm_scan,
+    # ops/delta_rule.delta_state)
     kernel, scope, alias = ("linear_scan", "linear_attn/linear_scan", 8) \
         if built.cfg.sparse_layers else ("ssm_scan", "ssm_mix/ssm_scan", 9)
+    if built.cfg.delta_layers:
+        kernel, scope, alias = "delta_state", "delta_mix/delta_scan", 11
     for name, text in built.texts.items():
         calls = [line for line in text.splitlines()
                  if re.search(rf"%{kernel}[\w.\-]* = .*custom-call\(", line)]
@@ -277,7 +302,7 @@ def test_the_linear_scan_takes_its_leaf_in_place(one_chip, no_persistent_cache,
             assert scope in line, line
         copies = re.findall(r"= f32\[([\d,]+)\]\{[^}]*\} copy\(", text)
         assert not shapes & set(copies), (name, shapes & set(copies))
-        if kernel == "ssm_scan":
+        if kernel != "linear_scan":
             made = set(re.findall(
                 rf"= f32\[(?:{'|'.join(shapes)})\]\{{[^}}]*\}} ([\w\-]+)\(", text))
             assert made <= {"parameter", "get-tuple-element", "bitcast", "tuple",

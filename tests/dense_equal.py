@@ -296,6 +296,7 @@ CELL_FILES = {
     "test_cell_programs_sala": ("minicpm-sala-9b-16l",),
     "test_cell_programs_sdar_trinity": ("sdar-30b-a3b-7l", "trinity-large-ep8-5l"),
     "test_cell_programs_mimo": ("mimo-v2.5-7l",),
+    "test_cell_programs_solar": ("solar-open2-ep8-4l",),
 }
 CELL_CONFIGS = tuple(sorted(sum(CELL_FILES.values(), ())))
 

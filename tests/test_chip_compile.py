@@ -489,3 +489,76 @@ def test_the_selection_compiles_at_the_sala_cells_shapes(
                       r"([\w\-]+)\(", text)
     assert set(made) <= {"parameter", "get-tuple-element", "bitcast"}, made
     assert compiled.memory_analysis().temp_size_in_bytes < 2**22
+
+
+# solar-open2-ep8-4l's KDA layers: the delta rule at the cell's shapes (64
+# heads of 128 x 128, a [16, 64, 128, 128] float32 leaf a layer, 4.19 MB a
+# row): the mixed launch's 512 flat tokens in the tile layout (8 chunks of the
+# rule's 64) and the decode chunk's one token a row. The state leaf goes in and
+# comes out as one buffer, and the call is named `delta_state` under its
+# scope: what `delta_scan_roofline` finds it by. float32 is the
+# configuration's STATED state: the leaf's dtype here and in
+# tests/cell_program_checks.py, and tests/test_solar_ops.py's 2e-4 bound,
+# hold it whatever `correct` can tell.
+@pytest.mark.parametrize("flat,tq", [(512, 8), (16, 1)], ids=["mixed", "decode"])
+def test_the_delta_rule_compiles_at_the_solar_cells_shapes(
+    one_chip, no_persistent_cache, flat, tq
+):
+    from distributed_llm_inference_tpu.ops.delta_rule import (CHUNK,
+                                                              delta_rule_rows)
+
+    cfg, slots, _, pool = cell_pool("solar-open2-ep8-4l")
+    S = _spec(one_chip)
+    lin = pool["lin"][0]
+    assert (slots, step_width(cfg, slots, 8), live_width(cfg, slots, 8)) == (
+        16, 512, 512)
+    assert (lin.shape, lin.dtype) == ((16, 64, 128, 128), jnp.float32)
+    assert len(pool["lin"]) == len(cfg.delta_layers) == 3 and 512 % CHUNK == 0
+    H, Dh = cfg.linear_heads, cfg.head_dim
+    compiled = jax.jit(
+        lambda q, k, v, g, beta, state, tok_row, zero: delta_rule_rows(
+            q, k, v, g, beta, state, tok_row, tq, zero=zero, interpret=False),
+        donate_argnums=(5,),
+    ).lower(*(S((flat, H, Dh), jnp.float32),) * 4, S((flat, H), jnp.float32),
+            S(lin.shape, lin.dtype), S((flat,), jnp.int32),
+            S((slots,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert any("delta_state" in c for c in _custom_call_names(text))
+    assert "delta_scan/jit(delta_state)" in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == lin.size * 4, memory
+    # the chunk algebra's operands over the launch's tokens (and, while the
+    # pair products' decays are written out once a launch, 268 MB of them:
+    # PERF.md section 7), nothing a decode step could notice
+    assert memory.temp_size_in_bytes < (450e6 if flat == 512 else 8e6), memory
+    made = re.findall(r"= f32\[16,64,128,128\]\{[^}]*\} ([\w\-]+)\(", text)
+    assert set(made) <= {"parameter", "get-tuple-element", "bitcast",
+                         "custom-call"}, made
+
+
+@pytest.mark.parametrize("pairs", [128, 4096])
+def test_routed_expert_matmul_compiles_at_the_solar_cells_widths(
+    one_chip, no_persistent_cache, monkeypatch, pairs
+):
+    """A decode chunk's 16 rows x 8 and a mixed step's 512 tokens x 8, over
+    the four layers' stacked bank of 40 held experts of 4,096 x 1,280: an
+    inner width that halves once (1,280 -> 640) and no further, where
+    `_group_tiling` then cuts the columns so that two buffers of a tile stay
+    inside the scoped VMEM (16.31 MB of 16 before it did: this PR's first
+    described-chip compile)."""
+    from distributed_llm_inference_tpu.models import experts as X
+
+    monkeypatch.setenv("DLI_PALLAS_INTERPRET", "0")
+    S = _spec(one_chip)
+    assert X._group_tiling(4096, 1280, 4096, 2) == (128, 640, 2048)
+    assert X._group_tiling(4096, 4096, 1280, 2) == (128, 1024, 1280)
+    # (the accepted widths keep the tiles they had)
+    assert X._group_tiling(3072, 2048, 768, 2) == (128, 2048, 768)
+    assert X._group_tiling(2048, 3072, 3072, 2) == (128, 384, 3072)
+    assert X._group_tiling(4096, 2048, 4096, 2) == (128, 256, 4096)
+    for k, n in ((4096, 1280), (1280, 4096)):
+        text = _compile(
+            X.grouped_matmul, S((pairs, k), jnp.bfloat16),
+            S((4, 40, k, n), jnp.bfloat16), S((40,), jnp.int32),
+            S((), jnp.int32))
+        assert any("routed_expert_matmul" in c for c in _custom_call_names(text))

@@ -40,10 +40,14 @@ FAMILIES = {
     "test-sala-tiny": BLOCKS | {"ffn", "linear_attn", "linear_scan",
                                 "sparse_select"},
     "test-granite-tiny": BLOCKS | {"ffn", "ssm_mix", "ssm_scan"},
+    # (every layer routes: no dense `ffn`)
+    "test-solar-tiny": BLOCKS | ROUTED | {"moe_shared", "delta_mix",
+                                          "delta_conv", "delta_scan"},
 }
 # a label that only ever nests in another
 NESTED = {"mla_absorb": "attn", "sparse_select": "attn",
-          "linear_scan": "linear_attn", "ssm_scan": "ssm_mix"}
+          "linear_scan": "linear_attn", "ssm_scan": "ssm_mix",
+          "delta_conv": "delta_mix", "delta_scan": "delta_mix"}
 
 
 def test_the_vocabulary_names_every_scope_once():

@@ -168,6 +168,7 @@ BENCHMARK_WIDTHS = {
     "kanana-2-30b-a3b-7l": (8, 512, 512),
     "lfm2-24b-a2b-9l": (16, 512, 512),
     "trinity-large-ep8-5l": (16, 512, 512),
+    "solar-open2-ep8-4l": (16, 512, 512),
     # the selection reads the launch's tiles; a decode row's tile is its blocks
     "minicpm-sala-9b-16l": (16, 136, 136),
     "sdar-30b-a3b-7l": (32, 512, 512),
