@@ -72,7 +72,7 @@ import jax.numpy as jnp
 
 from ..config import ModelConfig
 from ..ops.attention import slot_causal_mask
-from ..ops.delta_rule import delta_rule_rows
+from ..ops.delta_rule import delta_rule_rows, delta_rule_step
 from ..ops.norms import rms_norm
 from ..ops.ssm_scan import causal_conv_rows
 from .afmoe import add_routed, attention, draw_params, moe_ffn
@@ -199,7 +199,10 @@ def kda_mixer(cfg: ModelConfig, lp: Params, h, pool, layer: int, rows,
     """The "kda" mixer over a paged launch's flat tokens (normed h
     [W, 1, D]); `layer` the layer's index among the kda layers. A row with
     rows.take >= 0 leaves BOTH its states after this launch in that
-    snapshot. Returns (float32 [W, 1, D], pool)."""
+    snapshot. The delta rule's form follows the launch's shape (below): the
+    decode program's one token a row is the recurrence itself, any other
+    launch the chunked form; both carry the one `lin` leaf.
+    Returns (float32 [W, 1, D], pool)."""
     W = h.shape[0]
     H, Dh, dt_ = cfg.linear_heads, cfg.head_dim, cfg.jnp_dtype
     Hd = H * Dh
@@ -230,9 +233,16 @@ def kda_mixer(cfg: ModelConfig, lp: Params, h, pool, layer: int, rows,
         + lp["dt_bias"][None, :]).reshape(W, H, Dh)
     decay = -jnp.exp(lp["a_log"])[None, :, None] * decay
     beta = (2.0 if cfg.delta_neg_eigval else 1.0) * jax.nn.sigmoid(b)
-    # (a decode step is the same call: one token a row)
-    o, lin = delta_rule_rows(q, k, v, decay, beta, lin, rows.tok_row, tq,
-                             zero=zero, impl=cfg.attn_impl)
+    if (rows.restore is None and rows.take is None and tq == 1
+            and W == lin.shape[0]):
+        # the decode program's launch (engine/paged.make_paged_hook: flat
+        # place i is fleet row i's one token, no tenant starts, no snapshot
+        # is kept): the recurrence itself
+        o, lin = delta_rule_step(q, k, v, decay, beta, lin, rows.tok_row,
+                                 impl=cfg.attn_impl)
+    else:
+        o, lin = delta_rule_rows(q, k, v, decay, beta, lin, rows.tok_row, tq,
+                                 zero=zero, impl=cfg.attn_impl)
     if rows.take is not None:  # both states after the launch, by snapshot
         snap = _move_rows(snap, lin, rows.take >= 0, rows.take, slot)
         at = jnp.where(rows.take >= 0, rows.take, csnap.shape[0])  # dropped

@@ -45,9 +45,24 @@ Across chunks and launches the carried state goes through ONE Pallas program
 ops/ssm_scan.py's does: a row's state comes into VMEM once, the row's chunks
 are read against it and folded into it one after the other, and it goes back
 to where it came from (the state leaf is the program's aliased output: a row
-with no token costs no byte). A decode step (one token a row) is the same
-call. impl "xla" is the same sums chunk by chunk in XLA, over every row's
-state (the twin the CPU tests hold the program against; no serving path).
+with no token costs no byte). impl "xla" is the same sums chunk by chunk in
+XLA, over every row's state (the twin the CPU tests hold the program
+against; no serving path).
+
+A launch that gives every fleet row AT MOST ONE TOKEN by construction (the
+decode program: flat place i is fleet row i's) is `delta_rule_step`: the
+recurrence itself, of which every piece of the chunked form is the identity
+(A = 0, T = beta, G = g), with the state stored transposed:
+
+    Sd = S * exp(g)     r = sum_d Sd[:, d] k[d]     u = beta (v - r)
+    S' = Sd + u k^T     o = sum_d S'[:, d] q[d]
+
+in one Pallas program (`_step_kernel`) over (head group, the rows that carry
+a token) with the same row list and aliased leaf, float32 on the vector
+unit: a lane scaling, two lane reductions and an outer product a head, no
+chunk, no cumulative sum, no solve and no matrix unit. Both forms read and
+write the same leaf, so a row goes from one to the other at every hand-over
+between a mixed launch and a decode chunk.
 
 THE STATE'S LAYOUT. The leaf holds a head's state TRANSPOSED, [R, H, Dv, Dk]:
 the key channels on the lanes, so that a channel's decay scales a lane and
@@ -78,6 +93,9 @@ SUB = 16
 # VMEM the program's blocks may take, second buffers included: inside the
 # default scoped limit (16 MiB on v5e)
 _STATE_VMEM_BYTES = 10 * 2**20
+# the most heads a program of the one-token form holds (its body is written
+# out a head: a head's v and o are lane slices of a transposed tile)
+_STEP_HEADS = 16
 
 
 def chunks_of(first: int, count: int) -> int:
@@ -101,6 +119,16 @@ def _state_heads(H: int, Wp: int, Dk: int, Dv: int) -> int:
         if H % Hg == 0 and 2 * Hg * head <= _STATE_VMEM_BYTES:
             return Hg
     return 1
+
+
+def _touched_first(touched):
+    """(rows [R], n): the n rows that carry a token first, in order; every
+    place past them names the last of them, whose blocks then neither move
+    nor change."""
+    R = touched.shape[0]
+    n = jnp.sum(touched.astype(jnp.int32))
+    order = jnp.argsort(~touched, stable=True).astype(jnp.int32)
+    return order[jnp.minimum(jnp.arange(R), jnp.maximum(n - 1, 0))], n
 
 
 def _state_kernel(rows_ref, n_ref, first_ref, count_ref, zero_ref, w_ref,
@@ -182,12 +210,7 @@ def delta_state(w, u, qg, kend, dend, aqk, state, first, count, zero, *,
     Dv = u.shape[2]
     R = state.shape[0]
     Hg = _state_heads(H, Wp, Dk, Dv)
-    # the rows that carry a token first, in order; every place past them
-    # names the last of them, whose blocks then neither move nor change
-    touched = count > 0
-    n = jnp.sum(touched.astype(jnp.int32))
-    order = jnp.argsort(~touched, stable=True).astype(jnp.int32)
-    rows = order[jnp.minimum(jnp.arange(R), jnp.maximum(n - 1, 0))]
+    rows, n = _touched_first(count > 0)
 
     def tokens(width):
         return pl.BlockSpec((Hg, Wp, width), lambda g, j, *refs: (g, 0, 0))
@@ -370,3 +393,119 @@ def delta_rule_rows(q, k, v, g, beta, state, tok_row, tq: int, zero=None,
             interpret=resolve_interpret(interpret))
     o = o[:, :W].transpose(1, 0, 2)
     return jnp.where(live[:W, None, None], o, 0.0), state
+
+
+def _step_heads(H: int, Dk: int, Dv: int) -> int:
+    """Heads a program of the one-token form holds: the most (up to
+    `_STEP_HEADS`) that tile a block's second-last axis and whose state
+    blocks in and out, two buffers each, fit `_STATE_VMEM_BYTES`."""
+    for Hg in range(min(H, _STEP_HEADS), 0, -1):
+        if H % Hg == 0 and (Hg % 8 == 0 or Hg == H) \
+                and 4 * Hg * Dv * _lanes(Dk) * 4 <= _STATE_VMEM_BYTES:
+            return Hg
+    return H
+
+
+def _step_kernel(rows_ref, n_ref, q_ref, k_ref, v_ref, g_ref, beta_ref,
+                 s_in_ref, o_ref, s_out_ref, v_rows, o_cols, *, Hg: int):
+    """One program per (head group, place j): the j-th row that carries a
+    token (`_state_kernel`'s row list). q, k, g [1, Hg, Dk], v [1, Hg, Dv],
+    beta [1, Hg, 1] float32: the row's one token; the row's state [1, Hg, Dv,
+    Dk] in and out; o [1, Hg, Dv]. The recurrence of the module docstring a
+    head, on the vector unit: r, u and o come out of their lane reductions
+    as COLUMNS [Dv, 1], so v goes in and o comes out through one transposed
+    tile a program (v_rows [T, Dv], o_cols [Dv, T])."""
+    j = pl.program_id(1)
+    n = n_ref[0]
+
+    @pl.when((j == 0) & (n == 0))
+    def _():  # no token at all: the block this program holds goes back
+        s_out_ref[...] = s_in_ref[...]
+
+    @pl.when(j < n)
+    def _():
+        v_rows[0:Hg, :] = v_ref[0]
+        v_cols = v_rows[...].T  # [Dv, T]: head h's v down column h
+        for h in range(Hg):
+            at = slice(h, h + 1)
+            k = k_ref[0, at, :]  # [1, Dk]
+            Sd = s_in_ref[0, h] * jnp.exp(g_ref[0, at, :])  # [Dv, Dk]
+            u = beta_ref[0, at, :] * (
+                v_cols[:, at] - jnp.sum(Sd * k, axis=1, keepdims=True))
+            S = Sd + u * k
+            s_out_ref[0, h] = S
+            o_cols[:, at] = jnp.sum(S * q_ref[0, at, :], axis=1,
+                                    keepdims=True)
+        o_ref[0] = o_cols[...].T[0:Hg, :]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def delta_step(q, k, v, g, beta, state, live, *, interpret):
+    """The pallas_call of the one-token form: q, k, g [R, H, Dk], v [R, H,
+    Dv], beta [R, H, 1] float32 (place i is fleet row i's), state [R, H, Dv,
+    Dk] float32 (donate it: the output's buffer), live [R] bool the rows
+    that carry a token. Returns (o [R, H, Dv], a dead row's undefined; the
+    state after). Jitted, so that a stack's layers trace and lower ONE
+    kernel a step program."""
+    R, H, Dk = q.shape
+    Dv = v.shape[2]
+    Hg = _step_heads(H, Dk, Dv)
+    rows, n = _touched_first(live)
+
+    def of_row(*tail):
+        return pl.BlockSpec(
+            (1, Hg) + tail,
+            lambda g, j, rows, n: (rows[j], g) + (0,) * len(tail))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(H // Hg, R),
+        in_specs=[of_row(Dk), of_row(Dk), of_row(Dv), of_row(Dk), of_row(1),
+                  of_row(Dv, Dk)],
+        out_specs=[of_row(Dv), of_row(Dv, Dk)],
+        scratch_shapes=[pltpu.VMEM((_lanes(Hg), Dv), F32),
+                        pltpu.VMEM((Dv, _lanes(Hg)), F32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_step_kernel, Hg=Hg),
+        grid_spec=grid_spec,
+        # (the leaf is STATED in HBM, and its aliased operand with it: left
+        # to choose, the compiler's memory-space assignment carries a whole
+        # 64 MiB leaf to the chip's other memory and back around every call
+        # in the decode loop, for the few MB a step's live rows move)
+        out_shape=[jax.ShapeDtypeStruct(v.shape, F32),
+                   pltpu.HBM(state.shape, F32)],
+        # operands: 2 prefetched scalars, the token's five, the state
+        input_output_aliases={7: 1},
+        interpret=interpret,
+        name="delta_step",
+    )(rows, jnp.reshape(n, (1,)), q, k, v, g, beta, state)
+
+
+def _delta_step_xla(q, k, v, g, beta, state, live):
+    """`delta_step`'s sums in XLA over EVERY row's state."""
+    Sd = state * jnp.exp(g)[:, :, None, :]
+    u = beta * (v - jnp.sum(Sd * k[:, :, None, :], axis=-1))
+    S = Sd + u[:, :, :, None] * k[:, :, None, :]
+    o = jnp.sum(S * q[:, :, None, :], axis=-1)
+    return o, jnp.where(live[:, None, None, None], S, state)
+
+
+@jax.named_scope("delta_scan")
+def delta_rule_step(q, k, v, g, beta, state, tok_row, interpret=None,
+                    impl: str = "pallas"):
+    """`delta_rule_rows` for a launch whose flat place i is fleet row i's
+    ONE token (tok_row [R] int32: i, or -1 for a row that carries none): q,
+    k, g [R, H, Dk], v [R, H, Dv], beta [R, H]; state [R, H, Dv, Dk] float32
+    (donated). The recurrence itself (module docstring); no row starts from
+    zeros here (the decode program starts no tenant).
+    Returns (o [R, H, Dv] float32, zeros for a row with no token; the rows'
+    states after: a row with no token keeps its own, untouched)."""
+    live = tok_row >= 0
+    operands = tuple(a.astype(F32) for a in (q, k, v, g, beta[:, :, None]))
+    if impl == "xla":
+        o, state = _delta_step_xla(*operands, state, live)
+    else:
+        o, state = delta_step(*operands, state, live,
+                              interpret=resolve_interpret(interpret))
+    return jnp.where(live[:, None, None], o, 0.0), state

@@ -170,7 +170,8 @@ DELTA_CHUNKS_HELP = (
     "chunks of the flat token axis those row-steps' tokens were cut into "
     "(ops/delta_rule.CHUNK places each; the launch records' delta_chunks), "
     "by launch phase, a layer: chunks / rows is how many chunks a state's "
-    "trip through the program serves (1 in decode)"
+    "trip through the program serves (1 in decode: a decode chunk's row-step "
+    "counts one, though its one-token form cuts no chunk)"
 )
 SPARSE_SCORED_KEYS_HELP = (
     "compressed keys the sparse layers' selection scored, a layer and KV "

@@ -291,9 +291,19 @@ def test_the_linear_scan_takes_its_leaf_in_place(one_chip, no_persistent_cache,
     # ops/delta_rule.delta_state)
     kernel, scope, alias = ("linear_scan", "linear_attn/linear_scan", 8) \
         if built.cfg.sparse_layers else ("ssm_scan", "ssm_mix/ssm_scan", 9)
-    if built.cfg.delta_layers:
-        kernel, scope, alias = "delta_state", "delta_mix/delta_scan", 11
+    # the delta rule's kernel by program: a mixed launch's chunked form, and
+    # the decode program's one-token form (ISSUE 58: ops/delta_rule.delta_step,
+    # the token's five operands behind two prefetched scalars)
+    delta = {"mixed_step_ragged": ("delta_state", 11),
+             "decode_slots_paged": ("delta_step", 7)}
     for name, text in built.texts.items():
+        if built.cfg.delta_layers:
+            scope, (kernel, alias) = "delta_mix/delta_scan", delta[name]
+        if kernel == "delta_step":
+            # nothing of the chunked form in a program that gives a row one
+            # token: not its kernel, not a pair product's `[.., 16, 16, 128]`
+            assert not re.search(r"%delta_state[\w.\-]* = ", text)
+            assert not re.search(r"f32\[[\d,]*16,16,128\]", text)
         calls = [line for line in text.splitlines()
                  if re.search(rf"%{kernel}[\w.\-]* = .*custom-call\(", line)]
         assert len(calls) == len(built.cfg.linear_layers), (name, len(calls))
@@ -308,6 +318,15 @@ def test_the_linear_scan_takes_its_leaf_in_place(one_chip, no_persistent_cache,
             assert made <= {"parameter", "get-tuple-element", "bitcast", "tuple",
                             "custom-call", "dynamic-update-slice", "fusion",
                             "while", "conditional"}, (name, made)
+    if built.cfg.delta_layers:
+        # every layer's call is the SAME lowered kernel (a `jax.jit` of its
+        # own: a stack's layers trace and lower it once)
+        from dense_equal import canon
+
+        body, kernels = canon(built.texts["decode_slots_paged"])
+        bodies = {kernels[int(n) - 1] for n in re.findall(
+            r"%delta_step[\w.\-]* = [^\n]*<kernel (\d+)>", body)}
+        assert len(bodies) == 1, len(bodies)
 
 
 # -- the selection scores the compressed keys where they lie (ISSUE 50) ---------

@@ -494,18 +494,22 @@ def test_the_selection_compiles_at_the_sala_cells_shapes(
 # solar-open2-ep8-4l's KDA layers: the delta rule at the cell's shapes (64
 # heads of 128 x 128, a [16, 64, 128, 128] float32 leaf a layer, 4.19 MB a
 # row): the mixed launch's 512 flat tokens in the tile layout (8 chunks of the
-# rule's 64) and the decode chunk's one token a row. The state leaf goes in and
-# comes out as one buffer, and the call is named `delta_state` under its
-# scope: what `delta_scan_roofline` finds it by. float32 is the
+# rule's 64), the same chunked form over one token a row, and the decode
+# program's one-token form ("step": `delta_rule_step`, ISSUE 58). The state
+# leaf goes in and comes out as one buffer, and the call is named
+# `delta_state` (the one-token form's: `delta_step`) under its scope: what
+# `delta_scan_roofline` finds it by. float32 is the
 # configuration's STATED state: the leaf's dtype here and in
 # tests/cell_program_checks.py, and tests/test_solar_ops.py's 2e-4 bound,
 # hold it whatever `correct` can tell.
-@pytest.mark.parametrize("flat,tq", [(512, 8), (16, 1)], ids=["mixed", "decode"])
+@pytest.mark.parametrize("flat,tq,kernel", [
+    (512, 8, "delta_state"), (16, 1, "delta_state"), (16, 1, "delta_step")],
+    ids=["mixed", "decode", "step"])
 def test_the_delta_rule_compiles_at_the_solar_cells_shapes(
-    one_chip, no_persistent_cache, flat, tq
+    one_chip, no_persistent_cache, flat, tq, kernel
 ):
-    from distributed_llm_inference_tpu.ops.delta_rule import (CHUNK,
-                                                              delta_rule_rows)
+    from distributed_llm_inference_tpu.ops.delta_rule import (
+        CHUNK, delta_rule_rows, delta_rule_step)
 
     cfg, slots, _, pool = cell_pool("solar-open2-ep8-4l")
     S = _spec(one_chip)
@@ -515,16 +519,22 @@ def test_the_delta_rule_compiles_at_the_solar_cells_shapes(
     assert (lin.shape, lin.dtype) == ((16, 64, 128, 128), jnp.float32)
     assert len(pool["lin"]) == len(cfg.delta_layers) == 3 and 512 % CHUNK == 0
     H, Dh = cfg.linear_heads, cfg.head_dim
-    compiled = jax.jit(
-        lambda q, k, v, g, beta, state, tok_row, zero: delta_rule_rows(
-            q, k, v, g, beta, state, tok_row, tq, zero=zero, interpret=False),
-        donate_argnums=(5,),
-    ).lower(*(S((flat, H, Dh), jnp.float32),) * 4, S((flat, H), jnp.float32),
-            S(lin.shape, lin.dtype), S((flat,), jnp.int32),
-            S((slots,), jnp.bool_)).compile()
+
+    def rule(q, k, v, g, beta, state, tok_row, zero):
+        if kernel == "delta_step":  # (the decode program starts no tenant)
+            return delta_rule_step(q, k, v, g, beta, state, tok_row,
+                                   interpret=False)
+        return delta_rule_rows(q, k, v, g, beta, state, tok_row, tq,
+                               zero=zero, interpret=False)
+
+    compiled = jax.jit(rule, donate_argnums=(5,)).lower(
+        *(S((flat, H, Dh), jnp.float32),) * 4, S((flat, H), jnp.float32),
+        S(lin.shape, lin.dtype), S((flat,), jnp.int32),
+        S((slots,), jnp.bool_)).compile()
     text = compiled.as_text()
-    assert any("delta_state" in c for c in _custom_call_names(text))
-    assert "delta_scan/jit(delta_state)" in text
+    assert {c.split(".")[0] for c in _custom_call_names(text)
+            if "delta_st" in c} == {kernel}
+    assert f"delta_scan/jit({kernel})" in text
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == lin.size * 4, memory
     # the chunk algebra's operands over the launch's tokens (and, while the
@@ -534,6 +544,16 @@ def test_the_delta_rule_compiles_at_the_solar_cells_shapes(
     made = re.findall(r"= f32\[16,64,128,128\]\{[^}]*\} ([\w\-]+)\(", text)
     assert set(made) <= {"parameter", "get-tuple-element", "bitcast",
                          "custom-call"}, made
+    if kernel == "delta_step":
+        # the recurrence itself: 16 heads a program (4 x 16 grid steps a
+        # layer), no chunk of 64, no pair product, no product on the matrix
+        # unit, and a quarter of the scoped VMEM limit for the state's blocks
+        assert not re.search(r"f32\[[\d,]*16,16,128\]", text)
+        assert not re.search(r" (?:dot|convolution)\(", text)
+        assert memory.temp_size_in_bytes < 1e6, memory
+        call = next(line for line in text.splitlines()
+                    if re.search(r"%delta_step[\w.\-]* = .*custom-call\(", line))
+        assert "output_to_operand_aliasing={{1}: (7, {})}" in call
 
 
 @pytest.mark.parametrize("pairs", [128, 4096])

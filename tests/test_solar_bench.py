@@ -53,6 +53,9 @@ NOT_JOINED = ["ffn_ms_per_step", "attn_kv_roofline", "step_weight_roofline", "hy
               "mixed_tokens_live_pct", "window_kv_held_pct"]
 LIST_LESS = ["batch_rows_mean", "prefill_tok_pct", "step_device_ms_p50",
              "attn_kernel_ms_per_step", "device_idle_pct"]
+# appended by later PRs for this cell alone (PR 58: the share of the state's
+# row-steps that ride a decode chunk, which the one-token form serves)
+LATER = ["delta_decode_chunk_row_pct"]
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 PEAKS = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
 
@@ -414,9 +417,13 @@ def test_the_manifest_gained_one_configuration_one_cell_and_four_metrics():
     cell = manifest.Cell(man, CELL)
     assert {m["name"] for m in cell.end_to_end} == {"tpot_ms_p50", "setup_s"}
     assert {m["name"] for m in cell.per_layer} == \
-        set(LIST_LESS) | set(NEW_METRICS) | set(JOINED)
+        set(LIST_LESS) | set(NEW_METRICS) | set(JOINED) | set(LATER)
+    for name in LATER:
+        assert names.index(name) > at + 3 and by_name[name]["workloads"] == [CELL]
+        assert by_name[name]["layer"] == by_name["delta_scan_roofline"]["layer"]
     for other in ACCEPTED:  # nothing an accepted cell reports has changed
-        assert not set(NEW_METRICS) & {m["name"] for m in manifest.Cell(man, other).per_layer}
+        assert not set(NEW_METRICS + LATER) & {
+            m["name"] for m in manifest.Cell(man, other).per_layer}
     # the traffic file trinity-docs-xlong and sala-docs-xlong run, unedited
     assert cell.traffic == manifest.Cell(man, "trinity-docs-xlong").traffic
     assert cell.traffic["check"]["long_tokens"] == 12400 and cell.traffic["begin_at"] == 1

@@ -189,6 +189,57 @@ def test_the_logits_themselves_are_the_references_launch_by_launch(impl):
             assert_logits(lg, (want_a, want_b)[row][start:start + len(ids)])
 
 
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_a_decode_step_is_the_one_token_form_between_two_mixed_launches(impl, monkeypatch):
+    """The hand-over at the hooks' level (ISSUE 58): two rows prefill in a
+    ragged launch (the chunked form, a call a KDA layer), decode through the
+    decode program's hook (`engine/paged._forward_step_paged`: the one-token
+    form, a call a KDA layer and none of the chunked form; row 1 sits out the
+    later steps and its states wait), then row 0's next chunk rides a ragged
+    launch again beside row 1's decode token: every launch's logits are the
+    reference's, whichever form wrote the state the launch starts from."""
+    from distributed_llm_inference_tpu.models import solar_open2 as SO
+
+    calls = {"step": 0, "rows": 0}
+
+    def counting(name, fn):
+        def wrapped(*a, **k):
+            calls[name] += 1
+            return fn(*a, **k)
+        return wrapped
+
+    monkeypatch.setattr(SO, "delta_rule_step", counting("step", SO.delta_rule_step))
+    monkeypatch.setattr(SO, "delta_rule_rows", counting("rows", SO.delta_rule_rows))
+    cfg = CFG.replace(attn_impl=impl)
+    params = M.init_params(cfg, jax.random.PRNGKey(SEED))
+    pool = P.init_pool(cfg, 24, BS, n_slots=2, n_snapshots=2)
+    table = np.zeros((2, 8), np.int32)
+    table[0, :6], table[1, :4] = np.arange(1, 7), np.arange(7, 11)
+    a, b = prompt_ids(40, 15), prompt_ids(16, 16)
+    want = ref_logits(a), ref_logits(b)
+    first = [(0, 0, a[:21], P.RAGGED_FIRST), (1, 0, b[:12], P.RAGGED_FIRST)]
+    got, pool = U.launch(cfg, params, pool, table, first, retrace=True)
+    for (row, start, ids, _), lg in zip(first, got):
+        assert_logits(lg, want[row][start:start + len(ids)])
+    assert calls == {"step": 0, "rows": 3}
+    for t in range(6):  # row 1 carries a token in the first three steps only
+        active = np.array([True, t < 3])
+        toks = np.array([[a[21 + t]], [b[min(12 + t, 15)]]], np.int32)
+        pos = np.array([21 + t, min(12 + t, 15)], np.int32)
+        lg, pool = P._forward_step_paged(
+            cfg, params, jnp.asarray(toks), pool, jnp.asarray(table),
+            jnp.asarray(pos), active=jnp.asarray(active))
+        assert_logits(np.asarray(lg)[0], want[0][21 + t])
+        if active[1]:
+            assert_logits(np.asarray(lg)[1], want[1][12 + t])
+    assert calls == {"step": 18, "rows": 3}
+    last = [(0, 27, a[27:40], P.RAGGED_PREFILL), (1, 15, b[15:], P.RAGGED_DECODE)]
+    got, pool = U.launch(cfg, params, pool, table, last, retrace=True)
+    for (row, start, ids, _), lg in zip(last, got):
+        assert_logits(lg, want[row][start:start + len(ids)])
+    assert calls == {"step": 18, "rows": 6}
+
+
 def test_a_restored_row_reads_every_state_of_its_snapshot():
     """At the hooks' level, logits against the reference's: row 0 prefills 16
     tokens and leaves its states in snapshot 1; row 1, whose table shares

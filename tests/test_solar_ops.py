@@ -3,8 +3,10 @@ float64, at tiny sizes on the CPU: the launch form of the gated delta rule
 (the chunk algebra in XLA, the carried state's interpreted kernel or its XLA
 twin) for one row and for several rows ragged on one flat axis, with beta on
 both sides of 1, the fastest and the slowest decays `A_log` / `dt_bias` can
-give, a row spread over several launches, a row re-let from zeros, and the
-decode call.
+give, a row spread over several launches, a row re-let from zeros; and the
+decode program's one-token form (`delta_rule_step`: the interpreted kernel or
+its jax.numpy twin) against the same recurrence, alone, beside the chunked
+form of the same launch, and handed a chunked launch's state and back.
 """
 
 import jax
@@ -86,6 +88,43 @@ def _launch(args, state, tok_row, tile, impl, zero=None):
         tile, zero=None if zero is None else jnp.asarray(zero), impl=impl)
 
 
+def _every(R):
+    return np.arange(R, dtype=np.int32)
+
+
+def _step(args, state, tok_row, impl):
+    """The one-token form: flat place i is fleet row i's (or nobody's)."""
+    return DR.delta_rule_step(
+        *map(jnp.asarray, args), jnp.asarray(state), jnp.asarray(tok_row),
+        impl=impl)
+
+
+# a fleet of 4 (or 5) rows at one decode step: which rows carry a token
+STEPS = {
+    "all-rows": [0, 1, 2, 3],
+    "inactive-rows": [-1, 1, -1, 3, -1],
+    "last-row-alone": [-1, -1, -1, 3],
+    "no-row": [-1, -1, -1, -1],
+}
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("regime", list(REGIMES))
+@pytest.mark.parametrize("name", list(STEPS))
+def test_the_one_token_form_is_the_recurrence(name, regime, impl):
+    """A decode step's o and state after against the float64 recurrence in
+    every regime of `_draw` (decays to e^-48 a token and channel, beta to
+    1.98); a row with no token reads zeros and its state block comes back bit
+    for bit, wherever it lies among the rows that carry one."""
+    tok_row = np.asarray(STEPS[name], np.int32)
+    R, H, Dk, Dv = len(tok_row), 2, 32, 16
+    args = _draw(len(name) + len(regime), R, H, Dk, Dv, regime)
+    S0 = np.random.default_rng(5).standard_normal(
+        (R, H, Dv, Dk)).astype(np.float32)
+    o, S1 = _step(args, S0, tok_row, impl)
+    _check_rows(args, S0, tok_row, o, S1)
+
+
 def _check_rows(args, S0, tok_row, o, S1, zero=()):
     """Every row against the recurrence from its own start state (stored
     transposed: [R, H, Dv, Dk])."""
@@ -143,6 +182,21 @@ def test_keys_that_repeat_do_not_cancel_in_the_solve(impl):
 
 
 @pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_keys_that_repeat_step_after_step_are_the_recurrence(impl):
+    """The same keys a token at a time: 24 decode steps of two rows whose
+    keys are nearly one key, beta 1.9 and no decay to speak of (every step
+    reflects the state about almost the same direction), against one pass of
+    the recurrence."""
+    R, H, Dk, Dv, steps = 2, 2, 32, 16, 24
+    args = _draw(3, steps * R, H, Dk, Dv, "slow-small-beta", repeat_keys=True)
+    args = args[:4] + (np.full_like(args[4], 1.9),)
+    args = tuple(a.reshape((steps, R) + a.shape[1:]) for a in args)
+    S0 = np.zeros((R, H, Dv, Dk), np.float32)
+    _check_steps(args, S0, [
+        ("step", t, _every(R)) for t in range(steps)], impl)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
 @pytest.mark.parametrize("cuts", [(64, 64, 64), (37, 90, 1, 12), (1, 1, 1, 130)])
 def test_a_row_spread_over_launches_is_one_row(cuts, impl):
     """A row's tokens in several launches, the state carried between them
@@ -188,29 +242,121 @@ def test_a_row_let_again_starts_from_zeros(impl):
     _check_rows(args, S0, tok_row, o, S1, zero=(1,))
 
 
+def _check_steps(args, S0, plan, impl):
+    """args [steps, R, ...]: a token a row and step. plan: [(form, step,
+    tok_row [R])], form "step" the one-token form, "rows" the chunked form of
+    the same one-token-a-row launch; the state carried from call to call.
+    Every row against ONE pass of the recurrence over the steps it rode."""
+    R = S0.shape[0]
+    state, outs = jnp.asarray(S0), []
+    for form, t, tok_row in plan:
+        part = tuple(a[t] for a in args)
+        o, state = _step(part, state, tok_row, impl) if form == "step" \
+            else _launch(part, state, tok_row, 1, impl)
+        outs.append(np.asarray(o))
+    got = np.stack(outs)  # [len(plan), R, H, Dv]
+    for r in range(R):
+        rode = [i for i, (_, _, tok_row) in enumerate(plan) if tok_row[r] >= 0]
+        at = [plan[i][1] for i in rode]
+        assert not got[[i for i in range(len(plan)) if i not in rode], r].any()
+        if not rode:
+            np.testing.assert_array_equal(np.asarray(state)[r], S0[r])
+            continue
+        want, S = _recurrence(*(a[at, r] for a in args),
+                              S0[r].transpose(0, 2, 1))
+        assert np.abs(got[rode, r] - want).max() <= TOL * max(
+            1.0, np.abs(want).max()), r
+        S = S.transpose(0, 2, 1)
+        assert np.abs(np.asarray(state)[r] - S).max() <= TOL * max(
+            1.0, np.abs(S).max()), r
+
+
+# which form serves each of sixteen one-token-a-row steps of three rows
+HANDOVERS = {
+    # the decode program alone
+    "steps": lambda t: "step",
+    # the same launches through the chunked form (a mixed launch's decode
+    # rows): what the one-token form must agree with
+    "rows": lambda t: "rows",
+    # a mixed launch between decode chunks, and a decode chunk between mixed
+    # launches: each form reads the state the other wrote
+    "rows-then-steps": lambda t: "rows" if t < 5 else "step",
+    "steps-then-rows": lambda t: "step" if t < 11 else "rows",
+    "turn-about": lambda t: ("step", "rows")[(t // 3) % 2],
+}
+
+
 @pytest.mark.parametrize("impl", ["pallas", "xla"])
-def test_a_decode_step_is_the_same_call(impl):
+@pytest.mark.parametrize("name", list(HANDOVERS))
+def test_a_decode_step_is_the_recurrence(name, impl):
     """One token a row for sixteen steps, the state carried from call to
-    call, at the published head width: the recurrence over the sixteen."""
+    call, at the published head width: the recurrence over the sixteen,
+    whichever form serves a step (row 1 sits out steps 4 to 6: its state
+    waits, bit for bit, in either form's launch)."""
     H, Dk, Dv, R, steps = 2, 128, 128, 3, 16
     args = _draw(31, steps * R, H, Dk, Dv, "typical")
     args = tuple(a.reshape((steps, R) + a.shape[1:]) for a in args)
     S0 = np.random.default_rng(8).standard_normal(
         (R, H, Dv, Dk)).astype(np.float32)
-    state, outs = jnp.asarray(S0), []
-    tok_row = np.arange(R, dtype=np.int32)
-    for t in range(steps):
-        o, state = _launch(tuple(a[t] for a in args), state, tok_row, 1, impl)
-        outs.append(np.asarray(o))
-    got = np.stack(outs)  # [steps, R, H, Dv]
-    for r in range(R):
-        want, S = _recurrence(*(a[:, r] for a in args),
-                              S0[r].transpose(0, 2, 1))
-        assert np.abs(got[:, r] - want).max() <= TOL * max(
-            1.0, np.abs(want).max())
-        S = S.transpose(0, 2, 1)
-        assert np.abs(np.asarray(state)[r] - S).max() <= TOL * max(
-            1.0, np.abs(S).max())
+    plan = [(HANDOVERS[name](t), t,
+             np.where((_every(R) == 1) & (4 <= t <= 6), -1, _every(R)))
+            for t in range(steps)]
+    _check_steps(args, S0, plan, impl)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("regime", list(REGIMES))
+def test_the_two_forms_of_a_one_token_launch_agree(regime, impl):
+    """The SAME one-token-a-row launch through the one-token form and through
+    the chunked form: o and the state after agree to `TOL` (each is held to
+    the float64 recurrence above; this holds them to each other), and a row
+    with no token keeps its block in both."""
+    R, H, Dk, Dv = 4, 2, 128, 128
+    tok_row = np.asarray([0, -1, 2, 3], np.int32)
+    args = _draw(41, R, H, Dk, Dv, regime)
+    S0 = np.random.default_rng(9).standard_normal(
+        (R, H, Dv, Dk)).astype(np.float32)
+    o_step, S_step = map(np.asarray, _step(args, S0, tok_row, impl))
+    o_rows, S_rows = map(np.asarray, _launch(args, S0, tok_row, 1, impl))
+    assert np.abs(o_step - o_rows).max() <= TOL * max(1.0, np.abs(o_rows).max())
+    assert np.abs(S_step - S_rows).max() <= TOL * max(1.0, np.abs(S_rows).max())
+    np.testing.assert_array_equal(S_step[1], S0[1])
+    np.testing.assert_array_equal(S_rows[1], S0[1])
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_a_prompt_chunk_then_decode_steps_then_a_chunk_is_one_row(impl):
+    """The hand-over as the engine makes it: a 70-token chunk through the
+    chunked form (beside another row's), eight decode steps through the
+    one-token form, a 9-token chunk through the chunked form again, and two
+    more steps: one unbroken recurrence over the row's 89 tokens."""
+    H, Dk, Dv, R = 2, 32, 16, 3
+    cuts = [("rows", 70), ("step", 8), ("rows", 9), ("step", 2)]
+    n = sum(c for _, c in cuts)
+    args = _draw(51, n, H, Dk, Dv, "typical")
+    S0 = np.random.default_rng(6).standard_normal(
+        (R, H, Dv, Dk)).astype(np.float32)
+    want, S_end = _recurrence(*args, S0[1].transpose(0, 2, 1))
+    state, done, outs = jnp.asarray(S0), 0, []
+    for form, c in cuts:
+        if form == "rows":
+            part = tuple(a[done:done + c] for a in args)
+            o, state = _launch(part, state, np.full((c,), 1, np.int32), 1, impl)
+            outs.append(np.asarray(o))
+        else:
+            for t in range(done, done + c):
+                part = tuple(np.stack([np.zeros_like(a[t]), a[t],
+                                       np.zeros_like(a[t])]) for a in args)
+                o, state = _step(part, state, np.asarray([-1, 1, -1], np.int32),
+                                 impl)
+                outs.append(np.asarray(o)[1:2])
+        done += c
+    got = np.concatenate(outs)
+    assert np.abs(got - want).max() <= TOL * max(1.0, np.abs(want).max())
+    S_end = S_end.transpose(0, 2, 1)
+    assert np.abs(np.asarray(state)[1] - S_end).max() <= TOL * max(
+        1.0, np.abs(S_end).max())
+    np.testing.assert_array_equal(np.asarray(state)[[0, 2]], S0[[0, 2]])
 
 
 def test_the_hosts_count_of_chunks_is_the_kernels():
