@@ -743,7 +743,7 @@ class ModelConfig:
         row (head dim 64: 2), so that the paged kernels write in place
         (ops/paged_attention.writes_in_place); 1: a head a row. The
         families that keep a state a row beside K/V pack (their attention
-        goes through models/lfm2.pack_heads), but for a selected read,
+        goes through models/stack.pack_heads), but for a selected read,
         whose scoring reads a key head a row."""
         if not self.recurrent or self.sparse_layers or 128 % self.head_dim:
             return 1

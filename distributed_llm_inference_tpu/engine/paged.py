@@ -88,7 +88,7 @@ GROUP_LEAVES = (("k", "v"), ("kw", "vw"))
 
 def _routed_counts(cfg: ModelConfig):
     """The zeroed "routed" leaf of a family that may hold a share
-    (models/afmoe.add_routed): the experts held here, with one more column
+    (models/stack.add_routed): the experts held here, with one more column
     under a share, the pairs routed elsewhere."""
     share = cfg.experts_held < cfg.n_experts
     return jnp.zeros(

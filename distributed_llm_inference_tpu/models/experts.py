@@ -13,7 +13,7 @@ n_experts), combined by weight. The grouped product's operand is the
 STACKED bank [L, E, ...] seen as L x E groups of which only this layer's
 are non-empty: a scan that sliced the bank per layer would copy it (1.2 GB
 at 128 experts of 2048 x 768) every step, so the banks stay outside the
-scan's xs (`BANKS`). `_normal_slices` draws such a leaf slice by slice.
+scan's xs (`BANKS`). `normal_slices` draws such a leaf slice by slice.
 """
 
 from __future__ import annotations
@@ -34,7 +34,7 @@ BANKS = ("w_gate", "w_up", "w_down")  # the routed experts' stacked banks
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "scale", "dtype"))
-def _normal_slices(key, *, shape, scale, dtype):
+def normal_slices(key, *, shape, scale, dtype):
     """A leaf [n, ...] drawn slice by slice: slice i is
     normal(split(key, n)[i], shape[1:]) * scale, float32 rounded to
     `dtype`; the loop writes each slice into the output, so the float32

@@ -1,10 +1,9 @@
 """Gated short convolutions beside grouped-query attention over routed
 experts (LiquidAI/LFM2-24B-A2B, model_type lfm2_moe) in pure JAX.
 
-Layers of two kinds alternate in one stack (cfg.layer_types), so the layers
-are not scanned: the stack is a Python loop over the pattern, each layer
-reading its own row of the stacked leaves of its kind by a static index.
-RMSNorm eps cfg.norm_eps everywhere; x a layer's input, D = cfg.dim:
+The stack of two kinds of layer (cfg.layer_types) is models/stack.py's loop;
+here its leaves, its two mixers and their binding. RMSNorm eps cfg.norm_eps
+everywhere; x a layer's input, D = cfg.dim:
 
   layer l   h = x + Op_l(RMSNorm_op(x));  y = h + FFN_l(RMSNorm_ffn(h))
   head      RMSNorm (the family's `embedding_norm`), then the tied table
@@ -22,21 +21,15 @@ RMSNorm eps cfg.norm_eps everywhere; x a layer's input, D = cfg.dim:
             before the rotation, RoPE (half-rotation), causal softmax at
             Dh^-0.5, no bias: the llama family's pieces.
   FFN       the first cfg.first_k_dense layers: SwiGLU of cfg.ffn_dim; the
-            others: s = sigmoid(h w_router) in float32, the
-            n_experts_per_tok largest of s + router_bias chosen, weights
-            s / (sum of the chosen s + cfg.router_norm_eps) x
-            routed_scaling, each token through its experts alone
-            (models/experts.py). No shared expert.
+            others `stack.moe_ffn`: every expert held, no shared one.
 
-The residual stream, every sublayer's output and the router's scores are
-float32; matrix products take the parameter dtype in and float32 out; z is
-rounded to the parameter dtype where it is made, so what a later launch
+z is rounded to the parameter dtype where it is made, so what a later launch
 reads back from the state is what a neighbour in the same launch reads.
 
 The cache has two kinds of leaf. "k" / "v" hold the ATTENTION layers alone
 (their index among the attention layers is the leaf's layer axis): dense
 [La, B, KV, S, Dh], or the paged pool [La, N, KV / pack, bs, pack x Dh]
-with cfg.kv_pack heads side by side on the 128 lanes (`pack_heads`), so
+with cfg.kv_pack heads side by side on the 128 lanes (`stack.pack_heads`), so
 the paged kernels write head dim 64 in place. "conv" is the convolution
 layers' state: dense [Lc, B, K-1, D]; paged [Lc, slots, K-1, D] beside
 "tail" [Lc, N, K-1, D], one state a pool block (the last K-1 gated inputs
@@ -44,33 +37,29 @@ of the block), which a prefix hit restores the slot's state from. The
 paged hooks say how a launch's flat tokens fall into rows
 (`attn_hook.rows`, engine/paged.StateRows).
 
-Params pytree (L layers, Lc / La conv / attention layers, Ld / Lm dense /
-expert layers, E experts, F ffn_dim, Fm moe_ffn_dim, V vocab):
+Params pytree (L layers, Lc / La conv / attention layers, V vocab):
   embed [V, D] (also the head)   final_norm [D]
   layers: op_norm ffn_norm [L, D]
     conv:  w_in [Lc, D, 3D]  w_conv [Lc, K, D]  w_out [Lc, D, D]
     attn:  wq [La, D, H*Dh]  wk wv [La, D, KV*Dh]  wo [La, H*Dh, D]
            q_norm k_norm [La, Dh]
-    dense: w_gate w_up [Ld, D, F]  w_down [Ld, F, D]
-    moe:   w_router [Lm, D, E]  router_bias [Lm, E] float32
-           w_gate w_up [Lm, E, D, Fm]  w_down [Lm, E, Fm, D]
+    dense, moe: `stack.ffn_shapes`, `stack.moe_shapes`
 """
 
 from __future__ import annotations
 
+import functools
 from types import SimpleNamespace
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from . import stack
 from ..config import ModelConfig
-from ..ops.attention import causal_mask, slot_causal_mask
 from ..ops.norms import rms_norm
 from ..ops.rope import apply_rope, rope_cos_sin
-from .experts import BANKS, _normal_slices, route, routed_ffn
-from .llama import default_attn_hook
-from .mla_moe import ROUTER_BIAS_SCALE, swiglu
+from .stack import embed, unembed  # noqa: F401 - the family's ends
 
 Params = dict
 F32 = jnp.float32
@@ -98,10 +87,8 @@ def leaf_shapes(cfg: ModelConfig) -> dict:
     """{leaf path: (shape, init scale or None for ones)}, stacked leaves
     with their layer axis first."""
     D, V, K = cfg.dim, cfg.vocab_size, cfg.conv_kernel
-    H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    E, Fm, F = cfg.n_experts, cfg.moe_ffn_dim, cfg.ffn_dim
     n = stack_depths(cfg)
-    Lc, La, Ld, Lm = n["conv"], n["attn"], n["dense"], n["moe"]
+    Lc, La = n["conv"], n["attn"]
     s = D ** -0.5
     return {
         "embed": ((V, D), 0.02), "final_norm": ((D,), None),
@@ -110,59 +97,26 @@ def leaf_shapes(cfg: ModelConfig) -> dict:
         "conv.w_in": ((Lc, D, 3 * D), s),
         "conv.w_conv": ((Lc, K, D), K ** -0.5),
         "conv.w_out": ((Lc, D, D), s),
-        "attn.wq": ((La, D, H * Dh), s), "attn.wk": ((La, D, KV * Dh), s),
-        "attn.wv": ((La, D, KV * Dh), s),
-        "attn.wo": ((La, H * Dh, D), (H * Dh) ** -0.5),
-        "attn.q_norm": ((La, Dh), None), "attn.k_norm": ((La, Dh), None),
-        "dense.w_gate": ((Ld, D, F), s), "dense.w_up": ((Ld, D, F), s),
-        "dense.w_down": ((Ld, F, D), F ** -0.5),
-        "moe.w_router": ((Lm, D, E), s),
-        "moe.router_bias": ((Lm, E), ROUTER_BIAS_SCALE),
-        "moe.w_gate": ((Lm, E, D, Fm), s), "moe.w_up": ((Lm, E, D, Fm), s),
-        "moe.w_down": ((Lm, E, Fm, D), Fm ** -0.5),
+        **stack.attn_shapes("attn", La, D, cfg.n_heads, cfg.n_kv_heads,
+                            cfg.head_dim, qk_norm=True),
+        **stack.ffn_shapes("dense", n["dense"], D, cfg.ffn_dim),
+        **stack.moe_shapes(cfg, n["moe"]),
     }
 
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
-    """Seeded random parameters (tests and benchmarks): scaled normals drawn
-    slice by slice (models/experts._normal_slices), norm weights 1, the
-    selection bias normal * ROUTER_BIAS_SCALE in float32. The embedding is
-    the head too (tied). A kind with no layer keeps empty leaves."""
-    if not cfg.tie_embeddings:
-        raise ValueError(f"{cfg.name}: the lfm2 family ties its embeddings")
-    dt = cfg.jnp_dtype
-    ks = jax.random.split(key, 24)
-    layers: Params = {"conv": {}, "attn": {}, "dense": {}, "moe": {}}
-    params: Params = {"layers": layers}
-    for path, (shape, scale) in leaf_shapes(cfg).items():
-        if scale is None:
-            leaf = jnp.ones(shape, dt)
-        elif 0 in shape:
-            leaf = jnp.zeros(shape, dt)
-        else:
-            # the vocabulary table is drawn as 8 slices of rows
-            cut = 8 if path == "embed" and shape[0] % 8 == 0 else None
-            leaf = _normal_slices(
-                ks[LEAF_KEYS[path]], scale=float(scale),
-                shape=(cut, shape[0] // cut) + shape[1:] if cut else shape,
-                dtype=F32 if path == "moe.router_bias" else dt,
-            ).reshape(shape)
-        kind, _, name = path.rpartition(".")
-        if kind:
-            layers[kind][name] = leaf
-        elif name in ("embed", "final_norm"):
-            params[name] = leaf
-        else:
-            layers[name] = leaf
-    return params
+    """Seeded random parameters (tests and benchmarks): `stack.draw_params`,
+    every expert held, the selection bias in float32. The embedding is the
+    head too (tied)."""
+    return stack.draw_params(cfg, key, leaf_shapes(cfg), LEAF_KEYS,
+                             float32=("moe.router_bias",), by_index=False)
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_seq: Optional[int] = None,
                   n_layers: Optional[int] = None):
     """Zeroed dense cache: K/V of the attention layers alone and the
     convolution layers' state (module docstring)."""
-    if n_layers is not None and n_layers != cfg.n_layers:
-        raise ValueError("an lfm2 cache is not cut by layers (no pp)")
+    stack.whole_cache_only(cfg, n_layers)
     S = max_seq or cfg.max_seq_len
     n = stack_depths(cfg)
     kv = (n["attn"], batch, cfg.n_kv_heads, S, cfg.head_dim)
@@ -173,60 +127,16 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_seq: Optional[int] = None,
     }
 
 
-@jax.named_scope("embed")
-def embed(cfg: ModelConfig, params: Params, tokens, pos=0):
-    """[B, T] -> [B, T, D], float32: the residual stream's dtype."""
-    del pos
-    return params["embed"][tokens].astype(F32)
-
-
-@jax.named_scope("head")
-def unembed(cfg: ModelConfig, params: Params, x):
-    """The last RMSNorm and the tied table: float32 logits."""
-    h = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(cfg.jnp_dtype)
-    return jax.lax.dot_general(
-        h, params["embed"], (((h.ndim - 1,), (1,)), ((), ())),
-        preferred_element_type=F32,
-    )
-
-
 # -- attention ----------------------------------------------------------------
-
-
-def pack_heads(q, k, v, pack: int):
-    """The paged pool's row of `pack` K/V heads side by side
-    (engine/paged.init_pool): k / v [B, T, KV, Dh] -> [B, T, KV / pack,
-    pack x Dh], and each query head zero-extended to that row on its own
-    K/V head's part, so a score over the row is the head's own (the zero
-    lanes add nothing). The kernels then see KV / pack heads of pack x Dh
-    with `pack` times the group: head dim 64 on whole 128-lane tiles."""
-    B, T, H, Dh = q.shape
-    KV = k.shape[2]
-    part = (jnp.arange(H) // (H // KV)) % pack  # a query head's part of the row
-    q = jnp.concatenate(
-        [jnp.where((part == i)[:, None], q, jnp.zeros_like(q))
-         for i in range(pack)], axis=-1,
-    )
-    wide = k.shape[:2] + (KV // pack, pack * Dh)
-    return q, k.reshape(wide), v.reshape(wide), part
-
-
-def unpack_heads(out, part, pack: int):
-    """The kernels' output [B, T, H, pack x Dh] cut to each query head's own
-    part of the value row."""
-    Dh = out.shape[-1] // pack
-    pieces = out.reshape(out.shape[:-1] + (pack, Dh))
-    return jnp.take_along_axis(
-        pieces, part[None, None, :, None, None], axis=3
-    )[..., 0, :]
 
 
 def attention(cfg: ModelConfig, lp: Params, h, cache_k, cache_v, pos, cos,
               sin, mask, hook, layer):
     """The attention operator on normed h [B, T, D] (parameter dtype);
-    returns (float32 [B, T, D], new cache_k, new cache_v). cache_k / v: the
-    layer's slices of the dense cache (layer None), or under a paged hook
-    the whole pool leaves and `layer`, the layer's index in them."""
+    returns (float32 [B, T, D], new cache_k, new cache_v). cache_k / v and
+    layer: `stack.cached`'s. (The products are split into heads unpinned,
+    against models/stack.py's rule 1: the cell was measured so, and pinning
+    them is a `perf_opt` change.)"""
     B, T, _ = h.shape
     H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = (h @ lp["wq"]).reshape(B, T, H, Dh)
@@ -237,13 +147,13 @@ def attention(cfg: ModelConfig, lp: Params, h, cache_k, cache_v, pos, cos,
     q, k = apply_rope(q, k, cos, sin)
     pack = cfg.kv_pack if layer is not None else 1
     if pack > 1:
-        q, k, v, part = pack_heads(q, k, v, pack)
+        q, k, v, part = stack.pack_heads(q, k, v, pack)
     attn, new_k, new_v = hook(
         cfg, q, k, v, cache_k, cache_v, pos, mask, None, None, None,
         *(() if layer is None else (layer,)),
     )
     if pack > 1:
-        attn = unpack_heads(attn, part, pack)
+        attn = stack.unpack_heads(attn, part, pack)
     out = jnp.dot(attn.reshape(B, T, H * Dh), lp["wo"],
                   preferred_element_type=F32)
     return out, new_k, new_v
@@ -366,125 +276,43 @@ def conv_mix(cfg: ModelConfig, lp: Params, h, state):
     return out.reshape(B, T, D), new[0]
 
 
-# -- feed-forward -------------------------------------------------------------
-
-
-def moe_ffn(cfg: ModelConfig, lp: Params, banks: Params, layer: int, h,
-            live=None):
-    """Routed experts on normed h [B, T, D]: (float32 [B, T, D], routed
-    tokens an expert [E]). Every expert is held here."""
-    B, T, D = h.shape
-    flat = h.reshape(B * T, D)
-    with jax.named_scope("moe_route"):
-        chosen, weights = route(cfg, flat, lp["w_router"], lp["router_bias"])
-    out, sizes = routed_ffn(cfg, banks, layer, flat, chosen, weights,
-                            live=live)
-    return out.reshape(B, T, D), sizes
-
-
 # -- the stack ----------------------------------------------------------------
 
 
-def forward_layers(cfg: ModelConfig, layers: Params, x, cache, pos,
-                   update_gate=None, tp_axis=None, attn_hook=None,
-                   valid_start=None, ep_axis=None, attn_seq_len=None):
-    """Every layer over a chunk x [B, T, D] (float32 residual). cache: the
-    dense cache (`init_kv_cache`) or, under a paged hook (`attn_hook.paged`,
-    engine/paged.py), the pool with its "conv" / "tail" leaves; with a
-    "routed" leaf [2, Lm, E] int32 the expert layers add to it what they
-    routed (models/mla_moe.forward_layers' contract). pos: a scalar, or one
-    position a row (the flat token layout). Returns (x, new cache)."""
-    if tp_axis is not None or ep_axis is not None or update_gate is not None:
-        raise ValueError("the lfm2 family is not sharded over pp, tp or ep")
-    if valid_start is not None:
-        raise ValueError(
-            "the lfm2 family takes no left-padded rows: a pad token would "
-            "enter a convolution layer's state"
-        )
-    T = x.shape[1]
-    pos = jnp.asarray(pos, jnp.int32)
-    paged = getattr(attn_hook, "paged", False)
+def _prepare(cfg: ModelConfig, layers: Params, x, cache, pos, hook,
+             attn_seq_len):
+    """What the two mixers share, once a forward: the mask and the rotary
+    tables, the hook, and under a paged hook how the launch's flat tokens
+    fall into rows (engine/paged.StateRows) and the pool's block size."""
+    paged = getattr(hook, "paged", False)
     S = attn_seq_len if attn_seq_len is not None else cache["k"].shape[3]
-    if pos.ndim == 1:
-        positions = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-        mask = slot_causal_mask(pos, T, S)
-    else:
-        positions = pos + jnp.arange(T, dtype=jnp.int32)
-        mask = causal_mask(pos, T, S)
-    with jax.named_scope("attn"):  # the rotary tables, once a forward
+    positions, (mask,) = stack.positions_and_masks(pos, x.shape[1], S)
+    with jax.named_scope("attn"):
         cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
-    hook = attn_hook or default_attn_hook
-    rows = attn_hook.rows() if paged else None
-    # rows whose output nothing reads reach no expert (engine/paged's hooks
-    # say which: launch padding, freed slots)
-    live = getattr(attn_hook, "live", None)
-    if live is not None and T > 1:
-        live = jnp.repeat(live, T)
-    dt = cfg.jnp_dtype
-    banks = {name: layers["moe"][name] for name in BANKS}  # never sliced
-
-    def row(kind, i):  # layer i's leaves of its kind's small stack
-        return {name: leaf[i] for name, leaf in layers[kind].items()
-                if not (kind == "moe" and name in BANKS)}
-
-    new = dict(cache)
-    sizes = []
-    ic = ia = 0
-    # the step's scopes (utils/tracing.STEP_SCOPES): an operator with the
-    # norm in front of it; the residual add belongs to the block it feeds
-    scope = {"conv": "conv_mix", "full_attention": "attn"}
-    for li, kind in enumerate(cfg.layer_types):
-        routed = li >= cfg.first_k_dense
-        with jax.named_scope(scope[kind]):
-            h = rms_norm(x, layers["op_norm"][li], cfg.norm_eps).astype(dt)
-            if kind == "conv":
-                lp = row("conv", ic)
-                if paged:
-                    out, new["conv"], new["tail"] = conv_mix_rows(
-                        cfg, lp, h, new["conv"], new["tail"], ic, rows, pos,
-                        cache["k"].shape[3],
-                    )
-                else:
-                    out, state = conv_mix(cfg, lp, h, new["conv"][ic])
-                    new["conv"] = new["conv"].at[ic].set(state)
-                ic += 1
-            else:
-                # a paged hook takes the pool's leaves whole and the layer's
-                # index in them; the dense cache is cut and put back here
-                ck, cv = (new["k"], new["v"]) if paged else \
-                    (new["k"][ia], new["v"][ia])
-                out, ck, cv = attention(
-                    cfg, row("attn", ia), h, ck, cv, pos, cos, sin, mask, hook,
-                    ia if paged else None,
-                )
-                new["k"] = ck if paged else new["k"].at[ia].set(ck)
-                new["v"] = cv if paged else new["v"].at[ia].set(cv)
-                ia += 1
-        with jax.named_scope("moe_route" if routed else "ffn"):
-            x = x + out
-            h = rms_norm(x, layers["ffn_norm"][li], cfg.norm_eps).astype(dt)
-        if routed:
-            im = li - cfg.first_k_dense
-            out, counts = moe_ffn(cfg, row("moe", im), banks, im, h, live)
-            sizes.append(counts)
-        else:
-            lp = row("dense", li)
-            with jax.named_scope("ffn"):
-                out = swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
-        after = cfg.layer_types[li + 1:li + 2]
-        with jax.named_scope(scope[after[0]] if after else "head"):
-            x = x + out
-    if "routed" in cache:
-        sizes = jnp.stack(sizes)
-        new["routed"] = cache["routed"] + jnp.stack(
-            [sizes, (sizes > 0).astype(jnp.int32)]
-        )
-    return x, new
+    return SimpleNamespace(
+        pos=pos, mask=mask, cos=cos, sin=sin, hook=hook, paged=paged,
+        rows=hook.rows() if paged else None, bs=cache["k"].shape[3])
 
 
-def forward(cfg: ModelConfig, params: Params, tokens, cache, pos):
-    """Whole-model chunk forward: tokens [B, T] at offset pos -> (float32
-    logits [B, T, V], new cache)."""
-    x = embed(cfg, params, tokens)
-    x, cache = forward_layers(cfg, params["layers"], x, cache, pos)
-    return unembed(cfg, params, x), cache
+def _conv(cfg, c, lp, h, new, ic):
+    if c.paged:
+        out, new["conv"], new["tail"] = conv_mix_rows(
+            cfg, lp, h, new["conv"], new["tail"], ic, c.rows, c.pos, c.bs)
+    else:
+        out, state = conv_mix(cfg, lp, h, new["conv"][ic])
+        new["conv"] = new["conv"].at[ic].set(state)
+    return out, new
+
+
+def _attn(cfg, c, lp, h, new, ia):
+    return stack.cached(
+        new, ("k", "v"), ia, c.paged, lambda ck, cv, layer: attention(
+            cfg, lp, h, ck, cv, c.pos, c.cos, c.sin, c.mask, c.hook, layer))
+
+
+forward_layers = functools.partial(
+    stack.forward_layers, norms=("op_norm", "ffn_norm"), prepare=_prepare,
+    routed=True,
+    kinds={"conv": ("conv_mix", "conv", _conv),
+           "full_attention": ("attn", "attn", _attn)})
+forward = functools.partial(stack.forward, forward_layers)

@@ -51,7 +51,7 @@ from ..ops.norms import rms_norm
 from ..ops.quant import expert_einsum as eem
 from ..ops.quant import matmul as mm
 from ..ops.rope import apply_rope, rope_cos_sin
-from .experts import BANKS, _normal_slices, route, routed_ffn
+from .experts import BANKS, normal_slices, route, routed_ffn
 
 Params = dict
 KVCache = dict  # {"k": [L, B, KV, S, Dh], "v": [L, B, KV, S, Dh]}
@@ -66,7 +66,7 @@ ROUTED_LEAF_KEYS = {
 
 def _init_routed(cfg: ModelConfig, key: jax.Array) -> Params:
     """init_params of a routed-expert configuration (cfg.moe_ffn_dim > 0):
-    every drawn leaf slice by slice (models/experts._normal_slices: slice i
+    every drawn leaf slice by slice (models/experts.normal_slices: slice i
     of a stacked leaf [L, ...] is normal(split(key, L)[i]) * scale in
     float32, rounded to the dtype; the two vocabulary tables 8 slices of
     rows), because the float32 draw of a whole expert bank (5.6 GB at 7 x
@@ -91,7 +91,7 @@ def _init_routed(cfg: ModelConfig, key: jax.Array) -> Params:
         "w_up": ((L, E, D, F), s), "w_down": ((L, E, F, D), F ** -0.5),
     }
     layers = {
-        name: _normal_slices(ks[ROUTED_LEAF_KEYS[name]], shape=shape,
+        name: normal_slices(ks[ROUTED_LEAF_KEYS[name]], shape=shape,
                              scale=float(scale), dtype=dt)
         for name, (shape, scale) in shapes.items()
     }
@@ -105,7 +105,7 @@ def _init_routed(cfg: ModelConfig, key: jax.Array) -> Params:
 
     def table(name, shape, scale):
         cut = 8 if shape[0] % 8 == 0 else 1
-        return _normal_slices(
+        return normal_slices(
             ks[ROUTED_LEAF_KEYS[name]], scale=float(scale), dtype=dt,
             shape=(cut, shape[0] // cut) + shape[1:],
         ).reshape(shape)

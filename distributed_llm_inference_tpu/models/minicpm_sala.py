@@ -1,10 +1,10 @@
 """Sparse attention beside decayed linear attention (openbmb/MiniCPM-SALA,
 model_type minicpm_sala) in pure JAX.
 
-Layers of two kinds alternate in one stack (cfg.layer_types), so the layers
-are not scanned: a Python loop over the pattern, each layer reading its own
-row of the stacked leaves of its kind. RMSNorm eps cfg.norm_eps everywhere;
-x a layer's input, D = cfg.dim, r = cfg.residual_multiplier (the family's
+The stack of two kinds of layer (cfg.layer_types) is models/stack.py's loop,
+as is the tuple-a-layer draw; here the family's leaves, its selection, its
+two mixers and their binding. RMSNorm eps cfg.norm_eps everywhere; x a
+layer's input, D = cfg.dim, r = cfg.residual_multiplier (the family's
 scale_depth / sqrt(the PUBLISHED depth)):
 
   embed     table[token] x cfg.embed_multiplier (scale_emb)
@@ -84,19 +84,19 @@ where it leaves a [D, 4 D] one alone.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
 
+from . import stack
 from ..config import ModelConfig
 from ..ops import sparse_select
 from ..ops.flash_attention import resolve_interpret
 from ..ops.linear_attention import linear_attend_rows
 from ..ops.norms import rms_norm
 from ..ops.rope import apply_rope, rope_cos_sin
-from .experts import _normal_slices
-from .mla_moe import swiglu
+from .stack import embed, unembed  # noqa: F401 - the family's ends
 
 Params = dict
 F32 = jnp.float32
@@ -124,8 +124,8 @@ def stack_depths(cfg: ModelConfig) -> dict:
 def leaf_shapes(cfg: ModelConfig) -> dict:
     """{leaf path: (shape, init scale or None for ones)}, stacked leaves
     with their layer axis first."""
-    D, V, F, L = cfg.dim, cfg.vocab_size, cfg.ffn_dim, cfg.n_layers
-    H, KV, Dh, Hl = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.linear_heads
+    D, V, L = cfg.dim, cfg.vocab_size, cfg.n_layers
+    Dh, Hl = cfg.head_dim, cfg.linear_heads
     n = stack_depths(cfg)
     Ls, Ll = n["sparse"], n["linear"]
     s = D ** -0.5
@@ -133,95 +133,19 @@ def leaf_shapes(cfg: ModelConfig) -> dict:
         "embed": ((V, D), 0.02), "lm_head": ((V, D), s),
         "final_norm": ((D,), None),
         "op_norm": ((L, D), None), "ffn_norm": ((L, D), None),
-        "sparse.wq": ((Ls, D, H * Dh), s), "sparse.wk": ((Ls, D, KV * Dh), s),
-        "sparse.wv": ((Ls, D, KV * Dh), s),
-        "sparse.wo": ((Ls, H * Dh, D), (H * Dh) ** -0.5),
-        "sparse.wg": ((Ls, D, H * Dh), s),
-        "sparse.q_norm": ((Ls, Dh), None), "sparse.k_norm": ((Ls, Dh), None),
-        "linear.wq": ((Ll, D, Hl * Dh), s), "linear.wk": ((Ll, D, Hl * Dh), s),
-        "linear.wv": ((Ll, D, Hl * Dh), s),
-        "linear.wo": ((Ll, Hl * Dh, D), (Hl * Dh) ** -0.5),
-        "linear.wg": ((Ll, D, Hl * Dh), s),
-        "linear.q_norm": ((Ll, Dh), None), "linear.k_norm": ((Ll, Dh), None),
+        **stack.attn_shapes("sparse", Ls, D, cfg.n_heads, cfg.n_kv_heads, Dh,
+                            gate=True, qk_norm=True),
+        **stack.attn_shapes("linear", Ll, D, Hl, Hl, Dh, gate=True,
+                            qk_norm=True),
         "linear.o_norm": ((Ll, Hl * Dh), None),
-        "ffn.w_gate": ((L, D, F), s), "ffn.w_up": ((L, D, F), s),
-        "ffn.w_down": ((L, F, D), F ** -0.5),
+        **stack.ffn_shapes("ffn", L, D, cfg.ffn_dim),
     }
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _normal(key, shape, scale, dtype):
-    return (jax.random.normal(key, shape, F32) * scale).astype(dtype)
-
-
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
-    """Seeded random parameters (tests and benchmarks): a stacked shape
-    [n, ...] of `leaf_shapes` is n arrays, array i a scaled normal drawn from
-    split(key, n)[i] in float32 and rounded to the dtype (the slices
-    models/experts._normal_slices draws as one leaf); norm weights 1; the
-    two vocabulary tables 8 such slices of rows each where the vocabulary
-    divides."""
-    if cfg.tie_embeddings:
-        raise ValueError(f"{cfg.name}: the minicpm_sala family's head is "
-                         f"untied")
-    dt = cfg.jnp_dtype
-    ks = jax.random.split(key, 24)
-    layers: Params = {"sparse": {}, "linear": {}, "ffn": {}}
-    params: Params = {"layers": layers}
-    for path, (shape, scale) in leaf_shapes(cfg).items():
-        kind, _, name = path.rpartition(".")
-        if scale is None:
-            leaf = jnp.ones(shape, dt)
-            if kind:
-                leaf = tuple(leaf)
-        elif kind:
-            keys = jax.random.split(ks[LEAF_KEYS[path]], shape[0])
-            leaf = tuple(_normal(keys[i], shape[1:], float(scale), dt)
-                         for i in range(shape[0]))
-        else:
-            cut = 8 if shape[0] % 8 == 0 else 1
-            leaf = _normal_slices(
-                ks[LEAF_KEYS[path]], scale=float(scale),
-                shape=(cut, shape[0] // cut) + shape[1:], dtype=dt,
-            ).reshape(shape)
-        if kind:
-            layers[kind][name] = leaf
-        elif name in ("embed", "lm_head", "final_norm"):
-            params[name] = leaf
-        else:
-            layers[name] = leaf
-    for kind, order in W_IN.items():  # the input projections side by side
-        drawn = [layers[kind].pop(name) for name in order]
-        layers[kind]["w_in"] = tuple(
-            jnp.concatenate(parts, axis=1) for parts in zip(*drawn))
-    return params
-
-
-def init_kv_cache(cfg: ModelConfig, batch: int, max_seq: Optional[int] = None,
-                  n_layers: Optional[int] = None):
-    raise ValueError(
-        f"{cfg.name}: the minicpm_sala family is served from the paged pool "
-        f"by the continuous engine only (--continuous N --kv-pool-blocks M): "
-        f"there is no dense cache of compressed keys and matrix states"
-    )
-
-
-@jax.named_scope("embed")
-def embed(cfg: ModelConfig, params: Params, tokens, pos=0):
-    """[B, T] -> [B, T, D], float32: the residual stream's dtype."""
-    del pos
-    return params["embed"][tokens].astype(F32) * (cfg.embed_multiplier or 1.0)
-
-
-@jax.named_scope("head")
-def unembed(cfg: ModelConfig, params: Params, x):
-    """The last RMSNorm and the untied table: float32 logits."""
-    h = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(cfg.jnp_dtype)
-    logits = jax.lax.dot_general(
-        h, params["lm_head"], (((h.ndim - 1,), (1,)), ((), ())),
-        preferred_element_type=F32,
-    )
-    return logits / (cfg.logits_divider or 1.0)
+    """Seeded random parameters (tests and benchmarks):
+    `stack.draw_layer_tuples`; the head is untied."""
+    return stack.draw_layer_tuples(cfg, key, leaf_shapes(cfg), LEAF_KEYS, W_IN)
 
 
 # -- the selection ------------------------------------------------------------
@@ -325,10 +249,6 @@ def compressed_keys(cfg: ModelConfig, k, pool_k, pool_ck, layer: int, rows,
 # -- the mixers ---------------------------------------------------------------
 
 
-def _put(leaves: tuple, i: int, leaf) -> tuple:
-    return leaves[:i] + (leaf,) + leaves[i + 1:]
-
-
 def _project(cfg, lp, h, kind: str, heads_q: int, heads_k: int):
     """(q, k, v normed where the family norms them, each [W, 1, heads, Dh],
     and the gate's logits [W, heads_q x Dh] float32) of normed h [W, D]: one
@@ -336,11 +256,7 @@ def _project(cfg, lp, h, kind: str, heads_q: int, heads_k: int):
     W, Dh = h.shape[0], cfg.head_dim
     width = {"wq": heads_q, "wg": heads_q, "wk": heads_k, "wv": heads_k}
     cuts, at = {}, 0
-    # (handed on as it is: a slice straight after the product is moved
-    # through the dot onto the weight, and each part's product then reads the
-    # whole matrix again: models/llama.pin_products)
-    out = jax.lax.optimization_barrier(
-        jnp.dot(h, lp["w_in"], preferred_element_type=F32))
+    out = stack.project(lp, h)
     for name in W_IN[kind]:
         cuts[name] = out[:, at:at + width[name] * Dh]
         at += width[name] * Dh
@@ -353,12 +269,11 @@ def _project(cfg, lp, h, kind: str, heads_q: int, heads_k: int):
     return q, k, v, cuts["wg"]
 
 
-def sparse_attention(cfg: ModelConfig, lp: Params, h, pool, layer: int, hook,
-                     rows, pos, tq: int, tiles):
+def sparse_attention(cfg: ModelConfig, c, lp: Params, h, pool, layer: int):
     """The "minicpm4" mixer over a paged launch's flat tokens: normed h
-    [W, 1, D] at positions pos [W]; tiles: `tile_meta` of the launch.
-    Returns (float32 [W, 1, D], pool)."""
-    W = h.shape[0]
+    [W, 1, D] at positions c.pos [W] (c: `_prepare`'s; c.tiles `tile_meta`
+    of the launch). Returns (float32 [W, 1, D], pool)."""
+    W, rows, pos, tq = h.shape[0], c.rows, c.pos, c.tile
     H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q, k, v, gate = _project(cfg, lp, h[:, 0], "sparse", H, KV)
     MB = rows.table.shape[1]
@@ -368,14 +283,14 @@ def sparse_attention(cfg: ModelConfig, lp: Params, h, pool, layer: int, hook,
                                   pool["ck"][layer], layer, rows, pos)
         _, chosen = sparse_select.select_blocks(
             q[:, 0].reshape(G, tq, KV, H // KV, Dh), ck_pool, rows.table,
-            tiles, block=cfg.sparse_block, stride=cfg.sparse_stride,
+            c.tiles, block=cfg.sparse_block, stride=cfg.sparse_stride,
             kernel=cfg.sparse_kernel, topk=cfg.sparse_topk,
             window=cfg.sparse_window, init=cfg.sparse_init_blocks,
             dense_len=cfg.sparse_dense_len,
             interpret=resolve_interpret(None))
         chosen &= (rows.tok_row >= 0).reshape(G, tq, 1, 1)
         pages = page_lists(chosen, list_width(cfg, tq, MB))
-    attn, new_k, new_v = hook(
+    attn, new_k, new_v = c.hook(
         cfg, q, k, v, pool["k"], pool["v"], pos, None, None, None, None,
         layer, pages=pages,
     )
@@ -383,20 +298,19 @@ def sparse_attention(cfg: ModelConfig, lp: Params, h, pool, layer: int, hook,
          * jax.nn.sigmoid(gate)).astype(cfg.jnp_dtype)
     out = jnp.dot(y, lp["wo"], preferred_element_type=F32)
     return out[:, None], {**pool, "k": new_k, "v": new_v,
-                          "ck": _put(pool["ck"], layer, ck_pool)}
+                          "ck": stack.put(pool["ck"], layer, ck_pool)}
 
 
-def linear_attention(cfg: ModelConfig, lp: Params, h, pool, layer: int, rows,
-                     pos, cos, sin, tq: int):
+def linear_attention(cfg: ModelConfig, c, lp: Params, h, pool, layer: int):
     """The "lightning-attn" mixer over a paged launch's flat tokens (normed
-    h [W, 1, D]). A row that starts a tenant (rows.fresh) starts from zeros,
+    h [W, 1, D]; c: `_prepare`'s). A row that starts a tenant (rows.fresh) starts from zeros,
     or from snapshot rows.restore after a prefix hit, never from what the
     slot's previous tenant left; a row with rows.take >= 0 leaves its state
     after this launch in that snapshot. Returns (float32 [W, 1, D], pool)."""
-    W = h.shape[0]
+    W, rows = h.shape[0], c.rows
     Hl, Dh = cfg.linear_heads, cfg.head_dim
     q, k, v, gate = _project(cfg, lp, h[:, 0], "linear", Hl, Hl)
-    q, k = apply_rope(q, k, cos, sin)
+    q, k = apply_rope(q, k, c.cos, c.sin)
     q = (q.astype(F32) * Dh ** -0.5).astype(cfg.jnp_dtype)
     lin, snap = pool["lin"][layer], pool["snap"][layer]
 
@@ -411,7 +325,7 @@ def linear_attention(cfg: ModelConfig, lp: Params, h, pool, layer: int, rows,
         start = jax.lax.cond(jnp.any(rows.fresh), restored, lambda: lin)
     # (a decode step is the same call: one token a row, a tile each)
     o, lin = linear_attend_rows(q[:, 0], k[:, 0], v[:, 0], start,
-                                rows.tok_row, tq)
+                                rows.tok_row, c.tile)
     if rows.take is not None:
         at = jnp.where(rows.take >= 0, rows.take, snap.shape[0])  # dropped
         snap = jax.lax.cond(
@@ -420,77 +334,30 @@ def linear_attention(cfg: ModelConfig, lp: Params, h, pool, layer: int, rows,
     o = rms_norm(o.reshape(W, Hl * Dh), lp["o_norm"], cfg.norm_eps)
     y = (o.astype(F32) * jax.nn.sigmoid(gate)).astype(cfg.jnp_dtype)
     out = jnp.dot(y, lp["wo"], preferred_element_type=F32)
-    return out[:, None], {**pool, "lin": _put(pool["lin"], layer, lin),
-                          "snap": _put(pool["snap"], layer, snap)}
+    return out[:, None], {**pool, "lin": stack.put(pool["lin"], layer, lin),
+                          "snap": stack.put(pool["snap"], layer, snap)}
 
 
 # -- the stack ----------------------------------------------------------------
 
 
-def forward_layers(cfg: ModelConfig, layers: Params, x, cache, pos,
-                   update_gate=None, tp_axis=None, attn_hook=None,
-                   valid_start=None, ep_axis=None, attn_seq_len=None):
-    """Every layer over a paged launch's flat tokens x [W, 1, D] (float32
-    residual) at positions pos [W]; cache the pool (module docstring);
-    attn_hook a paged hook (engine/paged.py) whose `rows()` says how the
-    tokens fall into fleet rows. Returns (x, the pool)."""
-    if tp_axis is not None or ep_axis is not None or update_gate is not None:
-        raise ValueError("the minicpm_sala family is not sharded over pp, "
-                         "tp or ep")
-    if valid_start is not None or not getattr(attn_hook, "paged", False):
-        raise ValueError(
-            "the minicpm_sala family is served from the paged pool only: "
-            "flat tokens under a paged hook, no left-padded rows")
-    del attn_seq_len
-    W, T = x.shape[:2]
-    assert T == 1, "the paged launches carry one token a batch row"
-    pos = jnp.asarray(pos, jnp.int32)
-    rows = attn_hook.rows()
-    tq = attn_hook.tile
-    with jax.named_scope("linear_attn"):  # the rotary tables, once a forward
+def _prepare(cfg: ModelConfig, layers: Params, x, cache, pos, hook,
+             attn_seq_len):
+    """Once a forward: how the launch's flat tokens fall into fleet rows
+    (engine/paged.StateRows), the launch's tile, the linear layers' rotary
+    tables and the selection's scalars."""
+    rows, tq = hook.rows(), hook.tile
+    with jax.named_scope("linear_attn"):
         cos, sin = rope_cos_sin(pos[:, None], cfg.head_dim, cfg.rope_theta)
     with jax.named_scope("attn"), jax.named_scope("sparse_select"):
         tiles = tile_meta(cfg, rows, pos, tq)
-    dt = cfg.jnp_dtype
-    r = cfg.residual_multiplier or 1.0
-
-    def row(kind, i):
-        return {name: leaf[i] for name, leaf in layers[kind].items()}
-
-    new = dict(cache)
-    scope = {"lightning-attn": "linear_attn", "minicpm4": "attn"}
-    il = ia = 0
-    for li, kind in enumerate(cfg.layer_types):
-        with jax.named_scope(scope[kind]):
-            h = rms_norm(x, layers["op_norm"][li], cfg.norm_eps).astype(dt)
-            if kind == "minicpm4":
-                out, new = sparse_attention(cfg, row("sparse", ia), h, new,
-                                            ia, attn_hook, rows, pos, tq,
-                                            tiles)
-                ia += 1
-            else:
-                out, new = linear_attention(cfg, row("linear", il), h, new,
-                                            il, rows, pos, cos, sin, tq)
-                il += 1
-        with jax.named_scope("ffn"):
-            x = x + r * out
-            h = rms_norm(x, layers["ffn_norm"][li], cfg.norm_eps).astype(dt)
-            lp = row("ffn", li)
-            # (rows x D: a batch of one-token rows made the last product a
-            # multiply-and-reduce at half the bandwidth in the decode chunk)
-            # (... and handed on as it is: fused with the residual add and
-            # the next norm's sum of squares the same product took twice its
-            # time: my chip run, PR 48)
-            out = jax.lax.optimization_barrier(
-                swiglu(h[:, 0], lp["w_gate"], lp["w_up"], lp["w_down"])
-            )[:, None]
-        after = cfg.layer_types[li + 1:li + 2]
-        with jax.named_scope(scope[after[0]] if after else "head"):
-            x = x + r * out
-    return x, new
+    return SimpleNamespace(pos=pos, hook=hook, rows=rows, tile=tq, cos=cos,
+                           sin=sin, tiles=tiles)
 
 
-def forward(cfg: ModelConfig, params: Params, tokens, cache, pos):
-    raise ValueError(
-        f"{cfg.name}: the minicpm_sala family has no dense-cache forward; "
-        f"it is served from the paged pool (engine/paged.py)")
+forward_layers = functools.partial(
+    stack.forward_layers, norms=("op_norm", "ffn_norm"), prepare=_prepare,
+    dense=("ffn", stack.rows_ffn), paged_only=True,
+    kinds={"minicpm4": ("attn", "sparse", sparse_attention),
+           "lightning-attn": ("linear_attn", "linear", linear_attention)})
+init_kv_cache = forward = stack.paged_pool_only

@@ -60,18 +60,15 @@ from ..ops.attention import causal_mask, ragged_causal_mask, slot_causal_mask
 from ..ops.norms import rms_norm
 from ..ops.rope import rope_interleaved
 from .experts import (  # noqa: F401 - the family's routed half, re-exported
-    BANKS, _normal_slices, grouped_matmul, route, routed_expert_matmul,
+    BANKS, normal_slices, grouped_matmul, route, routed_expert_matmul,
     routed_ffn,
 )
 from .llama import pin_products, scan_layers  # one scan, dense cache or pool
+from .stack import ROUTER_BIAS_SCALE, swiglu
 
 Params = dict
 F32 = jnp.float32
 
-# the scale init_params draws the selection bias at (a trained checkpoint
-# brings its own): sigmoid scores of unit-variance logits lie a few
-# hundredths apart near the k-th place, so this changes some choices
-ROUTER_BIAS_SCALE = 0.05
 # init_params' key of each leaf: an index into split(key, 24), one table for
 # both stacks (cellbench/reference/mla_moe.py writes the same table down)
 LEAF_KEYS = {
@@ -141,7 +138,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             # the two vocabulary tables are drawn as 8 slices of rows
             cut = 8 if path in ("embed", "lm_head") and shape[0] % 8 == 0 \
                 else None
-            leaf = _normal_slices(
+            leaf = normal_slices(
                 ks[LEAF_KEYS[path]], scale=float(scale),
                 shape=(cut, shape[0] // cut) + shape[1:] if cut else shape,
                 dtype=F32 if path == "moe.router_bias" else dt,
@@ -260,14 +257,6 @@ def attention(cfg: ModelConfig, lp: Params, x, cache, pos, positions, mask,
 
 
 # -- feed-forward -------------------------------------------------------------
-
-
-def swiglu(h, w_gate, w_up, w_down):
-    """SwiGLU on h [..., D] in the parameter dtype; float32 out."""
-    gate = jax.nn.silu(jnp.dot(h, w_gate, preferred_element_type=F32))
-    up = jnp.dot(h, w_up, preferred_element_type=F32)
-    return jnp.dot((gate * up).astype(h.dtype), w_down,
-                   preferred_element_type=F32)
 
 
 def moe_ffn(cfg: ModelConfig, lp: Params, banks: Params, layer, h, live=None):

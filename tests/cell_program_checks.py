@@ -279,7 +279,7 @@ def test_the_linear_scan_takes_its_leaf_in_place(one_chip, no_persistent_cache,
     holds the leaves among the program's aliased arguments), and no
     instruction but a row's own `dynamic-update-slice` writes one where a
     snapshot is restored or kept a row at a time
-    (models/granite_hybrid._move_rows)."""
+    (models/stack.move_rows)."""
     built = cell_programs(config)
     if not built.cfg.linear_layers:
         assert "lin" not in built.pool

@@ -6,7 +6,7 @@ A case's shapes give its P through `_walk_shape` itself (asserted, so a
 change of the rule cannot leave a case at another P in silence): bf16 pools
 of 2 KV heads x 128 in blocks of 1024 / P tokens under a table 3 P + 1 pages
 wide, where the cap of 1,024 positions a step decides; a latent row pool;
-pairs of 64-number heads side by side (models/lfm2.pack_heads). Contexts end
+pairs of 64-number heads side by side (models/stack.pack_heads). Contexts end
 at P x k pages exactly, one position past, one page short; windows start
 inside a compute block; a tile's new rows straddle a page edge inside a
 compute block and across two; rows that hold nothing lie beside live ones.
@@ -21,7 +21,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from distributed_llm_inference_tpu.config import resolve_attn_impl
 from distributed_llm_inference_tpu.engine import paged as EP
-from distributed_llm_inference_tpu.models.lfm2 import pack_heads, unpack_heads
+from distributed_llm_inference_tpu.models.stack import pack_heads, unpack_heads
 from distributed_llm_inference_tpu.models.registry import get_model_config
 from distributed_llm_inference_tpu.ops.attention import attend
 from distributed_llm_inference_tpu.ops.paged_attention import (

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from distributed_llm_inference_tpu.engine import paged as P
-from distributed_llm_inference_tpu.models import afmoe
+from distributed_llm_inference_tpu.models import stack
 from distributed_llm_inference_tpu.models import api as M
 from distributed_llm_inference_tpu.models.registry import get_model_config
 
@@ -82,11 +82,11 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     for lo in range(0, 8, 2):  # four shares of two experts each
         part_cfg = cfg.replace(name=f"share{lo}", expert_lo=lo, n_experts_held=2)
         p = M.init_params(part_cfg, jax.random.PRNGKey(SEED))["layers"]["moe"]
-        row = {n: leaf[lm] for n, leaf in p.items() if n not in afmoe.BANKS}
-        out, sizes, away = afmoe.moe_ffn(
-            part_cfg, row, {n: p[n] for n in afmoe.BANKS}, lm, h)
+        row = {n: leaf[lm] for n, leaf in p.items() if n not in stack.BANKS}
+        out, sizes, away = stack.moe_ffn(
+            part_cfg, row, {n: p[n] for n in stack.BANKS}, lm, h)
         with jax.default_matmul_precision("highest"):
-            mine = afmoe.swiglu(h[0], row["ws_gate"], row["ws_up"], row["ws_down"])
+            mine = stack.swiglu(h[0], row["ws_gate"], row["ws_up"], row["ws_down"])
         total = total + (out[0] - mine)
         pairs += int(sizes.sum())
         assert int(sizes.sum()) + int(away) == 24 * cfg.n_experts_per_tok
@@ -113,11 +113,11 @@ def test_a_global_layer_reads_no_positions_and_a_sliding_one_does():
 
     def last(h, sliding):
         zeros = jnp.zeros((1, cfg.n_kv_heads, S, cfg.head_dim), jnp.float32)
-        out, _, _ = afmoe.attention(
+        out, _, _ = stack.gated_attention(
             cfg if sliding else cfg.replace(attn_window=None), lp, h, zeros, zeros,
             jnp.int32(0),
             rope_cos_sin(jnp.arange(T), cfg.head_dim, cfg.rope_theta) if sliding else None,
-            causal_mask(jnp.int32(0), T, S), afmoe.default_attn_hook, None)
+            causal_mask(jnp.int32(0), T, S), stack.default_attn_hook, None)
         return np.asarray(out)[0, -1]
 
     # (the same terms summed in another order)

@@ -20,7 +20,7 @@ from distributed_llm_inference_tpu.config import ModelConfig
 from distributed_llm_inference_tpu.engine import generate as G
 from distributed_llm_inference_tpu.engine import paged as P
 from distributed_llm_inference_tpu.models import api as M
-from distributed_llm_inference_tpu.models import experts, lfm2
+from distributed_llm_inference_tpu.models import experts, lfm2, stack
 from distributed_llm_inference_tpu.models.registry import get_model_config
 
 from lfm2_util import launch, ref_logits
@@ -348,7 +348,7 @@ def test_packed_heads_score_and_sum_as_the_heads_themselves():
     q = jnp.asarray(rng.normal(size=(B, T, H, Dh)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(B, S, KV, Dh)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(B, S, KV, Dh)), jnp.float32)
-    qp, kp, vp, part = lfm2.pack_heads(q, k, v, 2)
+    qp, kp, vp, part = stack.pack_heads(q, k, v, 2)
     assert qp.shape == (B, T, H, 128) and kp.shape == (B, S, KV // 2, 128)
     group = H // KV
     plain = jnp.einsum("bthd,bshd->bhts", q, jnp.repeat(k, group, axis=2))
@@ -357,7 +357,7 @@ def test_packed_heads_score_and_sum_as_the_heads_themselves():
     p = jax.nn.softmax(plain, axis=-1)
     want = jnp.einsum("bhts,bshd->bthd", p, jnp.repeat(v, group, axis=2))
     wide = jnp.einsum("bhts,bshd->bthd", p, jnp.repeat(vp, 2 * group, axis=2))
-    assert err(lfm2.unpack_heads(wide, part, 2), want) < 1e-5
+    assert err(stack.unpack_heads(wide, part, 2), want) < 1e-5
     assert float(jnp.abs(want).max()) > 0.5
 
 

@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from distributed_llm_inference_tpu.engine import paged as P
-from distributed_llm_inference_tpu.models import afmoe, mimo_v2
+from distributed_llm_inference_tpu.models import mimo_v2, stack
 from distributed_llm_inference_tpu.models import api as M
 from distributed_llm_inference_tpu.models.registry import get_model_config
 from distributed_llm_inference_tpu.ops.attention import attend
@@ -111,9 +111,9 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     for lo in range(8):  # eight shares of one expert each
         part_cfg = cfg.replace(name=f"share{lo}", expert_lo=lo, n_experts_held=1)
         p = M.init_params(part_cfg, jax.random.PRNGKey(SEED))["layers"]["moe"]
-        row = {n: leaf[lm] for n, leaf in p.items() if n not in afmoe.BANKS}
-        out, sizes, away = afmoe.moe_ffn(
-            part_cfg, row, {n: p[n] for n in afmoe.BANKS}, lm, h)
+        row = {n: leaf[lm] for n, leaf in p.items() if n not in stack.BANKS}
+        out, sizes, away = stack.moe_ffn(
+            part_cfg, row, {n: p[n] for n in stack.BANKS}, lm, h)
         total = total + out[0]
         pairs += int(sizes.sum())
         assert int(sizes.sum()) + int(away) == 24 * cfg.n_experts_per_tok

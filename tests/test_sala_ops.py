@@ -10,6 +10,7 @@ selection a query.
 
 import os
 import sys
+import types
 
 import jax
 import jax.numpy as jnp
@@ -114,8 +115,8 @@ def _linear_layer(rows, pool):
     h = jax.random.normal(jax.random.PRNGKey(4), (16, 1, CFG.dim), jnp.float32)
     pos = jnp.arange(16, dtype=jnp.int32)
     cos, sin = rope_cos_sin(pos[:, None], CFG.head_dim, CFG.rope_theta)
-    return MS.linear_attention(CFG, lp, h.astype(CFG.jnp_dtype), pool, 0,
-                               rows, pos, cos, sin, 8)
+    ctx = types.SimpleNamespace(rows=rows, pos=pos, cos=cos, sin=sin, tile=8)
+    return MS.linear_attention(CFG, ctx, lp, h.astype(CFG.jnp_dtype), pool, 0)
 
 
 def test_a_fresh_row_starts_from_its_snapshot_and_a_taken_one_holds_the_state():

@@ -366,7 +366,7 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_are_the_uncut_layer(
     shares' routed parts (each holding 8 / ep published experts of the same
     seed) plus the shared expert counted once are the uncut reference's
     layer, and every token-expert pair is computed in exactly one share."""
-    from distributed_llm_inference_tpu.models import afmoe
+    from distributed_llm_inference_tpu.models import stack
 
     cfg, layer = CFG, 2
     h = jax.random.normal(jax.random.PRNGKey(1), (1, 24, cfg.dim), jnp.float32)
@@ -381,11 +381,11 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_are_the_uncut_layer(
     for lo in range(0, 8, held):
         part = cfg.replace(name=f"share{lo}", expert_lo=lo, n_experts_held=held)
         p = M.init_params(part, jax.random.PRNGKey(SEED))["layers"]["moe"]
-        row = {n: leaf[layer] for n, leaf in p.items() if n not in afmoe.BANKS}
-        out, sizes, away = afmoe.moe_ffn(
-            part, row, {n: p[n] for n in afmoe.BANKS}, layer, h)
+        row = {n: leaf[layer] for n, leaf in p.items() if n not in stack.BANKS}
+        out, sizes, away = stack.moe_ffn(
+            part, row, {n: p[n] for n in stack.BANKS}, layer, h)
         with jax.default_matmul_precision("highest"):
-            mine = afmoe.swiglu(h[0], row["ws_gate"], row["ws_up"], row["ws_down"])
+            mine = stack.swiglu(h[0], row["ws_gate"], row["ws_up"], row["ws_down"])
         total = total + (out[0] - mine)
         pairs += int(sizes.sum())
         assert int(sizes.sum()) + int(away) == 24 * cfg.n_experts_per_tok
